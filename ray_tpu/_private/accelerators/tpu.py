@@ -19,7 +19,8 @@ from __future__ import annotations
 
 import glob
 import os
-from typing import Dict, List, Optional
+import sys
+from typing import Dict, Optional, Sequence
 
 # chips per host by generation: v2/v3/v4/v5p hosts carry 4 chips;
 # v5litepod (v5e) and v6e hosts carry up to 8.
@@ -28,6 +29,19 @@ _CHIPS_PER_HOST = {"v2": 4, "v3": 4, "v4": 4, "v5p": 4, "v5e": 8,
 # generations whose accelerator_type suffix counts TensorCores (2/chip)
 # rather than chips.
 _SUFFIX_IS_CORES = {"v2", "v3", "v4", "v5p"}
+
+
+# TPU_CHIPS_PER_HOST_BOUNDS for a process granted part of a host's
+# chips. Run on a v5litepod-4 host (chips 0..3 at x,y = 0,0 1,0 0,1 1,1)
+# with libtpu 0.0.34: one chip of four works (TPU_VISIBLE_CHIPS alone
+# would do; the bounds say what is true), four such processes run side
+# by side without per-process ports, and a process granted the whole
+# host keeps the host's own topology variables. Two chips: "2,1,1" with
+# chips 0,1 (neighbours in x) computed and all-reduced; the "1,2,1" of
+# the table this one was copied from exited before its first line of
+# output in four tries of five, for chips 0,1 and for 0,2. Pairs other
+# than 0,1 have not been run.
+_SUBSET_BOUNDS = {1: "1,1,1", 2: "2,1,1"}
 
 
 def detect_num_tpu_chips() -> int:
@@ -45,6 +59,36 @@ def detect_num_tpu_chips() -> int:
     if vfio:
         return len(vfio)
     return 0
+
+
+def apply_chip_grant(chip_ids: Sequence[int]) -> None:
+    """Bind this process to the chips its actor or task was granted.
+
+    Called by the worker once, before user code runs. With a grant the
+    process sees exactly those chips and JAX runs on `tpu`, so a missing
+    chip is an error and not a quiet CPU run; with none JAX runs on
+    `cpu` and the process can never open a chip that another worker was
+    granted."""
+    if chip_ids:
+        platform = "tpu"
+        whole_host = len(chip_ids) == detect_num_tpu_chips()
+        os.environ["TPU_VISIBLE_CHIPS"] = ",".join(map(str, chip_ids))
+        if not whole_host:
+            if len(chip_ids) not in _SUBSET_BOUNDS:
+                raise ValueError(
+                    f"no TPU_CHIPS_PER_HOST_BOUNDS known for "
+                    f"{len(chip_ids)} of this host's chips; grant 1, 2 "
+                    f"or all of them")
+            os.environ["TPU_CHIPS_PER_HOST_BOUNDS"] = \
+                _SUBSET_BOUNDS[len(chip_ids)]
+            os.environ["TPU_HOST_BOUNDS"] = "1,1,1"
+    else:
+        platform = "cpu"
+    os.environ["JAX_PLATFORMS"] = platform
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        # the variable was read when jax was imported
+        jax.config.update("jax_platforms", platform)
 
 
 def parse_accelerator_type(accelerator_type: str) -> tuple:
@@ -107,15 +151,6 @@ class TPUAcceleratorManager:
     @staticmethod
     def get_current_pod_worker_id() -> int:
         return int(os.environ.get("TPU_WORKER_ID", "0"))
-
-    @staticmethod
-    def set_visible_accelerators(chip_ids: List[int]) -> None:
-        """Restrict this process to a chip subset (reference :154-195)."""
-        os.environ["TPU_VISIBLE_CHIPS"] = ",".join(map(str, chip_ids))
-        n = len(chip_ids)
-        bounds = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1", 8: "2,4,1"}
-        if n in bounds:
-            os.environ["TPU_CHIPS_PER_HOST_BOUNDS"] = bounds[n]
 
     @classmethod
     def get_current_node_additional_resources(cls) -> Dict[str, float]:

@@ -40,6 +40,8 @@ PG_REMOVED = "REMOVED"
 PG_RESCHEDULING = "RESCHEDULING"
 
 from ray_tpu._private.config import CONFIG as _CFG
+
+_MONITOR_PERIOD_S = 0.5     # liveness sweep cadence
 _HYBRID_THRESHOLD = 0.5
 
 
@@ -942,7 +944,17 @@ class ClusterTaskManager:
     def _monitor_loop(self) -> None:
         """GcsHealthCheckManager parity: staleness-based liveness."""
         while self._running:
-            time.sleep(0.5)
+            t = time.monotonic()
+            time.sleep(_MONITOR_PERIOD_S)
+            if time.monotonic() - t > 2 * _MONITOR_PERIOD_S:
+                # This process did not run for a while (a worker opening
+                # a TPU freezes the whole microVM for seconds; so does a
+                # suspended host). Every heartbeat it should have made or
+                # read stood still with it, so a sweep now would declare
+                # the head's own node dead on the evidence of its own
+                # silence. Judge nobody; what is really dead is still
+                # silent at the next sweep.
+                continue
             try:
                 self._sweep_liveness()
             except Exception:

@@ -1709,7 +1709,7 @@ def main(argv: Optional[list[str]] = None) -> None:
     args = p.parse_args(argv)
 
     host, port = args.head.rsplit(":", 1)
-    from ray_tpu._private.runtime import detect_num_tpu_chips
+    from ray_tpu._private.accelerators import detect_num_tpu_chips
     num_cpus = (args.num_cpus if args.num_cpus is not None
                 else float(max(os.cpu_count() or 1, 4)))
     num_tpus = (args.num_tpus if args.num_tpus is not None

@@ -13,7 +13,6 @@ the reference even though control-plane hops are function calls.
 """
 from __future__ import annotations
 
-import glob
 import logging
 import os
 import socket
@@ -27,6 +26,7 @@ from ray_tpu._private import context as _context
 from ray_tpu._private import metrics_plane as _mp
 from ray_tpu._private import protocol
 from ray_tpu._private import tracing_plane as _tp
+from ray_tpu._private.accelerators import detect_num_tpu_chips
 from ray_tpu._private.controller import (ALIVE, DEAD, PENDING, RESTARTING,
                                          Controller)
 from ray_tpu._private.object_store import LocalStore, StoredObject, deserialize
@@ -37,21 +37,6 @@ from ray_tpu._private.specs import (ActorSpec, ActorTaskSpec, TaskSpec,
 from ray_tpu.exceptions import (ActorDiedError, ActorError, GetTimeoutError,
                                 TaskCancelledError, TaskError,
                                 WorkerDiedError)
-
-
-def detect_num_tpu_chips() -> int:
-    """TPU chip detection, reference python/ray/_private/accelerators/tpu.py:98-117
-    (probes /dev/accel* then /dev/vfio), with an env override."""
-    env = os.environ.get("RAY_TPU_CHIPS")
-    if env is not None:
-        return int(env)
-    accel = glob.glob("/dev/accel*")
-    if accel:
-        return len(accel)
-    vfio = glob.glob("/dev/vfio/[0-9]*")
-    if vfio:
-        return len(vfio)
-    return 0
 
 
 def _summarize_by_state(rows: list) -> dict:

@@ -18,7 +18,9 @@ Parity map (reference src/ray/raylet/):
 """
 from __future__ import annotations
 
+import math
 import os
+import signal
 import subprocess
 import sys
 import threading
@@ -38,6 +40,7 @@ IDLE = "idle"
 BUSY = "busy"
 ACTOR = "actor"
 STARTING = "starting"
+RETIRING = "retiring"    # told to exit so the chips it holds come free
 DEAD = "dead"
 
 from ray_tpu._private.config import CONFIG as _CFG
@@ -75,6 +78,10 @@ class WorkerRec:
     # spawned inside a container image: permanently bound to that env —
     # only exact-hash tasks may use it, and its hash never changes
     container: bool = False
+    # TPU chip ids this process was granted with its first actor or
+    # task: None until then, () pins it to the CPU, and ids stay with
+    # it until the process has exited (a process cannot hand a chip on).
+    chips: Optional[tuple] = None
 
 
 def _node_memory_fraction() -> float:
@@ -163,6 +170,9 @@ class Scheduler:
         self._cluster = cluster
         self.total = dict(node_resources)
         self.avail = dict(node_resources)
+        # chip ids no live process holds; `avail["TPU"]` counts grants,
+        # this names them, so each chip is open in one process at most
+        self._free_chips = list(range(int(self.total.get("TPU", 0))))
         self._addr = listen_addr
         self._max_workers = (max_workers or _CFG.worker_pool_max
                              or max(int(node_resources.get("CPU", 4)) * 2,
@@ -324,7 +334,10 @@ class Scheduler:
         env["PYTHONPATH"] = pkg_root + os.pathsep + env.get("PYTHONPATH", "")
         env["RAY_TPU_WORKER_ID"] = wid
         env["RAY_TPU_NODE_ID"] = self.node_id
-        cmd = [sys.executable, "-m", "ray_tpu._private.worker_main",
+        # faulthandler: a worker that a native library aborts (libtpu, a
+        # Mosaic kernel) says where, instead of just vanishing
+        cmd = [sys.executable, "-X", "faulthandler", "-m",
+               "ray_tpu._private.worker_main",
                "--addr", f"{self._addr[0]}:{self._addr[1]}",
                "--worker-id", wid]
         spawn_hash = ""
@@ -363,12 +376,48 @@ class Scheduler:
             conn.enable_coalescing()
             self._cv.notify_all()
 
+    @staticmethod
+    def _reap(rec: WorkerRec) -> None:
+        """Wait until the worker's process is gone. Its connection
+        closes a moment before it exits, and a chip is reusable only
+        once the process that opened it has."""
+        if rec.proc is None:
+            return
+        try:
+            rec.proc.wait(timeout=5.0)
+        except subprocess.TimeoutExpired:
+            rec.proc.kill()
+            rec.proc.wait()
+
+    def _retire_locked(self, rec: WorkerRec) -> None:
+        """Tell an idle worker to exit: chips come free for the next
+        grant only when the process that opened them is gone, and a
+        worker pinned to the CPU can never take a grant. on_worker_lost
+        finishes the job when its connection closes."""
+        rec.state = RETIRING
+        try:
+            rec.conn.send({"type": protocol.SHUTDOWN})
+        except Exception:
+            if rec.proc is not None:
+                rec.proc.terminate()
+
     def on_worker_lost(self, worker_id: str):
         """Returns (in-flight tasks, actor_id) for recovery."""
-        with self._cv:
+        with self._lock:
             rec = self._workers.get(worker_id)
+        if rec is not None and rec.chips:
+            self._reap(rec)
+            code = rec.proc.returncode if rec.proc is not None else 0
+            if code not in (0, -signal.SIGTERM) and rec.state != DEAD:
+                sys.stderr.write(
+                    f"ray_tpu: worker {worker_id} holding TPU chips "
+                    f"{rec.chips} exited with code {code}\n")
+        with self._cv:
             if rec is None or rec.state == DEAD:
                 return [], None
+            if rec.chips:
+                self._free_chips = sorted(self._free_chips + list(rec.chips))
+                rec.chips = ()
             if rec.state == STARTING:
                 self._spawning = max(0, self._spawning - 1)
             tasks, actor_id = list(rec.tasks.values()), rec.actor_id
@@ -727,6 +776,8 @@ class Scheduler:
                 self._promote_next_charge_locked(rec)
             if rec.state == BUSY and not rec.tasks:
                 rec.state = IDLE
+                if rec.chips:
+                    self._retire_locked(rec)
             # Dispatch the next queued specs NOW, on the completion
             # reader thread, instead of bouncing through the loop
             # thread — but with refill hysteresis: only sweep once this
@@ -783,7 +834,8 @@ class Scheduler:
                 pass
         return k
 
-    def _pick_worker(self, spec=None) -> Optional[WorkerRec]:
+    def _pick_worker(self, spec=None, chips: int = 0
+                     ) -> Optional[WorkerRec]:
         """Idle worker, preferring one whose last applied runtime env
         matches the spec's (runtime-env-keyed reuse). Pipelining onto a
         BUSY worker is the dispatch sweep's job (_pick_piggyback): it
@@ -802,6 +854,9 @@ class Scheduler:
                 continue
             if rec.container and rec.env_hash != want:
                 continue    # image-bound: invisible to other tasks
+            if rec.chips is not None and (chips or rec.chips):
+                continue    # chips go to a fresh process, CPU work
+                            # never to a process that holds chips
             if rec.state == IDLE:
                 if rec.env_hash == want:
                     return rec
@@ -845,11 +900,13 @@ class Scheduler:
         if depth <= 1:
             return None
         want = self._spec_env_hash(spec)
+        chips = self.chips_of(need)
         for rec in self._workers.values():
             if (rec.conn is None or rec.state != BUSY
                     or rec.worker_id not in eligible
                     or rec.blocked_depth > 0 or rec.env_hash != want
-                    or len(rec.tasks) >= depth or not rec.task_res):
+                    or len(rec.tasks) >= depth or not rec.task_res
+                    or len(rec.chips) != chips):
                 continue
             last_need, last_pg, _ = next(reversed(rec.task_res.values()))
             if last_pg != pg_key:
@@ -868,6 +925,12 @@ class Scheduler:
             res.setdefault("CPU", 1.0)
         res.pop("_pg_reserved", None)
         return res
+
+    @staticmethod
+    def chips_of(need: dict[str, float]) -> int:
+        """Whole chips a grant of `need` opens: a chip belongs to one
+        process, so a fraction still takes one."""
+        return math.ceil(need.get("TPU", 0.0) - 1e-9)
 
     def _effective_need(self, spec) -> dict[str, float]:
         return self.need_of(spec)
@@ -1256,6 +1319,7 @@ class Scheduler:
             pool = (self._bundles[pg_key]["avail"] if pg_key is not None
                     else self.avail)
             charged = True
+            chips = self.chips_of(need)
             if not fits(pool, need):
                 # Saturated: the spec may still pipeline onto a BUSY
                 # worker's existing grant (uncharged until the task
@@ -1270,7 +1334,9 @@ class Scheduler:
                     continue
                 charged = False
             else:
-                worker = self._pick_worker(spec)
+                if chips > len(self._free_chips):
+                    continue    # the last holder has not exited yet
+                worker = self._pick_worker(spec, chips)
                 if worker is None:
                     # no idle worker: pipeline onto a busy one rather
                     # than stalling the sweep on a spawn round-trip;
@@ -1295,7 +1361,15 @@ class Scheduler:
                 # than pending work items (raylet WorkerPool prestart logic,
                 # worker_pool.cc PrestartWorkers, is demand-capped the same
                 # way).
-                if (pool_count - blocked < self._max_workers
+                if chips and pool_count - blocked >= self._max_workers:
+                    # a grant needs a fresh process and the pool is
+                    # full of workers pinned to the CPU: make room
+                    states = [r.state for r in self._workers.values()]
+                    if RETIRING not in states and IDLE in states:
+                        self._retire_locked(next(
+                            r for r in self._workers.values()
+                            if r.state == IDLE))
+                elif (pool_count - blocked < self._max_workers
                         and self._spawning < min(len(self._pending), 4)):
                     spawn_err: Optional[BaseException] = None
                     self._send_dispatch_outbox(outbox)
@@ -1348,6 +1422,9 @@ class Scheduler:
                 acquire(pool, need)
             if not worker.container:     # image-bound hash is immutable
                 worker.env_hash = self._spec_env_hash(spec)
+            if worker.chips is None:
+                worker.chips = tuple(self._free_chips[:chips])
+                del self._free_chips[:chips]
             if isinstance(spec, ActorSpec):
                 worker.acquired = need
                 worker.pg_key = pg_key
@@ -1356,13 +1433,15 @@ class Scheduler:
                 self._rt.on_actor_dispatched(spec, worker.worker_id)
                 outbox.append((worker.conn,
                                {"type": protocol.ACTOR_CREATE,
-                                "spec": spec}))
+                                "spec": spec,
+                                "tpu_chips": worker.chips}))
             else:
                 worker.state = BUSY
                 worker.tasks[spec.task_id] = spec
                 worker.task_res[spec.task_id] = (need, pg_key, charged)
                 self._rt.on_task_dispatched(spec, worker.worker_id)
-                msg = {"type": protocol.TASK, "spec": spec}
+                msg = {"type": protocol.TASK, "spec": spec,
+                       "tpu_chips": worker.chips}
                 # getattr: a spec pickled by a pre-r9 peer has no
                 # trace fields (dataclasses pickle via __dict__)
                 if _tp.enabled() and getattr(spec, "trace_id", 0):
@@ -1497,6 +1576,7 @@ class Scheduler:
                     rec.proc.wait(timeout=max(0.1, deadline - time.time()))
                 except subprocess.TimeoutExpired:
                     rec.proc.kill()
+                    rec.proc.wait()     # no process outlives shutdown
 
     # ---- node-death paths (ClusterTaskManager hooks) ----
     def die_silently(self) -> None:
@@ -1558,6 +1638,14 @@ class Scheduler:
                     rec.proc.kill()
                 except Exception:
                     pass
+        held = [c for rec in workers for c in rec.chips or ()]
+        if held:
+            for rec in workers:
+                if rec.chips:
+                    self._reap(rec)
+            with self._cv:
+                self._free_chips = sorted(self._free_chips + held)
+                self._cv.notify_all()
         # killed workers may have sealed result shm without delivering
         # TASK_DONE — reap locally (the same hygiene the worker-lost
         # path applies; shm outlives processes until reboot otherwise)

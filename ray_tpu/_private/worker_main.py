@@ -343,6 +343,7 @@ class WorkerExecutor:
         self._cur_env_undo: dict = {"env": {}, "cwd": None, "paths": []}
         self._pending_cancels: set[str] = set()
         self._cancel_lock = threading.Lock()
+        self._grant_applied = False     # chips bound once, see handle()
         self._pool = ThreadPoolExecutor(max_workers=1,
                                         thread_name_prefix="rtpu-exec")
         self._actor: Any = None
@@ -488,6 +489,14 @@ class WorkerExecutor:
     # ---- message entry (called on reader thread) ----
     def handle(self, conn: protocol.Connection, msg: dict) -> None:
         mtype = msg["type"]
+        if mtype in (protocol.TASK, protocol.ACTOR_CREATE) \
+                and not self._grant_applied:
+            # here the process becomes its actor or starts its first
+            # task: bind it to the chips the scheduler granted (none
+            # pins JAX to the CPU) before any user code can touch JAX
+            from ray_tpu._private.accelerators import apply_chip_grant
+            apply_chip_grant(msg.get("tpu_chips") or ())
+            self._grant_applied = True
         if mtype == protocol.TASK:
             spec = msg["spec"]
             self._stamp_recv(spec, msg)

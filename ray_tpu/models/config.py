@@ -100,6 +100,19 @@ def tiny(vocab_size: int = 256) -> TransformerConfig:
         dtype="float32", param_dtype="float32")
 
 
+def bench_1b() -> TransformerConfig:
+    """The ~1B dense decoder `bench.py` and `chip_smoke.py` run, sized
+    for one 16 GB v5e chip: 953M parameters, bf16 weights and bf16 Adam
+    state, no remat at batch 2 x 2048, 1024-blocks for the flash kernels
+    (the `tools/bench_sweep.py` choice). Invented widths: Llama-shaped,
+    but no published model has them."""
+    return TransformerConfig(
+        vocab_size=32000, d_model=2048, n_layers=16, n_heads=16,
+        n_kv_heads=16, d_ff=5632, max_seq_len=2048, remat=False,
+        dtype="bfloat16", param_dtype="bfloat16", loss_chunk=0,
+        attn_block_q=1024, attn_block_k=1024)
+
+
 def llama2_7b() -> TransformerConfig:
     return TransformerConfig(
         vocab_size=32000, d_model=4096, n_layers=32, n_heads=32,
@@ -120,6 +133,7 @@ def llama3_8b() -> TransformerConfig:
 
 PRESETS = {
     "tiny": tiny,
+    "bench-1b": bench_1b,
     "llama2-7b": llama2_7b,
     "llama2-13b": llama2_13b,
     "llama3-8b": llama3_8b,

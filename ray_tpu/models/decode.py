@@ -32,25 +32,42 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin
 
 Params = Dict[str, Any]
 KVCache = Dict[str, jax.Array]
 
 
+def cache_sharding(config, mesh):
+    """How the paged cache lies on a mesh: kv heads over `tp`, the rest
+    whole — each tp shard holds its own heads' pages, which is what
+    `cache_page_bytes(tp_shards=...)` charges for."""
+    tp = mesh.shape.get("tp", 1)
+    if config.kv_heads % tp:
+        raise ValueError(
+            f"the KV cache shards its {config.kv_heads} kv heads over "
+            f"the mesh's tp axis of {tp}, which does not divide them; "
+            f"use a tp that divides kv_heads")
+    return NamedSharding(mesh, P(None, None, None,
+                                 "tp" if tp > 1 else None, None))
+
+
 def init_paged_cache(config, num_pages: int, page_size: int,
-                     dtype=None) -> KVCache:
-    """Zeroed paged cache: k/v each (layers, pages, page, kv, hd)."""
+                     dtype=None, mesh=None) -> KVCache:
+    """Zeroed paged cache: k/v each (layers, pages, page, kv, hd),
+    created sharded as `cache_sharding` says when given a mesh."""
     if config.moe_num_experts:
         raise NotImplementedError(
             "paged decoding supports dense FFN layers only")
     dt = dtype or config.activation_dtype
     shape = (config.n_layers, num_pages, page_size,
              config.kv_heads, config.head_dim)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    sharding = cache_sharding(config, mesh) if mesh is not None else None
+    zeros = jax.jit(lambda: jnp.zeros(shape, dt), out_shardings=sharding)
+    return {"k": zeros(), "v": zeros()}
 
 
 def cache_page_bytes(config, page_size: int, tp_shards: int = 1,
@@ -74,9 +91,10 @@ def _qkv(config, layer: Params, h):
     return q, k, v
 
 
-def _mlp(config, layer: Params, x):
+def _mlp(model, layer: Params, x):
+    config = model.config
     ad = config.activation_dtype
-    h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
+    h = model._norm(x, layer["mlp_norm"])
     gate = jax.nn.silu(h @ layer["gate"].astype(ad))
     up = h @ layer["up"].astype(ad)
     return x + (gate * up) @ layer["down"].astype(ad)
@@ -106,22 +124,23 @@ def prefill(model, params: Params, tokens: jax.Array, true_len,
     cos, sin = rope
 
     def body(x, layer):
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+        h = model._norm(x, layer["attn_norm"])
         q, k, v = _qkv(c, layer, h)
         q = apply_rope_cached(q, cos, sin)
         k = apply_rope_cached(k, cos, sin)
         qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         attn = flash_attention(qt, kt, vt, causal=True,
                                block_q=c.attn_block_q,
-                               block_k=c.attn_block_k)
+                               block_k=c.attn_block_k,
+                               mesh=model.kernel_mesh)
         attn = attn.transpose(0, 2, 1, 3).reshape(
             1, s, c.n_heads * c.head_dim)
         x = x + attn @ layer["wo"].astype(ad)
-        x = _mlp(c, layer, x)
+        x = _mlp(model, layer, x)
         return x, (k[0], v[0])                     # (s, kv, hd) each
 
     x, (ks, vs) = lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
+    x = model._norm(x, params["final_norm"])
     last = jnp.take(x[0], true_len - 1, axis=0)
     logits = (last @ model._head(params).astype(ad)).astype(jnp.float32)
 
@@ -182,7 +201,7 @@ def decode_step(model, params: Params, cache: KVCache,
     layers = params["layers"]
     for i in range(c.n_layers):
         layer = jax.tree_util.tree_map(lambda a: a[i], layers)
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+        h = model._norm(x, layer["attn_norm"])
         q, k, v = _qkv(c, layer, h)                  # (B, 1, heads, hd)
         q = apply_rope_cached(q, cos, sin)
         k = apply_rope_cached(k, cos, sin)
@@ -203,8 +222,8 @@ def decode_step(model, params: Params, cache: KVCache,
                          vals.astype(jnp.float32)).astype(ad)
         out = out.reshape(B, 1, c.n_heads * hd)
         x = x + out @ layer["wo"].astype(ad)
-        x = _mlp(c, layer, x)
+        x = _mlp(model, layer, x)
 
-    x = rms_norm(x, params["final_norm"], c.norm_eps)
+    x = model._norm(x, params["final_norm"])
     logits = (x[:, 0] @ model._head(params).astype(ad))
     return logits.astype(jnp.float32), {"k": ck, "v": cv}

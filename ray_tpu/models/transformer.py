@@ -20,6 +20,8 @@ from jax.sharding import Mesh
 
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.dispatch import (compute_platform, kernel_mesh,
+                                  mesh_platform, on_tpu, platform_pinned)
 from ray_tpu.ops.losses import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention_sharded
@@ -46,14 +48,18 @@ class Transformer:
                  mesh: Optional[Mesh] = None):
         self.config = config
         self.mesh = mesh
+        # what the Pallas kernels are shard-mapped over (None on one
+        # device: nothing to partition)
+        self.kernel_mesh = kernel_mesh(mesh)
 
     def _platform(self):
         """Platform the forward will actually run on: the mesh's devices
         when bound to a mesh (may differ from the default backend — e.g.
-        a virtual CPU mesh on a TPU host), else the default backend."""
-        if self.mesh is None:
+        a virtual CPU mesh on a TPU host), else the default backend. A
+        caller that already pinned a platform (an ahead-of-time
+        lowering for another one) keeps its pin."""
+        if self.mesh is None or platform_pinned():
             return None
-        from ray_tpu.ops.dispatch import mesh_platform
         return mesh_platform(self.mesh)
 
     # ------------------------------------------------------------ init
@@ -135,22 +141,26 @@ class Transformer:
 
     # --------------------------------------------------------- forward
     def _attention(self, q, k, v):
+        """Causal attention for one layer. `remat_policy="save_attn"`
+        exists to spare the backward a second run of the forward
+        kernel; off TPU there is no kernel to spare (attention is the
+        einsum reference), so there it is the same as "full"."""
         c = self.config
         if (c.use_ring_attention and self.mesh is not None
                 and self.mesh.shape.get("sp", 1) > 1):
             return ring_attention_sharded(q, k, v, self.mesh, causal=True)
-        if c.remat and c.remat_policy == "save_attn":
+        if c.remat and c.remat_policy == "save_attn" and on_tpu():
             from ray_tpu.ops.attention import flash_attention_saveable
-            from ray_tpu.ops.dispatch import on_tpu
-            if on_tpu():
-                return flash_attention_saveable(
-                    q, k, v, causal=True, block_q=c.attn_block_q,
-                    block_k=c.attn_block_k)
-            # off-TPU the einsum fallback has no kernel to spare; plain
-            # path keeps CPU tests exercising the same math.
+            return flash_attention_saveable(
+                q, k, v, causal=True, block_q=c.attn_block_q,
+                block_k=c.attn_block_k, mesh=self.kernel_mesh)
         return flash_attention(q, k, v, causal=True,
                                block_q=c.attn_block_q,
-                               block_k=c.attn_block_k)
+                               block_k=c.attn_block_k,
+                               mesh=self.kernel_mesh)
+
+    def _norm(self, x, w):
+        return rms_norm(x, w, self.config.norm_eps, self.kernel_mesh)
 
     def _constrain(self, x, axes):
         if self.mesh is None:
@@ -181,7 +191,7 @@ class Transformer:
         b, s, e = x.shape
         hd = c.head_dim
 
-        h = rms_norm(x, layer["attn_norm"], c.norm_eps)
+        h = self._norm(x, layer["attn_norm"])
         q = (h @ layer["wq"].astype(ad)).reshape(b, s, c.n_heads, hd)
         k = (h @ layer["wk"].astype(ad)).reshape(b, s, c.kv_heads, hd)
         v = (h @ layer["wv"].astype(ad)).reshape(b, s, c.kv_heads, hd)
@@ -198,7 +208,7 @@ class Transformer:
         x = x + attn @ layer["wo"].astype(ad)
         x = self._constrain(x, ("batch", "seq", "act_embed"))
 
-        h = rms_norm(x, layer["mlp_norm"], c.norm_eps)
+        h = self._norm(x, layer["mlp_norm"])
         if c.moe_num_experts:
             from ray_tpu.models.moe import moe_ffn
             y, aux = moe_ffn(
@@ -225,7 +235,6 @@ class Transformer:
     def hidden_and_aux(self, params: Params, tokens: jax.Array,
                        positions: Optional[jax.Array] = None):
         """(hidden states, summed MoE load-balance loss across layers)."""
-        from ray_tpu.ops.dispatch import compute_platform
         with compute_platform(self._platform()):
             return self._hidden(params, tokens, positions)
 
@@ -287,7 +296,7 @@ class Transformer:
 
             x = pipeline_apply(self.mesh, stage, params["layers"], x,
                                c.pipeline_microbatches, consts=rope)
-            return (rms_norm(x, params["final_norm"], c.norm_eps),
+            return (self._norm(x, params["final_norm"]),
                     jnp.float32(0.0))
 
         def body(carry, layer):
@@ -298,7 +307,7 @@ class Transformer:
         (x, moe_aux), _ = lax.scan(_checkpointed(body),
                                    (x, jnp.float32(0.0)),
                                    params["layers"])
-        return rms_norm(x, params["final_norm"], c.norm_eps), moe_aux
+        return self._norm(x, params["final_norm"]), moe_aux
 
     def _head(self, params: Params) -> jax.Array:
         return (params["embed"].T if self.config.tie_embeddings

@@ -17,9 +17,16 @@ fewer heads (num_kv_heads must divide num_heads) — the kernel maps query
 head h to kv head h // (num_heads // num_kv_heads) in the BlockSpec
 index map, no materialised repeat.
 
-On non-TPU backends the public `flash_attention` falls back to the
-reference einsum implementation; the kernel itself still runs anywhere
-via the Pallas interpreter (used by tests).
+The public `flash_attention` compiles the kernels whenever the target
+platform is a TPU (`ops.dispatch.on_tpu`), never the interpreter; off
+TPU it is the reference einsum, and tests reach the kernels through
+the Pallas interpreter with `flash_attention_kernel`.
+
+GSPMD cannot partition a Mosaic kernel, so every entry point takes the
+`mesh` the computation is sharded over and runs the kernel calls —
+`_flash_fwd` and `_flash_bwd_pallas`, not the AD wrappers around them,
+so a remat policy still sees the named residuals — inside
+`jax.shard_map` with the specs of `ops.dispatch.attention_specs`.
 """
 from __future__ import annotations
 
@@ -32,6 +39,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.dispatch import attention_specs, on_tpu, shard_kernel
 
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -129,12 +139,25 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                                                (8, lse.shape[0]))
 
 
-def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _stat_spec(spec_q: P) -> P:
+    """Spec of the per-row statistic (b, h, s) beside a (b, h, s, d)."""
+    return P(*spec_q[:3])
+
+
+def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+               mesh=None):
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]
     if h % kvh:
         raise ValueError(
             f"num_heads ({h}) must be a multiple of num_kv_heads ({kvh})")
+    if mesh is not None:
+        spec_q, spec_kv, _ = attention_specs(mesh, h, kvh)
+        return shard_kernel(
+            lambda q_, k_, v_: _flash_fwd(q_, k_, v_, causal, sm_scale,
+                                          block_q, block_k, interpret),
+            mesh, (spec_q, spec_kv, spec_kv),
+            (spec_q, _stat_spec(spec_q)))(q, k, v)
     group = h // kvh
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -301,10 +324,26 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
-                      block_q, block_k, interpret):
+                      block_q, block_k, interpret, mesh=None):
     """Full Pallas backward: returns (dq, dk, dv)."""
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]
+    if mesh is not None:
+        spec_q, spec_kv, _ = attention_specs(mesh, h, kvh)
+        stat = _stat_spec(spec_q)
+
+        def local(*a):
+            dq, dk, dv = _flash_bwd_pallas(*a, causal, sm_scale, block_q,
+                                           block_k, interpret)
+            if spec_kv[1] != spec_q[1]:
+                # MQA: the one kv head is replicated over tp and each
+                # device saw only its own q heads
+                dk, dv = lax.psum((dk, dv), spec_q[1])
+            return dq, dk, dv
+
+        return shard_kernel(
+            local, mesh, (spec_q, spec_kv, spec_kv, spec_q, stat, spec_q),
+            (spec_q, spec_kv, spec_kv))(q, k, v, o, lse, do)
     group = h // kvh
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
@@ -456,61 +495,77 @@ def _flash_bwd_xla(q, k, v, o, lse, do, causal, sm_scale, block_k):
 
 
 # ----------------------------------------------------------- public API
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret, mesh):
     """Returns (out, lse); lse has stop-gradient semantics (its cotangent
     is ignored by the VJP — it is an auxiliary statistic, not a loss
     term)."""
     return _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                      interpret)
+                      interpret, mesh)
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_fwd_rule(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                    mesh):
     out, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                          interpret)
+                          interpret, mesh)
     return (out, lse), (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, mesh,
+                    res, g):
     do, _g_lse = g  # lse cotangent dropped by design (see _flash docstring)
     q, k, v, out, lse = res
     return _flash_bwd_pallas(q, k, v, out, lse, do, causal, sm_scale,
-                             block_q, block_k, interpret)
+                             block_q, block_k, interpret, mesh)
 
 
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def _repeat_kv_for(mesh, q, k, v):
+    """GQA whose kv heads do not split over the mesh's tp axis: repeat
+    K/V to q's head count (see attention_specs). Done out here, in
+    differentiable jnp, so the kernels never see the case."""
+    if mesh is not None and attention_specs(mesh, q.shape[1],
+                                            k.shape[1])[2]:
+        rep = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    return k, v
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    return_lse: bool = False):
-    """Dispatching entry point: Pallas on TPU, reference elsewhere.
+                    return_lse: bool = False, mesh=None):
+    """Dispatching entry point: the compiled Pallas kernels when the
+    target platform is a TPU, the einsum reference elsewhere.
 
-    Shapes: q (b, h, s, d); k/v (b, kvh, s, d), kvh | h.
+    Shapes: q (b, h, s, d); k/v (b, kvh, s, d), kvh | h. `mesh`: the
+    mesh of more than one device the operands are sharded over
+    (`ops.dispatch.kernel_mesh`), or None.
     """
-    d = q.shape[-1]
+    if return_lse or on_tpu():
+        out, lse = _flash_kernel(q, k, v, causal, sm_scale, block_q,
+                                 block_k, mesh)
+        return (out, lse) if return_lse else out
+    return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
+
+
+def _flash_kernel(q, k, v, causal, sm_scale, block_q, block_k, mesh):
     if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(d)
-    from ray_tpu.ops.dispatch import on_tpu as _on_tpu
-    on_tpu = _on_tpu()
-    if return_lse:
-        return _flash(q, k, v, causal, sm_scale, block_q, block_k,
-                      not on_tpu)
-    if not on_tpu:
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale)
-    return _flash(q, k, v, causal, sm_scale, block_q, block_k, False)[0]
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    k, v = _repeat_kv_for(mesh, q, k, v)
+    # interpret only where there is no TPU to compile for
+    return _flash(q, k, v, causal, sm_scale, block_q, block_k,
+                  not on_tpu(), mesh)
 
 
 def flash_attention_kernel(q, k, v, causal=True, sm_scale=None,
-                           block_q=128, block_k=128):
+                           block_q=128, block_k=128, mesh=None):
     """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
-    from ray_tpu.ops.dispatch import on_tpu as _on_tpu
-    if sm_scale is None:
-        sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash(q, k, v, causal, sm_scale, block_q, block_k,
-                  not _on_tpu())[0]
+    return _flash_kernel(q, k, v, causal, sm_scale, block_q, block_k,
+                         mesh)[0]
 
 
 # --------------------------------------- remat-saveable attention path
@@ -540,21 +595,22 @@ def attn_remat_policy():
         *ATTN_RESIDUAL_NAMES)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
 def _attn_from_saved(q, k, v, out, lse, causal, sm_scale, block_q,
-                     block_k, interpret):
+                     block_k, interpret, mesh):
     return out
 
 
 def _afs_fwd(q, k, v, out, lse, causal, sm_scale, block_q, block_k,
-             interpret):
+             interpret, mesh):
     return out, (q, k, v, out, lse)
 
 
-def _afs_bwd(causal, sm_scale, block_q, block_k, interpret, res, do):
+def _afs_bwd(causal, sm_scale, block_q, block_k, interpret, mesh, res, do):
     q, k, v, out, lse = res
     dq, dk, dv = _flash_bwd_pallas(q, k, v, out, lse, do, causal,
-                                   sm_scale, block_q, block_k, interpret)
+                                   sm_scale, block_q, block_k, interpret,
+                                   mesh)
     # out/lse arrive through stop_gradient: their cotangents are dropped
     # symbolically, these zeros never materialise.
     return dq, dk, dv, jnp.zeros_like(out), jnp.zeros_like(lse)
@@ -567,24 +623,23 @@ def flash_attention_saveable(q: jax.Array, k: jax.Array, v: jax.Array,
                              causal: bool = True,
                              sm_scale: Optional[float] = None,
                              block_q: int = 128, block_k: int = 128,
-                             interpret: Optional[bool] = None) -> jax.Array:
+                             mesh=None) -> jax.Array:
     """Flash attention whose residuals survive `jax.checkpoint` when the
     wrapping policy is `attn_remat_policy()` (see block comment above).
-    Semantically identical to `flash_attention`; use inside rematted
-    layer bodies."""
+    Semantically identical to `flash_attention`'s kernel path; use
+    inside rematted layer bodies."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        from ray_tpu.ops.dispatch import on_tpu as _on_tpu
-        interpret = not _on_tpu()
+    interpret = not on_tpu()
+    k, v = _repeat_kv_for(mesh, q, k, v)
     from jax.ad_checkpoint import checkpoint_name
     # Run the forward kernel on gradient-stopped inputs: pallas_call has
     # no JVP rule, and the only differentiable route is _attn_from_saved.
     out, lse = _flash_fwd(lax.stop_gradient(q), lax.stop_gradient(k),
                           lax.stop_gradient(v), causal, sm_scale,
-                          block_q, block_k, interpret)
+                          block_q, block_k, interpret, mesh)
     out = checkpoint_name(out, "attn_out")
     lse = checkpoint_name(lse, "attn_lse")
     return _attn_from_saved(q, k, v, lax.stop_gradient(out),
                             lax.stop_gradient(lse), causal, sm_scale,
-                            block_q, block_k, interpret)
+                            block_q, block_k, interpret, mesh)

@@ -4,6 +4,11 @@ Memory-bound ops: the win over XLA's default lowering is avoiding the
 extra HBM round-trip between the moment computation and the scale apply.
 Backward is left to XLA via a reference-recompute custom_vjp — the
 recompute is VMEM-resident and fuses into the surrounding backward.
+
+The forward is always the kernel: compiled when the target platform is
+a TPU, interpreted elsewhere. Given the `mesh` the activation is
+sharded over it runs inside `jax.shard_map`, because GSPMD cannot
+partition a Mosaic kernel.
 """
 from __future__ import annotations
 
@@ -12,13 +17,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
 
-_LANE = 128
+from ray_tpu.ops.dispatch import activation_spec, on_tpu, shard_kernel
 
-
-def _interpret() -> bool:
-    from ray_tpu.ops.dispatch import on_tpu
-    return not on_tpu()
+_BLOCK_ROWS = 256
 
 
 # ---------------------------------------------------------------- rmsnorm
@@ -38,9 +41,12 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
     o_ref[:] = (y * (1.0 + w_ref[:].astype(jnp.float32))).astype(o_ref.dtype)
 
 
-def _rms_fwd_pallas(x2d: jax.Array, w: jax.Array, eps: float,
-                    block_rows: int) -> jax.Array:
+def _rms_fwd_pallas(x2d: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     rows, d = x2d.shape
+    # Rows are independent, so a ragged last block is harmless: what it
+    # reads past the end it also writes past the end, and that is
+    # dropped.
+    block_rows = min(rows, _BLOCK_ROWS)
     grid = (pl.cdiv(rows, block_rows),)
     return pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
@@ -51,34 +57,34 @@ def _rms_fwd_pallas(x2d: jax.Array, w: jax.Array, eps: float,
             pl.BlockSpec((d,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        interpret=_interpret(),
+        interpret=not on_tpu(),
     )(x2d, w)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6,
+             mesh=None) -> jax.Array:
     """y = x * rsqrt(mean(x^2) + eps) * (1 + w), fused.
 
     Follows the (1 + w) convention (gemma/llama3 style) so a zero-init
     scale is the identity. Accepts any leading shape; normalises the
-    last axis.
+    last axis. `mesh`: the mesh of more than one device a (batch, seq,
+    embed) activation is sharded over (`ops.dispatch.kernel_mesh`), or
+    None.
     """
-    lead = x.shape[:-1]
+    if mesh is not None:
+        spec = activation_spec(mesh)
+        return shard_kernel(lambda x_, w_: rms_norm(x_, w_, eps), mesh,
+                            (spec, P()), spec)(x, w)
     d = x.shape[-1]
-    x2d = x.reshape(-1, d)
-    rows = x2d.shape[0]
-    block = min(rows, 256)
-    if rows % block:
-        return rms_norm_reference(x, w, eps)
-    out = _rms_fwd_pallas(x2d, w, eps, block)
-    return out.reshape(*lead, d)
+    return _rms_fwd_pallas(x.reshape(-1, d), w, eps).reshape(x.shape)
 
 
-def _rms_fwd_rule(x, w, eps):
-    return rms_norm(x, w, eps), (x, w)
+def _rms_fwd_rule(x, w, eps, mesh):
+    return rms_norm(x, w, eps, mesh), (x, w)
 
 
-def _rms_bwd_rule(eps, res, g):
+def _rms_bwd_rule(eps, mesh, res, g):
     x, w = res
     _, vjp = jax.vjp(lambda x_, w_: rms_norm_reference(x_, w_, eps), x, w)
     return vjp(g)

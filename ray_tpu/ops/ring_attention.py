@@ -19,9 +19,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
 from ray_tpu.ops.attention import DEFAULT_MASK_VALUE, flash_attention
+from ray_tpu.ops.dispatch import attention_specs, kernel_mesh
 
 NEG_INF = -jnp.inf
 
@@ -133,36 +134,14 @@ def ring_attention_sharded(q, k, v, mesh: Mesh, causal: bool = True,
     ``Mesh(devs, ("sp",))``) shards only the sequence axis.
     """
     if mesh.shape.get(axis, 1) == 1:
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale)
-    # Only reference axes that exist in the mesh AND are nontrivial —
-    # a spec naming an absent axis raises inside shard_map.
-    batch_axes = tuple(a for a in ("dp", "fsdp")
-                       if a != axis and mesh.shape.get(a, 1) > 1)
-    head_axis = "tp" if (axis != "tp"
-                         and mesh.shape.get("tp", 1) > 1) else None
-    tp = mesh.shape[head_axis] if head_axis else 1
-    h, kvh = q.shape[1], k.shape[1]
-    spec_q = P(batch_axes or None, head_axis, axis, None)
-    if kvh % tp == 0:
-        # kv heads shard over tp alongside q heads.
-        spec_kv = spec_q
-    elif kvh == 1:
-        # MQA: the single kv head replicates over tp; every query head
-        # maps to it, so the local-shape grouping in _chunk_attention is
-        # trivially correct. (General kvh>1 replication is NOT safe:
-        # spec_q gives each tp device a contiguous global head block,
-        # and the chunk kernel's local grouping would misalign q groups
-        # to kv heads — so any other non-divisible case falls through to
-        # the explicit repeat below.)
-        spec_kv = P(batch_axes or None, None, axis, None)
-    else:
-        # Last resort: materialise the GQA repeat so K/V carry Q's head
-        # spec. Costs n_heads/kv_heads x in K/V memory and ring-transfer
-        # volume — prefer kv_heads % tp == 0 configs on real workloads.
-        rep = h // kvh
+        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                               mesh=kernel_mesh(mesh))
+    spec_q, spec_kv, repeat_kv = attention_specs(
+        mesh, q.shape[1], k.shape[1], seq_axis=axis)
+    if repeat_kv:
+        rep = q.shape[1] // k.shape[1]
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
-        spec_kv = spec_q
     fn = jax.shard_map(
         lambda q_, k_, v_: ring_attention(q_, k_, v_, axis=axis,
                                           causal=causal, sm_scale=sm_scale),

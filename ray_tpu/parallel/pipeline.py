@@ -25,23 +25,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def _shard_map(f, *, mesh, axis_names, in_specs, out_specs,
-               check_vma=False):
-    """jax.shard_map compat: the stable partial-manual API when this
-    jax has it, else jax.experimental.shard_map (axis_names -> its
-    `auto` complement, check_vma -> check_rep). Keeps the pipeline
-    schedules runnable across the jax versions the fleet actually
-    ships."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=axis_names,
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(mesh.axis_names) - set(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma, auto=auto)
-
-
 def partition_layers(n_layers: int, n_stages: int) -> list:
     """Canonical stage partition: ``[(start, count), ...]`` per stage,
     with the remainder layers assigned to the LAST stage (it already
@@ -200,7 +183,7 @@ def pipeline_apply(mesh: Mesh,
     stage_call = _make_stage_call(stage_fn, layer_fn, counts)
 
     @functools.partial(
-        _shard_map, mesh=mesh, axis_names={"pp"},
+        jax.shard_map, mesh=mesh, axis_names={"pp"},
         in_specs=(jax.tree_util.tree_map(lambda _: P("pp"), stacked),
                   P(), jax.tree_util.tree_map(lambda _: P(),
                                               tuple(consts))),
@@ -299,7 +282,7 @@ def pipeline_grads_1f1b(mesh: Mesh,
     A = min(M, 2 * (S - 1) + 1)       # activation ring slots per stage
 
     @functools.partial(
-        _shard_map, mesh=mesh, axis_names={"pp"},
+        jax.shard_map, mesh=mesh, axis_names={"pp"},
         in_specs=(jax.tree_util.tree_map(lambda _: P("pp"), stacked),
                   P(), P(),
                   jax.tree_util.tree_map(lambda _: P(), tuple(consts))),

@@ -184,8 +184,6 @@ class QEnvRunner:
     time-major on-policy runner."""
 
     def __init__(self, config: "DQNConfig", worker_index: int = 0):
-        from ray_tpu._private.jaxenv import pin_platform_from_env
-        pin_platform_from_env()
         import gymnasium as gym
         self.config = config
         seed = config.seed + 1000 * worker_index

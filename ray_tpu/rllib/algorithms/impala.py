@@ -131,8 +131,6 @@ class IMPALALearner:
     N_REPLICATED_ARGS = 2
 
     def __init__(self, config: IMPALALearnerConfig):
-        from ray_tpu._private.jaxenv import pin_platform_from_env
-        pin_platform_from_env()
         self.config = config
         self.module = ActorCriticModule(
             config.obs_dim, config.num_actions, tuple(config.hidden))

@@ -97,8 +97,6 @@ class SACEnvRunner:
     are squashed-Gaussian samples scaled to the env bounds."""
 
     def __init__(self, config: "SACConfig", worker_index: int = 0):
-        from ray_tpu._private.jaxenv import pin_platform_from_env
-        pin_platform_from_env()
         import gymnasium as gym
         self.config = config
         seed = config.seed + 1000 * worker_index

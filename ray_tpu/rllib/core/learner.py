@@ -77,8 +77,6 @@ class PPOLearner:
     def __init__(self, config: PPOLearnerConfig,
                  module: Optional[ActorCriticModule] = None,
                  mesh=None):
-        from ray_tpu._private.jaxenv import pin_platform_from_env
-        pin_platform_from_env()
         self.config = config
         self.module = module or ActorCriticModule(
             config.obs_dim, config.num_actions, tuple(config.hidden),

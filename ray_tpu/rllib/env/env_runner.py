@@ -51,8 +51,6 @@ class SingleAgentEnvRunner:
         return obs.astype(np.float32)
 
     def __init__(self, config: EnvRunnerConfig, worker_index: int = 0):
-        from ray_tpu._private.jaxenv import pin_platform_from_env
-        pin_platform_from_env()
         import gymnasium as gym
 
         self.config = config

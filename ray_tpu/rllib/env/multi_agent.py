@@ -74,8 +74,6 @@ class MultiAgentEnvRunner:
 
     def __init__(self, config: MultiAgentEnvRunnerConfig,
                  worker_index: int = 0):
-        from ray_tpu._private.jaxenv import pin_platform_from_env
-        pin_platform_from_env()
         import jax
         self.config = config
         seed = config.seed + 1000 * worker_index

@@ -56,8 +56,6 @@ class SebulbaEnvRunner:
 
     def __init__(self, config: SebulbaRunnerConfig, runner_index: int,
                  inference: Sequence[Any]):
-        from ray_tpu._private.jaxenv import pin_platform_from_env
-        pin_platform_from_env()
         import gymnasium as gym
         from ray_tpu.experimental.wire_channel import serve_channel
 

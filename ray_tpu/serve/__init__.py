@@ -151,10 +151,7 @@ class _Replica:
             init_args = _resolve_bound(tuple(init_args), controller_name)
             init_kwargs = _resolve_bound(dict(init_kwargs),
                                          controller_name)
-        if isinstance(cls_or_fn, type):
-            self._obj = cls_or_fn(*init_args, **init_kwargs)
-        else:
-            self._obj = cls_or_fn       # function deployment
+        self._obj = None
         self._ongoing = 0
         self._total = 0
         self._lock = threading.Lock()
@@ -171,6 +168,14 @@ class _Replica:
                 args=(deployment, replica_id, controller_name,
                       report_period_s),
                 daemon=True, name="replica-report").start()
+        # built after the report thread is up: loading a model onto a
+        # chip outlasts the controller's start-up grace, and a replica
+        # killed for silence while it loads is replaced by one that
+        # will be too
+        if isinstance(cls_or_fn, type):
+            self._obj = cls_or_fn(*init_args, **init_kwargs)
+        else:
+            self._obj = cls_or_fn       # function deployment
 
     def _report_loop(self, deployment: str, rid: str,
                      controller_name: str, period: float) -> None:
@@ -180,6 +185,14 @@ class _Replica:
             try:
                 if controller is None:
                     controller = ray_tpu.get_actor(controller_name)
+                health = getattr(self._obj, "check_health", None)
+                if health is not None:
+                    try:
+                        health()
+                    except Exception:
+                        # silence is how a replica says it is unwell:
+                        # the controller kills and replaces it
+                        return
                 with self._lock:
                     self._sweep_streams()
                     ongoing = self._ongoing + len(self._streams)
@@ -497,12 +510,21 @@ class ServeController:
 
     # ------------------------------------------------------- reconcile
     def _reconcile_loop(self) -> None:
+        stood_still = False
         while self._running:
-            try:
-                self._reconcile_once()
-            except BaseException:
-                pass
+            # A host that did not run for a while (replicas opening
+            # their TPUs freeze a microVM for seconds at a time) read no
+            # replica reports either: judging liveness now would kill
+            # every replica for this process's own silence. See
+            # _private/cluster.py:_monitor_loop.
+            if not stood_still:
+                try:
+                    self._reconcile_once()
+                except BaseException:
+                    pass
+            t = time.monotonic()
             time.sleep(1.0)
+            stood_still = time.monotonic() - t > 2.0
 
     def _reconcile_once(self) -> None:
         import cloudpickle

@@ -14,10 +14,15 @@ Quickstart (byte-level "tokenizer": tiny preset vocab is 256)::
     from ray_tpu.serve import llm
 
     ray_tpu.init(num_cpus=4)
-    handle = llm.serve_llm(num_replicas=2, mesh={"dp": 1, "tp": 2})
+    handle = llm.serve_llm(num_replicas=2)
     stream = handle.generate(list(b"the pod "), max_tokens=32)
     for token in stream:          # arrives as the engine decodes
         print(token)
+
+A replica runs on the chips it is granted and on the CPU otherwise: on
+a TPU pass ``ray_actor_options={"num_tpus": n}`` and keep replicas x n
+within the host's chips; ``mesh={"dp": 1, "tp": n}`` then splits the
+weights and the KV cache over them.
 
 `RAY_TPU_LLM_STREAM=0` falls back to polled `next_tokens` actor calls
 (the legacy chunk path's semantics, with server-side parking).
@@ -53,6 +58,10 @@ def serve_llm(name: str = "llm", model: Any = "tiny",
     pre-seeds all nodes), an ObjectRef, or None (each replica inits
     identically from `seed` — fine for tests, wasteful for real
     weights).
+
+    `ray_actor_options={"num_tpus": n}` gives each replica n chips; a
+    replica without a grant runs on the CPU (`engine_stats()` says
+    where each runs). `mesh` must fit the replica's devices.
     """
     import ray_tpu
     from ray_tpu import serve

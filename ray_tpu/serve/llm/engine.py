@@ -23,8 +23,12 @@ partition can never duplicate or interleave tokens at the consumer.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
+import os
 import threading
 import time
+import traceback
 import uuid
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
@@ -34,6 +38,7 @@ from ray_tpu.serve.llm.kv_cache import PageAllocator, pages_needed
 FINISH_STOP = "stop"
 FINISH_LENGTH = "length"
 FINISH_DRAINED = "drained"
+FINISH_ERROR = "error"
 
 
 def _bucket(n: int, lo: int = 16, hi: int = 1 << 30) -> int:
@@ -96,7 +101,12 @@ class EngineCore:
         self.model = Transformer(config, mesh=mesh)
         self.params = params
         self._cache = _dec.init_paged_cache(config, self.num_pages,
-                                            self.page_size)
+                                            self.page_size, mesh=mesh)
+        # on a mesh every step hands the cache back as it lay, whatever
+        # the partitioner would have preferred for one call
+        self._jit = jax.jit if mesh is None else functools.partial(
+            jax.jit, out_shardings=(None, jax.tree.map(
+                lambda a: a.sharding, self._cache)))
         self._dec = _dec
         self._waiting: deque = deque()
         self._running: List[_Seq] = []
@@ -110,7 +120,15 @@ class EngineCore:
             return _dec.decode_step(self.model, params, cache, tokens,
                                     positions, pts, active,
                                     self.page_size)
-        self._decode_fn = jax.jit(_step)
+        self._decode_fn = self._jit(_step)
+        self._devices = (list(mesh.devices.flat) if mesh is not None
+                         else jax.devices()[:1])
+        chips = os.environ.get("TPU_VISIBLE_CHIPS", "")
+        self._device_info = {
+            "platform": self._devices[0].platform,
+            "device_kind": self._devices[0].device_kind,
+            "device_ids": [d.id for d in self._devices],
+            "chips": [int(c) for c in chips.split(",") if c]}
         self.counters = {"admitted": 0, "evictions": 0, "finished": 0,
                          "tokens": 0, "steps": 0}
 
@@ -192,7 +210,7 @@ class EngineCore:
                 return self._dec.prefill(self.model, params, tokens,
                                          true_len, page_table, cache,
                                          self.page_size)
-            fn = self._jax.jit(_pre)
+            fn = self._jit(_pre)
             self._prefill_fns[s_pad] = fn
         return fn
 
@@ -322,6 +340,14 @@ class EngineCore:
         return sum(s.remaining for s in self._running) \
             + sum(s.remaining for s in self._waiting)
 
+    def device_stats(self) -> dict:
+        """Where this engine runs, as JAX reports it, plus the chips the
+        scheduler granted the process (device ids are per process: four
+        one-chip replicas all hold device 0, each of another chip)."""
+        return {**self._device_info, "bytes_in_use": [
+            (d.memory_stats() or {}).get("bytes_in_use")
+            for d in self._devices]}
+
     def stats(self) -> dict:
         return {"waiting": len(self._waiting),
                 "running": len(self._running),
@@ -353,6 +379,8 @@ class LLMEngine:
         from ray_tpu._private.config import CONFIG
         from ray_tpu.models import Transformer
         from ray_tpu.models.config import PRESETS, TransformerConfig
+        from ray_tpu.util.compile_cache import use_compile_cache
+        use_compile_cache()
         if isinstance(model, str):
             config = PRESETS[model]()
         elif isinstance(model, dict):
@@ -361,8 +389,18 @@ class LLMEngine:
             config = model
         built_mesh = None
         if mesh:
-            from ray_tpu.parallel.mesh import prepare_mesh
-            built_mesh = prepare_mesh(**mesh)
+            # the replica's own mesh, over as many of its devices as the
+            # axes name (a replica has no data axis unless asked for one)
+            from ray_tpu.parallel.mesh import AXIS_ORDER, MeshSpec
+            spec = MeshSpec(**{"dp": 1, **mesh})
+            sizes = [getattr(spec, a) for a in AXIS_ORDER]
+            n = len(jax.devices()) if -1 in sizes else math.prod(sizes)
+            if n > len(jax.devices()):
+                raise ValueError(
+                    f"mesh {mesh} needs {n} devices, this replica has "
+                    f"{len(jax.devices())}: grant it the chips "
+                    f"(ray_actor_options={{'num_tpus': {n}}})")
+            built_mesh = spec.build(jax.devices()[:n])
         page_size = int(page_size or CONFIG.llm_page_size)
         max_batch = int(max_batch or CONFIG.llm_max_batch)
         if not num_pages and kv_budget_bytes:
@@ -370,11 +408,21 @@ class LLMEngine:
             tp = built_mesh.shape.get("tp", 1) if built_mesh else 1
             num_pages = pages_from_budget(config, page_size,
                                           kv_budget_bytes, tp_shards=tp)
+        model = Transformer(config, mesh=built_mesh)
+        shardings = None
+        if built_mesh is not None:
+            # the replica's weights lie on its mesh as the training
+            # rules say (heads/mlp/vocab over tp, embed over fsdp) and
+            # the cache splits its kv heads over tp — which is what
+            # pages_from_budget(tp_shards=tp) above assumed
+            from ray_tpu.parallel.sharding import param_shardings
+            shardings = param_shardings(built_mesh,
+                                        model.param_logical_axes())
         if weights is not None:
             import ray_tpu
-            params = ray_tpu.get(weights)
+            params = jax.device_put(ray_tpu.get(weights), shardings)
         else:
-            params = Transformer(config, mesh=built_mesh).init(
+            params = jax.jit(model.init, out_shardings=shardings)(
                 jax.random.PRNGKey(seed))
         self.core = EngineCore(config, params, mesh=built_mesh,
                                num_pages=num_pages, page_size=page_size,
@@ -390,7 +438,12 @@ class LLMEngine:
         if CONFIG.llm_stream:
             from ray_tpu.serve.llm.stream import TokenStreamServer
             self._stream = TokenStreamServer(self.incarnation,
-                                             self._backlog)
+                                             self._backlog, self._lock)
+        # the step thread's traceback once core.step() has raised: the
+        # engine is dead from then on and says so, it does not look slow
+        self._failed: Optional[str] = None
+        self._serve_stats = {"queue_wait_p95": 0.0,
+                             "outstanding_tokens": 0}
         self._stop = threading.Event()
         self._kick = threading.Event()
         self._thread = threading.Thread(target=self._loop,
@@ -409,11 +462,42 @@ class LLMEngine:
                 self._kick.clear()
                 continue
             with self._lock:
-                events = self.core.step()
+                try:
+                    events = self.core.step()
+                except Exception:
+                    # a compile error or exhausted HBM is not transient
+                    self._fail(traceback.format_exc())
+                    return
                 self._ingest(events)
-            delay = CONFIG.llm_step_delay_s
-            if delay > 0:               # chaos pacing, 0 in production
-                time.sleep(delay)
+            # chaos pacing; at 0 (production) still a yield, or this
+            # thread re-takes its lock before generate() and subscribers
+            # waiting on it ever run
+            time.sleep(CONFIG.llm_step_delay_s)
+
+    def _fail(self, err: str) -> None:          # holds self._lock
+        """core.step() raised: every open request ends now with `err`,
+        generate() refuses from here on, check_health() fails."""
+        self._failed = err
+        now = time.monotonic()
+        events = []
+        for rid, b in self._buf.items():
+            if b["done"]:
+                continue
+            b.update(done=True, reason=FINISH_ERROR, err=err, t_done=now)
+            events.append({"rid": rid, "token": None,
+                           "seq": len(b["toks"]), "first": False,
+                           "done": True, "reason": FINISH_ERROR,
+                           "attempt": b["attempt"], "err": err})
+        self._cond.notify_all()
+        if self._stream is not None and events:
+            self._stream.publish(events)
+
+    def check_health(self) -> None:
+        """Raises once the step thread has died (the replica's report
+        loop calls this; a replica that fails it stops reporting and
+        the controller replaces it)."""
+        if self._failed is not None:
+            raise RuntimeError(f"llm engine step failed:\n{self._failed}")
 
     def _ingest(self, events: List[dict]) -> None:
         """Record step output into the polled buffers and wake parked
@@ -448,15 +532,15 @@ class LLMEngine:
             self._buf.pop(rid, None)
 
     def _backlog(self, rid: str, cursor: int) -> Optional[dict]:
-        """Stream-subscribe replay: everything from `cursor` on."""
-        with self._lock:
-            b = self._buf.get(rid)
-            if b is None:
-                return None
-            return {"rid": rid, "attempt": b["attempt"],
-                    "base": cursor, "toks": list(b["toks"][cursor:]),
-                    "done": b["done"], "reason": b["reason"],
-                    "err": b["err"]}
+        """Stream-subscribe replay: everything from `cursor` on. The
+        stream server calls this holding self._lock."""
+        b = self._buf.get(rid)
+        if b is None:
+            return None
+        return {"rid": rid, "attempt": b["attempt"],
+                "base": cursor, "toks": list(b["toks"][cursor:]),
+                "done": b["done"], "reason": b["reason"],
+                "err": b["err"]}
 
     # ------------------------------------------------------ serve API
     def ping(self):
@@ -468,6 +552,7 @@ class LLMEngine:
         (subscribe at `stream` with `rid`) or next_tokens polling."""
         submit_t = time.monotonic()
         with self._lock:
+            self.check_health()
             rid = self.core.submit(prompt, max_tokens=max_tokens,
                                    stop=stop, rid=rid, attempt=attempt,
                                    submit_t=submit_t)
@@ -536,13 +621,16 @@ class LLMEngine:
                      "first": False, "done": True,
                      "reason": FINISH_DRAINED, "attempt": d["attempt"]})
             self._cond.notify_all()
-        if self._stream is not None and drained_events:
-            self._stream.publish(drained_events)
+            if self._stream is not None and drained_events:
+                self._stream.publish(drained_events)
         return descs
 
     def engine_stats(self) -> dict:
         with self._lock:
             st = self.core.stats()
+        st.update(self.core.device_stats())
+        st["pid"] = os.getpid()
+        st["failed"] = self._failed
         st["incarnation"] = self.incarnation
         st["stream"] = self._stream.addr if self._stream else None
         return st
@@ -551,9 +639,19 @@ class LLMEngine:
         """Merged into the replica's pushed report — the r11-style
         injectable queue-latency p95 the controller's latency-target
         autoscaling consumes."""
-        with self._lock:
-            return {"queue_wait_p95": self.core.queue_wait_p95(),
+        # This runs on the replica's report thread, whose reports are
+        # also its liveness: it must never wait for the step thread,
+        # which holds the lock through a whole step and so through
+        # every first compile (a replica compiling for 11 s was killed
+        # as dead). A busy engine reports what it last could.
+        if self._lock.acquire(blocking=False):
+            try:
+                self._serve_stats = {
+                    "queue_wait_p95": self.core.queue_wait_p95(),
                     "outstanding_tokens": self.core.outstanding_tokens()}
+            finally:
+                self._lock.release()
+        return self._serve_stats
 
     def close(self):
         self._stop.set()

@@ -192,6 +192,11 @@ class TokenStream:
         self._t_submit = time.monotonic()
         self._submit(first=True)
 
+    @property
+    def failovers(self) -> int:
+        """Times this generation was re-submitted to another replica."""
+        return self._failovers
+
     # ---------------------------------------------------- submission
     def _submit(self, first: bool = False, exclude=()) -> None:
         last_err = None

@@ -51,9 +51,13 @@ class TokenStreamServer:
     step's events."""
 
     def __init__(self, incarnation: str,
-                 backlog: Callable[[str, int], Optional[dict]]):
+                 backlog: Callable[[str, int], Optional[dict]],
+                 engine_lock):
         self._inc = incarnation
+        # `backlog` and `publish` both run under `engine_lock` (the
+        # engine's, held by its step thread while it ingests a step)
         self._backlog = backlog
+        self._engine_lock = engine_lock
         self._lock = threading.Lock()
         # rid -> list of (conn, sent_cursor)
         self._subs: Dict[str, List[list]] = {}
@@ -99,33 +103,37 @@ class TokenStreamServer:
         if mtype == "llm_sub":
             rid = msg["req"]
             cursor = int(msg.get("cursor", 0))
-            # register FIRST, replay second: a live publish racing the
-            # replay can duplicate but never gap; the client trims by
-            # sequence offset
-            with self._lock:
-                self._subs.setdefault(rid, []).append([conn, cursor])
-            back = self._backlog(rid, cursor)
-            if back is None:
-                self._send(conn, {"type": "llm_tok", "req": rid,
-                                  "inc": self._inc, "unknown": True,
-                                  "attempt": -1, "base": cursor,
-                                  "toks": [], "done": True,
-                                  "reason": None, "err": "unknown_rid"})
-                return
-            if back["toks"] or back["done"]:
-                self._send(conn, {"type": "llm_tok", "req": rid,
-                                  "inc": self._inc,
-                                  "attempt": back["attempt"],
-                                  "base": back["base"],
-                                  "toks": back["toks"],
-                                  "done": back["done"],
-                                  "reason": back["reason"],
-                                  "err": back["err"]})
-                with self._lock:
-                    for s in self._subs.get(rid, ()):
-                        if s[0] is conn and s[1] < back["base"] \
-                                + len(back["toks"]):
-                            s[1] = back["base"] + len(back["toks"])
+            # Replay and registration are one step under the engine's
+            # lock, so no live frame can reach this subscriber ahead of
+            # its replay: the client drops a frame that starts past its
+            # cursor as a gap, and a stream whose first frames were
+            # dropped ends short. (Registering first and replaying when
+            # the lock came free lost whole generations to that: the
+            # step loop re-takes its lock at once and a waiter can
+            # starve until the request is done.)
+            with self._engine_lock:
+                back = self._backlog(rid, cursor)
+                if back is None:
+                    self._send(conn, {"type": "llm_tok", "req": rid,
+                                      "inc": self._inc, "unknown": True,
+                                      "attempt": -1, "base": cursor,
+                                      "toks": [], "done": True,
+                                      "reason": None,
+                                      "err": "unknown_rid"})
+                    return
+                if back["toks"] or back["done"]:
+                    self._send(conn, {"type": "llm_tok", "req": rid,
+                                      "inc": self._inc,
+                                      "attempt": back["attempt"],
+                                      "base": back["base"],
+                                      "toks": back["toks"],
+                                      "done": back["done"],
+                                      "reason": back["reason"],
+                                      "err": back["err"]})
+                if not back["done"]:
+                    with self._lock:
+                        self._subs.setdefault(rid, []).append(
+                            [conn, back["base"] + len(back["toks"])])
         elif mtype == "llm_unsub":
             rid = msg["req"]
             with self._lock:
@@ -153,13 +161,14 @@ class TokenStreamServer:
         for ev in events:
             rec = per_rid.setdefault(
                 ev["rid"], {"base": ev["seq"], "toks": [],
-                            "done": False, "reason": None,
+                            "done": False, "reason": None, "err": None,
                             "attempt": ev["attempt"]})
             if ev["token"] is not None:
                 rec["toks"].append(ev["token"])
             if ev["done"]:
                 rec["done"] = True
                 rec["reason"] = ev["reason"]
+                rec["err"] = ev.get("err")
         for rid, rec in per_rid.items():
             with self._lock:
                 subs = list(self._subs.get(rid, ()))
@@ -177,7 +186,8 @@ class TokenStreamServer:
                                   "attempt": rec["attempt"],
                                   "base": base, "toks": toks,
                                   "done": rec["done"],
-                                  "reason": rec["reason"], "err": None})
+                                  "reason": rec["reason"],
+                                  "err": rec["err"]})
                 s[1] = base + len(toks)
             if rec["done"]:
                 with self._lock:

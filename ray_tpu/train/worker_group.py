@@ -23,8 +23,8 @@ class RayTrainWorker:
     """Actor running one training session (one per host)."""
 
     def __init__(self, rank: int, world_size: int):
-        from ray_tpu._private.jaxenv import pin_platform_from_env
-        pin_platform_from_env()
+        from ray_tpu.util.compile_cache import use_compile_cache
+        use_compile_cache()
         self._rank = rank
         self._world_size = world_size
         self._session: Optional[_TrainSession] = None

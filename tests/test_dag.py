@@ -107,6 +107,7 @@ def test_channel_dag_chain_and_pipelining(ray_cluster):
         dag.teardown()
 
 
+@pytest.mark.slow        # ~4s; PR 21 budget: chain_and_pipelining stays tier-1
 def test_channel_dag_multi_output_and_fanout(ray_cluster):
     Stage = _stage_cls()
     a, b, m = Stage.remote("a"), Stage.remote("b"), Stage.remote("m")

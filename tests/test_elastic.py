@@ -406,6 +406,7 @@ def test_elastic_shrink_on_node_loss(head1, tmp_path):
             == [(m["step"], m["loss"]) for m in baseline.metrics_history])
 
 
+@pytest.mark.slow        # ~6s; PR 21 budget: shrink and drain e2e stay tier-1
 def test_elastic_grow_on_node_join(head1, tmp_path):
     """Reshape in the OTHER direction: a node joining mid-fit() grows
     the group to the new capacity (after a pre-grow checkpoint flush),

@@ -457,6 +457,7 @@ def test_metric_prune_series():
     assert list(reg.collect()["m"]["series"]) == [(("node", "b"),)]
 
 
+@pytest.mark.slow        # ~4s; PR 21 budget: the two-agent scrape stays tier-1
 def test_in_process_node_workers_scraped(metrics_env):
     """A cluster-sim node (Cluster.add_node, no agent process) owns
     real subprocess workers — their registries must reach the cluster

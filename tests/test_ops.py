@@ -138,11 +138,11 @@ def test_flash_saveable_grads_and_remat_policy():
     g_ref = jax.grad(lambda *a: jnp.sum(mha_reference(*a, causal=True) ** 2),
                      argnums=(0, 1, 2))(q, k, v)
     g_sv = jax.grad(lambda *a: jnp.sum(flash_attention_saveable(
-        *a, causal=True, block_q=64, block_k=64, interpret=True) ** 2),
+        *a, causal=True, block_q=64, block_k=64) ** 2),
         argnums=(0, 1, 2))(q, k, v)
     rematted = jax.checkpoint(
         lambda *a: flash_attention_saveable(
-            *a, causal=True, block_q=64, block_k=64, interpret=True),
+            *a, causal=True, block_q=64, block_k=64),
         policy=attn_remat_policy())
     g_rm = jax.grad(lambda *a: jnp.sum(rematted(*a) ** 2),
                     argnums=(0, 1, 2))(q, k, v)

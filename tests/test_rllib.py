@@ -769,6 +769,9 @@ def test_appo_cartpole_learning_gate(fresh_cluster):
     assert best >= 300, f"APPO failed to learn CartPole: best={best}"
 
 
+# ~5s; PR 21 budget: the DQN update/shape tests and the APPO gate
+# stay tier-1
+@pytest.mark.slow
 def test_c51_distributional_dqn_learning_gate(fresh_cluster):
     """Distributional C51 + dueling + double-Q + n-step + prioritized
     replay learns CartPole (reference rllib/algorithms/dqn rainbow

@@ -361,7 +361,7 @@ def test_trainer_jax_distributed_two_processes(tmp_path):
         make_loop(),
         scaling_config=ScalingConfig(num_workers=2),
         run_config=RunConfig(name="dist", storage_path=str(tmp_path)),
-        backend_config=JaxConfig(distributed=True, platform="cpu"),
+        backend_config=JaxConfig(distributed=True),
     ).fit()
     assert result.error is None
     assert result.metrics["procs"] == 2

@@ -230,6 +230,11 @@ class _ServingMetrics:
         self.tokens = Counter(
             "ray_tpu_llm_tokens",
             "LLM tokens emitted by this engine replica", registry=reg)
+        self.step = Histogram(
+            "ray_tpu_llm_step_s",
+            "LLM engine step: wall time of one iteration of the step "
+            "loop that had work (admit, decode, ingest and publish)",
+            boundaries=bounds, registry=reg)
 
 
 _mx: Optional[_RuntimeMetrics] = None
@@ -238,7 +243,7 @@ _sv: Optional[_ServingMetrics] = None
 
 
 def serving_metrics() -> Optional[dict]:
-    """TTFT/TPOT histograms + token counter for the LLM engine, or
+    """TTFT/TPOT/step histograms + token counter for the LLM engine, or
     None while the plane is disabled (callers skip their observes)."""
     if not enabled():
         return None
@@ -249,7 +254,8 @@ def serving_metrics() -> Optional[dict]:
             m = _sv
             if m is None:
                 _sv = m = _ServingMetrics()
-    return {"ttft": m.ttft, "tpot": m.tpot, "tokens": m.tokens}
+    return {"ttft": m.ttft, "tpot": m.tpot, "tokens": m.tokens,
+            "step": m.step}
 
 
 def _metrics() -> _RuntimeMetrics:

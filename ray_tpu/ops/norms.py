@@ -22,6 +22,10 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.ops.dispatch import activation_spec, on_tpu, shard_kernel
 
 _BLOCK_ROWS = 256
+# its name in a device trace, and the scope that keeps a transform's
+# wrapping off it (see ops/attention.py, KERNEL_FWD)
+KERNEL_RMS_FWD = "rms_norm_fwd"
+KERNEL_SCOPE = "rms_norm"
 
 
 # ---------------------------------------------------------------- rmsnorm
@@ -48,7 +52,7 @@ def _rms_fwd_pallas(x2d: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     # dropped.
     block_rows = min(rows, _BLOCK_ROWS)
     grid = (pl.cdiv(rows, block_rows),)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
         out_shape=jax.ShapeDtypeStruct((rows, d), x2d.dtype),
         grid=grid,
@@ -58,7 +62,10 @@ def _rms_fwd_pallas(x2d: jax.Array, w: jax.Array, eps: float) -> jax.Array:
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         interpret=not on_tpu(),
-    )(x2d, w)
+        name=KERNEL_RMS_FWD,
+    )
+    with jax.named_scope(KERNEL_SCOPE):
+        return call(x2d, w)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))

@@ -33,12 +33,56 @@ import uuid
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
+from ray_tpu._private import tracing_plane as _tp
+from ray_tpu.serve.llm import spans as _sp
 from ray_tpu.serve.llm.kv_cache import PageAllocator, pages_needed
 
 FINISH_STOP = "stop"
 FINISH_LENGTH = "length"
 FINISH_DRAINED = "drained"
 FINISH_ERROR = "error"
+
+# a step this long is kept in EngineCore.slow_steps (PERF.md Findings 4)
+SLOW_STEP_S = 1.0
+
+_clock = time.monotonic     # a step's and its phases' seconds
+
+
+class _Phase:
+    """A child span of one step whose seconds also add up in the step's
+    own record (`EngineCore.slow_steps` keeps them for a slow step)."""
+
+    __slots__ = ("_acc", "_key", "_span", "_t0")
+
+    def __init__(self, acc: dict, name: str, **attrs):
+        self._acc = acc
+        self._key = name
+        self._span = _sp.span(name, **attrs)
+
+    def __enter__(self) -> None:
+        self._t0 = _clock()
+        self._span.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._span.__exit__(*exc)
+        self._acc[self._key] = (self._acc.get(self._key, 0.0)
+                                + _clock() - self._t0)
+
+
+def _name_os_thread(name: str) -> None:
+    """Give the calling thread its name at the OS too (Python 3.12 names a
+    thread only for itself). A profiler's trace files host events by the
+    OS name, and every Python thread is `python3` there: two of them
+    collapse into one line for a reader that keys lines by name."""
+    try:
+        import ctypes
+        prctl = ctypes.CDLL(None).prctl
+        prctl.argtypes = [ctypes.c_int, ctypes.c_char_p,
+                          *[ctypes.c_ulong] * 3]
+        prctl.restype = ctypes.c_int
+        prctl(15, name.encode()[:15], 0, 0, 0)      # PR_SET_NAME
+    except (OSError, AttributeError):   # not Linux: it stays `python3`
+        pass
 
 
 def _bucket(n: int, lo: int = 16, hi: int = 1 << 30) -> int:
@@ -62,6 +106,8 @@ class _Seq:
     emitted: List[int] = dataclasses.field(default_factory=list)
     pages: List[int] = dataclasses.field(default_factory=list)
     evictions: int = 0
+    trace_id: int = 0           # the llm.* spans of this request
+    first_token_t: Optional[float] = None
 
     @property
     def total_len(self) -> int:
@@ -129,8 +175,24 @@ class EngineCore:
             "device_kind": self._devices[0].device_kind,
             "device_ids": [d.id for d in self._devices],
             "chips": [int(c) for c in chips.split(",") if c]}
-        self.counters = {"admitted": 0, "evictions": 0, "finished": 0,
-                         "tokens": 0, "steps": 0}
+        # what one decode step gathers per layer, whatever its lanes hold
+        self._read_positions = (self.max_batch * self.max_pages_per_seq
+                                * self.page_size)
+        self.counters = {
+            "admitted": 0, "evictions": 0, "finished": 0, "tokens": 0,
+            "steps": 0,
+            # prompt tokens prefilled, and the same after padding to
+            # their bucket (what the prefill programs computed)
+            "prefill_tokens": 0, "prefill_padded_tokens": 0,
+            "prefill_programs": 0,      # prefill functions built
+            # decode dispatches, and the lanes that held a sequence
+            "decode_steps": 0, "decode_lane_steps": 0,
+            # cache positions those lanes held / the dispatches gathered
+            "kv_positions_live": 0, "kv_positions_read": 0}
+        # the steps that took SLOW_STEP_S or more: wall seconds, when,
+        # the decode batch and the seconds in each phase
+        self.slow_steps: deque = deque(maxlen=16)
+        self._step_span = 0     # span id of the step running, or 0
 
     # ------------------------------------------------------ intake
     def submit(self, prompt: Sequence[int], max_tokens: int = 16,
@@ -159,7 +221,8 @@ class EngineCore:
                    stop=frozenset(int(t) for t in stop),
                    attempt=int(attempt),
                    submit_t=(time.monotonic() if submit_t is None
-                             else submit_t))
+                             else submit_t),
+                   trace_id=_tp.new_id() if _tp.enabled() else 0)
         self._waiting.append(seq)
         self._by_rid[rid] = seq
         return rid
@@ -212,6 +275,7 @@ class EngineCore:
                                          self.page_size)
             fn = self._jit(_pre)
             self._prefill_fns[s_pad] = fn
+            self.counters["prefill_programs"] += 1
         return fn
 
     def _emit(self, events: List[dict], seq: _Seq, token: int) -> None:
@@ -227,9 +291,26 @@ class EngineCore:
                        "seq": len(seq.emitted) - 1, "first": first,
                        "done": done, "reason": reason,
                        "attempt": seq.attempt})
+        if first or done:
+            now = time.monotonic()
+            if first:
+                self._request_span(seq, _sp.REQ_PREFILL, seq.admit_t, now)
+                seq.first_token_t = now
+            if done:
+                self._request_span(seq, _sp.REQ_DECODE,
+                                   seq.first_token_t, now)
         if done:
             self.counters["finished"] += 1
             self.cancel(seq.rid)
+
+    def _request_span(self, seq: _Seq, name: str, t0: float,
+                      t1: float) -> None:
+        """One stage of a request's life, to the flight recorder: under
+        the request's trace id, child of the step that ended it."""
+        if seq.trace_id and _tp.enabled():
+            _tp.record("llm", name, int(t0 * 1e9), int(t1 * 1e9),
+                       seq.trace_id, _tp.new_id(), self._step_span,
+                       {"rid": seq.rid})
 
     def _evict_one(self, keep: _Seq) -> bool:
         """Preempt the youngest running sequence other than `keep`,
@@ -251,35 +332,58 @@ class EngineCore:
 
     def step(self) -> List[dict]:
         """One engine iteration: admit, then decode everyone once."""
+        self.counters["steps"] += 1
+        t0, t_mono_ns = _clock(), time.monotonic_ns()
+        phases: Dict[str, float] = {}
+        with _sp.span(_sp.STEP, step=self.counters["steps"],
+                      t_mono_ns=t_mono_ns) as ctx:
+            self._step_span = ctx[1] if ctx else 0
+            events, lanes = self._step(phases)
+        wall = _clock() - t0
+        if wall >= SLOW_STEP_S:
+            self.slow_steps.append({
+                "step": self.counters["steps"], "wall_s": wall,
+                "t_mono_ns": t_mono_ns, "lanes": lanes,
+                "phases": phases})
+        return events
+
+    def _step(self, phases: Dict[str, float]):
+        """The step's work; returns (events, lanes decoded)."""
         import jax.numpy as jnp
         np = self._np
+        c = self.counters
         events: List[dict] = []
-        self.counters["steps"] += 1
 
         # ---- per-iteration admission: prefill into free pages
         while self._waiting and len(self._running) < self.max_batch:
             seq = self._waiting[0]
             toks = seq.prompt + seq.emitted
-            need = pages_needed(len(toks), self.page_size)
-            pages = self.alloc.alloc(need)
+            pages = self.alloc.alloc(pages_needed(len(toks), self.page_size))
             if pages is None:
                 break                      # pool dry: decode continues
-            self._waiting.popleft()
-            seq.pages = pages
-            now = time.monotonic()
-            if seq.admit_t is None:        # first admission only
-                seq.admit_t = now
-                self._queue_waits.append((now, now - seq.submit_t))
             s_pad = _bucket(len(toks), hi=self.config.max_seq_len)
-            padded = np.zeros((s_pad,), np.int32)
-            padded[:len(toks)] = toks
-            logits, self._cache = self._prefill_fn(s_pad)(
-                self.params, jnp.asarray(padded),
-                jnp.int32(len(toks)), jnp.asarray(self._page_table(seq)),
-                self._cache)
-            self._running.append(seq)
-            self.counters["admitted"] += 1
-            self._emit(events, seq, int(logits.argmax()))
+            with _Phase(phases, _sp.PREFILL, rid=seq.rid, tokens=len(toks),
+                        bucket=s_pad,
+                        new_program=int(s_pad not in self._prefill_fns)):
+                self._waiting.popleft()
+                seq.pages = pages
+                now = time.monotonic()
+                if seq.admit_t is None:    # first admission only
+                    seq.admit_t = now
+                    self._queue_waits.append((now, now - seq.submit_t))
+                    self._request_span(seq, _sp.REQ_QUEUE, seq.submit_t,
+                                       now)
+                padded = np.zeros((s_pad,), np.int32)
+                padded[:len(toks)] = toks
+                logits, self._cache = self._prefill_fn(s_pad)(
+                    self.params, jnp.asarray(padded),
+                    jnp.int32(len(toks)),
+                    jnp.asarray(self._page_table(seq)), self._cache)
+                self._running.append(seq)
+                c["admitted"] += 1
+                c["prefill_tokens"] += len(toks)
+                c["prefill_padded_tokens"] += s_pad
+                self._emit(events, seq, int(logits.argmax()))
 
         # ---- decode every in-flight sequence by one token
         batch = [s for s in self._running]
@@ -306,25 +410,39 @@ class EngineCore:
                     break
         batch = [s for s in batch if s in self._running]
         if not batch:
-            return events
-        B = self.max_batch
-        tokens = np.zeros((B,), np.int32)
-        positions = np.zeros((B,), np.int32)
-        pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
-        active = np.zeros((B,), bool)
-        for i, seq in enumerate(batch):
-            tokens[i] = seq.emitted[-1]
-            positions[i] = seq.total_len - 1
-            pts[i] = self._page_table(seq)
-            active[i] = True
-        logits, self._cache = self._decode_fn(
-            self.params, self._cache, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(pts),
-            jnp.asarray(active))
-        next_tokens = np.asarray(logits.argmax(axis=-1))
-        for i, seq in enumerate(batch):
-            self._emit(events, seq, int(next_tokens[i]))
-        return events
+            return events, 0
+        with _Phase(phases, _sp.TABLES):
+            B = self.max_batch
+            tokens = np.zeros((B,), np.int32)
+            positions = np.zeros((B,), np.int32)
+            pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
+            active = np.zeros((B,), bool)
+            live = 0
+            for i, seq in enumerate(batch):
+                tokens[i] = seq.emitted[-1]
+                positions[i] = seq.total_len - 1
+                pts[i] = self._page_table(seq)
+                active[i] = True
+                live += seq.total_len
+            args = (jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(pts), jnp.asarray(active))
+        c["decode_steps"] += 1
+        c["decode_lane_steps"] += len(batch)
+        c["kv_positions_live"] += live
+        c["kv_positions_read"] += self._read_positions
+        # an annotation's attributes are fixed when it opens, so the
+        # step's counts ride the first span that opens once they are known
+        with _Phase(phases, _sp.DISPATCH, lanes=len(batch),
+                    live_positions=live,
+                    read_positions=self._read_positions):
+            logits, self._cache = self._decode_fn(
+                self.params, self._cache, *args)
+        with _Phase(phases, _sp.FETCH):
+            next_tokens = np.asarray(logits.argmax(axis=-1))
+        with _Phase(phases, _sp.EMIT):
+            for i, seq in enumerate(batch):
+                self._emit(events, seq, int(next_tokens[i]))
+        return events, len(batch)
 
     # ------------------------------------------------------- signals
     def queue_wait_p95(self, window_s: float = 30.0) -> float:
@@ -355,6 +473,7 @@ class EngineCore:
                 "num_pages": self.num_pages,
                 "outstanding_tokens": self.outstanding_tokens(),
                 "queue_wait_p95": self.queue_wait_p95(),
+                "slow_steps": list(self.slow_steps),
                 **self.counters}
 
 
@@ -454,14 +573,17 @@ class LLMEngine:
     # ---------------------------------------------------- step thread
     def _loop(self) -> None:
         from ray_tpu._private.config import CONFIG
+        _name_os_thread(self._thread.name)
         while not self._stop.is_set():
             with self._lock:
                 busy = self.core.has_work
             if not busy:
-                self._kick.wait(0.05)
+                with _sp.span(_sp.WAIT):
+                    self._kick.wait(0.05)
                 self._kick.clear()
                 continue
             with self._lock:
+                t0 = _clock()
                 try:
                     events = self.core.step()
                 except Exception:
@@ -469,10 +591,13 @@ class LLMEngine:
                     self._fail(traceback.format_exc())
                     return
                 self._ingest(events)
+                if self._metrics:
+                    self._metrics["step"].observe(_clock() - t0)
             # chaos pacing; at 0 (production) still a yield, or this
             # thread re-takes its lock before generate() and subscribers
             # waiting on it ever run
-            time.sleep(CONFIG.llm_step_delay_s)
+            with _sp.span(_sp.YIELD):
+                time.sleep(CONFIG.llm_step_delay_s)
 
     def _fail(self, err: str) -> None:          # holds self._lock
         """core.step() raised: every open request ends now with `err`,
@@ -502,28 +627,30 @@ class LLMEngine:
     def _ingest(self, events: List[dict]) -> None:
         """Record step output into the polled buffers and wake parked
         pollers; push to stream subscribers OUTSIDE any model time."""
-        now = time.monotonic()
-        for ev in events:
-            b = self._buf.get(ev["rid"])
-            if b is None:
-                continue
-            if ev["token"] is not None:
-                if not b["toks"] and self._metrics:
-                    self._metrics["ttft"].observe(now - b["submit_t"])
-                elif b["toks"] and self._metrics:
-                    self._metrics["tpot"].observe(now - b["last_tok_t"])
-                b["last_tok_t"] = now
-                b["toks"].append(ev["token"])
-                if self._metrics:
-                    self._metrics["tokens"].inc()
-            if ev["done"]:
-                b["done"] = True
-                b["reason"] = ev["reason"]
-                b["t_done"] = now
-        self._cond.notify_all()
-        self._sweep(now)
-        if self._stream is not None:
-            self._stream.publish(events)
+        with _sp.span(_sp.INGEST):
+            now = time.monotonic()
+            for ev in events:
+                b = self._buf.get(ev["rid"])
+                if b is None:
+                    continue
+                if ev["token"] is not None:
+                    if not b["toks"] and self._metrics:
+                        self._metrics["ttft"].observe(now - b["submit_t"])
+                    elif b["toks"] and self._metrics:
+                        self._metrics["tpot"].observe(
+                            now - b["last_tok_t"])
+                    b["last_tok_t"] = now
+                    b["toks"].append(ev["token"])
+                    if self._metrics:
+                        self._metrics["tokens"].inc()
+                if ev["done"]:
+                    b["done"] = True
+                    b["reason"] = ev["reason"]
+                    b["t_done"] = now
+            self._cond.notify_all()
+            self._sweep(now)
+            if self._stream is not None:
+                self._stream.publish(events)
 
     def _sweep(self, now: float) -> None:     # holds self._lock
         dead = [rid for rid, b in self._buf.items()
@@ -551,7 +678,9 @@ class LLMEngine:
         """Accept one generation; tokens arrive via the push stream
         (subscribe at `stream` with `rid`) or next_tokens polling."""
         submit_t = time.monotonic()
-        with self._lock:
+        with _sp.span(_sp.SUBMIT, rid=rid or ""):
+            self._lock.acquire()
+        try:
             self.check_health()
             rid = self.core.submit(prompt, max_tokens=max_tokens,
                                    stop=stop, rid=rid, attempt=attempt,
@@ -560,6 +689,8 @@ class LLMEngine:
                               "err": None, "t_done": 0.0,
                               "attempt": int(attempt),
                               "submit_t": submit_t, "last_tok_t": 0.0}
+        finally:
+            self._lock.release()
         self._kick.set()
         return {"rid": rid, "attempt": int(attempt),
                 "incarnation": self.incarnation,

@@ -1,0 +1,33 @@
+"""Names of the serving engine's spans: the contract between the engine
+and the token stream, the metrics that read a profiler trace
+(`benchmarks/harness/spans.py`), and PERF.md section 3 / README
+"Distributed tracing". Every span but the three `llm.*` ones goes to
+both sinks of `util.tracing.annotate`: the profiler's trace, on the
+clock the device's trace is aligned to, and the flight recorder.
+"""
+from __future__ import annotations
+
+from ray_tpu.util.tracing import annotate
+
+# the step thread, nested as listed
+WAIT = "engine.wait_for_work"       # idle: no request waiting or running
+STEP = "engine.step"                # one EngineCore.step()
+PREFILL = "engine.prefill"          # one admission, to its first token
+TABLES = "engine.page_tables"       # the decode batch's host arrays
+DISPATCH = "engine.decode_dispatch"     # carries the step's counts
+FETCH = "engine.fetch_tokens"       # waits for the device's answer
+EMIT = "engine.emit"
+INGEST = "engine.ingest"
+PUBLISH = "stream.publish"          # child of ingest: a step's frames
+YIELD = "engine.yield"              # the lock released between steps
+# the caller's thread: generate() from entry to the engine's lock held
+SUBMIT = "engine.submit"
+# one request's life, flight recorder only, each written when it ends,
+# all three under the trace id the request keeps
+REQ_QUEUE = "llm.queue"             # submit to admission
+REQ_PREFILL = "llm.prefill"         # admission to first token
+REQ_DECODE = "llm.decode"           # first token to done
+
+
+class span(annotate):
+    kind = "llm"

@@ -34,6 +34,7 @@ import threading
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ray_tpu._private import protocol
+from ray_tpu.serve.llm import spans as _sp
 
 STREAM_STATS = {
     "frames_out": 0,        # server: token frames pushed
@@ -169,6 +170,13 @@ class TokenStreamServer:
                 rec["done"] = True
                 rec["reason"] = ev["reason"]
                 rec["err"] = ev.get("err")
+        # one span for all of a step's frames: a span around each send
+        # cost the step thread more than the bound of the cell it was
+        # measured in (PERF.md, Findings PR 26)
+        with _sp.span(_sp.PUBLISH, frames=len(per_rid)):
+            self._push(per_rid)
+
+    def _push(self, per_rid: Dict[str, dict]) -> None:
         for rid, rec in per_rid.items():
             with self._lock:
                 subs = list(self._subs.get(rid, ()))

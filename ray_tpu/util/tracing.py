@@ -40,6 +40,8 @@ from __future__ import annotations
 import contextlib
 from typing import Iterator, Optional
 
+from ray_tpu._private import tracing_plane as _tp
+
 
 @contextlib.contextmanager
 def profile(log_dir: str) -> Iterator[None]:
@@ -53,23 +55,53 @@ def profile(log_dir: str) -> Iterator[None]:
         jax.profiler.stop_trace()
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named span: lands in the flight recorder (so it shows on
-    task_timeline() next to the runtime's spans, joining the ambient
-    trace when called inside a traced task, else starting its own)
-    AND as a jax TraceAnnotation inside a profile() capture; near-zero
-    cost when tracing is disabled and no jax trace is active."""
-    from ray_tpu._private import tracing_plane as _tp
-    with _tp.span("user", name, root=True):
+_trace_annotation = None     # TraceAnnotation, or False without jax
+
+
+def _profiler_annotation():
+    """`jax.profiler.TraceAnnotation`, looked up once and on first use,
+    so that a module on the hot path can use `annotate` and still import
+    no JAX; False where JAX is unavailable or broken (recorder only)."""
+    global _trace_annotation
+    if _trace_annotation is None:
         try:
-            import jax
-            ta = jax.profiler.TraceAnnotation(name)
-        except Exception:        # jax unavailable/broken: recorder only
-            yield
-            return
-        with ta:
-            yield
+            from jax.profiler import TraceAnnotation
+            _trace_annotation = TraceAnnotation
+        except Exception:
+            _trace_annotation = False
+    return _trace_annotation
+
+
+class annotate:
+    """Named span with two sinks: the flight recorder (so it shows on
+    task_timeline() next to the runtime's spans, joining the ambient
+    trace when called inside a traced task, else starting its own;
+    gated by RAY_TPU_TRACE) AND a jax TraceAnnotation, which lands in a
+    profile() capture on the clock the device's trace is aligned to.
+    Keyword attributes go to both (the recorder's `extra`, the
+    annotation's arguments). Near-zero cost when tracing is disabled
+    and no jax trace is active. `__enter__` returns the recorder's
+    (trace_id, span_id), or None while the recorder is off."""
+
+    __slots__ = ("_span", "_ta")
+    kind = "user"           # the recorder's span kind
+
+    def __init__(self, name: str, **attrs):
+        self._span = _tp.span(self.kind, name, root=True,
+                              extra=attrs or None)
+        ta = _profiler_annotation()
+        self._ta = ta(name, **attrs) if ta else None
+
+    def __enter__(self) -> Optional[tuple]:
+        ctx = self._span.__enter__()
+        if self._ta is not None:
+            self._ta.__enter__()
+        return ctx
+
+    def __exit__(self, *exc) -> None:
+        if self._ta is not None:
+            self._ta.__exit__(*exc)
+        self._span.__exit__(*exc)
 
 
 def annotate_fn(name: Optional[str] = None):
@@ -103,7 +135,6 @@ def task_timeline(filename: Optional[str] = None,
     import json
 
     from ray_tpu._private import context as _ctx
-    from ray_tpu._private import tracing_plane as _tp
     dump = _ctx.get_ctx().state_op("trace_dump")
     trace = _tp.chrome_trace(dump.get("processes", []),
                              trace_id=trace_id)

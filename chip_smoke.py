@@ -15,7 +15,9 @@ from seed 0):
          of different lengths submitted together and streamed.
   check  a num_tpus=1 task rebuilds the weights from the seed and asserts
          every served token is the teacher-forced argmax of
-         Transformer.apply (or within LOGIT_MARGIN of it).
+         Transformer.apply (or within LOGIT_MARGIN of it); the paged
+         decode-attention kernel against the einsum at the serving
+         cells' shapes; which attention the engine's decode step holds.
 
 With --chips 4 the train phase runs again with one worker holding all
 four chips on MeshSpec(fsdp=2, tp=2), serving is four one-chip replicas,
@@ -44,10 +46,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 # bf16 kernels against float32-accumulating references on the same
 # device: largest error over largest reference value.
-KERNEL_TOL = {"attn_out": 3e-2, "attn_grad": 5e-2, "rms_norm": 2e-2}
+KERNEL_TOL = {"attn_out": 3e-2, "attn_grad": 5e-2, "rms_norm": 2e-2,
+              "paged_attn": 3e-2}
 # a served token may lose to the teacher-forced argmax by this much
-# (logits have a standard deviation near 1): decode reads a float32
-# paged cache, the full forward runs the bf16 flash kernel.
+# (logits have a standard deviation near 1): decode reads the paged
+# cache one position at a time, the full forward runs the flash kernel.
 LOGIT_MARGIN = 0.1
 # one chip and fsdp=2.tp=2 reduce in different orders, in bf16: the first
 # loss is one forward apart; later ones also carry five adamw steps whose
@@ -306,10 +309,58 @@ def serve_phase(cfg, prompts, *, chips: int, replicas: int = 1,
             "replicas": [
         {k: st[k] for k in ("pid", "platform", "device_kind",
                             "device_ids", "chips", "bytes_in_use",
+                            "decode_attention", "decode_kernel_steps",
                             "admitted", "tokens")} for st in stats]}
 
 
 # ------------------------------------------------------------- check
+def _paged_attention_check() -> dict:
+    """The paged decode kernel against the gather + einsum on this
+    process's first device, at the serving cells' shapes: 8 lanes, 16
+    query heads over 8 kv heads of 128, 16-token pages of bf16, tables of
+    256; ragged lanes, one empty, pages in no order. The CPU suite only
+    ever sees the interpreter."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.ops import paged_attention as pa
+
+    lanes, heads, kvh, hd, page, max_pages, layers = 8, 16, 8, 128, 16, 256, 2
+    lengths = [1, 16, 17, 577, 1300, 0, 4096, 640]
+    pages = lanes * max_pages + 1
+    rng = np.random.default_rng(7)
+    pt = (1 + rng.permutation(pages - 1)).reshape(lanes, max_pages)
+    for lane, n in enumerate(lengths):
+        pt[lane, -(-n // page):] = -1
+
+    @jax.jit
+    def inputs(key):
+        kq, kk, kv = jax.random.split(key, 3)
+        pool = (layers, pages, page, kvh * hd)
+        return (jax.random.normal(kq, (lanes, heads, hd), jnp.bfloat16),
+                jax.random.normal(kk, pool, jnp.bfloat16),
+                jax.random.normal(kv, pool, jnp.bfloat16))
+
+    q, k, v = inputs(jax.random.PRNGKey(11))
+    args = (q, k, v, jnp.int32(1), jnp.asarray(pt, jnp.int32),
+            jnp.asarray(lengths, jnp.int32))
+    if not pa.uses_kernel(hd, page, k.dtype):
+        raise AssertionError(
+            "the paged decode kernel is not what runs at the serving "
+            "cells' shapes on this device")
+    got = jax.jit(pa.paged_decode_attention)(*args).astype(jnp.float32)
+    want = jax.jit(pa.paged_attention_reference)(*args).astype(jnp.float32)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    if not bool(jnp.isfinite(got).all()) or err > KERNEL_TOL["paged_attn"]:
+        raise AssertionError(
+            f"paged decode attention leaves the einsum by {err:.3g} "
+            f"(limit {KERNEL_TOL['paged_attn']})")
+    if bool(got[lengths.index(0)].any()):
+        raise AssertionError("a lane that holds nothing got an output")
+    return {"paged_attn": err}
+
+
 def _check_served(model_kwargs: dict, prompts, served_runs, prev_pids):
     """Runs on the chip after every replica is gone: the weights again
     from seed 0, Transformer.apply over prompt + served tokens, every
@@ -361,11 +412,16 @@ def _check_served(model_kwargs: dict, prompts, served_runs, prev_pids):
         raise AssertionError(
             f"prefill HLO holds {prefill_calls} tpu_custom_call, "
             f"expected 4")
-    return {"pid": os.getpid(), "platform": device.platform,
-            "device_kind": device.device_kind,
-            "device_count": len(jax.devices()),
-            "tokens_checked": total, "exact_argmax": exact,
-            "worst_margin": worst, "prefill_custom_calls": prefill_calls}
+    report = {"pid": os.getpid(), "platform": device.platform,
+              "device_kind": device.device_kind,
+              "device_count": len(jax.devices()),
+              "tokens_checked": total, "exact_argmax": exact,
+              "worst_margin": worst, "prefill_custom_calls": prefill_calls,
+              # which attention this engine's decode step holds
+              "decode_attention": core.device_stats()["decode_attention"]}
+    if device.platform == "tpu":
+        report["kernel_errors"] = _paged_attention_check()
+    return report
 
 
 def check_phase(cfg, prompts, served_runs, *, chips: int,

@@ -9,21 +9,37 @@ same ops) so prefill+decode logits agree with `Transformer.apply` to
 float tolerance — tests pin that equivalence.
 
 The cache is paged (vLLM-style): per layer, `(num_pages, page_size,
-kv_heads, head_dim)` arrays, and a sequence owns an arbitrary set of
+kv_heads * head_dim)` arrays, and a sequence owns an arbitrary set of
 pages listed in its page table. Paging is what makes continuous
 batching viable — a finished sequence returns its pages to the pool
 immediately instead of stranding a max-length slab.
 
 Layout note: pages are stacked on a leading layers axis, matching the
-stacked/scanned parameter layout. Prefill scans the layer body (one
-compile regardless of depth); the decode step unrolls a Python loop
-over layers — at serving depths that compile cost is paid once per
-(batch, pages) shape and the unrolled body lets XLA alias the per-layer
-cache updates in place.
+stacked/scanned parameter layout, and a position is one row of all its
+kv heads side by side: a page is contiguous, `(page_size, kv * hd)`
+tiles as the paged decode kernel (`ops.paged_attention`) copies it in,
+and the stacked pool is what the kernel takes, with the layer's index,
+so no per-layer slice of it is ever made. Prefill scans the layer body
+(one compile regardless of depth) and writes whole pages; the decode
+step unrolls a Python loop over layers — at serving depths that compile
+cost is paid once per (batch, pages) shape.
+
+Both programs move cache bytes in proportion to what the lanes hold,
+not to the pool or the context limit. The writes are scatters into the
+pool itself, which holds only if the caller donates the cache
+(`EngineCore` jits both with `donate_argnums`): without donation XLA
+copies both pools whole before the first scatter. The decode step's
+attention reads through `ops.paged_attention.paged_decode_attention`:
+the kernel where the platform is a TPU and the shapes tile, the gather
+and masked einsum over the whole table elsewhere (`decode_attention`
+says which).
 
 Out-of-range page writes use `num_pages` as the drop sentinel: scatter
-mode="drop" discards them, which is how padded prefill tails and
-inactive decode rows stay out of the cache without branching.
+mode="drop" discards them, which is how pages past a prompt and
+inactive decode rows stay out of the cache without branching. A
+prefill writes its last page whole: the rows past the prompt hold the
+padding's keys until the decode steps that reach them overwrite them,
+and nothing reads a position before it is written.
 """
 from __future__ import annotations
 
@@ -34,6 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin
 
@@ -42,9 +59,10 @@ KVCache = Dict[str, jax.Array]
 
 
 def cache_sharding(config, mesh):
-    """How the paged cache lies on a mesh: kv heads over `tp`, the rest
-    whole — each tp shard holds its own heads' pages, which is what
-    `cache_page_bytes(tp_shards=...)` charges for."""
+    """How the paged cache lies on a mesh: kv heads (blocks of a row's
+    lanes) over `tp`, the rest whole — each tp shard holds its own
+    heads' pages, which is what `cache_page_bytes(tp_shards=...)`
+    charges for."""
     tp = mesh.shape.get("tp", 1)
     if config.kv_heads % tp:
         raise ValueError(
@@ -52,19 +70,19 @@ def cache_sharding(config, mesh):
             f"the mesh's tp axis of {tp}, which does not divide them; "
             f"use a tp that divides kv_heads")
     return NamedSharding(mesh, P(None, None, None,
-                                 "tp" if tp > 1 else None, None))
+                                 "tp" if tp > 1 else None))
 
 
 def init_paged_cache(config, num_pages: int, page_size: int,
                      dtype=None, mesh=None) -> KVCache:
-    """Zeroed paged cache: k/v each (layers, pages, page, kv, hd),
+    """Zeroed paged cache: k/v each (layers, pages, page, kv * hd),
     created sharded as `cache_sharding` says when given a mesh."""
     if config.moe_num_experts:
         raise NotImplementedError(
             "paged decoding supports dense FFN layers only")
     dt = dtype or config.activation_dtype
     shape = (config.n_layers, num_pages, page_size,
-             config.kv_heads, config.head_dim)
+             config.kv_heads * config.head_dim)
     sharding = cache_sharding(config, mesh) if mesh is not None else None
     zeros = jax.jit(lambda: jnp.zeros(shape, dt), out_shardings=sharding)
     return {"k": zeros(), "v": zeros()}
@@ -79,6 +97,16 @@ def cache_page_bytes(config, page_size: int, tp_shards: int = 1,
     kv_local = max(1, config.kv_heads // max(1, tp_shards))
     return (2 * config.n_layers * page_size * kv_local
             * config.head_dim * dt.itemsize)
+
+
+def decode_attention(config, page_size: int, dtype=None) -> str:
+    """Which attention a `decode_step` traced here holds: the kernel's
+    name, or "einsum" (the platform being traced for and the shapes
+    decide, `ops.paged_attention.uses_kernel`)."""
+    dt = dtype or config.activation_dtype
+    if _paged.uses_kernel(config.head_dim, page_size, dt):
+        return _paged.KERNEL_PAGED_DECODE
+    return "einsum"
 
 
 def _qkv(config, layer: Params, h):
@@ -110,6 +138,7 @@ def prefill(model, params: Params, tokens: jax.Array, true_len,
     true_len: scalar int32, actual prompt length.
     page_table: (max_pages,) int32 page ids; entries past the prompt's
     pages may be anything (writes there are dropped).
+    cache: donate it, or both pools are copied whole.
 
     Returns (last-position logits (vocab,) f32, updated cache).
     """
@@ -144,18 +173,24 @@ def prefill(model, params: Params, tokens: jax.Array, true_len,
     last = jnp.take(x[0], true_len - 1, axis=0)
     logits = (last @ model._head(params).astype(ad)).astype(jnp.float32)
 
-    pos = jnp.arange(s)
-    page_ids = jnp.take(page_table, pos // page_size, mode="clip")
-    # positions past the prompt scatter to the drop sentinel
-    page_ids = jnp.where(pos < true_len, page_ids, num_pages)
-    slots = pos % page_size
-    cache = {
-        "k": cache["k"].at[:, page_ids, slots].set(
-            ks.astype(cache["k"].dtype), mode="drop"),
-        "v": cache["v"].at[:, page_ids, slots].set(
-            vs.astype(cache["v"].dtype), mode="drop"),
-    }
-    return logits, cache
+    # whole pages: (layers, s, kv, hd) -> (layers, pages, page, kv * hd)
+    n = -(-s // page_size)
+    first = jnp.arange(n) * page_size
+    page_ids = jnp.take(page_table, jnp.arange(n), mode="clip")
+    # pages past the prompt scatter to the drop sentinel
+    page_ids = jnp.where(first < true_len, page_ids, num_pages)
+
+    layer_ids = jnp.arange(c.n_layers)[:, None]
+
+    def paged(a, pool):
+        a = a.astype(pool.dtype).reshape(c.n_layers, s, -1)
+        a = jnp.pad(a, ((0, 0), (0, n * page_size - s), (0, 0)))
+        # both indices explicit: a slice over the layers would make the
+        # CPU backend transpose the pool to scatter and copy it back
+        return pool.at[layer_ids, page_ids[None, :]].set(
+            a.reshape(c.n_layers, n, page_size, -1), mode="drop")
+
+    return logits, {"k": paged(ks, cache["k"]), "v": paged(vs, cache["v"])}
 
 
 def decode_step(model, params: Params, cache: KVCache,
@@ -169,6 +204,7 @@ def decode_step(model, params: Params, cache: KVCache,
     page_tables: (B, max_pages) int32, -1 for unassigned slots.
     active: (B,) bool — inactive (padding) rows neither write cache
     nor produce meaningful logits.
+    cache: donate it, or both pools are copied whole.
 
     Returns (logits (B, vocab) f32, updated cache).
     """
@@ -178,8 +214,6 @@ def decode_step(model, params: Params, cache: KVCache,
     ck, cv = cache["k"], cache["v"]
     num_pages = ck.shape[1]
     B = tokens.shape[0]
-    max_pages = page_tables.shape[1]
-    span = max_pages * page_size
 
     x = model._embed_lookup(params["embed"].astype(ad),
                             tokens[:, None])               # (B, 1, e)
@@ -189,14 +223,9 @@ def decode_step(model, params: Params, cache: KVCache,
         page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
     wr_page = jnp.where(active & (my_page >= 0), my_page, num_pages)
     wr_slot = positions % page_size
-    # context mask: cache slot j is visible iff j <= position and its
-    # page is assigned (own-position k/v is written before the read)
-    flat = jnp.arange(span)
-    assigned = jnp.repeat(page_tables >= 0, page_size, axis=1)
-    mask = (flat[None, :] <= positions[:, None]) & assigned
-    gather_pt = jnp.clip(page_tables, 0, num_pages - 1)
-    groups = c.n_heads // c.kv_heads
-    scale = 1.0 / (hd ** 0.5)
+    # cache slot j is visible iff j <= position and its page is assigned
+    # (own-position k/v is written before the read)
+    lengths = jnp.where(active, positions + 1, 0)
 
     layers = params["layers"]
     for i in range(c.n_layers):
@@ -206,21 +235,13 @@ def decode_step(model, params: Params, cache: KVCache,
         q = apply_rope_cached(q, cos, sin)
         k = apply_rope_cached(k, cos, sin)
         ck = ck.at[i, wr_page, wr_slot].set(
-            k[:, 0].astype(ck.dtype), mode="drop")
+            k[:, 0].astype(ck.dtype).reshape(B, -1), mode="drop")
         cv = cv.at[i, wr_page, wr_slot].set(
-            v[:, 0].astype(cv.dtype), mode="drop")
-        keys = ck[i][gather_pt].reshape(B, span, c.kv_heads, hd)
-        vals = cv[i][gather_pt].reshape(B, span, c.kv_heads, hd)
-        qg = q[:, 0].reshape(B, c.kv_heads, groups, hd)
-        scores = jnp.einsum(
-            "bkgd,bskd->bkgs", qg.astype(jnp.float32),
-            keys.astype(jnp.float32)) * scale
-        scores = jnp.where(mask[:, None, None, :], scores,
-                           jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bkgs,bskd->bkgd", probs,
-                         vals.astype(jnp.float32)).astype(ad)
-        out = out.reshape(B, 1, c.n_heads * hd)
+            v[:, 0].astype(cv.dtype).reshape(B, -1), mode="drop")
+        out = _paged.paged_decode_attention(
+            q[:, 0], ck, cv, i, page_tables, lengths,
+            mesh=model.kernel_mesh)
+        out = out.astype(ad).reshape(B, 1, c.n_heads * hd)
         x = x + out @ layer["wo"].astype(ad)
         x = _mlp(model, layer, x)
 

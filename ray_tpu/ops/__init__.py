@@ -14,4 +14,8 @@ from ray_tpu.ops.attention import (  # noqa: F401
     flash_attention,
     mha_reference,
 )
+from ray_tpu.ops.paged_attention import (  # noqa: F401
+    paged_attention_reference,
+    paged_decode_attention,
+)
 from ray_tpu.ops.ring_attention import ring_attention  # noqa: F401

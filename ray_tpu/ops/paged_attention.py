@@ -1,0 +1,290 @@
+"""Paged decode attention: one query token per lane against the KV pages
+the lane holds, as a Pallas TPU kernel.
+
+The serving engine's cache is a pool of pages `(layers, pages,
+page_size, kv_heads * head_dim)` (`models.decode.init_paged_cache`); a
+lane owns the pages its table lists, in any order. The decode step's
+attention has to move what the lanes hold and no more: the kernel leaves
+the pool in HBM, takes page tables, lengths and the layer index by scalar
+prefetch, and copies in only the live pages of each lane, one DMA a page
+(a page is contiguous, and a kv head is whole lanes of its rows),
+double-buffered in blocks of `BLOCK_PAGES` pages. Lengths are data: one
+compiled program whatever the lanes hold.
+
+Numerics follow `attention._flash_fwd_kernel`: keys and values stay in
+the pool's dtype for the MXU, scores, running max and sum and the
+accumulator are float32, probabilities are rounded to the value dtype for
+the second matmul. The `n_heads // kv_heads` query heads of a kv head are
+one matmul's rows.
+
+`paged_attention_reference` is the gather + masked einsum the decode step
+ran before: the off-TPU path and the ground truth of the tests, which
+reach the kernel through the Pallas interpreter
+(`paged_decode_attention_kernel`).
+"""
+from __future__ import annotations
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
+from ray_tpu.ops.dispatch import on_tpu, shard_kernel
+
+# The kernel's name on the device's clock (see attention.KERNEL_FWD).
+KERNEL_PAGED_DECODE = "paged_decode_attn"
+KERNEL_PAGED_SCOPE = "paged_decode_attention"
+
+# Pages copied in and attended to per inner step of the kernel.
+BLOCK_PAGES = 8
+# Blocks in VMEM at once: one attended to, the next on its way (more
+# bought nothing on a v5e: a block's matmuls, not its copies, take longest).
+BLOCK_SLOTS = 2
+
+
+def paged_decode_tiles(head_dim: int, page_size: int, dtype) -> bool:
+    """Whether the kernel tiles these shapes: a head is whole lanes of a
+    pool row, and a page is whole `(sublane, 128)` tiles of the pool's
+    dtype, so that a page lands in VMEM with one contiguous copy and a
+    block of pages reads as one `(positions, head_dim)` matrix a head."""
+    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return head_dim % 128 == 0 and page_size % sublanes == 0
+
+
+# ------------------------------------------------------------- reference
+def paged_attention_reference(q, k_pool, v_pool, layer, page_tables,
+                              lengths):
+    """Gather every table entry, mask, softmax in float32.
+
+    q: (B, n_heads, hd); pools (layers, pages, page, kv * hd);
+    page_tables (B, max_pages) int32, -1 unassigned; lengths (B,) int32:
+    positions `< length` on assigned pages are visible.
+    Returns (B, n_heads, hd) in q's dtype; a lane that sees nothing
+    gets zeros.
+    """
+    B, n_heads, hd = q.shape
+    num_pages, page = k_pool.shape[1:3]
+    kvh = k_pool.shape[3] // hd
+    span = page_tables.shape[1] * page
+    pt = jnp.clip(page_tables, 0, num_pages - 1)
+    keys = k_pool[layer][pt].reshape(B, span, kvh, hd)
+    vals = v_pool[layer][pt].reshape(B, span, kvh, hd)
+    mask = ((jnp.arange(span)[None, :] < lengths[:, None])
+            & jnp.repeat(page_tables >= 0, page, axis=1))
+    qg = q.reshape(B, kvh, n_heads // kvh, hd)
+    scores = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),
+                        keys.astype(jnp.float32)) * (1.0 / hd ** 0.5)
+    scores = jnp.where(mask[:, None, None, :], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bkgs,bskd->bkgd", probs, vals.astype(jnp.float32))
+    out = jnp.where(mask.any(axis=1)[:, None, None, None], out, 0.0)
+    return out.astype(q.dtype).reshape(B, n_heads, hd)
+
+
+# ---------------------------------------------------------------- kernel
+def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
+                         q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, acc_ref, m_ref, l_ref, *,
+                         sm_scale: float, page_size: int,
+                         block_pages: int, max_pages: int):
+    b = pl.program_id(0)
+    slots = k_buf.shape[0]
+    kvh, _, hd = q_ref.shape[1:]
+    bk = block_pages * page_size
+    layer = layer_ref[0]
+    length = len_ref[b]
+    n_pages = jnp.minimum(pl.cdiv(length, page_size), max_pages)
+    n_blocks = pl.cdiv(n_pages, block_pages)
+
+    def page_at(blk, p):
+        """(table entry, whether the lane holds a page there)."""
+        idx = blk * block_pages + p
+        page = pt_ref[b * max_pages + jnp.minimum(idx, max_pages - 1)]
+        return page, (idx < n_pages) & (page >= 0)
+
+    def each_copy(blk, act):
+        """`act` on the copy of every page of block `blk` the lane holds,
+        into the block's slot."""
+        slot = blk % slots
+        for p in range(block_pages):
+            page, live = page_at(blk, p)
+
+            @pl.when(live)
+            def _():
+                for pool, buf, sem in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                    act(pltpu.make_async_copy(
+                        pool.at[layer, page], buf.at[slot, p],
+                        sems.at[slot, sem]))
+
+    def start(blk):
+        each_copy(blk, lambda copy: copy.start())
+
+    def wait(blk):
+        each_copy(blk, lambda copy: copy.wait())
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(b == 0)
+    def _():
+        # a page that is not copied in keeps what its buffer held: the
+        # values of an earlier block, finite, once this has run (a
+        # probability of 0 does not neutralise NaN: 0 * NaN)
+        v_buf[:] = jnp.zeros_like(v_buf)
+
+    for ahead in range(slots - 1):
+        @pl.when(ahead < n_blocks)
+        def _():
+            start(ahead)
+
+    def body(blk, carry):
+        @pl.when(blk + slots - 1 < n_blocks)
+        def _():
+            start(blk + slots - 1)
+
+        wait(blk)
+        slot = blk % slots
+        pos = blk * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        page_of = lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page_size
+        seen = pos < length                              # (1, bk)
+        for p in range(block_pages):
+            _, live = page_at(blk, p)
+            seen = seen & ((page_of != p) | live)
+        for h in range(kvh):
+            q = q_ref[0, h]                              # (G, hd)
+            head = slice(h * hd, (h + 1) * hd)
+            k = k_buf[slot, :, :, head].reshape(bk, hd)
+            v = v_buf[slot, :, :, head].reshape(bk, hd)
+            s = lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # (G, bk)
+            s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
+            m_prev = m_ref[h, :, :1]                     # (G, 1)
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # (a block with nothing to see leaves m at the mask's value)
+            prob = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            l_new = alpha * l_ref[h, :, :1] + jnp.sum(
+                prob, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + lax.dot_general(
+                prob.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        return carry
+
+    lax.fori_loop(0, n_blocks, body, 0)
+
+    l = l_ref[:, :, :1]
+    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype)
+
+
+def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
+                  interpret: bool, mesh=None):
+    B, n_heads, hd = q.shape
+    page_size, kvh = k_pool.shape[2], k_pool.shape[3] // hd
+    if n_heads % kvh:
+        raise ValueError(
+            f"num_heads ({n_heads}) must be a multiple of num_kv_heads "
+            f"({kvh})")
+    if not paged_decode_tiles(hd, page_size, k_pool.dtype):
+        raise ValueError(
+            f"the paged decode kernel does not tile head_dim {hd} with "
+            f"{page_size}-position pages of {k_pool.dtype}")
+    group = n_heads // kvh
+    qg = q.reshape(B, kvh, group, hd)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    if mesh is not None:
+        # kv heads over tp, as models.decode.cache_sharding lays the pool
+        tp = "tp" if mesh.shape.get("tp", 1) > 1 else None
+        spec_q, spec_pool = P(None, tp, None, None), P(None, None, None, tp)
+        out = shard_kernel(
+            functools.partial(_paged_decode_call, interpret=interpret),
+            mesh, (spec_q, spec_pool, spec_pool, P(), P(), P()),
+            spec_q)(qg, k_pool, v_pool, layer, page_tables, lengths)
+    else:
+        out = _paged_decode_call(qg, k_pool, v_pool, layer, page_tables,
+                                 lengths, interpret=interpret)
+    return out.reshape(B, n_heads, hd)
+
+
+# jitted: the decode step calls it once a layer with the same shapes, the
+# layer's index an argument, so the kernel is traced and lowered once a
+# program and not once a layer (24 of them cost a replica 8 s of set-up)
+@functools.partial(jax.jit, static_argnames="interpret")
+def _paged_decode_call(qg, k_pool, v_pool, layer, page_tables, lengths,
+                       interpret: bool):
+    B, kvh, group, hd = qg.shape
+    page_size = k_pool.shape[2]
+    max_pages = page_tables.shape[1]
+    block_pages = min(BLOCK_PAGES, max_pages)
+    kernel = functools.partial(
+        _paged_decode_kernel, sm_scale=1.0 / math.sqrt(hd),
+        page_size=page_size, block_pages=block_pages, max_pages=max_pages)
+    block = (1, kvh, group, hd)
+    buf = (BLOCK_SLOTS, block_pages, page_size, kvh * hd)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec(block, lambda b, *_: (b, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(block, lambda b, *_: (b, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM(buf, k_pool.dtype),
+                pltpu.VMEM(buf, v_pool.dtype),
+                pltpu.SemaphoreType.DMA((BLOCK_SLOTS, 2)),
+                pltpu.VMEM((kvh, group, hd), jnp.float32),    # acc
+                pltpu.VMEM((kvh, group, 128), jnp.float32),   # running max
+                pltpu.VMEM((kvh, group, 128), jnp.float32),   # running sum
+            ]),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        interpret=interpret,
+        name=KERNEL_PAGED_DECODE,
+    )
+    with jax.named_scope(KERNEL_PAGED_SCOPE):
+        return call(layer, lengths.astype(jnp.int32),
+                    page_tables.astype(jnp.int32).reshape(-1),
+                    qg, k_pool, v_pool)
+
+
+def paged_decode_attention(q, k_pool, v_pool, layer, page_tables, lengths,
+                           mesh=None):
+    """Dispatching entry point: the compiled kernel when the target
+    platform is a TPU and the shapes are ones it tiles
+    (`paged_decode_tiles`), the gather + einsum reference elsewhere.
+    Shapes as `paged_attention_reference`; `mesh`: the mesh of more than
+    one device the pool is sharded over (`ops.dispatch.kernel_mesh`)."""
+    if uses_kernel(q.shape[-1], k_pool.shape[2], k_pool.dtype):
+        return _paged_decode(q, k_pool, v_pool, layer, page_tables,
+                             lengths, False, mesh)
+    return paged_attention_reference(q, k_pool, v_pool, layer,
+                                     page_tables, lengths)
+
+
+def uses_kernel(head_dim: int, page_size: int, dtype) -> bool:
+    """What `paged_decode_attention` decides, for a caller that reports
+    it: decided by what can be seen, the platform being traced for and
+    the shapes, and by nothing else."""
+    return on_tpu() and paged_decode_tiles(head_dim, page_size, dtype)
+
+
+def paged_decode_attention_kernel(q, k_pool, v_pool, layer, page_tables,
+                                  lengths, mesh=None):
+    """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
+    return _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
+                         not on_tpu(), mesh)

@@ -148,6 +148,8 @@ class EngineCore:
         self.params = params
         self._cache = _dec.init_paged_cache(config, self.num_pages,
                                             self.page_size, mesh=mesh)
+        # both programs update the pool in place: the cache argument is
+        # donated (whoever holds the old one holds a deleted buffer), and
         # on a mesh every step hands the cache back as it lay, whatever
         # the partitioner would have preferred for one call
         self._jit = jax.jit if mesh is None else functools.partial(
@@ -166,7 +168,11 @@ class EngineCore:
             return _dec.decode_step(self.model, params, cache, tokens,
                                     positions, pts, active,
                                     self.page_size)
-        self._decode_fn = self._jit(_step)
+        self._decode_fn = self._jit(_step, donate_argnums=(1,))
+        # which attention the decode step holds (the paged kernel's name
+        # or "einsum"), decided where the step is traced: here
+        self._attention = _dec.decode_attention(
+            config, self.page_size, self._cache["k"].dtype)
         self._devices = (list(mesh.devices.flat) if mesh is not None
                          else jax.devices()[:1])
         chips = os.environ.get("TPU_VISIBLE_CHIPS", "")
@@ -175,9 +181,10 @@ class EngineCore:
             "device_kind": self._devices[0].device_kind,
             "device_ids": [d.id for d in self._devices],
             "chips": [int(c) for c in chips.split(",") if c]}
-        # what one decode step gathers per layer, whatever its lanes hold
-        self._read_positions = (self.max_batch * self.max_pages_per_seq
-                                * self.page_size)
+        # what one decode step's einsum gathers per layer, whatever its
+        # lanes hold; the kernel reads the pages they hold
+        self._table_positions = (self.max_batch * self.max_pages_per_seq
+                                 * self.page_size)
         self.counters = {
             "admitted": 0, "evictions": 0, "finished": 0, "tokens": 0,
             "steps": 0,
@@ -185,9 +192,11 @@ class EngineCore:
             # their bucket (what the prefill programs computed)
             "prefill_tokens": 0, "prefill_padded_tokens": 0,
             "prefill_programs": 0,      # prefill functions built
-            # decode dispatches, and the lanes that held a sequence
-            "decode_steps": 0, "decode_lane_steps": 0,
-            # cache positions those lanes held / the dispatches gathered
+            # decode dispatches, those whose attention was the paged
+            # kernel, and the lanes that held a sequence
+            "decode_steps": 0, "decode_kernel_steps": 0,
+            "decode_lane_steps": 0,
+            # cache positions those lanes held / the dispatches read
             "kv_positions_live": 0, "kv_positions_read": 0}
         # the steps that took SLOW_STEP_S or more: wall seconds, when,
         # the decode batch and the seconds in each phase
@@ -273,7 +282,7 @@ class EngineCore:
                 return self._dec.prefill(self.model, params, tokens,
                                          true_len, page_table, cache,
                                          self.page_size)
-            fn = self._jit(_pre)
+            fn = self._jit(_pre, donate_argnums=(4,))
             self._prefill_fns[s_pad] = fn
             self.counters["prefill_programs"] += 1
         return fn
@@ -417,24 +426,28 @@ class EngineCore:
             positions = np.zeros((B,), np.int32)
             pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
             active = np.zeros((B,), bool)
-            live = 0
+            live = held = 0
             for i, seq in enumerate(batch):
                 tokens[i] = seq.emitted[-1]
                 positions[i] = seq.total_len - 1
                 pts[i] = self._page_table(seq)
                 active[i] = True
                 live += seq.total_len
+                held += pages_needed(seq.total_len, self.page_size)
             args = (jnp.asarray(tokens), jnp.asarray(positions),
                     jnp.asarray(pts), jnp.asarray(active))
+        kernel = self._attention != "einsum"
+        # the kernel copies in each lane's live pages, whole
+        read = held * self.page_size if kernel else self._table_positions
         c["decode_steps"] += 1
+        c["decode_kernel_steps"] += int(kernel)
         c["decode_lane_steps"] += len(batch)
         c["kv_positions_live"] += live
-        c["kv_positions_read"] += self._read_positions
+        c["kv_positions_read"] += read
         # an annotation's attributes are fixed when it opens, so the
         # step's counts ride the first span that opens once they are known
         with _Phase(phases, _sp.DISPATCH, lanes=len(batch),
-                    live_positions=live,
-                    read_positions=self._read_positions):
+                    live_positions=live, read_positions=read):
             logits, self._cache = self._decode_fn(
                 self.params, self._cache, *args)
         with _Phase(phases, _sp.FETCH):
@@ -461,10 +474,13 @@ class EngineCore:
     def device_stats(self) -> dict:
         """Where this engine runs, as JAX reports it, plus the chips the
         scheduler granted the process (device ids are per process: four
-        one-chip replicas all hold device 0, each of another chip)."""
-        return {**self._device_info, "bytes_in_use": [
-            (d.memory_stats() or {}).get("bytes_in_use")
-            for d in self._devices]}
+        one-chip replicas all hold device 0, each of another chip), and
+        which attention its compiled decode step holds."""
+        return {**self._device_info,
+                "decode_attention": self._attention,
+                "bytes_in_use": [
+                    (d.memory_stats() or {}).get("bytes_in_use")
+                    for d in self._devices]}
 
     def stats(self) -> dict:
         return {"waiting": len(self._waiting),

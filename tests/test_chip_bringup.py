@@ -294,7 +294,8 @@ def test_engine_on_a_mesh_shards_params_and_cache():
         cache = eng.core._cache["k"]
         assert cache.sharding.spec[3] == "tp"
         assert len({s.data.shape for s in cache.addressable_shards}) == 1
-        assert cache.addressable_shards[0].data.shape[3] == 1  # 2 kv / tp
+        # a row holds 2 kv heads of 16 side by side: one head a shard
+        assert cache.addressable_shards[0].data.shape[3] == 16
         wq = eng.core.params["layers"]["wq"]
         assert wq.addressable_shards[0].data.shape[-1] == wq.shape[-1] // 2
         st = eng.engine_stats()

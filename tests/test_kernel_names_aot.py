@@ -1,9 +1,11 @@
-"""The four Pallas kernels compiled ahead of time for a v5e, with no chip
+"""The Pallas kernels compiled ahead of time for a v5e, with no chip
 attached: each must carry its own name as the name of its custom-call
 instruction, which is what an `XLA Ops` event of a profiler trace is
 called and what `benchmarks/metrics/kernel.flash_*_roofline.train.py`
 and the ledger's `device_ops` find it by. Also a guard that the kernels
-still compile for the chip at a real head size.
+still compile for the chip at a real head size, and that the decode
+step compiled for the chip holds the paged kernel and updates the pool
+in place.
 
 The topology is described inside a module-scoped fixture (one process at
 a time may load libtpu: nothing here touches it at import time), and all
@@ -17,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from ray_tpu.ops import attention, norms
+from ray_tpu.ops import attention, norms, paged_attention
 from ray_tpu.ops.dispatch import compute_platform
 
 
@@ -32,11 +34,23 @@ def topo():
 
 
 @pytest.fixture(scope="module")
-def compiled_text(topo):
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    and cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compiled_text(topo, no_compile_cache):
     """One train-like program: a rematted layer of saveable flash
     attention (GQA, 8 heads over 4 of 128) and an rms_norm, forward and
-    backward, so that every kernel is in it once or more."""
-    from jax.experimental.compilation_cache import compilation_cache
+    backward, so that every training kernel is in it once or more."""
     one_chip = SingleDeviceSharding(topo.devices[0])
 
     def shape(*dims):
@@ -49,20 +63,11 @@ def compiled_text(topo):
         y = jax.checkpoint(layer)(q, k, v, x)
         return y.astype(jnp.float32).sum()
 
-    # a compile for a described chip is written to the persistent cache
-    # and cannot be read back without one: keep it out
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        with compute_platform("tpu"):
-            step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))
-            return step.trace(shape(1, 8, 512, 128), shape(1, 4, 512, 128),
-                              shape(1, 4, 512, 128), shape(512, 1024),
-                              shape(1024)).lower().compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was)
-        compilation_cache.reset_cache()
+    with compute_platform("tpu"):
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))
+        return step.trace(shape(1, 8, 512, 128), shape(1, 4, 512, 128),
+                          shape(1, 4, 512, 128), shape(512, 1024),
+                          shape(1024)).lower().compile().as_text()
 
 
 def kernel_names(text: str):
@@ -87,3 +92,87 @@ def test_no_kernel_is_named_after_its_enclosing_call(compiled_text):
     # the benchmark's label for such an event is 'kernel:<name>'
     assert not {"closed_call", "checkpoint", "rematted_computation"} \
         & set(names)
+
+
+# ------------------------------------------------------ the decode step
+# a pool of the cells' 2048 pages: a small one the compiler would move
+# into fast memory whole, which no deployment's fits
+LANES, PAGE, PAGES = 8, 16, 2048
+
+
+def _compile_decode_step(devices, tp: int):
+    """The decode step as `EngineCore` jits it (the cache donated, on a
+    mesh handed back as it lay): two layers at the serving cells' head
+    shapes (16 heads over 8 kv heads of 128, 16-token bf16 pages, 8
+    lanes), on one chip or over `tp` of them. Returns (compiled, the
+    pool's shape on a device)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ray_tpu.models import Transformer, TransformerConfig, decode
+    from ray_tpu.parallel.mesh import MeshSpec
+    from ray_tpu.parallel.sharding import param_shardings
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=2048, n_layers=2, n_heads=16, n_kv_heads=8,
+        d_ff=256, max_seq_len=512, remat=False, dtype="bfloat16",
+        param_dtype="bfloat16")
+    pool = (cfg.n_layers, PAGES, PAGE, cfg.kv_heads * cfg.head_dim)
+    abstract = jax.eval_shape(Transformer(cfg).init, jax.random.PRNGKey(0))
+    if tp == 1:
+        model, out = Transformer(cfg), None
+        whole = pooled = SingleDeviceSharding(devices[0])
+        placed = jax.tree.map(lambda a: whole, abstract)
+    else:
+        mesh = MeshSpec(dp=1, tp=tp).build(list(devices)[:tp])
+        model = Transformer(cfg, mesh=mesh)
+        whole = NamedSharding(mesh, P())
+        pooled = decode.cache_sharding(cfg, mesh)
+        placed = param_shardings(mesh, model.param_logical_axes())
+        out = (None, {"k": pooled, "v": pooled})
+
+    def shape(dtype, *dims, sharding=whole):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    params = jax.tree.map(
+        lambda a, s: shape(a.dtype, *a.shape, sharding=s), abstract, placed)
+    cache = {name: shape(jnp.bfloat16, *pool, sharding=pooled)
+             for name in "kv"}
+
+    def _step(params, cache, tokens, positions, pts, active):
+        return decode.decode_step(model, params, cache, tokens, positions,
+                                  pts, active, PAGE)
+
+    with compute_platform("tpu"):
+        assert decode.decode_attention(cfg, PAGE) == "paged_decode_attn"
+        compiled = jax.jit(
+            _step, donate_argnums=(1,), out_shardings=out).trace(
+            params, cache, shape(jnp.int32, LANES),
+            shape(jnp.int32, LANES),
+            shape(jnp.int32, LANES, cfg.max_seq_len // PAGE),
+            shape(jnp.bool_, LANES)).lower().compile()
+    return compiled, pool[:3] + (pool[3] // tp,)
+
+
+@pytest.fixture(scope="module", params=[1, 4], ids=["one-chip", "tp4"])
+def decode_step(request, topo, no_compile_cache):
+    return _compile_decode_step(topo.devices, request.param)
+
+
+def test_decode_step_holds_the_paged_kernel(decode_step):
+    compiled, _ = decode_step
+    names = kernel_names(compiled.as_text())
+    # one call a layer (on a mesh: of each device's own kv heads), named
+    # for the trace's `kernel:paged_decode_attn`
+    assert names.count(paged_attention.KERNEL_PAGED_DECODE) == 2
+
+
+def test_decode_step_for_the_chip_updates_the_pool_in_place(decode_step):
+    compiled, pool = decode_step
+    nbytes = 2 * 2 * pool[0] * pool[1] * pool[2] * pool[3]   # k and v
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    shape = ",".join(map(str, pool))
+    made = re.findall(rf"= \w+\[{shape}\]\S* ([\w\-]+)\(",
+                      compiled.as_text())
+    # no copy of the pool, no slice of it made for the kernel, no gather
+    # of its shards: the scatters of the four writes (2 layers x k, v),
+    # in fusions or bare
+    assert made and set(made) <= {"parameter", "scatter", "fusion",
+                                  "bitcast", "get-tuple-element"}, made

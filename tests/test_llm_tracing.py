@@ -96,10 +96,35 @@ def test_core_spans_and_parents(tiny_model, recorder):
     assert [e[7] for e in pre] == [
         {"rid": "a", "tokens": 5, "bucket": 16, "new_program": 1}]
     disp = [e[7] for e in evs if e[4] == sp.DISPATCH]
+    # the einsum (the tiny model's heads are no shape the kernel tiles)
+    # gathers every lane's whole table, whatever the lanes hold
     read = 2 * core.max_pages_per_seq * 8
     assert disp == [
         {"lanes": 1, "live_positions": 6, "read_positions": read},
         {"lanes": 1, "live_positions": 7, "read_positions": read}]
+
+
+def test_read_positions_under_the_kernel_are_the_pages_held(tiny_model,
+                                                            recorder):
+    """What the counts mean where the decode step holds the paged kernel
+    (the test says so in the engine's stead: the compiled step here is
+    the einsum still): each lane's live pages, whole."""
+    from ray_tpu.ops.paged_attention import KERNEL_PAGED_DECODE
+    core = _core(tiny_model)
+    core._attention = KERNEL_PAGED_DECODE
+    core.submit(list(range(1, 6)), max_tokens=3, rid="a")
+    core.submit(list(range(1, 21)), max_tokens=2, rid="b")
+    _run(core)
+    disp = [e[7] for e in _mine(recorder) if e[4] == sp.DISPATCH]
+    # 8-position pages: a holds 6 then 7 positions (1 page), b 21 (3)
+    assert disp == [
+        {"lanes": 2, "live_positions": 6 + 21, "read_positions": 8 + 24},
+        {"lanes": 1, "live_positions": 7, "read_positions": 8}]
+    st = core.stats()
+    assert st["kv_positions_read"] == 8 + 24 + 8
+    assert st["kv_positions_live"] == 6 + 21 + 7
+    assert st["decode_kernel_steps"] == st["decode_steps"] == 2
+    assert core.device_stats()["decode_attention"] == "paged_decode_attn"
 
 
 def test_request_spans_share_one_trace(tiny_model, recorder):
@@ -138,12 +163,16 @@ def test_counters_on_a_fixed_schedule(tiny_model):
     assert {k: st[k] for k in (
         "steps", "admitted", "finished", "tokens", "prefill_tokens",
         "prefill_padded_tokens", "prefill_programs", "decode_steps",
-        "decode_lane_steps", "kv_positions_live", "kv_positions_read")} == {
+        "decode_kernel_steps", "decode_lane_steps", "kv_positions_live",
+        "kv_positions_read")} == {
         "steps": 2, "admitted": 2, "finished": 2, "tokens": 5,
         "prefill_tokens": 25, "prefill_padded_tokens": 48,
         "prefill_programs": 2, "decode_steps": 2, "decode_lane_steps": 3,
+        # the einsum ran both (tiny heads): the old constant a step
+        "decode_kernel_steps": 0,
         # step 1: a holds 5 + 1, b 20 + 1; step 2: a holds 5 + 2
         "kv_positions_live": 6 + 21 + 7, "kv_positions_read": 2 * read}
+    assert core.device_stats()["decode_attention"] == "einsum"
     # (a step that compiles may well take a second: slow_steps keeps it)
     assert all(s["step"] == 1 for s in st["slow_steps"])
     # a third request in a bucket already built: no new program

@@ -1,35 +1,25 @@
 """A configuration file (`benchmarks/configs/<name>.json`, published key
-names) read into the sizes the yardstick needs, and into the program's own
-`TransformerConfig` for the system under test."""
+names) and the model module it names (`benchmarks/models/<model>.py`): the
+one place the yardstick learns an architecture's sizes, weights, reference,
+required operations and the program's own config for it. Nothing here, in
+`run.py`, `tools/` or `metrics/` names a model module or branches on one."""
 from __future__ import annotations
 
-import dataclasses
+import functools
+import importlib.util
 import json
 import os
+import sys
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-@dataclasses.dataclass(frozen=True)
-class Sizes:
-    vocab: int
-    d_model: int
-    layers: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    rope_theta: float
-    norm_eps: float
-    tied: bool
-
-    @property
-    def q_dim(self) -> int:
-        return self.heads * self.head_dim
-
-    @property
-    def kv_dim(self) -> int:
-        return self.kv_heads * self.head_dim
+# What a model module has to offer (benchmarks/README.md, "An architecture",
+# says what each is): a module without one of these is refused when loaded,
+# on the CPU, and not deep inside a run on the chip.
+INTERFACE = ("Sizes", "sizes", "tiny", "weight_shapes", "program_config",
+             "train_model", "logits_fn", "loss_fn", "reference_rows",
+             "matmul_params", "attention_flops_per_token",
+             "train_flops_per_token", "param_count")
 
 
 def load_config(name: str) -> dict:
@@ -37,38 +27,33 @@ def load_config(name: str) -> dict:
         return json.load(f)
 
 
-def sizes(cfg: dict) -> Sizes:
-    return Sizes(vocab=cfg["vocab_size"], d_model=cfg["hidden_size"],
-                 layers=cfg["num_hidden_layers"],
-                 heads=cfg["num_attention_heads"],
-                 kv_heads=cfg["num_key_value_heads"],
-                 head_dim=cfg["head_dim"], d_ff=cfg["intermediate_size"],
-                 rope_theta=float(cfg["rope_theta"]),
-                 norm_eps=float(cfg["rms_norm_eps"]),
-                 tied=bool(cfg["tie_word_embeddings"]))
+@functools.lru_cache(maxsize=None)
+def _model_module(name: str):
+    """Imported by path, once a process: its `Sizes` is a static argument
+    of jitted programs, and a second copy of the class would compile them
+    again."""
+    path = os.path.join(HERE, "models", name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no model module {path}")
+    spec = importlib.util.spec_from_file_location(
+        "bench_model_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod    # dataclasses look their module up there
+    try:
+        spec.loader.exec_module(mod)
+        missing = [n for n in INTERFACE if not hasattr(mod, n)]
+        if missing:
+            raise AttributeError(f"model module {path} lacks {missing}")
+    except BaseException:
+        del sys.modules[spec.name]
+        raise
+    return mod
 
 
-def tiny(cfg: dict) -> dict:
-    """The same file at rehearsal size: control flow on the CPU, never a
-    measurement. Ratios of heads stay; every width shrinks."""
-    small = dict(cfg)
-    small.update(hidden_size=64, num_hidden_layers=2, num_attention_heads=4,
-                 num_key_value_heads=2, head_dim=16, intermediate_size=128,
-                 vocab_size=512)
-    return small
-
-
-def program_config(cfg: dict, max_seq_len: int, **extra):
-    """The program's TransformerConfig for this file. The program derives
-    head_dim as d_model / n_heads, which must agree with the file."""
-    from ray_tpu.models.config import TransformerConfig
-    s = sizes(cfg)
-    if s.d_model != s.heads * s.head_dim:
-        raise ValueError("the program cannot hold head_dim * heads != hidden")
-    dtype = cfg.get("torch_dtype", "bfloat16")
-    return TransformerConfig(
-        vocab_size=s.vocab, d_model=s.d_model, n_layers=s.layers,
-        n_heads=s.heads, n_kv_heads=s.kv_heads, d_ff=s.d_ff,
-        max_seq_len=max_seq_len, rope_theta=s.rope_theta,
-        norm_eps=s.norm_eps, tie_embeddings=s.tied, dtype=dtype,
-        param_dtype=dtype, **extra)
+def load_model(cfg: dict):
+    """The module of the model a configuration names. A file that names
+    none is an error, not a default."""
+    if "model" not in cfg:
+        raise KeyError(f"configuration {cfg.get('name')!r} names no "
+                       f"\"model\" (a file of benchmarks/models/)")
+    return _model_module(cfg["model"])

@@ -1,30 +1,9 @@
-"""Operations and bytes the algorithms require, from shapes alone. What the
-program recomputes (remat, the two-kernel backward's second QK^T and dP)
-does not count, and neither does the embedding lookup, a gather."""
+"""Operations and bytes the kernels' algorithms require, from shapes alone,
+and the least time a chip could take for them. What the program recomputes
+(remat, the two-kernel backward's second QK^T and dP) does not count. A
+whole model's counts (`matmul_params`, `train_flops_per_token`) are its
+module's, under `benchmarks/models/`."""
 from __future__ import annotations
-
-from benchmarks.harness.modelcfg import Sizes
-
-
-def matmul_params(s: Sizes) -> int:
-    """Parameters that multiply activations: every layer's projections and
-    MLP, and the output head. Not the embedding table, not the norms."""
-    per_layer = (s.d_model * s.q_dim + 2 * s.d_model * s.kv_dim
-                 + s.q_dim * s.d_model + 3 * s.d_model * s.d_ff)
-    return s.layers * per_layer + s.d_model * s.vocab
-
-
-def attention_flops_per_token(s: Sizes, seq_len: int,
-                              passes: int = 3) -> float:
-    """Causal attention per token and layer: QK^T and PV are 2 * seq * q_dim
-    multiply-adds each over the causal half, so 2 * seq * q_dim operations
-    forward; the backward is twice that (`passes` 3 = forward + backward)."""
-    return passes * 2.0 * seq_len * s.q_dim * s.layers
-
-
-def train_flops_per_token(s: Sizes, seq_len: int) -> float:
-    """Forward + backward: 6 per matmul parameter plus causal attention."""
-    return 6.0 * matmul_params(s) + attention_flops_per_token(s, seq_len)
 
 
 def flash_call(batch: int, heads: int, kv_heads: int, seq: int,
@@ -44,8 +23,28 @@ def flash_call(batch: int, heads: int, kv_heads: int, seq: int,
             "fwd_bytes": float(fwd_bytes), "bwd_bytes": float(bwd_bytes)}
 
 
+def paged_decode_call(live_positions: int, lanes: int, layers: int,
+                      kv_dim: int, q_dim: int, itemsize: int = 2) -> dict:
+    """Decode attention over a paged cache, all layers, as the algorithm
+    needs it, for one step or (the counts being sums) for many: every live
+    position's key and value (`kv_dim` numbers each) read once a layer, each
+    lane's query in and output out; QK^T and PV are 2 * live * q_dim
+    operations each a layer. A page's unused tail, which a kernel that
+    copies whole pages reads too, does not count."""
+    kv = 2 * live_positions * kv_dim * itemsize
+    q_and_o = 2 * lanes * q_dim * itemsize
+    return {"flops": 2.0 * 2.0 * live_positions * q_dim * layers,
+            "bytes": float(layers * (kv + q_and_o))}
+
+
 def roofline_seconds(flops: float, nbytes: float, peaks: dict):
     """The least time the chip could take and which peak bounds it."""
     t_ops = flops / peaks["bf16_flops"]
     t_mem = nbytes / peaks["hbm_bytes_per_s"]
     return (t_ops, "ops") if t_ops >= t_mem else (t_mem, "bytes")
+
+
+def roofline_share(flops: float, nbytes: float, spent_s: float,
+                   peaks: dict) -> float:
+    """Per cent of its roofline: the least time over the time spent."""
+    return 100.0 * roofline_seconds(flops, nbytes, peaks)[0] / spent_s

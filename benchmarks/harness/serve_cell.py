@@ -17,7 +17,7 @@ import numpy as np
 
 from benchmarks.harness import stats, traffic
 from benchmarks.harness.cells import CompileCounter
-from benchmarks.harness.modelcfg import program_config, sizes
+from benchmarks.harness.modelcfg import load_model
 
 NOW = time.monotonic          # the engine's own clock (queue waits)
 
@@ -60,14 +60,12 @@ class Sent:
         self.accepted_t = None      # when generate() returned
 
 
-def build_engine(cfg: dict, params_fn):
+def build_engine(model, cfg: dict, params_fn):
     """The program's engine at the deployment the file states, holding the
     benchmark's own weights."""
     from ray_tpu.serve.llm.engine import LLMEngine
     dep = cfg["deployment"]
-    extra = {k: dep[k] for k in ("attn_block_q", "attn_block_k") if k in dep}
-    pcfg = program_config(cfg, max_seq_len=dep["context_limit"], remat=False,
-                          **extra)
+    pcfg = model.program_config(cfg, max_seq_len=dep["context_limit"])
     engine = LLMEngine(model=pcfg, seed=0, num_pages=dep["num_pages"],
                        page_size=dep["page_size"],
                        max_batch=dep["max_batch"])
@@ -252,18 +250,17 @@ def window_metrics(collector, sent, t0, seconds) -> dict:
             "no_first_token": no_first}
 
 
-def check_against_reference(engine, cfg, mix, params, requests, prompts,
-                            seed: int, window_requests: int):
+def check_against_reference(engine, model, sz, mix, params, requests,
+                            prompts, seed: int, window_requests: int):
     """Prefill-then-decode logits of the engine's own compiled programs on a
     seeded sample of the cell's requests, against the reference's full
     forward. Returns one relative RMS error per sampled request."""
     import jax.numpy as jnp
     from ray_tpu.serve.llm.engine import _bucket
     from ray_tpu.serve.llm.kv_cache import pages_needed
-    from benchmarks.harness import reference
+    from benchmarks.harness.reference import rel_rms
 
     core = engine.core
-    sz = sizes(cfg)
     steps = int(mix["check_decode_steps"])
     n_check = min(int(mix["check_requests"]), core.max_batch)
     rng = np.random.Generator(np.random.PCG64([int(seed), 0x63686b]))
@@ -313,9 +310,9 @@ def check_against_reference(engine, cfg, mix, params, requests, prompts,
     for (toks, p, _), rows in zip(seqs, got):
         padded = np.zeros((ref_len,), np.int32)
         padded[:len(toks)] = toks
-        want = reference.reference_rows(sz, params, jnp.asarray(padded),
-                                        jnp.int32(p - 1), steps + 1)
-        errors.append(reference.rel_rms(jnp.stack(rows), want))
+        want = model.reference_rows(sz, params, jnp.asarray(padded),
+                                    jnp.int32(p - 1), steps + 1)
+        errors.append(rel_rms(jnp.stack(rows), want))
     return errors, [int(requests[i].prompt_len) for i in picks]
 
 
@@ -326,13 +323,13 @@ class Served:
                  log=lambda m: None):
         t_a = time.perf_counter()
         from ray_tpu.serve.llm.stream import stream_client
-        from benchmarks.harness.weights import make_weights
-        self.cfg, self.sz, self.seed = cfg, sizes(cfg), seed
+        self.cfg, self.model, self.seed = cfg, load_model(cfg), seed
+        self.sz = self.model.sizes(cfg)
         self.requests = traffic.schedule(mix)
         self.prompts = traffic.prompt_tokens(self.requests, self.sz.vocab,
                                              seed)
-        self.engine = build_engine(cfg,
-                                   lambda: make_weights(self.sz, seed))
+        self.engine = build_engine(self.model, cfg,
+                                   lambda: self.weights(seed))
         t_b = time.perf_counter()
         self.client = stream_client()
         self.open_loop = mix["kind"] == "open_loop_schedule"
@@ -384,9 +381,13 @@ class Served:
                 "errors": dict(collector.errors), "traced": traced,
                 "queue_wait_s": waits, **w}
 
+    def weights(self, seed: int):
+        from benchmarks.harness.weights import make_weights
+        return make_weights(self.model.weight_shapes(self.sz), seed)
+
     def check(self, mix: dict):
         return check_against_reference(
-            self.engine, self.cfg, mix, self.engine.core.params,
+            self.engine, self.model, self.sz, mix, self.engine.core.params,
             self.requests, self.prompts, self.seed, len(self.in_window))
 
     def close(self):

@@ -15,7 +15,7 @@ PR 26) gives `None`, and every metric built on it leaves its line.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 from benchmarks.harness import xplane
 from benchmarks.harness.xplane import Event     # `stats` holds attributes
@@ -134,25 +134,16 @@ def per_decode_step_ms(run: dict, *names: str) -> Optional[float]:
     return 1e3 * idle / steps
 
 
-def kernel_roofline(run: dict, names: List[str], part: str
-                    ) -> Optional[float]:
-    """Share of its roofline of a flash kernel in a traced training run:
-    the device events called after `names[0]` are counted, each stands for
-    one call of what `required_ops.flash_call` gives under `part` ('fwd' or
-    'bwd'), and the least time of those calls is set against the device
-    time of the events of all `names`."""
-    from benchmarks.harness.required_ops import flash_call, roofline_seconds
+def kernel_calls(run: dict, names: List[str]
+                 ) -> Optional[Tuple[int, float]]:
+    """A kernel in a traced run: how many device events are called after
+    `names[0]` (its calls), and the device seconds of the events of all
+    `names` (a backward may be two kernels). None where the trace holds
+    none; what the calls require is the metric's to work out, from the
+    cell's shapes and `required_ops`."""
     trace = run.get("trace")
     if trace is None:
         return None
     events = [xplane.kernel_events(trace, rf"^%{n}[.\d]* = ") for n in names]
     calls, spent = len(events[0]), sum(e.dur for evs in events for e in evs)
-    if not calls or not spent:
-        return None
-    z, s = run["sizes"], run["samples"]
-    one = flash_call(s["batch"], z.heads, z.kv_heads, s["seq_len"],
-                     z.head_dim)
-    least, _bound = roofline_seconds(calls * one[part + "_flops"],
-                                     calls * one[part + "_bytes"],
-                                     run["peaks"])
-    return 100.0 * least / spent
+    return (calls, spent) if calls and spent else None

@@ -1,25 +1,16 @@
-"""A training cell: steps of `Transformer.loss` + AdamW jitted as `bench.py`
-jits them (donated state, bf16 weights and optimizer state), on the
-benchmark's own weights and tokens, with the first sequence's loss and
-gradient compared to the plain reference before the optimizer exists."""
+"""A training cell: steps of the program's loss (`train_model` of the
+configuration's model module) + AdamW jitted as `bench.py` jits them
+(donated state, bf16 weights and optimizer state), on the benchmark's own
+weights and tokens, with the first sequence's loss and gradient compared to
+the module's plain reference before the optimizer exists."""
 from __future__ import annotations
 
 import math
 import time
 
-from benchmarks.harness.modelcfg import program_config, sizes
+from benchmarks.harness.modelcfg import load_model
 
 NOW = time.perf_counter
-
-
-def build_model(cfg: dict, seq_len: int):
-    from ray_tpu.models import Transformer
-    dep = cfg["deployment"]
-    extra = {k: dep[k] for k in ("attn_block_q", "attn_block_k") if k in dep}
-    pcfg = program_config(cfg, max_seq_len=seq_len,
-                          remat=bool(dep.get("remat", False)), loss_chunk=0,
-                          **extra)
-    return Transformer(pcfg)
 
 
 def make_tokens(mix: dict, vocab: int, seed: int):
@@ -31,24 +22,25 @@ def make_tokens(mix: dict, vocab: int, seed: int):
     return jax.jit(lambda k: jax.random.randint(k, shape, 0, vocab))(key)
 
 
-def compare_fn(model, sz, control: bool = False):
-    """One program: the system's loss and gradient on one sequence, the
-    reference's on the same, and what separates them. With `control` the
-    reference in fp8 stands in the system's place."""
+def compare_fn(program, model, sz, control: bool = False):
+    """One program: the system's loss and gradient on one sequence
+    (`program.loss`), the reference's on the same (`model.loss_fn`), and
+    what separates them. With `control` the reference in fp8 stands in the
+    system's place."""
     import jax
     import jax.numpy as jnp
-    from benchmarks.harness import reference
+    from benchmarks.harness.reference import fp8_round
 
     def compare(params, seq):
         if control:
             loss_p, g_p = jax.value_and_grad(
-                lambda p: reference.loss_fn(sz, p, seq, reference.fp8_round,
-                                            remat=True))(params)
+                lambda p: model.loss_fn(sz, p, seq, fp8_round,
+                                        remat=True))(params)
         else:
-            loss_p, g_p = jax.value_and_grad(model.loss)(
+            loss_p, g_p = jax.value_and_grad(program.loss)(
                 params, {"tokens": seq[None]})
         loss_r, g_r = jax.value_and_grad(
-            lambda p: reference.loss_fn(sz, p, seq, remat=True))(params)
+            lambda p: model.loss_fn(sz, p, seq, remat=True))(params)
         f32 = jnp.float32
         num = sum(jnp.sum(jnp.square(a.astype(f32) - b.astype(f32)))
                   for a, b in zip(jax.tree_util.tree_leaves(g_p),
@@ -60,10 +52,11 @@ def compare_fn(model, sz, control: bool = False):
     return jax.jit(compare)
 
 
-def check_against_reference(model, sz, params, seq, cfg, log,
+def check_against_reference(program, model, sz, params, seq, cfg, log,
                             control: bool = False) -> dict:
+    compare = compare_fn(program, model, sz, control)
     loss_p, loss_r, grad_err, grad_norm = (
-        float(x) for x in compare_fn(model, sz, control)(params, seq))
+        float(x) for x in compare(params, seq))
     ref = cfg["reference"]
     out = {"loss": loss_p, "reference_loss": loss_r,
            "loss_error": abs(loss_p - loss_r), "grad_error": grad_err,
@@ -80,7 +73,7 @@ def check_against_reference(model, sz, params, seq, cfg, log,
     return out
 
 
-def compile_step(model, mix: dict, params, tokens):
+def compile_step(program, mix: dict, params, tokens):
     """The train step ahead of time, so its memory_analysis() is to hand."""
     import jax
     import optax
@@ -88,7 +81,7 @@ def compile_step(model, mix: dict, params, tokens):
     opt_state = jax.jit(opt.init)(params)
 
     def _step(p, s, batch_):
-        loss, g = jax.value_and_grad(model.loss)(p, batch_)
+        loss, g = jax.value_and_grad(program.loss)(p, batch_)
         updates, s = opt.update(g, s, p)
         return optax.apply_updates(p, updates), s, loss
 
@@ -103,14 +96,16 @@ def run(cell: dict, cfg: dict, mix: dict, args, t_start: float,
     from benchmarks.harness.cells import CompileCounter
     from benchmarks.harness.weights import make_weights
 
-    sz = sizes(cfg)
+    model = load_model(cfg)
+    sz = model.sizes(cfg)
     seconds = float(args.seconds)
     b, s = int(mix["batch"]), int(mix["seq_len"])
-    model = build_model(cfg, s)
-    params = make_weights(sz, args.seed)
+    program = model.train_model(cfg, s)
+    params = make_weights(model.weight_shapes(sz), args.seed)
     tokens = make_tokens(mix, sz.vocab, args.seed)
-    check = check_against_reference(model, sz, params, tokens[0, 0], cfg, log)
-    step, opt_state = compile_step(model, mix, params, tokens)
+    check = check_against_reference(program, model, sz, params, tokens[0, 0],
+                                    cfg, log)
+    step, opt_state = compile_step(program, mix, params, tokens)
     mem_an = step.memory_analysis()
     n_batches = tokens.shape[0]
     batches = [{"tokens": tokens[i]} for i in range(n_batches)]

@@ -1,7 +1,8 @@
 """Weights from `--seed`, made on the device in one jitted call, in the type
-they are served or trained in. The tree has the layout the program's
-`Transformer` holds (stacked layers), but nothing here comes from the
-program: the reference and the system under test get the same arrays."""
+they are served or trained in. The tree of `(shape, std)` is the model
+module's (`weight_shapes`), with the layout the program holds, but nothing
+here comes from the program: the reference and the system under test get
+the same arrays."""
 from __future__ import annotations
 
 import math
@@ -9,49 +10,25 @@ import math
 import jax
 import jax.numpy as jnp
 
-from benchmarks.harness.modelcfg import Sizes
 
-
-def _shapes(s: Sizes) -> dict:
-    L, e, f = s.layers, s.d_model, s.d_ff
-    std = 0.02
-    out_std = std / math.sqrt(2 * L)
-    shapes = {
-        "embed": ((s.vocab, e), std),
-        "final_norm": ((e,), 0.1),
-        "layers": {
-            "attn_norm": ((L, e), 0.1),
-            "wq": ((L, e, s.q_dim), std),
-            "wk": ((L, e, s.kv_dim), std),
-            "wv": ((L, e, s.kv_dim), std),
-            "wo": ((L, s.q_dim, e), out_std),
-            "mlp_norm": ((L, e), 0.1),
-            "gate": ((L, e, f), std),
-            "up": ((L, e, f), std),
-            "down": ((L, f, e), out_std),
-        },
-    }
-    if not s.tied:
-        shapes["lm_head"] = ((e, s.vocab), std)
-    return shapes
-
-
-def _leaves(shapes):
+def leaves(shapes):
+    """(the `(shape, std)` leaves in flattening order, the tree's def)."""
     return jax.tree_util.tree_flatten(
         shapes, is_leaf=lambda x: isinstance(x, tuple)
         and isinstance(x[0], tuple))
 
 
-def make_weights(s: Sizes, seed: int, dtype=jnp.bfloat16):
-    """Normal weights (0.02, output projections scaled down by depth, norm
-    scales 0.1 around the identity) for every leaf, one program."""
-    leaves, treedef = _leaves(_shapes(s))
+def make_weights(shapes, seed: int, dtype=jnp.bfloat16):
+    """Normal weights of each leaf's own std for every leaf of the tree,
+    one program. The key is split over the leaves in flattening order, so a
+    seed gives a tree the same arrays wherever it is built."""
+    flat, treedef = leaves(shapes)
 
     def build(key):
-        keys = jax.random.split(key, len(leaves))
+        keys = jax.random.split(key, len(flat))
         out = [(jax.random.normal(k, shape, jnp.float32) * scale
                 ).astype(dtype)
-               for k, (shape, scale) in zip(keys, leaves)]
+               for k, (shape, scale) in zip(keys, flat)]
         return jax.tree_util.tree_unflatten(treedef, out)
 
     # fold the seed in two halves: seeds pass 2**31
@@ -60,5 +37,5 @@ def make_weights(s: Sizes, seed: int, dtype=jnp.bfloat16):
     return jax.jit(build)(key)
 
 
-def param_count(s: Sizes) -> int:
-    return sum(math.prod(shape) for shape, _ in _leaves(_shapes(s))[0])
+def param_count(shapes) -> int:
+    return sum(math.prod(shape) for shape, _ in leaves(shapes)[0])

@@ -1,6 +1,6 @@
-"""Time to first token, 90th percentile (nearest rank; 96 requests, 9 beyond
-it). Recorded, not judged: at 0.6 of the knee this rank sits on the edge of
-the lane wait, where neighbouring requests read 250, 340 and 520 ms."""
+"""Time to first token, 90th percentile (nearest rank; 219 requests at the
+chat cell's 4.4 requests/s, 21 beyond it). Recorded, not judged: a stalled
+step (PERF.md, Findings 4) moves it by its own length."""
 from benchmarks.harness.stats import percentile
 
 

@@ -1,8 +1,10 @@
 """Cache positions the decode lanes held as a share of the positions the
-decode program gathered (`max_batch x max_pages_per_seq x page_size` a
-step and layer, whatever the lanes hold): `live_positions` over
-`read_positions`, summed over the traced `engine.decode_dispatch` spans
-(the engine's counters `kv_positions_live` / `kv_positions_read`)."""
+decode program read (under the paged kernel each lane's live pages, whole,
+so the loss is the last page's unused tail; under the einsum every lane's
+whole table, `max_batch x max_pages_per_seq x page_size`, whatever the
+lanes hold): `live_positions` over `read_positions`, summed over the traced
+`engine.decode_dispatch` spans (the engine's counters `kv_positions_live`
+/ `kv_positions_read`)."""
 from benchmarks.harness.spans import DISPATCH, of_run
 
 

@@ -1,8 +1,10 @@
 """Gap between a request's consecutive tokens at the client, 99th percentile
-of all gaps that ended inside the window (some 6400 gaps, 64 beyond it).
-Host clock. Not the 98th: at this cell's rate that rank lies on the boundary
-between two clusters of gaps (a decode step plus a 512- or a 1024-bucket
-prefill, 62 and 76 ms) and flips between them from run to run."""
+of all gaps that ended inside the window (some 14,600 gaps at the chat
+cell's 4.4 requests/s, 146 beyond it). Host clock. At that rate the rank
+lies some 25 gaps inside the cluster of a decode step plus a 1024-bucket
+prefill (40-42 ms, which reaches past the 99.5th); at 1.74 requests/s,
+after PR 27 had made the step four times shorter, it lay two gaps from the
+edge between two clusters (21.9 and 24.8 ms) and flipped from run to run."""
 from benchmarks.harness.stats import percentile
 
 

@@ -2,9 +2,9 @@
 
     python3 benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-The cell's configuration, traffic mix and metric readers are files found by
-the names BENCHMARK.json gives (see benchmarks/README.md); nothing of a cell
-is in this file. The last line of standard output is the result. Without a
+The cell's configuration, the model module it names, its traffic mix and its
+metric readers are files found by the names BENCHMARK.json gives (see
+benchmarks/README.md); nothing of a cell or an architecture is in this file. The last line of standard output is the result. Without a
 TPU holding the chips the cell asks for, the run fails and prints no result;
 `--rehearse 1` walks the same control flow at a tiny size on the CPU and
 marks its output as not a measurement.
@@ -76,12 +76,14 @@ def main() -> int:
     except NoAccelerator as e:
         log(f"no measurement: {e}")
         return 3
+    model = modelcfg.load_model(cfg)
     if args.rehearse:
-        cfg = modelcfg.tiny(cfg)
+        cfg = model.tiny(cfg)
     import jax
     device = jax.devices()[0]
     log(f"set-up: acquiring the device {acquire_s:.2f} s (not counted), "
-        f"{time.perf_counter() - T_START - acquire_s:.2f} s of imports")
+        f"{time.perf_counter() - T_START - acquire_s:.2f} s of imports; "
+        f"model module {cfg['model']}")
 
     if mix["kind"] == "train_steps":
         from benchmarks.harness import train_cell as driver
@@ -91,7 +93,8 @@ def main() -> int:
 
     run = {"result": result, "samples": result["samples"], "cell": cell,
            "cfg": cfg, "mix": mix, "peaks": peaks, "trace": None,
-           "sizes": modelcfg.sizes(cfg), "seconds": result["window_s"]}
+           "model": model, "sizes": model.sizes(cfg),
+           "seconds": result["window_s"]}
     dev = {"platform": device.platform, "kind": device.device_kind,
            "count": int(cell["chips"]),
            "memory_peak_bytes": result["memory_peak_bytes"]}

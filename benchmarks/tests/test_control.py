@@ -14,7 +14,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness import modelcfg, reference, train_cell
+from benchmarks.harness import modelcfg, train_cell
+from benchmarks.harness.reference import rel_rms
 from benchmarks.harness.weights import make_weights
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -23,42 +24,43 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 @pytest.fixture(scope="module")
 def small():
-    cfg = modelcfg.tiny(modelcfg.load_config("internlm2-1.8b"))
+    cfg = modelcfg.load_config("internlm2-1.8b")
+    model = modelcfg.load_model(cfg)
+    cfg = model.tiny(cfg)
     cfg.update(hidden_size=128, num_attention_heads=4, head_dim=32,
                intermediate_size=512, num_hidden_layers=4)
-    sz = modelcfg.sizes(cfg)
-    return cfg, sz, make_weights(sz, 3)
+    sz = model.sizes(cfg)
+    return cfg, model, sz, make_weights(model.weight_shapes(sz), 3)
 
 
 def test_serving_logits_pass_and_the_fp8_control_fails(small):
     from ray_tpu.models import Transformer
-    cfg, sz, params = small
+    cfg, model, sz, params = small
     limit = 0.025       # this size: sound reads 0.008, the control 0.056
     toks = jnp.asarray(np.random.default_rng(0).integers(0, sz.vocab, 256),
                        jnp.int32)
-    want = reference.reference_rows(sz, params, toks, jnp.int32(200), 9)
-    model = Transformer(modelcfg.program_config(cfg, 256, remat=False))
-    got = model.apply(params, toks[None])[0, 200:209]
-    control = reference.reference_rows(sz, params, toks, jnp.int32(200), 9,
-                                       True)
-    sound_err = reference.rel_rms(got, want)
-    control_err = reference.rel_rms(control, want)
+    want = model.reference_rows(sz, params, toks, jnp.int32(200), 9)
+    program = Transformer(model.program_config(cfg, 256, remat=False))
+    got = program.apply(params, toks[None])[0, 200:209]
+    control = model.reference_rows(sz, params, toks, jnp.int32(200), 9, True)
+    sound_err = rel_rms(got, want)
+    control_err = rel_rms(control, want)
     assert sound_err <= limit < control_err
     assert control_err > 3 * sound_err
 
 
 def test_training_gradient_passes_and_the_fp8_control_fails(small):
-    cfg, sz, params = small
+    cfg, model, sz, params = small
     # this size: sound reads 0.0075, the control 0.068
     cfg = dict(cfg, reference={"grad_limit": 0.02},
                deployment={"remat": True})
-    model = train_cell.build_model(cfg, 256)
+    program = model.train_model(cfg, 256)
     seq = jnp.asarray(np.random.default_rng(1).integers(0, sz.vocab, 256),
                       jnp.int32)
-    sound = train_cell.check_against_reference(model, sz, params, seq, cfg,
-                                               lambda m: None)
-    control = train_cell.check_against_reference(model, sz, params, seq, cfg,
-                                                 lambda m: None, control=True)
+    sound = train_cell.check_against_reference(
+        program, model, sz, params, seq, cfg, lambda m: None)
+    control = train_cell.check_against_reference(
+        program, model, sz, params, seq, cfg, lambda m: None, control=True)
     assert sound["ok"] and not control["ok"]
     assert control["grad_error"] > 3 * sound["grad_error"]
 
@@ -71,15 +73,15 @@ def test_chip_limits_stand_three_times_clear_of_both_readings(name, key):
 
 
 def test_reference_prefix_is_untouched_by_padding(small):
-    _, sz, params = small
+    _, model, sz, params = small
     toks = np.random.default_rng(2).integers(0, sz.vocab, 128)
     padded = np.zeros(256, np.int64)
     padded[:128] = toks
-    a = reference.reference_rows(sz, params, jnp.asarray(toks, jnp.int32),
-                                 jnp.int32(100), 8)
-    b = reference.reference_rows(sz, params, jnp.asarray(padded, jnp.int32),
-                                 jnp.int32(100), 8)
-    assert reference.rel_rms(a, b) < 1e-5
+    a = model.reference_rows(sz, params, jnp.asarray(toks, jnp.int32),
+                             jnp.int32(100), 8)
+    b = model.reference_rows(sz, params, jnp.asarray(padded, jnp.int32),
+                             jnp.int32(100), 8)
+    assert rel_rms(a, b) < 1e-5
 
 
 def test_rehearsal_prints_no_metric_and_the_measured_path_needs_a_tpu():

@@ -19,9 +19,9 @@ from benchmarks.harness.cells import load_cell, prepare_device
 def serving(cfg, mix, seeds, controls):
     import jax.numpy as jnp
     import numpy as np
-    from benchmarks.harness import reference, traffic
+    from benchmarks.harness import traffic
+    from benchmarks.harness.reference import rel_rms
     from benchmarks.harness.serve_cell import Served
-    from benchmarks.harness.weights import make_weights
     served, rows = None, []
     for n, seed in enumerate(seeds):
         if served is None:
@@ -29,7 +29,7 @@ def serving(cfg, mix, seeds, controls):
         else:
             served.seed = seed
             served.engine.core.params = None
-            served.engine.core.params = make_weights(served.sz, seed)
+            served.engine.core.params = served.weights(seed)
             served.prompts = traffic.prompt_tokens(
                 served.requests, served.sz.vocab, seed)
         errors, lens = served.check(mix)
@@ -37,6 +37,7 @@ def serving(cfg, mix, seeds, controls):
                "prompts": lens}
         if n < controls:
             sz, params = served.sz, served.engine.core.params
+            reference_rows = served.model.reference_rows
             steps = int(mix["check_decode_steps"])
             toks = np.zeros((2176,), np.int32)
             p = lens[0]
@@ -44,9 +45,8 @@ def serving(cfg, mix, seeds, controls):
                 0, sz.vocab, p + steps)
             args = (sz, params, jnp.asarray(toks), jnp.int32(p - 1),
                     steps + 1)
-            row["control"] = reference.rel_rms(
-                reference.reference_rows(*args, True),
-                reference.reference_rows(*args, False))
+            row["control"] = rel_rms(reference_rows(*args, True),
+                                     reference_rows(*args, False))
         rows.append(row)
         print(json.dumps(row), flush=True)
     served.close()
@@ -55,21 +55,22 @@ def serving(cfg, mix, seeds, controls):
 
 def training(cfg, mix, seeds, controls):
     from benchmarks.harness import train_cell
-    from benchmarks.harness.modelcfg import sizes
+    from benchmarks.harness.modelcfg import load_model
     from benchmarks.harness.weights import make_weights
-    sz = sizes(cfg)
-    model = train_cell.build_model(cfg, int(mix["seq_len"]))
+    model = load_model(cfg)
+    sz = model.sizes(cfg)
+    program = model.train_model(cfg, int(mix["seq_len"]))
     rows = []
     for n, seed in enumerate(seeds):
-        params = make_weights(sz, seed)
+        params = make_weights(model.weight_shapes(sz), seed)
         seq = train_cell.make_tokens(mix, sz.vocab, seed)[0, 0]
-        out = train_cell.check_against_reference(model, sz, params, seq, cfg,
-                                                 print)
+        out = train_cell.check_against_reference(program, model, sz, params,
+                                                 seq, cfg, print)
         row = {"seed": seed, "sound_loss": out["loss_error"],
                "sound_grad": out["grad_error"]}
         if n < controls:
-            c = train_cell.check_against_reference(model, sz, params, seq,
-                                                   cfg, print, control=True)
+            c = train_cell.check_against_reference(
+                program, model, sz, params, seq, cfg, print, control=True)
             row.update(control_loss=c["loss_error"],
                        control_grad=c["grad_error"])
         rows.append(row)
@@ -89,8 +90,8 @@ def main():
     _, cell, cfg, mix = load_cell(a.workload)
     prepare_device(cell, bool(a.rehearse))
     if a.rehearse:
-        from benchmarks.harness.modelcfg import tiny
-        cfg = tiny(cfg)
+        from benchmarks.harness.modelcfg import load_model
+        cfg = load_model(cfg).tiny(cfg)
     seeds = [int(x) for x in a.seeds.split(",")]
     if mix["kind"] == "train_steps":
         rows = training(cfg, mix, seeds, a.controls)

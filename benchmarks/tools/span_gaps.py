@@ -29,8 +29,8 @@ def main():
     _, cell, cfg, mix = load_cell(a.workload)
     prepare_device(cell, bool(a.rehearse))
     if a.rehearse:
-        from benchmarks.harness.modelcfg import tiny
-        cfg = tiny(cfg)
+        from benchmarks.harness.modelcfg import load_model
+        cfg = load_model(cfg).tiny(cfg)
     from benchmarks.harness import spans, xplane
     from benchmarks.harness.serve_cell import Served
     served = Served(cfg, mix, a.seed, a.seconds)

@@ -27,8 +27,8 @@ def main():
     _, cell, cfg, mix = load_cell(a.workload)
     prepare_device(cell, bool(a.rehearse))
     if a.rehearse:
-        from benchmarks.harness.modelcfg import tiny
-        cfg = tiny(cfg)
+        from benchmarks.harness.modelcfg import load_model
+        cfg = load_model(cfg).tiny(cfg)
     from benchmarks.harness.serve_cell import Served
     from benchmarks.harness.stats import percentile
     rates = [float(r) for r in a.rates.split(",")]

@@ -6,9 +6,43 @@ ray_tpu.ops kernels, with parameters as plain pytrees annotated by
 logical sharding axes (ray_tpu.parallel.sharding). Layers are stacked
 and scanned (`lax.scan`) so compile time is O(1) in depth; remat is a
 config switch.
+
+A second architecture sits beside it: `MLAMoE` (`models/mla_moe.py`),
+multi-head latent attention with a latent paged cache and a dropless
+routed feed-forward with shared experts, a leading dense layer and then
+expert layers, held per layer. A config's type names its class
+(`build_model`), and the serving engine asks the model it is given for
+its cache and programs (`init_cache`, `prefill`, `decode_step`,
+`cache_page_bytes`, `decode_attention`) and names neither class.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
                                    decode_step,
                                    init_paged_cache, prefill)
 from ray_tpu.models.transformer import Transformer  # noqa: F401
+from ray_tpu.models.mla_moe import MLAMoE, MLAMoEConfig  # noqa: F401,E402
+
+
+# a dict of config fields names its class under "type"; without the key it
+# is the flagship decoder's
+CONFIG_TYPES = {"transformer": TransformerConfig, "mla_moe": MLAMoEConfig}
+
+
+def model_config(model):
+    """A preset's name, a dict of config fields (`"type"` names the class,
+    one of `CONFIG_TYPES`; a `TransformerConfig`'s without it) or a
+    config object -> the config object."""
+    from ray_tpu.models.config import PRESETS
+    if isinstance(model, str):
+        return PRESETS[model]()
+    if isinstance(model, dict):
+        fields = dict(model)
+        return CONFIG_TYPES[fields.pop("type", "transformer")](**fields)
+    return model
+
+
+def build_model(config, mesh=None):
+    """The model class a config's type names, bound to `mesh`."""
+    if isinstance(config, MLAMoEConfig):
+        return MLAMoE(config, mesh=mesh)
+    return Transformer(config, mesh=mesh)

@@ -1,5 +1,13 @@
 """Incremental decoding for the ray_tpu Transformer: paged KV cache.
 
+The serving engine reaches this module through `Transformer`'s own
+`init_cache` / `prefill` / `decode_step` / `cache_page_bytes` /
+`decode_attention`: it asks the model its config names and imports
+nothing here. `models.mla_moe.MLAMoE` answers the same questions with a
+latent pool (one row `[c_kv | k_rope]` a position and layer, every head's)
+and a decode step through experts; this file is the dense decoder's, keys
+and values per kv head.
+
 Serving needs two forwards the training graph never runs: a *prefill*
 that processes a whole prompt once while writing every layer's K/V
 into cache pages, and a *decode step* that advances a batch of
@@ -79,7 +87,10 @@ def init_paged_cache(config, num_pages: int, page_size: int,
     created sharded as `cache_sharding` says when given a mesh."""
     if config.moe_num_experts:
         raise NotImplementedError(
-            "paged decoding supports dense FFN layers only")
+            "the capacity-factor MoE of Transformer (models.moe.moe_ffn) "
+            "drops tokens and is not served; the serving path through "
+            "experts is the dropless layer of models.mla_moe.MLAMoE "
+            "(models.moe.dropless_moe_ffn)")
     dt = dtype or config.activation_dtype
     shape = (config.n_layers, num_pages, page_size,
              config.kv_heads * config.head_dim)

@@ -1,0 +1,464 @@
+"""A decoder with multi-head latent attention (MLA) and routed plus shared
+experts: the `glm4_moe_lite` / DeepSeek-V2-V3 family of layers, on the
+same ops as `Transformer` and behind the same serving engine.
+
+Every layer's attention is MLA. With `x` the normed input:
+
+    c_q  = RMSNorm(x W_qa)                      (q_lora_rank)
+    q    = c_q W_qb        -> heads of [q_nope | q_rope]
+    [c_kv | k_rope] = x W_kva;  c_kv = RMSNorm(c_kv);  k_rope = RoPE(k_rope)
+    [k_nope | v] a head = c_kv W_kvb;           q_rope = RoPE(q_rope)
+    scores = q . [k_nope | k_rope] / sqrt(nope + rope), causal softmax
+    o = concat_h(P v) W_o
+
+`k_rope` is one for all heads. The first `first_k_dense_replace` layers
+have a SwiGLU feed-forward, the rest `models.moe.dropless_moe_ffn` (sigmoid
+scores in float32, top-k of score + bias, weights from the scores alone,
+normalised and scaled) plus `n_shared_experts` shared experts applied to
+every token. The layers are unlike, so they are held per layer (a list),
+not stacked, and every program unrolls them.
+
+**The cache is latent**: a position costs a layer one row `[c_kv | k_rope]`
+(after the norm and the rotation), `kv_lora_rank + qk_rope_head_dim`
+numbers against `heads * (qk + v)` uncompressed, padded with zeros to whole
+128-lanes (`row_width`; the padding is this layout's cost). The pool is
+`(layers, pages, page_size, row_width)` under the key `"kv"`, paged as
+`models.decode`'s is. `prefill` runs the expanded form above through
+`flash_attention` (keys and values are both `nope + rope = v_head_dim` wide
+in the published sizes; the cache never holds them) and writes whole pages
+in place. `decode_step` runs the absorbed form: `q_lat = q_nope W_UK^T`,
+scores `q_lat . c_kv + q_rope . k_rope`, `o_lat = P c_kv`, `o = o_lat W_UV`
+— one row read once, key and value both — through
+`ops.paged_attention.mla_paged_decode_attention`.
+
+Beside the pool the cache carries what the experts did: `"moe_load"`
+`(expert layers, experts)` int32, pairs an expert, accumulated over decode
+steps, and `"moe_step"`, the last decode step's `moe_pairs`,
+`moe_experts_touched` and `moe_load_max` (the busiest expert's pairs), each
+an int32 scalar summed over the expert layers, under the names the
+engine's counters take: it fetches them with the step's tokens.
+
+Given a mesh the class refuses: experts over chips have not been built
+(PERF.md section 7).
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.moe import dropless_moe_ffn
+from ray_tpu.ops import paged_attention as _paged
+from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.losses import softmax_cross_entropy
+from ray_tpu.ops.norms import rms_norm, rms_norm_reference
+from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin
+
+Params = Dict[str, Any]
+Cache = Dict[str, Any]
+
+# prefill's flash blocks (keys and values are 256 wide: PERF.md section 4)
+ATTN_BLOCK = 128
+# what a decode step counts over its expert layers (`Cache["moe_step"]`);
+# the engine's counters take these names
+STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max")
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAMoEConfig:
+    """Fields under the published keys' meanings (`config.json` of
+    `glm4_moe_lite`); `head_dim` is not `d_model / n_heads` here."""
+    vocab_size: int = 154880
+    d_model: int = 2048                     # hidden_size
+    n_layers: int = 47                      # num_hidden_layers
+    n_heads: int = 20                       # num_attention_heads
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    d_ff: int = 10240                       # intermediate_size (dense)
+    moe_intermediate_size: int = 1536
+    n_routed_experts: int = 64
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 4
+    first_k_dense_replace: int = 1
+    routed_scaling_factor: float = 1.8
+    norm_topk_prob: bool = True
+    scoring_func: str = "sigmoid"
+    max_seq_len: int = 4096
+    rope_theta: float = 1000000.0
+    norm_eps: float = 1e-5
+    dtype: str = "bfloat16"
+    param_dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if self.scoring_func != "sigmoid":
+            raise ValueError(f"scoring {self.scoring_func!r}: only the "
+                             f"published sigmoid scoring is built")
+        if self.qk_head_dim != self.v_head_dim:
+            raise ValueError(
+                f"prefill runs keys ({self.qk_head_dim} wide) and values "
+                f"({self.v_head_dim}) through one flash kernel, which "
+                f"wants them alike")
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row_width(self) -> int:
+        """A cache row: latent and rotary key, padded to whole lanes."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def n_moe_layers(self) -> int:
+        return max(0, self.n_layers - self.first_k_dense_replace)
+
+    @property
+    def activation_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def parameter_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+
+def tiny_mla_moe(vocab_size: int = 256) -> MLAMoEConfig:
+    """CI/debug model: every mechanism at a size the CPU runs in seconds
+    (row width 128, so the kernels tile under the interpreter)."""
+    return MLAMoEConfig(
+        vocab_size=vocab_size, d_model=64, n_layers=3, n_heads=4,
+        q_lora_rank=48, kv_lora_rank=96, qk_nope_head_dim=16,
+        qk_rope_head_dim=16, v_head_dim=32, d_ff=128,
+        moe_intermediate_size=32, n_routed_experts=8, n_shared_experts=1,
+        num_experts_per_tok=2, first_k_dense_replace=1, max_seq_len=128,
+        dtype="float32", param_dtype="float32")
+
+
+class MLAMoE:
+    """Functional model bundle for one MLAMoEConfig: `init`, `apply` /
+    `loss` (training graph), and what a serving engine asks a model for
+    (`init_cache`, `prefill`, `decode_step`, `cache_page_bytes`,
+    `decode_attention`, `step_stats`, `cache_stats`)."""
+
+    def __init__(self, config: MLAMoEConfig, mesh=None):
+        if mesh is not None:
+            raise NotImplementedError(
+                "MLAMoE runs on one device and takes no mesh: experts "
+                "and the latent cache are not sharded over chips yet")
+        self.config = config
+
+    # ------------------------------------------------------------ init
+    def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
+        """(shape, init std) of layer i's leaves; std 0 means zeros (a
+        norm scale, stored as w with the layer multiplying by 1 + w)."""
+        c = self.config
+        e, H = c.d_model, c.n_heads
+        std = 0.02
+        out_std = std / math.sqrt(2 * c.n_layers)
+        shapes = {
+            "attn_norm": ((e,), 0.0),
+            "wq_a": ((e, c.q_lora_rank), std),
+            "q_norm": ((c.q_lora_rank,), 0.0),
+            "wq_b": ((c.q_lora_rank, H * c.qk_head_dim), std),
+            "wkv_a": ((e, c.kv_lora_rank + c.qk_rope_head_dim), std),
+            "kv_norm": ((c.kv_lora_rank,), 0.0),
+            "wkv_b": ((c.kv_lora_rank,
+                       H * (c.qk_nope_head_dim + c.v_head_dim)), std),
+            "wo": ((H * c.v_head_dim, e), out_std),
+            "mlp_norm": ((e,), 0.0),
+        }
+        if i < c.first_k_dense_replace:
+            shapes.update(gate=((e, c.d_ff), std), up=((e, c.d_ff), std),
+                          down=((c.d_ff, e), out_std))
+            return shapes
+        E, f = c.n_routed_experts, c.moe_intermediate_size
+        fs = f * c.n_shared_experts
+        shapes.update(
+            router=((e, E), std), router_bias=((E,), 0.0),
+            moe_gate=((E, e, f), std), moe_up=((E, e, f), std),
+            moe_down=((E, f, e), out_std),
+            shared_gate=((e, fs), std), shared_up=((e, fs), std),
+            shared_down=((fs, e), out_std))
+        return shapes
+
+    def init(self, key: jax.Array) -> Params:
+        c = self.config
+        pd = c.parameter_dtype
+
+        def fill(key, shapes):
+            keys = jax.random.split(key, len(shapes))
+            return {name: (jax.random.normal(k, shape, jnp.float32)
+                           * std).astype(pd) if std else jnp.zeros(shape, pd)
+                    for k, (name, (shape, std)) in zip(keys,
+                                                       shapes.items())}
+
+        keys = jax.random.split(key, c.n_layers + 1)
+        top = fill(keys[-1], {
+            "embed": ((c.vocab_size, c.d_model), 0.02),
+            "lm_head": ((c.d_model, c.vocab_size), 0.02)})
+        return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
+                "layers": [fill(keys[i], self.layer_shapes(i))
+                           for i in range(c.n_layers)]}
+
+    # --------------------------------------------------------- pieces
+    def _norm(self, x, w):
+        return rms_norm(x, w, self.config.norm_eps, None)
+
+    def _q(self, layer: Params, h):
+        """h (..., e) -> q (..., heads, nope + rope), not yet rotated."""
+        c = self.config
+        ad = c.activation_dtype
+        c_q = rms_norm_reference(h @ layer["wq_a"].astype(ad),
+                                 layer["q_norm"], c.norm_eps)
+        q = c_q @ layer["wq_b"].astype(ad)
+        return q.reshape(*h.shape[:-1], c.n_heads, c.qk_head_dim)
+
+    def _latent(self, layer: Params, h, cos, sin):
+        """h (..., e) -> the cache's row parts: c_kv (..., latent) after
+        its norm and k_rope (..., rope) after the rotation."""
+        c = self.config
+        ad = c.activation_dtype
+        kv = h @ layer["wkv_a"].astype(ad)
+        c_kv = rms_norm_reference(kv[..., :c.kv_lora_rank],
+                                  layer["kv_norm"], c.norm_eps)
+        k_rope = apply_rope_cached(kv[..., None, c.kv_lora_rank:], cos, sin)
+        return c_kv, k_rope[..., 0, :]
+
+    def _rows(self, c_kv, k_rope, dtype):
+        """The rows a cache page holds: [c_kv | k_rope | zeros]."""
+        c = self.config
+        pad = c.row_width - c.kv_lora_rank - c.qk_rope_head_dim
+        rows = jnp.concatenate([c_kv, k_rope], axis=-1).astype(dtype)
+        return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+
+    def _wkv_b(self, layer: Params):
+        """W_kvb as (latent, heads, nope + v)."""
+        c = self.config
+        return layer["wkv_b"].astype(c.activation_dtype).reshape(
+            c.kv_lora_rank, c.n_heads, c.qk_nope_head_dim + c.v_head_dim)
+
+    def _attn_expanded(self, layer: Params, h, cos, sin):
+        """Causal MLA over whole sequences, keys and values expanded from
+        the latent. h (b, s, e). Returns (attention output before W_o
+        (b, s, heads * v), c_kv, k_rope)."""
+        c = self.config
+        b, s, _ = h.shape
+        nope = c.qk_nope_head_dim
+        q = self._q(layer, h)                           # (b, s, H, qk)
+        q = jnp.concatenate(
+            [q[..., :nope], apply_rope_cached(q[..., nope:], cos, sin)],
+            axis=-1)
+        c_kv, k_rope = self._latent(layer, h, cos, sin)
+        kv = jnp.einsum("bsc,chd->bshd", c_kv, self._wkv_b(layer))
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(
+                k_rope[:, :, None, :], (b, s, c.n_heads,
+                                        c.qk_rope_head_dim))], axis=-1)
+        v = kv[..., nope:]
+        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        out = flash_attention(qt, kt, vt, causal=True,
+                              sm_scale=1.0 / math.sqrt(c.qk_head_dim),
+                              block_q=ATTN_BLOCK, block_k=ATTN_BLOCK)
+        out = out.transpose(0, 2, 1, 3).reshape(
+            b, s, c.n_heads * c.v_head_dim)
+        return out, c_kv, k_rope
+
+    def _ffn(self, layer: Params, x, valid=None):
+        """Feed-forward of one layer on tokens x (T, e) after the norm.
+        Returns (y, expert counts or None for a dense layer)."""
+        c = self.config
+        ad = c.activation_dtype
+        if "router" not in layer:
+            gate = jax.nn.silu(x @ layer["gate"].astype(ad))
+            return (gate * (x @ layer["up"].astype(ad))) @ layer[
+                "down"].astype(ad), None
+        y, counts = dropless_moe_ffn(
+            x, layer["router"], layer["router_bias"], layer["moe_gate"],
+            layer["moe_up"], layer["moe_down"],
+            top_k=c.num_experts_per_tok, norm_topk_prob=c.norm_topk_prob,
+            scale=c.routed_scaling_factor, valid=valid)
+        shared = jax.nn.silu(x @ layer["shared_gate"].astype(ad))
+        shared = (shared * (x @ layer["shared_up"].astype(ad))) @ layer[
+            "shared_down"].astype(ad)
+        return y + shared, counts
+
+    def _block_ffn(self, layer: Params, x, valid=None):
+        """x (..., e) + ffn(norm(x)); returns (x, counts)."""
+        h = self._norm(x, layer["mlp_norm"])
+        y, counts = self._ffn(layer, h.reshape(-1, h.shape[-1]),
+                              None if valid is None else valid.reshape(-1))
+        return x + y.reshape(x.shape), counts
+
+    # --------------------------------------------------------- forward
+    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens (b, s) -> hidden states after the final norm."""
+        c = self.config
+        ad = c.activation_dtype
+        b, s = tokens.shape
+        x = params["embed"].astype(ad)[tokens]
+        cos, sin = rope_cos_sin(jnp.broadcast_to(jnp.arange(s), (b, s)),
+                                c.qk_rope_head_dim, c.rope_theta)
+        for layer in params["layers"]:
+            h = self._norm(x, layer["attn_norm"])
+            attn, _, _ = self._attn_expanded(layer, h, cos, sin)
+            x = x + attn @ layer["wo"].astype(ad)
+            x, _ = self._block_ffn(layer, x)
+        return self._norm(x, params["final_norm"])
+
+    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
+        x = self.hidden(params, tokens)
+        head = params["lm_head"].astype(self.config.activation_dtype)
+        return (x @ head).astype(jnp.float32)
+
+    def loss(self, params: Params, batch: Dict[str, jax.Array]):
+        """Causal LM loss of batch["tokens"] (b, s), as
+        `Transformer.loss`; no auxiliary term (the published routing has
+        a bias moved between steps, not a loss)."""
+        tokens = batch["tokens"]
+        mask = batch.get("loss_mask")
+        logits = self.apply(params, tokens)[:, :-1]
+        if mask is not None:
+            mask = mask[:, 1:]
+        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
+        return loss
+
+    # ------------------------------------------------ what an engine asks
+    def init_cache(self, num_pages: int, page_size: int,
+                   dtype=None) -> Cache:
+        c = self.config
+        dt = dtype or c.activation_dtype
+        shape = (c.n_layers, num_pages, page_size, c.row_width)
+        make = jax.jit(lambda: {
+            "kv": jnp.zeros(shape, dt),
+            "moe_load": jnp.zeros((c.n_moe_layers, c.n_routed_experts),
+                                  jnp.int32),
+            "moe_step": {name: jnp.zeros((), jnp.int32)
+                         for name in STEP_COUNTS}})
+        return make()
+
+    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
+                         dtype=None) -> int:
+        """Bytes one page costs (all layers): the rows as the pool holds
+        them, padding and all. The latent is shared by every head, so a
+        tp shard holds it whole."""
+        c = self.config
+        dt = jnp.dtype(dtype or c.activation_dtype)
+        return c.n_layers * page_size * c.row_width * dt.itemsize
+
+    def decode_attention(self, page_size: int, dtype=None) -> str:
+        """Which attention a `decode_step` traced here holds: the latent
+        kernel's name, or "einsum"."""
+        c = self.config
+        if _paged.mla_uses_kernel(c.row_width, c.kv_lora_rank, page_size,
+                                  dtype or c.activation_dtype):
+            return _paged.KERNEL_MLA_PAGED_DECODE
+        return "einsum"
+
+    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
+        """What the last decode step counted: scalars still on the device,
+        by the names the engine's counters take. The engine fetches them
+        with the step's tokens."""
+        return cache["moe_step"] if self.config.n_moe_layers else {}
+
+    def cache_stats(self, cache: Cache) -> Dict[str, Any]:
+        """For `EngineCore.device_stats()`: pairs an expert since the
+        cache was made, by expert layer."""
+        return {"moe_load": jax.device_get(cache["moe_load"]).tolist()}
+
+    def prefill(self, params: Params, tokens: jax.Array, true_len,
+                page_table: jax.Array, cache: Cache,
+                page_size: int) -> Tuple[jax.Array, Cache]:
+        """One padded prompt, as `models.decode.prefill`: the expanded
+        attention, the latent rows written as whole pages in place
+        (donate the cache). Padding past `true_len` is given to no
+        expert. Returns (last-position logits (vocab,) f32, cache)."""
+        c = self.config
+        ad = c.activation_dtype
+        pool = cache["kv"]
+        num_pages = pool.shape[1]
+        s = tokens.shape[0]
+        x = params["embed"].astype(ad)[tokens][None]            # (1, s, e)
+        cos, sin = rope_cos_sin(jnp.arange(s)[None], c.qk_rope_head_dim,
+                                c.rope_theta)
+        valid = (jnp.arange(s) < true_len)[None]
+        n = -(-s // page_size)
+        page_ids = jnp.take(page_table, jnp.arange(n), mode="clip")
+        page_ids = jnp.where(jnp.arange(n) * page_size < true_len,
+                             page_ids, num_pages)
+        for i, layer in enumerate(params["layers"]):
+            h = self._norm(x, layer["attn_norm"])
+            attn, c_kv, k_rope = self._attn_expanded(layer, h, cos, sin)
+            rows = self._rows(c_kv[0], k_rope[0], pool.dtype)
+            rows = jnp.pad(rows, ((0, n * page_size - s), (0, 0)))
+            pool = pool.at[i, page_ids].set(
+                rows.reshape(n, page_size, c.row_width), mode="drop")
+            x = x + attn @ layer["wo"].astype(ad)
+            x, _ = self._block_ffn(layer, x, valid)
+        x = self._norm(x, params["final_norm"])
+        last = jnp.take(x[0], true_len - 1, axis=0)
+        logits = (last @ params["lm_head"].astype(ad)).astype(jnp.float32)
+        return logits, {**cache, "kv": pool}
+
+    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
+                    positions: jax.Array, page_tables: jax.Array,
+                    active: jax.Array,
+                    page_size: int) -> Tuple[jax.Array, Cache]:
+        """Advance a padded batch by one token each, as
+        `models.decode.decode_step`, in the absorbed form. Inactive lanes
+        write nothing and are given to no expert. Returns (logits
+        (B, vocab) f32, cache) — donate the cache."""
+        c = self.config
+        ad = c.activation_dtype
+        nope, latent = c.qk_nope_head_dim, c.kv_lora_rank
+        pool = cache["kv"]
+        num_pages = pool.shape[1]
+        B = tokens.shape[0]
+        x = params["embed"].astype(ad)[tokens]                  # (B, e)
+        cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
+                                c.rope_theta)              # (B, 1, rope/2)
+        my_page = jnp.take_along_axis(
+            page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
+        wr_page = jnp.where(active & (my_page >= 0), my_page, num_pages)
+        wr_slot = positions % page_size
+        lengths = jnp.where(active, positions + 1, 0)
+        sm_scale = 1.0 / math.sqrt(c.qk_head_dim)
+        pad = c.row_width - latent - c.qk_rope_head_dim
+        load = cache["moe_load"]
+        pairs = touched = load_max = jnp.int32(0)
+        for i, layer in enumerate(params["layers"]):
+            h = self._norm(x, layer["attn_norm"])
+            q = self._q(layer, h)                           # (B, H, qk)
+            c_kv, k_rope = self._latent(layer, h, cos, sin)
+            pool = pool.at[i, wr_page, wr_slot].set(
+                self._rows(c_kv, k_rope, pool.dtype), mode="drop")
+            w_kvb = self._wkv_b(layer)
+            q_lat = jnp.einsum("bhn,chn->bhc", q[..., :nope],
+                               w_kvb[..., :nope])
+            q_rope = apply_rope_cached(q[..., nope:], cos, sin)
+            q_row = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
+                            ((0, 0), (0, 0), (0, pad)))
+            o_lat = _paged.mla_paged_decode_attention(
+                q_row.astype(pool.dtype), pool, i, page_tables, lengths,
+                latent, sm_scale)
+            out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
+                             w_kvb[..., nope:])
+            x = x + out.reshape(B, -1) @ layer["wo"].astype(ad)
+            x, counts = self._block_ffn(layer, x, active)
+            if counts is not None:
+                j = i - c.first_k_dense_replace
+                load = load.at[j].add(counts["load"])
+                pairs = pairs + counts["pairs"]
+                touched = touched + counts["touched"]
+                load_max = load_max + jnp.max(counts["load"])
+        x = self._norm(x, params["final_norm"])
+        logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
+        return logits, {"kv": pool, "moe_load": load,
+                        "moe_step": dict(zip(STEP_COUNTS, (
+                            pairs, touched, load_max)))}
+

@@ -1,4 +1,15 @@
-"""Mixture-of-Experts FFN: top-k routing + capacity-based dispatch.
+"""Mixture-of-Experts FFN: two routed paths.
+
+`moe_ffn` (below, first) is the capacity-factor layer `Transformer` trains
+with: top-k of a softmax, a fixed capacity an expert, overflow dropped.
+`dropless_moe_ffn` (at the end) is the serving path of `models.mla_moe`:
+no capacity and no dropped token at any imbalance — the (token, expert)
+pairs are sorted by expert and `ops.grouped_matmul` multiplies each
+expert's rows by its matrices, reading only experts that have rows. Its
+scoring is data of the caller's config (`route_topk`). A `simplicity` PR
+folds the two (ROADMAP D1).
+
+The capacity-factor layer: top-k routing + capacity-based dispatch.
 
 Expert parallelism the TPU way (SURVEY.md §2.4 EP row — absent from the
 reference in-tree, delivered here natively): expert weights carry the
@@ -118,3 +129,60 @@ MOE_PARAM_AXES = {
     "moe_up": ("experts", "embed", "mlp"),
     "moe_down": ("experts", "mlp", "embed"),
 }
+
+
+# ------------------------------------------------------------ dropless
+def route_topk(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
+               top_k: int, norm_topk_prob: bool = True,
+               scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scoring in float32: choose the top-k of `score + bias` (the
+    bias moves the choice only), weigh by the score itself, divide by the
+    chosen scores' sum where `norm_topk_prob`, times `scale`.
+
+    x (T, d); router_w (d, E); bias (E,). Returns (experts (T, k) int32,
+    weights (T, k) float32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, top_e = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    if norm_topk_prob:
+        top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_e.astype(jnp.int32), top_w * scale
+
+
+def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
+                     gate_w: jax.Array, up_w: jax.Array, down_w: jax.Array,
+                     *, top_k: int, norm_topk_prob: bool = True,
+                     scale: float = 1.0,
+                     valid: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """y_t = sum over the k experts token t chose of w_ti * E_i(x_t), every
+    expert a SwiGLU; no token is dropped whatever the imbalance.
+
+    x (T, d); gate_w / up_w (E, d, f); down_w (E, f, d). `valid` (T,)
+    bool: tokens that are padding get no pair and a zero result. Returns
+    (y (T, d), {"pairs": pairs dispatched, "touched": experts with a pair,
+    "load": (E,) pairs an expert}), the counts int32 on the device."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+    T, d = x.shape
+    E = router_w.shape[-1]
+    top_e, top_w = route_topk(x, router_w, bias, top_k=top_k,
+                              norm_topk_prob=norm_topk_prob, scale=scale)
+    if valid is not None:           # padding sorts past the last expert
+        top_e = jnp.where(valid[:, None], top_e, E)
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)    # pairs sorted by expert
+    load = jnp.bincount(flat_e, length=E + 1)[:E].astype(jnp.int32)
+    xs = x[order // top_k]
+    h = (jax.nn.silu(grouped_matmul(xs, gate_w, load))
+         * grouped_matmul(xs, up_w, load))
+    # (the rows of padding, past the last expert's, come back as zeros)
+    ys = grouped_matmul(h, down_w, load).astype(jnp.float32)
+    ys = ys * top_w.reshape(-1)[order][:, None]
+    # back to token order: the inverse of the sort, then the k pairs of a
+    # token summed in float32
+    y = ys[jnp.argsort(order)].reshape(T, top_k, d).sum(axis=1)
+    return y.astype(x.dtype), {
+        "pairs": jnp.sum(load), "touched": jnp.sum(load > 0).astype(
+            jnp.int32), "load": load}

@@ -322,6 +322,44 @@ class Transformer:
         logits = self._constrain(logits, ("batch", "seq", "vocab"))
         return logits.astype(jnp.float32)
 
+    # ------------------------------------------- what an engine asks
+    # The serving engine asks a model for its cache and its two programs
+    # (`models.build_model`); this class answers with `models.decode`.
+    def init_cache(self, num_pages: int, page_size: int, dtype=None):
+        from ray_tpu.models import decode
+        return decode.init_paged_cache(self.config, num_pages, page_size,
+                                       dtype, mesh=self.mesh)
+
+    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
+                         dtype=None) -> int:
+        from ray_tpu.models import decode
+        return decode.cache_page_bytes(self.config, page_size,
+                                       tp_shards=tp_shards, dtype=dtype)
+
+    def decode_attention(self, page_size: int, dtype=None) -> str:
+        from ray_tpu.models import decode
+        return decode.decode_attention(self.config, page_size, dtype)
+
+    def prefill(self, params: Params, tokens, true_len, page_table, cache,
+                page_size: int):
+        from ray_tpu.models import decode
+        return decode.prefill(self, params, tokens, true_len, page_table,
+                              cache, page_size)
+
+    def decode_step(self, params: Params, cache, tokens, positions,
+                    page_tables, active, page_size: int):
+        from ray_tpu.models import decode
+        return decode.decode_step(self, params, cache, tokens, positions,
+                                  page_tables, active, page_size)
+
+    def step_stats(self, cache) -> Dict[str, jax.Array]:
+        """Counts of the last decode step for the engine to fetch with
+        its tokens: a dense decoder has none."""
+        return {}
+
+    def cache_stats(self, cache) -> Dict[str, Any]:
+        return {}
+
     # ------------------------------------------------------------ loss
     def loss(self, params: Params, batch: Dict[str, jax.Array]):
         """Causal LM loss. batch: tokens (b, s); optional loss_mask

@@ -21,6 +21,11 @@ one matmul's rows.
 ran before: the off-TPU path and the ground truth of the tests, which
 reach the kernel through the Pallas interpreter
 (`paged_decode_attention_kernel`).
+
+The latent pool of `models.mla_moe` has its own kernel at the end of this
+file, `mla_paged_decode_attn`: a position is one row `[c_kv | k_rope |
+padding]` shared by every head, read once and used as the key (all of it)
+and as the value (its first `latent` numbers).
 """
 from __future__ import annotations
 
@@ -288,3 +293,213 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, layer, page_tables,
     """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
     return _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
                          not on_tpu(), mesh)
+
+
+# ------------------------------------------------- the latent (MLA) pool
+#
+# Multi-head latent attention in its absorbed form: the cache holds one
+# row a position, `[c_kv (latent) | k_rope | zeros]`, padded to whole
+# 128-lanes; a head's query is `[q_nope W_UK^T | q_rope | zeros]` of the
+# same width, so a score is one dot product with the row, and the head's
+# output is the probabilities times the rows' first `latent` numbers (the
+# caller multiplies by W_UV afterwards). All heads share the rows: one
+# matmul's rows are the heads.
+KERNEL_MLA_PAGED_DECODE = "mla_paged_decode_attn"
+KERNEL_MLA_PAGED_SCOPE = "mla_paged_decode_attention"
+# Pages a block: larger blocks than the per-head pool's, a row being
+# narrower than its keys and values together (PERF.md, PR 27: time goes
+# with the number of blocks).
+MLA_BLOCK_PAGES = 16
+
+
+def mla_paged_decode_tiles(width: int, latent: int, page_size: int,
+                           dtype) -> bool:
+    """Whether the latent kernel tiles these shapes: a row and its value
+    part are whole 128-lanes, a page whole sublanes."""
+    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return (width % 128 == 0 and latent % 128 == 0
+            and page_size % sublanes == 0)
+
+
+def mla_paged_attention_reference(q, pool, layer, page_tables, lengths,
+                                  latent: int, sm_scale: float):
+    """Gather every table entry, mask, softmax in float32.
+
+    q (B, heads, width); pool (layers, pages, page, width); page_tables
+    (B, max_pages) int32, -1 unassigned; lengths (B,). Returns (B, heads,
+    latent) in q's dtype; a lane that sees nothing gets zeros."""
+    B = q.shape[0]
+    num_pages, page, width = pool.shape[1:]
+    span = page_tables.shape[1] * page
+    pt = jnp.clip(page_tables, 0, num_pages - 1)
+    rows = pool[layer][pt].reshape(B, span, width).astype(jnp.float32)
+    mask = ((jnp.arange(span)[None, :] < lengths[:, None])
+            & jnp.repeat(page_tables >= 0, page, axis=1))
+    scores = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32),
+                        rows) * sm_scale
+    scores = jnp.where(mask[:, None, :], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhs,bsc->bhc", probs, rows[..., :latent])
+    out = jnp.where(mask.any(axis=1)[:, None, None], out, 0.0)
+    return out.astype(q.dtype)
+
+
+def _mla_paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
+                             q_ref, pool_hbm, o_ref,
+                             buf, sems, acc_ref, m_ref, l_ref, *,
+                             sm_scale: float, page_size: int,
+                             block_pages: int, max_pages: int,
+                             latent: int):
+    b = pl.program_id(0)
+    slots, width = buf.shape[0], buf.shape[3]
+    bk = block_pages * page_size
+    layer = layer_ref[0]
+    length = len_ref[b]
+    n_pages = jnp.minimum(pl.cdiv(length, page_size), max_pages)
+    n_blocks = pl.cdiv(n_pages, block_pages)
+
+    def page_at(blk, p):
+        idx = blk * block_pages + p
+        page = pt_ref[b * max_pages + jnp.minimum(idx, max_pages - 1)]
+        return page, (idx < n_pages) & (page >= 0)
+
+    def each_copy(blk, act):
+        slot = blk % slots
+        for p in range(block_pages):
+            page, live = page_at(blk, p)
+
+            @pl.when(live)
+            def _():
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[layer, page], buf.at[slot, p],
+                    sems.at[slot]))
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(b == 0)
+    def _():
+        # as in the per-head kernel: a page not copied in must hold
+        # finite values (0 * NaN)
+        buf[:] = jnp.zeros_like(buf)
+
+    for ahead in range(slots - 1):
+        @pl.when(ahead < n_blocks)
+        def _():
+            each_copy(ahead, lambda copy: copy.start())
+
+    q = q_ref[0]                                         # (heads, width)
+
+    def body(blk, carry):
+        @pl.when(blk + slots - 1 < n_blocks)
+        def _():
+            each_copy(blk + slots - 1, lambda copy: copy.start())
+
+        each_copy(blk, lambda copy: copy.wait())
+        slot = blk % slots
+        pos = blk * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        page_of = lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page_size
+        seen = pos < length
+        for p in range(block_pages):
+            _, live = page_at(blk, p)
+            seen = seen & ((page_of != p) | live)
+        rows = buf[slot].reshape(bk, width)
+        s = lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+        s = jnp.where(seen, s, DEFAULT_MASK_VALUE)       # (heads, bk)
+        m_prev = m_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        prob = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l_new = alpha * l_ref[:, :1] + jnp.sum(prob, axis=-1,
+                                               keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
+            prob.astype(rows.dtype), rows[:, :latent],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        return carry
+
+    lax.fori_loop(0, n_blocks, body, 0)
+    l = l_ref[:, :1]
+    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+        o_ref.dtype)
+
+
+# jitted for the reason `_paged_decode_call` is: traced once a program
+@functools.partial(jax.jit,
+                   static_argnames=("latent", "sm_scale", "interpret"))
+def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
+                           latent: int, sm_scale: float, interpret: bool):
+    B, heads, width = q.shape
+    page_size = pool.shape[2]
+    if not mla_paged_decode_tiles(width, latent, page_size, pool.dtype):
+        raise ValueError(
+            f"the latent decode kernel does not tile rows of {width} "
+            f"(value part {latent}) in {page_size}-position pages of "
+            f"{pool.dtype}")
+    # the heads are a matmul's rows: whole sublanes of them
+    sublanes = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
+    hp = -(-heads // sublanes) * sublanes
+    qp = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
+    max_pages = page_tables.shape[1]
+    block_pages = min(MLA_BLOCK_PAGES, max_pages)
+    kernel = functools.partial(
+        _mla_paged_decode_kernel, sm_scale=sm_scale, page_size=page_size,
+        block_pages=block_pages, max_pages=max_pages, latent=latent)
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, hp, width), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, hp, latent),
+                                   lambda b, *_: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((BLOCK_SLOTS, block_pages, page_size, width),
+                           pool.dtype),
+                pltpu.SemaphoreType.DMA((BLOCK_SLOTS,)),
+                pltpu.VMEM((hp, latent), jnp.float32),    # acc
+                pltpu.VMEM((hp, 128), jnp.float32),       # running max
+                pltpu.VMEM((hp, 128), jnp.float32),       # running sum
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, hp, latent), q.dtype),
+        interpret=interpret,
+        name=KERNEL_MLA_PAGED_DECODE,
+    )
+    with jax.named_scope(KERNEL_MLA_PAGED_SCOPE):
+        out = call(jnp.asarray(layer, jnp.int32).reshape(1),
+                   lengths.astype(jnp.int32),
+                   page_tables.astype(jnp.int32).reshape(-1), qp, pool)
+    return out[:, :heads]
+
+
+def mla_uses_kernel(width: int, latent: int, page_size: int, dtype) -> bool:
+    """What `mla_paged_decode_attention` decides, for a caller that
+    reports it: the platform being traced for and the shapes."""
+    return on_tpu() and mla_paged_decode_tiles(width, latent, page_size,
+                                               dtype)
+
+
+def mla_paged_decode_attention(q, pool, layer, page_tables, lengths,
+                               latent: int, sm_scale: float):
+    """Dispatching entry point of the latent pool's decode attention: the
+    compiled kernel on a TPU where the shapes tile, the gather + einsum
+    reference elsewhere. Shapes as `mla_paged_attention_reference`."""
+    if mla_uses_kernel(q.shape[-1], latent, pool.shape[2], pool.dtype):
+        return _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
+                                      latent, float(sm_scale), False)
+    return mla_paged_attention_reference(q, pool, layer, page_tables,
+                                         lengths, latent, sm_scale)
+
+
+def mla_paged_decode_attention_kernel(q, pool, layer, page_tables, lengths,
+                                      latent: int, sm_scale: float):
+    """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
+    return _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
+                                  latent, float(sm_scale), not on_tpu())

@@ -130,8 +130,7 @@ class EngineCore:
                  num_pages: int = 0, page_size: int = 16,
                  max_batch: int = 8):
         import jax
-        from ray_tpu.models import Transformer
-        from ray_tpu.models import decode as _dec
+        from ray_tpu.models import build_model
         self.config = config
         self.page_size = int(page_size)
         self.max_batch = int(max_batch)
@@ -144,10 +143,11 @@ class EngineCore:
             num_pages = self.max_batch * self.max_pages_per_seq
         self.num_pages = int(num_pages)
         self.alloc = PageAllocator(self.num_pages)
-        self.model = Transformer(config, mesh=mesh)
+        # the config's type names the model class; the engine asks the
+        # model for its cache and programs and names no class itself
+        self.model = build_model(config, mesh)
         self.params = params
-        self._cache = _dec.init_paged_cache(config, self.num_pages,
-                                            self.page_size, mesh=mesh)
+        self._cache = self.model.init_cache(self.num_pages, self.page_size)
         # both programs update the pool in place: the cache argument is
         # donated (whoever holds the old one holds a deleted buffer), and
         # on a mesh every step hands the cache back as it lay, whatever
@@ -155,7 +155,6 @@ class EngineCore:
         self._jit = jax.jit if mesh is None else functools.partial(
             jax.jit, out_shardings=(None, jax.tree.map(
                 lambda a: a.sharding, self._cache)))
-        self._dec = _dec
         self._waiting: deque = deque()
         self._running: List[_Seq] = []
         self._by_rid: Dict[str, _Seq] = {}
@@ -165,14 +164,13 @@ class EngineCore:
         self._np = __import__("numpy")
 
         def _step(params, cache, tokens, positions, pts, active):
-            return _dec.decode_step(self.model, params, cache, tokens,
-                                    positions, pts, active,
-                                    self.page_size)
+            return self.model.decode_step(params, cache, tokens,
+                                          positions, pts, active,
+                                          self.page_size)
         self._decode_fn = self._jit(_step, donate_argnums=(1,))
-        # which attention the decode step holds (the paged kernel's name
+        # which attention the decode step holds (a paged kernel's name
         # or "einsum"), decided where the step is traced: here
-        self._attention = _dec.decode_attention(
-            config, self.page_size, self._cache["k"].dtype)
+        self._attention = self.model.decode_attention(self.page_size)
         self._devices = (list(mesh.devices.flat) if mesh is not None
                          else jax.devices()[:1])
         chips = os.environ.get("TPU_VISIBLE_CHIPS", "")
@@ -197,7 +195,11 @@ class EngineCore:
             "decode_steps": 0, "decode_kernel_steps": 0,
             "decode_lane_steps": 0,
             # cache positions those lanes held / the dispatches read
-            "kv_positions_live": 0, "kv_positions_read": 0}
+            "kv_positions_live": 0, "kv_positions_read": 0,
+            # and what the model counts on the device in a decode step,
+            # under the model's own names (`step_stats`: the counts come
+            # back with the step's tokens and are summed over the steps)
+            **dict.fromkeys(self.model.step_stats(self._cache), 0)}
         # the steps that took SLOW_STEP_S or more: wall seconds, when,
         # the decode batch and the seconds in each phase
         self.slow_steps: deque = deque(maxlen=16)
@@ -279,9 +281,9 @@ class EngineCore:
         fn = self._prefill_fns.get(s_pad)
         if fn is None:
             def _pre(params, tokens, true_len, page_table, cache):
-                return self._dec.prefill(self.model, params, tokens,
-                                         true_len, page_table, cache,
-                                         self.page_size)
+                return self.model.prefill(params, tokens, true_len,
+                                          page_table, cache,
+                                          self.page_size)
             fn = self._jit(_pre, donate_argnums=(4,))
             self._prefill_fns[s_pad] = fn
             self.counters["prefill_programs"] += 1
@@ -450,9 +452,20 @@ class EngineCore:
                     live_positions=live, read_positions=read):
             logits, self._cache = self._decode_fn(
                 self.params, self._cache, *args)
+        on_device = self.model.step_stats(self._cache)
+        counts: Dict[str, int] = {}
         with _Phase(phases, _sp.FETCH):
-            next_tokens = np.asarray(logits.argmax(axis=-1))
-        with _Phase(phases, _sp.EMIT):
+            if on_device:   # one fetch: the tokens and the step's counts
+                next_tokens, fetched = self._jax.device_get(
+                    (logits.argmax(axis=-1), on_device))
+                counts = {name: int(n) for name, n in fetched.items()}
+                for name, n in counts.items():
+                    c[name] += n
+            else:
+                next_tokens = np.asarray(logits.argmax(axis=-1))
+        # the model's counts of this step are known only now: they ride
+        # the step's last span (attributes are fixed when a span opens)
+        with _Phase(phases, _sp.EMIT, **counts):
             for i, seq in enumerate(batch):
                 self._emit(events, seq, int(next_tokens[i]))
         return events, len(batch)
@@ -471,16 +484,29 @@ class EngineCore:
         return sum(s.remaining for s in self._running) \
             + sum(s.remaining for s in self._waiting)
 
-    def device_stats(self) -> dict:
+    def device_stats(self, in_cache: Optional[dict] = None) -> dict:
         """Where this engine runs, as JAX reports it, plus the chips the
         scheduler granted the process (device ids are per process: four
-        one-chip replicas all hold device 0, each of another chip), and
-        which attention its compiled decode step holds."""
+        one-chip replicas all hold device 0, each of another chip),
+        which attention its compiled decode step holds (a paged kernel's
+        name or "einsum"), what a cache position costs, and what the
+        model keeps in the cache beside the pages (pairs an expert):
+        `in_cache`, for a caller that read `cache_stats()` where no step
+        could run, else read here."""
         return {**self._device_info,
                 "decode_attention": self._attention,
+                "cache_bytes_per_position":
+                    self.model.cache_page_bytes(self.page_size)
+                    // self.page_size,
+                **(self.cache_stats() if in_cache is None else in_cache),
                 "bytes_in_use": [
                     (d.memory_stats() or {}).get("bytes_in_use")
                     for d in self._devices]}
+
+    def cache_stats(self) -> dict:
+        """What the model keeps in the cache beside the pages. Not while
+        a step runs: the cache is donated to it."""
+        return self.model.cache_stats(self._cache)
 
     def stats(self) -> dict:
         return {"waiting": len(self._waiting),
@@ -497,8 +523,9 @@ class LLMEngine:
     """Serve deployment class: one continuous-batching engine per
     replica group.
 
-    init is serve-replica friendly: `model` is a preset name or a
-    TransformerConfig kwargs dict; `weights` is an ObjectRef (cold
+    init is serve-replica friendly: `model` is a preset name, a dict of
+    config fields or a config object (`models.model_config`; the
+    config's type names the model class); `weights` is an ObjectRef (cold
     replicas pull it through the object plane, which the r12 broadcast
     relay pre-seeds on every node) or None to init from `seed`;
     `mesh` is an axes dict (e.g. {"dp": 1, "tp": 2}) building this
@@ -512,16 +539,10 @@ class LLMEngine:
                  seed: int = 0):
         import jax
         from ray_tpu._private.config import CONFIG
-        from ray_tpu.models import Transformer
-        from ray_tpu.models.config import PRESETS, TransformerConfig
+        from ray_tpu.models import build_model, model_config
         from ray_tpu.util.compile_cache import use_compile_cache
         use_compile_cache()
-        if isinstance(model, str):
-            config = PRESETS[model]()
-        elif isinstance(model, dict):
-            config = TransformerConfig(**model)
-        else:
-            config = model
+        config = model_config(model)
         built_mesh = None
         if mesh:
             # the replica's own mesh, over as many of its devices as the
@@ -543,7 +564,7 @@ class LLMEngine:
             tp = built_mesh.shape.get("tp", 1) if built_mesh else 1
             num_pages = pages_from_budget(config, page_size,
                                           kv_budget_bytes, tp_shards=tp)
-        model = Transformer(config, mesh=built_mesh)
+        model = build_model(config, built_mesh)
         shardings = None
         if built_mesh is not None:
             # the replica's weights lie on its mesh as the training
@@ -773,9 +794,10 @@ class LLMEngine:
         return descs
 
     def engine_stats(self) -> dict:
-        with self._lock:
+        with self._lock:        # no step runs: the cache is not donated
             st = self.core.stats()
-        st.update(self.core.device_stats())
+            in_cache = self.core.cache_stats()
+        st.update(self.core.device_stats(in_cache))
         st["pid"] = os.getpid()
         st["failed"] = self._failed
         st["incarnation"] = self.incarnation

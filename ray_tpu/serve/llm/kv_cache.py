@@ -1,6 +1,9 @@
 """KV-cache page bookkeeping for the LLM engine.
 
-The device-side page arrays live in `ray_tpu.models.decode`; this
+The device-side page arrays are the model's (`model.init_cache`:
+`ray_tpu.models.decode`'s keys and values per kv head for `Transformer`,
+one latent row a position for `MLAMoE`, whose page costs
+`layers * page_size * row_width` whatever the heads); this
 module owns the host-side pool: which pages are free, which sequence
 holds which pages, and how many pages a replica can afford given its
 mesh shards. Pure Python so the tier-1 tests exercise alloc / free /
@@ -18,12 +21,13 @@ def pages_needed(n_positions: int, page_size: int) -> int:
 
 def pages_from_budget(config, page_size: int, budget_bytes: int,
                       tp_shards: int = 1, dtype=None) -> int:
-    """Pool size a per-shard HBM budget affords: the cache splits its
-    kv heads across tp shards, so doubling tp doubles the pages the
-    same per-chip budget buys (the mesh-sized cache of the tentpole)."""
-    from ray_tpu.models.decode import cache_page_bytes
-    per_page = cache_page_bytes(config, page_size, tp_shards=tp_shards,
-                                dtype=dtype)
+    """Pool size a per-shard HBM budget affords, by what the config's
+    model says a page costs a shard: a cache of keys and values per head
+    splits its kv heads across tp shards, so doubling tp doubles the
+    pages the same per-chip budget buys; a latent cache does not."""
+    from ray_tpu.models import build_model
+    per_page = build_model(config).cache_page_bytes(
+        page_size, tp_shards=tp_shards, dtype=dtype)
     return max(0, budget_bytes // per_page)
 
 

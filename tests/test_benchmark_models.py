@@ -1,0 +1,270 @@
+"""Tier-1 guard of the benchmark's seam (PR 28 left it for a PR that may
+touch `tests/`): every configuration in BENCHMARK.json names a model module
+with the whole interface, its `reduced` keys are accounted for, every cell
+finds its files, every metric lists cells that exist, and each module's
+program agrees with its own plain reference at `tiny(cfg)` on the CPU.
+Also the readers of the per-layer metrics PR 29 added, on events built by
+hand.
+"""
+import importlib.util
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+BENCH = os.path.join(ROOT, "benchmarks")
+
+from benchmarks.harness import modelcfg, spans, xplane       # noqa: E402
+from benchmarks.harness.peaks import PEAKS                   # noqa: E402
+from benchmarks.harness.reference import rel_rms             # noqa: E402
+from benchmarks.harness.weights import leaves, make_weights  # noqa: E402
+
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCHMARK = json.load(_f)
+CONFIGS = BENCHMARK["configs"]
+CELLS = BENCHMARK["workloads"]
+METRICS = BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]
+
+
+def _cfg(entry):
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        return json.load(f)
+
+
+def by_name(items):
+    return pytest.mark.parametrize("entry", items,
+                                   ids=[x["name"] for x in items])
+
+
+# ------------------------------------------------------ configurations
+@by_name(CONFIGS)
+def test_configuration_names_a_model_module_with_the_interface(entry):
+    cfg = _cfg(entry)
+    assert cfg["name"] == entry["name"] and cfg["source"] == entry["source"]
+    model = modelcfg.load_model(cfg)
+    for name in modelcfg.INTERFACE:
+        assert callable(getattr(model, name)), name
+    sz = model.sizes(cfg)
+    assert sz.vocab == cfg["vocab_size"]
+    flat, _ = leaves(model.weight_shapes(sz))
+    assert model.param_count(sz) == sum(
+        int(np.prod(shape)) for shape, _ in flat)
+    assert cfg.get("parameters", model.param_count(sz)) == \
+        model.param_count(sz)
+    assert model.matmul_params(sz) < model.param_count(sz)
+
+
+@by_name(CONFIGS)
+def test_reduced_keys_are_listed_with_their_published_values(entry):
+    cfg = _cfg(entry)
+    assert cfg["reduced"] == entry["reduced"]
+    assert set(cfg["published"]) == set(cfg["reduced"])
+    for key in cfg["reduced"]:
+        assert cfg[key] != cfg["published"][key], key
+        # a cut is of depth, context or count; never of a width
+        assert not key.endswith(("_dim", "_rank", "_size")), key
+    dep = cfg["deployment"]
+    assert dep["chips"] in (1, 4)
+    if "context_limit" in dep:
+        assert dep["context_limit"] <= cfg["max_position_embeddings"]
+        assert dep["num_pages"] * dep["page_size"] >= dep["context_limit"]
+    ref = cfg["reference"]
+    limit = ref["limit"] if "limit" in ref else ref["grad_limit"]
+    assert ref["sound_largest"] < limit < ref["control_smallest"]
+
+
+@by_name(CONFIGS)
+def test_program_agrees_with_the_reference_at_tiny_size(entry):
+    """The program's full forward in the configuration's own precision
+    against `reference_rows` (float32), seeded weights, on the CPU."""
+    from ray_tpu.models import build_model
+    cfg = _cfg(entry)
+    model = modelcfg.load_model(cfg)
+    small = model.tiny(cfg)
+    sz = model.sizes(small)
+    params = make_weights(model.weight_shapes(sz), 3000000021)
+    program = build_model(model.program_config(small, max_seq_len=128))
+    toks = np.zeros((128,), np.int32)
+    toks[:72] = np.random.default_rng(0).integers(0, sz.vocab, 72)
+    got = program.apply(params, jnp.asarray(toks[None, :72]))[0, 40:72]
+    want = model.reference_rows(sz, params, jnp.asarray(toks),
+                                jnp.int32(40), 32)
+    assert want.shape == (32, sz.vocab)
+    assert rel_rms(got, want) < 0.06
+    control = model.reference_rows(sz, params, jnp.asarray(toks),
+                                   jnp.int32(40), 32, True)
+    assert rel_rms(control, want) > rel_rms(got, want)
+    # the train model's loss is the program's, and finite
+    loss = model.train_model(small, 72).loss(
+        params, {"tokens": jnp.asarray(toks[None, :72])})
+    assert abs(float(loss) - float(model.loss_fn(
+        sz, params, jnp.asarray(toks[:72])))) < 0.05
+
+
+# ------------------------------------------------------------- cells
+@by_name(CELLS)
+def test_cell_finds_its_files_and_reports_enough(entry):
+    from benchmarks.harness.cells import load_cell
+    _, cell, cfg, mix = load_cell(entry["name"])
+    assert cell["chips"] == cfg["deployment"]["chips"] == 1
+    assert os.path.isfile(os.path.join(BENCH, "models",
+                                       cfg["model"] + ".py"))
+    assert os.path.isfile(os.path.join(BENCH, "traffic",
+                                       entry["traffic"] + ".json"))
+    assert entry["name"] == f"{entry['config']}.{entry['traffic']}"
+    assert len(entry["why"]) <= 200
+    reports = {g: [m["name"] for m in BENCHMARK[g]
+                   if "workloads" not in m or entry["name"] in m["workloads"]]
+               for g in ("end_to_end", "per_layer")}
+    assert "setup_s" in reports["end_to_end"]
+    assert len(reports["end_to_end"]) >= 2 and reports["per_layer"]
+    if mix["kind"] == "closed_loop":
+        dep = cfg["deployment"]
+        assert mix["clients"] >= dep["max_batch"]
+        longest = mix["prompt_tokens"]["max"] + mix["output_tokens"][
+            "exactly"]
+        assert longest <= dep["context_limit"]
+
+
+@by_name(METRICS)
+def test_metric_has_a_reader_and_lists_cells_that_exist(entry):
+    assert os.path.isfile(os.path.join(BENCH, "metrics",
+                                       entry["name"] + ".py"))
+    cells = {c["name"] for c in CELLS}
+    assert set(entry.get("workloads", cells)) <= cells
+    if "moves" in entry:
+        moved = {m["name"]: m for m in BENCHMARK["end_to_end"]}[
+            entry["moves"]]
+        assert set(entry["workloads"]) <= set(moved.get("workloads", cells))
+        assert callable(metric(entry["name"]))
+
+
+def test_a_fifth_of_the_cells_may_take_four_chips_and_none_does():
+    assert len(CELLS) == 4 and not [c for c in CELLS if c["chips"] != 1]
+    assert len({(c["config"], c["traffic"]) for c in CELLS}) == len(CELLS)
+
+
+# --------------------------------------------- PR 29's metric readers
+def metric(name):
+    spec = importlib.util.spec_from_file_location(
+        "m_" + name.replace(".", "_"),
+        os.path.join(BENCH, "metrics", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+GLM = "glm-4.7-flash-1chip"
+E = xplane.Event
+
+
+def _kernel(name, i, start, dur):
+    return E(f"%{name}.{i} = bf16[32,2048] custom-call(...), "
+             f"custom_call_target=\"tpu_custom_call\"", start, dur)
+
+
+@pytest.fixture()
+def traced_glm_run():
+    """Two decode steps of 10 ms from t = 0 and t = 0.02, a prefill between
+    them: in a step, every 1.4 ms a latent kernel of 0.2 ms and, in the six
+    expert layers, three grouped matmuls of 0.3 ms behind it."""
+    cfg = modelcfg.load_config(GLM)
+    model = modelcfg.load_model(cfg)
+    ops, modules = [], []
+    for s, t0 in enumerate((0.0, 0.02)):
+        modules.append(E("jit__step(7)", t0, 0.010))
+        for layer in range(7):
+            t = t0 + 1.4e-3 * layer
+            ops.append(_kernel("mla_paged_decode_attn", layer, t, 0.2e-3))
+            for j in range(3 if layer else 0):
+                ops.append(_kernel("moe_gmm", 3 * layer + j,
+                                   t + 0.3e-3 * (j + 1), 0.3e-3))
+    modules.append(E("jit__pre(9)", 0.011, 0.008))
+    ops += [_kernel("moe_gmm", 90 + j, 0.012 + 1e-3 * j, 0.9e-3)
+            for j in range(3)]
+    ops.sort(key=lambda e: e.start)
+    modules.sort(key=lambda e: e.start)
+    steps = []
+    for t0 in (0.0, 0.02):
+        steps.append(E(spans.DISPATCH, t0, 1e-4, {
+            "lanes": 32, "live_positions": 36800, "read_positions": 37000}))
+        steps.append(E("engine.emit", t0 + 0.011, 1e-4, {
+            "moe_pairs": 768, "moe_experts_touched": 330,
+            "moe_load_max": 42}))
+    return {"trace": xplane.Trace({0: modules}, {0: ops}, {}, {}),
+            "model": model, "sizes": model.sizes(cfg), "cfg": cfg,
+            "peaks": PEAKS["TPU v5 lite"], "result": {"traced": {}},
+            "_spans": spans.Reading(steps, {}, 0.0)}
+
+
+def test_mla_decode_roofline_from_live_positions(traced_glm_run):
+    run = traced_glm_run
+    need = run["model"].mla_decode_call(run["sizes"], 73600, 64)
+    # 576 numbers a position a layer, once; queries in, latents out
+    assert need["bytes"] == 7 * 2 * (73600 * 576 + 64 * 20 * (576 + 512))
+    assert need["flops"] == 2.0 * 20 * (576 + 512) * 73600 * 7
+    want = 100 * (need["bytes"] / 819e9) / (14 * 0.2e-3)
+    assert metric("kernel.mla_decode_roofline.batch32")(run) == \
+        pytest.approx(want, rel=1e-6)
+    assert 0 < want < 100
+
+
+def test_moe_gmm_roofline_counts_touched_experts_and_decode_steps_only(
+        traced_glm_run):
+    run = traced_glm_run
+    need = run["model"].moe_gmm_call(run["sizes"], 1536, 660)
+    assert need["bytes"] == 660 * 3 * 2048 * 1536 * 2 + 1536 * 2 * 2048 * 2
+    assert need["flops"] == 6.0 * 2048 * 1536 * 1536
+    # 36 events inside the two steps; the prefill's three are left out
+    want = 100 * (need["bytes"] / 819e9) / (36 * 0.3e-3)
+    assert metric("kernel.moe_gmm_roofline.batch32")(run) == \
+        pytest.approx(want, rel=1e-6)
+    # every expert held would be 64 x 6 x 2 = 768 > 660 touched
+    assert need["bytes"] < run["model"].moe_gmm_call(
+        run["sizes"], 1536, 768)["bytes"]
+
+
+def test_step_moe_ms_is_the_span_between_latent_kernels(traced_glm_run):
+    # 1.4 ms from a kernel's start to the next, less its own 0.2 ms, six
+    # expert layers
+    assert metric("step.moe_ms.batch32")(traced_glm_run) == pytest.approx(
+        6 * 1.2, rel=1e-6)
+
+
+def test_expert_counts_read_from_the_emit_spans(traced_glm_run):
+    run = traced_glm_run
+    assert metric("moe.experts_touched_share.batch32")(run) == \
+        pytest.approx(100 * 660 / (64 * 6 * 2))
+    assert metric("moe.load_max_over_mean.batch32")(run) == \
+        pytest.approx(84 * 64 / 1536)
+
+
+@pytest.mark.parametrize("name", [
+    "kernel.mla_decode_roofline.batch32", "kernel.moe_gmm_roofline.batch32",
+    "step.moe_ms.batch32", "moe.experts_touched_share.batch32",
+    "moe.load_max_over_mean.batch32"])
+def test_new_metrics_leave_the_line_where_there_is_nothing_to_read(
+        traced_glm_run, name):
+    """The parent's program (no such kernel, no such span attribute), a
+    dense model's module, an untraced run: None, and nothing raised."""
+    run = dict(traced_glm_run)
+    dense = modelcfg.load_config("internlm2-1.8b")
+    plain = [E(spans.DISPATCH, 0.0, 1e-4, {"lanes": 8, "live_positions": 9,
+                                            "read_positions": 16}),
+             E("engine.emit", 0.01, 1e-4, {})]
+    run.update(
+        trace=xplane.Trace({0: [E("jit__step(7)", 0.0, 0.01)]},
+                           {0: [E("%fusion.1 = bf16[8] fusion()", 0, 1e-3)]},
+                           {}, {}),
+        model=modelcfg.load_model(dense), cfg=dense,
+        sizes=modelcfg.load_model(dense).sizes(dense),
+        _spans=spans.Reading(plain, {}, 0.0))
+    assert metric(name)(run) is None
+    run.update(trace=None, _spans=None)
+    assert metric(name)(run) is None

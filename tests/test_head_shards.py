@@ -213,6 +213,8 @@ def test_agent_decref_storm_rides_delta_frames():
     frames (not per-connection DECREF_BATCH forwards) and the objects
     must actually delete."""
     from ray_tpu.cluster_utils import NodeAgentProcess
+    if ray_tpu.is_initialized():      # a shared suite runtime may be
+        ray_tpu.shutdown()            # live (one runtime per process)
     rt = ray_tpu.init(num_cpus=0)
     agent = None
     try:

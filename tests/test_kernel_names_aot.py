@@ -176,3 +176,74 @@ def test_decode_step_for_the_chip_updates_the_pool_in_place(decode_step):
     # in fusions or bare
     assert made and set(made) <= {"parameter", "scatter", "fusion",
                                   "bitcast", "get-tuple-element"}, made
+
+
+# ------------------------------------- the second architecture's step
+def _compile_mla_moe(devices, which: str):
+    """`MLAMoE`'s decode step or 1024-token prefill as `EngineCore` jits
+    them, for one chip: the dense layer and one expert layer at the
+    published widths of GLM-4.7-Flash (20 heads, latent 512 + 64, 64
+    experts of 2048 x 1536), 32 lanes, 16-token bf16 pages."""
+    from ray_tpu.models.mla_moe import MLAMoE, MLAMoEConfig
+    cfg = MLAMoEConfig(vocab_size=1024, n_layers=2, max_seq_len=2048)
+    model = MLAMoE(cfg)
+    one = SingleDeviceSharding(devices[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def ints(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(PAGES, PAGE)))
+    lanes, tables = 32, cfg.max_seq_len // PAGE
+    with compute_platform("tpu"):
+        assert model.decode_attention(PAGE) == "mla_paged_decode_attn"
+        if which == "step":
+            def _step(params, cache, tokens, positions, pts, active):
+                return model.decode_step(params, cache, tokens, positions,
+                                         pts, active, PAGE)
+            traced = jax.jit(_step, donate_argnums=(1,)).trace(
+                params, cache, ints(lanes), ints(lanes),
+                ints(lanes, tables), jax.ShapeDtypeStruct(
+                    (lanes,), jnp.bool_, sharding=one))
+        else:
+            def _pre(params, tokens, true_len, page_table, cache):
+                return model.prefill(params, tokens, true_len, page_table,
+                                     cache, PAGE)
+            traced = jax.jit(_pre, donate_argnums=(4,)).trace(
+                params, ints(1024), ints(), ints(tables), cache)
+        return traced.lower().compile(), cache["kv"].shape
+
+
+@pytest.fixture(scope="module", params=["step", "prefill"])
+def mla_moe_program(request, topo, no_compile_cache):
+    return (request.param,) + _compile_mla_moe(topo.devices, request.param)
+
+
+def test_mla_moe_programs_hold_their_kernels_by_name(mla_moe_program):
+    from ray_tpu.ops import grouped_matmul
+    which, compiled, _ = mla_moe_program
+    names = kernel_names(compiled.as_text())
+    # gate, up and down of the one expert layer
+    assert names.count(grouped_matmul.KERNEL_GMM) == 3
+    if which == "step":     # one latent kernel a layer, no flash kernel
+        assert names.count(paged_attention.KERNEL_MLA_PAGED_DECODE) == 2
+        assert attention.KERNEL_FWD not in names
+    else:                   # the expanded form at d = 256, a layer
+        assert names.count(attention.KERNEL_FWD) == 2
+        assert paged_attention.KERNEL_MLA_PAGED_DECODE not in names
+
+
+def test_mla_moe_programs_update_the_latent_pool_in_place(mla_moe_program):
+    _, compiled, pool = mla_moe_program
+    assert pool == (2, PAGES, PAGE, 640)
+    nbytes = 2 * pool[0] * pool[1] * pool[2] * pool[3]
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    shape = ",".join(map(str, pool))
+    made = re.findall(rf"= \w+\[{shape}\]\S* ([\w\-]+)\(",
+                      compiled.as_text())
+    assert made and set(made) <= {"parameter", "scatter", "fusion",
+                                  "bitcast", "get-tuple-element"}, made

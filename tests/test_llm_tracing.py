@@ -323,3 +323,76 @@ def test_engine_module_imports_no_jax():
         capture_output=True, text=True, timeout=60,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert out.stdout.strip() == "False", out.stderr[-400:]
+
+
+# ------------------------------------------- the second architecture
+@pytest.fixture(scope="module")
+def tiny_mla():
+    from ray_tpu.models.mla_moe import MLAMoE, tiny_mla_moe
+    cfg = tiny_mla_moe()
+    return cfg, MLAMoE(cfg).init(jax.random.PRNGKey(0))
+
+
+def test_program_names_are_the_same_for_the_second_architecture(tiny_mla):
+    """`jit__step` / `jit__pre` whatever the model: the engine jits its own
+    two functions, and the model is what they call."""
+    core = _core(tiny_mla)
+    B, P = core.max_batch, core.max_pages_per_seq
+    dec = core._decode_fn.lower(
+        core.params, core._cache, jnp.zeros((B,), jnp.int32),
+        jnp.zeros((B,), jnp.int32), jnp.zeros((B, P), jnp.int32),
+        jnp.zeros((B,), bool))
+    assert "module @jit__step" in dec.as_text()
+    pre = core._prefill_fn(16).lower(
+        core.params, jnp.zeros((16,), jnp.int32), jnp.int32(3),
+        jnp.zeros((P,), jnp.int32), core._cache)
+    assert "module @jit__pre" in pre.as_text()
+
+
+def test_kernel_and_counter_names_the_expert_metrics_read():
+    """`kernel.mla_decode_roofline.batch32`, `kernel.moe_gmm_roofline.
+    batch32` and `step.moe_ms.batch32` find device events by these names
+    (`benchmarks/harness/decode_events.py`), and the `moe.*` metrics the
+    attributes of `engine.emit`."""
+    from ray_tpu.ops import grouped_matmul, paged_attention
+    assert paged_attention.KERNEL_MLA_PAGED_DECODE == "mla_paged_decode_attn"
+    assert grouped_matmul.KERNEL_GMM == "moe_gmm"
+    assert sp.EMIT == "engine.emit" and sp.DISPATCH == \
+        "engine.decode_dispatch"
+    # (that a compiled program carries them: tests/test_kernel_names_aot.py)
+
+
+def test_expert_counts_ride_the_step_s_emit_span(tiny_mla, recorder):
+    cfg, _ = tiny_mla
+    core = _core(tiny_mla)
+    core.submit([3, 17, 91, 254, 8], max_tokens=3, rid="a")
+    core.submit([4, 5, 6], max_tokens=2, rid="b")
+    _run(core)
+    evs = _mine(recorder)
+    assert {e[4] for e in evs} == CORE_SPANS | REQUEST_SPANS
+    emits = [e[7] for e in evs if e[4] == sp.EMIT]
+    lanes = [e[7]["lanes"] for e in evs if e[4] == sp.DISPATCH]
+    assert lanes == [2, 1]
+    per_lane = cfg.num_experts_per_tok * cfg.n_moe_layers
+    assert [e["moe_pairs"] for e in emits] == [n * per_lane for n in lanes]
+    for e, n in zip(emits, lanes):
+        assert cfg.n_moe_layers <= e["moe_load_max"] <= e["moe_pairs"]
+        assert e["moe_load_max"] <= e["moe_experts_touched"] * n
+        assert e["moe_experts_touched"] <= e["moe_pairs"]
+    st = core.stats()
+    assert st["moe_pairs"] == sum(e["moe_pairs"] for e in emits)
+    assert st["moe_experts_touched"] == sum(
+        e["moe_experts_touched"] for e in emits)
+    # latent rows: the same meaning of live and read positions
+    assert st["kv_positions_live"] == (6 + 4) + 7
+    assert core.device_stats()["decode_attention"] == "einsum"
+
+
+def test_a_dense_step_s_emit_span_carries_no_expert_count(tiny_model,
+                                                          recorder):
+    core = _core(tiny_model)
+    core.submit([1, 2, 3], max_tokens=2, rid="a")
+    _run(core)
+    emits = [e[7] for e in _mine(recorder) if e[4] == sp.EMIT]
+    assert len(emits) == 1 and not emits[0]
+    assert "moe_pairs" not in core.stats()

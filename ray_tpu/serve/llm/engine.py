@@ -5,9 +5,25 @@ every iteration first ADMITS waiting requests (prefill into free KV
 pages) and then DECODES every in-flight sequence by one token — so a
 short request admitted mid-flight finishes while a long one is still
 generating, and a long generation never convoys short ones behind it
-(vLLM's iteration-level scheduling, PAPERS.md serving economics). It
-has no threads and steps synchronously, which is what the tier-1
-tests drive.
+(vLLM's iteration-level scheduling, PAPERS.md serving economics).
+
+The decode loop is a pipeline one step deep. A call of `step()`
+dispatches this iteration's prefills and decode step from what the
+host can count (lengths, positions, page lists, `max_tokens`), and
+only then reads the tokens of the decode step dispatched by the call
+before: its events are one step behind its dispatch, and the host's
+work on them runs beside the device's next step. The next input
+tokens never visit the host: greedy sampling is a program on the
+device, whose token vector feeds the next step as it is (a sequence
+keeps one lane from admission to its end) and is fetched for emission
+a step late. A sequence that ends by `max_tokens` gives up its lane
+and pages when its last step is dispatched (the device runs programs
+in the order given, so pages reused by a later prefill are written
+after the step that last touched them); one that ends by a stop token
+is known a step late and runs one step more, whose token is dropped.
+Eviction and `drain()` read what is in flight first; `cancel()` does
+not wait. The core has no threads, which is what the tier-1 tests
+drive: a last call with nothing to dispatch reads what is in flight.
 
 `LLMEngine` wraps the core as a Serve deployment class: a background
 step thread, per-request token buffers for the polled fallback, and a
@@ -31,7 +47,7 @@ import time
 import traceback
 import uuid
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ray_tpu._private import tracing_plane as _tp
 from ray_tpu.serve.llm import spans as _sp
@@ -108,22 +124,36 @@ class _Seq:
     evictions: int = 0
     trace_id: int = 0           # the llm.* spans of this request
     first_token_t: Optional[float] = None
+    lane: int = -1              # the decode lane held, or -1
+    # tokens asked of the device: those emitted and those not read yet
+    asked: int = 0
 
     @property
-    def total_len(self) -> int:
-        return len(self.prompt) + len(self.emitted)
+    def device_len(self) -> int:
+        """Positions the cache holds once everything asked for ran."""
+        return len(self.prompt) + self.asked
 
     @property
     def remaining(self) -> int:
         return max(0, self.max_tokens - len(self.emitted))
 
 
+@dataclasses.dataclass
+class _Flight:
+    """A decode step dispatched and not read yet."""
+    tokens: Any                         # (max_batch,) int32, on the device
+    counts: Dict[str, Any]              # the model's, of this step alone
+    lanes: List[Tuple[int, _Seq]]       # whose token each lane's is
+
+
 class EngineCore:
     """Deterministic (greedy) continuous-batching scheduler.
 
     step() events are dicts: {rid, token, seq, done, reason, first,
-    attempt}. `seq` indexes into this attempt's emitted tokens; a
-    client that re-prefilled elsewhere offsets by its resume base.
+    attempt}, those of the decode step dispatched one call earlier and
+    of this call's prefills. `seq` indexes into this attempt's emitted
+    tokens; a client that re-prefilled elsewhere offsets by its resume
+    base.
     """
 
     def __init__(self, config, params, mesh=None,
@@ -156,11 +186,17 @@ class EngineCore:
             jax.jit, out_shardings=(None, jax.tree.map(
                 lambda a: a.sharding, self._cache)))
         self._waiting: deque = deque()
+        # the sequences that hold a lane, oldest admission first
         self._running: List[_Seq] = []
+        self._lanes: List[Optional[_Seq]] = [None] * self.max_batch
+        # every sequence that waits or runs (one runs until its last
+        # token is read, which is after it gave up its lane)
         self._by_rid: Dict[str, _Seq] = {}
+        self._flight: Optional[_Flight] = None
+        # this call's prefills: (sequence, its first token on the device)
+        self._firsts: List[Tuple[_Seq, Any]] = []
         self._queue_waits: deque = deque(maxlen=1024)  # (t, wait_s)
         self._prefill_fns: Dict[int, Any] = {}
-        self._jax = jax
         self._np = __import__("numpy")
 
         def _step(params, cache, tokens, positions, pts, active):
@@ -168,6 +204,26 @@ class EngineCore:
                                           positions, pts, active,
                                           self.page_size)
         self._decode_fn = self._jit(_step, donate_argnums=(1,))
+        # greedy sampling where the logits are: the token vector is the
+        # next step's argument as it stands, replicated on a mesh
+        replicated = None if mesh is None else jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec())
+        small = jax.jit if mesh is None else functools.partial(
+            jax.jit, out_shardings=replicated)
+
+        def _next(logits, counts):
+            # the step's counts are copied out of the cache before the
+            # next step is given it
+            return (logits.argmax(axis=-1).astype("int32"),
+                    jax.tree.map(lambda n: n.copy(), counts))
+
+        def _place(tokens, lane, logits):
+            first = logits.argmax().astype("int32")
+            return tokens.at[lane].set(first), first
+        self._next_fn = small(_next)
+        self._place_fn = small(_place)      # a prefill's token to its lane
+        self._tokens = jax.device_put(
+            self._np.zeros((self.max_batch,), "int32"), replicated)
         # which attention the decode step holds (a paged kernel's name
         # or "einsum"), decided where the step is traced: here
         self._attention = self.model.decode_attention(self.page_size)
@@ -199,7 +255,14 @@ class EngineCore:
             # and what the model counts on the device in a decode step,
             # under the model's own names (`step_stats`: the counts come
             # back with the step's tokens and are summed over the steps)
-            **dict.fromkeys(self.model.step_stats(self._cache), 0)}
+            **dict.fromkeys(self.model.step_stats(self._cache), 0),
+            # decode steps dispatched before the one before was read;
+            # times what was in flight was read with nothing dispatched
+            # behind it (eviction, drain, no lane left to decode); and
+            # lane-steps whose token nobody got (a step past a stop
+            # token, a request cancelled with its step in flight)
+            "decode_steps_ahead": 0, "pipeline_flushes": 0,
+            "discarded_lane_steps": 0}
         # the steps that took SLOW_STEP_S or more: wall seconds, when,
         # the decode batch and the seconds in each phase
         self.slow_steps: deque = deque(maxlen=16)
@@ -239,23 +302,44 @@ class EngineCore:
         return rid
 
     def cancel(self, rid: str) -> bool:
+        """Forget a request now. A step in flight is not waited for: the
+        token its lane yields is dropped when the step is read."""
         seq = self._by_rid.pop(rid, None)
         if seq is None:
             return False
-        if seq in self._running:
-            self._running.remove(seq)
+        if seq.lane >= 0:
+            self._release(seq)
         elif seq in self._waiting:
             self._waiting.remove(seq)
-        if seq.pages:
-            self.alloc.free(seq.pages)
-            seq.pages = []
+        flight = self._flight
+        if flight is not None:
+            if any(s is seq for _, s in flight.lanes):
+                self.counters["discarded_lane_steps"] += 1
+            if not any(self._owns(s) for _, s in flight.lanes):
+                self._flight = None     # nobody's: abandoned, not read
         return True
 
-    def drain(self) -> List[dict]:
-        """Stop everything in flight and hand back re-dispatchable
-        descriptors (SUSPECT drain: the router re-prefills these on a
+    def _owns(self, seq: _Seq) -> bool:
+        """Whether `seq` still waits or runs under its request id."""
+        return self._by_rid.get(seq.rid) is seq
+
+    def _release(self, seq: _Seq) -> None:
+        """Give back the lane and the pages `seq` holds. Whatever was
+        dispatched before this reads and writes them first."""
+        self._running.remove(seq)
+        self._lanes[seq.lane] = None
+        seq.lane = -1
+        self.alloc.free(seq.pages)
+        seq.pages = []
+
+    def drain(self) -> Tuple[List[dict], List[dict]]:
+        """Stop everything in flight: returns the events of what the
+        device had been asked for, then re-dispatchable descriptors of
+        what is left (SUSPECT drain: the router re-prefills these on a
         surviving replica; `emitted` rides along so the survivor
         continues rather than restarts)."""
+        self._step_span = 0         # no step: its request spans are roots
+        events = self._flush({})
         out = []
         for seq in list(self._running) + list(self._waiting):
             out.append({"rid": seq.rid, "prompt": list(seq.prompt),
@@ -264,18 +348,14 @@ class EngineCore:
                         "stop": sorted(seq.stop),
                         "attempt": seq.attempt})
             self.cancel(seq.rid)
-        return out
+        return events, out
 
     # ------------------------------------------------------- stepping
     @property
     def has_work(self) -> bool:
-        return bool(self._waiting or self._running)
-
-    def _page_table(self, seq: _Seq):
-        np = self._np
-        pt = np.full((self.max_pages_per_seq,), -1, np.int32)
-        pt[:len(seq.pages)] = seq.pages
-        return pt
+        """True while a sequence waits or runs (one runs until its last
+        token is read)."""
+        return bool(self._by_rid)
 
     def _prefill_fn(self, s_pad: int):
         fn = self._prefill_fns.get(s_pad)
@@ -328,21 +408,28 @@ class EngineCore:
         returning its pages to the pool; the victim re-queues at the
         FRONT of the waiting line with its emitted tokens intact (it
         re-prefills prompt+emitted and continues — work is delayed,
-        never lost)."""
+        never lost). Nothing may be in flight: `emitted` has to be
+        whole."""
         for victim in reversed(self._running):
             if victim is keep:
                 continue
-            self._running.remove(victim)
-            self.alloc.free(victim.pages)
-            victim.pages = []
+            self._release(victim)
             victim.evictions += 1
             self._waiting.appendleft(victim)
             self.counters["evictions"] += 1
             return True
         return False
 
+    def _note_asked(self, seq: _Seq) -> None:
+        """One more token of `seq` was asked of the device. If it is the
+        last, by `max_tokens`, the lane and the pages come free now."""
+        seq.asked += 1
+        if seq.asked >= seq.max_tokens:
+            self._release(seq)
+
     def step(self) -> List[dict]:
-        """One engine iteration: admit, then decode everyone once."""
+        """One engine iteration: admit, dispatch the next decode step of
+        everyone, then read the tokens of the step before."""
         self.counters["steps"] += 1
         t0, t_mono_ns = _clock(), time.monotonic_ns()
         phases: Dict[str, float] = {}
@@ -359,7 +446,7 @@ class EngineCore:
         return events
 
     def _step(self, phases: Dict[str, float]):
-        """The step's work; returns (events, lanes decoded)."""
+        """The step's work; returns (events, lanes dispatched)."""
         import jax.numpy as jnp
         np = self._np
         c = self.counters
@@ -386,30 +473,39 @@ class EngineCore:
                                        now)
                 padded = np.zeros((s_pad,), np.int32)
                 padded[:len(toks)] = toks
+                pt = np.full((self.max_pages_per_seq,), -1, np.int32)
+                pt[:len(pages)] = pages
                 logits, self._cache = self._prefill_fn(s_pad)(
                     self.params, jnp.asarray(padded),
-                    jnp.int32(len(toks)),
-                    jnp.asarray(self._page_table(seq)), self._cache)
+                    jnp.int32(len(toks)), jnp.asarray(pt), self._cache)
+                seq.lane = self._lanes.index(None)
+                self._lanes[seq.lane] = seq
                 self._running.append(seq)
+                self._tokens, first = self._place_fn(
+                    self._tokens, np.int32(seq.lane), logits)
+                first.copy_to_host_async()
+                self._firsts.append((seq, first))
                 c["admitted"] += 1
                 c["prefill_tokens"] += len(toks)
                 c["prefill_padded_tokens"] += s_pad
-                self._emit(events, seq, int(logits.argmax()))
+                seq.asked = len(seq.emitted)
+                self._note_asked(seq)
 
         # ---- decode every in-flight sequence by one token
-        batch = [s for s in self._running]
-        for seq in list(batch):
-            if seq not in self._running:
-                continue       # evicted by an earlier seq's page grab
+        for seq in list(self._running):
             # page for the incoming token's KV write, evicting the
             # youngest other sequence if the pool is dry
-            while pages_needed(seq.total_len, self.page_size) \
-                    > len(seq.pages):
+            while seq.lane >= 0 and pages_needed(
+                    seq.device_len, self.page_size) > len(seq.pages):
                 got = self.alloc.alloc(1)
                 if got is not None:
                     seq.pages.extend(got)
-                    continue
-                if not self._evict_one(seq):
+                elif self._flight is not None or self._firsts:
+                    # a victim re-queues what it has emitted, so that has
+                    # to be whole first; a stop token read here may end a
+                    # sequence (this one too) and free the page wanted
+                    events += self._flush(phases)
+                elif not self._evict_one(seq):
                     # alone and out of pages: feasibility was checked
                     # at submit, so this cannot happen; guard anyway
                     self.cancel(seq.rid)
@@ -417,27 +513,25 @@ class EngineCore:
                                    "seq": len(seq.emitted), "first": False,
                                    "done": True, "reason": "oom",
                                    "attempt": seq.attempt})
-                    batch.remove(seq)
-                    break
-        batch = [s for s in batch if s in self._running]
+        batch = list(self._running)
         if not batch:
-            return events, 0
+            # nothing to dispatch behind what is in flight
+            return events + self._flush(phases), 0
         with _Phase(phases, _sp.TABLES):
             B = self.max_batch
-            tokens = np.zeros((B,), np.int32)
             positions = np.zeros((B,), np.int32)
             pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
             active = np.zeros((B,), bool)
             live = held = 0
-            for i, seq in enumerate(batch):
-                tokens[i] = seq.emitted[-1]
-                positions[i] = seq.total_len - 1
-                pts[i] = self._page_table(seq)
+            for seq in batch:
+                i = seq.lane
+                positions[i] = seq.device_len - 1
+                pts[i, :len(seq.pages)] = seq.pages
                 active[i] = True
-                live += seq.total_len
-                held += pages_needed(seq.total_len, self.page_size)
-            args = (jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(pts), jnp.asarray(active))
+                live += seq.device_len
+                held += pages_needed(seq.device_len, self.page_size)
+            args = (jnp.asarray(positions), jnp.asarray(pts),
+                    jnp.asarray(active))
         kernel = self._attention != "einsum"
         # the kernel copies in each lane's live pages, whole
         read = held * self.page_size if kernel else self._table_positions
@@ -451,24 +545,50 @@ class EngineCore:
         with _Phase(phases, _sp.DISPATCH, lanes=len(batch),
                     live_positions=live, read_positions=read):
             logits, self._cache = self._decode_fn(
-                self.params, self._cache, *args)
-        on_device = self.model.step_stats(self._cache)
-        counts: Dict[str, int] = {}
-        with _Phase(phases, _sp.FETCH):
-            if on_device:   # one fetch: the tokens and the step's counts
-                next_tokens, fetched = self._jax.device_get(
-                    (logits.argmax(axis=-1), on_device))
-                counts = {name: int(n) for name, n in fetched.items()}
-                for name, n in counts.items():
-                    c[name] += n
-            else:
-                next_tokens = np.asarray(logits.argmax(axis=-1))
-        # the model's counts of this step are known only now: they ride
-        # the step's last span (attributes are fixed when a span opens)
+                self.params, self._cache, self._tokens, *args)
+            self._tokens, counts = self._next_fn(
+                logits, self.model.step_stats(self._cache))
+            # the copies to the host start as soon as the step has run
+            for a in (self._tokens, *counts.values()):
+                a.copy_to_host_async()
+        before, self._flight = self._flight, _Flight(
+            self._tokens, counts, [(s.lane, s) for s in batch])
+        c["decode_steps_ahead"] += int(before is not None)
+        for seq in batch:
+            self._note_asked(seq)
+        return events + self._read(phases, before), len(batch)
+
+    def _flush(self, phases: Dict[str, float]) -> List[dict]:
+        """Read what is in flight with nothing dispatched behind it."""
+        flight, self._flight = self._flight, None
+        self.counters["pipeline_flushes"] += int(flight is not None)
+        return self._read(phases, flight)
+
+    def _read(self, phases: Dict[str, float],
+              flight: Optional[_Flight]) -> List[dict]:
+        """Fetch and emit the tokens of `flight`, a decode step, and the
+        first tokens of the prefills dispatched since the last read."""
+        firsts, self._firsts = self._firsts, []
+        if flight is None and not firsts:
+            return []
+        np = self._np
+        with _Phase(phases, _sp.FETCH):     # the copies were started
+            tokens = np.asarray(flight.tokens) if flight else ()
+            first_tokens = [int(np.asarray(t)) for _, t in firsts]
+            counts = {name: int(np.asarray(n)) for name, n in (
+                flight.counts if flight else {}).items()}
+        for name, n in counts.items():
+            self.counters[name] += n
+        events: List[dict] = []
+        # the model's counts of the step whose tokens these are: known
+        # only now, they ride the span that emits them
         with _Phase(phases, _sp.EMIT, **counts):
-            for i, seq in enumerate(batch):
-                self._emit(events, seq, int(next_tokens[i]))
-        return events, len(batch)
+            for lane, seq in (flight.lanes if flight else ()):
+                if self._owns(seq):
+                    self._emit(events, seq, int(tokens[lane]))
+            for (seq, _), token in zip(firsts, first_tokens):
+                self._emit(events, seq, token)
+        return events
 
     # ------------------------------------------------------- signals
     def queue_wait_p95(self, window_s: float = 30.0) -> float:
@@ -481,8 +601,7 @@ class EngineCore:
                          int(0.95 * (len(waits) - 1) + 0.999))]
 
     def outstanding_tokens(self) -> int:
-        return sum(s.remaining for s in self._running) \
-            + sum(s.remaining for s in self._waiting)
+        return sum(s.remaining for s in self._by_rid.values())
 
     def device_stats(self, in_cache: Optional[dict] = None) -> dict:
         """Where this engine runs, as JAX reports it, plus the chips the
@@ -504,8 +623,9 @@ class EngineCore:
                     for d in self._devices]}
 
     def cache_stats(self) -> dict:
-        """What the model keeps in the cache beside the pages. Not while
-        a step runs: the cache is donated to it."""
+        """What the model keeps in the cache beside the pages, once the
+        step in flight has run. Not while a step is being dispatched:
+        the cache is donated to it."""
         return self.model.cache_stats(self._cache)
 
     def stats(self) -> dict:
@@ -775,7 +895,10 @@ class LLMEngine:
         fail over; the descriptors carry emitted tokens so the
         survivor resumes mid-generation."""
         with self._lock:
-            descs = self.core.drain()
+            events, descs = self.core.drain()
+            # the tokens that were in flight are the clients' before the
+            # terminal frame (a request may have ended with them)
+            self._ingest(events)
             now = time.monotonic()
             drained_events = []
             for d in descs:
@@ -794,7 +917,7 @@ class LLMEngine:
         return descs
 
     def engine_stats(self) -> dict:
-        with self._lock:        # no step runs: the cache is not donated
+        with self._lock:        # no step is being dispatched
             st = self.core.stats()
             in_cache = self.core.cache_stats()
         st.update(self.core.device_stats(in_cache))

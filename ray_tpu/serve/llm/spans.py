@@ -9,14 +9,19 @@ from __future__ import annotations
 
 from ray_tpu.util.tracing import annotate
 
-# the step thread, nested as listed
+# the step thread, nested as listed. A step dispatches before it reads:
+# prefills, tables and the next decode step first, then the tokens of the
+# decode step the call before dispatched, so everything from the fetch to
+# the next dispatch runs beside a device step.
 WAIT = "engine.wait_for_work"       # idle: no request waiting or running
 STEP = "engine.step"                # one EngineCore.step()
-PREFILL = "engine.prefill"          # one admission, to its first token
+PREFILL = "engine.prefill"          # one admission: its prefill dispatched
 TABLES = "engine.page_tables"       # the decode batch's host arrays
-DISPATCH = "engine.decode_dispatch"     # carries the step's counts
-FETCH = "engine.fetch_tokens"       # waits for the device's answer
-EMIT = "engine.emit"
+DISPATCH = "engine.decode_dispatch"     # carries this dispatch's counts
+# waits for the tokens of the step before (and this call's prefills), with
+# the step just dispatched queued behind them on the device
+FETCH = "engine.fetch_tokens"
+EMIT = "engine.emit"                # carries the counts of the step it emits
 INGEST = "engine.ingest"
 PUBLISH = "stream.publish"          # child of ingest: a step's frames
 YIELD = "engine.yield"              # the lock released between steps

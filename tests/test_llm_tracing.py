@@ -81,12 +81,23 @@ def test_core_spans_and_parents(tiny_model, recorder):
     assert {e[4] for e in evs} == CORE_SPANS | REQUEST_SPANS
     assert {e[3] for e in evs} == {"llm"}
     steps = {e[1]: e for e in evs if e[4] == sp.STEP}
-    # step 1 admits and decodes, step 2 decodes the third token
-    assert len(steps) == 2
+    # step 1 admits, dispatches the first decode step and reads the
+    # prefill's token; step 2 dispatches the last one and reads the
+    # first one's; step 3 has nothing to dispatch and reads the last
+    assert len(steps) == 3
     for e in steps.values():
         assert e[2] == 0                       # a root, a trace each
         assert e[7]["t_mono_ns"] <= e[5]       # stamped before it opens
-    assert sorted(e[7]["step"] for e in steps.values()) == [1, 2]
+    assert sorted(e[7]["step"] for e in steps.values()) == [1, 2, 3]
+    number = {sid: e[7]["step"] for sid, e in steps.items()}
+    inside = {n: [e[4] for e in evs if e[2] == sid and e[4] in CORE_SPANS]
+              for sid, n in number.items()}
+    # (sorted by start) a step's tokens are fetched after the next step
+    # is dispatched, and a call with nothing to dispatch only reads
+    assert inside == {
+        1: [sp.PREFILL, sp.TABLES, sp.DISPATCH, sp.FETCH, sp.EMIT],
+        2: [sp.TABLES, sp.DISPATCH, sp.FETCH, sp.EMIT],
+        3: [sp.FETCH, sp.EMIT]}
     for e in evs:
         if e[4] in CORE_SPANS - {sp.STEP}:
             parent = steps[e[2]]               # KeyError = wrong parent
@@ -150,8 +161,10 @@ def test_request_spans_share_one_trace(tiny_model, recorder):
 
 def test_counters_on_a_fixed_schedule(tiny_model):
     """Two prompts, 5 tokens (bucket 16) and 20 (bucket 32), 3 and 2
-    tokens out, two lanes. Step 1 prefills both (one token each) and
-    decodes both: b is done. Step 2 decodes a alone: done."""
+    tokens out, two lanes. Step 1 prefills both, dispatches both lanes'
+    decode step (b's last: its lane and pages come free) and reads the
+    prefills' tokens. Step 2 dispatches a alone and reads the first
+    decode step: b is done. Step 3 reads a's last token."""
     core = _core(tiny_model)
     core.submit(list(range(1, 6)), max_tokens=3, rid="a")
     core.submit(list(range(1, 21)), max_tokens=2, rid="b")
@@ -165,21 +178,28 @@ def test_counters_on_a_fixed_schedule(tiny_model):
         "prefill_padded_tokens", "prefill_programs", "decode_steps",
         "decode_kernel_steps", "decode_lane_steps", "kv_positions_live",
         "kv_positions_read")} == {
-        "steps": 2, "admitted": 2, "finished": 2, "tokens": 5,
+        "steps": 3, "admitted": 2, "finished": 2, "tokens": 5,
         "prefill_tokens": 25, "prefill_padded_tokens": 48,
         "prefill_programs": 2, "decode_steps": 2, "decode_lane_steps": 3,
         # the einsum ran both (tiny heads): the old constant a step
         "decode_kernel_steps": 0,
         # step 1: a holds 5 + 1, b 20 + 1; step 2: a holds 5 + 2
         "kv_positions_live": 6 + 21 + 7, "kv_positions_read": 2 * read}
+    # the second dispatch went out before the first was read; the last
+    # was read with nothing behind it; nobody's token was dropped
+    assert (st["decode_steps_ahead"], st["pipeline_flushes"],
+            st["discarded_lane_steps"]) == (1, 1, 0)
     assert core.device_stats()["decode_attention"] == "einsum"
     # (a step that compiles may well take a second: slow_steps keeps it)
     assert all(s["step"] == 1 for s in st["slow_steps"])
     # a third request in a bucket already built: no new program
     core.submit(list(range(1, 10)), max_tokens=1, rid="c")
-    _run(core)
+    assert [(e["rid"], e["seq"], e["reason"]) for e in _run(core)] == [
+        ("c", 0, "length")]         # one call: a prefill and its token
     assert core.counters["prefill_programs"] == 2
     assert core.counters["prefill_padded_tokens"] == 48 + 16
+    assert core.counters["decode_steps"] == 2       # it took no lane-step
+    assert core.counters["pipeline_flushes"] == 1   # nothing was in flight
 
 
 def test_trace_off_records_nothing_and_changes_no_token(tiny_model,
@@ -217,6 +237,7 @@ def test_slow_step_is_kept_with_its_phases(tiny_model, monkeypatch):
     core.submit([1, 2, 3], max_tokens=3, rid="a")
     core.step()                                 # quick: not kept
     assert not core.slow_steps
+
     fetch = core._np.asarray
 
     class SlowNumpy:
@@ -231,9 +252,10 @@ def test_slow_step_is_kept_with_its_phases(tiny_model, monkeypatch):
 
     core_np = core._np
     core._np = SlowNumpy()
-    core.step()
+    core.step()         # dispatches the last step, waits for the first
     core._np = core_np
     core.step()
+    assert not core.has_work
     assert [s["step"] for s in core.slow_steps] == [2]
     slow = core.stats()["slow_steps"][0]
     assert slow["lanes"] == 1 and slow["t_mono_ns"] > 0
@@ -373,6 +395,10 @@ def test_expert_counts_ride_the_step_s_emit_span(tiny_mla, recorder):
     emits = [e[7] for e in evs if e[4] == sp.EMIT]
     lanes = [e[7]["lanes"] for e in evs if e[4] == sp.DISPATCH]
     assert lanes == [2, 1]
+    # the first call emits the prefills' tokens: no decode step's, so no
+    # count; then each call emits the step dispatched by the call before
+    assert not emits[0] and len(emits) == 3
+    emits = emits[1:]
     per_lane = cfg.num_experts_per_tok * cfg.n_moe_layers
     assert [e["moe_pairs"] for e in emits] == [n * per_lane for n in lanes]
     for e, n in zip(emits, lanes):
@@ -394,5 +420,29 @@ def test_a_dense_step_s_emit_span_carries_no_expert_count(tiny_model,
     core.submit([1, 2, 3], max_tokens=2, rid="a")
     _run(core)
     emits = [e[7] for e in _mine(recorder) if e[4] == sp.EMIT]
-    assert len(emits) == 1 and not emits[0]
+    # the prefill's token, then the one decode step's
+    assert len(emits) == 2 and not any(emits)
     assert "moe_pairs" not in core.stats()
+
+
+def test_each_emit_span_carries_the_counts_of_the_step_it_emits(tiny_mla,
+                                                                recorder):
+    """Three lanes that end one after the other: decode step k is
+    dispatched in call k with 3, 2, 1 lanes, its tokens are emitted in
+    call k + 1, and that call's `engine.emit` holds step k's counts (the
+    cache they were counted in has been donated to step k + 1 by then)."""
+    cfg, _ = tiny_mla
+    core = _core(tiny_mla, max_batch=3)
+    for rid, n in (("a", 4), ("b", 3), ("c", 2)):
+        core.submit([7, 8, 9], max_tokens=n, rid=rid)
+    _run(core)
+    evs = _mine(recorder)
+    call = {e[1]: e[7]["step"] for e in evs if e[4] == sp.STEP}
+    lanes = {call[e[2]]: e[7]["lanes"] for e in evs if e[4] == sp.DISPATCH}
+    assert lanes == {1: 3, 2: 2, 3: 1}
+    pairs = {call[e[2]]: (e[7] or {}).get("moe_pairs")
+             for e in evs if e[4] == sp.EMIT}
+    per_lane = cfg.num_experts_per_tok * cfg.n_moe_layers
+    assert pairs == {1: None, 2: 3 * per_lane, 3: 2 * per_lane,
+                     4: 1 * per_lane}
+    assert core.stats()["moe_pairs"] == 6 * per_lane

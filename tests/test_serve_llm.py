@@ -15,6 +15,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu.models.config import tiny
 from ray_tpu.models.transformer import Transformer
@@ -77,7 +78,7 @@ def _drain(core, max_steps=200):
         for e in evs:
             if e["done"]:
                 order.append((e["rid"], e["reason"]))
-        if not core.stats()["running"] and not core.stats()["waiting"]:
+        if not core.has_work:      # the last token has been read
             break
     return order, events
 
@@ -106,8 +107,10 @@ def test_decode_matches_full_forward(tiny_model):
 
 def test_admission_interleaves_prefill_and_decode(tiny_model):
     """A new request prefills in the same iteration an in-flight one
-    decodes — and a short generation submitted after a long one still
-    finishes first (continuous batching, not run-to-completion)."""
+    decodes (the call returns the new one's first token beside the
+    token of the decode step the call before dispatched) — and a short
+    generation submitted after a long one still finishes first
+    (continuous batching, not run-to-completion)."""
     cfg, model, params = tiny_model
     core = EngineCore(cfg, params, num_pages=64, page_size=8,
                       max_batch=4)
@@ -115,10 +118,13 @@ def test_admission_interleaves_prefill_and_decode(tiny_model):
     first = core.step()
     assert [e["rid"] for e in first if e["first"]] == ["long"]
     core.submit(list(range(20, 24)), max_tokens=3, rid="short")
+    assert core.counters["decode_steps"] == 1     # long's, not read yet
     mixed = core.step()
-    kinds = {(e["rid"], e["first"]) for e in mixed}
-    # the same step admits (prefills) short AND decodes long
-    assert ("short", True) in kinds and ("long", False) in kinds
+    kinds = {(e["rid"], e["first"], e["seq"]) for e in mixed}
+    # the same step admits (prefills) short AND decodes long: it reads
+    # long's first decode step, one step behind the two it dispatched
+    assert kinds == {("short", True, 0), ("long", False, 1)}
+    assert core.counters["decode_lane_steps"] == 1 + 2
     order, _ = _drain(core)
     assert order[0] == ("short", FINISH_LENGTH)
     assert order[-1][0] == "long"
@@ -185,6 +191,228 @@ def test_eviction_requeues_with_emitted_preserved(tiny_model):
     # NOT re-emitted; emitted is preserved) — the stream stays exact
     assert got_b == ref_toks
     assert core.stats()["free_pages"] == 4
+
+
+# ------------------------------------------- the pipeline, both models
+@pytest.fixture(scope="module", params=["dense", "mla_moe"])
+def served(request):
+    """(config, params) of each tiny model the engine serves."""
+    if request.param == "dense":
+        cfg = tiny()
+        return cfg, Transformer(cfg).init(jax.random.PRNGKey(0))
+    from ray_tpu.models.mla_moe import MLAMoE, tiny_mla_moe
+    cfg = tiny_mla_moe()
+    return cfg, MLAMoE(cfg).init(jax.random.PRNGKey(0))
+
+
+def _alone(served, prompt, max_tokens, stop=()):
+    """The reference: the request by itself in a roomy engine (one a
+    model, kept: its programs compile once)."""
+    cfg, params = served
+    core = _ALONE.get(id(params))
+    if core is None:
+        core = _ALONE[id(params)] = EngineCore(
+            cfg, params, num_pages=32, page_size=4, max_batch=1)
+    core.submit(prompt, max_tokens=max_tokens, stop=stop, rid="r")
+    _, events = _drain(core)
+    return [e["token"] for e in events]
+
+
+_ALONE = {}
+
+
+def test_every_request_gets_the_tokens_it_gets_alone(served):
+    """A mixed schedule through three lanes and a pool that runs dry:
+    admission mid-flight, a stop token, `max_tokens` 1, a cancel with a
+    step in flight, eviction. Every token is the one the request gets
+    when it is served alone."""
+    cfg, params = served
+    prompts = {"long": [3, 17, 91, 254, 8, 1], "one": [5, 6, 7],
+               "stop": [9, 8, 7, 6], "mid": [200, 100, 50, 25, 12],
+               "gone": [4, 4, 4], "late": [2, 3]}
+    budget = {"long": 14, "one": 1, "stop": 12, "mid": 9, "gone": 12,
+              "late": 6}
+    free = {rid: _alone(served, prompts[rid], n)
+            for rid, n in budget.items()}
+    # stop at the third token's first occurrence
+    stop = free["stop"][2]
+    want = dict(free, stop=free["stop"][:free["stop"].index(stop) + 1])
+    assert want["stop"] == _alone(served, prompts["stop"], 12, (stop,))
+
+    core = EngineCore(cfg, params, num_pages=7, page_size=4, max_batch=3)
+    for rid in ("long", "one", "stop", "gone"):
+        core.submit(prompts[rid], max_tokens=budget[rid], rid=rid,
+                    stop=(stop,) if rid == "stop" else ())
+    got = {rid: [] for rid in prompts}
+    reasons = {}
+    for i in range(200):
+        if i == 3:
+            core.submit(prompts["mid"], max_tokens=9, rid="mid")
+        if i == 4:
+            assert core._flight is not None       # a step in flight
+            assert any(s.rid == "gone" for _, s in core._flight.lanes)
+            n_gone = len(got["gone"])
+            assert core.cancel("gone")
+            core.submit(prompts["late"], max_tokens=6, rid="late")
+        if not core.has_work:
+            break
+        for ev in core.step():
+            got[ev["rid"]].append(ev["token"])
+            assert ev["seq"] == len(got[ev["rid"]]) - 1
+            if ev["done"]:
+                reasons[ev["rid"]] = ev["reason"]
+    assert not core.has_work and core._flight is None
+    assert core.counters["evictions"] >= 1
+    assert core.counters["pipeline_flushes"] >= 1
+    gone = got.pop("gone")
+    assert len(gone) == n_gone and gone == free["gone"][:n_gone]
+    assert got == {rid: want[rid] for rid in got}
+    assert reasons == {"long": FINISH_LENGTH, "one": FINISH_LENGTH,
+                       "stop": FINISH_STOP, "mid": FINISH_LENGTH,
+                       "late": FINISH_LENGTH}
+    assert core.stats()["free_pages"] == 7
+    assert core._lanes == [None] * 3 and not core._running
+
+
+def test_drain_reads_the_step_in_flight_and_a_survivor_continues(served):
+    cfg, params = served
+    prompt = [3, 17, 91, 254, 8]
+    want = _alone(served, prompt, 9)
+    core = EngineCore(cfg, params, num_pages=32, page_size=4, max_batch=2)
+    core.submit(prompt, max_tokens=9, rid="d")
+    core.submit([1, 2], max_tokens=3, rid="e")
+    seen = [e["token"] for _ in range(2) for e in core.step()
+            if e["rid"] == "d"]
+    assert core._flight is not None
+    events, descs = core.drain()
+    # e's last step was in flight: it ended with the drain's events and
+    # is no one's to resume
+    assert [(e["rid"], e["done"]) for e in events] == [
+        ("d", False), ("e", True)]
+    assert [d["rid"] for d in descs] == ["d"]
+    emitted = descs[0]["emitted"]
+    assert emitted == seen + [events[0]["token"]] == want[:len(emitted)]
+    assert not core.has_work and core.stats()["free_pages"] == 32
+    assert core.counters["pipeline_flushes"] == 1
+    # the survivor re-prefills prompt + emitted and goes on from there
+    rest = _alone(served, descs[0]["prompt"] + emitted,
+                  descs[0]["max_tokens"] - len(emitted))
+    assert emitted + rest == want
+
+
+def test_a_lane_freed_by_max_tokens_is_taken_in_the_next_dispatch(served):
+    """Four requests of two decode steps each through two lanes: a lane
+    comes free when its sequence's last step is dispatched, a step before
+    that step's token is read, and the request that waits takes it in the
+    next call — four full decode steps, no lane-step empty."""
+    cfg, params = served
+    core = EngineCore(cfg, params, num_pages=32, page_size=4, max_batch=2)
+    for i in range(4):
+        core.submit([10 + i, 20 + i, 30 + i], max_tokens=3, rid=f"r{i}")
+    lanes = []
+    while core.has_work:
+        before = core.counters["decode_lane_steps"]
+        waiting = len(core._waiting)
+        core.step()
+        stepped = core.counters["decode_lane_steps"] - before
+        if waiting:
+            assert stepped == 2     # nobody waits beside an empty lane
+        lanes.append(stepped)
+    assert lanes == [2, 2, 2, 2, 0]
+    assert core.counters["decode_steps"] == 4
+    assert core.counters["decode_steps_ahead"] == 3
+    assert core.counters["finished"] == 4
+
+
+@pytest.mark.parametrize("at", [0, 1, 3])
+def test_a_stop_token_costs_one_discarded_lane_step(served, at):
+    """The stop token is known a step late: the lane runs one step more,
+    whose token nobody gets, and then every page is free. Token 0 is the
+    prefill's."""
+    cfg, params = served
+    prompt = [5, 6, 7]
+    free = _alone(served, prompt, 8)
+    stop = free[at]
+    want = free[:free.index(stop) + 1]
+    core = EngineCore(cfg, params, num_pages=16, page_size=4, max_batch=2)
+    core.submit(prompt, max_tokens=8, stop=(stop,), rid="s")
+    order, events = _drain(core)
+    assert order == [("s", FINISH_STOP)]
+    assert [e["token"] for e in events] == want    # the stop token last
+    c = core.counters
+    assert c["discarded_lane_steps"] == 1
+    assert c["decode_lane_steps"] == len(want)     # one past the last
+    assert core.stats()["free_pages"] == 16 and core._flight is None
+
+
+def test_cancel_with_a_step_in_flight_leaves_an_idle_core(served):
+    """What the benchmark's harness does after a window: cancel whatever
+    is open, require `has_work` false, then call the compiled programs
+    directly on `core._cache`. The dispatch nobody owns is abandoned."""
+    cfg, params = served
+    core = EngineCore(cfg, params, num_pages=16, page_size=4, max_batch=2)
+    core.submit([1, 2, 3], max_tokens=20, rid="a")
+    core.submit([4, 5, 6, 7], max_tokens=20, rid="b")
+    core.step()
+    core.step()
+    assert core._flight is not None and core.has_work
+    assert core.cancel("a") and core.has_work
+    assert core.cancel("b")
+    assert not core.has_work and core._flight is None
+    assert core.counters["discarded_lane_steps"] == 2
+    assert core.stats()["free_pages"] == 16
+    assert core.step() == []            # nothing is waited for
+    # the harness's check: a prefill and a decode step of its own
+    pages = core.alloc.alloc(2)
+    pt = np.full((core.max_pages_per_seq,), -1, np.int32)
+    pt[:2] = pages
+    logits, core._cache = core._prefill_fn(16)(
+        core.params, jnp.zeros((16,), jnp.int32), jnp.int32(3),
+        jnp.asarray(pt), core._cache)
+    assert logits.shape == (cfg.vocab_size,)
+    B = core.max_batch
+    pts = np.full((B, core.max_pages_per_seq), -1, np.int32)
+    pts[1] = pt
+    logits, core._cache = core._decode_fn(
+        core.params, core._cache, jnp.asarray([0, 9], jnp.int32),
+        jnp.asarray([0, 3], jnp.int32), jnp.asarray(pts),
+        jnp.asarray([False, True]))
+    assert logits.shape == (B, cfg.vocab_size)
+    # tokens made on the host and tokens left on the device are one
+    # program's argument: nothing compiled a second time
+    assert core._decode_fn._cache_size() == 1
+    core.alloc.free(pages)
+    # and the engine goes on from the cache the check left
+    core.submit([1, 2, 3], max_tokens=4, rid="again")
+    _, events = _drain(core)
+    assert [e["token"] for e in events] == _alone(served, [1, 2, 3], 4)
+
+
+def test_pipeline_counters_on_a_fixed_schedule(tiny_model):
+    """Two lanes; a runs to four tokens, b stops at its second. Call 1
+    prefills both and dispatches step 1; call 2 dispatches step 2 ahead
+    and reads step 1 (b's stop token: its lane in step 2 is discarded);
+    call 3 dispatches a's last step ahead; call 4 reads it with nothing
+    behind."""
+    cfg, model, params = tiny_model
+    second = _alone((cfg, params), [4, 5, 6, 7], 2)[1]
+    core = EngineCore(cfg, params, num_pages=32, page_size=4, max_batch=2)
+    core.submit([1, 2, 3], max_tokens=4, rid="a")
+    core.submit([4, 5, 6, 7], max_tokens=9, stop=(second,), rid="b")
+    order, _ = _drain(core)
+    assert order == [("b", FINISH_STOP), ("a", FINISH_LENGTH)]
+    keys = ("steps", "decode_steps", "decode_lane_steps",
+            "decode_steps_ahead", "pipeline_flushes",
+            "discarded_lane_steps")
+    assert [core.stats()[k] for k in keys] == [4, 3, 5, 2, 1, 1]
+    # a request cancelled with its step in flight: the dispatch is
+    # abandoned, not flushed
+    core.submit([1, 2, 3], max_tokens=9, rid="c")
+    core.step()
+    core.step()
+    core.cancel("c")
+    assert [core.stats()[k] for k in keys] == [6, 5, 7, 3, 1, 2]
+    assert not core.has_work
 
 
 # ------------------------------------------------------ engine + stream

@@ -11,7 +11,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 
@@ -96,7 +96,9 @@ def _send(engine, client, collector, sent: Dict[str, Sent], rid: str,
 def warm_up(engine, client, requests, vocab: int, timeout_s: float) -> None:
     """One request per prefill bucket the cell's prompts fall into, two
     tokens each: compiles (or loads) every prefill program, the decode
-    program and the host-side argmax, and walks the stream path once."""
+    program and the small programs beside them (the next step's tokens,
+    picked on the device since PR 30, and a lane's placement), and walks
+    the stream path once."""
     from ray_tpu.serve.llm.engine import _bucket
     limit = engine.core.config.max_seq_len
     buckets = sorted({_bucket(r.prompt_len, hi=limit) for r in requests})
@@ -444,10 +446,14 @@ def run(cell: dict, cfg: dict, mix: dict, args, t_start: float,
     }
 
 
-def trace_part_of_window(mix, t0, seconds, trace_dir) -> Optional[dict]:
+def trace_part_of_window(mix, t0, seconds, trace_dir) -> dict:
     """Trace `trace.seconds` of the window from `trace.start_s` on (both
-    shrunk to fit a shorter window); returns the traced span's length."""
+    shrunk to fit a shorter window). The traced window is the span called
+    `xplane.WINDOW` that this thread writes into the trace around its
+    sleep: `xplane.traced_window` reads its length and the device's busy
+    time there, and no clock of the host's is read here."""
     import jax
+    from benchmarks.harness.xplane import WINDOW
     spec = mix.get("trace", {})
     length = min(float(spec.get("seconds", 5)), 0.5 * seconds)
     start = min(float(spec.get("start_s", 0)), seconds - length - 0.5)
@@ -458,8 +464,7 @@ def trace_part_of_window(mix, t0, seconds, trace_dir) -> Optional[dict]:
     opts.python_tracer_level = int(spec.get("python_tracer", 0))
     opts.host_tracer_level = 2
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    a = time.perf_counter()
-    time.sleep(length)
-    b = time.perf_counter()
+    with jax.profiler.TraceAnnotation(WINDOW):
+        time.sleep(length)
     jax.profiler.stop_trace()
-    return {"window_s": b - a, "dir": trace_dir}
+    return {"dir": trace_dir}

@@ -95,6 +95,7 @@ def run(cell: dict, cfg: dict, mix: dict, args, t_start: float,
     import jax
     from benchmarks.harness.cells import CompileCounter
     from benchmarks.harness.weights import make_weights
+    from benchmarks.harness.xplane import WINDOW
 
     model = load_model(cfg)
     sz = model.sizes(cfg)
@@ -134,17 +135,19 @@ def run(cell: dict, cfg: dict, mix: dict, args, t_start: float,
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             jax.profiler.start_trace(args.trace_dir, profiler_options=opts)
-            a = NOW()
-            for _ in range(trace_steps):
-                with jax.profiler.TraceAnnotation("bench.train_step"):
-                    params, opt_state, loss = step(
-                        params, opt_state, batches[len(losses) % n_batches])
-                    losses.append(loss)
-                    jax.block_until_ready(loss)
-            z = NOW()
+            stalled_from = NOW()
+            # the traced window: from the sync above to the last step's
+            with jax.profiler.TraceAnnotation(WINDOW):
+                for _ in range(trace_steps):
+                    with jax.profiler.TraceAnnotation("bench.train_step"):
+                        params, opt_state, loss = step(
+                            params, opt_state,
+                            batches[len(losses) % n_batches])
+                        losses.append(loss)
+                        jax.block_until_ready(loss)
             jax.profiler.stop_trace()
-            traced = {"window_s": z - a, "steps": trace_steps,
-                      "dir": args.trace_dir, "stall_s": NOW() - a}
+            traced = {"steps": trace_steps, "dir": args.trace_dir,
+                      "stall_s": NOW() - stalled_from}
     jax.block_until_ready(losses[-1])
     elapsed = NOW() - t0
     in_window_compiles = compiles.stop()

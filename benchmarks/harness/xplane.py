@@ -2,12 +2,23 @@
 union of the intervals in which an operation ran, device time per program
 and per operation, and the idle gaps attributed to what the host was doing.
 Reads with `jax.profiler.ProfileData` alone; checked against the small
-recorded trace in `benchmarks/tests/data/`.
+recorded traces in `benchmarks/tests/data/`.
 
 Times are seconds on the trace's own clock. The device's clock runs a
 little ahead of the host's in these traces (a program shows as starting
-before the host enqueued it), so before gaps are attributed the device is
-shifted by the largest such lead over all programs (`clock_shift_s`).
+before the host enqueued it), so wherever device time meets a host span
+(the window, the gaps) the device is shifted by the largest such lead over
+all programs (`clock_shift_s`).
+
+The traced window is a span inside the trace: the harness wraps what it
+traces in one `TraceAnnotation` called `WINDOW`, opened after `start_trace`
+has returned and closed before `stop_trace` is called. `traced_window` is
+the one reader of it: the window's length is the marker's, and busy time is
+what the device did inside it, so busy time cannot pass the window however
+long the profiler takes to start and to stop. What is a sum over the window
+(busy, idle gaps, the operations' seconds of the breakdown) is clipped to
+it; what is a mean per event (a program's time, a kernel's) keeps every
+event of the trace, so that a count and its time stay a pair.
 """
 from __future__ import annotations
 
@@ -17,6 +28,8 @@ import os
 import re
 from typing import Dict, List, Optional, Tuple
 
+WINDOW = "bench.trace_window"
+EVER = (float("-inf"), float("inf"))
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 _OP_NAME = re.compile(r"^%?([\w.\-]+)")
 _SUFFIX = re.compile(r"[.\d]+$")
@@ -66,7 +79,9 @@ def load(path: str) -> Trace:
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 evs = _events(line, want_stats=True)
-                trace.host[line.name] = evs
+                # two threads may carry one name
+                trace.host[f"{line.name}#{len(trace.host)}"
+                           if line.name in trace.host else line.name] = evs
                 for ev in evs:
                     if ev.name == "DoEnqueueProgram" and "run_id" in ev.stats:
                         trace.enqueues[int(ev.stats["run_id"])] = ev.start
@@ -123,16 +138,50 @@ def union_intervals(spans: List[Tuple[float, float]]
     return merged
 
 
-def busy_seconds(trace: Trace, lo: float = float("-inf"),
-                 hi: float = float("inf")) -> float:
-    """Seconds with an operation running, averaged over the devices traced."""
+def busy_intervals(trace: Trace, dev: int, lo: float = EVER[0],
+                   hi: float = EVER[1]) -> List[Tuple[float, float]]:
+    """The intervals in which an operation ran on device `dev`, on the
+    host's clock, cut to `[lo, hi]`."""
+    shift = clock_shift_s(trace)
+    merged = union_intervals([(e.start + shift, e.end + shift)
+                              for e in trace.ops.get(dev, [])])
+    return [(max(a, lo), min(b, hi)) for a, b in merged if b > lo and a < hi]
+
+
+def busy_seconds(trace: Trace, lo: float = EVER[0],
+                 hi: float = EVER[1]) -> float:
+    """Seconds with an operation running inside `[lo, hi]` of the host's
+    clock, averaged over the devices traced."""
     if not trace.ops:
         return 0.0
-    total = 0.0
-    for evs in trace.ops.values():
-        for a, b in union_intervals([(e.start, e.end) for e in evs]):
-            total += max(0.0, min(b, hi) - max(a, lo))
-    return total / len(trace.ops)
+    return sum(b - a for dev in trace.ops
+               for a, b in busy_intervals(trace, dev, lo, hi)) / len(trace.ops)
+
+
+@dataclasses.dataclass(frozen=True)
+class Window:
+    lo: float           # the marker's start and end, on the trace's clock
+    hi: float
+    busy_s: float       # of the device inside it: above no window_s
+
+    @property
+    def window_s(self) -> float:
+        return self.hi - self.lo
+
+
+def traced_window(trace: Trace) -> Window:
+    """The traced window and the device's busy time inside it: the one
+    place either comes from."""
+    marks = [e for evs in trace.host.values() for e in evs
+             if e.name == WINDOW]
+    if len(marks) != 1:
+        raise ValueError(
+            f"the trace holds {len(marks)} spans called {WINDOW!r}: the "
+            "traced window is that span and nothing else, so whoever traces "
+            "wraps what it traces in exactly one, between start_trace's "
+            "return and the call of stop_trace")
+    lo, hi = marks[0].start, marks[0].end
+    return Window(lo, hi, busy_seconds(trace, lo, hi))
 
 
 def device_span(trace: Trace) -> Tuple[float, float]:
@@ -164,10 +213,19 @@ def self_times(events: List[Event]) -> List[Tuple[Event, float]]:
     return [(e, max(t, 0.0)) for e, t in out]
 
 
-def op_times(trace: Trace) -> Dict[str, float]:
-    """Device seconds of self time summed by operation label (device 0)."""
+def op_times(trace: Trace, lo: float = EVER[0],
+             hi: float = EVER[1]) -> Dict[str, float]:
+    """Device seconds of self time inside `[lo, hi]` of the host's clock,
+    summed by operation label (device 0): they add up to its busy time."""
+    shift = clock_shift_s(trace)
+    lo, hi = lo - shift, hi - shift             # on the device's clock
+    cut = [e if lo <= e.start and e.end <= hi else dataclasses.replace(
+               e, start=max(e.start, lo),
+               dur=min(e.end, hi) - max(e.start, lo))
+           for e in trace.ops.get(min(trace.ops, default=0), [])
+           if e.end > lo and e.start < hi]
     out: Dict[str, float] = {}
-    for ev, own in self_times(trace.ops.get(min(trace.ops, default=0), [])):
+    for ev, own in self_times(cut):
         label = op_label(ev.name)
         out[label] = out.get(label, 0.0) + own
     return out
@@ -193,36 +251,43 @@ def clock_shift_s(trace: Trace) -> float:
     return lead
 
 
-def idle_gaps(trace: Trace, min_gap_s: float = 50e-6
+def idle_gaps(trace: Trace, min_gap_s: float = 50e-6,
+              lo: float = EVER[0], hi: float = EVER[1]
               ) -> List[Tuple[float, float]]:
-    """Gaps between busy intervals of device 0, in host-clock seconds."""
-    evs = trace.ops.get(min(trace.ops, default=0), [])
-    shift = clock_shift_s(trace)
-    busy = union_intervals([(e.start + shift, e.end + shift) for e in evs])
+    """Gaps between busy intervals of device 0, in host-clock seconds.
+    Given a window `[lo, hi]`, the gaps inside it, those at its two ends
+    among them: with the busy time they make up the window, but for what
+    `min_gap_s` leaves out."""
+    busy = busy_intervals(trace, min(trace.ops, default=0), lo, hi)
+    if (lo, hi) != EVER:
+        busy = [(lo, lo), *busy, (hi, hi)]
     return [(a_end, b_start) for (_, a_end), (b_start, _)
             in zip(busy, busy[1:]) if b_start - a_end >= min_gap_s]
 
 
 def attribute_gaps(trace: Trace, threads: Optional[List[str]] = None,
-                   min_gap_s: float = 50e-6) -> Dict[str, float]:
-    """Idle seconds by what the host was doing: each gap is cut at the
-    boundaries of the host spans that overlap it, and every piece goes to
-    the innermost (shortest) span covering it, or to 'no_host_span'.
+                   min_gap_s: float = 50e-6, lo: float = EVER[0],
+                   hi: float = EVER[1]) -> Dict[str, float]:
+    """Idle seconds by what the host was doing: each gap (of `[lo, hi]`,
+    where given) is cut at the boundaries of the host spans that overlap
+    it, and every piece goes to the innermost (shortest) span covering it,
+    or to 'no_host_span'. The window's own marker covers everything and
+    says nothing, so it is no span here.
     `threads`: substrings of the host thread names to read (default all)."""
     spans: List[Event] = []
     for name, evs in trace.host.items():
         if threads and not any(t in name for t in threads):
             continue
-        spans.extend(e for e in evs if e.dur > 0)
+        spans.extend(e for e in evs if e.dur > 0 and e.name != WINDOW)
     spans.sort(key=lambda e: e.start)
     starts = [e.start for e in spans]
     import bisect
     out: Dict[str, float] = {}
     longest = max((e.dur for e in spans), default=0.0)
-    for a, b in idle_gaps(trace, min_gap_s):
-        lo = bisect.bisect_left(starts, a - longest)
-        hi = bisect.bisect_right(starts, b)
-        over = [e for e in spans[lo:hi] if e.end > a and e.start < b]
+    for a, b in idle_gaps(trace, min_gap_s, lo, hi):
+        first = bisect.bisect_left(starts, a - longest)
+        last = bisect.bisect_right(starts, b)
+        over = [e for e in spans[first:last] if e.end > a and e.start < b]
         cuts = sorted({a, b, *[min(max(e.start, a), b) for e in over],
                        *[min(max(e.end, a), b) for e in over]})
         for x, y in zip(cuts, cuts[1:]):

@@ -1,5 +1,5 @@
-"""Time to first token, 90th percentile (nearest rank; 219 requests at the
-chat cell's 4.4 requests/s, 21 beyond it). Recorded, not judged: a stalled
+"""Time to first token, 90th percentile (nearest rank; 396 requests at the
+chat cell's 8.4 requests/s, 39 beyond it). Recorded, not judged: a stalled
 step (PERF.md, Findings 4) moves it by its own length."""
 from benchmarks.harness.stats import percentile
 

@@ -108,15 +108,21 @@ def main() -> int:
         from benchmarks.harness import xplane
         trace = xplane.load(xplane.find_xplane(result["traced"]["dir"]))
         run["trace"] = trace
-        dev["busy_s"] = xplane.busy_seconds(trace)
-        dev["window_s"] = result["traced"]["window_s"]
+        # the window is the span the harness marked inside the trace, and
+        # what is summed over it (busy, the breakdown) is cut to it
+        w = xplane.traced_window(trace)
+        result["traced"].update(window_s=w.window_s, lo=w.lo, hi=w.hi)
+        dev["busy_s"], dev["window_s"] = w.busy_s, w.window_s
+        # what the profiler recorded before and after the window: what a
+        # busy time taken over the whole trace would add to `busy_s`
+        line["busy_outside_window_s"] = xplane.busy_seconds(trace) - w.busy_s
         steppers = [name for name, evs in trace.host.items()
                     if any(e.name in ("bench.engine_step", "bench.train_step")
                            for e in evs)]
         line["breakdown"] = {
-            "device_ops": xplane.top(xplane.op_times(trace)),
+            "device_ops": xplane.top(xplane.op_times(trace, w.lo, w.hi)),
             "idle_gaps": xplane.top(xplane.attribute_gaps(
-                trace, threads=steppers or None))}
+                trace, threads=steppers or None, lo=w.lo, hi=w.hi))}
         line["programs"] = {k: [len(v), sum(v)] for k, v in
                             xplane.program_times(trace).items()}
     if args.samples_out:

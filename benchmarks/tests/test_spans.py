@@ -60,7 +60,11 @@ def a_run(tmp_path, pb, traced=True):
         d.mkdir(parents=True)
         shutil.copy(pb, d / "vm.xplane.pb")
         run["trace"] = xplane.load(pb)
-        run["result"]["traced"] = {"dir": str(tmp_path), "window_s": 0.3}
+        # these recordings predate the window's marker: the device's span
+        # stands in for what run.py reads with `xplane.traced_window`
+        lo, hi = xplane.device_span(run["trace"])
+        run["result"]["traced"] = {"dir": str(tmp_path), "lo": lo, "hi": hi,
+                                   "window_s": hi - lo}
     return run
 
 

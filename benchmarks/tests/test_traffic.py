@@ -47,7 +47,7 @@ def test_chat_totals_in_the_51_second_window():
     tot = traffic.totals(reqs, 51.0)
     # hand-checked once against the generator's own output for this file
     assert tot["requests"] == sum(1 for r in reqs if r.due_s < 51.0)
-    assert tot["requests"] == 219        # 4.4 requests/s for 51 s
+    assert tot["requests"] == 396        # 8.4 requests/s for 51 s
     lens = np.array([r.prompt_len for r in reqs])
     outs = np.array([r.max_tokens for r in reqs])
     assert lens.min() >= 32 and lens.max() <= 2048

@@ -4,10 +4,12 @@
 
 One engine, one warm-up, nothing patched (`serve_cell.instrument()` is not
 used): an untraced window, a traced one, an untraced one. Prints every piece
-of `harness/spans.py`'s split of the traced span beside `xplane.idle_gaps`'
-total (they must add up), the share that fell outside every span, the
-engine's counters and `slow_steps`, and each window's tokens per second
-(what the profiler costs while it is on). Writes
+of `harness/spans.py`'s split of the trace's idle time beside
+`xplane.idle_gaps`' total (they must add up), the share that fell outside
+every span, the traced window and the device's busy time in it as `run.py`
+reads them (`xplane.traced_window`: the marker span, and what the device did
+inside it), the engine's counters and `slow_steps`, and each window's tokens
+per second (what the profiler costs while it is on). Writes
 chiprun_out/span_gaps.<cell>.json.
 """
 import argparse
@@ -45,16 +47,17 @@ def main():
             trace = xplane.load(path)
             r = spans.read(trace, path)
             steps = len(r.named(spans.DISPATCH))
+            win = xplane.traced_window(trace)
             out.update(
-                traced_s=w["traced"]["window_s"],
-                busy_s=xplane.busy_seconds(trace), idle_s=r.idle_s,
+                traced_s=win.window_s, busy_s=win.busy_s, idle_s=r.idle_s,
                 pieces_s=sum(r.gaps.values()),
                 outside_share=(r.gaps.get(spans.OUTSIDE, 0.0)
                                / (r.idle_s or 1.0)),   # no device on a CPU
                 decode_steps=steps,
                 tokens_per_s_traced_span=(
-                    r.attr_sum(spans.DISPATCH, "lanes")
-                    / w["traced"]["window_s"]),
+                    sum(int(s.stats["lanes"])
+                        for s in r.named(spans.DISPATCH)
+                        if win.lo <= s.start < win.hi) / win.window_s),
                 gaps_ms_per_step={k: 1e3 * v / steps for k, v in sorted(
                     r.gaps.items(), key=lambda kv: -kv[1])})
     stats = served.engine.engine_stats()

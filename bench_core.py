@@ -297,144 +297,6 @@ def _direct_actor_bench(n_calls: int, direct: bool) -> dict:
         CONFIG.reload()
 
 
-def _llm_serve_bench(n_requests: int = 24, rate_per_s: float = 12.0,
-                     max_tokens: int = 24, stream: bool = True) -> dict:
-    """LLM serving open-loop load generator (r19): two engine replica
-    groups behind an `LLMHandle`, requests arriving on a FIXED
-    schedule regardless of completions (open loop — a closed loop
-    would let a slow server throttle its own offered load and hide
-    queueing). Per-request TTFT (submit -> first token, covers
-    admission + prefill) and TPOT (steady decode cadence) land as
-    p50/p99; per_second is aggregate generated tokens/s.
-
-    The A/B arm is the token path: direct-stream (engine workers push
-    llm_tok frames over peer-dialed connections; the head never sees
-    a token) vs polled (RAY_TPU_LLM_STREAM=0: every chunk rides a
-    `next_tokens` actor call through the head tables).
-    head_frames_per_token counts the head process's socket frames
-    minus the stream plane's own, per generated token — the stream
-    arm must read ~0."""
-    import threading
-
-    import ray_tpu
-    from ray_tpu._private import protocol
-    from ray_tpu._private.config import CONFIG
-    from ray_tpu.cluster_utils import NodeAgentProcess
-    os.environ["RAY_TPU_LLM_STREAM"] = "1" if stream else "0"
-    CONFIG.reload()
-    agents = []
-    try:
-        rt = ray_tpu.init(num_cpus=0, resources={"head": 4.0})
-        from ray_tpu import serve as _serve
-        from ray_tpu.serve import llm
-        from ray_tpu.serve.llm.stream import STREAM_STATS
-        # controller pinned to the head; replicas pinned to agents
-        ray_tpu.remote(max_concurrency=16, resources={"head": 0.01})(
-            _serve.ServeController).options(
-                name=_serve._CONTROLLER_NAME,
-                get_if_exists=True).remote()
-        agents = [NodeAgentProcess(num_cpus=2,
-                                   resources={"llm_bench": 1.0})
-                  for _ in range(2)]
-        deadline = time.time() + 60
-        while (time.time() < deadline
-               and len(rt.cluster.alive_nodes()) < 3):
-            time.sleep(0.1)
-        handle = llm.serve_llm(
-            name="bench_llm", model="tiny", num_replicas=2,
-            num_pages=64, page_size=8, max_batch=8,
-            ray_actor_options={"resources": {"llm_bench": 1.0}})
-        prompts = [[1 + (i % 7), 2 + i, 3, 5 + (i % 3)]
-                   for i in range(n_requests)]
-        # warm both replicas: first generations pay prefill/decode
-        # jit compiles that would otherwise pollute the timed TTFTs
-        for p in prompts[:4]:
-            handle.generate(p, max_tokens=4, timeout_s=120).tokens()
-
-        s0 = dict(protocol.WIRE_STATS)
-        f0 = STREAM_STATS["frames_in"]
-        lock = threading.Lock()
-        recs = []
-        t_start = time.perf_counter()
-
-        def one(i: int) -> None:
-            delay = t_start + i / rate_per_s - time.perf_counter()
-            if delay > 0:
-                time.sleep(delay)
-            s = handle.generate(prompts[i], max_tokens=max_tokens,
-                                timeout_s=60.0)
-            toks = s.tokens()
-            n = len(toks)
-            tpot = ((s.t_last - s._t_submit - s.ttft_s) / (n - 1)
-                    if n > 1 and s.t_last is not None else 0.0)
-            with lock:
-                recs.append({"ttft": s.ttft_s or 0.0, "tpot": tpot,
-                             "n": n, "attempt": s._attempt})
-
-        threads = [threading.Thread(target=one, args=(i,))
-                   for i in range(n_requests)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=180)
-        wall = time.perf_counter() - t_start
-        wire = dict(protocol.WIRE_STATS)
-        stream_rx = STREAM_STATS["frames_in"] - f0
-        head_frames = (wire["tx_frames"] - s0["tx_frames"]
-                       + wire["rx_frames"] - s0["rx_frames"]
-                       - stream_rx)
-        total_tokens = sum(r["n"] for r in recs)
-
-        def _pct(vals, q):
-            vals = sorted(vals)
-            return vals[min(len(vals) - 1, int(len(vals) * q))]
-
-        ttfts = [r["ttft"] for r in recs]
-        tpots = [r["tpot"] for r in recs if r["n"] > 1]
-        return {
-            "n": total_tokens, "seconds": round(wall, 4),
-            "per_second": round(total_tokens / wall, 1),
-            "unit": "tok",
-            "requests": len(recs),
-            "offered_per_s": rate_per_s,
-            "ttft_p50_ms": round(_pct(ttfts, 0.50) * 1e3, 2),
-            "ttft_p99_ms": round(_pct(ttfts, 0.99) * 1e3, 2),
-            "tpot_p50_ms": round(_pct(tpots, 0.50) * 1e3, 3),
-            "tpot_p99_ms": round(_pct(tpots, 0.99) * 1e3, 3),
-            "head_frames_per_token": round(
-                max(0, head_frames) / max(1, total_tokens), 3),
-            "failovers": sum(1 for r in recs if r["attempt"] > 0),
-        }
-    finally:
-        try:
-            from ray_tpu import serve as _serve
-            _serve.shutdown()
-        except BaseException:
-            pass
-        for ag in agents:
-            ag.terminate()
-        for ag in agents:
-            ag.wait(10)
-        import ray_tpu as _rt
-        _rt.shutdown()
-        os.environ.pop("RAY_TPU_LLM_STREAM", None)
-        CONFIG.reload()
-
-
-def _llm_serve_section(results: dict) -> None:
-    """serve_llm token-path A/B (r19). Acceptance: the stream arm's
-    head_frames_per_token reads ~0 while the polled arm pays actor
-    calls per chunk, with no TTFT regression."""
-    _pl, _st = _ab_pair(
-        results, "serve_llm_polled",
-        lambda: _llm_serve_bench(stream=False),
-        "serve_llm_stream",
-        lambda: _llm_serve_bench(stream=True))
-    if _pl["per_second"]:
-        _st["stream_speedup"] = round(
-            _st["per_second"] / _pl["per_second"], 2)
-
-
 def _rl_bench(direct: bool, n_updates: int = 12) -> dict:
     """Sebulba RL throughput (r20): 4 env-runner actors on one agent
     act against 2 batched inference actors on another while the
@@ -1063,9 +925,6 @@ def main(as_json: bool = False) -> dict:
         _dd["direct_speedup"] = round(
             _dd["per_second"] / _h["per_second"], 2)
 
-    # ------ LLM serving: direct-stream vs polled token plane (r19)
-    _llm_serve_section(results)
-
     # --------------------- 100k-task drain: sustained head envelope
     # (r10 acceptance scenario; r16 acceptance metric — the scale at
     # which per-task head cost used to GROW with the in-flight
@@ -1426,23 +1285,6 @@ def main(as_json: bool = False) -> dict:
     return results
 
 
-def llm_main(as_json: bool = False) -> dict:
-    """Just the r19 serving A/B — the full suite takes tens of
-    minutes; this path re-measures the token plane in isolation."""
-    results: dict = {}
-    _llm_serve_section(results)
-    if as_json:
-        print(json.dumps(results))
-    else:
-        for name, r in results.items():
-            print(f"{name:24s} {r['per_second']:>10} {r['unit']}/s "
-                  f"(ttft p50/p99 {r['ttft_p50_ms']}/"
-                  f"{r['ttft_p99_ms']} ms, tpot p50/p99 "
-                  f"{r['tpot_p50_ms']}/{r['tpot_p99_ms']} ms, "
-                  f"head frames/tok {r['head_frames_per_token']})")
-    return results
-
-
 def rl_main(as_json: bool = False) -> dict:
     """Just the r20 Sebulba A/B — re-measures the RL act path in
     isolation (the full suite takes tens of minutes)."""
@@ -1460,9 +1302,7 @@ def rl_main(as_json: bool = False) -> dict:
 
 
 if __name__ == "__main__":
-    if "--serve-llm" in sys.argv:
-        llm_main(as_json="--json" in sys.argv)
-    elif "--rl" in sys.argv:
+    if "--rl" in sys.argv:
         rl_main(as_json="--json" in sys.argv)
     else:
         main(as_json="--json" in sys.argv)

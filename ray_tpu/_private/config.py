@@ -526,12 +526,6 @@ _define("direct_actor_delta_delay_max_ms", 250.0,
         "clock; a near-full frame snaps the window back to "
         "direct_actor_delta_delay_ms. Bounds both mirror lag and "
         "crash-loss scope for slow callers.")
-_define("llm_stream", True,
-        "LLM serving token transport (serve/llm): 1 streams tokens "
-        "over a peer-dialed push connection to the engine replica "
-        "(r18-style direct plane — the head never sees a token "
-        "frame); 0 falls back to the polled next_tokens actor-call "
-        "path through the ordinary request plane.")
 _define("llm_page_size", 16,
         "KV-cache page size in token positions. Every sequence's "
         "cache occupancy is a whole number of pages; smaller pages "
@@ -546,11 +540,6 @@ _define("llm_step_delay_s", 0.0,
         "iterations. Stretches generations so fault-injection tests "
         "can land a kill or partition mid-stream; keep 0 in "
         "production.")
-_define("llm_stream_wait_s", 0.5,
-        "Polled token fallback (llm_stream=0): how long next_tokens "
-        "parks server-side waiting for fresh tokens before returning "
-        "an empty slice — converts client busy-polling into bounded "
-        "server-side waits.")
 _define("rl_ring_depth", 2,
         "Sebulba RL trajectory rings (rllib/sebulba): wire-channel "
         "ring depth between each env-runner and the learner. The "

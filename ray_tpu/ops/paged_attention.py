@@ -25,7 +25,8 @@ reach the kernel through the Pallas interpreter
 The latent pool of `models.mla_moe` has its own kernel at the end of this
 file, `mla_paged_decode_attn`: a position is one row `[c_kv | k_rope |
 padding]` shared by every head, read once and used as the key (all of it)
-and as the value (its first `latent` numbers).
+and as the value (its first `latent` numbers). Both kernels stand on one
+page walk (`_walk_pages`) and differ in their matmuls.
 """
 from __future__ import annotations
 
@@ -93,15 +94,24 @@ def paged_attention_reference(q, k_pool, v_pool, layer, page_tables,
     return out.astype(q.dtype).reshape(B, n_heads, hd)
 
 
-# ---------------------------------------------------------------- kernel
-def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
-                         q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, acc_ref, m_ref, l_ref, *,
-                         sm_scale: float, page_size: int,
-                         block_pages: int, max_pages: int):
+# ----------------------------------------- the page walk of both kernels
+#
+# What the per-head kernel and the latent one (end of this file) share is
+# everything but their matmuls: a lane's table read in order, its live
+# pages copied in block by block behind the block being attended to, the
+# mask of what a block holds, the online softmax, and the `pallas_call`
+# around it. A kernel's body names its keys and values; the rest is here.
+def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
+                l_ref, copies, value_buf, attend, *, page_size: int,
+                block_pages: int, max_pages: int):
+    """A paged decode kernel but for its matmuls: `attend(slot, seen)` on
+    every block of pages the grid's lane holds, in table order, between the
+    reset of the running softmax and its division into `o_ref`. `slot`: the
+    block's place in the buffers; `seen` (1, block positions): what the
+    lane sees of it. `copies`: (pool in HBM, buffer, its semaphore's index
+    after the slot's) a pool; `value_buf`: where the values are read."""
     b = pl.program_id(0)
-    slots = k_buf.shape[0]
-    kvh, _, hd = q_ref.shape[1:]
+    slots = value_buf.shape[0]
     bk = block_pages * page_size
     layer = layer_ref[0]
     length = len_ref[b]
@@ -123,16 +133,10 @@ def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
 
             @pl.when(live)
             def _():
-                for pool, buf, sem in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                for pool, buf, sem in copies:
                     act(pltpu.make_async_copy(
                         pool.at[layer, page], buf.at[slot, p],
-                        sems.at[slot, sem]))
-
-    def start(blk):
-        each_copy(blk, lambda copy: copy.start())
-
-    def wait(blk):
-        each_copy(blk, lambda copy: copy.wait())
+                        sems.at[(slot, *sem)]))
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
@@ -143,55 +147,119 @@ def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
         # a page that is not copied in keeps what its buffer held: the
         # values of an earlier block, finite, once this has run (a
         # probability of 0 does not neutralise NaN: 0 * NaN)
-        v_buf[:] = jnp.zeros_like(v_buf)
+        value_buf[:] = jnp.zeros_like(value_buf)
 
     for ahead in range(slots - 1):
         @pl.when(ahead < n_blocks)
         def _():
-            start(ahead)
+            each_copy(ahead, lambda copy: copy.start())
 
     def body(blk, carry):
         @pl.when(blk + slots - 1 < n_blocks)
         def _():
-            start(blk + slots - 1)
+            each_copy(blk + slots - 1, lambda copy: copy.start())
 
-        wait(blk)
-        slot = blk % slots
+        each_copy(blk, lambda copy: copy.wait())
         pos = blk * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         page_of = lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page_size
         seen = pos < length                              # (1, bk)
         for p in range(block_pages):
             _, live = page_at(blk, p)
             seen = seen & ((page_of != p) | live)
-        for h in range(kvh):
-            q = q_ref[0, h]                              # (G, hd)
-            head = slice(h * hd, (h + 1) * hd)
-            k = k_buf[slot, :, :, head].reshape(bk, hd)
-            v = v_buf[slot, :, :, head].reshape(bk, hd)
-            s = lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * sm_scale  # (G, bk)
-            s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
-            m_prev = m_ref[h, :, :1]                     # (G, 1)
-            m_new = jnp.maximum(m_prev,
-                                jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            # (a block with nothing to see leaves m at the mask's value)
-            prob = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-            l_new = alpha * l_ref[h, :, :1] + jnp.sum(
-                prob, axis=-1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + lax.dot_general(
-                prob.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+        attend(blk % slots, seen)
         return carry
 
     lax.fori_loop(0, n_blocks, body, 0)
-
-    l = l_ref[:, :, :1]
+    l = l_ref[..., :1]
     o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
         o_ref.dtype)
+
+
+def _softmax_update(q, k, v, seen, sm_scale: float, acc, m, l):
+    """One block into the running softmax of `q` (rows, width): `k`
+    (positions, width), `v` (positions, out), `seen` (1, positions); refs
+    `acc` (rows, out), `m` and `l` (rows, 128) of these rows."""
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(seen, s, DEFAULT_MASK_VALUE)           # (rows, bk)
+    m_prev = m[:, :1]                                    # (rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    # (a block with nothing to see leaves m at the mask's value)
+    prob = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+    l_new = alpha * l[:, :1] + jnp.sum(prob, axis=-1, keepdims=True)
+    acc[:] = acc[:] * alpha + lax.dot_general(
+        prob.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m[:] = jnp.broadcast_to(m_new, m.shape)
+    l[:] = jnp.broadcast_to(l_new, l.shape)
+
+
+def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
+                       page_tables, lengths, *, block_pages: int,
+                       out_width: int, sems: tuple, interpret: bool):
+    """The `pallas_call` around `_walk_pages`: a grid over the lanes; the
+    layer, lengths and flat tables by scalar prefetch; `q` (lanes, ...,
+    rows, width) a lane a block; the pools left in HBM; scratch as
+    `_walk_pages` takes it, the blocks of at most `block_pages` pages.
+    `kernel` gets the walk's sizes by keyword."""
+    lanes, *rows = q.shape
+    out = (*rows[:-1], out_width)
+    stat = (*rows[:-1], 128)
+    page_size, max_pages = pools[0].shape[2], page_tables.shape[1]
+    block_pages = min(block_pages, max_pages)
+
+    def lane(b, *_):
+        return (b,) + (0,) * len(rows)
+
+    call = pl.pallas_call(
+        functools.partial(kernel, page_size=page_size,
+                          block_pages=block_pages, max_pages=max_pages),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(lanes,),
+            in_specs=[pl.BlockSpec((1, *rows), lane)]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec((1, *out), lane),
+            scratch_shapes=[
+                *(pltpu.VMEM((BLOCK_SLOTS, block_pages, page_size,
+                              pool.shape[3]), pool.dtype)
+                  for pool in pools),
+                pltpu.SemaphoreType.DMA(sems),
+                pltpu.VMEM(out, jnp.float32),            # acc
+                pltpu.VMEM(stat, jnp.float32),           # running max
+                pltpu.VMEM(stat, jnp.float32),           # running sum
+            ]),
+        out_shape=jax.ShapeDtypeStruct((lanes, *out), q.dtype),
+        interpret=interpret,
+        name=name,
+    )
+    with jax.named_scope(scope):
+        return call(jnp.asarray(layer, jnp.int32).reshape(1),
+                    lengths.astype(jnp.int32),
+                    page_tables.astype(jnp.int32).reshape(-1), q, *pools)
+
+
+# ---------------------------------------------------------------- kernel
+def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
+                         q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, acc_ref, m_ref, l_ref, *,
+                         sm_scale: float, **walk):
+    kvh, _, hd = q_ref.shape[1:]
+    bk = k_buf.shape[1] * k_buf.shape[2]
+
+    def attend(slot, seen):
+        for h in range(kvh):
+            head = slice(h * hd, (h + 1) * hd)           # a head's lanes
+            _softmax_update(
+                q_ref[0, h],                             # (G, hd)
+                k_buf[slot, :, :, head].reshape(bk, hd),
+                v_buf[slot, :, :, head].reshape(bk, hd),
+                seen, sm_scale, acc_ref.at[h], m_ref.at[h], l_ref.at[h])
+
+    _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
+                l_ref, ((k_hbm, k_buf, (0,)), (v_hbm, v_buf, (1,))), v_buf,
+                attend, **walk)
 
 
 def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
@@ -229,42 +297,14 @@ def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
 @functools.partial(jax.jit, static_argnames="interpret")
 def _paged_decode_call(qg, k_pool, v_pool, layer, page_tables, lengths,
                        interpret: bool):
-    B, kvh, group, hd = qg.shape
-    page_size = k_pool.shape[2]
-    max_pages = page_tables.shape[1]
-    block_pages = min(BLOCK_PAGES, max_pages)
-    kernel = functools.partial(
-        _paged_decode_kernel, sm_scale=1.0 / math.sqrt(hd),
-        page_size=page_size, block_pages=block_pages, max_pages=max_pages)
-    block = (1, kvh, group, hd)
-    buf = (BLOCK_SLOTS, block_pages, page_size, kvh * hd)
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec(block, lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec(block, lambda b, *_: (b, 0, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM(buf, k_pool.dtype),
-                pltpu.VMEM(buf, v_pool.dtype),
-                pltpu.SemaphoreType.DMA((BLOCK_SLOTS, 2)),
-                pltpu.VMEM((kvh, group, hd), jnp.float32),    # acc
-                pltpu.VMEM((kvh, group, 128), jnp.float32),   # running max
-                pltpu.VMEM((kvh, group, 128), jnp.float32),   # running sum
-            ]),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
-        interpret=interpret,
-        name=KERNEL_PAGED_DECODE,
-    )
-    with jax.named_scope(KERNEL_PAGED_SCOPE):
-        return call(layer, lengths.astype(jnp.int32),
-                    page_tables.astype(jnp.int32).reshape(-1),
-                    qg, k_pool, v_pool)
+    hd = qg.shape[-1]
+    kernel = functools.partial(_paged_decode_kernel,
+                               sm_scale=1.0 / math.sqrt(hd))
+    return _paged_pallas_call(
+        kernel, KERNEL_PAGED_DECODE, KERNEL_PAGED_SCOPE, qg,
+        (k_pool, v_pool), layer, page_tables, lengths,
+        block_pages=BLOCK_PAGES, out_width=hd, sems=(BLOCK_SLOTS, 2),
+        interpret=interpret)
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, page_tables, lengths,
@@ -348,84 +388,17 @@ def mla_paged_attention_reference(q, pool, layer, page_tables, lengths,
 def _mla_paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
                              q_ref, pool_hbm, o_ref,
                              buf, sems, acc_ref, m_ref, l_ref, *,
-                             sm_scale: float, page_size: int,
-                             block_pages: int, max_pages: int,
-                             latent: int):
-    b = pl.program_id(0)
-    slots, width = buf.shape[0], buf.shape[3]
-    bk = block_pages * page_size
-    layer = layer_ref[0]
-    length = len_ref[b]
-    n_pages = jnp.minimum(pl.cdiv(length, page_size), max_pages)
-    n_blocks = pl.cdiv(n_pages, block_pages)
-
-    def page_at(blk, p):
-        idx = blk * block_pages + p
-        page = pt_ref[b * max_pages + jnp.minimum(idx, max_pages - 1)]
-        return page, (idx < n_pages) & (page >= 0)
-
-    def each_copy(blk, act):
-        slot = blk % slots
-        for p in range(block_pages):
-            page, live = page_at(blk, p)
-
-            @pl.when(live)
-            def _():
-                act(pltpu.make_async_copy(
-                    pool_hbm.at[layer, page], buf.at[slot, p],
-                    sems.at[slot]))
-
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
-    l_ref[:] = jnp.zeros_like(l_ref)
-
-    @pl.when(b == 0)
-    def _():
-        # as in the per-head kernel: a page not copied in must hold
-        # finite values (0 * NaN)
-        buf[:] = jnp.zeros_like(buf)
-
-    for ahead in range(slots - 1):
-        @pl.when(ahead < n_blocks)
-        def _():
-            each_copy(ahead, lambda copy: copy.start())
-
+                             sm_scale: float, latent: int, **walk):
+    bk, width = buf.shape[1] * buf.shape[2], buf.shape[3]
     q = q_ref[0]                                         # (heads, width)
 
-    def body(blk, carry):
-        @pl.when(blk + slots - 1 < n_blocks)
-        def _():
-            each_copy(blk + slots - 1, lambda copy: copy.start())
+    def attend(slot, seen):
+        rows = buf[slot].reshape(bk, width)      # keys; values in front
+        _softmax_update(q, rows, rows[:, :latent], seen, sm_scale,
+                        acc_ref, m_ref, l_ref)
 
-        each_copy(blk, lambda copy: copy.wait())
-        slot = blk % slots
-        pos = blk * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        page_of = lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page_size
-        seen = pos < length
-        for p in range(block_pages):
-            _, live = page_at(blk, p)
-            seen = seen & ((page_of != p) | live)
-        rows = buf[slot].reshape(bk, width)
-        s = lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        s = jnp.where(seen, s, DEFAULT_MASK_VALUE)       # (heads, bk)
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        prob = jnp.where(seen, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_ref[:, :1] + jnp.sum(prob, axis=-1,
-                                               keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
-            prob.astype(rows.dtype), rows[:, :latent],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-        return carry
-
-    lax.fori_loop(0, n_blocks, body, 0)
-    l = l_ref[:, :1]
-    o_ref[0] = (acc_ref[:] / jnp.where(l == 0.0, 1.0, l)).astype(
-        o_ref.dtype)
+    _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
+                l_ref, ((pool_hbm, buf, ()),), buf, attend, **walk)
 
 
 # jitted for the reason `_paged_decode_call` is: traced once a program
@@ -433,7 +406,7 @@ def _mla_paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
                    static_argnames=("latent", "sm_scale", "interpret"))
 def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
                            latent: int, sm_scale: float, interpret: bool):
-    B, heads, width = q.shape
+    heads, width = q.shape[1:]
     page_size = pool.shape[2]
     if not mla_paged_decode_tiles(width, latent, page_size, pool.dtype):
         raise ValueError(
@@ -444,38 +417,12 @@ def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
     sublanes = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
     hp = -(-heads // sublanes) * sublanes
     qp = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
-    max_pages = page_tables.shape[1]
-    block_pages = min(MLA_BLOCK_PAGES, max_pages)
-    kernel = functools.partial(
-        _mla_paged_decode_kernel, sm_scale=sm_scale, page_size=page_size,
-        block_pages=block_pages, max_pages=max_pages, latent=latent)
-    call = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, hp, width), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, hp, latent),
-                                   lambda b, *_: (b, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((BLOCK_SLOTS, block_pages, page_size, width),
-                           pool.dtype),
-                pltpu.SemaphoreType.DMA((BLOCK_SLOTS,)),
-                pltpu.VMEM((hp, latent), jnp.float32),    # acc
-                pltpu.VMEM((hp, 128), jnp.float32),       # running max
-                pltpu.VMEM((hp, 128), jnp.float32),       # running sum
-            ]),
-        out_shape=jax.ShapeDtypeStruct((B, hp, latent), q.dtype),
-        interpret=interpret,
-        name=KERNEL_MLA_PAGED_DECODE,
-    )
-    with jax.named_scope(KERNEL_MLA_PAGED_SCOPE):
-        out = call(jnp.asarray(layer, jnp.int32).reshape(1),
-                   lengths.astype(jnp.int32),
-                   page_tables.astype(jnp.int32).reshape(-1), qp, pool)
+    kernel = functools.partial(_mla_paged_decode_kernel, sm_scale=sm_scale,
+                               latent=latent)
+    out = _paged_pallas_call(
+        kernel, KERNEL_MLA_PAGED_DECODE, KERNEL_MLA_PAGED_SCOPE, qp,
+        (pool,), layer, page_tables, lengths, block_pages=MLA_BLOCK_PAGES,
+        out_width=latent, sems=(BLOCK_SLOTS,), interpret=interpret)
     return out[:, :heads]
 
 

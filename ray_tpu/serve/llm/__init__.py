@@ -24,8 +24,9 @@ a TPU pass ``ray_actor_options={"num_tpus": n}`` and keep replicas x n
 within the host's chips; ``mesh={"dp": 1, "tp": n}`` then splits the
 weights and the KV cache over them.
 
-`RAY_TPU_LLM_STREAM=0` falls back to polled `next_tokens` actor calls
-(the legacy chunk path's semantics, with server-side parking).
+Tokens leave a replica one way: pushed to the subscriber over the
+engine's own stream listener (`stream.py`); a replica that offers no
+stream address, or refuses a subscription, is failed over like a dead one.
 """
 from __future__ import annotations
 

@@ -26,9 +26,11 @@ not wait. The core has no threads, which is what the tier-1 tests
 drive: a last call with nothing to dispatch reads what is in flight.
 
 `LLMEngine` wraps the core as a Serve deployment class: a background
-step thread, per-request token buffers for the polled fallback, and a
-`TokenStreamServer` pushing tokens to peer-dialed subscribers the
-moment the step that produced them completes (CONFIG.llm_stream).
+step thread, and a `TokenStreamServer` pushing tokens to peer-dialed
+subscribers the moment the step that produced them completes — the one
+way tokens leave a replica. The per-request token buffers are that
+stream's backlog (a subscriber that connects late or again is replayed
+from its cursor) and where TTFT / TPOT are taken for the metrics plane.
 
 Failure semantics: every emitted token carries (incarnation, attempt,
 seq). A replica that restarts gets a fresh incarnation; a request
@@ -52,6 +54,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from ray_tpu._private import tracing_plane as _tp
 from ray_tpu.serve.llm import spans as _sp
 from ray_tpu.serve.llm.kv_cache import PageAllocator, pages_needed
+from ray_tpu.serve.llm.stream import TokenStreamServer
 
 FINISH_STOP = "stop"
 FINISH_LENGTH = "length"
@@ -705,16 +708,12 @@ class LLMEngine:
                                max_batch=max_batch)
         self.incarnation = uuid.uuid4().hex[:8]
         self._lock = threading.Lock()        # core + buffers
-        self._cond = threading.Condition(self._lock)
         # rid -> {"toks": [...], "done", "reason", "err", "t_done",
         #         "attempt", "submit_t", "last_tok_t"}
         self._buf: Dict[str, dict] = {}
         self._metrics = _serving_metrics()
-        self._stream = None
-        if CONFIG.llm_stream:
-            from ray_tpu.serve.llm.stream import TokenStreamServer
-            self._stream = TokenStreamServer(self.incarnation,
-                                             self._backlog, self._lock)
+        self._stream = TokenStreamServer(self.incarnation, self._backlog,
+                                         self._lock)
         # the step thread's traceback once core.step() has raised: the
         # engine is dead from then on and says so, it does not look slow
         self._failed: Optional[str] = None
@@ -770,8 +769,7 @@ class LLMEngine:
                            "seq": len(b["toks"]), "first": False,
                            "done": True, "reason": FINISH_ERROR,
                            "attempt": b["attempt"], "err": err})
-        self._cond.notify_all()
-        if self._stream is not None and events:
+        if events:
             self._stream.publish(events)
 
     def check_health(self) -> None:
@@ -782,8 +780,8 @@ class LLMEngine:
             raise RuntimeError(f"llm engine step failed:\n{self._failed}")
 
     def _ingest(self, events: List[dict]) -> None:
-        """Record step output into the polled buffers and wake parked
-        pollers; push to stream subscribers OUTSIDE any model time."""
+        """Record step output into the stream's backlog buffers, then
+        push it to the subscribers, OUTSIDE any model time."""
         with _sp.span(_sp.INGEST):
             now = time.monotonic()
             for ev in events:
@@ -804,10 +802,8 @@ class LLMEngine:
                     b["done"] = True
                     b["reason"] = ev["reason"]
                     b["t_done"] = now
-            self._cond.notify_all()
             self._sweep(now)
-            if self._stream is not None:
-                self._stream.publish(events)
+            self._stream.publish(events)
 
     def _sweep(self, now: float) -> None:     # holds self._lock
         dead = [rid for rid, b in self._buf.items()
@@ -832,8 +828,8 @@ class LLMEngine:
 
     def generate(self, prompt, max_tokens: int = 16, stop=(),
                  rid: Optional[str] = None, attempt: int = 0) -> dict:
-        """Accept one generation; tokens arrive via the push stream
-        (subscribe at `stream` with `rid`) or next_tokens polling."""
+        """Accept one generation; its tokens arrive on the push stream:
+        subscribe at the returned `stream` address with `rid`."""
         submit_t = time.monotonic()
         with _sp.span(_sp.SUBMIT, rid=rid or ""):
             self._lock.acquire()
@@ -851,38 +847,7 @@ class LLMEngine:
         self._kick.set()
         return {"rid": rid, "attempt": int(attempt),
                 "incarnation": self.incarnation,
-                "stream": (self._stream.addr if self._stream else None)}
-
-    def next_tokens(self, rid: str, cursor: int = 0,
-                    wait_s: Optional[float] = None,
-                    limit: int = 256) -> dict:
-        """Polled fallback (CONFIG.llm_stream=0): park up to wait_s for
-        tokens past `cursor` — bounded server-side waits instead of
-        client busy-polling."""
-        from ray_tpu._private.config import CONFIG
-        wait_s = CONFIG.llm_stream_wait_s if wait_s is None else wait_s
-        deadline = time.monotonic() + max(0.0, wait_s)
-        with self._cond:
-            while True:
-                b = self._buf.get(rid)
-                if b is None:
-                    raise RuntimeError(
-                        f"unknown request {rid!r} on this replica")
-                if len(b["toks"]) > cursor or b["done"]:
-                    toks = b["toks"][cursor:cursor + limit]
-                    return {"toks": toks, "cursor": cursor + len(toks),
-                            "done": (b["done"] and
-                                     cursor + len(toks) >= len(b["toks"])),
-                            "reason": b["reason"], "err": b["err"],
-                            "attempt": b["attempt"],
-                            "incarnation": self.incarnation}
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    return {"toks": [], "cursor": cursor, "done": False,
-                            "reason": None, "err": None,
-                            "attempt": b["attempt"],
-                            "incarnation": self.incarnation}
-                self._cond.wait(remaining)
+                "stream": self._stream.addr}
 
     def cancel(self, rid: str) -> bool:
         with self._lock:
@@ -911,8 +876,7 @@ class LLMEngine:
                     {"rid": d["rid"], "token": None, "seq": 0,
                      "first": False, "done": True,
                      "reason": FINISH_DRAINED, "attempt": d["attempt"]})
-            self._cond.notify_all()
-            if self._stream is not None and drained_events:
+            if drained_events:
                 self._stream.publish(drained_events)
         return descs
 
@@ -924,7 +888,7 @@ class LLMEngine:
         st["pid"] = os.getpid()
         st["failed"] = self._failed
         st["incarnation"] = self.incarnation
-        st["stream"] = self._stream.addr if self._stream else None
+        st["stream"] = self._stream.addr
         return st
 
     def __serve_stats__(self) -> dict:
@@ -948,8 +912,7 @@ class LLMEngine:
     def close(self):
         self._stop.set()
         self._kick.set()
-        if self._stream is not None:
-            self._stream.close()
+        self._stream.close()
 
     def __del__(self):
         try:

@@ -25,6 +25,7 @@ import time
 from typing import Dict, List, Optional, Sequence
 
 import ray_tpu
+from ray_tpu.serve.llm.stream import stream_client
 
 _FAILOVER_MAX = 4
 
@@ -160,22 +161,19 @@ class LLMHandle:
 class TokenStream:
     """Iterator over one generation's tokens with transparent failover.
 
-    Push mode (CONFIG.llm_stream): frames arrive on the peer-dialed
-    stream connection; `__next__` just waits on the sink queue. Polled
-    mode: `next_tokens` actor calls with server-side parking and
-    client-side adaptive backoff. Either way the consumer sees each
-    token exactly once and a terminal error at most once.
+    Frames arrive on the peer-dialed stream connection of the replica
+    that accepted the generation; `__next__` just waits on the sink
+    queue. The consumer sees each token exactly once and a terminal
+    error at most once.
     """
 
     def __init__(self, handle: LLMHandle, prompt: List[int],
                  max_tokens: int, stop: List[int], timeout_s: float):
-        from ray_tpu._private.config import CONFIG
         self._h = handle
         self._prompt = prompt
         self._max_tokens = max_tokens
         self._stop = stop
         self._timeout_s = timeout_s
-        self._push = bool(CONFIG.llm_stream)
         self.emitted: List[int] = []
         self.finish_reason: Optional[str] = None
         self._pending: List[int] = []
@@ -186,7 +184,6 @@ class TokenStream:
         self._sink: queue.Queue = queue.Queue()
         self._cursor = 0          # engine-side tokens consumed (attempt)
         self._owed = 0            # depth this stream added to replica
-        self._backoff = 0.0
         self.ttft_s: Optional[float] = None
         self.t_last: Optional[float] = None
         self._t_submit = time.monotonic()
@@ -236,24 +233,21 @@ class TokenStream:
             self._inc = acc["incarnation"]
             self._stream_addr = acc.get("stream")
             self._cursor = 0
-            self._owed = max_tokens
-            self._h._depth_add(replica, max_tokens)
             # fresh sink per attempt: frames a dead attempt already
             # delivered can never masquerade as the new one's
             self._sink = queue.Queue()
-            if self._push and not self._stream_addr:
-                # engine replica runs with the stream plane off
-                # (RAY_TPU_LLM_STREAM=0 server-side): poll instead
-                self._push = False
-            if self._push:
-                from ray_tpu.serve.llm.stream import stream_client
-                ok = stream_client().subscribe(
+            # a replica that names no stream address has failed this
+            # attempt as one that refuses the subscription has
+            if not (self._stream_addr and stream_client().subscribe(
                     tuple(self._stream_addr), self._rid, self._inc,
-                    self._attempt, 0, self._sink)
-                if not ok:
-                    self._h._note_failure(replica)
-                    exclude = tuple(exclude) + (replica._actor_id,)
-                    continue
+                    self._attempt, 0, self._sink)):
+                last_err = RuntimeError(
+                    f"replica gave no token stream for {self._rid!r}")
+                self._h._note_failure(replica)
+                exclude = tuple(exclude) + (replica._actor_id,)
+                continue
+            self._owed = max_tokens
+            self._h._depth_add(replica, max_tokens)
             return
         raise RuntimeError(
             f"llm generate failed after {tries} attempts") from last_err
@@ -269,8 +263,7 @@ class TokenStream:
             self._h._note_failure(dead)
             self._h._depth_add(dead, -self._owed)
             self._owed = 0
-        if self._push and self._rid:
-            from ray_tpu.serve.llm.stream import stream_client
+        if self._rid:
             stream_client().unsubscribe(self._rid)
         self._attempt += 1
         self._submit(exclude=(dead._actor_id,) if dead is not None
@@ -292,10 +285,7 @@ class TokenStream:
                 return tok
             if self.finish_reason is not None:
                 raise StopIteration
-            if self._push:
-                self._pump_push()
-            else:
-                self._pump_polled()
+            self._pump()
 
     def _accept(self, base: int, toks: List[int]) -> None:
         """Overlap-trimmed append: only tokens at exactly the next
@@ -312,7 +302,7 @@ class TokenStream:
                 self._h._depth_add(self._replica, -len(fresh))
                 self._owed = max(0, self._owed - len(fresh))
 
-    def _pump_push(self) -> None:
+    def _pump(self) -> None:
         try:
             msg = self._sink.get(timeout=self._timeout_s)
         except queue.Empty:
@@ -334,45 +324,12 @@ class TokenStream:
                 raise RuntimeError(f"generation failed: {msg['err']}")
             self._finish(reason)
 
-    def _pump_polled(self) -> None:
-        try:
-            out = ray_tpu.get(self._replica.handle_request.remote(
-                "next_tokens", (self._rid,),
-                {"cursor": self._cursor}, False),
-                timeout=self._timeout_s)
-        except BaseException:
-            self._failover("poll failed")
-            return
-        if out.get("incarnation") != self._inc \
-                or out.get("attempt") != self._attempt:
-            self._failover("stale replica state")
-            return
-        toks = out.get("toks", [])
-        self._accept(self._cursor, toks)
-        if out.get("done"):
-            reason = out.get("reason")
-            if reason == "drained":
-                self._failover("replica drained")
-                return
-            if out.get("err"):
-                raise RuntimeError(
-                    f"generation failed: {out['err']}")
-            self._finish(reason)
-        elif not toks:
-            # dry poll: adaptive backoff on top of the server-side
-            # park, so an idle generation costs ~2 calls/s, not a spin
-            self._backoff = min(0.25, (self._backoff or 0.01) * 2)
-            time.sleep(self._backoff)
-        else:
-            self._backoff = 0.0
-
     def _finish(self, reason: Optional[str]) -> None:
         self.finish_reason = reason or "stop"
         if self._replica is not None:
             self._h._depth_add(self._replica, -self._owed)
             self._owed = 0
-        if self._push and self._rid:
-            from ray_tpu.serve.llm.stream import stream_client
+        if self._rid:
             stream_client().unsubscribe(self._rid)
 
     def tokens(self) -> List[int]:
@@ -385,8 +342,7 @@ class TokenStream:
         if self.finish_reason is not None:
             return
         self.finish_reason = "cancelled"
-        if self._push and self._rid:
-            from ray_tpu.serve.llm.stream import stream_client
+        if self._rid:
             stream_client().unsubscribe(self._rid)
         try:
             self._replica.handle_request.remote(

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 import ray_tpu
+from llm_streams import read_stream
 from ray_tpu.models import Transformer
 from ray_tpu.models.config import tiny
 from ray_tpu.ops.attention import flash_attention_kernel, mha_reference
@@ -244,7 +245,7 @@ def test_failing_engine_step_reaches_streams_and_generate():
             raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
         eng.core.step = boom
         acc = eng.generate([1, 2, 3], max_tokens=4)
-        out = eng.next_tokens(acc["rid"], wait_s=10.0)
+        _, out = read_stream(acc)
         assert out["done"] and out["reason"] == "error"
         assert "out of HBM" in out["err"]
         assert "out of HBM" in eng.engine_stats()["failed"]
@@ -305,13 +306,9 @@ def test_engine_on_a_mesh_shards_params_and_cache():
         try:
             toks = []
             for e in (eng, plain):
-                rid = e.generate([5, 6, 7, 8], max_tokens=6)["rid"]
-                got = []
-                while len(got) < 6:
-                    got += e.next_tokens(rid, cursor=len(got),
-                                         wait_s=30.0)["toks"]
-                toks.append(got)
-            assert toks[0] == toks[1]
+                toks.append(read_stream(
+                    e.generate([5, 6, 7, 8], max_tokens=6))[0])
+            assert toks[0] == toks[1] and len(toks[0]) == 6
         finally:
             plain.close()
     finally:

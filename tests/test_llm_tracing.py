@@ -6,7 +6,6 @@ program names the benchmark's `step.decode_ms.*` / `step.prefill_ms.chat`
 read. CPU, in-process, no cluster.
 """
 import os
-import queue
 import subprocess
 import sys
 import time
@@ -16,6 +15,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from llm_streams import read_stream
 from ray_tpu._private import tracing_plane as tp
 from ray_tpu._private.config import CONFIG
 from ray_tpu.models.config import tiny
@@ -285,22 +285,12 @@ def test_program_names_the_benchmark_reads(tiny_model):
 
 
 def test_llm_engine_emits_every_span_of_the_table(tiny_model, recorder):
-    from ray_tpu.serve.llm.stream import stream_client
     eng = LLMEngine(model="tiny", num_pages=32, page_size=8, max_batch=2,
                     seed=0)
     try:
         time.sleep(0.12)                # idle: the step thread waits
         acc = eng.generate([4, 5, 6], max_tokens=4, rid="s")
-        sink = queue.Queue()
-        assert stream_client().subscribe(acc["stream"], "s",
-                                         acc["incarnation"], 0, 0, sink)
-        got, deadline = 0, time.time() + 20
-        while time.time() < deadline:
-            msg = sink.get(timeout=10)
-            got = max(got, msg["base"] + len(msg["toks"]))
-            if msg["done"]:
-                break
-        assert got == 4
+        assert len(read_stream(acc)[0]) == 4
         st = eng.engine_stats()
         assert st["decode_steps"] >= 1
         assert isinstance(st["slow_steps"], list)
@@ -329,9 +319,8 @@ def test_step_histogram_on_the_metrics_plane(tiny_model):
     eng = LLMEngine(model="tiny", num_pages=32, page_size=8, max_batch=2,
                     seed=0)
     try:
-        eng.generate([1, 2, 3], max_tokens=3, rid="h")
-        while not eng.next_tokens("h", cursor=0, wait_s=5.0)["done"]:
-            pass
+        assert len(read_stream(
+            eng.generate([1, 2, 3], max_tokens=3, rid="h"))[0]) == 3
     finally:
         eng.close()
     assert "ray_tpu_llm_step_s" in DEFAULT_REGISTRY.prometheus_text()

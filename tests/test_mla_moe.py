@@ -229,6 +229,26 @@ def test_latent_kernel_skips_unassigned_entries():
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
+def test_latent_kernel_reads_pages_in_table_order():
+    """The same rows under another placement of the pages give the same
+    output (the table, not the pool's order, says where a position is),
+    over more than one block of pages a lane."""
+    q, pool, tables, lens = _latent_case((150, 60, 129), seed=3, pages=64,
+                                         max_pages=40)
+    assert -(-150 // 8) > paged.MLA_BLOCK_PAGES
+    perm = np.random.default_rng(4).permutation(pool.shape[1])
+    inv = np.argsort(perm).astype(np.int32)
+    moved = jnp.where(tables >= 0, jnp.asarray(inv)[jnp.maximum(tables, 0)],
+                      -1)
+    got = paged.mla_paged_decode_attention_kernel(
+        q, pool, 1, tables, lens, 128, 0.1)
+    again = paged.mla_paged_decode_attention_kernel(
+        q, pool[:, perm], 1, moved.astype(jnp.int32), lens, 128, 0.1)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+    np.testing.assert_allclose(got, paged.mla_paged_attention_reference(
+        q, pool, 1, tables, lens, 128, 0.1), atol=2e-5, rtol=2e-5)
+
+
 @pytest.mark.parametrize("width,latent,page,dtype,tiles", [
     (640, 512, 16, jnp.bfloat16, True), (576, 512, 16, jnp.bfloat16, False),
     (640, 512, 8, jnp.bfloat16, False), (128, 96, 8, jnp.float32, False),

@@ -2,8 +2,8 @@
 
 Tier-1 exercises the engine in-process on the CPU backend (no cluster):
 per-iteration admission ordering, page alloc/free across prefill/
-decode/eviction, stop/max-token termination, push + polled token
-transports with incarnation fencing. The slow e2e deploys two replica
+decode/eviction, stop/max-token termination, the push token stream
+with incarnation fencing. The slow e2e deploys two replica
 groups through serve and streams two concurrent generations of
 different lengths end to end.
 """
@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from llm_streams import read_stream
 from ray_tpu.models.config import tiny
 from ray_tpu.models.transformer import Transformer
 from ray_tpu.serve.llm.engine import (FINISH_LENGTH, FINISH_STOP,
@@ -416,33 +417,54 @@ def test_pipeline_counters_on_a_fixed_schedule(tiny_model):
 
 
 # ------------------------------------------------------ engine + stream
-def test_engine_polled_path_and_signals(tiny_model):
+def test_engine_stream_replay_and_signals(tiny_model):
+    """(Was `test_engine_polled_path_and_signals`: the same six tokens,
+    replay and signals, read through subscriptions.)"""
     eng = LLMEngine(model="tiny", num_pages=32, page_size=8,
                     max_batch=4, seed=0)
     try:
         acc = eng.generate([1, 2, 3], max_tokens=6, rid="p")
         assert acc["rid"] == "p" and acc["attempt"] == 0
-        out, cur = [], 0
-        while True:
-            r = eng.next_tokens("p", cursor=cur, wait_s=0.5)
-            assert r["incarnation"] == acc["incarnation"]
-            out.extend(r["toks"])
-            cur = r["cursor"]
-            if r["done"]:
-                break
-        assert len(out) == 6 and r["reason"] == FINISH_LENGTH
-        # mid-stream cursor replay: re-reading from 0 returns the full
-        # prefix again (dup-safe)
-        r0 = eng.next_tokens("p", cursor=0, wait_s=0.1)
-        assert r0["toks"][: len(out)] == out
-        with pytest.raises(RuntimeError):
-            eng.next_tokens("nope", wait_s=0.01)
+        out, last = read_stream(acc)
+        assert len(out) == 6 and last["reason"] == FINISH_LENGTH
+        assert last["inc"] == acc["incarnation"]
+        # cursor replay: a second subscription at 0 gets the whole
+        # prefix again (dup-safe), one from the middle gets the rest
+        again, last = read_stream(acc)
+        assert again == out and last["base"] == 0 and last["done"]
+        assert read_stream(acc, cursor=4)[0] == out[4:]
         st = eng.engine_stats()
         assert st["queue_wait_p95"] >= 0.0
+        assert tuple(st["stream"]) == tuple(acc["stream"])
         hook = eng.__serve_stats__()
         assert set(hook) >= {"queue_wait_p95", "outstanding_tokens"}
     finally:
         eng.close()
+
+
+def test_engine_has_one_token_path_whatever_the_environment(monkeypatch):
+    """The switch that chose a polled path is gone: its variable in the
+    environment selects nothing, and there is nothing to poll."""
+    from ray_tpu._private.config import CONFIG
+    monkeypatch.setenv("RAY_TPU_LLM_STREAM", "0")
+    CONFIG.reload()
+    try:
+        for gone in ("llm_stream", "llm_stream_wait_s"):
+            with pytest.raises(AttributeError):
+                getattr(CONFIG, gone)
+        eng = LLMEngine(model="tiny", num_pages=32, page_size=8,
+                        max_batch=2, seed=0)
+        try:
+            assert not hasattr(eng, "next_tokens")
+            assert not hasattr(eng, "_cond")
+            acc = eng.generate([1, 2, 3], max_tokens=3)
+            assert acc["stream"] == eng._stream.addr
+            assert len(read_stream(acc)[0]) == 3
+        finally:
+            eng.close()
+    finally:
+        monkeypatch.delenv("RAY_TPU_LLM_STREAM")
+        CONFIG.reload()
 
 
 def test_engine_push_stream_and_zombie_fence(tiny_model):
@@ -453,17 +475,8 @@ def test_engine_push_stream_and_zombie_fence(tiny_model):
         cl = stream_client()
         acc = eng.generate([4, 5, 6], max_tokens=5, rid="push1")
         assert acc["stream"] is not None
-        sink = queue.Queue()
-        assert cl.subscribe(acc["stream"], "push1",
-                            acc["incarnation"], 0, 0, sink)
-        toks, done, reason = [], False, None
-        deadline = time.time() + 10
-        while not done and time.time() < deadline:
-            msg = sink.get(timeout=5)
-            fresh = msg["toks"][max(0, len(toks) - msg["base"]):]
-            toks.extend(fresh)
-            done, reason = msg["done"], msg["reason"]
-        assert len(toks) == 5 and reason == FINISH_LENGTH
+        toks, last = read_stream(acc)
+        assert len(toks) == 5 and last["reason"] == FINISH_LENGTH
 
         # wrong incarnation -> every frame fenced, nothing delivered
         z0 = STREAM_STATS["zombie_dropped"]
@@ -488,18 +501,77 @@ def test_engine_push_stream_and_zombie_fence(tiny_model):
         eng.close()
 
 
+class _Remote:
+    """`.remote(...)` that answers in place (with `ray_tpu.get` patched
+    to hand its argument back)."""
+
+    def __init__(self, fn):
+        self.remote = fn
+
+
+class _Replica:
+    """What `TokenStream` sees of a replica actor: an id and
+    `handle_request.remote(method, args, kwargs, stream)`."""
+
+    def __init__(self, actor_id, engine=None):
+        self._actor_id = actor_id
+        self.calls = []
+
+        def call(method, args, kwargs, _streaming):
+            self.calls.append(method)
+            if engine is not None:
+                return getattr(engine, method)(*args, **kwargs)
+            # a replica that accepts the generation and names no stream
+            return {"rid": "lost", "attempt": kwargs["attempt"],
+                    "incarnation": "none", "stream": None}
+        self.handle_request = _Remote(call)
+
+
+def test_replica_without_a_stream_address_is_failed_over(monkeypatch):
+    """No transport is tried in the stream's place: the attempt fails as
+    a refused subscription does, the replica cools down, owes nothing,
+    and the next replica serves the whole generation."""
+    import ray_tpu
+    from ray_tpu.serve.llm import router
+    monkeypatch.setattr(ray_tpu, "get", lambda x, timeout=None: x)
+    eng = LLMEngine(model="tiny", num_pages=32, page_size=8,
+                    max_batch=2, seed=0)
+    try:
+        mute, good = _Replica("mute"), _Replica("good", eng)
+        ctrl = type("Ctrl", (), {})()
+        ctrl.get_replicas = _Remote(lambda name: [mute, good])
+        handle = router.LLMHandle("llm", controller=ctrl)
+        s = handle.generate([1, 2, 3], max_tokens=5, timeout_s=20)
+        assert not hasattr(s, "_push")
+        assert len(s.tokens()) == 5 and s.finish_reason == FINISH_LENGTH
+        assert mute.calls == ["generate"]       # asked once, never polled
+        assert good.calls == ["generate"]
+        assert s._replica is good and s.failovers == 0
+        assert "mute" in handle._cooldown
+        assert handle._depth.get("mute", 0) == 0
+        assert handle._depth.get("good", 0) == 0
+        # nobody left to ask: an error, not a wait on a path that is gone
+        ctrl.get_replicas = _Remote(lambda name: [mute])
+        handle._refresh(force=True)
+        with pytest.raises(RuntimeError, match="generate failed"):
+            handle.generate([1, 2, 3], max_tokens=2, timeout_s=5)
+    finally:
+        eng.close()
+
+
 def test_engine_drain_marks_and_publishes(tiny_model):
     eng = LLMEngine(model="tiny", num_pages=32, page_size=8,
                     max_batch=2, seed=0)
     try:
-        eng.generate([1] * 20, max_tokens=40, rid="d")
+        acc = eng.generate([1] * 20, max_tokens=40, rid="d")
         descs = eng.drain()
         assert [d["rid"] for d in descs] == ["d"]
         d = descs[0]
         # descriptor carries everything a survivor needs to re-prefill
         assert d["prompt"] == [1] * 20 and d["max_tokens"] == 40
-        r = eng.next_tokens("d", cursor=0, wait_s=0.1)
-        assert r["done"] and r["reason"] == "drained"
+        # a subscriber sees the terminal frame and fails over
+        _, last = read_stream(acc)
+        assert last["done"] and last["reason"] == "drained"
     finally:
         eng.close()
 
@@ -536,23 +608,10 @@ def test_llm_e2e_two_replicas_short_finishes_first(ray_cluster):
         assert len(results["short"]) == 4
         assert len(results["long"]) == 48
         assert done_at["short"] < done_at["long"]
-        # push transport actually carried the tokens (no polling)
-        from ray_tpu._private.config import CONFIG
-        if CONFIG.llm_stream:
-            assert STREAM_STATS["tokens_in"] - t_in0 >= 52
+        # the push transport carried the tokens
+        assert STREAM_STATS["tokens_in"] - t_in0 >= 52
         st = handle.stats()
         assert len(st) >= 2          # one engine_stats dict per replica
-
-        # polled fallback: same request plane, no push subscription
-        import os
-        os.environ["RAY_TPU_LLM_STREAM"] = "0"
-        CONFIG.reload()
-        try:
-            s = handle.generate([9, 9, 9], max_tokens=3, timeout_s=120)
-            assert len(s.tokens()) == 3
-        finally:
-            os.environ.pop("RAY_TPU_LLM_STREAM", None)
-            CONFIG.reload()
     finally:
         from ray_tpu import serve as _s
         _s.shutdown()

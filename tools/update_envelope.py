@@ -57,12 +57,6 @@ LABELS = [
      "(RAY_TPU_DIRECT_ACTOR=0)"),
     ("actor_sync_direct",
      "sync actor calls, worker caller, direct plane (r18)"),
-    ("serve_llm_polled",
-     "LLM serving open-loop, 2 replica groups, polled token plane "
-     "(RAY_TPU_LLM_STREAM=0)"),
-    ("serve_llm_stream",
-     "LLM serving open-loop, 2 replica groups, direct-stream tokens "
-     "(r19)"),
     ("rl_sebulba_head",
      "Sebulba RL, 4 env-runners x 2 inference actors, head-routed "
      "act() (RAY_TPU_DIRECT_ACTOR=0)"),
@@ -128,20 +122,6 @@ def _fmt_result(rec: dict) -> str:
             # multiple of the same-session 5k-delegated floor
             out += (f" ({rec['vs_delegated_floor']}x the 5k-delegated "
                     f"head-CPU floor)")
-        if "ttft_p50_ms" in rec:
-            # r19 serving columns: time-to-first-token (admission +
-            # prefill) and time-per-output-token (decode cadence)
-            out += (f" (ttft p50/p99 {rec['ttft_p50_ms']}/"
-                    f"{rec['ttft_p99_ms']} ms, tpot p50/p99 "
-                    f"{rec['tpot_p50_ms']}/{rec['tpot_p99_ms']} ms)")
-        if "head_frames_per_token" in rec:
-            # r19 acceptance counter: head socket frames per generated
-            # token net of the stream plane's own (~0 on the direct-
-            # stream arm — tokens ride peer-dialed connections)
-            out += (f" (head frames/tok "
-                    f"{rec['head_frames_per_token']})")
-        if "stream_speedup" in rec:
-            out += f" (stream speedup {rec['stream_speedup']}x)"
         if "staleness_p50" in rec:
             # r20 Sebulba columns: policy-version lag of each shard
             # the learner consumed (bounded by the trajectory ring
@@ -237,7 +217,7 @@ def _fmt_bubble(rec: dict) -> str:
 
 def render_block(results: dict, keep: dict = None) -> str:
     """`keep` maps scenario label -> previously rendered row: a
-    partial run (e.g. ``bench_core.py --serve-llm``) refreshes only
+    partial run (e.g. ``bench_core.py --rl``) refreshes only
     its own rows and the rest of the table survives verbatim."""
     keep = keep or {}
     known = [k for k, _ in LABELS]

@@ -32,6 +32,13 @@ so no per-layer slice of it is ever made. Prefill scans the layer body
 step unrolls a Python loop over layers — at serving depths that compile
 cost is paid once per (batch, pages) shape.
 
+The decode step's q / k / v products stay flat `(B, 1, heads * hd)`
+through one `lax.optimization_barrier`: with the view into heads in the
+compiler's reach it folds it into the dot and, for that, writes every
+layer's wq and wk out transposed in every step (a quarter of the step's
+bytes). `tests/test_kernel_names_aot.py` pins that the step compiled
+for a v5e makes no copy of either. `_qkv` is `prefill`'s alone.
+
 Both programs move cache bytes in proportion to what the lanes hold,
 not to the pool or the context limit. The writes are scatters into the
 pool itself, which holds only if the caller donates the cache
@@ -242,13 +249,15 @@ def decode_step(model, params: Params, cache: KVCache,
     for i in range(c.n_layers):
         layer = jax.tree_util.tree_map(lambda a: a[i], layers)
         h = model._norm(x, layer["attn_norm"])
-        q, k, v = _qkv(c, layer, h)                  # (B, 1, heads, hd)
-        q = apply_rope_cached(q, cos, sin)
-        k = apply_rope_cached(k, cos, sin)
+        # flat until past the barrier (the module's docstring says why)
+        q, k, v = lax.optimization_barrier(tuple(
+            h @ layer[w].astype(ad) for w in ("wq", "wk", "wv")))
+        q = apply_rope_cached(q.reshape(B, 1, c.n_heads, hd), cos, sin)
+        k = apply_rope_cached(k.reshape(B, 1, c.kv_heads, hd), cos, sin)
         ck = ck.at[i, wr_page, wr_slot].set(
-            k[:, 0].astype(ck.dtype).reshape(B, -1), mode="drop")
+            k.astype(ck.dtype).reshape(B, -1), mode="drop")
         cv = cv.at[i, wr_page, wr_slot].set(
-            v[:, 0].astype(cv.dtype).reshape(B, -1), mode="drop")
+            v[:, 0].astype(cv.dtype), mode="drop")
         out = _paged.paged_decode_attention(
             q[:, 0], ck, cv, i, page_tables, lengths,
             mesh=model.kernel_mesh)

@@ -4,8 +4,8 @@ instruction, which is what an `XLA Ops` event of a profiler trace is
 called and what `benchmarks/metrics/kernel.flash_*_roofline.train.py`
 and the ledger's `device_ops` find it by. Also a guard that the kernels
 still compile for the chip at a real head size, and that the decode
-step compiled for the chip holds the paged kernel and updates the pool
-in place.
+step compiled for the chip holds the paged kernel, updates the pool in
+place and reads its weights where they lie.
 
 The topology is described inside a module-scoped fixture (one process at
 a time may load libtpu: nothing here touches it at import time), and all
@@ -98,22 +98,24 @@ def test_no_kernel_is_named_after_its_enclosing_call(compiled_text):
 # a pool of the cells' 2048 pages: a small one the compiler would move
 # into fast memory whole, which no deployment's fits
 LANES, PAGE, PAGES = 8, 16, 2048
+# the dense serving cells' widths; six of their 24 layers compile in 2 s
+LAYERS, D_MODEL, HEADS, KV_HEADS, D_FF = 6, 2048, 16, 8, 8192
 
 
 def _compile_decode_step(devices, tp: int):
     """The decode step as `EngineCore` jits it (the cache donated, on a
-    mesh handed back as it lay): two layers at the serving cells' head
-    shapes (16 heads over 8 kv heads of 128, 16-token bf16 pages, 8
-    lanes), on one chip or over `tp` of them. Returns (compiled, the
-    pool's shape on a device)."""
+    mesh handed back as it lay): six layers at the serving cells' widths
+    (2048, 16 heads over 8 kv heads of 128, d_ff 8192, 16-token bf16
+    pages, 8 lanes), on one chip or over `tp` of them. Returns
+    (compiled, the pool's shape on a device)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from ray_tpu.models import Transformer, TransformerConfig, decode
     from ray_tpu.parallel.mesh import MeshSpec
     from ray_tpu.parallel.sharding import param_shardings
     cfg = TransformerConfig(
-        vocab_size=512, d_model=2048, n_layers=2, n_heads=16, n_kv_heads=8,
-        d_ff=256, max_seq_len=512, remat=False, dtype="bfloat16",
-        param_dtype="bfloat16")
+        vocab_size=512, d_model=D_MODEL, n_layers=LAYERS, n_heads=HEADS,
+        n_kv_heads=KV_HEADS, d_ff=D_FF, max_seq_len=512, remat=False,
+        dtype="bfloat16", param_dtype="bfloat16")
     pool = (cfg.n_layers, PAGES, PAGE, cfg.kv_heads * cfg.head_dim)
     abstract = jax.eval_shape(Transformer(cfg).init, jax.random.PRNGKey(0))
     if tp == 1:
@@ -156,12 +158,27 @@ def decode_step(request, topo, no_compile_cache):
     return _compile_decode_step(topo.devices, request.param)
 
 
+def results(text: str):
+    """(name, opcode or custom-call target, shapes of its result with
+    unit dimensions dropped) of each instruction of the program's entry
+    computation; a tuple's result is every array in it."""
+    entry = text[text.index("\nENTRY "):]
+    for name, result, op, rest in re.findall(
+            r"^\s+(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\((.*)$",
+            entry, re.M):
+        target = re.search(r'custom_call_target="(\w+)"', rest)
+        shapes = [
+            tuple(int(d) for d in dims.split(",") if d not in ("", "1"))
+            for dims in re.findall(r"\w+\[([\d,]*)\]", result)]
+        yield name, target.group(1) if target else op, shapes
+
+
 def test_decode_step_holds_the_paged_kernel(decode_step):
     compiled, _ = decode_step
     names = kernel_names(compiled.as_text())
     # one call a layer (on a mesh: of each device's own kv heads), named
     # for the trace's `kernel:paged_decode_attn`
-    assert names.count(paged_attention.KERNEL_PAGED_DECODE) == 2
+    assert names.count(paged_attention.KERNEL_PAGED_DECODE) == LAYERS
 
 
 def test_decode_step_for_the_chip_updates_the_pool_in_place(decode_step):
@@ -172,10 +189,36 @@ def test_decode_step_for_the_chip_updates_the_pool_in_place(decode_step):
     made = re.findall(rf"= \w+\[{shape}\]\S* ([\w\-]+)\(",
                       compiled.as_text())
     # no copy of the pool, no slice of it made for the kernel, no gather
-    # of its shards: the scatters of the four writes (2 layers x k, v),
+    # of its shards: the scatters of the writes (each layer's k and v),
     # in fusions or bare
     assert made and set(made) <= {"parameter", "scatter", "fusion",
                                   "bitcast", "get-tuple-element"}, made
+
+
+def test_decode_step_reads_wq_and_wk_where_they_lie(decode_step):
+    """No instruction writes a layer's wq or wk out anew. With the view
+    into heads folded into the dot (`(h @ wq).reshape(b, 1, heads, hd)`)
+    the compiler wants each as `(heads, hd, d_model)`: a
+    `slice_bitcast_fusion` transposes every layer's, `slice-start` /
+    `slice-done` and `ConcatBitcast` bring the copies into fast memory
+    and a `copy` turns them once more, a quarter of the step's bytes
+    (PERF.md, PR 34). `decode_step` keeps the products flat past a
+    barrier, and the dots read the stacked weights in place."""
+    compiled, pool = decode_step
+    tp = KV_HEADS * 128 // pool[3]
+    a_layers = set()
+    for heads in (HEADS // tp, KV_HEADS // tp):     # flat or by heads
+        a_layers |= {(D_MODEL, heads * 128), (heads * 128, D_MODEL),
+                     (D_MODEL, heads, 128), (heads, 128, D_MODEL)}
+    relaid = [
+        (name, op) for name, op, shapes in results(compiled.as_text())
+        if a_layers & set(shapes) and (
+            op in ("copy", "ConcatBitcast") or op.startswith("slice")
+            or name.startswith("slice_bitcast_fusion"))]
+    assert not relaid, relaid
+    if tp == 1:     # nor room kept for such copies (62 MB with them)
+        assert (compiled.memory_analysis().temp_size_in_bytes
+                < 2 * D_MODEL * HEADS * 128)
 
 
 # ------------------------------------- the second architecture's step

@@ -14,6 +14,12 @@ expert layers, held per layer. A config's type names its class
 (`build_model`), and the serving engine asks the model it is given for
 its cache and programs (`init_cache`, `prefill`, `decode_step`,
 `cache_page_bytes`, `decode_attention`) and names neither class.
+
+A third: `GQAWindowMoE` (`models/gqa_window_moe.py`), a GQA decoder whose
+layers are named one by one by the config's lists: full attention or a
+sliding window, each kind with its own head count, rotary scheme and cache
+(`window_pages`: the ring a sliding layer keeps of a sequence), a per-head
+output gate, and a dense or a routed feed-forward.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
@@ -21,11 +27,15 @@ from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
                                    init_paged_cache, prefill)
 from ray_tpu.models.transformer import Transformer  # noqa: F401
 from ray_tpu.models.mla_moe import MLAMoE, MLAMoEConfig  # noqa: F401,E402
+from ray_tpu.models.gqa_window_moe import (  # noqa: F401,E402
+    GQAWindowMoE, GQAWindowMoEConfig)
 
 
 # a dict of config fields names its class under "type"; without the key it
 # is the flagship decoder's
-CONFIG_TYPES = {"transformer": TransformerConfig, "mla_moe": MLAMoEConfig}
+CONFIG_TYPES = {"transformer": TransformerConfig, "mla_moe": MLAMoEConfig,
+                "gqa_window_moe": GQAWindowMoEConfig}
+MODEL_TYPES = {MLAMoEConfig: MLAMoE, GQAWindowMoEConfig: GQAWindowMoE}
 
 
 def model_config(model):
@@ -43,6 +53,4 @@ def model_config(model):
 
 def build_model(config, mesh=None):
     """The model class a config's type names, bound to `mesh`."""
-    if isinstance(config, MLAMoEConfig):
-        return MLAMoE(config, mesh=mesh)
-    return Transformer(config, mesh=mesh)
+    return MODEL_TYPES.get(type(config), Transformer)(config, mesh=mesh)

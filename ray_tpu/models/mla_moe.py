@@ -351,6 +351,10 @@ class MLAMoE:
         dt = jnp.dtype(dtype or c.activation_dtype)
         return c.n_layers * page_size * c.row_width * dt.itemsize
 
+    def window_pages(self, page_size: int) -> int:
+        """No layer keeps a ring of a sequence's last pages."""
+        return 0
+
     def decode_attention(self, page_size: int, dtype=None) -> str:
         """Which attention a `decode_step` traced here holds: the latent
         kernel's name, or "einsum"."""

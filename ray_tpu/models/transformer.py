@@ -336,6 +336,11 @@ class Transformer:
         return decode.cache_page_bytes(self.config, page_size,
                                        tp_shards=tp_shards, dtype=dtype)
 
+    def window_pages(self, page_size: int) -> int:
+        """No layer keeps a ring of a sequence's last pages: every
+        layer's cache is whole (`kv_cache.PageAllocator`'s one class)."""
+        return 0
+
     def decode_attention(self, page_size: int, dtype=None) -> str:
         from ray_tpu.models import decode
         return decode.decode_attention(self.config, page_size, dtype)

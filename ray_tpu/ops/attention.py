@@ -59,16 +59,22 @@ KERNEL_FWD = "flash_fwd"
 KERNEL_BWD_DKDV = "flash_bwd_dkdv"
 KERNEL_BWD_DQ = "flash_bwd_dq"
 KERNEL_SCOPE = "flash_attention"
+# the forward with a sliding window (forward only: serving's prefill), under
+# a name of its own that a reader looking for KERNEL_FWD does not match
+KERNEL_WINDOW_FWD = "flash_window_fwd"
+KERNEL_WINDOW_SCOPE = "flash_window_attention"
 
 
 # ------------------------------------------------------------- reference
 def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
                   causal: bool = True,
                   sm_scale: Optional[float] = None,
-                  bias: Optional[jax.Array] = None) -> jax.Array:
+                  bias: Optional[jax.Array] = None,
+                  window: Optional[int] = None) -> jax.Array:
     """Plain einsum attention; ground truth + CPU path.
 
-    q: (b, h, s, d); k/v: (b, kvh, s, d) with kvh | h.
+    q: (b, h, s, d); k/v: (b, kvh, s, d) with kvh | h. `window`: query i
+    sees the `window` keys i - window + 1 .. i.
     """
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]
@@ -86,6 +92,8 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
         qi = lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         ki = lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
         logits = jnp.where(qi >= ki, logits, DEFAULT_MASK_VALUE)
+        if window is not None:
+            logits = jnp.where(qi - ki < window, logits, DEFAULT_MASK_VALUE)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", probs.astype(v.dtype), v)
 
@@ -94,12 +102,18 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       acc_ref, m_ref, l_ref, *,
                       sm_scale: float, causal: bool,
-                      block_q: int, block_k: int, seq_k: int):
+                      block_q: int, block_k: int, seq_k: int,
+                      window: Optional[int] = None):
     i = pl.program_id(2)           # q block
     j = pl.program_id(3)           # k block
     nk = pl.num_programs(3)
+    jg = j                         # this step's place in the grid
+    if window is not None:
+        # the grid walks only the key blocks a query block's windows
+        # reach: j counts from the first of them (`_window_first_block`)
+        j = _window_first_block(i, block_q, block_k, window) + j
 
-    @pl.when(j == 0)
+    @pl.when(jg == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
@@ -122,6 +136,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             qi = i * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             s = jnp.where(qi >= ki, s, DEFAULT_MASK_VALUE)
+            if window is not None:      # the window's lower edge
+                s = jnp.where(qi - ki < window, s, DEFAULT_MASK_VALUE)
         if seq_k % block_k:
             # tail K block: mask padding columns past the true length,
             # and zero V's padding rows — they hold garbage and p=0
@@ -135,6 +151,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)            # rescale factor
         p = jnp.exp(s - m_new)                     # (bq, bk)
+        if window is not None:
+            # a row whose window has not reached this block yet has seen
+            # nothing: its maximum is still the mask's value, and exp(0)
+            # would count the masked keys
+            p = jnp.where(s > DEFAULT_MASK_VALUE, p, 0.0)
         l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -142,7 +163,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == nk - 1)
+    @pl.when(jg == nk - 1)
     def _final():
         l = l_ref[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -152,6 +173,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # last-two-dims (8, 128) Mosaic tiling rule; sublanes broadcast.
         lse_ref[0, 0, :, :] = jnp.broadcast_to(lse[:, 0][None, :],
                                                (8, lse.shape[0]))
+
+
+def _window_first_block(i, block_q: int, block_k: int, window: int):
+    """The first key block that query block i's windows reach."""
+    return jnp.maximum(i * block_q - (window - 1), 0) // block_k
 
 
 def _stat_spec(spec_q: P) -> P:
@@ -210,6 +236,68 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         name=KERNEL_FWD,
     )
     with jax.named_scope(KERNEL_SCOPE):
+        out, lse = call(q, k, v)
+    return out, lse[:, :, 0, :]
+
+
+def _flash_window_fwd(q, k, v, sm_scale, block_q, block_k, interpret,
+                      window: int):
+    """Causal forward in which query i sees keys i - window + 1 .. i: the
+    forward kernel on a grid that holds, a query block, only the key blocks
+    its windows reach (those wholly below are never copied in; the blocks
+    at the window's two edges are masked). One device, sq == sk."""
+    b, h, sq, d = q.shape
+    kvh, sk = k.shape[1], k.shape[2]
+    if sq != sk or window < 1 or h % kvh:
+        raise ValueError(
+            f"a window of {window} over {sq} queries and {sk} keys, {h} "
+            f"heads over {kvh}: self-attention, a window >= 1 and heads a "
+            f"multiple of the kv heads only")
+    group = h // kvh
+    block_q = min(block_q, sq)
+    block_k = min(block_k, sk)
+    nk = pl.cdiv(sk, block_k)
+    # key blocks between the lowest window's first key and the diagonal's
+    # last: at most this many, whatever the query block
+    reach = min(nk, (window - 1 + block_q - 1) // block_k + 2)
+
+    def kv_block(b_, h_, i, j):
+        # past the diagonal the kernel skips: stay on the diagonal's block,
+        # which is not copied in again
+        last = (i * block_q + block_q - 1) // block_k
+        first = _window_first_block(i, block_q, block_k, window)
+        return (b_, h_ // group, jnp.minimum(first + j, last), 0)
+
+    call = pl.pallas_call(
+        functools.partial(
+            _flash_fwd_kernel, sm_scale=sm_scale, causal=True,
+            block_q=block_q, block_k=block_k, seq_k=sk, window=window),
+        grid=(b, h, pl.cdiv(sq, block_q), reach),
+        in_specs=[
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_k, d), kv_block),
+            pl.BlockSpec((1, 1, block_k, d), kv_block),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, 8, block_q),
+                         lambda b_, h_, i, j: (b_, h_, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, 8, sq), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), jnp.float32),     # acc
+            pltpu.VMEM((block_q, 128), jnp.float32),   # running max
+            pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
+        ],
+        interpret=interpret,
+        name=KERNEL_WINDOW_FWD,
+    )
+    with jax.named_scope(KERNEL_WINDOW_SCOPE):
         out, lse = call(q, k, v)
     return out, lse[:, :, 0, :]
 
@@ -561,14 +649,25 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    return_lse: bool = False, mesh=None):
+                    return_lse: bool = False, mesh=None,
+                    window: Optional[int] = None):
     """Dispatching entry point: the compiled Pallas kernels when the
     target platform is a TPU, the einsum reference elsewhere.
 
     Shapes: q (b, h, s, d); k/v (b, kvh, s, d), kvh | h. `mesh`: the
     mesh of more than one device the operands are sharded over
-    (`ops.dispatch.kernel_mesh`), or None.
+    (`ops.dispatch.kernel_mesh`), or None. `window`: causal attention in
+    which a query sees its last `window` keys, itself among them; forward
+    only (a prefill), on one device.
     """
+    if window is not None:
+        if not causal or mesh is not None or return_lse:
+            raise ValueError("a window is causal, forward only and on one "
+                             "device")
+        if on_tpu():
+            return flash_window_attention_kernel(q, k, v, window, sm_scale,
+                                                 block_q, block_k)
+        return mha_reference(q, k, v, sm_scale=sm_scale, window=window)
     if return_lse or on_tpu():
         out, lse = _flash_kernel(q, k, v, causal, sm_scale, block_q,
                                  block_k, mesh)
@@ -590,6 +689,16 @@ def flash_attention_kernel(q, k, v, causal=True, sm_scale=None,
     """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
     return _flash_kernel(q, k, v, causal, sm_scale, block_q, block_k,
                          mesh)[0]
+
+
+def flash_window_attention_kernel(q, k, v, window: int, sm_scale=None,
+                                  block_q=128, block_k=128):
+    """The windowed forward kernel (interpreter off-TPU) — `flash_attention`
+    with a window on a TPU, and the test hook elsewhere."""
+    if sm_scale is None:
+        sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    return _flash_window_fwd(q, k, v, sm_scale, block_q, block_k,
+                             not on_tpu(), window)[0]
 
 
 # --------------------------------------- remat-saveable attention path

@@ -27,6 +27,12 @@ file, `mla_paged_decode_attn`: a position is one row `[c_kv | k_rope |
 padding]` shared by every head, read once and used as the key (all of it)
 and as the value (its first `latent` numbers). Both kernels stand on one
 page walk (`_walk_pages`) and differ in their matmuls.
+
+A layer with a sliding window keeps a lane's last pages only, in a ring:
+logical page j of a lane lies at entry `j mod ring` of its table, and what
+fell out of the window is overwritten. `paged_window_decode_attn` is the
+per-head kernel on the same walk, begun at the first page the window
+reaches, its table wrapped, positions below the window masked.
 """
 from __future__ import annotations
 
@@ -74,15 +80,23 @@ def paged_attention_reference(q, k_pool, v_pool, layer, page_tables,
     Returns (B, n_heads, hd) in q's dtype; a lane that sees nothing
     gets zeros.
     """
+    span = page_tables.shape[1] * k_pool.shape[2]
+    seen = jnp.arange(span)[None, :] < lengths[:, None]
+    return _gathered_attention(q, k_pool, v_pool, layer, page_tables, seen)
+
+
+def _gathered_attention(q, k_pool, v_pool, layer, tables, seen):
+    """Attention of q (B, n_heads, hd) over every entry of `tables` (B,
+    entries), gathered whole: `seen` (B, entries * page) says which of the
+    gathered positions a lane sees, on assigned entries."""
     B, n_heads, hd = q.shape
     num_pages, page = k_pool.shape[1:3]
     kvh = k_pool.shape[3] // hd
-    span = page_tables.shape[1] * page
-    pt = jnp.clip(page_tables, 0, num_pages - 1)
+    span = tables.shape[1] * page
+    pt = jnp.clip(tables, 0, num_pages - 1)
     keys = k_pool[layer][pt].reshape(B, span, kvh, hd)
     vals = v_pool[layer][pt].reshape(B, span, kvh, hd)
-    mask = ((jnp.arange(span)[None, :] < lengths[:, None])
-            & jnp.repeat(page_tables >= 0, page, axis=1))
+    mask = seen & jnp.repeat(tables >= 0, page, axis=1)
     qg = q.reshape(B, kvh, n_heads // kvh, hd)
     scores = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),
                         keys.astype(jnp.float32)) * (1.0 / hd ** 0.5)
@@ -103,25 +117,35 @@ def paged_attention_reference(q, k_pool, v_pool, layer, page_tables,
 # around it. A kernel's body names its keys and values; the rest is here.
 def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
                 l_ref, copies, value_buf, attend, *, page_size: int,
-                block_pages: int, max_pages: int):
+                block_pages: int, max_pages: int, window: int = 0):
     """A paged decode kernel but for its matmuls: `attend(slot, seen)` on
     every block of pages the grid's lane holds, in table order, between the
     reset of the running softmax and its division into `o_ref`. `slot`: the
     block's place in the buffers; `seen` (1, block positions): what the
     lane sees of it. `copies`: (pool in HBM, buffer, its semaphore's index
-    after the slot's) a pool; `value_buf`: where the values are read."""
+    after the slot's) a pool; `value_buf`: where the values are read.
+    With a `window` the lane sees its last `window` positions only and the
+    table is a ring of `max_pages` entries (logical page j at entry j mod
+    `max_pages`): the walk begins at the first page the window reaches."""
     b = pl.program_id(0)
     slots = value_buf.shape[0]
     bk = block_pages * page_size
     layer = layer_ref[0]
     length = len_ref[b]
-    n_pages = jnp.minimum(pl.cdiv(length, page_size), max_pages)
+    if window:
+        first = jnp.maximum(length - window, 0) // page_size
+        n_pages = jnp.minimum(pl.cdiv(length, page_size) - first, max_pages)
+    else:
+        n_pages = jnp.minimum(pl.cdiv(length, page_size), max_pages)
     n_blocks = pl.cdiv(n_pages, block_pages)
 
     def page_at(blk, p):
         """(table entry, whether the lane holds a page there)."""
         idx = blk * block_pages + p
-        page = pt_ref[b * max_pages + jnp.minimum(idx, max_pages - 1)]
+        if window:
+            page = pt_ref[b * max_pages + (first + idx) % max_pages]
+        else:
+            page = pt_ref[b * max_pages + jnp.minimum(idx, max_pages - 1)]
         return page, (idx < n_pages) & (page >= 0)
 
     def each_copy(blk, act):
@@ -162,7 +186,11 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
         each_copy(blk, lambda copy: copy.wait())
         pos = blk * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
         page_of = lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page_size
-        seen = pos < length                              # (1, bk)
+        if window:
+            pos = pos + first * page_size
+            seen = (pos < length) & (pos >= length - window)
+        else:
+            seen = pos < length                          # (1, bk)
         for p in range(block_pages):
             _, live = page_at(blk, p)
             seen = seen & ((page_of != p) | live)
@@ -197,12 +225,14 @@ def _softmax_update(q, k, v, seen, sm_scale: float, acc, m, l):
 
 def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
                        page_tables, lengths, *, block_pages: int,
-                       out_width: int, sems: tuple, interpret: bool):
+                       out_width: int, sems: tuple, interpret: bool,
+                       **walk):
     """The `pallas_call` around `_walk_pages`: a grid over the lanes; the
     layer, lengths and flat tables by scalar prefetch; `q` (lanes, ...,
     rows, width) a lane a block; the pools left in HBM; scratch as
     `_walk_pages` takes it, the blocks of at most `block_pages` pages.
-    `kernel` gets the walk's sizes by keyword."""
+    `kernel` gets the walk's sizes by keyword, and what else `walk` holds
+    (a `window`)."""
     lanes, *rows = q.shape
     out = (*rows[:-1], out_width)
     stat = (*rows[:-1], 128)
@@ -214,7 +244,8 @@ def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
 
     call = pl.pallas_call(
         functools.partial(kernel, page_size=page_size,
-                          block_pages=block_pages, max_pages=max_pages),
+                          block_pages=block_pages, max_pages=max_pages,
+                          **walk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(lanes,),
@@ -333,6 +364,95 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, layer, page_tables,
     """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
     return _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
                          not on_tpu(), mesh)
+
+
+# ------------------------------------------ a sliding window over a ring
+KERNEL_PAGED_WINDOW_DECODE = "paged_window_decode_attn"
+KERNEL_PAGED_WINDOW_SCOPE = "paged_window_decode_attention"
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages a sequence keeps for a layer that sees its last `window`
+    positions: the window's pages and one more, since its first and last
+    page are both partly inside."""
+    return -(-window // page_size) + 1
+
+
+def ring_walk(length: int, window: int, page_size: int):
+    """(live, read) positions of a lane `length` long in a window layer:
+    what the window holds, and what the walk copies in (whole pages from
+    the first the window reaches)."""
+    first = max(length - window, 0) // page_size
+    return (min(length, window),
+            (-(-length // page_size) - first) * page_size)
+
+
+def paged_window_attention_reference(q, k_pool, v_pool, layer, ring_tables,
+                                     lengths, window: int):
+    """Gather every ring entry, work out which position it holds, mask,
+    softmax in float32.
+
+    q (B, n_heads, hd); pools (layers, pages, page, kv * hd); ring_tables
+    (B, ring) int32, -1 unassigned: logical page j of a lane lies at entry
+    j mod ring, the newest such j winning; lengths (B,): a lane sees
+    positions length - window .. length - 1. Returns (B, n_heads, hd) in
+    q's dtype; a lane that sees nothing gets zeros."""
+    page, ring = k_pool.shape[2], ring_tables.shape[1]
+    # entry r holds the newest logical page j <= the lane's last with
+    # j = r (mod ring)
+    last = (lengths[:, None] - 1) // page                      # (B, 1)
+    logical = last - (last - jnp.arange(ring)[None, :]) % ring  # (B, ring)
+    pos = (jnp.repeat(logical, page, axis=1) * page
+           + jnp.tile(jnp.arange(page), ring)[None, :])
+    seen = ((pos >= 0) & (pos < lengths[:, None])
+            & (pos >= lengths[:, None] - window))
+    return _gathered_attention(q, k_pool, v_pool, layer, ring_tables, seen)
+
+
+# jitted for the reason `_paged_decode_call` is: traced once a program
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def _paged_window_decode_call(q, k_pool, v_pool, layer, ring_tables,
+                              lengths, window: int, interpret: bool):
+    B, n_heads, hd = q.shape
+    page_size, kvh = k_pool.shape[2], k_pool.shape[3] // hd
+    if n_heads % kvh or not paged_decode_tiles(hd, page_size, k_pool.dtype):
+        raise ValueError(
+            f"the paged window kernel does not take {n_heads} heads over "
+            f"{kvh} kv heads of {hd} in {page_size}-position pages of "
+            f"{k_pool.dtype}")
+    if ring_tables.shape[1] < ring_pages(window, page_size):
+        raise ValueError(
+            f"a window of {window} needs a ring of "
+            f"{ring_pages(window, page_size)} pages, the table has "
+            f"{ring_tables.shape[1]}")
+    kernel = functools.partial(_paged_decode_kernel,
+                               sm_scale=1.0 / math.sqrt(hd))
+    out = _paged_pallas_call(
+        kernel, KERNEL_PAGED_WINDOW_DECODE, KERNEL_PAGED_WINDOW_SCOPE,
+        q.reshape(B, kvh, n_heads // kvh, hd), (k_pool, v_pool), layer,
+        ring_tables, lengths, block_pages=BLOCK_PAGES, out_width=hd,
+        sems=(BLOCK_SLOTS, 2), interpret=interpret, window=window)
+    return out.reshape(B, n_heads, hd)
+
+
+def paged_window_decode_attention(q, k_pool, v_pool, layer, ring_tables,
+                                  lengths, window: int):
+    """Dispatching entry point of a window layer's decode attention: the
+    compiled kernel on a TPU where the shapes tile, the gather + einsum
+    reference elsewhere. Shapes as `paged_window_attention_reference`."""
+    if uses_kernel(q.shape[-1], k_pool.shape[2], k_pool.dtype):
+        return _paged_window_decode_call(q, k_pool, v_pool, layer,
+                                         ring_tables, lengths, int(window),
+                                         False)
+    return paged_window_attention_reference(q, k_pool, v_pool, layer,
+                                            ring_tables, lengths, window)
+
+
+def paged_window_decode_attention_kernel(q, k_pool, v_pool, layer,
+                                         ring_tables, lengths, window: int):
+    """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
+    return _paged_window_decode_call(q, k_pool, v_pool, layer, ring_tables,
+                                     lengths, int(window), not on_tpu())
 
 
 # ------------------------------------------------- the latent (MLA) pool

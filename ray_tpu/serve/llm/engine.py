@@ -175,12 +175,19 @@ class EngineCore:
             # kv_cache.pages_from_budget at engine construction)
             num_pages = self.max_batch * self.max_pages_per_seq
         self.num_pages = int(num_pages)
-        self.alloc = PageAllocator(self.num_pages)
         # the config's type names the model class; the engine asks the
         # model for its cache and programs and names no class itself
         self.model = build_model(config, mesh)
         self.params = params
-        self._cache = self.model.init_cache(self.num_pages, self.page_size)
+        # a model with window layers keeps a ring of `_ring` pages a
+        # sequence in a pool of its own: the allocator's second class
+        # (`kv_cache.py`), one ring a decode lane; 0: one class, one pool
+        self._ring = self.model.window_pages(self.page_size)
+        self.alloc = PageAllocator(self.num_pages, ring=self._ring,
+                                   sequences=self.max_batch)
+        self._cache = self.model.init_cache(
+            self.num_pages, self.page_size,
+            **({"ring_pages": self.alloc.ring_pages} if self._ring else {}))
         # both programs update the pool in place: the cache argument is
         # donated (whoever holds the old one holds a deleted buffer), and
         # on a mesh every step hands the cache back as it lay, whatever
@@ -253,8 +260,11 @@ class EngineCore:
             # kernel, and the lanes that held a sequence
             "decode_steps": 0, "decode_kernel_steps": 0,
             "decode_lane_steps": 0,
-            # cache positions those lanes held / the dispatches read
+            # cache positions those lanes held / the dispatches read, a
+            # layer whose cache is whole; and the same of a window layer,
+            # which holds and reads a sequence's last positions only
             "kv_positions_live": 0, "kv_positions_read": 0,
+            "kv_window_positions_live": 0, "kv_window_positions_read": 0,
             # and what the model counts on the device in a decode step,
             # under the model's own names (`step_stats`: the counts come
             # back with the step's tokens and are summed over the steps)
@@ -287,7 +297,7 @@ class EngineCore:
             raise ValueError(
                 f"prompt ({len(prompt)}) + max_tokens ({max_tokens}) "
                 f"exceeds max_seq_len {self.config.max_seq_len}")
-        if pages_needed(total, self.page_size) > self.num_pages:
+        if not self.alloc.fits(pages_needed(total, self.page_size)):
             raise ValueError(
                 f"request needs {pages_needed(total, self.page_size)} "
                 f"pages; pool holds {self.num_pages}")
@@ -500,7 +510,7 @@ class EngineCore:
             # youngest other sequence if the pool is dry
             while seq.lane >= 0 and pages_needed(
                     seq.device_len, self.page_size) > len(seq.pages):
-                got = self.alloc.alloc(1)
+                got = self.alloc.alloc(1, held=len(seq.pages))
                 if got is not None:
                     seq.pages.extend(got)
                 elif self._flight is not None or self._firsts:
@@ -525,7 +535,8 @@ class EngineCore:
             positions = np.zeros((B,), np.int32)
             pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
             active = np.zeros((B,), bool)
-            live = held = 0
+            kernel = self._attention != "einsum"
+            live = held = window_live = window_read = 0
             for seq in batch:
                 i = seq.lane
                 positions[i] = seq.device_len - 1
@@ -533,9 +544,13 @@ class EngineCore:
                 active[i] = True
                 live += seq.device_len
                 held += pages_needed(seq.device_len, self.page_size)
+                if self._ring:      # what a window layer holds and reads
+                    wl, wr = self.model.window_positions(
+                        seq.device_len, self.page_size, kernel)
+                    window_live += wl
+                    window_read += wr
             args = (jnp.asarray(positions), jnp.asarray(pts),
                     jnp.asarray(active))
-        kernel = self._attention != "einsum"
         # the kernel copies in each lane's live pages, whole
         read = held * self.page_size if kernel else self._table_positions
         c["decode_steps"] += 1
@@ -543,10 +558,15 @@ class EngineCore:
         c["decode_lane_steps"] += len(batch)
         c["kv_positions_live"] += live
         c["kv_positions_read"] += read
+        c["kv_window_positions_live"] += window_live
+        c["kv_window_positions_read"] += window_read
+        window = ({"window_positions_live": window_live,
+                   "window_positions_read": window_read}
+                  if self._ring else {})
         # an annotation's attributes are fixed when it opens, so the
         # step's counts ride the first span that opens once they are known
         with _Phase(phases, _sp.DISPATCH, lanes=len(batch),
-                    live_positions=live, read_positions=read):
+                    live_positions=live, read_positions=read, **window):
             logits, self._cache = self._decode_fn(
                 self.params, self._cache, self._tokens, *args)
             self._tokens, counts = self._next_fn(
@@ -629,7 +649,10 @@ class EngineCore:
         """What the model keeps in the cache beside the pages, once the
         step in flight has run. Not while a step is being dispatched:
         the cache is donated to it."""
-        return self.model.cache_stats(self._cache)
+        ring = ({"ring_pages": self.alloc.ring_pages,
+                 "ring_pages_used": self.alloc.ring_used}
+                if self._ring else {})
+        return {**self.model.cache_stats(self._cache), **ring}
 
     def stats(self) -> dict:
         return {"waiting": len(self._waiting),
@@ -686,7 +709,8 @@ class LLMEngine:
             from ray_tpu.serve.llm.kv_cache import pages_from_budget
             tp = built_mesh.shape.get("tp", 1) if built_mesh else 1
             num_pages = pages_from_budget(config, page_size,
-                                          kv_budget_bytes, tp_shards=tp)
+                                          kv_budget_bytes, tp_shards=tp,
+                                          sequences=max_batch)
         model = build_model(config, built_mesh)
         shardings = None
         if built_mesh is not None:

@@ -17,7 +17,11 @@ WAIT = "engine.wait_for_work"       # idle: no request waiting or running
 STEP = "engine.step"                # one EngineCore.step()
 PREFILL = "engine.prefill"          # one admission: its prefill dispatched
 TABLES = "engine.page_tables"       # the decode batch's host arrays
-DISPATCH = "engine.decode_dispatch"     # carries this dispatch's counts
+# carries this dispatch's counts: `lanes`, `live_positions` and
+# `read_positions` (a layer whose cache is whole) and, for a model with
+# window layers, `window_positions_live` / `window_positions_read` (a layer
+# that holds a sequence's last positions in a ring)
+DISPATCH = "engine.decode_dispatch"
 # waits for the tokens of the step before (and this call's prefills), with
 # the step just dispatched queued behind them on the device
 FETCH = "engine.fetch_tokens"

@@ -34,21 +34,51 @@ from ray_tpu.ops.dispatch import on_tpu
 KERNEL_GMM = "moe_gmm"
 KERNEL_GMM_SCOPE = "grouped_matmul"
 
-# Rows a tile: a tile that g groups share is multiplied g times, so small
-# tiles waste less of the MXU and large ones read a group's matrix less
-# often (once a tile it has rows in).
-TILE_M = 512
-# Output columns a block (the group's matrix is read a block at a time).
-TILE_N = 512
+# The most rows a tile holds: under the rows the MXU multiplies in the time
+# a group's block takes to arrive (197 TFLOP/s over 819 GB/s is 240 rows of
+# bf16 on a v5e, whatever k and n), so that a work item which fetches a
+# block is never longer than the fetch.
+MAX_TILE_ROWS = 128
+# The most bytes one block of a group's matrix holds (two are in flight).
+RHS_BLOCK_BYTES = 8 << 20
+
+
+def gmm_tile_shape(m: int, k: int, n: int, dtype):
+    """The tiles the kernel cuts these shapes into: (rows a tile, output
+    columns a block), or None where they do not cut into whole
+    `(sublane, 128)` tiles. From the call's static shapes and nothing else.
+
+    A work item multiplies a whole tile of rows by one group's block and
+    keeps the group's own rows. Up to `MAX_TILE_ROWS` the rows it throws away
+    cost nothing, since the item waits for its block that long anyway, and
+    every item is a grid step: so the row tile is the largest power of two
+    in `m` up to there, whether a group has one row or a thousand (swept on
+    the chip at 1 to 1024 rows a group: PERF.md section 6, PR 36). A column
+    block is the whole `n` where the group's matrix fits the block's bytes,
+    one contiguous read and one grid step; else the widest multiple of 128
+    that divides `n` and fits."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublanes = 8 * max(1, 4 // itemsize)
+    tm = min(MAX_TILE_ROWS, m & -m)     # the power of two in m
+    if tm < sublanes or k % 128 or n % 128:
+        return None
+    tn = max(c for c in range(128, n + 1, 128) if n % c == 0
+             and (c == 128 or k * c * itemsize <= RHS_BLOCK_BYTES))
+    return tm, tn
+
+
+def gmm_vmem_bytes(tm: int, tn: int, k: int, dtype) -> int:
+    """The `vmem_limit_bytes` of a call with these tiles: two of each
+    block in flight, the float32 product beside them, and room for the
+    compiler's own."""
+    itemsize = jnp.dtype(dtype).itemsize
+    return (2 * itemsize * (tm * k + k * tn + tm * tn) + 4 * tm * tn
+            + (4 << 20))
 
 
 def gmm_tiles(m: int, k: int, n: int, dtype) -> bool:
-    """Whether the kernel tiles these shapes: whole `(sublane, 128)` tiles
-    of rows and columns."""
-    sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    tm = min(m, TILE_M)
-    return (m % tm == 0 and tm % sublanes == 0 and k % 128 == 0
-            and n % 128 == 0)
+    """Whether the kernel tiles these shapes."""
+    return gmm_tile_shape(m, k, n, dtype) is not None
 
 
 def work_list(group_sizes, m: int, tm: int):
@@ -103,14 +133,9 @@ def _gmm_kernel(group_ref, tile_ref, start_ref, end_ref, count_ref,
 def _gmm_call(lhs, rhs, group_sizes, interpret: bool):
     m, k = lhs.shape
     G, _, n = rhs.shape
-    tm = min(m, TILE_M)
-    tn = TILE_N if n % TILE_N == 0 else 128
+    tm, tn = gmm_tile_shape(m, k, n, lhs.dtype)
     group, tile, starts, ends, count = work_list(group_sizes, m, tm)
     n_work = group.shape[0]
-    itemsize = jnp.dtype(lhs.dtype).itemsize
-    # two of each block in flight, and the float32 product beside them
-    vmem = (2 * itemsize * (tm * k + k * tn + tm * tn) + 4 * tm * tn
-            + (4 << 20))
     call = pl.pallas_call(
         functools.partial(_gmm_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -126,7 +151,7 @@ def _gmm_call(lhs, rhs, group_sizes, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=int(vmem)),
+            vmem_limit_bytes=gmm_vmem_bytes(tm, tn, k, lhs.dtype)),
         interpret=interpret,
         name=KERNEL_GMM,
     )

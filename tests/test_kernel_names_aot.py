@@ -222,14 +222,10 @@ def test_decode_step_reads_wq_and_wk_where_they_lie(decode_step):
 
 
 # ------------------------------------- the second architecture's step
-def _compile_mla_moe(devices, which: str):
-    """`MLAMoE`'s decode step or 1024-token prefill as `EngineCore` jits
-    them, for one chip: the dense layer and one expert layer at the
-    published widths of GLM-4.7-Flash (20 heads, latent 512 + 64, 64
-    experts of 2048 x 1536), 32 lanes, 16-token bf16 pages."""
-    from ray_tpu.models.mla_moe import MLAMoE, MLAMoEConfig
-    cfg = MLAMoEConfig(vocab_size=1024, n_layers=2, max_seq_len=2048)
-    model = MLAMoE(cfg)
+def _compile_served(devices, model, which: str, make_cache, kernels: str):
+    """The decode step (32 lanes) or the prefill of a whole
+    `max_seq_len` of a model with its own programs, as `EngineCore` jits
+    them, for one chip, 16-token bf16 pages. Returns (compiled, cache)."""
     one = SingleDeviceSharding(devices[0])
 
     def on_chip(tree):
@@ -240,25 +236,39 @@ def _compile_mla_moe(devices, which: str):
         return jax.ShapeDtypeStruct(dims, jnp.int32, sharding=one)
 
     params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    cache = on_chip(jax.eval_shape(lambda: model.init_cache(PAGES, PAGE)))
-    lanes, tables = 32, cfg.max_seq_len // PAGE
+    cache = on_chip(jax.eval_shape(make_cache))
+    lanes, seq = 32, model.config.max_seq_len
     with compute_platform("tpu"):
-        assert model.decode_attention(PAGE) == "mla_paged_decode_attn"
+        assert model.decode_attention(PAGE) == kernels
         if which == "step":
             def _step(params, cache, tokens, positions, pts, active):
                 return model.decode_step(params, cache, tokens, positions,
                                          pts, active, PAGE)
             traced = jax.jit(_step, donate_argnums=(1,)).trace(
                 params, cache, ints(lanes), ints(lanes),
-                ints(lanes, tables), jax.ShapeDtypeStruct(
+                ints(lanes, seq // PAGE), jax.ShapeDtypeStruct(
                     (lanes,), jnp.bool_, sharding=one))
         else:
             def _pre(params, tokens, true_len, page_table, cache):
                 return model.prefill(params, tokens, true_len, page_table,
                                      cache, PAGE)
             traced = jax.jit(_pre, donate_argnums=(4,)).trace(
-                params, ints(1024), ints(), ints(tables), cache)
-        return traced.lower().compile(), cache["kv"].shape
+                params, ints(seq), ints(), ints(seq // PAGE), cache)
+        return traced.lower().compile(), cache
+
+
+def _compile_mla_moe(devices, which: str):
+    """`MLAMoE`'s decode step or 2048-token prefill (the batch32 cell's
+    largest bucket: 8192 pairs through `moe_gmm`): the dense layer and one
+    expert layer at the published widths of GLM-4.7-Flash (20 heads,
+    latent 512 + 64, 64 experts of 2048 x 1536)."""
+    from ray_tpu.models.mla_moe import MLAMoE, MLAMoEConfig
+    model = MLAMoE(MLAMoEConfig(vocab_size=1024, n_layers=2,
+                                max_seq_len=2048))
+    compiled, cache = _compile_served(
+        devices, model, which, lambda: model.init_cache(PAGES, PAGE),
+        "mla_paged_decode_attn")
+    return compiled, cache["kv"].shape
 
 
 @pytest.fixture(scope="module", params=["step", "prefill"])
@@ -270,7 +280,8 @@ def test_mla_moe_programs_hold_their_kernels_by_name(mla_moe_program):
     from ray_tpu.ops import grouped_matmul
     which, compiled, _ = mla_moe_program
     names = kernel_names(compiled.as_text())
-    # gate, up and down of the one expert layer
+    # gate, up and down of the one expert layer: each a whole matrix a
+    # block (12.6 MB of two in flight), which the compiler gave room
     assert names.count(grouped_matmul.KERNEL_GMM) == 3
     if which == "step":     # one latent kernel a layer, no flash kernel
         assert names.count(paged_attention.KERNEL_MLA_PAGED_DECODE) == 2
@@ -290,3 +301,38 @@ def test_mla_moe_programs_update_the_latent_pool_in_place(mla_moe_program):
                       compiled.as_text())
     assert made and set(made) <= {"parameter", "scatter", "fusion",
                                   "bitcast", "get-tuple-element"}, made
+
+
+# -------------------------------------- the third architecture's step
+def _compile_gqa_window_moe(devices, which: str):
+    """`GQAWindowMoE`'s decode step or 8192-token prefill (the mixed8k
+    cell's largest bucket: 65,536 pairs through `moe_gmm`): a sliding and
+    a full layer, both sparse, at the published widths of Laguna-XS.2 (64
+    and 48 heads over 8 kv heads of 128, 256 experts of 2048 x 512),
+    rings of 33 pages a lane."""
+    from ray_tpu.models.gqa_window_moe import (FULL, SLIDING, SPARSE,
+                                               GQAWindowMoE,
+                                               GQAWindowMoEConfig)
+    model = GQAWindowMoE(GQAWindowMoEConfig(
+        vocab_size=1024, layer_types=(SLIDING, FULL),
+        n_heads_per_layer=(64, 48), mlp_layer_types=(SPARSE, SPARSE)))
+    return _compile_served(
+        devices, model, which, lambda: model.init_cache(
+            PAGES, PAGE, ring_pages=32 * model.window_pages(PAGE)),
+        "paged_decode_attn+paged_window_decode_attn")[0]
+
+
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_gqa_window_moe_programs_hold_their_kernels_by_name(
+        which, topo, no_compile_cache):
+    from ray_tpu.ops import grouped_matmul
+    names = kernel_names(
+        _compile_gqa_window_moe(topo.devices, which).as_text())
+    # gate, up and down of both layers' experts, a whole matrix a block
+    assert names.count(grouped_matmul.KERNEL_GMM) == 6
+    if which == "step":
+        assert names.count(paged_attention.KERNEL_PAGED_DECODE) == 1
+        assert names.count(paged_attention.KERNEL_PAGED_WINDOW_DECODE) == 1
+    else:
+        assert names.count(attention.KERNEL_FWD) == 1
+        assert names.count(attention.KERNEL_WINDOW_FWD) == 1

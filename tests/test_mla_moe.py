@@ -269,33 +269,86 @@ def test_decode_attention_names_the_latent_kernel_or_einsum():
 
 
 # ------------------------------------------------- the grouped matmul
-@pytest.mark.parametrize("m,sizes", [
-    (128, (0, 5, 0, 100, 3, 0, 0, 10)),           # rows past the last group
-    (1024, (300, 0, 0, 512, 1, 0, 100, 50)),      # tiles shared by groups
-    (1024, (0, 0, 0, 0, 0, 0, 0, 1024)),          # one group has them all
-    (2048, (300, 0, 0, 200, 1, 0, 100, 50)),      # whole tiles unvisited
-    (128, (0,) * 8)])                             # nothing to do
-def test_grouped_matmul_kernel_matches_ragged_dot(m, sizes):
+@pytest.mark.parametrize("m,sizes,k,n", [
+    (128, (0, 5, 0, 100, 3, 0, 0, 10), 128, 256),   # rows past the last
+    (1024, (300, 0, 0, 512, 1, 0, 100, 50), 128, 256),  # tiles shared
+    (1024, (0, 0, 0, 0, 0, 0, 0, 1024), 128, 256),  # one group has all
+    (2048, (300, 0, 0, 200, 1, 0, 100, 50), 128, 256),  # tiles unvisited
+    (128, (0,) * 8, 128, 256),                      # nothing to do
+    # at the tiles the rule gives since PR 36 (128 rows, the whole n):
+    (512, (40, 300, 0, 0, 100, 0, 0, 0), 128, 256),  # a group in 3 tiles
+    (128, (10, 20, 5, 30, 1, 2, 40, 16), 128, 256),  # 8 groups, one tile
+    (256, (100, 0, 60, 96), 128, 384),      # k < n (down): n one block
+    (256, (1, 0, 200, 3), 384, 128),        # k > n (gate and up)
+    (48, (7, 0, 30, 2), 128, 128),          # m not a power of two: 16 rows
+    (128, (50, 70), 2048, 2048)])           # a matrix of 16 MiB: 2 blocks
+def test_grouped_matmul_kernel_matches_ragged_dot(m, sizes, k, n):
     rng = np.random.default_rng(m + sum(sizes))
-    lhs = jnp.asarray(rng.normal(size=(m, 128)), jnp.float32)
-    rhs = jnp.asarray(rng.normal(size=(len(sizes), 128, 256)), jnp.float32)
+    lhs = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    rhs = jnp.asarray(rng.normal(size=(len(sizes), k, n)), jnp.float32)
     gs = jnp.asarray(sizes, jnp.int32)
     want = lax.ragged_dot(lhs, rhs, gs)
     got = gmm.grouped_matmul_kernel(lhs, rhs, gs)
-    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(got, want, atol=1e-4 * k / 128, rtol=1e-4)
     assert not np.asarray(got[sum(sizes):]).any()
 
 
 def test_grouped_matmul_work_list_visits_only_groups_with_rows():
     sizes = jnp.asarray((0, 5, 0, 600, 3, 0, 0, 10), jnp.int32)
-    group, tile, starts, ends, count = gmm.work_list(sizes, 1024, 512)
+    tm, _ = gmm.gmm_tile_shape(1024, 128, 256, jnp.float32)
+    assert tm == 128
+    group, tile, starts, ends, count = gmm.work_list(sizes, 1024, tm)
     n = int(count[0])
-    assert n == 5 and group.shape == (2 + 8 - 1,)
-    assert group[:n].tolist() == [1, 3, 3, 4, 7]
-    assert tile[:n].tolist() == [0, 0, 1, 1, 1]
+    assert n == 8 and group.shape == (8 + 8 - 1,)
+    assert group[:n].tolist() == [1, 3, 3, 3, 3, 3, 4, 7]
+    assert tile[:n].tolist() == [0, 0, 1, 2, 3, 4, 4, 4]
     # the padding repeats the last real pair: no block moves there
-    assert set(group[n:].tolist()) == {7} and set(tile[n:].tolist()) == {1}
+    assert set(group[n:].tolist()) == {7} and set(tile[n:].tolist()) == {4}
     assert (starts[3], ends[3]) == (5, 605)
+
+
+# (pairs, experts, k, n) of the two expert cells: a decode step's and the
+# named prefill bucket's, gate / up and their transpose, down
+CELL_SHAPES = [
+    (256, 256, 2048, 512), (256, 256, 512, 2048),           # Laguna step
+    (128, 64, 2048, 1536), (128, 64, 1536, 2048),           # GLM step
+    (8192, 64, 2048, 1536), (8192, 64, 1536, 2048),         # GLM 2048
+    (32768, 256, 2048, 512), (32768, 256, 512, 2048)]       # Laguna 4096
+
+
+@pytest.mark.parametrize("m,groups,k,n", CELL_SHAPES)
+def test_grouped_matmul_tiles_at_the_cells_shapes(m, groups, k, n):
+    dt = jnp.bfloat16
+    tm, tn = gmm.gmm_tile_shape(m, k, n, dt)
+    assert m % tm == 0 and tm % 16 == 0         # whole bf16 sublane tiles
+    assert tn == n              # a group's matrix is one block, one step
+    # an item that fetches a block multiplies no longer than the fetch
+    # takes (v5e: 2 tm k n / 197 TFLOP/s against 2 k n bytes / 819 GB/s),
+    # so rows thrown away are free
+    assert tm <= 197e12 / 819e9
+    if m > 256:     # a prefill: the MXU's rows over the rows needed
+        assert (m // tm + groups - 1) * tm <= 2.5 * m
+    # two of each block in flight fit the limit the call sets, and that
+    # is a quarter of a v5e's VMEM at most
+    blocks = 2 * 2 * (tm * k + k * tn + tm * tn)
+    assert blocks < gmm.gmm_vmem_bytes(tm, tn, k, dt) <= 32 << 20
+
+
+@pytest.mark.parametrize("m,k,n,dtype,tiles", [
+    (100, 2048, 1536, jnp.bfloat16, None),      # no sublane tile in 100
+    (1000, 2048, 1536, jnp.bfloat16, None),     # 8 rows: half of one
+    (1000, 2048, 1536, jnp.float32, (8, 768)),  # a whole one; 12 MiB
+    (128, 2048, 1500, jnp.bfloat16, None),      # columns not whole lanes
+    (128, 200, 1536, jnp.bfloat16, None),
+    (48, 128, 256, jnp.bfloat16, (16, 256)),
+    (4096, 8192, 1536, jnp.bfloat16, (128, 512)),   # 24 MiB: in 3 blocks
+    (4096, 8192, 2048, jnp.bfloat16, (128, 512))])  # 32 MiB: in 4
+def test_grouped_matmul_tile_rule_and_what_it_refuses(m, k, n, dtype,
+                                                      tiles):
+    assert gmm.gmm_tile_shape(m, k, n, dtype) == tiles
+    assert gmm.gmm_tiles(m, k, n, dtype) == (tiles is not None)
+    with compute_platform("tpu"):
+        assert gmm.uses_kernel(m, k, n, dtype) == (tiles is not None)
 
 
 def test_grouped_matmul_path_and_gradient():

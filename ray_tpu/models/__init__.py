@@ -20,6 +20,13 @@ layers are named one by one by the config's lists: full attention or a
 sliding window, each kind with its own head count, rotary scheme and cache
 (`window_pages`: the ring a sliding layer keeps of a sequence), a per-head
 output gate, and a dense or a routed feed-forward.
+
+A fourth: `HybridDelta` (`models/hybrid_delta.py`), post-norm blocks whose
+mixers are gated delta-rule layers (linear attention: a recurrent state of
+one size a sequence, `ops/gated_delta.py`) or full multi-head attention
+without rotary embedding, named layer by layer. What a model keeps of a
+sequence for ever (a ring, a state) it names to the engine as
+`fixed_pages`.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
@@ -29,13 +36,17 @@ from ray_tpu.models.transformer import Transformer  # noqa: F401
 from ray_tpu.models.mla_moe import MLAMoE, MLAMoEConfig  # noqa: F401,E402
 from ray_tpu.models.gqa_window_moe import (  # noqa: F401,E402
     GQAWindowMoE, GQAWindowMoEConfig)
+from ray_tpu.models.hybrid_delta import (  # noqa: F401,E402
+    HybridDelta, HybridDeltaConfig)
 
 
 # a dict of config fields names its class under "type"; without the key it
 # is the flagship decoder's
 CONFIG_TYPES = {"transformer": TransformerConfig, "mla_moe": MLAMoEConfig,
-                "gqa_window_moe": GQAWindowMoEConfig}
-MODEL_TYPES = {MLAMoEConfig: MLAMoE, GQAWindowMoEConfig: GQAWindowMoE}
+                "gqa_window_moe": GQAWindowMoEConfig,
+                "hybrid_delta": HybridDeltaConfig}
+MODEL_TYPES = {MLAMoEConfig: MLAMoE, GQAWindowMoEConfig: GQAWindowMoE,
+               HybridDeltaConfig: HybridDelta}
 
 
 def model_config(model):

@@ -30,7 +30,7 @@ window / page + 1 pages of it for ever, in a ring: pools `"wk"`, `"wv"` of
 `(sliding layers, ring_pages, page, kv x hd)`, logical page j at entry `j
 mod window_pages`, what fell out of the window overwritten. The ring's
 pages are the ids `0 .. ring_pages - 1`, which the full pools hold too
-(`serve/llm/kv_cache.py`: the allocator's ring class), so one table serves
+(`serve/llm/kv_cache.py`: the allocator's fixed class), so one table serves
 both kinds and nothing is keyed by lane. `prefill` writes a full layer's
 pages whole and a sliding layer's last `window_pages`; `decode_step` reads
 a full layer through `ops.paged_attention.paged_decode_attention` and a
@@ -218,8 +218,8 @@ class GQAWindowMoE:
     """Functional model bundle for one GQAWindowMoEConfig: `init`, `apply`
     / `loss` (a plain forward, the tests' and a trainer's), and what a
     serving engine asks a model for (`init_cache`, `prefill`,
-    `decode_step`, `cache_page_bytes`, `window_pages`, `window_positions`,
-    `decode_attention`, `step_stats`, `cache_stats`)."""
+    `decode_step`, `cache_page_bytes`, `fixed_pages`, `fixed_step_counts`,
+    `prefill_counts`, `decode_attention`, `step_stats`, `cache_stats`)."""
 
     def __init__(self, config: GQAWindowMoEConfig, mesh=None):
         if mesh is not None:
@@ -396,6 +396,23 @@ class GQAWindowMoE:
             return 0
         return _paged.ring_pages(c.sliding_window, page_size)
 
+    def fixed_pages(self, page_size: int) -> int:
+        """Pages of the allocator's fixed class a sequence holds for ever:
+        its sliding layers' ring."""
+        return self.window_pages(page_size)
+
+    def fixed_step_counts(self, length: int, page_size: int,
+                          kernel: bool = True) -> Dict[str, int]:
+        """What a lane's ring costs a decode step, by the names the
+        engine's span carries (`window_positions`)."""
+        live, read = self.window_positions(length, page_size, kernel)
+        return {"window_positions_live": live,
+                "window_positions_read": read}
+
+    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
+        """Nothing to add to the engine's prefill span."""
+        return {}
+
     def window_positions(self, length: int, page_size: int,
                          kernel: bool = True) -> Tuple[int, int]:
         """(positions a sliding layer holds live, positions its decode
@@ -409,13 +426,13 @@ class GQAWindowMoE:
         return live, read
 
     def init_cache(self, num_pages: int, page_size: int, dtype=None,
-                   ring_pages: int = 0) -> Cache:
-        """`num_pages` pages in the full layers' pools, `ring_pages` (the
-        allocator's ring class) in the sliding layers'."""
+                   fixed_pages: int = 0) -> Cache:
+        """`num_pages` pages in the full layers' pools, `fixed_pages` (the
+        allocator's fixed class: the rings) in the sliding layers'."""
         c = self.config
         dt = dtype or c.activation_dtype
         full = (len(c.full_layers), num_pages, page_size, c.kv_dim)
-        ring = (len(c.sliding_layers), max(ring_pages, 1), page_size,
+        ring = (len(c.sliding_layers), max(fixed_pages, 1), page_size,
                 c.kv_dim)
         make = jax.jit(lambda: {
             "k": jnp.zeros(full, dt), "v": jnp.zeros(full, dt),
@@ -427,13 +444,14 @@ class GQAWindowMoE:
         return make()
 
     def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
-                         dtype=None, ring: bool = False) -> int:
+                         dtype=None, fixed: bool = False) -> int:
         """Bytes one page costs: keys and values of the full layers for a
         page of the pool `num_pages` counts, of the sliding layers for a
-        page of the ring (`ring`), which a ring-class page costs besides."""
+        page of the ring (`fixed`), which a fixed-class page costs
+        besides."""
         c = self.config
         dt = jnp.dtype(dtype or c.activation_dtype)
-        layers = len(c.sliding_layers if ring else c.full_layers)
+        layers = len(c.sliding_layers if fixed else c.full_layers)
         return (2 * layers * page_size
                 * (c.kv_dim // max(1, tp_shards)) * dt.itemsize)
 

@@ -351,9 +351,14 @@ class MLAMoE:
         dt = jnp.dtype(dtype or c.activation_dtype)
         return c.n_layers * page_size * c.row_width * dt.itemsize
 
-    def window_pages(self, page_size: int) -> int:
-        """No layer keeps a ring of a sequence's last pages."""
+    def fixed_pages(self, page_size: int) -> int:
+        """Nothing is kept of a sequence for ever: every layer's cache
+        grows with it (`kv_cache.PageAllocator`'s one class)."""
         return 0
+
+    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
+        """Nothing to add to the engine's prefill span."""
+        return {}
 
     def decode_attention(self, page_size: int, dtype=None) -> str:
         """Which attention a `decode_step` traced here holds: the latent

@@ -336,10 +336,14 @@ class Transformer:
         return decode.cache_page_bytes(self.config, page_size,
                                        tp_shards=tp_shards, dtype=dtype)
 
-    def window_pages(self, page_size: int) -> int:
-        """No layer keeps a ring of a sequence's last pages: every
-        layer's cache is whole (`kv_cache.PageAllocator`'s one class)."""
+    def fixed_pages(self, page_size: int) -> int:
+        """Nothing is kept of a sequence for ever: every layer's cache
+        grows with it (`kv_cache.PageAllocator`'s one class)."""
         return 0
+
+    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
+        """Nothing to add to the engine's prefill span."""
+        return {}
 
     def decode_attention(self, page_size: int, dtype=None) -> str:
         from ray_tpu.models import decode

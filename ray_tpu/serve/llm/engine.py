@@ -113,6 +113,15 @@ def _bucket(n: int, lo: int = 16, hi: int = 1 << 30) -> int:
     return min(b, hi)
 
 
+# What `model.fixed_step_counts` names on the `engine.decode_dispatch`
+# span, and the counter that sums each over the dispatches.
+_FIXED_COUNTERS = {
+    "window_positions_live": "kv_window_positions_live",
+    "window_positions_read": "kv_window_positions_read",
+    "state_slots": "state_slots_live",
+    "state_bytes": "state_bytes_moved"}
+
+
 @dataclasses.dataclass
 class _Seq:
     rid: str
@@ -179,15 +188,17 @@ class EngineCore:
         # model for its cache and programs and names no class itself
         self.model = build_model(config, mesh)
         self.params = params
-        # a model with window layers keeps a ring of `_ring` pages a
-        # sequence in a pool of its own: the allocator's second class
-        # (`kv_cache.py`), one ring a decode lane; 0: one class, one pool
-        self._ring = self.model.window_pages(self.page_size)
-        self.alloc = PageAllocator(self.num_pages, ring=self._ring,
+        # what a model keeps of a sequence for ever (a window layer's
+        # ring of pages, a recurrent layer's state) is named by the first
+        # `_fixed` pages a sequence holds: the allocator's fixed class
+        # (`kv_cache.py`), `_fixed` a decode lane; 0: one class, one pool
+        self._fixed = self.model.fixed_pages(self.page_size)
+        self.alloc = PageAllocator(self.num_pages, fixed=self._fixed,
                                    sequences=self.max_batch)
         self._cache = self.model.init_cache(
             self.num_pages, self.page_size,
-            **({"ring_pages": self.alloc.ring_pages} if self._ring else {}))
+            **({"fixed_pages": self.alloc.fixed_pages} if self._fixed
+               else {}))
         # both programs update the pool in place: the cache argument is
         # donated (whoever holds the old one holds a deleted buffer), and
         # on a mesh every step hands the cache back as it lay, whatever
@@ -261,10 +272,13 @@ class EngineCore:
             "decode_steps": 0, "decode_kernel_steps": 0,
             "decode_lane_steps": 0,
             # cache positions those lanes held / the dispatches read, a
-            # layer whose cache is whole; and the same of a window layer,
-            # which holds and reads a sequence's last positions only
+            # layer whose cache is whole; and what the lanes' fixed parts
+            # cost the same dispatches (`_FIXED_COUNTERS`, summed from the
+            # model's `fixed_step_counts`): a window layer holds and reads
+            # a sequence's last positions only, a recurrent layer moves a
+            # state of one size
             "kv_positions_live": 0, "kv_positions_read": 0,
-            "kv_window_positions_live": 0, "kv_window_positions_read": 0,
+            **dict.fromkeys(_FIXED_COUNTERS.values(), 0),
             # and what the model counts on the device in a decode step,
             # under the model's own names (`step_stats`: the counts come
             # back with the step's tokens and are summed over the steps)
@@ -475,7 +489,8 @@ class EngineCore:
             s_pad = _bucket(len(toks), hi=self.config.max_seq_len)
             with _Phase(phases, _sp.PREFILL, rid=seq.rid, tokens=len(toks),
                         bucket=s_pad,
-                        new_program=int(s_pad not in self._prefill_fns)):
+                        new_program=int(s_pad not in self._prefill_fns),
+                        **self.model.prefill_counts(len(toks), s_pad)):
                 self._waiting.popleft()
                 seq.pages = pages
                 now = time.monotonic()
@@ -536,7 +551,8 @@ class EngineCore:
             pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
             active = np.zeros((B,), bool)
             kernel = self._attention != "einsum"
-            live = held = window_live = window_read = 0
+            live = held = 0
+            fixed: Dict[str, int] = {}
             for seq in batch:
                 i = seq.lane
                 positions[i] = seq.device_len - 1
@@ -544,11 +560,10 @@ class EngineCore:
                 active[i] = True
                 live += seq.device_len
                 held += pages_needed(seq.device_len, self.page_size)
-                if self._ring:      # what a window layer holds and reads
-                    wl, wr = self.model.window_positions(
-                        seq.device_len, self.page_size, kernel)
-                    window_live += wl
-                    window_read += wr
+                if self._fixed:     # what the lane's fixed part costs
+                    for name, n in self.model.fixed_step_counts(
+                            seq.device_len, self.page_size, kernel).items():
+                        fixed[name] = fixed.get(name, 0) + n
             args = (jnp.asarray(positions), jnp.asarray(pts),
                     jnp.asarray(active))
         # the kernel copies in each lane's live pages, whole
@@ -558,15 +573,12 @@ class EngineCore:
         c["decode_lane_steps"] += len(batch)
         c["kv_positions_live"] += live
         c["kv_positions_read"] += read
-        c["kv_window_positions_live"] += window_live
-        c["kv_window_positions_read"] += window_read
-        window = ({"window_positions_live": window_live,
-                   "window_positions_read": window_read}
-                  if self._ring else {})
+        for name, n in fixed.items():
+            c[_FIXED_COUNTERS[name]] += n
         # an annotation's attributes are fixed when it opens, so the
         # step's counts ride the first span that opens once they are known
         with _Phase(phases, _sp.DISPATCH, lanes=len(batch),
-                    live_positions=live, read_positions=read, **window):
+                    live_positions=live, read_positions=read, **fixed):
             logits, self._cache = self._decode_fn(
                 self.params, self._cache, self._tokens, *args)
             self._tokens, counts = self._next_fn(
@@ -649,10 +661,10 @@ class EngineCore:
         """What the model keeps in the cache beside the pages, once the
         step in flight has run. Not while a step is being dispatched:
         the cache is donated to it."""
-        ring = ({"ring_pages": self.alloc.ring_pages,
-                 "ring_pages_used": self.alloc.ring_used}
-                if self._ring else {})
-        return {**self.model.cache_stats(self._cache), **ring}
+        fixed = ({"fixed_pages": self.alloc.fixed_pages,
+                  "fixed_pages_used": self.alloc.fixed_used}
+                 if self._fixed else {})
+        return {**self.model.cache_stats(self._cache), **fixed}
 
     def stats(self) -> dict:
         return {"waiting": len(self._waiting),

@@ -15,12 +15,18 @@ from ray_tpu.util.tracing import annotate
 # the next dispatch runs beside a device step.
 WAIT = "engine.wait_for_work"       # idle: no request waiting or running
 STEP = "engine.step"                # one EngineCore.step()
-PREFILL = "engine.prefill"          # one admission: its prefill dispatched
+# one admission: its prefill dispatched; carries `tokens`, `bucket` and
+# what the model says the prefill runs (`prefill_counts`: a model with
+# linear-attention layers, the `scan_chunks` of its recurrence)
+PREFILL = "engine.prefill"
 TABLES = "engine.page_tables"       # the decode batch's host arrays
 # carries this dispatch's counts: `lanes`, `live_positions` and
-# `read_positions` (a layer whose cache is whole) and, for a model with
-# window layers, `window_positions_live` / `window_positions_read` (a layer
-# that holds a sequence's last positions in a ring)
+# `read_positions` (a layer whose cache is whole) and what the model says
+# the lanes' fixed parts cost (`fixed_step_counts`): for window layers
+# `window_positions_live` / `window_positions_read` (a layer that holds a
+# sequence's last positions in a ring), for linear-attention layers
+# `state_slots` / `state_bytes` (the lanes whose state the step reads and
+# writes, and the bytes moved for them)
 DISPATCH = "engine.decode_dispatch"
 # waits for the tokens of the step before (and this call's prefills), with
 # the step just dispatched queued behind them on the device

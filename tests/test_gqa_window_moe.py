@@ -6,8 +6,9 @@ gqa_window_moe.py`) and to itself: the rotary formulas against numpy, the
 windowed flash forward and the ring decode kernel (through the Pallas
 interpreter) against masked einsums, the allocator's two classes, prefill
 then decode through the engine's own programs across the ring's wrap,
-eviction and re-prefill, and the programs of the two older models pinned
-to what they lowered to before the shared kernels took a window. Tiny
+eviction and re-prefill, and the programs of the older models pinned to
+what they lowered to before the shared kernels changed (a window in
+PR 35; a group of one query head and the fixed class's name in PR 37). Tiny
 sizes, CPU, seeded.
 """
 import hashlib
@@ -276,7 +277,7 @@ def test_ring_walk_reads_at_most_the_ring_and_names_its_kernel():
 # ------------------------------------------- the allocator's two classes
 def test_one_class_is_the_allocator_it_always_was():
     a = PageAllocator(6)
-    assert a.ring_pages == 0 and a.alloc(3) == [0, 1, 2]
+    assert a.fixed_pages == 0 and a.alloc(3) == [0, 1, 2]
     assert a.alloc(1, held=3) == [3] and a.alloc(3) is None
     a.free([1])
     assert a.alloc(1) == [1] and a.free_pages == 2 and a.fits(6)
@@ -284,26 +285,26 @@ def test_one_class_is_the_allocator_it_always_was():
 
 
 def test_two_classes_alloc_extend_free_and_double_free():
-    a = PageAllocator(20, ring=3, sequences=2)
-    assert a.ring_pages == 6 and a.free_pages == 20
+    a = PageAllocator(20, fixed=3, sequences=2)
+    assert a.fixed_pages == 6 and a.free_pages == 20
     first = a.alloc(5)                  # admission: 3 of the ring, then 2
     assert first == [0, 1, 2, 6, 7]
     short = a.alloc(2)                  # a sequence under its ring
     assert short == [3, 4]
     assert a.alloc(1, held=2) == [5]    # its extension asks by what it holds
     assert a.alloc(1, held=3) == [8]    # past the ring: the other class
-    assert a.ring_used == 6 and a.used_pages == 9
+    assert a.fixed_used == 6 and a.used_pages == 9
     assert a.alloc(1) is None           # a third sequence: no ring left
     assert a.free_pages == 11           # and nothing was claimed
     a.free(short + [5])
-    assert a.ring_used == 3 and a.alloc(4) == [5, 4, 3, 9]
+    assert a.fixed_used == 3 and a.alloc(4) == [5, 4, 3, 9]
     with pytest.raises(ValueError, match="freed twice"):
         a.free([6, 6])
     # a lone sequence: its ring from the ring class, the rest from the other
     assert a.fits(3 + 14) and not a.fits(3 + 15)
     # a pool smaller than the rings asked for is all ring class
-    small = PageAllocator(4, ring=3, sequences=2)
-    assert small.ring_pages == 4 and small.fits(3) and not small.fits(4)
+    small = PageAllocator(4, fixed=3, sequences=2)
+    assert small.fixed_pages == 4 and small.fits(3) and not small.fits(4)
 
 
 # ------------------------------------------------- the model, end to end
@@ -357,7 +358,7 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(
         tiny_ref, p, steps):
     mod, sz, params, pc = tiny_ref
     core = EngineCore(pc, params, num_pages=0, page_size=PAGE, max_batch=3)
-    assert core.alloc.ring == 5 and core.alloc.ring_pages == 15
+    assert core.alloc.fixed == 5 and core.alloc.fixed_pages == 15
     toks = np.zeros((256,), np.int32)
     toks[:p + steps] = np.random.default_rng(p).integers(0, sz.vocab,
                                                          p + steps)
@@ -376,11 +377,11 @@ def test_a_sliding_layers_cache_is_a_ring_and_the_engine_counts_it(tiny_ref):
     assert cache["k"].shape == (2, core.num_pages, PAGE, sz.kv_dim)
     assert cache["wk"].shape == (3, 2 * 5, PAGE, sz.kv_dim)
     assert core.model.cache_page_bytes(PAGE) == 2 * 2 * PAGE * sz.kv_dim * 4
-    assert core.model.cache_page_bytes(PAGE, ring=True) == (
+    assert core.model.cache_page_bytes(PAGE, fixed=True) == (
         2 * 3 * PAGE * sz.kv_dim * 4)
     # the ring is paid first, the rest buys pages of the full layers' pool
     page, ring = core.model.cache_page_bytes(PAGE), \
-        core.model.cache_page_bytes(PAGE, ring=True)
+        core.model.cache_page_bytes(PAGE, fixed=True)
     assert pages_from_budget(pc, PAGE, 10 * ring + 7 * page,
                              sequences=2) == 7
     core.submit(list(range(1, 101)), max_tokens=20, rid="long")
@@ -389,7 +390,7 @@ def test_a_sliding_layers_cache_is_a_ring_and_the_engine_counts_it(tiny_ref):
         core.step()
         if core._running:
             st = core.cache_stats()
-            assert 0 < st["ring_pages_used"] <= st["ring_pages"] == 10
+            assert 0 < st["fixed_pages_used"] <= st["fixed_pages"] == 10
     c = core.counters
     # a lane 100-120 long holds 32 positions of a sliding layer and reads
     # at most the ring's 40; the short lane holds what it has
@@ -398,14 +399,14 @@ def test_a_sliding_layers_cache_is_a_ring_and_the_engine_counts_it(tiny_ref):
     assert c["kv_window_positions_live"] <= c["kv_window_positions_read"]
     st = core.device_stats()
     assert st["decode_attention"] == "einsum"
-    assert st["ring_pages_used"] == 0 and np.asarray(
+    assert st["fixed_pages_used"] == 0 and np.asarray(
         st["moe_load"]).shape == (4, 8)
     assert c["moe_pairs"] == c["decode_lane_steps"] * 2 * 4
     with compute_platform("tpu"):
         served = GQAWindowMoE(GQAWindowMoEConfig())
         assert served.decode_attention(16) == (
             "paged_decode_attn+paged_window_decode_attn")
-    assert served.window_pages(16) == 33
+    assert served.window_pages(16) == served.fixed_pages(16) == 33
     assert served.cache_page_bytes(16) == 2 * 2 * 16 * 1024 * 2
     assert served.param_count() == 3869857792
 
@@ -430,7 +431,7 @@ def test_eviction_and_re_prefill_give_the_same_greedy_tokens():
     # and 3 pages) cannot both stay, the youngest is evicted, frees both
     # classes and resumes
     core = EngineCore(cfg, params, num_pages=13, page_size=PAGE, max_batch=2)
-    assert core.alloc.ring_pages == 10
+    assert core.alloc.fixed_pages == 10
     prompts = {"a": list(range(3, 33)), "b": [5, 6, 7] * 9}
     core.submit(prompts["a"], max_tokens=26, rid="a")
     core.submit(prompts["b"], max_tokens=30, rid="b")
@@ -441,7 +442,7 @@ def test_eviction_and_re_prefill_give_the_same_greedy_tokens():
         for ev in core.step():
             got[ev["rid"]].append(ev["token"])
     assert core.counters["evictions"] >= 1
-    assert core.alloc.free_pages == 13 and core.alloc.ring_used == 0
+    assert core.alloc.free_pages == 13 and core.alloc.fixed_used == 0
     for rid, n in (("a", 26), ("b", 30)):
         assert got[rid] == _greedy(model, params, prompts[rid], n), rid
     with pytest.raises(ValueError, match="pages"):
@@ -470,7 +471,7 @@ def test_a_config_names_its_model_and_refusals_are_plain():
         RopeParams(rope_type="llama3")
     for model in (Transformer(TransformerConfig()),
                   MLAMoE(tiny_mla_moe())):
-        assert model.window_pages(16) == 0
+        assert model.fixed_pages(16) == 0
 
 
 # ------------------- the older models' programs are what they were
@@ -490,7 +491,9 @@ def _program_text(fn, *args):
 def _programs(model, cfg, B=2, s=32, page=16):
     mp = cfg.max_seq_len // page
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    cache = jax.eval_shape(lambda: model.init_cache(B * mp, page))
+    fixed = B * model.fixed_pages(page)
+    cache = jax.eval_shape(lambda: model.init_cache(
+        B * mp, page, **({"fixed_pages": fixed} if fixed else {})))
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)     # noqa
     return {
         "prefill": _program_text(
@@ -508,6 +511,10 @@ PINNED = {
     ("Transformer", "decode_step"): "a567fb1f06be49e7",
     ("MLAMoE", "prefill"): "ad1cf41a588df5a6",
     ("MLAMoE", "decode_step"): "3ff8c18bb669e7c8",
+    # PR 37 renamed the allocator's ring class and gave the page walk a
+    # group of one query head: the third class is pinned to PR 36's text
+    ("GQAWindowMoE", "prefill"): "2ee05703a435ae1e",
+    ("GQAWindowMoE", "decode_step"): "b17180939d5f66e2",
 }
 
 
@@ -517,8 +524,16 @@ def test_older_models_programs_lower_to_the_parents_text(name, program):
         cfg = TransformerConfig(vocab_size=256, d_model=256, n_layers=2,
                                 n_heads=2, n_kv_heads=1, d_ff=512,
                                 max_seq_len=128)
-    else:
+    elif name == "MLAMoE":
         cfg = tiny_mla_moe()
+    else:       # heads of 128, so the paged and flash kernels are in it
+        cfg = GQAWindowMoEConfig(
+            vocab_size=256, d_model=128, n_kv_heads=1, head_dim=128,
+            layer_types=("full_attention", "sliding_attention"),
+            n_heads_per_layer=(2, 4), mlp_layer_types=("dense", "sparse"),
+            sliding_window=32, d_ff=256, moe_intermediate_size=128,
+            shared_expert_intermediate_size=128, num_experts=8,
+            num_experts_per_tok=2, max_seq_len=128)
     text = _programs(build_model(cfg), cfg)[program]
     assert "pallas_call" in text            # the kernels are in the text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[

@@ -318,7 +318,7 @@ def _compile_gqa_window_moe(devices, which: str):
         n_heads_per_layer=(64, 48), mlp_layer_types=(SPARSE, SPARSE)))
     return _compile_served(
         devices, model, which, lambda: model.init_cache(
-            PAGES, PAGE, ring_pages=32 * model.window_pages(PAGE)),
+            PAGES, PAGE, fixed_pages=32 * model.fixed_pages(PAGE)),
         "paged_decode_attn+paged_window_decode_attn")[0]
 
 
@@ -336,3 +336,41 @@ def test_gqa_window_moe_programs_hold_their_kernels_by_name(
     else:
         assert names.count(attention.KERNEL_FWD) == 1
         assert names.count(attention.KERNEL_WINDOW_FWD) == 1
+
+
+# ------------------------------------- the fourth architecture's step
+def _compile_hybrid_delta(devices, which: str):
+    """`HybridDelta`'s decode step or 3072-token prefill (the answers3k
+    cell's context limit): a linear and a full layer at the published
+    widths of Olmo-Hybrid-7B (30 heads of 96 / 192 and a state of 96 x
+    5760 a lane; 30 query heads over 30 kv heads of 128: a group of one
+    in the page walk), 32 state slots and nobody's."""
+    from ray_tpu.models.hybrid_delta import (FULL, LINEAR, HybridDelta,
+                                             HybridDeltaConfig)
+    model = HybridDelta(HybridDeltaConfig(
+        vocab_size=1024, layer_types=(LINEAR, FULL)))
+    return _compile_served(
+        devices, model, which, lambda: model.init_cache(
+            PAGES, PAGE, fixed_pages=32 * model.fixed_pages(PAGE)),
+        "paged_decode_attn+gated_delta_step")
+
+
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_hybrid_delta_programs_hold_their_kernels_and_alias_the_state(
+        which, topo, no_compile_cache):
+    from ray_tpu.ops import gated_delta
+    compiled, cache = _compile_hybrid_delta(topo.devices, which)
+    names = kernel_names(compiled.as_text())
+    if which == "step":
+        assert names.count(gated_delta.KERNEL_STEP) == 1
+        assert names.count(paged_attention.KERNEL_PAGED_DECODE) == 1
+        assert gated_delta.KERNEL_CHUNK not in names
+    else:
+        assert names.count(gated_delta.KERNEL_CHUNK) == 1
+        assert names.count(attention.KERNEL_FWD) == 1
+        assert gated_delta.KERNEL_STEP not in names
+    # every pool is updated in place: the state (33 slots of 96 x 5760
+    # float32), the tail, the keys and the values
+    assert cache["state"].shape == (1, 33, 96, 5760)
+    nbytes = sum(a.size * a.dtype.itemsize for a in cache.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes

@@ -117,6 +117,16 @@ def cache_page_bytes(config, page_size: int, tp_shards: int = 1,
             * config.head_dim * dt.itemsize)
 
 
+def walk_block_pages(config, page_size: int, max_pages: int,
+                     tp_shards: int = 1, dtype=None) -> int:
+    """Pages a block of the decode kernel's walk holds over tables of
+    `max_pages`: `ops.paged_attention.walk_block_pages` asked what the
+    kernel asks it, a layer's page of keys and values on one shard."""
+    return _paged.walk_block_pages(
+        cache_page_bytes(config, page_size, tp_shards, dtype)
+        // config.n_layers, page_size, max_pages)
+
+
 def decode_attention(config, page_size: int, dtype=None) -> str:
     """Which attention a `decode_step` traced here holds: the kernel's
     name, or "einsum" (the platform being traced for and the shapes

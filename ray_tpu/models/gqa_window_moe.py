@@ -227,6 +227,7 @@ class GQAWindowMoE:
                 "GQAWindowMoE runs on one device and takes no mesh: experts "
                 "and the two pools are not sharded over chips yet")
         self.config = config
+        self._ring_walks: Dict[int, list] = {}  # `fixed_step_counts`'s
 
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
@@ -406,8 +407,23 @@ class GQAWindowMoE:
         """What a lane's ring costs a decode step, by the names the
         engine's span carries (`window_positions`)."""
         live, read = self.window_positions(length, page_size, kernel)
+        blocks, attended = self._ring_walk(page_size)[
+            read // page_size] if kernel else (0, read)
         return {"window_positions_live": live,
-                "window_positions_read": read}
+                "window_positions_read": read,
+                "window_walk_blocks": blocks,
+                "window_positions_attended": attended}
+
+    def _ring_walk(self, page_size: int) -> list:
+        """`walk_counts` of the kernel's walk over a ring by the pages it
+        reaches (the engine asks a lane a step: worked out once)."""
+        if page_size not in self._ring_walks:
+            ring = self.window_pages(page_size)
+            block = self.walk_block_pages(page_size, ring, fixed=True)
+            self._ring_walks[page_size] = [
+                _paged.walk_counts(n, block, page_size)
+                for n in range(ring + 1)]
+        return self._ring_walks[page_size]
 
     def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
         """Nothing to add to the engine's prefill span."""
@@ -466,6 +482,17 @@ class GQAWindowMoE:
                 + [_paged.KERNEL_PAGED_WINDOW_DECODE]
                 * bool(c.sliding_layers))
         return "einsum"
+
+    def walk_block_pages(self, page_size: int, max_pages: int,
+                         fixed: bool = False) -> int:
+        """Pages a block of a full layer's walk holds over tables of
+        `max_pages` (of a sliding layer's over its ring: `fixed`), asked
+        what the kernel asks (a layer's page of keys and values)."""
+        c = self.config
+        layers = len(c.sliding_layers if fixed else c.full_layers)
+        return _paged.walk_block_pages(
+            self.cache_page_bytes(page_size, fixed=fixed) // max(1, layers),
+            page_size, max_pages)
 
     def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
         """What the last decode step counted, still on the device, by the

@@ -419,6 +419,14 @@ class HybridDelta:
         return "+".join([_paged.KERNEL_PAGED_DECODE] * bool(c.full_layers)
                         + [step] * bool(c.linear_layers))
 
+    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
+        """Pages a block of the full layers' walk holds over tables of
+        `max_pages`, asked what the kernel asks (a layer's page of keys
+        and values)."""
+        return _paged.walk_block_pages(
+            self.cache_page_bytes(page_size)
+            // max(1, len(self.config.full_layers)), page_size, max_pages)
+
     def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
         return {}
 

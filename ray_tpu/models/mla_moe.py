@@ -369,6 +369,13 @@ class MLAMoE:
             return _paged.KERNEL_MLA_PAGED_DECODE
         return "einsum"
 
+    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
+        """Pages a block of the latent kernel's walk holds over tables of
+        `max_pages`, asked what the kernel asks (a layer's page of rows)."""
+        return _paged.walk_block_pages(
+            self.cache_page_bytes(page_size) // self.config.n_layers,
+            page_size, max_pages)
+
     def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
         """What the last decode step counted: scalars still on the device,
         by the names the engine's counters take. The engine fetches them

@@ -349,6 +349,12 @@ class Transformer:
         from ray_tpu.models import decode
         return decode.decode_attention(self.config, page_size, dtype)
 
+    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
+        from ray_tpu.models import decode
+        tp = self.kernel_mesh.shape.get("tp", 1) if self.kernel_mesh else 1
+        return decode.walk_block_pages(self.config, page_size, max_pages,
+                                       tp_shards=tp)
+
     def prefill(self, params: Params, tokens, true_len, page_table, cache,
                 page_size: int):
         from ray_tpu.models import decode

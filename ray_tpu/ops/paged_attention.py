@@ -8,8 +8,10 @@ attention has to move what the lanes hold and no more: the kernel leaves
 the pool in HBM, takes page tables, lengths and the layer index by scalar
 prefetch, and copies in only the live pages of each lane, one DMA a page
 (a page is contiguous, and a kv head is whole lanes of its rows),
-double-buffered in blocks of `BLOCK_PAGES` pages. Lengths are data: one
-compiled program whatever the lanes hold.
+double-buffered in blocks whose size comes from the bytes of a page
+(`walk_block_pages`); a block is multiplied over the part of it the lane
+holds (`walk_prefixes`). Lengths are data: one compiled program whatever
+the lanes hold.
 
 Numerics follow `attention._flash_fwd_kernel`: keys and values stay in
 the pool's dtype for the MXU, scores, running max and sum and the
@@ -53,11 +55,71 @@ from ray_tpu.ops.dispatch import on_tpu, shard_kernel
 KERNEL_PAGED_DECODE = "paged_decode_attn"
 KERNEL_PAGED_SCOPE = "paged_decode_attention"
 
-# Pages copied in and attended to per inner step of the kernel.
-BLOCK_PAGES = 8
 # Blocks in VMEM at once: one attended to, the next on its way (more
 # bought nothing on a v5e: a block's matmuls, not its copies, take longest).
 BLOCK_SLOTS = 2
+# A block costs a v5e a fixed time whatever it holds (each head's chain of
+# score matmul, max, exp, sum, value matmul and rescale: half a microsecond
+# at 8 kv heads), so it is as large as two things let it be, both chosen on
+# the chip (`tools/bench_paged.py --sweep`; PERF.md section 6, PR 38): the
+# bytes of VMEM the blocks' buffers may take, all slots and pools together
+# (what bounds a page of 30 kv heads) ...
+WALK_BUFFER_BYTES = 16 << 20
+# ... and its positions: a lane's first block is copied in with nothing to
+# multiply in front of it, and past these that wait outweighs the blocks
+# saved (what bounds a page of 8 kv heads, or of one latent row).
+BLOCK_POSITIONS = 1024
+# Pages a turn of the loop that starts, or waits for, a block's copies
+# (unrolled, the scalar core overlaps their table reads and descriptors:
+# it is what bounds a walk over pages as small as a latent row's).
+COPY_UNROLL = 4
+# Heads a turn of the loop over a block's heads multiplies, unrolled (as
+# many, up to these, as divide the kv heads). A head's chain of matmul, max,
+# exp, sum, matmul hides behind its neighbour's only inside one turn (one
+# head a turn: 15-25 % slower); but every unrolled head is a copy of the
+# matmuls in the program, which a replica traces once and lowers once a
+# layer at set-up (30 heads unrolled at two lengths: 3.5 s of it; in 5
+# turns of 6: none, and 4 % of the kernel; PERF.md section 6, PR 38).
+HEAD_UNROLL = 8
+# Positions a block is multiplied in whole pieces of: the keys of a piece
+# are one 128 x 128 tile of the MXU a head.
+PIECE_POSITIONS = 128
+
+
+def walk_block_pages(page_bytes: int, page_size: int, max_pages: int) -> int:
+    """Pages a block of the walk holds: as many whole pieces as
+    `BLOCK_POSITIONS` hold and as fit `WALK_BUFFER_BYTES` at `page_bytes`
+    a page (all pools together) in `BLOCK_SLOTS` slots, one piece at
+    least, and no more than the table's `max_pages`."""
+    piece = max(1, PIECE_POSITIONS // page_size)
+    fit = min(WALK_BUFFER_BYTES // (BLOCK_SLOTS * page_bytes),
+              BLOCK_POSITIONS // page_size)
+    return min(max(fit // piece, 1) * piece, max_pages)
+
+
+@functools.lru_cache(maxsize=None)
+def walk_prefixes(block_pages: int, page_size: int) -> tuple:
+    """The lengths in pages, ascending, that the matmuls of a block reach:
+    one piece, two, the block's half in whole pieces where that is more,
+    and the block. A block of which the walk reaches `n` pages is
+    multiplied as far as the least of these that is `>= n`: piece by piece
+    up to two (what a walk in blocks of a piece would cost), else in one
+    softmax update, so a full block is one and no lane pays more than
+    twice what it holds. Few, because each is a copy of every head's
+    matmuls in the program (a replica traces them at set-up)."""
+    piece = min(max(1, PIECE_POSITIONS // page_size), block_pages)
+    half = block_pages // 2 // piece * piece
+    return tuple(sorted({n for n in (piece, 2 * piece, half)
+                         if piece <= n < block_pages} | {block_pages}))
+
+
+def walk_counts(pages: int, block_pages: int, page_size: int):
+    """(blocks, positions multiplied) of a lane whose walk covers `pages`
+    pages: what `_walk_pages` does, counted on the host."""
+    full, rest = divmod(pages, block_pages)
+    tail = next((n for n in walk_prefixes(block_pages, page_size)
+                 if n >= rest), 0) if rest else 0
+    return full + bool(rest), (full * block_pages + tail) * page_size
 
 
 def paged_decode_tiles(head_dim: int, page_size: int, dtype) -> bool:
@@ -116,20 +178,30 @@ def _gathered_attention(q, k_pool, v_pool, layer, tables, seen):
 # mask of what a block holds, the online softmax, and the `pallas_call`
 # around it. A kernel's body names its keys and values; the rest is here.
 def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
-                l_ref, copies, value_buf, attend, *, page_size: int,
-                block_pages: int, max_pages: int, window: int = 0):
-    """A paged decode kernel but for its matmuls: `attend(slot, seen)` on
-    every block of pages the grid's lane holds, in table order, between the
-    reset of the running softmax and its division into `o_ref`. `slot`: the
-    block's place in the buffers; `seen` (1, block positions): what the
-    lane sees of it. `copies`: (pool in HBM, buffer, its semaphore's index
-    after the slot's) a pool; `value_buf`: where the values are read.
+                l_ref, finite_ref, copies, value_buf, attend, *,
+                page_size: int, block_pages: int, max_pages: int,
+                window: int = 0):
+    """A paged decode kernel but for its matmuls: `attend(slot, start,
+    pages, seen, rolled)` on every block of pages the grid's lane holds, in table
+    order, between the reset of the running softmax and its division into
+    `o_ref`. `slot`: the block's place in the buffers; `pages` (static)
+    from page `start` of it: the part to multiply, a piece of the block or
+    one of its `walk_prefixes`, which together hold all the lane holds of
+    it; `seen` (1, positions of those pages): what the lane sees of
+    them; `rolled`: the part is long enough for a kernel to loop over its
+    heads and not unroll them (a head's chain of matmul, max, exp, sum,
+    matmul hides behind the next head's only when both are in one basic
+    block, which a piece needs and a whole block does not; and every
+    unrolled head is a copy of the matmuls in the program, which a replica
+    traces and lowers once a layer at set-up). `copies`: (pool in HBM, buffer, its semaphore's index after
+    the slot's) a pool; `value_buf`: where the values are read;
+    `finite_ref` (slots,) in SMEM: the walk's own note of how much of each
+    slot of it holds numbers.
     With a `window` the lane sees its last `window` positions only and the
     table is a ring of `max_pages` entries (logical page j at entry j mod
     `max_pages`): the walk begins at the first page the window reaches."""
     b = pl.program_id(0)
     slots = value_buf.shape[0]
-    bk = block_pages * page_size
     layer = layer_ref[0]
     length = len_ref[b]
     if window:
@@ -140,27 +212,47 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
     n_blocks = pl.cdiv(n_pages, block_pages)
 
     def page_at(blk, p):
-        """(table entry, whether the lane holds a page there)."""
+        """(table entry, whether the lane holds a page there) of page `p`
+        of block `blk`, one the walk reaches."""
         idx = blk * block_pages + p
         if window:
             page = pt_ref[b * max_pages + (first + idx) % max_pages]
         else:
-            page = pt_ref[b * max_pages + jnp.minimum(idx, max_pages - 1)]
-        return page, (idx < n_pages) & (page >= 0)
+            page = pt_ref[b * max_pages + idx]
+        return page, page >= 0
+
+    def reach_of(blk):
+        """Pages of block `blk` the walk reaches."""
+        return jnp.minimum(n_pages - blk * block_pages, block_pages)
+
+    prefixes = walk_prefixes(block_pages, page_size)
+    piece = prefixes[0]
 
     def each_copy(blk, act):
         """`act` on the copy of every page of block `blk` the lane holds,
-        into the block's slot."""
+        into the block's slot (a loop, not unrolled: a block of 64 pages
+        is traced as one page, and a short lane pays for the pages it
+        has). Returns how many pages those were."""
         slot = blk % slots
-        for p in range(block_pages):
-            page, live = page_at(blk, p)
 
-            @pl.when(live)
-            def _():
-                for pool, buf, sem in copies:
-                    act(pltpu.make_async_copy(
-                        pool.at[layer, page], buf.at[slot, p],
-                        sems.at[(slot, *sem)]))
+        reach = reach_of(blk)
+
+        def some(i, held):
+            for p in range(COPY_UNROLL):
+                p = i * COPY_UNROLL + p
+                page, live = page_at(blk, p)
+                live = live & (p < reach)
+
+                @pl.when(live)
+                def _():
+                    for pool, buf, sem in copies:
+                        act(pltpu.make_async_copy(
+                            pool.at[layer, page], buf.at[slot, p],
+                            sems.at[(slot, *sem)]))
+                held = held + live.astype(jnp.int32)
+            return held
+        return lax.fori_loop(0, pl.cdiv(reach, COPY_UNROLL), some,
+                             jnp.int32(0))
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
@@ -168,34 +260,93 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
 
     @pl.when(b == 0)
     def _():
-        # a page that is not copied in keeps what its buffer held: the
-        # values of an earlier block, finite, once this has run (a
-        # probability of 0 does not neutralise NaN: 0 * NaN)
-        value_buf[:] = jnp.zeros_like(value_buf)
+        for slot in range(slots):
+            finite_ref[slot] = 0
+
+    def start(blk):
+        """Begin the copies of block `blk`. A page of the prefix the block
+        will be multiplied at that is not copied in keeps what its buffer
+        held, and a probability of 0 does not neutralise NaN (0 * NaN): so
+        first the slot's values are made numbers that far, once a call
+        (`finite_ref`: zeros, or later the values of an earlier block)."""
+        slot, reach = blk % slots, reach_of(blk)
+        need = prefixes[-1]
+        for pages in prefixes[-2::-1]:
+            need = jnp.where(reach <= pages, pages, need)
+
+        @pl.when(finite_ref[slot] < need)
+        def _():
+            under = 0
+            for pages in prefixes:
+                @pl.when((finite_ref[slot] <= under) & (need > under))
+                def _(under=under, pages=pages):
+                    part = value_buf.at[slot, under:pages]
+                    part[:] = jnp.zeros_like(part)
+                under = pages
+            finite_ref[slot] = need
+        each_copy(blk, lambda copy: copy.start())
 
     for ahead in range(slots - 1):
         @pl.when(ahead < n_blocks)
         def _():
-            each_copy(ahead, lambda copy: copy.start())
+            start(ahead)
 
     def body(blk, carry):
         @pl.when(blk + slots - 1 < n_blocks)
         def _():
-            each_copy(blk + slots - 1, lambda copy: copy.start())
+            start(blk + slots - 1)
 
-        each_copy(blk, lambda copy: copy.wait())
-        pos = blk * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1)
-        page_of = lax.broadcasted_iota(jnp.int32, (1, bk), 1) // page_size
-        if window:
-            pos = pos + first * page_size
-            seen = (pos < length) & (pos >= length - window)
-        else:
-            seen = pos < length                          # (1, bk)
-        for p in range(block_pages):
-            _, live = page_at(blk, p)
-            seen = seen & ((page_of != p) | live)
-        attend(blk % slots, seen)
+        held = each_copy(blk, lambda copy: copy.wait())
+        # the block is multiplied over the shortest prefix that holds the
+        # pages the walk reaches in it: what lies behind was not copied in
+        # and is not read, what lies inside and is not live is not `seen`
+        reach = reach_of(blk)
+
+        slot, whole = blk % slots, held == reach
+
+        def by_pieces():
+            def one(i, carry):
+                attend(slot, i * piece, piece,
+                       seen_of(blk, i * piece, piece, reach, whole), False)
+                return carry
+            lax.fori_loop(0, pl.cdiv(reach, piece), one, 0)
+
+        def at_once(pages):
+            return lambda: attend(
+                slot, 0, pages, seen_of(blk, 0, pages, reach, whole), True)
+
+        def multiply(ways):
+            (pages, way), *rest = ways
+            if rest:
+                lax.cond(reach <= pages, way, lambda: multiply(rest))
+            else:
+                way()
+        # (whole pieces only: a table's own length may not be)
+        looped = [n for n in prefixes if n <= 2 * piece and not n % piece]
+        multiply([(n, by_pieces) for n in looped[-1:]]
+                 + [(n, at_once(n)) for n in prefixes if n not in looped])
         return carry
+
+    def seen_of(blk, start, pages, reach, whole):
+        """(1, positions) of `pages` pages of the block from its page
+        `start`: which of them the lane sees, of the `reach` pages the walk
+        reaches in the block; `whole`: none of those is missing from the
+        table."""
+        at = start * page_size + lax.broadcasted_iota(
+            jnp.int32, (1, pages * page_size), 1)
+        pos = (blk * block_pages + (first if window else 0)) * page_size + at
+        seen = (pos < length) & (at < reach * page_size)
+        if window:
+            seen = seen & (pos >= length - window)
+
+        def holes():     # 1 where the table has no page (as int32: a
+            def one(p, out):                 # branch yields no mask)
+                return jnp.where((at // page_size == p)
+                                 & ~page_at(blk, p)[1], 1, out)
+            return lax.fori_loop(start, jnp.minimum(reach, start + pages),
+                                 one, jnp.zeros_like(at))
+        return seen & (lax.cond(whole, lambda: jnp.zeros_like(at), holes)
+                       == 0)
 
     lax.fori_loop(0, n_blocks, body, 0)
     l = l_ref[..., :1]
@@ -224,20 +375,21 @@ def _softmax_update(q, k, v, seen, sm_scale: float, acc, m, l):
 
 
 def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
-                       page_tables, lengths, *, block_pages: int,
-                       out_width: int, sems: tuple, interpret: bool,
-                       **walk):
+                       page_tables, lengths, *, out_width: int, sems: tuple,
+                       interpret: bool, **walk):
     """The `pallas_call` around `_walk_pages`: a grid over the lanes; the
     layer, lengths and flat tables by scalar prefetch; `q` (lanes, ...,
     rows, width) a lane a block; the pools left in HBM; scratch as
-    `_walk_pages` takes it, the blocks of at most `block_pages` pages.
-    `kernel` gets the walk's sizes by keyword, and what else `walk` holds
-    (a `window`)."""
+    `_walk_pages` takes it, the blocks of `walk_block_pages` pages by what
+    a page of these pools weighs. `kernel` gets the walk's sizes by
+    keyword, and what else `walk` holds (a `window`)."""
     lanes, *rows = q.shape
     out = (*rows[:-1], out_width)
     stat = (*rows[:-1], 128)
     page_size, max_pages = pools[0].shape[2], page_tables.shape[1]
-    block_pages = min(block_pages, max_pages)
+    block_pages = walk_block_pages(
+        sum(page_size * pool.shape[3] * pool.dtype.itemsize
+            for pool in pools), page_size, max_pages)
 
     def lane(b, *_):
         return (b,) + (0,) * len(rows)
@@ -260,8 +412,12 @@ def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
                 pltpu.VMEM(out, jnp.float32),            # acc
                 pltpu.VMEM(stat, jnp.float32),           # running max
                 pltpu.VMEM(stat, jnp.float32),           # running sum
+                pltpu.SMEM((BLOCK_SLOTS,), jnp.int32),   # slots made finite
             ]),
         out_shape=jax.ShapeDtypeStruct((lanes, *out), q.dtype),
+        # the buffers beside what a call is given when it asks for nothing
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=WALK_BUFFER_BYTES + (16 << 20)),
         interpret=interpret,
         name=name,
     )
@@ -274,23 +430,35 @@ def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
 # ---------------------------------------------------------------- kernel
 def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
                          q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, acc_ref, m_ref, l_ref, *,
-                         sm_scale: float, **walk):
+                         k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
+                         finite_ref, *, sm_scale: float, **walk):
     kvh, _, hd = q_ref.shape[1:]
-    bk = k_buf.shape[1] * k_buf.shape[2]
 
-    def attend(slot, seen):
-        for h in range(kvh):
-            head = slice(h * hd, (h + 1) * hd)           # a head's lanes
+    def attend(slot, start, pages, seen, rolled):
+        part, n = pl.ds(start, pages), pages * k_buf.shape[2]
+
+        def head(h, lanes):                              # a head's lanes
             _softmax_update(
                 q_ref[0, h],                             # (G, hd)
-                k_buf[slot, :, :, head].reshape(bk, hd),
-                v_buf[slot, :, :, head].reshape(bk, hd),
+                k_buf[slot, part, :, lanes].reshape(n, hd),
+                v_buf[slot, part, :, lanes].reshape(n, hd),
                 seen, sm_scale, acc_ref.at[h], m_ref.at[h], l_ref.at[h])
+        if rolled:      # a turn of the loop: as many heads as divide them
+            turn = max(n for n in range(1, HEAD_UNROLL + 1) if not kvh % n)
+
+            def some(i, carry):
+                for h in range(turn):
+                    h = i * turn + h
+                    head(h, pl.ds(pl.multiple_of(h * hd, hd), hd))
+                return carry
+            lax.fori_loop(0, kvh // turn, some, 0)
+        else:
+            for h in range(kvh):
+                head(h, slice(h * hd, (h + 1) * hd))
 
     _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
-                l_ref, ((k_hbm, k_buf, (0,)), (v_hbm, v_buf, (1,))), v_buf,
-                attend, **walk)
+                l_ref, finite_ref, ((k_hbm, k_buf, (0,)), (v_hbm, v_buf, (1,))),
+                v_buf, attend, **walk)
 
 
 def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
@@ -334,8 +502,7 @@ def _paged_decode_call(qg, k_pool, v_pool, layer, page_tables, lengths,
     return _paged_pallas_call(
         kernel, KERNEL_PAGED_DECODE, KERNEL_PAGED_SCOPE, qg,
         (k_pool, v_pool), layer, page_tables, lengths,
-        block_pages=BLOCK_PAGES, out_width=hd, sems=(BLOCK_SLOTS, 2),
-        interpret=interpret)
+        out_width=hd, sems=(BLOCK_SLOTS, 2), interpret=interpret)
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, page_tables, lengths,
@@ -430,8 +597,8 @@ def _paged_window_decode_call(q, k_pool, v_pool, layer, ring_tables,
     out = _paged_pallas_call(
         kernel, KERNEL_PAGED_WINDOW_DECODE, KERNEL_PAGED_WINDOW_SCOPE,
         q.reshape(B, kvh, n_heads // kvh, hd), (k_pool, v_pool), layer,
-        ring_tables, lengths, block_pages=BLOCK_PAGES, out_width=hd,
-        sems=(BLOCK_SLOTS, 2), interpret=interpret, window=window)
+        ring_tables, lengths, out_width=hd, sems=(BLOCK_SLOTS, 2),
+        interpret=interpret, window=window)
     return out.reshape(B, n_heads, hd)
 
 
@@ -466,10 +633,6 @@ def paged_window_decode_attention_kernel(q, k_pool, v_pool, layer,
 # matmul's rows are the heads.
 KERNEL_MLA_PAGED_DECODE = "mla_paged_decode_attn"
 KERNEL_MLA_PAGED_SCOPE = "mla_paged_decode_attention"
-# Pages a block: larger blocks than the per-head pool's, a row being
-# narrower than its keys and values together (PERF.md, PR 27: time goes
-# with the number of blocks).
-MLA_BLOCK_PAGES = 16
 
 
 def mla_paged_decode_tiles(width: int, latent: int, page_size: int,
@@ -507,18 +670,21 @@ def mla_paged_attention_reference(q, pool, layer, page_tables, lengths,
 
 def _mla_paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
                              q_ref, pool_hbm, o_ref,
-                             buf, sems, acc_ref, m_ref, l_ref, *,
-                             sm_scale: float, latent: int, **walk):
-    bk, width = buf.shape[1] * buf.shape[2], buf.shape[3]
+                             buf, sems, acc_ref, m_ref, l_ref, finite_ref,
+                             *, sm_scale: float, latent: int, **walk):
+    width = buf.shape[3]
     q = q_ref[0]                                         # (heads, width)
 
-    def attend(slot, seen):
-        rows = buf[slot].reshape(bk, width)      # keys; values in front
+    def attend(slot, start, pages, seen, rolled):
+        # keys; values in front
+        rows = buf[slot, pl.ds(start, pages)].reshape(
+            pages * buf.shape[2], width)
         _softmax_update(q, rows, rows[:, :latent], seen, sm_scale,
                         acc_ref, m_ref, l_ref)
 
     _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
-                l_ref, ((pool_hbm, buf, ()),), buf, attend, **walk)
+                l_ref, finite_ref, ((pool_hbm, buf, ()),), buf, attend,
+                **walk)
 
 
 # jitted for the reason `_paged_decode_call` is: traced once a program
@@ -541,8 +707,8 @@ def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
                                latent=latent)
     out = _paged_pallas_call(
         kernel, KERNEL_MLA_PAGED_DECODE, KERNEL_MLA_PAGED_SCOPE, qp,
-        (pool,), layer, page_tables, lengths, block_pages=MLA_BLOCK_PAGES,
-        out_width=latent, sems=(BLOCK_SLOTS,), interpret=interpret)
+        (pool,), layer, page_tables, lengths, out_width=latent,
+        sems=(BLOCK_SLOTS,), interpret=interpret)
     return out[:, :heads]
 
 

@@ -118,6 +118,8 @@ def _bucket(n: int, lo: int = 16, hi: int = 1 << 30) -> int:
 _FIXED_COUNTERS = {
     "window_positions_live": "kv_window_positions_live",
     "window_positions_read": "kv_window_positions_read",
+    "window_walk_blocks": "kv_window_walk_blocks",
+    "window_positions_attended": "kv_window_positions_attended",
     "state_slots": "state_slots_live",
     "state_bytes": "state_bytes_moved"}
 
@@ -260,6 +262,13 @@ class EngineCore:
         # lanes hold; the kernel reads the pages they hold
         self._table_positions = (self.max_batch * self.max_pages_per_seq
                                  * self.page_size)
+        # the kernel's walk over a lane by the pages it holds: (blocks,
+        # positions its matmuls multiply), by the kernel's own rule
+        from ray_tpu.ops.paged_attention import walk_counts
+        self._walk_block = self.model.walk_block_pages(
+            self.page_size, self.max_pages_per_seq)
+        self._walk = [walk_counts(n, self._walk_block, self.page_size)
+                      for n in range(self.max_pages_per_seq + 1)]
         self.counters = {
             "admitted": 0, "evictions": 0, "finished": 0, "tokens": 0,
             "steps": 0,
@@ -278,6 +287,10 @@ class EngineCore:
             # a sequence's last positions only, a recurrent layer moves a
             # state of one size
             "kv_positions_live": 0, "kv_positions_read": 0,
+            # the shape of the kernel's walk over such a layer: the blocks
+            # the lanes' pages came in, and the positions its matmuls
+            # multiplied (the part of each block a lane holds, in pieces)
+            "kv_walk_blocks": 0, "kv_positions_attended": 0,
             **dict.fromkeys(_FIXED_COUNTERS.values(), 0),
             # and what the model counts on the device in a decode step,
             # under the model's own names (`step_stats`: the counts come
@@ -551,7 +564,7 @@ class EngineCore:
             pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
             active = np.zeros((B,), bool)
             kernel = self._attention != "einsum"
-            live = held = 0
+            live = held = blocks = attended = 0
             fixed: Dict[str, int] = {}
             for seq in batch:
                 i = seq.lane
@@ -559,7 +572,11 @@ class EngineCore:
                 pts[i, :len(seq.pages)] = seq.pages
                 active[i] = True
                 live += seq.device_len
-                held += pages_needed(seq.device_len, self.page_size)
+                pages = pages_needed(seq.device_len, self.page_size)
+                held += pages
+                if kernel:
+                    blocks += self._walk[pages][0]
+                    attended += self._walk[pages][1]
                 if self._fixed:     # what the lane's fixed part costs
                     for name, n in self.model.fixed_step_counts(
                             seq.device_len, self.page_size, kernel).items():
@@ -568,17 +585,23 @@ class EngineCore:
                     jnp.asarray(active))
         # the kernel copies in each lane's live pages, whole
         read = held * self.page_size if kernel else self._table_positions
+        if not kernel:      # the gather multiplies all it reads
+            attended = read
         c["decode_steps"] += 1
         c["decode_kernel_steps"] += int(kernel)
         c["decode_lane_steps"] += len(batch)
         c["kv_positions_live"] += live
         c["kv_positions_read"] += read
+        c["kv_walk_blocks"] += blocks
+        c["kv_positions_attended"] += attended
         for name, n in fixed.items():
             c[_FIXED_COUNTERS[name]] += n
         # an annotation's attributes are fixed when it opens, so the
         # step's counts ride the first span that opens once they are known
         with _Phase(phases, _sp.DISPATCH, lanes=len(batch),
-                    live_positions=live, read_positions=read, **fixed):
+                    live_positions=live, read_positions=read,
+                    walk_blocks=blocks, attended_positions=attended,
+                    **fixed):
             logits, self._cache = self._decode_fn(
                 self.params, self._cache, self._tokens, *args)
             self._tokens, counts = self._next_fn(

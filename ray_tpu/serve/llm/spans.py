@@ -20,11 +20,14 @@ STEP = "engine.step"                # one EngineCore.step()
 # linear-attention layers, the `scan_chunks` of its recurrence)
 PREFILL = "engine.prefill"
 TABLES = "engine.page_tables"       # the decode batch's host arrays
-# carries this dispatch's counts: `lanes`, `live_positions` and
-# `read_positions` (a layer whose cache is whole) and what the model says
-# the lanes' fixed parts cost (`fixed_step_counts`): for window layers
-# `window_positions_live` / `window_positions_read` (a layer that holds a
-# sequence's last positions in a ring), for linear-attention layers
+# carries this dispatch's counts: `lanes`, `live_positions`,
+# `read_positions`, `walk_blocks` and `attended_positions` (a layer whose
+# cache is whole: what the lanes hold, what the kernel copies in, the
+# blocks of its walk and the positions its matmuls multiply) and what the
+# model says the lanes' fixed parts cost (`fixed_step_counts`): for window
+# layers `window_positions_live` / `_read` / `_attended` and
+# `window_walk_blocks` (a layer that holds a sequence's last positions in
+# a ring), for linear-attention layers
 # `state_slots` / `state_bytes` (the lanes whose state the step reads and
 # writes, and the bytes moved for them)
 DISPATCH = "engine.decode_dispatch"

@@ -88,3 +88,21 @@ def fresh_cluster():
     rt = ray_tpu.init(num_cpus=4)
     yield rt
     ray_tpu.shutdown()
+
+
+@pytest.fixture
+def walk_budget(monkeypatch):
+    """Sets `ops.paged_attention.WALK_BUFFER_BYTES` for a test, so that
+    tables as small as the tests' are walked in more than one block; the
+    jitted calls forget what they were traced with, before and after."""
+    from ray_tpu.ops import paged_attention as pa
+    calls = (pa._paged_decode_call, pa._paged_window_decode_call,
+             pa._mla_paged_decode_call)
+
+    def set_to(nbytes):
+        monkeypatch.setattr(pa, "WALK_BUFFER_BYTES", nbytes)
+        for call in calls:
+            call.clear_cache()
+    yield set_to
+    for call in calls:
+        call.clear_cache()

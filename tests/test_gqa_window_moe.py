@@ -248,6 +248,33 @@ def test_ring_kernel_matches_masked_einsum_over_the_whole_sequence(
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
 
 
+@pytest.mark.parametrize("block", [33, 16])
+@pytest.mark.parametrize("lengths,heads,kvh", [
+    ((1000, 513, 528, 529), 2, 2),      # wrapped; the ring's edges; 1 a group
+    ((4000, 512, 100, 0), 4, 2),        # wrapped many times, under, idle
+    ((2000, 640, 641, 1), 12, 2),       # a group of 6; a position
+])
+def test_ring_of_33_pages_wraps_inside_a_block(walk_budget, block, lengths,
+                                               heads, kvh):
+    """The cells' ring (a window of 512 in 16-position pages) as one block
+    of 33 pages, multiplied in one or two pieces of 8 or whole, and as
+    blocks of 16: the ring's wrap falls inside a block, wherever the
+    window begins."""
+    window, page = 512, 16
+    page_bytes = 2 * page * kvh * 128 * 4
+    if block != 33:
+        walk_budget(paged.BLOCK_SLOTS * block * page_bytes)
+    assert paged.walk_block_pages(page_bytes, page, 33) == block
+    assert paged.walk_prefixes(33, page) == (8, 16, 33)
+    q, kp, vp, tables, whole = _ring_case(lengths, window, page, heads, kvh,
+                                          seed=3)
+    want = _window_einsum(q, whole, window, kvh)
+    got = paged.paged_window_decode_attention_kernel(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), 1,
+        jnp.asarray(tables), jnp.asarray(lengths, jnp.int32), window)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
+
+
 def test_ring_walk_reads_at_most_the_ring_and_names_its_kernel():
     window, page = 512, 16
     ring = paged.ring_pages(window, page)
@@ -397,6 +424,10 @@ def test_a_sliding_layers_cache_is_a_ring_and_the_engine_counts_it(tiny_ref):
     assert c["kv_window_positions_live"] < c["kv_positions_live"]
     assert c["kv_window_positions_read"] <= c["decode_lane_steps"] * 40
     assert c["kv_window_positions_live"] <= c["kv_window_positions_read"]
+    # the gather multiplies all it reads, in no blocks
+    assert c["kv_window_positions_attended"] == c["kv_window_positions_read"]
+    assert c["kv_positions_attended"] == c["kv_positions_read"]
+    assert c["kv_window_walk_blocks"] == c["kv_walk_blocks"] == 0
     st = core.device_stats()
     assert st["decode_attention"] == "einsum"
     assert st["fixed_pages_used"] == 0 and np.asarray(
@@ -407,6 +438,22 @@ def test_a_sliding_layers_cache_is_a_ring_and_the_engine_counts_it(tiny_ref):
         assert served.decode_attention(16) == (
             "paged_decode_attn+paged_window_decode_attn")
     assert served.window_pages(16) == served.fixed_pages(16) == 33
+    # under the kernels a lane's ring is one block, multiplied as far as
+    # the least of 8, 16 and 33 pages that holds what the walk reaches
+    # (`ring_walk`'s read), a full layer's table blocks of the rule's size
+    assert served.walk_block_pages(16, 33, fixed=True) == 33
+    counts = served.fixed_step_counts(3400, 16)
+    assert counts["window_walk_blocks"] == 1
+    assert counts["window_positions_read"] == 33 * 16
+    assert counts["window_positions_attended"] == 33 * 16
+    assert served.fixed_step_counts(100, 16)[
+        "window_positions_attended"] == 8 * 16
+    assert served.fixed_step_counts(3408, 16)[
+        "window_positions_read"] == 32 * 16
+    assert served.fixed_step_counts(200, 16)[
+        "window_positions_attended"] == 16 * 16
+    block = served.walk_block_pages(16, 512)
+    assert block == paged.walk_block_pages(2 * 16 * 1024 * 2, 16, 512)
     assert served.cache_page_bytes(16) == 2 * 2 * 16 * 1024 * 2
     assert served.param_count() == 3869857792
 
@@ -508,13 +555,18 @@ def _programs(model, cfg, B=2, s=32, page=16):
 
 PINNED = {
     ("Transformer", "prefill"): "40910654b9cf7e7c",
-    ("Transformer", "decode_step"): "a567fb1f06be49e7",
+    # PR 38 changed the page walk (its block from the bytes of a page, a
+    # block multiplied over the part a lane holds): the two decode steps
+    # that hold a paged kernel are pinned anew to PR 38's text. The tiny
+    # `MLAMoE`'s rows are no shape the latent kernel tiles, so its step
+    # gathers and stands at PR 36's text, as the three prefills do.
+    ("Transformer", "decode_step"): "e987d15ddaabf26d",
     ("MLAMoE", "prefill"): "ad1cf41a588df5a6",
     ("MLAMoE", "decode_step"): "3ff8c18bb669e7c8",
     # PR 37 renamed the allocator's ring class and gave the page walk a
     # group of one query head: the third class is pinned to PR 36's text
     ("GQAWindowMoE", "prefill"): "2ee05703a435ae1e",
-    ("GQAWindowMoE", "decode_step"): "b17180939d5f66e2",
+    ("GQAWindowMoE", "decode_step"): "1dad72ccb3bdd029",
 }
 
 

@@ -110,9 +110,12 @@ def test_core_spans_and_parents(tiny_model, recorder):
     # the einsum (the tiny model's heads are no shape the kernel tiles)
     # gathers every lane's whole table, whatever the lanes hold
     read = 2 * core.max_pages_per_seq * 8
+    # and multiplies all of it, in no blocks
     assert disp == [
-        {"lanes": 1, "live_positions": 6, "read_positions": read},
-        {"lanes": 1, "live_positions": 7, "read_positions": read}]
+        {"lanes": 1, "live_positions": 6, "read_positions": read,
+         "walk_blocks": 0, "attended_positions": read},
+        {"lanes": 1, "live_positions": 7, "read_positions": read,
+         "walk_blocks": 0, "attended_positions": read}]
 
 
 def test_read_positions_under_the_kernel_are_the_pages_held(tiny_model,
@@ -127,11 +130,19 @@ def test_read_positions_under_the_kernel_are_the_pages_held(tiny_model,
     core.submit(list(range(1, 21)), max_tokens=2, rid="b")
     _run(core)
     disp = [e[7] for e in _mine(recorder) if e[4] == sp.DISPATCH]
-    # 8-position pages: a holds 6 then 7 positions (1 page), b 21 (3)
+    # 8-position pages: a holds 6 then 7 positions (1 page), b 21 (3);
+    # a block is the lane's whole table here, a block a lane, multiplied
+    # over a piece of 128 positions (16 pages: all the table has)
+    assert core._walk_block == core.max_pages_per_seq <= 16
+    piece = core.max_pages_per_seq * 8
     assert disp == [
-        {"lanes": 2, "live_positions": 6 + 21, "read_positions": 8 + 24},
-        {"lanes": 1, "live_positions": 7, "read_positions": 8}]
+        {"lanes": 2, "live_positions": 6 + 21, "read_positions": 8 + 24,
+         "walk_blocks": 2, "attended_positions": 2 * piece},
+        {"lanes": 1, "live_positions": 7, "read_positions": 8,
+         "walk_blocks": 1, "attended_positions": piece}]
     st = core.stats()
+    assert st["kv_walk_blocks"] == 3
+    assert st["kv_positions_attended"] == 3 * piece
     assert st["kv_positions_read"] == 8 + 24 + 8
     assert st["kv_positions_live"] == 6 + 21 + 7
     assert st["decode_kernel_steps"] == st["decode_steps"] == 2

@@ -229,13 +229,44 @@ def test_latent_kernel_skips_unassigned_entries():
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
 
 
-def test_latent_kernel_reads_pages_in_table_order():
+def _latent_blocks_of_64(walk_budget, max_pages):
+    """Blocks of 64 8-position pages of 256 float32: four pieces of 128
+    positions, multiplied in one or two pieces or whole."""
+    page_bytes = 8 * 256 * 4
+    walk_budget(paged.BLOCK_SLOTS * 64 * page_bytes)
+    assert paged.walk_block_pages(page_bytes, 8, max_pages) == 64
+    assert paged.walk_prefixes(64, 8) == (16, 32, 64)
+
+
+@pytest.mark.parametrize("lengths", [
+    (255, 256, 257, 0),         # two pieces' edges (32 pages of 8), idle
+    (127, 128, 129, 1),         # a piece's, a position
+    (511, 512, 513, 8),         # a block's, a page
+    (600, 40, 300, 385),        # unlike lanes: 2 blocks, 1, 1, 1
+], ids=lambda v: "-".join(map(str, v)))
+def test_latent_kernel_walks_blocks_in_pieces(walk_budget, lengths):
+    _latent_blocks_of_64(walk_budget, 80)
+    q, pool, tables, lens = _latent_case(lengths, seed=5, pages=330,
+                                         max_pages=80)
+    holes = np.asarray(tables).copy()
+    holes[0, 3] = holes[3, 0] = -1      # in a full block, a lane's first
+    if lengths[0] > 500:
+        holes[0, 70] = -1               # and in the tail's piece
+    holes = jnp.asarray(holes)
+    got = paged.mla_paged_decode_attention_kernel(
+        q, pool, 1, holes, lens, 128, 0.1)
+    np.testing.assert_allclose(got, paged.mla_paged_attention_reference(
+        q, pool, 1, holes, lens, 128, 0.1), atol=2e-5, rtol=2e-5)
+
+
+def test_latent_kernel_reads_pages_in_table_order(walk_budget):
     """The same rows under another placement of the pages give the same
     output (the table, not the pool's order, says where a position is),
     over more than one block of pages a lane."""
     q, pool, tables, lens = _latent_case((150, 60, 129), seed=3, pages=64,
                                          max_pages=40)
-    assert -(-150 // 8) > paged.MLA_BLOCK_PAGES
+    walk_budget(paged.BLOCK_SLOTS * 16 * 8 * 256 * 4)
+    assert -(-150 // 8) > paged.walk_block_pages(8 * 256 * 4, 8, 40) == 16
     perm = np.random.default_rng(4).permutation(pool.shape[1])
     inv = np.argsort(perm).astype(np.int32)
     moved = jnp.where(tables >= 0, jnp.asarray(inv)[jnp.maximum(tables, 0)],

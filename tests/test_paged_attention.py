@@ -23,7 +23,8 @@ PAGE, HD, MAX_PAGES = 16, 128, 20
 FULL = PAGE * MAX_PAGES
 
 
-def _case(lengths, kvh=2, group=2, seed=0, layers=2, holes=()):
+def _case(lengths, kvh=2, group=2, seed=0, layers=2, holes=(),
+          MAX_PAGES=MAX_PAGES):
     """Random bf16 pools and queries; each lane's table lists pages drawn
     without order from a pool larger than all tables, -1 past the lane's
     pages and at `holes` (lane, table index)."""
@@ -103,6 +104,145 @@ def test_kernel_reads_pages_in_table_order():
         q, k[:, perm], v[:, perm], 1, moved.astype(jnp.int32), ln)
     np.testing.assert_array_equal(np.asarray(again, np.float32),
                                   np.asarray(got, np.float32))
+
+
+# ------------------------------------------- the walk's blocks and pieces
+# (`walk_budget`, of conftest.py, sets `WALK_BUFFER_BYTES` for a test)
+LONG = 72           # pages a table: two blocks of 32 pages and a piece
+BLOCK = 32 * PAGE   # positions of such a block; a piece holds 8 * PAGE
+
+
+def _blocks_of_32(walk_budget, kvh=2):
+    walk_budget(pa.BLOCK_SLOTS * 32 * 2 * PAGE * kvh * HD * 2)
+    assert pa.walk_block_pages(2 * PAGE * kvh * HD * 2, PAGE, LONG) == 32
+    assert pa.walk_prefixes(32, PAGE) == (8, 16, 32)
+
+
+@pytest.mark.parametrize("lengths", [
+    [BLOCK - 1, BLOCK, BLOCK + 1],              # a block's edges
+    [2 * BLOCK - 1, 2 * BLOCK, 2 * BLOCK + 1],
+    [8 * PAGE - 1, 8 * PAGE, 8 * PAGE + 1],     # a piece's, in the first
+    [BLOCK + 8 * PAGE - 1, BLOCK + 8 * PAGE,    # and in a later block
+     BLOCK + 8 * PAGE + 1],
+    [16 * PAGE, 16 * PAGE + 1, 24 * PAGE + 1],  # two pieces, then whole
+    [PAGE, 1, 0],                               # a page, a position, none
+    [LONG * PAGE, 0, 3, BLOCK + 5],             # unlike lanes in one call
+], ids=lambda v: "-".join(map(str, v)))
+def test_kernel_walks_blocks_in_pieces(walk_budget, lengths):
+    """Blocks of 32 pages multiplied in one or two pieces of 8 or whole:
+    every edge of a block and of a piece gives what the gather gives."""
+    _blocks_of_32(walk_budget)
+    q, k, v, pt, ln = _case(lengths, seed=5, MAX_PAGES=LONG)
+    _close(pa.paged_decode_attention_kernel(q, k, v, 1, pt, ln),
+           pa.paged_attention_reference(q, k, v, 1, pt, ln))
+
+
+@pytest.mark.parametrize("kvh,group", [(2, 1), (1, 2), (1, 6)])
+def test_kernel_walks_blocks_at_each_group(walk_budget, kvh, group):
+    _blocks_of_32(walk_budget, kvh)
+    q, k, v, pt, ln = _case([2 * BLOCK + 40, BLOCK, 130], kvh=kvh,
+                            group=group, seed=6, MAX_PAGES=LONG)
+    _close(pa.paged_decode_attention_kernel(q, k, v, 0, pt, ln),
+           pa.paged_attention_reference(q, k, v, 0, pt, ln))
+
+
+def test_kernel_skips_holes_in_a_full_block_and_in_a_tail_piece(
+        walk_budget):
+    """-1 inside a block that is multiplied whole, inside the piece the
+    last block is multiplied at, and as a lane's first page: not read, not
+    seen; and what lies behind the tail's prefix is never multiplied,
+    whatever an earlier lane left in the buffer."""
+    _blocks_of_32(walk_budget)
+    q, k, v, pt, ln = _case(
+        [LONG * PAGE, BLOCK + 3 * PAGE, 2 * BLOCK + 20], seed=7,
+        MAX_PAGES=LONG,
+        holes=[(0, 0), (0, 17), (0, 31), (0, 40), (1, 5), (1, 33),
+               (2, 64), (2, 65)])
+    want = pa.paged_attention_reference(q, k, v, 0, pt, ln)
+    got = pa.paged_decode_attention_kernel(q, k, v, 0, pt, ln)
+    _close(got, want)
+    poisoned = k.at[:, 0].set(jnp.nan), v.at[:, 0].set(jnp.nan)
+    assert 0 not in np.asarray(pt)
+    again = pa.paged_decode_attention_kernel(q, *poisoned, 0, pt, ln)
+    np.testing.assert_array_equal(np.asarray(again, np.float32),
+                                  np.asarray(got, np.float32))
+
+
+# page bytes of a layer (all pools), table pages: the five configurations
+CELLS = {
+    "internlm2-1.8b": (2 * 16 * 8 * 128 * 2, 256),
+    "laguna-xs.2 full": (2 * 16 * 8 * 128 * 2, 512),
+    "laguna-xs.2 window": (2 * 16 * 8 * 128 * 2, 33),
+    "olmo-hybrid-7b": (2 * 16 * 30 * 128 * 2, 192),
+    "glm-4.7-flash": (16 * 640 * 2, 256),
+    "mistral-7b-v0.1": (2 * 16 * 8 * 128 * 2, 2048),
+}
+
+
+@pytest.mark.parametrize("name", sorted(CELLS))
+def test_block_rule_at_the_configurations_shapes(name):
+    """Whole pieces unless the table is the block, inside the budget of
+    bytes and of positions, no longer than the table; the prefixes end at
+    the block and never leave a lane more than twice what it holds."""
+    page_bytes, table = CELLS[name]
+    block = pa.walk_block_pages(page_bytes, 16, table)
+    piece = pa.PIECE_POSITIONS // 16
+    assert 1 <= block <= table
+    assert block % piece == 0 or block == table
+    assert (pa.BLOCK_SLOTS * block * page_bytes <= pa.WALK_BUFFER_BYTES
+            or block == piece)
+    assert block * 16 <= pa.BLOCK_POSITIONS
+    # what the sweep chose (PERF.md section 6, PR 38)
+    assert block == {"olmo-hybrid-7b": 32, "laguna-xs.2 window": 33}.get(
+        name, 64)
+    prefixes = pa.walk_prefixes(block, 16)
+    assert prefixes[-1] == block and prefixes[0] == min(piece, block)
+    assert list(prefixes) == sorted(set(prefixes))
+    for pages in range(1, block + 1):
+        blocks, attended = pa.walk_counts(pages, block, 16)
+        assert blocks == 1
+        assert pages * 16 <= attended < max(2 * pages, piece + 1) * 16
+    # a full block is one update; what is behind it starts anew
+    assert pa.walk_counts(3 * block, block, 16) == (3, 3 * block * 16)
+    assert pa.walk_counts(3 * block + 1, block, 16) == (
+        4, (3 * block + prefixes[0]) * 16)
+    assert pa.walk_counts(0, block, 16) == (0, 0)
+
+
+def test_a_wider_page_never_gets_more_pages():
+    widths = [16 * 64 * n for n in (1, 5, 10, 32, 64, 120, 245, 1000)]
+    blocks = [pa.walk_block_pages(w, 16, 4096) for w in widths]
+    assert blocks == sorted(blocks, reverse=True)
+    assert blocks[-1] == 8          # one piece at least, whatever it weighs
+    assert blocks[0] == pa.BLOCK_POSITIONS // 16    # positions bound it
+    assert pa.walk_block_pages(widths[0], 32, 4096) == blocks[0] // 2
+    # and a table shorter than a piece is its own block
+    assert pa.walk_block_pages(widths[3], 16, 5) == 5
+    assert pa.walk_prefixes(5, 16) == (5,)
+
+
+@pytest.mark.parametrize("name,pools", [
+    ("Transformer", "k v"), ("MLAMoE", "kv"), ("GQAWindowMoE", "k v"),
+    ("GQAWindowMoE", "wk wv"), ("HybridDelta", "k v")])
+def test_a_model_asks_the_rule_what_its_kernel_asks(name, pools):
+    """`model.walk_block_pages` (the engine's counts stand on it) is the
+    rule at the bytes of a page of the pools the kernel is handed."""
+    from ray_tpu.models import build_model
+    from ray_tpu.models.gqa_window_moe import GQAWindowMoEConfig
+    from ray_tpu.models.hybrid_delta import HybridDeltaConfig
+    from ray_tpu.models.mla_moe import MLAMoEConfig
+    model = build_model({"Transformer": tiny, "MLAMoE": MLAMoEConfig,
+                         "GQAWindowMoE": GQAWindowMoEConfig,
+                         "HybridDelta": HybridDeltaConfig}[name](), None)
+    fixed = model.fixed_pages(16)
+    cache = jax.eval_shape(lambda: model.init_cache(
+        64, 16, **({"fixed_pages": 4 * fixed} if fixed else {})))
+    page_bytes = sum(16 * cache[p].shape[3] * cache[p].dtype.itemsize
+                     for p in pools.split())
+    for table in (33, 256, 4096):
+        asked = (model.walk_block_pages(16, table, fixed=True)
+                 if pools == "wk wv" else model.walk_block_pages(16, table))
+        assert asked == pa.walk_block_pages(page_bytes, 16, table)
 
 
 @pytest.mark.parametrize("hd,page,dtype,tiles", [

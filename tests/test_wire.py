@@ -244,6 +244,11 @@ def test_batch_emission_is_negotiated():
     """A sender must not emit BatchFrame until it has OBSERVED the peer
     speaking MINOR >= 1; before that, coalesced flushes go out as
     plain concatenated frames any same-major peer can parse."""
+    # WIRE_STATS counts the whole process: a shared runtime another file
+    # left alive in this worker sends frames of its own between two reads
+    # of it (`ray_cluster` makes a new one when it is next asked for)
+    import ray_tpu
+    ray_tpu.shutdown()
     got = []
     server_box = {}
     lsock = socket.socket()

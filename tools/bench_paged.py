@@ -1,0 +1,194 @@
+"""The three paged decode kernels on the chip, alone: `paged_decode_attn`,
+`paged_window_decode_attn` and `mla_paged_decode_attn` at the widths, lanes
+and table lengths of the serving cells, every lane at one length, 24 calls
+(layers) a program. Prints milliseconds a layer and the share of the bytes
+`required_ops.paged_decode_call` says the algorithm needs, at 819 GB/s.
+Chip only:
+
+    chiprun -- python tools/bench_paged.py
+    chiprun -- python tools/bench_paged.py --sweep        # every block
+    chiprun -- python tools/bench_paged.py --shapes pr27,laguna-window
+
+`--sweep` puts each block size (in pages) in the place of
+`walk_block_pages`'s answer: what `WALK_BUFFER_BYTES` and `BLOCK_POSITIONS`
+in `ops/paged_attention.py` were chosen from (PERF.md section 6, PR 38);
+`--prefixes each` lets the matmuls of a block reach every whole number of
+pieces in the place of `walk_prefixes`'s few, `--heads n` puts `n` in the
+place of `HEAD_UNROLL`. A tree from before PR 38 has two constants and no
+rule: copy this file into its `tools/` and run it there to set parent
+beside change in one call (`--json` writes the rows).
+"""
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmarks.harness.required_ops import paged_decode_call
+from ray_tpu.ops import paged_attention as pa
+
+PAGE, HD, LAYERS, HBM = 16, 128, 24, 819e9
+POSITIONS = (0, 16, 577, 1180, 3400, 4096)
+# name: (kernel, lanes, query heads, kv heads, table pages, window);
+# the latent kernel: 20 heads over rows of 640 that hold 576 numbers
+SHAPES = {
+    "pr27": ("full", 8, 16, 8, 256, 0),             # internlm2's cells
+    "laguna-full": ("full", 32, 48, 8, 512, 0),
+    "laguna-window": ("window", 32, 64, 8, 33, 512),
+    "olmo": ("full", 32, 30, 30, 192, 0),
+    "glm": ("latent", 32, 20, 1, 256, 0),
+}
+LATENT, ROW, ROW_HELD = 512, 640, 576
+
+
+def program(kernel, window):
+    """`LAYERS` calls of a kernel, each one's queries hanging on the one
+    before: lengths and tables are data, so one program a shape and block."""
+    def step(q, tables, lens, *pools):
+        for _ in range(LAYERS):
+            if kernel == "latent":
+                out = jnp.pad(pa.mla_paged_decode_attention_kernel(
+                    q, *pools, 0, tables, lens, LATENT, 0.07),
+                    ((0, 0), (0, 0), (0, ROW - LATENT)))
+            elif window:
+                out = pa.paged_window_decode_attention_kernel(
+                    q, *pools, 0, tables, lens, window)
+            else:
+                out = pa.paged_decode_attention_kernel(q, *pools, 0, tables,
+                                                       lens)
+            q = q + out * 1e-3
+        return q
+    return jax.jit(step)
+
+
+def pools_of(kernel, lanes, heads, kvh, table):
+    """(queries, pools): a pool holds a whole table a lane."""
+    key = jax.random.PRNGKey(0)
+    if kernel == "latent":
+        return (jax.random.normal(key, (lanes, heads, ROW), jnp.bfloat16),
+                (jax.random.normal(key, (1, lanes * table, PAGE, ROW),
+                                   jnp.bfloat16),))
+    k = jax.random.normal(key, (1, lanes * table, PAGE, kvh * HD),
+                          jnp.bfloat16)
+    return (jax.random.normal(key, (lanes, heads, HD), jnp.bfloat16),
+            (k, k[:, ::-1]))
+
+
+def lanes_at(kernel, lanes, heads, kvh, table, window, length):
+    """(tables, lengths, bytes the algorithm needs a layer): every lane
+    `length` long, its pages anywhere in the pool."""
+    held = min(-(-length // PAGE), table)
+    rng = np.random.default_rng(length)
+    tables = np.full((lanes, table), -1, np.int32)
+    tables[:, :held] = rng.permutation(lanes * table)[:lanes * held].reshape(
+        lanes, held)
+    live = lanes * (min(length, window) if window else length)
+    if kernel == "latent":
+        need = paged_decode_call(live, lanes, 1, ROW_HELD // 2,
+                                 heads * (ROW_HELD + LATENT) // 2)
+    else:
+        need = paged_decode_call(live, lanes, 1, kvh * HD, heads * HD)
+    return (jnp.asarray(tables), jnp.full((lanes,), length, jnp.int32),
+            need["bytes"])
+
+
+def timed(fn, *args):
+    """Milliseconds a layer: the least of three batches of calls, each
+    long enough (0.05 s or ten calls) to be read on the host's clock."""
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    jax.block_until_ready(fn(*args))
+    n = max(10, int(0.05 / max(time.perf_counter() - t, 1e-5)))
+    best = float("inf")
+    for _ in range(3):
+        t = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t) / n)
+    return best * 1e3 / LAYERS
+
+
+def set_block(pages, prefixes):
+    """`pages` a block in the place of what the tree would choose (None:
+    as the tree stands); the rule's doubling or every piece."""
+    jax.clear_caches()      # the jitted calls keep what they were traced with
+    if not hasattr(pa, "walk_block_pages"):     # before PR 38
+        if pages is not None:
+            pa.BLOCK_PAGES = pa.MLA_BLOCK_PAGES = pages
+        return
+    if pages is not None:
+        pa.walk_block_pages = lambda page_bytes, page_size, max_pages: min(
+            pages, max_pages)
+    if prefixes == "each":
+        piece = pa.PIECE_POSITIONS // PAGE
+        pa.walk_prefixes = lambda block, page_size: (
+            *range(piece, block, piece), block)
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--blocks", default="8,16,32,64,128",
+                    help="pages a block, under --sweep")
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--positions", help="comma-separated lengths of a "
+                    "lane, in place of " + ",".join(map(str, POSITIONS)))
+    ap.add_argument("--prefixes", choices=("rule", "each"), default="rule")
+    ap.add_argument("--heads", type=int, help="heads a turn of the loop "
+                    "over a block's heads, in place of HEAD_UNROLL")
+    ap.add_argument("--json", help="write the rows here too")
+    opts = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        raise SystemExit("needs a TPU")
+    blocks = [int(b) for b in opts.blocks.split(",")] if opts.sweep else [
+        None]
+    positions = [int(p) for p in opts.positions.split(",")] \
+        if opts.positions else POSITIONS
+    if opts.heads:
+        pa.HEAD_UNROLL = opts.heads
+    rows = []
+    for name in opts.shapes.split(","):
+        kernel, lanes, heads, kvh, table, window = SHAPES[name]
+        q, pools = pools_of(kernel, lanes, heads, kvh, table)
+        done = set()
+        for block in blocks:
+            if block is not None:
+                block = min(block, table)   # as the call would cap it
+                if block in done:
+                    continue
+                done.add(block)
+            set_block(block, opts.prefixes)
+            fn = program(kernel, window)
+            for length in positions:
+                if length > table * PAGE and not window:
+                    continue
+                tables, lens, need = lanes_at(*SHAPES[name], length)
+                try:
+                    ms = timed(fn, q, tables, lens, *pools)
+                except Exception as e:      # say so and go on
+                    print(f"{name} block {block} positions {length}: "
+                          f"failed: {str(e)[:300]}", flush=True)
+                    continue
+                share = need / HBM * 1e5 / ms
+                rows.append({"shape": name, "block": block,
+                             "positions": length, "ms_a_layer": ms,
+                             "bytes_share": share})
+                print(f"{name} ({kernel}, {lanes} lanes) block "
+                      f"{block or 'as it stands'} positions {length}: "
+                      f"{ms:.4f} ms a layer, {share:.1f} % of the bytes' "
+                      f"floor", flush=True)
+    if opts.json:
+        os.makedirs(os.path.dirname(opts.json) or ".", exist_ok=True)
+        with open(opts.json, "w") as f:
+            json.dump(rows, f, indent=1)
+
+
+if __name__ == "__main__":
+    main()

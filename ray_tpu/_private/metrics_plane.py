@@ -235,6 +235,24 @@ class _ServingMetrics:
             "LLM engine step: wall time of one iteration of the step "
             "loop that had work (admit, decode, ingest and publish)",
             boundaries=bounds, registry=reg)
+        # a replica's start (`serve/llm/setup_record.py`): seconds, so
+        # counters an engine adds to once a phase and once a program
+        self.setup = Counter(
+            "ray_tpu_llm_setup_s",
+            "LLM engine construction, seconds by phase (the span's name: "
+            "engine.setup is the whole, engine.setup.* its parts)",
+            tag_keys=("phase",), registry=reg)
+        self.build_s = Counter(
+            "ray_tpu_llm_program_build_s",
+            "LLM engine program builds, seconds of the calls that built "
+            "a program: JAX's trace, its lowering, the backend's compile "
+            "or cache retrieval, and the rest of the call",
+            tag_keys=("program", "phase"), registry=reg)
+        self.builds = Counter(
+            "ray_tpu_llm_program_builds",
+            "LLM engine programs built, by whether the persistent "
+            "compile cache held them and whether it was a rebuild",
+            tag_keys=("cache", "rebuild"), registry=reg)
 
 
 _mx: Optional[_RuntimeMetrics] = None
@@ -243,8 +261,9 @@ _sv: Optional[_ServingMetrics] = None
 
 
 def serving_metrics() -> Optional[dict]:
-    """TTFT/TPOT/step histograms + token counter for the LLM engine, or
-    None while the plane is disabled (callers skip their observes)."""
+    """TTFT/TPOT/step histograms, the token counter and the set-up
+    counters for the LLM engine, or None while the plane is disabled
+    (callers skip their observes)."""
     if not enabled():
         return None
     global _sv
@@ -255,7 +274,8 @@ def serving_metrics() -> Optional[dict]:
             if m is None:
                 _sv = m = _ServingMetrics()
     return {"ttft": m.ttft, "tpot": m.tpot, "tokens": m.tokens,
-            "step": m.step}
+            "step": m.step, "setup": m.setup, "build_s": m.build_s,
+            "builds": m.builds}
 
 
 def _metrics() -> _RuntimeMetrics:

@@ -54,6 +54,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from ray_tpu._private import tracing_plane as _tp
 from ray_tpu.serve.llm import spans as _sp
 from ray_tpu.serve.llm.kv_cache import PageAllocator, pages_needed
+from ray_tpu.serve.llm.setup_record import SetupRecord
 from ray_tpu.serve.llm.stream import TokenStreamServer
 
 FINISH_STOP = "stop"
@@ -84,8 +85,32 @@ class _Phase:
 
     def __exit__(self, *exc) -> None:
         self._span.__exit__(*exc)
-        self._acc[self._key] = (self._acc.get(self._key, 0.0)
-                                + _clock() - self._t0)
+        self._add(_clock() - self._t0)
+
+    def _add(self, seconds: float) -> None:
+        self._acc[self._key] = self._acc.get(self._key, 0.0) + seconds
+
+
+class _SetupPhase(_Phase):
+    """A phase of an engine's construction: its seconds stay in the
+    engine's record and go to the metrics plane (`ray_tpu_llm_setup_s`),
+    both under the span's name. `__enter__` gives the span, for what is
+    known only at the phase's end (`span.add`: the recorder's)."""
+
+    __slots__ = ("_series",)
+
+    def __init__(self, record: SetupRecord, name: str):
+        super().__init__(record.phases, name)
+        self._series = record.series
+
+    def __enter__(self) -> _sp.span:
+        super().__enter__()
+        return self._span
+
+    def _add(self, seconds: float) -> None:
+        super()._add(seconds)
+        if self._series:
+            self._series["setup"].inc(seconds, {"phase": self._key})
 
 
 def _name_os_thread(name: str) -> None:
@@ -102,6 +127,12 @@ def _name_os_thread(name: str) -> None:
         prctl(15, name.encode()[:15], 0, 0, 0)      # PR_SET_NAME
     except (OSError, AttributeError):   # not Linux: it stays `python3`
         pass
+
+
+def _tree_bytes(tree) -> int:
+    """Bytes of a tree of arrays, whole (over every shard)."""
+    import jax
+    return sum(a.nbytes for a in jax.tree.leaves(tree))
 
 
 def _bucket(n: int, lo: int = 16, hi: int = 1 << 30) -> int:
@@ -172,9 +203,14 @@ class EngineCore:
 
     def __init__(self, config, params, mesh=None,
                  num_pages: int = 0, page_size: int = 16,
-                 max_batch: int = 8):
+                 max_batch: int = 8,
+                 record: Optional[SetupRecord] = None):
         import jax
         from ray_tpu.models import build_model
+        # what this engine's start cost, phase by phase and program by
+        # program (`stats()`'s `setup` and `programs`); `LLMEngine` hands
+        # in the one it began before there was a core
+        self.record = record or SetupRecord(_serving_metrics())
         self.config = config
         self.page_size = int(page_size)
         self.max_batch = int(max_batch)
@@ -188,7 +224,8 @@ class EngineCore:
         self.num_pages = int(num_pages)
         # the config's type names the model class; the engine asks the
         # model for its cache and programs and names no class itself
-        self.model = build_model(config, mesh)
+        with _SetupPhase(self.record, _sp.SETUP_MODEL):
+            self.model = build_model(config, mesh)
         self.params = params
         # what a model keeps of a sequence for ever (a window layer's
         # ring of pages, a recurrent layer's state) is named by the first
@@ -197,17 +234,14 @@ class EngineCore:
         self._fixed = self.model.fixed_pages(self.page_size)
         self.alloc = PageAllocator(self.num_pages, fixed=self._fixed,
                                    sequences=self.max_batch)
-        self._cache = self.model.init_cache(
-            self.num_pages, self.page_size,
-            **({"fixed_pages": self.alloc.fixed_pages} if self._fixed
-               else {}))
-        # both programs update the pool in place: the cache argument is
-        # donated (whoever holds the old one holds a deleted buffer), and
-        # on a mesh every step hands the cache back as it lay, whatever
-        # the partitioner would have preferred for one call
-        self._jit = jax.jit if mesh is None else functools.partial(
-            jax.jit, out_shardings=(None, jax.tree.map(
-                lambda a: a.sharding, self._cache)))
+        with _SetupPhase(self.record, _sp.SETUP_CACHE) as span:
+            self._cache = self.model.init_cache(
+                self.num_pages, self.page_size,
+                **({"fixed_pages": self.alloc.fixed_pages} if self._fixed
+                   else {}))
+            span.add(bytes=_tree_bytes(self._cache),
+                     num_pages=self.num_pages,
+                     fixed_pages=self.alloc.fixed_pages)
         self._waiting: deque = deque()
         # the sequences that hold a lane, oldest admission first
         self._running: List[_Seq] = []
@@ -221,6 +255,60 @@ class EngineCore:
         self._queue_waits: deque = deque(maxlen=1024)  # (t, wait_s)
         self._prefill_fns: Dict[int, Any] = {}
         self._np = __import__("numpy")
+        with _SetupPhase(self.record, _sp.SETUP_PROGRAMS):
+            self._init_programs(mesh)
+        self.counters = {
+            "admitted": 0, "evictions": 0, "finished": 0, "tokens": 0,
+            "steps": 0,
+            # prompt tokens prefilled, and the same after padding to
+            # their bucket (what the prefill programs computed)
+            "prefill_tokens": 0, "prefill_padded_tokens": 0,
+            # prefill programs built: `record.count("_pre")`
+            "prefill_programs": 0,
+            # decode dispatches, those whose attention was the paged
+            # kernel, and the lanes that held a sequence
+            "decode_steps": 0, "decode_kernel_steps": 0,
+            "decode_lane_steps": 0,
+            # cache positions those lanes held / the dispatches read, a
+            # layer whose cache is whole; and what the lanes' fixed parts
+            # cost the same dispatches (`_FIXED_COUNTERS`, summed from the
+            # model's `fixed_step_counts`): a window layer holds and reads
+            # a sequence's last positions only, a recurrent layer moves a
+            # state of one size
+            "kv_positions_live": 0, "kv_positions_read": 0,
+            # the shape of the kernel's walk over such a layer: the blocks
+            # the lanes' pages came in, and the positions its matmuls
+            # multiplied (the part of each block a lane holds, in pieces)
+            "kv_walk_blocks": 0, "kv_positions_attended": 0,
+            **dict.fromkeys(_FIXED_COUNTERS.values(), 0),
+            # and what the model counts on the device in a decode step,
+            # under the model's own names (`step_stats`: the counts come
+            # back with the step's tokens and are summed over the steps)
+            **dict.fromkeys(self.model.step_stats(self._cache), 0),
+            # decode steps dispatched before the one before was read;
+            # times what was in flight was read with nothing dispatched
+            # behind it (eviction, drain, no lane left to decode); and
+            # lane-steps whose token nobody got (a step past a stop
+            # token, a request cancelled with its step in flight)
+            "decode_steps_ahead": 0, "pipeline_flushes": 0,
+            "discarded_lane_steps": 0}
+        # the steps that took SLOW_STEP_S or more: wall seconds, when,
+        # the decode batch, the seconds in each phase and the programs
+        # built in it
+        self.slow_steps: deque = deque(maxlen=16)
+        self._step_span = 0     # span id of the step running, or 0
+
+    def _init_programs(self, mesh) -> None:
+        """The jitted wrappers (built by the calls that first dispatch
+        them) and what the host needs to count a dispatch."""
+        import jax
+        # both programs update the pool in place: the cache argument is
+        # donated (whoever holds the old one holds a deleted buffer), and
+        # on a mesh every step hands the cache back as it lay, whatever
+        # the partitioner would have preferred for one call
+        self._jit = jax.jit if mesh is None else functools.partial(
+            jax.jit, out_shardings=(None, jax.tree.map(
+                lambda a: a.sharding, self._cache)))
 
         def _step(params, cache, tokens, positions, pts, active):
             return self.model.decode_step(params, cache, tokens,
@@ -269,44 +357,6 @@ class EngineCore:
             self.page_size, self.max_pages_per_seq)
         self._walk = [walk_counts(n, self._walk_block, self.page_size)
                       for n in range(self.max_pages_per_seq + 1)]
-        self.counters = {
-            "admitted": 0, "evictions": 0, "finished": 0, "tokens": 0,
-            "steps": 0,
-            # prompt tokens prefilled, and the same after padding to
-            # their bucket (what the prefill programs computed)
-            "prefill_tokens": 0, "prefill_padded_tokens": 0,
-            "prefill_programs": 0,      # prefill functions built
-            # decode dispatches, those whose attention was the paged
-            # kernel, and the lanes that held a sequence
-            "decode_steps": 0, "decode_kernel_steps": 0,
-            "decode_lane_steps": 0,
-            # cache positions those lanes held / the dispatches read, a
-            # layer whose cache is whole; and what the lanes' fixed parts
-            # cost the same dispatches (`_FIXED_COUNTERS`, summed from the
-            # model's `fixed_step_counts`): a window layer holds and reads
-            # a sequence's last positions only, a recurrent layer moves a
-            # state of one size
-            "kv_positions_live": 0, "kv_positions_read": 0,
-            # the shape of the kernel's walk over such a layer: the blocks
-            # the lanes' pages came in, and the positions its matmuls
-            # multiplied (the part of each block a lane holds, in pieces)
-            "kv_walk_blocks": 0, "kv_positions_attended": 0,
-            **dict.fromkeys(_FIXED_COUNTERS.values(), 0),
-            # and what the model counts on the device in a decode step,
-            # under the model's own names (`step_stats`: the counts come
-            # back with the step's tokens and are summed over the steps)
-            **dict.fromkeys(self.model.step_stats(self._cache), 0),
-            # decode steps dispatched before the one before was read;
-            # times what was in flight was read with nothing dispatched
-            # behind it (eviction, drain, no lane left to decode); and
-            # lane-steps whose token nobody got (a step past a stop
-            # token, a request cancelled with its step in flight)
-            "decode_steps_ahead": 0, "pipeline_flushes": 0,
-            "discarded_lane_steps": 0}
-        # the steps that took SLOW_STEP_S or more: wall seconds, when,
-        # the decode batch and the seconds in each phase
-        self.slow_steps: deque = deque(maxlen=16)
-        self._step_span = 0     # span id of the step running, or 0
 
     # ------------------------------------------------------ intake
     def submit(self, prompt: Sequence[int], max_tokens: int = 16,
@@ -406,7 +456,9 @@ class EngineCore:
                                           self.page_size)
             fn = self._jit(_pre, donate_argnums=(4,))
             self._prefill_fns[s_pad] = fn
-            self.counters["prefill_programs"] += 1
+        # every bucket's function is called `_pre`: a build that JAX
+        # reports from the call about to be made is this bucket's
+        self.record.bucket = s_pad
         return fn
 
     def _emit(self, events: List[dict], seq: _Seq, token: int) -> None:
@@ -473,16 +525,22 @@ class EngineCore:
         self.counters["steps"] += 1
         t0, t_mono_ns = _clock(), time.monotonic_ns()
         phases: Dict[str, float] = {}
+        # a program JAX builds on this thread inside the step is this
+        # engine's (`setup_record.py`); a dispatch tests `record.open`
+        self.record.watch(self.counters["steps"])
         with _sp.span(_sp.STEP, step=self.counters["steps"],
                       t_mono_ns=t_mono_ns) as ctx:
             self._step_span = ctx[1] if ctx else 0
             events, lanes = self._step(phases)
+        self.record.unwatch()
         wall = _clock() - t0
         if wall >= SLOW_STEP_S:
             self.slow_steps.append({
                 "step": self.counters["steps"], "wall_s": wall,
                 "t_mono_ns": t_mono_ns, "lanes": lanes,
-                "phases": phases})
+                "phases": phases,
+                # a first bucket, or a program built again: why it was slow
+                "builds": self.record.of_step(self.counters["steps"])})
         return events
 
     def _step(self, phases: Dict[str, float]):
@@ -524,6 +582,9 @@ class EngineCore:
                 self._running.append(seq)
                 self._tokens, first = self._place_fn(
                     self._tokens, np.int32(seq.lane), logits)
+                if self.record.open:    # JAX built a program in here
+                    self.record.close()
+                    c["prefill_programs"] = self.record.count("_pre")
                 first.copy_to_host_async()
                 self._firsts.append((seq, first))
                 c["admitted"] += 1
@@ -606,6 +667,8 @@ class EngineCore:
                 self.params, self._cache, self._tokens, *args)
             self._tokens, counts = self._next_fn(
                 logits, self.model.step_stats(self._cache))
+            if self.record.open:        # JAX built a program in here
+                self.record.close()
             # the copies to the host start as soon as the step has run
             for a in (self._tokens, *counts.values()):
                 a.copy_to_host_async()
@@ -697,6 +760,10 @@ class EngineCore:
                 "outstanding_tokens": self.outstanding_tokens(),
                 "queue_wait_p95": self.queue_wait_p95(),
                 "slow_steps": list(self.slow_steps),
+                # what the engine's start cost: seconds by phase (the
+                # spans' names) and a row for every program built
+                "setup": dict(self.record.phases),
+                "programs": [dict(row) for row in self.record.programs],
                 **self.counters}
 
 
@@ -718,53 +785,71 @@ class LLMEngine:
                  num_pages: int = 0, page_size: int = 0,
                  max_batch: int = 0, kv_budget_bytes: int = 0,
                  seed: int = 0):
+        # the start's cost stays readable when its spans have left the
+        # ring: `engine_stats()`'s `setup` and `programs`
+        record = SetupRecord(_serving_metrics())
+        with _SetupPhase(record, _sp.SETUP):
+            self._setup(record, model, weights, mesh, num_pages, page_size,
+                        max_batch, kv_budget_bytes, seed)
+
+    def _setup(self, record: SetupRecord, model, weights, mesh,
+               num_pages: int, page_size: int, max_batch: int,
+               kv_budget_bytes: int, seed: int) -> None:
         import jax
         from ray_tpu._private.config import CONFIG
         from ray_tpu.models import build_model, model_config
         from ray_tpu.util.compile_cache import use_compile_cache
         use_compile_cache()
-        config = model_config(model)
-        built_mesh = None
-        if mesh:
-            # the replica's own mesh, over as many of its devices as the
-            # axes name (a replica has no data axis unless asked for one)
-            from ray_tpu.parallel.mesh import AXIS_ORDER, MeshSpec
-            spec = MeshSpec(**{"dp": 1, **mesh})
-            sizes = [getattr(spec, a) for a in AXIS_ORDER]
-            n = len(jax.devices()) if -1 in sizes else math.prod(sizes)
-            if n > len(jax.devices()):
-                raise ValueError(
-                    f"mesh {mesh} needs {n} devices, this replica has "
-                    f"{len(jax.devices())}: grant it the chips "
-                    f"(ray_actor_options={{'num_tpus': {n}}})")
-            built_mesh = spec.build(jax.devices()[:n])
-        page_size = int(page_size or CONFIG.llm_page_size)
-        max_batch = int(max_batch or CONFIG.llm_max_batch)
-        if not num_pages and kv_budget_bytes:
-            from ray_tpu.serve.llm.kv_cache import pages_from_budget
-            tp = built_mesh.shape.get("tp", 1) if built_mesh else 1
-            num_pages = pages_from_budget(config, page_size,
-                                          kv_budget_bytes, tp_shards=tp,
-                                          sequences=max_batch)
-        model = build_model(config, built_mesh)
-        shardings = None
-        if built_mesh is not None:
-            # the replica's weights lie on its mesh as the training
-            # rules say (heads/mlp/vocab over tp, embed over fsdp) and
-            # the cache splits its kv heads over tp — which is what
-            # pages_from_budget(tp_shards=tp) above assumed
-            from ray_tpu.parallel.sharding import param_shardings
-            shardings = param_shardings(built_mesh,
-                                        model.param_logical_axes())
-        if weights is not None:
-            import ray_tpu
-            params = jax.device_put(ray_tpu.get(weights), shardings)
-        else:
-            params = jax.jit(model.init, out_shardings=shardings)(
-                jax.random.PRNGKey(seed))
+        with _SetupPhase(record, _sp.SETUP_MODEL):
+            config = model_config(model)
+            built_mesh = None
+            if mesh:
+                # the replica's own mesh, over as many of its devices as
+                # the axes name (a replica has no data axis unless asked
+                # for one)
+                from ray_tpu.parallel.mesh import AXIS_ORDER, MeshSpec
+                spec = MeshSpec(**{"dp": 1, **mesh})
+                sizes = [getattr(spec, a) for a in AXIS_ORDER]
+                n = (len(jax.devices()) if -1 in sizes
+                     else math.prod(sizes))
+                if n > len(jax.devices()):
+                    raise ValueError(
+                        f"mesh {mesh} needs {n} devices, this replica has "
+                        f"{len(jax.devices())}: grant it the chips "
+                        f"(ray_actor_options={{'num_tpus': {n}}})")
+                built_mesh = spec.build(jax.devices()[:n])
+            page_size = int(page_size or CONFIG.llm_page_size)
+            max_batch = int(max_batch or CONFIG.llm_max_batch)
+            if not num_pages and kv_budget_bytes:
+                from ray_tpu.serve.llm.kv_cache import pages_from_budget
+                tp = built_mesh.shape.get("tp", 1) if built_mesh else 1
+                num_pages = pages_from_budget(config, page_size,
+                                              kv_budget_bytes, tp_shards=tp,
+                                              sequences=max_batch)
+            model = build_model(config, built_mesh)
+            shardings = None
+            if built_mesh is not None:
+                # the replica's weights lie on its mesh as the training
+                # rules say (heads/mlp/vocab over tp, embed over fsdp) and
+                # the cache splits its kv heads over tp — which is what
+                # pages_from_budget(tp_shards=tp) above assumed
+                from ray_tpu.parallel.sharding import param_shardings
+                shardings = param_shardings(built_mesh,
+                                            model.param_logical_axes())
+        with _SetupPhase(record, _sp.SETUP_WEIGHTS) as span:
+            if weights is not None:
+                import ray_tpu
+                params = jax.device_put(ray_tpu.get(weights), shardings)
+            else:
+                record.watch(0)         # `init` is this engine's program
+                params = jax.jit(model.init, out_shardings=shardings)(
+                    jax.random.PRNGKey(seed))
+                record.close()
+                record.unwatch()
+            span.add(bytes=_tree_bytes(params))
         self.core = EngineCore(config, params, mesh=built_mesh,
                                num_pages=num_pages, page_size=page_size,
-                               max_batch=max_batch)
+                               max_batch=max_batch, record=record)
         self.incarnation = uuid.uuid4().hex[:8]
         self._lock = threading.Lock()        # core + buffers
         # rid -> {"toks": [...], "done", "reason", "err", "t_done",
@@ -778,6 +863,9 @@ class LLMEngine:
         self._failed: Optional[str] = None
         self._serve_stats = {"queue_wait_p95": 0.0,
                              "outstanding_tokens": 0}
+        # times the step thread found its lock held, and the seconds it
+        # then waited (`engine.lock_wait`)
+        self._lock_waits, self._lock_wait_s = 0, 0.0
         self._stop = threading.Event()
         self._kick = threading.Event()
         self._thread = threading.Thread(target=self._loop,
@@ -790,14 +878,16 @@ class LLMEngine:
         from ray_tpu._private.config import CONFIG
         _name_os_thread(self._thread.name)
         while not self._stop.is_set():
-            with self._lock:
-                busy = self.core.has_work
+            self._take_lock()
+            busy = self.core.has_work
+            self._lock.release()
             if not busy:
                 with _sp.span(_sp.WAIT):
                     self._kick.wait(0.05)
                 self._kick.clear()
                 continue
-            with self._lock:
+            self._take_lock()
+            try:
                 t0 = _clock()
                 try:
                     events = self.core.step()
@@ -808,11 +898,28 @@ class LLMEngine:
                 self._ingest(events)
                 if self._metrics:
                     self._metrics["step"].observe(_clock() - t0)
+            finally:
+                self._lock.release()
             # chaos pacing; at 0 (production) still a yield, or this
             # thread re-takes its lock before generate() and subscribers
             # waiting on it ever run
             with _sp.span(_sp.YIELD):
                 time.sleep(CONFIG.llm_step_delay_s)
+
+    def _take_lock(self) -> None:
+        """The step thread's acquisitions: the only things it does under
+        no other span. A lock that is free costs the try; one that a
+        caller's `generate()` / `cancel()`, `engine_stats()` or a
+        subscriber's backlog holds is waited for under a span and counted,
+        so idle time of the device under no span of the program is the
+        process standing still, not this wait."""
+        if self._lock.acquire(blocking=False):
+            return
+        t0 = _clock()
+        with _sp.span(_sp.LOCK_WAIT):
+            self._lock.acquire()
+        self._lock_waits += 1
+        self._lock_wait_s += _clock() - t0
 
     def _fail(self, err: str) -> None:          # holds self._lock
         """core.step() raised: every open request ends now with `err`,
@@ -944,6 +1051,8 @@ class LLMEngine:
             st = self.core.stats()
             in_cache = self.core.cache_stats()
         st.update(self.core.device_stats(in_cache))
+        st["lock_waits"] = self._lock_waits
+        st["lock_wait_s"] = self._lock_wait_s
         st["pid"] = os.getpid()
         st["failed"] = self._failed
         st["incarnation"] = self.incarnation

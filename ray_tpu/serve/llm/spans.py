@@ -9,11 +9,35 @@ from __future__ import annotations
 
 from ray_tpu.util.tracing import annotate
 
+# an engine's construction (`LLMEngine.__init__`), nested as listed; each
+# phase's seconds also stay in `engine_stats()["setup"]` and go to the
+# metrics plane (`ray_tpu_llm_setup_s`), under these names
+# (`setup_record.py`). A bare `EngineCore` writes its own three.
+SETUP = "engine.setup"
+SETUP_MODEL = "engine.setup.model"      # build_model, mesh and shardings
+# the engine's own `init` or the `device_put` of given weights; `bytes`
+SETUP_WEIGHTS = "engine.setup.weights"
+# `init_cache`; `bytes`, `num_pages`, `fixed_pages`
+SETUP_CACHE = "engine.setup.cache"
+# the rest of `EngineCore.__init__`: the jitted wrappers, the walk's table
+SETUP_PROGRAMS = "engine.setup.programs"
+# one program built (`init`, `_step`, `_next`, `_place`, a bucket's
+# `_pre`): from JAX's first sight of it to the return of the call that
+# dispatched it, inside the `engine.prefill` / `engine.decode_dispatch` /
+# `engine.setup.weights` that made the call. `program`, `bucket` (0: it
+# has none), `rebuild` (it had been built before) on both sinks; in the
+# recorder also `cache_hit`, `trace_s`, `lower_s`, `compile_s`, known when
+# it ends (the rows of `engine_stats()["programs"]`)
+BUILD = "engine.build_program"
 # the step thread, nested as listed. A step dispatches before it reads:
 # prefills, tables and the next decode step first, then the tokens of the
 # decode step the call before dispatched, so everything from the fetch to
 # the next dispatch runs beside a device step.
 WAIT = "engine.wait_for_work"       # idle: no request waiting or running
+# the step thread waits for the engine's lock, which a caller's
+# `generate()` / `cancel()`, `engine_stats()` or a subscriber's backlog
+# holds; written only where the lock was not free at once
+LOCK_WAIT = "engine.lock_wait"
 STEP = "engine.step"                # one EngineCore.step()
 # one admission: its prefill dispatched; carries `tokens`, `bucket` and
 # what the model says the prefill runs (`prefill_counts`: a model with

@@ -81,7 +81,10 @@ class annotate:
     Keyword attributes go to both (the recorder's `extra`, the
     annotation's arguments). Near-zero cost when tracing is disabled
     and no jax trace is active. `__enter__` returns the recorder's
-    (trace_id, span_id), or None while the recorder is off."""
+    (trace_id, span_id), or None while the recorder is off. What is
+    known only once the block has run (`add`) goes to the recorder,
+    which writes a span when it ends; the annotation took its arguments
+    when it opened."""
 
     __slots__ = ("_span", "_ta")
     kind = "user"           # the recorder's span kind
@@ -97,6 +100,13 @@ class annotate:
         if self._ta is not None:
             self._ta.__enter__()
         return ctx
+
+    def add(self, **attrs) -> None:
+        """Attributes for the recorder's span, before it ends."""
+        if self._span.extra is None:
+            self._span.extra = attrs
+        else:
+            self._span.extra.update(attrs)
 
     def __exit__(self, *exc) -> None:
         if self._ta is not None:

@@ -16,10 +16,12 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.attention import (ATTN_RESIDUAL_NAMES, flash_attention,
+                                   flash_attention_saveable)
 from ray_tpu.ops.dispatch import (compute_platform, kernel_mesh,
                                   mesh_platform, on_tpu, platform_pinned)
 from ray_tpu.ops.losses import softmax_cross_entropy
@@ -32,6 +34,25 @@ Params = Dict[str, Any]
 # Activation logical axes (all optional constraints; params use the
 # rules in parallel.sharding directly).
 _ACT_RULES_EXTRA = {"act_embed": None, "expert_capacity": None}
+
+
+# What `_layer` names for a checkpoint policy: q, k and v as the
+# attention call takes them (rotated and transposed, so that the backward
+# runs neither again), the stream after `x + attn @ wo`, and the MLP's two
+# matmul outputs. The attention output and its log-sum-exp are named by
+# `flash_attention_saveable` (ATTN_RESIDUAL_NAMES).
+ATTN_INPUT_NAMES = ("attn_q", "attn_k", "attn_v")
+ATTN_STREAM_NAME = "attn_stream"
+MLP_NAMES = ("mlp_gate", "mlp_up")
+
+# `TransformerConfig.remat_policy` -> the names a rematted layer keeps
+# (config.py has what each rung costs). No rung keeps the stream or the
+# MLP's names yet: they are there for the policy that has the memory.
+REMAT_SAVED_NAMES = {
+    "full": (),
+    "save_attn": ATTN_RESIDUAL_NAMES,
+    "save_attn_qkv": ATTN_INPUT_NAMES + ATTN_RESIDUAL_NAMES,
+}
 
 
 def _rules():
@@ -140,17 +161,28 @@ class Transformer:
         return axes
 
     # --------------------------------------------------------- forward
+    def _saved_names(self):
+        """The checkpoint names a rematted layer keeps for its backward."""
+        c = self.config
+        if not c.remat:
+            return ()
+        if c.remat_policy not in REMAT_SAVED_NAMES:
+            raise ValueError(
+                f"remat_policy {c.remat_policy!r}: one of "
+                f"{sorted(REMAT_SAVED_NAMES)}")
+        return REMAT_SAVED_NAMES[c.remat_policy]
+
     def _attention(self, q, k, v):
-        """Causal attention for one layer. `remat_policy="save_attn"`
-        exists to spare the backward a second run of the forward
-        kernel; off TPU there is no kernel to spare (attention is the
-        einsum reference), so there it is the same as "full"."""
+        """Causal attention for one layer. A policy that keeps the
+        attention output spares the backward a second run of the
+        forward kernel, which takes the call whose residuals carry
+        names; off TPU there is no kernel to spare (attention is the
+        einsum reference, recomputed from the q, k and v that are kept)."""
         c = self.config
         if (c.use_ring_attention and self.mesh is not None
                 and self.mesh.shape.get("sp", 1) > 1):
             return ring_attention_sharded(q, k, v, self.mesh, causal=True)
-        if c.remat and c.remat_policy == "save_attn" and on_tpu():
-            from ray_tpu.ops.attention import flash_attention_saveable
+        if ATTN_RESIDUAL_NAMES[0] in self._saved_names() and on_tpu():
             return flash_attention_saveable(
                 q, k, v, causal=True, block_q=c.attn_block_q,
                 block_k=c.attn_block_k, mesh=self.kernel_mesh)
@@ -203,10 +235,13 @@ class Transformer:
         k = k.transpose(0, 2, 1, 3)
         v = v.transpose(0, 2, 1, 3)
         q = self._constrain(q, ("batch", "heads", "seq", "head_dim"))
+        q, k, v = (checkpoint_name(a, name)
+                   for a, name in zip((q, k, v), ATTN_INPUT_NAMES))
         attn = self._attention(q, k, v)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, c.n_heads * hd)
         x = x + attn @ layer["wo"].astype(ad)
         x = self._constrain(x, ("batch", "seq", "act_embed"))
+        x = checkpoint_name(x, ATTN_STREAM_NAME)
 
         h = self._norm(x, layer["mlp_norm"])
         if c.moe_num_experts:
@@ -220,9 +255,10 @@ class Transformer:
             x = x + y
             return (self._constrain(x, ("batch", "seq", "act_embed")),
                     aux["moe_load_balance_loss"])
-        gate = jax.nn.silu(h @ layer["gate"].astype(ad))
-        up = h @ layer["up"].astype(ad)
-        mlp = self._constrain(gate * up, ("batch", "seq", "mlp"))
+        gate = checkpoint_name(h @ layer["gate"].astype(ad), MLP_NAMES[0])
+        up = checkpoint_name(h @ layer["up"].astype(ad), MLP_NAMES[1])
+        mlp = self._constrain(jax.nn.silu(gate) * up,
+                              ("batch", "seq", "mlp"))
         x = x + mlp @ layer["down"].astype(ad)
         return (self._constrain(x, ("batch", "seq", "act_embed")),
                 jnp.float32(0.0))
@@ -254,10 +290,9 @@ class Transformer:
         from ray_tpu.ops.rope import rope_cos_sin
         rope = rope_cos_sin(positions, c.head_dim, c.rope_theta)
 
-        remat_policy = None
-        if c.remat and c.remat_policy == "save_attn":
-            from ray_tpu.ops.attention import attn_remat_policy
-            remat_policy = attn_remat_policy()
+        saved = self._saved_names()
+        remat_policy = (jax.checkpoint_policies.save_only_these_names(*saved)
+                        if saved else None)
 
         def _checkpointed(body):
             if c.remat:

@@ -715,17 +715,12 @@ def flash_window_attention_kernel(q, k, v, window: int, sm_scale=None,
 # `_attn_from_saved` is the only differentiable op: its VJP runs the
 # Pallas backward straight from the saved residuals. Cotangents for
 # out/lse die at stop_gradient, so the forward kernel is never
-# differentiated or (with `save_only_these_names("attn_out","attn_lse")`)
-# re-executed. q/k/v are still rematerialised by the layer recompute —
-# that is three cheap matmuls + rope, not the attention kernel.
+# differentiated or (with `save_only_these_names(*ATTN_RESIDUAL_NAMES)`)
+# re-executed. q/k/v are the caller's to name: `models.transformer` does,
+# and its default policy keeps them too (three matmuls and the rotary a
+# layer that the backward then does not run again).
 
 ATTN_RESIDUAL_NAMES = ("attn_out", "attn_lse")
-
-
-def attn_remat_policy():
-    """Checkpoint policy saving exactly the flash-attention residuals."""
-    return jax.checkpoint_policies.save_only_these_names(
-        *ATTN_RESIDUAL_NAMES)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
@@ -758,7 +753,7 @@ def flash_attention_saveable(q: jax.Array, k: jax.Array, v: jax.Array,
                              block_q: int = 128, block_k: int = 128,
                              mesh=None) -> jax.Array:
     """Flash attention whose residuals survive `jax.checkpoint` when the
-    wrapping policy is `attn_remat_policy()` (see block comment above).
+    wrapping policy keeps ATTN_RESIDUAL_NAMES (see block comment above).
     Semantically identical to `flash_attention`'s kernel path; use
     inside rematted layer bodies."""
     if sm_scale is None:
@@ -771,8 +766,8 @@ def flash_attention_saveable(q: jax.Array, k: jax.Array, v: jax.Array,
     out, lse = _flash_fwd(lax.stop_gradient(q), lax.stop_gradient(k),
                           lax.stop_gradient(v), causal, sm_scale,
                           block_q, block_k, interpret, mesh)
-    out = checkpoint_name(out, "attn_out")
-    lse = checkpoint_name(lse, "attn_lse")
+    out = checkpoint_name(out, ATTN_RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, ATTN_RESIDUAL_NAMES[1])
     return _attn_from_saved(q, k, v, lax.stop_gradient(out),
                             lax.stop_gradient(lse), causal, sm_scale,
                             block_q, block_k, interpret, mesh)

@@ -83,7 +83,8 @@ def _mesh_fsdp_tp():
 
 
 @pytest.mark.parametrize("over", [
-    {}, {"remat": True, "remat_policy": "save_attn"}])
+    {}, {"remat": True, "remat_policy": "save_attn"},
+    {"remat": True}])       # the default rung: what the train cell runs
 def test_mesh_model_lowers_for_tpu(over):
     """GSPMD cannot partition a Mosaic kernel: before the shard_map
     wrappers this lowering died with 'Mosaic kernels cannot be

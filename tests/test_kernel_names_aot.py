@@ -94,6 +94,37 @@ def test_no_kernel_is_named_after_its_enclosing_call(compiled_text):
         & set(names)
 
 
+# ------------------------------------------------- the scanned trunk
+@pytest.mark.parametrize("policy,flash_forwards", [
+    (None, 1), ("save_attn_qkv", 1), ("save_attn", 1), ("full", 2)])
+def test_rematted_trunk_runs_the_flash_forward_once(
+        topo, no_compile_cache, policy, flash_forwards):
+    """`Transformer`'s scan over rematted layers, loss and gradient, for
+    one chip: the forward's while body holds one `flash_fwd`, and the
+    backward's holds another only where the policy keeps nothing
+    ("full"). Under `remat=True` alone (`policy` None) it is the kernel
+    whose output and log-sum-exp carry names, run once."""
+    from ray_tpu.models import Transformer, TransformerConfig
+    over = {} if policy is None else {"remat_policy": policy}
+    model = Transformer(TransformerConfig(
+        vocab_size=512, d_model=512, n_layers=4, n_heads=4, n_kv_heads=2,
+        d_ff=1024, max_seq_len=512, dtype="bfloat16",
+        param_dtype="bfloat16", remat=True, **over))
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 512), jnp.int32,
+                                            sharding=one_chip)}
+    with compute_platform("tpu"):
+        text = jax.jit(jax.value_and_grad(model.loss)).trace(
+            params, batch).lower().compile().as_text()
+    names = kernel_names(text)
+    assert names.count(attention.KERNEL_FWD) == flash_forwards
+    assert names.count(attention.KERNEL_BWD_DKDV) == 1
+    assert names.count(attention.KERNEL_BWD_DQ) == 1
+
+
 # ------------------------------------------------------ the decode step
 # a pool of the cells' 2048 pages: a small one the compiler would move
 # into fast memory whole, which no deployment's fits

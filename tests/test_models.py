@@ -1,4 +1,6 @@
 """Model zoo tests on the virtual 8-device mesh."""
+import collections
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -248,3 +250,109 @@ def test_moe_capacity_drops_tokens():
     assert np.isfinite(np.asarray(out)).all()
     assert float(aux["moe_dropped_fraction"]) > 0.1
     assert expert_capacity(64, 4, 2, 0.25) == 8
+
+
+# ------------------------------------- what a rematted layer keeps
+# GQA at widths that tell the projections apart: wq and wo (48, 48),
+# wk and wv (48, 24), gate and up (48, 80)
+_REMAT_CFG = dict(vocab_size=64, d_model=48, n_layers=2, n_heads=4,
+                  n_kv_heads=2, d_ff=80, max_seq_len=32, dtype="float32",
+                  param_dtype="float32")
+
+
+def _remat_batch():
+    return {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 16),
+                                         0, 64)}
+
+
+def _remat_model(policy):
+    """`policy` None: `remat=True` and nothing said about a policy."""
+    over = {} if policy is None else {"remat_policy": policy}
+    return Transformer(TransformerConfig(**_REMAT_CFG, remat=True, **over))
+
+
+@pytest.fixture(scope="module")
+def unrematted():
+    model = Transformer(TransformerConfig(**_REMAT_CFG, remat=False))
+    params = model.init(jax.random.PRNGKey(0))
+    return params, jax.value_and_grad(model.loss)(
+        params, _remat_batch())
+
+
+@pytest.mark.parametrize("policy", ["full", "save_attn", "save_attn_qkv",
+                                    None])
+def test_remat_policy_keeps_loss_and_gradients(unrematted, policy):
+    """A kept value is the value that would have been recomputed: every
+    rung, and the default, gives the loss and gradients of no remat."""
+    params, (loss0, grads0) = unrematted
+    loss, grads = jax.value_and_grad(_remat_model(policy).loss)(
+        params, _remat_batch())
+    np.testing.assert_allclose(float(loss), float(loss0), atol=1e-6)
+    for (path, g), g0 in zip(jax.tree_util.tree_leaves_with_path(grads),
+                             jax.tree.leaves(grads0)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(g0),
+                                   atol=1e-6, err_msg=str(path))
+
+
+def test_unknown_remat_policy_is_refused():
+    model = _remat_model("save_everything")
+    with pytest.raises(ValueError, match="save_attn_qkv"):
+        model.loss(model.init(jax.random.PRNGKey(0)),
+                   _remat_batch())
+
+
+def _walk(jaxpr, visit):
+    for eqn in jaxpr.eqns:
+        visit(eqn)
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _walk(sub, visit)
+
+
+def _layer_scans(policy):
+    """What the two scans over the layers hold, forward then backward:
+    the weight shapes of the dots of the form `activations @ weight` (a
+    backward's own dots contract other dimensions, so those in its scan
+    are the forward's, run again) and a count of every primitive."""
+    model = _remat_model(policy)
+    jaxpr = jax.make_jaxpr(jax.grad(model.loss))(
+        model.init(jax.random.PRNGKey(0)), _remat_batch())
+    scans = []
+    _walk(jaxpr.jaxpr, lambda e: e.primitive.name == "scan"
+          and scans.append(e))
+    assert [e.params["reverse"] for e in scans] == [False, True]
+    out = []
+    for scan in scans:
+        weights, prims = collections.Counter(), collections.Counter()
+
+        def visit(eqn):
+            prims[eqn.primitive.name] += 1
+            if (eqn.primitive.name == "dot_general"
+                    and eqn.params["dimension_numbers"]
+                    == (((2,), (0,)), ((), ()))):
+                weights[eqn.invars[1].aval.shape] += 1
+        _walk(scan.params["jaxpr"].jaxpr, visit)
+        out.append((weights, prims))
+    return out
+
+
+def test_default_remat_reruns_no_projection_rotation_or_transpose():
+    """Under the default the backward's scan holds no q, k or v
+    projection, neither rotation and none of the three transposes into
+    the kernel's layout, where "full" holds them all: it fails if a name
+    is dropped, or put on q or k before the rotary. What stays is what no
+    rung keeps: the output projection (the stream after it is named and
+    not kept), gate and up."""
+    (fwd, _), (again, prims) = _layer_scans(None)
+    (fwd_full, _), (again_full, prims_full) = _layer_scans("full")
+    layer = {(48, 48): 2, (48, 24): 2, (48, 80): 2, (80, 48): 1}
+    assert fwd == fwd_full == layer
+    assert again_full == {(48, 48): 2, (48, 24): 2, (48, 80): 2}
+    assert again == {(48, 48): 1, (48, 80): 2}
+    # a rotation ends in a concatenate; q, k and v are each turned to
+    # (b, h, s, hd)
+    assert prims_full["concatenate"] - prims["concatenate"] == 2
+    assert prims_full["transpose"] - prims["transpose"] == 3

@@ -126,8 +126,8 @@ def test_flash_gqa_backward():
 def test_flash_saveable_grads_and_remat_policy():
     """The remat-saveable path (named out/lse residuals) must produce the
     same gradients as the reference, standalone and under jax.checkpoint
-    with attn_remat_policy (the bench's save_attn configuration)."""
-    from ray_tpu.ops.attention import (attn_remat_policy,
+    with a policy that keeps them (`remat_policy="save_attn"`)."""
+    from ray_tpu.ops.attention import (ATTN_RESIDUAL_NAMES,
                                        flash_attention_saveable)
     b, h, s, d = 1, 2, 128, 32
     ks = jax.random.split(jax.random.PRNGKey(11), 3)
@@ -143,7 +143,8 @@ def test_flash_saveable_grads_and_remat_policy():
     rematted = jax.checkpoint(
         lambda *a: flash_attention_saveable(
             *a, causal=True, block_q=64, block_k=64),
-        policy=attn_remat_policy())
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *ATTN_RESIDUAL_NAMES))
     g_rm = jax.grad(lambda *a: jnp.sum(rematted(*a) ** 2),
                     argnums=(0, 1, 2))(q, k, v)
     for a, b_, c in zip(g_ref, g_sv, g_rm):

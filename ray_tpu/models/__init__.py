@@ -27,6 +27,13 @@ one size a sequence, `ops/gated_delta.py`) or full multi-head attention
 without rotary embedding, named layer by layer. What a model keeps of a
 sequence for ever (a ring, a state) it names to the engine as
 `fixed_pages`.
+
+A fifth: `ShortcutMLAMoE` (`models/shortcut_mla_moe.py`), double layers of
+two latent attentions and two dense feed-forwards with a routed
+feed-forward beside them that joins at the layer's end; its router is a
+softmax over experts and slots that compute nothing, and the layer is told
+which of the experts it holds. The latent attention is
+`models/latent.py`'s, which `MLAMoE` runs too.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
@@ -38,15 +45,19 @@ from ray_tpu.models.gqa_window_moe import (  # noqa: F401,E402
     GQAWindowMoE, GQAWindowMoEConfig)
 from ray_tpu.models.hybrid_delta import (  # noqa: F401,E402
     HybridDelta, HybridDeltaConfig)
+from ray_tpu.models.shortcut_mla_moe import (  # noqa: F401,E402
+    ShortcutMLAMoE, ShortcutMLAMoEConfig)
 
 
 # a dict of config fields names its class under "type"; without the key it
 # is the flagship decoder's
 CONFIG_TYPES = {"transformer": TransformerConfig, "mla_moe": MLAMoEConfig,
                 "gqa_window_moe": GQAWindowMoEConfig,
-                "hybrid_delta": HybridDeltaConfig}
+                "hybrid_delta": HybridDeltaConfig,
+                "shortcut_mla_moe": ShortcutMLAMoEConfig}
 MODEL_TYPES = {MLAMoEConfig: MLAMoE, GQAWindowMoEConfig: GQAWindowMoE,
-               HybridDeltaConfig: HybridDelta}
+               HybridDeltaConfig: HybridDelta,
+               ShortcutMLAMoEConfig: ShortcutMLAMoE}
 
 
 def model_config(model):

@@ -1,0 +1,265 @@
+"""Multi-head latent attention (MLA) as the classes that have it share it:
+`models.mla_moe.MLAMoE` (one attention a layer) and
+`models.shortcut_mla_moe.ShortcutMLAMoE` (two), so that each class's tests
+and cells guard the other's attention.
+
+With `x` the normed input of an attention and `a_q`, `a_kv` the config's two
+LoRA scales (1 where the published model has none):
+
+    c_q  = a_q RMSNorm(x W_qa)                  (q_lora_rank)
+    q    = c_q W_qb        -> heads of [q_nope | q_rope]
+    [c_kv | k_rope] = x W_kva;  c_kv = a_kv RMSNorm(c_kv)
+    k_rope = RoPE(k_rope)
+    [k_nope | v] a head = c_kv W_kvb;           q_rope = RoPE(q_rope)
+    scores = q . [k_nope | k_rope] / sqrt(nope + rope), causal softmax
+    o = concat_h(P v) W_o
+
+`a_q` multiplies the normed query latent (linear in it, so every head of
+`q` carries it) and `a_kv` the normed key-value latent **as the cache holds
+it**: a row is `[a_kv c_kv | k_rope | zeros]`, scaled once when it is
+written, so `k_nope` and `v` carry the factor and `k_rope` does not, in the
+expanded form (`W_kvb` of the scaled latent) and in the absorbed one (the
+scaled row read as key and as value) alike, and no step pays for it again.
+
+A pool row of the cache is one attention's `(pages, page_size, row_width)`;
+`attn_expanded` is the prefill's form (keys and values through the flash
+forward, which takes values narrower than keys) and `attn_absorbed` the
+decode step's (`ops.paged_attention.mla_paged_decode_attention`).
+"""
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Tuple
+
+import jax.numpy as jnp
+
+from ray_tpu.ops import paged_attention as _paged
+from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.norms import rms_norm_reference
+from ray_tpu.ops.rope import apply_rope_cached
+
+Params = Dict[str, Any]
+
+# prefill's flash blocks (keys are 192 or 256 wide: PERF.md section 4)
+ATTN_BLOCK = 128
+
+
+class LatentDims:
+    """What a config with the latent attention's fields (`n_heads`,
+    `q_lora_rank`, `kv_lora_rank`, `qk_nope_head_dim`, `qk_rope_head_dim`,
+    `v_head_dim`, `d_model`) derives from them."""
+
+    # the LoRA scales; a config whose model has them overrides these
+    q_lora_scale = 1.0
+    kv_lora_scale = 1.0
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row_width(self) -> int:
+        """A cache row: latent and rotary key, padded to whole lanes."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def activation_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def parameter_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+
+def attn_shapes(c, std: float, out_std: float
+                ) -> Dict[str, Tuple[tuple, float]]:
+    """(shape, init std) of one latent attention's leaves and of the norm
+    before it; std 0 means zeros (a norm scale, stored as w with the layer
+    multiplying by 1 + w)."""
+    e, H = c.d_model, c.n_heads
+    return {
+        "attn_norm": ((e,), 0.0),
+        "wq_a": ((e, c.q_lora_rank), std),
+        "q_norm": ((c.q_lora_rank,), 0.0),
+        "wq_b": ((c.q_lora_rank, H * c.qk_head_dim), std),
+        "wkv_a": ((e, c.kv_lora_rank + c.qk_rope_head_dim), std),
+        "kv_norm": ((c.kv_lora_rank,), 0.0),
+        "wkv_b": ((c.kv_lora_rank,
+                   H * (c.qk_nope_head_dim + c.v_head_dim)), std),
+        "wo": ((H * c.v_head_dim, e), out_std),
+    }
+
+
+class LatentAttention:
+    """The latent attention of `self.config` (a `LatentDims`); `layer` is
+    the dict of one attention's leaves (`attn_shapes`)."""
+
+    def _q(self, layer: Params, h):
+        """h (..., e) -> q (..., heads, nope + rope), not yet rotated."""
+        c = self.config
+        ad = c.activation_dtype
+        c_q = rms_norm_reference(h @ layer["wq_a"].astype(ad),
+                                 layer["q_norm"], c.norm_eps)
+        if c.q_lora_scale != 1.0:
+            c_q = c_q * jnp.asarray(c.q_lora_scale, ad)
+        q = c_q @ layer["wq_b"].astype(ad)
+        return q.reshape(*h.shape[:-1], c.n_heads, c.qk_head_dim)
+
+    def _latent(self, layer: Params, h, cos, sin):
+        """h (..., e) -> the cache's row parts: c_kv (..., latent) after
+        its norm (and its scale) and k_rope (..., rope) after the
+        rotation."""
+        c = self.config
+        ad = c.activation_dtype
+        kv = h @ layer["wkv_a"].astype(ad)
+        c_kv = kv[..., :c.kv_lora_rank]
+        if c.kv_lora_scale != 1.0:      # scaled in float32, rounded once
+            c_kv = (rms_norm_reference(c_kv.astype(jnp.float32),
+                                       layer["kv_norm"], c.norm_eps)
+                    * c.kv_lora_scale).astype(ad)
+        else:
+            c_kv = rms_norm_reference(c_kv, layer["kv_norm"], c.norm_eps)
+        k_rope = apply_rope_cached(kv[..., None, c.kv_lora_rank:], cos, sin)
+        return c_kv, k_rope[..., 0, :]
+
+    def _rows(self, c_kv, k_rope, dtype):
+        """The rows a cache page holds: [c_kv | k_rope | zeros]."""
+        c = self.config
+        pad = c.row_width - c.kv_lora_rank - c.qk_rope_head_dim
+        rows = jnp.concatenate([c_kv, k_rope], axis=-1).astype(dtype)
+        return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+
+    def _wkv_b(self, layer: Params):
+        """W_kvb as (latent, heads, nope + v)."""
+        c = self.config
+        return layer["wkv_b"].astype(c.activation_dtype).reshape(
+            c.kv_lora_rank, c.n_heads, c.qk_nope_head_dim + c.v_head_dim)
+
+    def _attn_expanded(self, layer: Params, h, cos, sin):
+        """Causal MLA over whole sequences, keys and values expanded from
+        the latent. h (b, s, e). Returns (attention output before W_o
+        (b, s, heads * v), c_kv, k_rope)."""
+        c = self.config
+        b, s, _ = h.shape
+        nope = c.qk_nope_head_dim
+        q = self._q(layer, h)                           # (b, s, H, qk)
+        q = jnp.concatenate(
+            [q[..., :nope], apply_rope_cached(q[..., nope:], cos, sin)],
+            axis=-1)
+        c_kv, k_rope = self._latent(layer, h, cos, sin)
+        kv = jnp.einsum("bsc,chd->bshd", c_kv, self._wkv_b(layer))
+        k = jnp.concatenate(
+            [kv[..., :nope], jnp.broadcast_to(
+                k_rope[:, :, None, :], (b, s, c.n_heads,
+                                        c.qk_rope_head_dim))], axis=-1)
+        v = kv[..., nope:]
+        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        out = flash_attention(qt, kt, vt, causal=True,
+                              sm_scale=1.0 / math.sqrt(c.qk_head_dim),
+                              block_q=ATTN_BLOCK, block_k=ATTN_BLOCK)
+        out = out.transpose(0, 2, 1, 3).reshape(
+            b, s, c.n_heads * c.v_head_dim)
+        return out, c_kv, k_rope
+
+    def _write_pages(self, pool, row: int, c_kv, k_rope, page_ids,
+                     page_size: int):
+        """A prefill's rows of one sequence (c_kv (s, latent), k_rope
+        (s, rope)) written into pool row `row` as whole pages, in place;
+        a page id of `num_pages` drops its page."""
+        n = page_ids.shape[0]
+        rows = self._rows(c_kv, k_rope, pool.dtype)
+        rows = jnp.pad(rows, ((0, n * page_size - rows.shape[0]), (0, 0)))
+        return pool.at[row, page_ids].set(
+            rows.reshape(n, page_size, self.config.row_width), mode="drop")
+
+    def _attn_absorbed(self, layer: Params, h, cos, sin, pool, row: int,
+                       wr_page, wr_slot, page_tables, lengths):
+        """One decode position a lane in the absorbed form: `q_lat = q_nope
+        W_UK^T`, scores `q_lat . c_kv + q_rope . k_rope`, `o_lat = P c_kv`,
+        `o = o_lat W_UV`, over pool row `row`, which first gets this
+        position's row (`wr_page` of `num_pages` writes nothing). h (B, e).
+        Returns (attention output before W_o (B, heads * v), pool)."""
+        c = self.config
+        ad = c.activation_dtype
+        nope, latent = c.qk_nope_head_dim, c.kv_lora_rank
+        sm_scale = 1.0 / math.sqrt(c.qk_head_dim)
+        pad = c.row_width - latent - c.qk_rope_head_dim
+        q = self._q(layer, h)                           # (B, H, qk)
+        c_kv, k_rope = self._latent(layer, h, cos, sin)
+        pool = pool.at[row, wr_page, wr_slot].set(
+            self._rows(c_kv, k_rope, pool.dtype), mode="drop")
+        w_kvb = self._wkv_b(layer)
+        q_lat = jnp.einsum("bhn,chn->bhc", q[..., :nope],
+                           w_kvb[..., :nope])
+        q_rope = apply_rope_cached(q[..., nope:], cos, sin)
+        q_row = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
+                        ((0, 0), (0, 0), (0, pad)))
+        o_lat = _paged.mla_paged_decode_attention(
+            q_row.astype(pool.dtype), pool, row, page_tables, lengths,
+            latent, sm_scale)
+        out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
+                         w_kvb[..., nope:])
+        return out.reshape(h.shape[0], -1), pool
+
+    # ------------------------------------------------ what an engine asks
+    @property
+    def pool_rows(self) -> int:
+        """Rows of the latent pool: one an attention."""
+        raise NotImplementedError
+
+    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
+                         dtype=None) -> int:
+        """Bytes one page costs (all pool rows): the rows as the pool holds
+        them, padding and all. The latent is shared by every head, so a
+        tp shard holds it whole."""
+        c = self.config
+        dt = jnp.dtype(dtype or c.activation_dtype)
+        return self.pool_rows * page_size * c.row_width * dt.itemsize
+
+    def fixed_pages(self, page_size: int) -> int:
+        """Nothing is kept of a sequence for ever: every attention's cache
+        grows with it (`kv_cache.PageAllocator`'s one class)."""
+        return 0
+
+    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
+        """Nothing to add to the engine's prefill span."""
+        return {}
+
+    def decode_attention(self, page_size: int, dtype=None) -> str:
+        """Which attention a `decode_step` traced here holds: the latent
+        kernel's name, or "einsum"."""
+        c = self.config
+        if _paged.mla_uses_kernel(c.row_width, c.kv_lora_rank, page_size,
+                                  dtype or c.activation_dtype):
+            return _paged.KERNEL_MLA_PAGED_DECODE
+        return "einsum"
+
+    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
+        """Pages a block of the latent kernel's walk holds over tables of
+        `max_pages`, asked what the kernel asks (one pool row's page)."""
+        return _paged.walk_block_pages(
+            self.cache_page_bytes(page_size) // self.pool_rows,
+            page_size, max_pages)
+
+
+def decode_lanes(positions, page_tables, active, num_pages: int,
+                 page_size: int):
+    """Where each lane of a decode step writes and how far it sees:
+    (wr_page, wr_slot, lengths). A lane that is inactive or whose page is
+    unassigned writes to page `num_pages`, which `mode="drop"` drops."""
+    my_page = jnp.take_along_axis(
+        page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
+    wr_page = jnp.where(active & (my_page >= 0), my_page, num_pages)
+    return wr_page, positions % page_size, jnp.where(active, positions + 1,
+                                                     0)
+
+
+def prefill_page_ids(page_table, true_len, s: int, num_pages: int,
+                     page_size: int):
+    """The pages a padded prompt of `s` positions writes: the table's
+    first ceil(s / page_size) entries, those wholly past `true_len`
+    replaced by `num_pages` (dropped)."""
+    n = -(-s // page_size)
+    page_ids = jnp.take(page_table, jnp.arange(n), mode="clip")
+    return jnp.where(jnp.arange(n) * page_size < true_len, page_ids,
+                     num_pages)

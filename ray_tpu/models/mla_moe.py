@@ -11,10 +11,12 @@ Every layer's attention is MLA. With `x` the normed input:
     scores = q . [k_nope | k_rope] / sqrt(nope + rope), causal softmax
     o = concat_h(P v) W_o
 
-`k_rope` is one for all heads. The first `first_k_dense_replace` layers
-have a SwiGLU feed-forward, the rest `models.moe.dropless_moe_ffn` (sigmoid
-scores in float32, top-k of score + bias, weights from the scores alone,
-normalised and scaled) plus `n_shared_experts` shared experts applied to
+`k_rope` is one for all heads; the attention's pieces are
+`models.latent.LatentAttention`'s, shared with `ShortcutMLAMoE`. The first
+`first_k_dense_replace` layers have a SwiGLU feed-forward, the rest
+`models.moe.dropless_moe_ffn` (scores in float32, sigmoid as published,
+top-k of score + bias, weights from the scores alone, normalised and
+scaled) plus `n_shared_experts` shared experts applied to
 every token. The layers are unlike, so they are held per layer (a list),
 not stacked, and every program unrolls them.
 
@@ -24,7 +26,7 @@ numbers against `heads * (qk + v)` uncompressed, padded with zeros to whole
 128-lanes (`row_width`; the padding is this layout's cost). The pool is
 `(layers, pages, page_size, row_width)` under the key `"kv"`, paged as
 `models.decode`'s is. `prefill` runs the expanded form above through
-`flash_attention` (keys and values are both `nope + rope = v_head_dim` wide
+`flash_attention` (keys `nope + rope` wide, values `v_head_dim`, the same
 in the published sizes; the cache never holds them) and writes whole pages
 in place. `decode_step` runs the absorbed form: `q_lat = q_nope W_UK^T`,
 scores `q_lat . c_kv + q_rope . k_rope`, `o_lat = P c_kv`, `o = o_lat W_UV`
@@ -50,25 +52,23 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.latent import (LatentAttention, LatentDims, attn_shapes,
+                                   decode_lanes, prefill_page_ids)
 from ray_tpu.models.moe import dropless_moe_ffn
-from ray_tpu.ops import paged_attention as _paged
-from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.losses import softmax_cross_entropy
-from ray_tpu.ops.norms import rms_norm, rms_norm_reference
-from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin
+from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.rope import rope_cos_sin
 
 Params = Dict[str, Any]
 Cache = Dict[str, Any]
 
-# prefill's flash blocks (keys and values are 256 wide: PERF.md section 4)
-ATTN_BLOCK = 128
 # what a decode step counts over its expert layers (`Cache["moe_step"]`);
 # the engine's counters take these names
 STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max")
 
 
 @dataclasses.dataclass(frozen=True)
-class MLAMoEConfig:
+class MLAMoEConfig(LatentDims):
     """Fields under the published keys' meanings (`config.json` of
     `glm4_moe_lite`); `head_dim` is not `d_model / n_heads` here."""
     vocab_size: int = 154880
@@ -96,35 +96,16 @@ class MLAMoEConfig:
     param_dtype: str = "bfloat16"
 
     def __post_init__(self):
+        # `route_topk` scores by softmax too (`ShortcutMLAMoE` does); this
+        # class's plain reference writes down the sigmoid alone
         if self.scoring_func != "sigmoid":
-            raise ValueError(f"scoring {self.scoring_func!r}: only the "
-                             f"published sigmoid scoring is built")
-        if self.qk_head_dim != self.v_head_dim:
             raise ValueError(
-                f"prefill runs keys ({self.qk_head_dim} wide) and values "
-                f"({self.v_head_dim}) through one flash kernel, which "
-                f"wants them alike")
-
-    @property
-    def qk_head_dim(self) -> int:
-        return self.qk_nope_head_dim + self.qk_rope_head_dim
-
-    @property
-    def row_width(self) -> int:
-        """A cache row: latent and rotary key, padded to whole lanes."""
-        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+                f"scoring {self.scoring_func!r}: this class has been held "
+                f"to its reference under sigmoid scoring only")
 
     @property
     def n_moe_layers(self) -> int:
         return max(0, self.n_layers - self.first_k_dense_replace)
-
-    @property
-    def activation_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def parameter_dtype(self):
-        return jnp.dtype(self.param_dtype)
 
 
 def tiny_mla_moe(vocab_size: int = 256) -> MLAMoEConfig:
@@ -139,7 +120,7 @@ def tiny_mla_moe(vocab_size: int = 256) -> MLAMoEConfig:
         dtype="float32", param_dtype="float32")
 
 
-class MLAMoE:
+class MLAMoE(LatentAttention):
     """Functional model bundle for one MLAMoEConfig: `init`, `apply` /
     `loss` (training graph), and what a serving engine asks a model for
     (`init_cache`, `prefill`, `decode_step`, `cache_page_bytes`,
@@ -157,21 +138,10 @@ class MLAMoE:
         """(shape, init std) of layer i's leaves; std 0 means zeros (a
         norm scale, stored as w with the layer multiplying by 1 + w)."""
         c = self.config
-        e, H = c.d_model, c.n_heads
+        e = c.d_model
         std = 0.02
         out_std = std / math.sqrt(2 * c.n_layers)
-        shapes = {
-            "attn_norm": ((e,), 0.0),
-            "wq_a": ((e, c.q_lora_rank), std),
-            "q_norm": ((c.q_lora_rank,), 0.0),
-            "wq_b": ((c.q_lora_rank, H * c.qk_head_dim), std),
-            "wkv_a": ((e, c.kv_lora_rank + c.qk_rope_head_dim), std),
-            "kv_norm": ((c.kv_lora_rank,), 0.0),
-            "wkv_b": ((c.kv_lora_rank,
-                       H * (c.qk_nope_head_dim + c.v_head_dim)), std),
-            "wo": ((H * c.v_head_dim, e), out_std),
-            "mlp_norm": ((e,), 0.0),
-        }
+        shapes = {**attn_shapes(c, std, out_std), "mlp_norm": ((e,), 0.0)}
         if i < c.first_k_dense_replace:
             shapes.update(gate=((e, c.d_ff), std), up=((e, c.d_ff), std),
                           down=((c.d_ff, e), out_std))
@@ -208,65 +178,6 @@ class MLAMoE:
     # --------------------------------------------------------- pieces
     def _norm(self, x, w):
         return rms_norm(x, w, self.config.norm_eps, None)
-
-    def _q(self, layer: Params, h):
-        """h (..., e) -> q (..., heads, nope + rope), not yet rotated."""
-        c = self.config
-        ad = c.activation_dtype
-        c_q = rms_norm_reference(h @ layer["wq_a"].astype(ad),
-                                 layer["q_norm"], c.norm_eps)
-        q = c_q @ layer["wq_b"].astype(ad)
-        return q.reshape(*h.shape[:-1], c.n_heads, c.qk_head_dim)
-
-    def _latent(self, layer: Params, h, cos, sin):
-        """h (..., e) -> the cache's row parts: c_kv (..., latent) after
-        its norm and k_rope (..., rope) after the rotation."""
-        c = self.config
-        ad = c.activation_dtype
-        kv = h @ layer["wkv_a"].astype(ad)
-        c_kv = rms_norm_reference(kv[..., :c.kv_lora_rank],
-                                  layer["kv_norm"], c.norm_eps)
-        k_rope = apply_rope_cached(kv[..., None, c.kv_lora_rank:], cos, sin)
-        return c_kv, k_rope[..., 0, :]
-
-    def _rows(self, c_kv, k_rope, dtype):
-        """The rows a cache page holds: [c_kv | k_rope | zeros]."""
-        c = self.config
-        pad = c.row_width - c.kv_lora_rank - c.qk_rope_head_dim
-        rows = jnp.concatenate([c_kv, k_rope], axis=-1).astype(dtype)
-        return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
-
-    def _wkv_b(self, layer: Params):
-        """W_kvb as (latent, heads, nope + v)."""
-        c = self.config
-        return layer["wkv_b"].astype(c.activation_dtype).reshape(
-            c.kv_lora_rank, c.n_heads, c.qk_nope_head_dim + c.v_head_dim)
-
-    def _attn_expanded(self, layer: Params, h, cos, sin):
-        """Causal MLA over whole sequences, keys and values expanded from
-        the latent. h (b, s, e). Returns (attention output before W_o
-        (b, s, heads * v), c_kv, k_rope)."""
-        c = self.config
-        b, s, _ = h.shape
-        nope = c.qk_nope_head_dim
-        q = self._q(layer, h)                           # (b, s, H, qk)
-        q = jnp.concatenate(
-            [q[..., :nope], apply_rope_cached(q[..., nope:], cos, sin)],
-            axis=-1)
-        c_kv, k_rope = self._latent(layer, h, cos, sin)
-        kv = jnp.einsum("bsc,chd->bshd", c_kv, self._wkv_b(layer))
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(
-                k_rope[:, :, None, :], (b, s, c.n_heads,
-                                        c.qk_rope_head_dim))], axis=-1)
-        v = kv[..., nope:]
-        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        out = flash_attention(qt, kt, vt, causal=True,
-                              sm_scale=1.0 / math.sqrt(c.qk_head_dim),
-                              block_q=ATTN_BLOCK, block_k=ATTN_BLOCK)
-        out = out.transpose(0, 2, 1, 3).reshape(
-            b, s, c.n_heads * c.v_head_dim)
-        return out, c_kv, k_rope
 
     def _ffn(self, layer: Params, x, valid=None):
         """Feed-forward of one layer on tokens x (T, e) after the norm.
@@ -342,39 +253,9 @@ class MLAMoE:
                          for name in STEP_COUNTS}})
         return make()
 
-    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
-                         dtype=None) -> int:
-        """Bytes one page costs (all layers): the rows as the pool holds
-        them, padding and all. The latent is shared by every head, so a
-        tp shard holds it whole."""
-        c = self.config
-        dt = jnp.dtype(dtype or c.activation_dtype)
-        return c.n_layers * page_size * c.row_width * dt.itemsize
-
-    def fixed_pages(self, page_size: int) -> int:
-        """Nothing is kept of a sequence for ever: every layer's cache
-        grows with it (`kv_cache.PageAllocator`'s one class)."""
-        return 0
-
-    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
-        """Nothing to add to the engine's prefill span."""
-        return {}
-
-    def decode_attention(self, page_size: int, dtype=None) -> str:
-        """Which attention a `decode_step` traced here holds: the latent
-        kernel's name, or "einsum"."""
-        c = self.config
-        if _paged.mla_uses_kernel(c.row_width, c.kv_lora_rank, page_size,
-                                  dtype or c.activation_dtype):
-            return _paged.KERNEL_MLA_PAGED_DECODE
-        return "einsum"
-
-    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
-        """Pages a block of the latent kernel's walk holds over tables of
-        `max_pages`, asked what the kernel asks (a layer's page of rows)."""
-        return _paged.walk_block_pages(
-            self.cache_page_bytes(page_size) // self.config.n_layers,
-            page_size, max_pages)
+    @property
+    def pool_rows(self) -> int:
+        return self.config.n_layers
 
     def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
         """What the last decode step counted: scalars still on the device,
@@ -403,17 +284,13 @@ class MLAMoE:
         cos, sin = rope_cos_sin(jnp.arange(s)[None], c.qk_rope_head_dim,
                                 c.rope_theta)
         valid = (jnp.arange(s) < true_len)[None]
-        n = -(-s // page_size)
-        page_ids = jnp.take(page_table, jnp.arange(n), mode="clip")
-        page_ids = jnp.where(jnp.arange(n) * page_size < true_len,
-                             page_ids, num_pages)
+        page_ids = prefill_page_ids(page_table, true_len, s, num_pages,
+                                    page_size)
         for i, layer in enumerate(params["layers"]):
             h = self._norm(x, layer["attn_norm"])
             attn, c_kv, k_rope = self._attn_expanded(layer, h, cos, sin)
-            rows = self._rows(c_kv[0], k_rope[0], pool.dtype)
-            rows = jnp.pad(rows, ((0, n * page_size - s), (0, 0)))
-            pool = pool.at[i, page_ids].set(
-                rows.reshape(n, page_size, c.row_width), mode="drop")
+            pool = self._write_pages(pool, i, c_kv[0], k_rope[0], page_ids,
+                                     page_size)
             x = x + attn @ layer["wo"].astype(ad)
             x, _ = self._block_ffn(layer, x, valid)
         x = self._norm(x, params["final_norm"])
@@ -431,40 +308,20 @@ class MLAMoE:
         (B, vocab) f32, cache) — donate the cache."""
         c = self.config
         ad = c.activation_dtype
-        nope, latent = c.qk_nope_head_dim, c.kv_lora_rank
         pool = cache["kv"]
-        num_pages = pool.shape[1]
-        B = tokens.shape[0]
         x = params["embed"].astype(ad)[tokens]                  # (B, e)
         cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
                                 c.rope_theta)              # (B, 1, rope/2)
-        my_page = jnp.take_along_axis(
-            page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
-        wr_page = jnp.where(active & (my_page >= 0), my_page, num_pages)
-        wr_slot = positions % page_size
-        lengths = jnp.where(active, positions + 1, 0)
-        sm_scale = 1.0 / math.sqrt(c.qk_head_dim)
-        pad = c.row_width - latent - c.qk_rope_head_dim
+        wr_page, wr_slot, lengths = decode_lanes(
+            positions, page_tables, active, pool.shape[1], page_size)
         load = cache["moe_load"]
         pairs = touched = load_max = jnp.int32(0)
         for i, layer in enumerate(params["layers"]):
             h = self._norm(x, layer["attn_norm"])
-            q = self._q(layer, h)                           # (B, H, qk)
-            c_kv, k_rope = self._latent(layer, h, cos, sin)
-            pool = pool.at[i, wr_page, wr_slot].set(
-                self._rows(c_kv, k_rope, pool.dtype), mode="drop")
-            w_kvb = self._wkv_b(layer)
-            q_lat = jnp.einsum("bhn,chn->bhc", q[..., :nope],
-                               w_kvb[..., :nope])
-            q_rope = apply_rope_cached(q[..., nope:], cos, sin)
-            q_row = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
-                            ((0, 0), (0, 0), (0, pad)))
-            o_lat = _paged.mla_paged_decode_attention(
-                q_row.astype(pool.dtype), pool, i, page_tables, lengths,
-                latent, sm_scale)
-            out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
-                             w_kvb[..., nope:])
-            x = x + out.reshape(B, -1) @ layer["wo"].astype(ad)
+            out, pool = self._attn_absorbed(
+                layer, h, cos, sin, pool, i, wr_page, wr_slot, page_tables,
+                lengths)
+            x = x + out @ layer["wo"].astype(ad)
             x, counts = self._block_ffn(layer, x, active)
             if counts is not None:
                 j = i - c.first_k_dense_replace

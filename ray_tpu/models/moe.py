@@ -2,12 +2,15 @@
 
 `moe_ffn` (below, first) is the capacity-factor layer `Transformer` trains
 with: top-k of a softmax, a fixed capacity an expert, overflow dropped.
-`dropless_moe_ffn` (at the end) is the serving path of `models.mla_moe`:
+`dropless_moe_ffn` (at the end) is the serving path of the classes with
+routed experts (`models.mla_moe`, `gqa_window_moe`, `shortcut_mla_moe`):
 no capacity and no dropped token at any imbalance — the (token, expert)
 pairs are sorted by expert and `ops.grouped_matmul` multiplies each
 expert's rows by its matrices, reading only experts that have rows. Its
-scoring is data of the caller's config (`route_topk`). A `simplicity` PR
-folds the two (ROADMAP D1).
+scoring (sigmoid or softmax, renormalised or not), the slots that compute
+nothing and the share of the experts it holds are data of the caller's
+config (`route_topk`, `dropless_moe_ffn`). A `simplicity` PR folds the two
+(ROADMAP D1).
 
 The capacity-factor layer: top-k routing + capacity-based dispatch.
 
@@ -28,6 +31,7 @@ dense-FFN parity plus sharding-invariance on an ep>1 mesh.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
@@ -132,18 +136,24 @@ MOE_PARAM_AXES = {
 
 
 # ------------------------------------------------------------ dropless
+SCORING = {"sigmoid": jax.nn.sigmoid,
+           "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 def route_topk(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
                top_k: int, norm_topk_prob: bool = True,
-               scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
-    """Sigmoid scoring in float32: choose the top-k of `score + bias` (the
+               scale: float = 1.0, scoring: str = "sigmoid"
+               ) -> Tuple[jax.Array, jax.Array]:
+    """Scores in float32, `scoring` a key of `SCORING` (each slot's sigmoid,
+    or a softmax over all slots): choose the top-k of `score + bias` (the
     bias moves the choice only), weigh by the score itself, divide by the
     chosen scores' sum where `norm_topk_prob`, times `scale`.
 
-    x (T, d); router_w (d, E); bias (E,). Returns (experts (T, k) int32,
-    weights (T, k) float32)."""
+    x (T, d); router_w (d, slots); bias (slots,). Returns (slots chosen
+    (T, k) int32, weights (T, k) float32)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = SCORING[scoring](logits)
     _, top_e = lax.top_k(scores + bias.astype(jnp.float32), top_k)
     top_w = jnp.take_along_axis(scores, top_e, axis=-1)
     if norm_topk_prob:
@@ -155,20 +165,50 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
                      gate_w: jax.Array, up_w: jax.Array, down_w: jax.Array,
                      *, top_k: int, norm_topk_prob: bool = True,
                      scale: float = 1.0,
-                     valid: Optional[jax.Array] = None
+                     valid: Optional[jax.Array] = None,
+                     scoring: str = "sigmoid", zero_experts: int = 0,
+                     held: Optional[Tuple[int, int]] = None
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """y_t = sum over the k experts token t chose of w_ti * E_i(x_t), every
+    """y_t = sum over the k slots token t chose of w_ti * E_i(x_t), every
     expert a SwiGLU; no token is dropped whatever the imbalance.
 
-    x (T, d); gate_w / up_w (E, d, f); down_w (E, f, d). `valid` (T,)
-    bool: tokens that are padding get no pair and a zero result. Returns
-    (y (T, d), {"pairs": pairs dispatched, "touched": experts with a pair,
-    "load": (E,) pairs an expert}), the counts int32 on the device."""
+    The router is `slots` wide: the experts, then `zero_experts` slots
+    that compute nothing, for which `E_i(x) = x` (a pair that chose one
+    adds `w x` and is no row of the grouped matmul). `held` = `(first,
+    count)` says which of the experts the given matrices are, one chip's
+    share of a layer divided over chips: the layer routes over all slots,
+    computes its own experts' rows and the identity part of every token it
+    has, and leaves out what the experts held elsewhere would add (their
+    pairs sort past the last held expert, as padding does). None: all.
+
+    x (T, d); gate_w / up_w (held, d, f); down_w (held, f, d). `valid`
+    (T,) bool: tokens that are padding get no pair and a zero result.
+    Returns (y (T, d), {"pairs": pairs given to held experts, "touched":
+    held experts with a pair, "load": (held,) pairs an expert,
+    "zero_pairs": pairs of slots that compute nothing, "away_pairs": pairs
+    of experts held elsewhere}), the counts int32 on the device."""
     from ray_tpu.ops.grouped_matmul import grouped_matmul
     T, d = x.shape
-    E = router_w.shape[-1]
+    experts = router_w.shape[-1] - zero_experts
+    first, E = held or (0, experts)
+    if E != gate_w.shape[0] or not 0 <= first <= experts - E:
+        raise ValueError(f"experts {first}..{first + E} of {experts} held, "
+                         f"{gate_w.shape[0]} given")
     top_e, top_w = route_topk(x, router_w, bias, top_k=top_k,
-                              norm_topk_prob=norm_topk_prob, scale=scale)
+                              norm_topk_prob=norm_topk_prob, scale=scale,
+                              scoring=scoring)
+    zero_pairs = away_pairs = jnp.int32(0)
+    identity = None
+    if zero_experts or E != experts:
+        live = jnp.broadcast_to(True if valid is None else valid[:, None],
+                                top_e.shape)
+        zero = live & (top_e >= experts)
+        here = (top_e >= first) & (top_e < first + E)
+        zero_pairs = jnp.sum(zero).astype(jnp.int32)
+        away_pairs = jnp.sum(live & ~zero & ~here).astype(jnp.int32)
+        if zero_experts:
+            identity = jnp.sum(jnp.where(zero, top_w, 0.0), axis=-1)
+        top_e = jnp.where(here, top_e - first, E)
     if valid is not None:           # padding sorts past the last expert
         top_e = jnp.where(valid[:, None], top_e, E)
     flat_e = top_e.reshape(-1)
@@ -183,6 +223,9 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     # back to token order: the inverse of the sort, then the k pairs of a
     # token summed in float32
     y = ys[jnp.argsort(order)].reshape(T, top_k, d).sum(axis=1)
+    if identity is not None:
+        y = y + identity[:, None] * x.astype(jnp.float32)
     return y.astype(x.dtype), {
         "pairs": jnp.sum(load), "touched": jnp.sum(load > 0).astype(
-            jnp.int32), "load": load}
+            jnp.int32), "load": load, "zero_pairs": zero_pairs,
+        "away_pairs": away_pairs}

@@ -1,0 +1,376 @@
+"""A decoder of double layers with a shortcut expert branch: two latent
+attentions and two dense feed-forwards in line, and beside them a routed
+feed-forward that reads the first half's normed stream and joins at the
+layer's end (the shortcut-connected mixture of experts of the
+LongCat-Flash family), on the ops `MLAMoE` runs on and behind the same
+serving engine.
+
+A layer, `x` its input and every `N` an RMSNorm with its own weight:
+
+    h  = x + MLA_0(N(x))
+    u  = N(h)
+    m  = MoE(u)                      # the shortcut branch: joins at the end
+    h  = h + FFN_0(u)                # dense SwiGLU
+    h  = h + MLA_1(N(h))
+    h  = h + FFN_1(N(h))
+    x' = h + m
+
+Nothing orders `m` against the second half but the data: no barrier is put
+between them, and the compiler places the branch where it likes.
+
+`MLA` is `models.latent.LatentAttention`'s, with the two LoRA scales this
+family has: `mla_scale_q_lora` multiplies the normed query latent by
+`sqrt(d_model / q_lora_rank)` **before `W_qb`** (1,536 numbers a token
+where the heads are 12,288), and `mla_scale_kv_lora` the normed key-value
+latent by `sqrt(d_model / kv_lora_rank)` **in the row the cache holds**:
+scaled once, in float32, when the row is written, so `k_nope` and `v`
+carry it and `k_rope` does not, and neither `W_kvb` nor a decode step
+multiplies by it again.
+
+`MoE` is `models.moe.dropless_moe_ffn`: a softmax in float32 over
+`n_routed_experts + zero_expert_num` slots, the top `num_experts_per_tok`
+of `score + bias` chosen, weights `routed_scaling_factor x score` and not
+renormalised; the last `zero_expert_num` slots compute nothing (a pair
+adds `w u`). **The layer is told which experts it holds**
+(`experts_held = (first, count)` of `n_routed_experts`): it routes over all
+slots, computes its own experts' rows, adds the identity part of every
+token and leaves out what the experts held elsewhere would add, which is
+one chip's part of a layer divided over chips, without the exchange.
+No shared expert, no leading dense layer.
+
+**The cache**: a layer owns two rows of the latent pool, `(2 x layers,
+pages, page_size, row_width)` under `"kv"`, row `2 i + j` attention `j` of
+layer `i`; both are written for every position, so a page costs twice what
+`MLAMoE`'s costs a layer. Beside it `"moe_load"` `(layers, held experts)`
+int32, pairs a held expert over decode steps, and `"moe_step"`, the last
+decode step's `STEP_COUNTS` summed over layers: `moe_pairs` (rows given to
+held experts), `moe_experts_touched`, `moe_load_max`, `moe_zero_pairs`
+(choices of a slot that computes nothing) and `moe_away_pairs` (choices of
+an expert held elsewhere), under the names the engine's counters take.
+
+Given a mesh the class refuses: the exchange over chips has not been built
+(PERF.md section 7).
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.latent import (LatentAttention, LatentDims, attn_shapes,
+                                   decode_lanes, prefill_page_ids)
+from ray_tpu.models.moe import SCORING, dropless_moe_ffn
+from ray_tpu.ops.losses import softmax_cross_entropy
+from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.rope import rope_cos_sin
+
+Params = Dict[str, Any]
+Cache = Dict[str, Any]
+
+# what a decode step counts over its layers (`Cache["moe_step"]`); the
+# engine's counters take these names
+STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
+               "moe_zero_pairs", "moe_away_pairs")
+
+
+def _step_counts(counts: Dict[str, jax.Array]) -> Tuple[jax.Array, ...]:
+    """One layer's `dropless_moe_ffn` counts in `STEP_COUNTS`' order."""
+    return (counts["pairs"], counts["touched"], jnp.max(counts["load"]),
+            counts["zero_pairs"], counts["away_pairs"])
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortcutMLAMoEConfig(LatentDims):
+    """Fields under the published keys' meanings (`config.json` of
+    LongCat-Flash); `n_layers` counts double layers, `n_routed_experts`
+    the experts of the whole layer and `experts_held` this chip's."""
+    vocab_size: int = 131072
+    d_model: int = 6144                     # hidden_size
+    n_layers: int = 28                      # num_layers (double layers)
+    n_heads: int = 64                       # num_attention_heads
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    d_ff: int = 12288                       # ffn_hidden_size (dense)
+    moe_intermediate_size: int = 2048       # expert_ffn_hidden_size
+    n_routed_experts: int = 512
+    zero_expert_num: int = 256              # slots that compute nothing
+    experts_held: Optional[Tuple[int, int]] = None   # (first, count); all
+    num_experts_per_tok: int = 12           # moe_topk
+    routed_scaling_factor: float = 6.0
+    norm_topk_prob: bool = False
+    scoring_func: str = "softmax"
+    mla_scale_q_lora: bool = True
+    mla_scale_kv_lora: bool = True
+    max_seq_len: int = 4096
+    rope_theta: float = 10000000.0
+    norm_eps: float = 1e-5
+    dtype: str = "bfloat16"
+    param_dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if self.scoring_func not in SCORING:
+            raise ValueError(f"scoring {self.scoring_func!r}: the router "
+                             f"scores by one of {sorted(SCORING)}")
+        first, count = self.held
+        if count < 1 or not 0 <= first <= self.n_routed_experts - count:
+            raise ValueError(f"experts_held {self.experts_held} of "
+                             f"{self.n_routed_experts} experts")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts this chip holds."""
+        return tuple(self.experts_held or (0, self.n_routed_experts))
+
+    @property
+    def router_slots(self) -> int:
+        return self.n_routed_experts + self.zero_expert_num
+
+    @property
+    def q_lora_scale(self) -> float:
+        return (math.sqrt(self.d_model / self.q_lora_rank)
+                if self.mla_scale_q_lora else 1.0)
+
+    @property
+    def kv_lora_scale(self) -> float:
+        return (math.sqrt(self.d_model / self.kv_lora_rank)
+                if self.mla_scale_kv_lora else 1.0)
+
+
+def tiny_shortcut_mla_moe(vocab_size: int = 256,
+                          experts_held=(4, 4)) -> ShortcutMLAMoEConfig:
+    """CI/debug model: every mechanism at a size the CPU runs in seconds
+    (values narrower than keys, a share of the experts that does not start
+    at 0, slots that compute nothing, both scales other than 1)."""
+    return ShortcutMLAMoEConfig(
+        vocab_size=vocab_size, d_model=64, n_layers=2, n_heads=4,
+        q_lora_rank=32, kv_lora_rank=96, qk_nope_head_dim=16,
+        qk_rope_head_dim=16, v_head_dim=8, d_ff=128,
+        moe_intermediate_size=32, n_routed_experts=16, zero_expert_num=8,
+        experts_held=experts_held, num_experts_per_tok=4, max_seq_len=128,
+        dtype="float32", param_dtype="float32")
+
+
+class ShortcutMLAMoE(LatentAttention):
+    """Functional model bundle for one ShortcutMLAMoEConfig: `init`,
+    `apply` / `loss` (training graph), and what a serving engine asks a
+    model for (`init_cache`, `prefill`, `decode_step`, `cache_page_bytes`,
+    `decode_attention`, `step_stats`, `cache_stats`)."""
+
+    def __init__(self, config: ShortcutMLAMoEConfig, mesh=None):
+        if mesh is not None:
+            raise NotImplementedError(
+                "ShortcutMLAMoE runs on one device and takes no mesh: the "
+                "experts' exchange over chips has not been built")
+        self.config = config
+
+    # ------------------------------------------------------------ init
+    def layer_shapes(self) -> Dict[str, Any]:
+        """(shape, init std) of a double layer's leaves; std 0 means zeros
+        (a norm scale, stored as w with the layer multiplying by 1 + w)."""
+        c = self.config
+        e, f, E = c.d_model, c.moe_intermediate_size, c.held[1]
+        std = 0.02
+        out_std = std / math.sqrt(4 * c.n_layers)
+        ffn = {"mlp_norm": ((e,), 0.0), "gate": ((e, c.d_ff), std),
+               "up": ((e, c.d_ff), std), "down": ((c.d_ff, e), out_std)}
+        return {
+            "attn": [attn_shapes(c, std, out_std) for _ in range(2)],
+            "ffn": [dict(ffn) for _ in range(2)],
+            "router": ((e, c.router_slots), std),
+            "router_bias": ((c.router_slots,), 0.0),
+            "moe_gate": ((E, e, f), std), "moe_up": ((E, e, f), std),
+            "moe_down": ((E, f, e), out_std)}
+
+    def init(self, key: jax.Array) -> Params:
+        c = self.config
+        pd = c.parameter_dtype
+        is_leaf = lambda x: isinstance(x, tuple)                # noqa: E731
+
+        def fill(key, shapes):
+            flat, treedef = jax.tree_util.tree_flatten(shapes,
+                                                       is_leaf=is_leaf)
+            keys = jax.random.split(key, len(flat))
+            return jax.tree_util.tree_unflatten(treedef, [
+                (jax.random.normal(k, shape, jnp.float32)
+                 * std).astype(pd) if std else jnp.zeros(shape, pd)
+                for k, (shape, std) in zip(keys, flat)])
+
+        keys = jax.random.split(key, c.n_layers + 1)
+        top = fill(keys[-1], {
+            "embed": ((c.vocab_size, c.d_model), 0.02),
+            "lm_head": ((c.d_model, c.vocab_size), 0.02)})
+        shapes = self.layer_shapes()
+        return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
+                "layers": [fill(keys[i], shapes)
+                           for i in range(c.n_layers)]}
+
+    # --------------------------------------------------------- pieces
+    def _norm(self, x, w):
+        return rms_norm(x, w, self.config.norm_eps, None)
+
+    def _dense(self, ffn: Params, u):
+        """SwiGLU of one half on the normed stream u (..., e)."""
+        ad = self.config.activation_dtype
+        gate = jax.nn.silu(u @ ffn["gate"].astype(ad))
+        return (gate * (u @ ffn["up"].astype(ad))) @ ffn["down"].astype(ad)
+
+    def _experts(self, layer: Params, u, valid=None):
+        """The shortcut branch on the normed stream u (..., e): this
+        chip's experts' part and the identity part. Returns (m, counts)."""
+        c = self.config
+        m, counts = dropless_moe_ffn(
+            u.reshape(-1, u.shape[-1]), layer["router"],
+            layer["router_bias"], layer["moe_gate"], layer["moe_up"],
+            layer["moe_down"], top_k=c.num_experts_per_tok,
+            norm_topk_prob=c.norm_topk_prob, scale=c.routed_scaling_factor,
+            valid=None if valid is None else valid.reshape(-1),
+            scoring=c.scoring_func, zero_experts=c.zero_expert_num,
+            held=c.held)
+        return m.reshape(u.shape), counts
+
+    def _layer(self, layer: Params, x, attend, valid=None):
+        """One double layer on the stream x (..., e); `attend(j, h)` is
+        attention `j` of the layer on its normed input, before `W_o`.
+        Returns (x', the expert branch's counts)."""
+        ad = self.config.activation_dtype
+        a0, a1 = layer["attn"]
+        f0, f1 = layer["ffn"]
+        h = x + attend(0, self._norm(x, a0["attn_norm"])) @ a0["wo"].astype(
+            ad)
+        u = self._norm(h, f0["mlp_norm"])
+        m, counts = self._experts(layer, u, valid)
+        h = h + self._dense(f0, u)
+        h = h + attend(1, self._norm(h, a1["attn_norm"])) @ a1["wo"].astype(
+            ad)
+        h = h + self._dense(f1, self._norm(h, f1["mlp_norm"]))
+        return h + m, counts
+
+    # --------------------------------------------------------- forward
+    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens (b, s) -> hidden states after the final norm."""
+        c = self.config
+        b, s = tokens.shape
+        x = params["embed"].astype(c.activation_dtype)[tokens]
+        cos, sin = rope_cos_sin(jnp.broadcast_to(jnp.arange(s), (b, s)),
+                                c.qk_rope_head_dim, c.rope_theta)
+        for layer in params["layers"]:
+            x, _ = self._layer(
+                layer, x, lambda j, h, layer=layer: self._attn_expanded(
+                    layer["attn"][j], h, cos, sin)[0])
+        return self._norm(x, params["final_norm"])
+
+    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
+        x = self.hidden(params, tokens)
+        head = params["lm_head"].astype(self.config.activation_dtype)
+        return (x @ head).astype(jnp.float32)
+
+    def loss(self, params: Params, batch: Dict[str, jax.Array]):
+        """Causal LM loss of batch["tokens"] (b, s), as `MLAMoE.loss`."""
+        tokens = batch["tokens"]
+        mask = batch.get("loss_mask")
+        logits = self.apply(params, tokens)[:, :-1]
+        if mask is not None:
+            mask = mask[:, 1:]
+        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
+        return loss
+
+    # ------------------------------------------------ what an engine asks
+    @property
+    def pool_rows(self) -> int:
+        return 2 * self.config.n_layers
+
+    def init_cache(self, num_pages: int, page_size: int,
+                   dtype=None) -> Cache:
+        c = self.config
+        dt = dtype or c.activation_dtype
+        shape = (self.pool_rows, num_pages, page_size, c.row_width)
+        make = jax.jit(lambda: {
+            "kv": jnp.zeros(shape, dt),
+            "moe_load": jnp.zeros((c.n_layers, c.held[1]), jnp.int32),
+            "moe_step": {name: jnp.zeros((), jnp.int32)
+                         for name in STEP_COUNTS}})
+        return make()
+
+    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
+        """What the last decode step counted: scalars still on the device,
+        by the names the engine's counters take. The engine fetches them
+        with the step's tokens."""
+        return cache["moe_step"]
+
+    def cache_stats(self, cache: Cache) -> Dict[str, Any]:
+        """For `EngineCore.device_stats()`: pairs a held expert since the
+        cache was made, by layer."""
+        return {"moe_load": jax.device_get(cache["moe_load"]).tolist()}
+
+    def prefill(self, params: Params, tokens: jax.Array, true_len,
+                page_table: jax.Array, cache: Cache,
+                page_size: int) -> Tuple[jax.Array, Cache]:
+        """One padded prompt, as `MLAMoE.prefill`: the expanded attention,
+        both of a layer's pool rows written as whole pages in place
+        (donate the cache). Padding past `true_len` is given to no expert
+        and adds no identity part. Returns (last-position logits (vocab,)
+        f32, cache)."""
+        c = self.config
+        ad = c.activation_dtype
+        pool = cache["kv"]
+        s = tokens.shape[0]
+        x = params["embed"].astype(ad)[tokens][None]            # (1, s, e)
+        cos, sin = rope_cos_sin(jnp.arange(s)[None], c.qk_rope_head_dim,
+                                c.rope_theta)
+        valid = (jnp.arange(s) < true_len)[None]
+        page_ids = prefill_page_ids(page_table, true_len, s, pool.shape[1],
+                                    page_size)
+        for i, layer in enumerate(params["layers"]):
+            def attend(j, h):
+                nonlocal pool
+                out, c_kv, k_rope = self._attn_expanded(layer["attn"][j], h,
+                                                        cos, sin)
+                pool = self._write_pages(pool, 2 * i + j, c_kv[0],
+                                         k_rope[0], page_ids, page_size)
+                return out
+            x, _ = self._layer(layer, x, attend, valid)
+        x = self._norm(x, params["final_norm"])
+        last = jnp.take(x[0], true_len - 1, axis=0)
+        logits = (last @ params["lm_head"].astype(ad)).astype(jnp.float32)
+        return logits, {**cache, "kv": pool}
+
+    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
+                    positions: jax.Array, page_tables: jax.Array,
+                    active: jax.Array,
+                    page_size: int) -> Tuple[jax.Array, Cache]:
+        """Advance a padded batch by one token each, as
+        `MLAMoE.decode_step`, both attentions in the absorbed form.
+        Inactive lanes write nothing, are given to no expert and add no
+        identity part. Returns (logits (B, vocab) f32, cache) — donate the
+        cache."""
+        c = self.config
+        ad = c.activation_dtype
+        pool = cache["kv"]
+        x = params["embed"].astype(ad)[tokens]                  # (B, e)
+        cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
+                                c.rope_theta)              # (B, 1, rope/2)
+        wr_page, wr_slot, lengths = decode_lanes(
+            positions, page_tables, active, pool.shape[1], page_size)
+        load = cache["moe_load"]
+        sums = [jnp.int32(0)] * len(STEP_COUNTS)
+        for i, layer in enumerate(params["layers"]):
+            def attend(j, h):
+                nonlocal pool
+                out, pool = self._attn_absorbed(
+                    layer["attn"][j], h, cos, sin, pool, 2 * i + j, wr_page,
+                    wr_slot, page_tables, lengths)
+                return out
+            x, counts = self._layer(layer, x, attend, active)
+            load = load.at[i].add(counts["load"])
+            sums = [a + n for a, n in zip(sums, _step_counts(counts))]
+        x = self._norm(x, params["final_norm"])
+        logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
+        return logits, {"kv": pool, "moe_load": load,
+                        "moe_step": dict(zip(STEP_COUNTS, sums))}

@@ -15,7 +15,9 @@ cross-check/fallback path (`_flash_bwd_xla`).
 Layout: (batch, num_heads, seq, head_dim). GQA supported: K/V may have
 fewer heads (num_kv_heads must divide num_heads) — the kernel maps query
 head h to kv head h // (num_heads // num_kv_heads) in the BlockSpec
-index map, no materialised repeat.
+index map, no materialised repeat. The forward takes values of another
+width than the keys (the output is as wide as the values); the backward
+kernels do not.
 
 The public `flash_attention` compiles the kernels whenever the target
 platform is a TPU (`ops.dispatch.on_tpu`), never the interpreter; off
@@ -189,6 +191,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                mesh=None):
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]
+    dv = v.shape[-1]        # values may be narrower than keys (forward only)
     if h % kvh:
         raise ValueError(
             f"num_heads ({h}) must be a multiple of num_kv_heads ({kvh})")
@@ -214,21 +217,21 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                          lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
+            pl.BlockSpec((1, 1, block_k, dv),
                          lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, 8, block_q),
                          lambda b_, h_, i, j: (b_, h_, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, 8, sq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),     # acc
+            pltpu.VMEM((block_q, dv), jnp.float32),    # acc
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max
             pltpu.VMEM((block_q, 128), jnp.float32),   # running sum
         ],
@@ -627,6 +630,10 @@ def _flash_bwd_rule(causal, sm_scale, block_q, block_k, interpret, mesh,
                     res, g):
     do, _g_lse = g  # lse cotangent dropped by design (see _flash docstring)
     q, k, v, out, lse = res
+    if v.shape[-1] != q.shape[-1]:
+        raise NotImplementedError(
+            f"the flash backward wants keys ({q.shape[-1]} wide) and values "
+            f"({v.shape[-1]}) alike: only the forward takes unlike widths")
     return _flash_bwd_pallas(q, k, v, out, lse, do, causal, sm_scale,
                              block_q, block_k, interpret, mesh)
 
@@ -654,7 +661,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Dispatching entry point: the compiled Pallas kernels when the
     target platform is a TPU, the einsum reference elsewhere.
 
-    Shapes: q (b, h, s, d); k/v (b, kvh, s, d), kvh | h. `mesh`: the
+    Shapes: q (b, h, s, d); k (b, kvh, s, d) and v (b, kvh, s, dv), kvh |
+    h; `dv` may differ from `d` in the forward (latent attention's values
+    are narrower than its keys), the backward wants them alike. `mesh`: the
     mesh of more than one device the operands are sharded over
     (`ops.dispatch.kernel_mesh`), or None. `window`: causal attention in
     which a query sees its last `window` keys, itself among them; forward

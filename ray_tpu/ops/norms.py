@@ -22,6 +22,9 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.ops.dispatch import activation_spec, on_tpu, shard_kernel
 
 _BLOCK_ROWS = 256
+# the most numbers a block holds: 256 rows up to 4,096 wide; a wider row
+# gets fewer (160 at 6,144, where 256 rows pass the kernel's 16 MB of VMEM)
+_BLOCK_NUMBERS = _BLOCK_ROWS * 4096
 # its name in a device trace, and the scope that keeps a transform's
 # wrapping off it (see ops/attention.py, KERNEL_FWD)
 KERNEL_RMS_FWD = "rms_norm_fwd"
@@ -50,7 +53,8 @@ def _rms_fwd_pallas(x2d: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     # Rows are independent, so a ragged last block is harmless: what it
     # reads past the end it also writes past the end, and that is
     # dropped.
-    block_rows = min(rows, _BLOCK_ROWS)
+    block_rows = min(rows, _BLOCK_ROWS,
+                     max(16, _BLOCK_NUMBERS // d // 16 * 16))
     grid = (pl.cdiv(rows, block_rows),)
     call = pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),

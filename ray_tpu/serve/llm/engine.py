@@ -239,9 +239,13 @@ class EngineCore:
                 self.num_pages, self.page_size,
                 **({"fixed_pages": self.alloc.fixed_pages} if self._fixed
                    else {}))
+            # a model whose pool has a row an attention says how many
+            # (`pool_rows`: two a layer where a layer has two attentions)
+            rows = getattr(self.model, "pool_rows", None)
             span.add(bytes=_tree_bytes(self._cache),
                      num_pages=self.num_pages,
-                     fixed_pages=self.alloc.fixed_pages)
+                     fixed_pages=self.alloc.fixed_pages,
+                     **({} if rows is None else {"pool_rows": rows}))
         self._waiting: deque = deque()
         # the sequences that hold a lane, oldest admission first
         self._running: List[_Seq] = []

@@ -17,7 +17,8 @@ SETUP = "engine.setup"
 SETUP_MODEL = "engine.setup.model"      # build_model, mesh and shardings
 # the engine's own `init` or the `device_put` of given weights; `bytes`
 SETUP_WEIGHTS = "engine.setup.weights"
-# `init_cache`; `bytes`, `num_pages`, `fixed_pages`
+# `init_cache`; `bytes`, `num_pages`, `fixed_pages`, and `pool_rows` where
+# the model's pool has a row an attention (the latent caches)
 SETUP_CACHE = "engine.setup.cache"
 # the rest of `EngineCore.__init__`: the jitted wrappers, the walk's table
 SETUP_PROGRAMS = "engine.setup.programs"
@@ -58,7 +59,12 @@ DISPATCH = "engine.decode_dispatch"
 # waits for the tokens of the step before (and this call's prefills), with
 # the step just dispatched queued behind them on the device
 FETCH = "engine.fetch_tokens"
-EMIT = "engine.emit"                # carries the counts of the step it emits
+# carries the counts of the step it emits, under the model's own names
+# (`step_stats`): `moe_pairs`, `moe_experts_touched`, `moe_load_max`, and
+# from a layer that holds a share of its experts `moe_zero_pairs` (choices
+# of a slot that computes nothing) and `moe_away_pairs` (of an expert held
+# elsewhere)
+EMIT = "engine.emit"
 INGEST = "engine.ingest"
 PUBLISH = "stream.publish"          # child of ingest: a step's frames
 YIELD = "engine.yield"              # the lock released between steps

@@ -67,8 +67,10 @@ def test_reduced_keys_are_listed_with_their_published_values(entry):
     assert set(cfg["published"]) == set(cfg["reduced"])
     for key in cfg["reduced"]:
         assert cfg[key] != cfg["published"][key], key
-        # a cut is of depth, context or count; never of a width
-        assert not key.endswith(("_dim", "_rank", "_size")), key
+        # a cut is of depth, context or count (layers, experts held, rows
+        # of the vocabulary); never of a width
+        assert key == "vocab_size" or not key.endswith(
+            ("_dim", "_rank", "_size")), key
     dep = cfg["deployment"]
     assert dep["chips"] in (1, 4)
     if "context_limit" in dep:
@@ -147,7 +149,7 @@ def test_metric_has_a_reader_and_lists_cells_that_exist(entry):
 
 
 def test_a_fifth_of_the_cells_may_take_four_chips_and_none_does():
-    assert len(CELLS) == 6 and not [c for c in CELLS if c["chips"] != 1]
+    assert len(CELLS) == 7 and not [c for c in CELLS if c["chips"] != 1]
     assert len({(c["config"], c["traffic"]) for c in CELLS}) == len(CELLS)
 
 
@@ -265,6 +267,110 @@ def test_new_metrics_leave_the_line_where_there_is_nothing_to_read(
                            {}, {}),
         model=modelcfg.load_model(dense), cfg=dense,
         sizes=modelcfg.load_model(dense).sizes(dense),
+        _spans=spans.Reading(plain, {}, 0.0))
+    assert metric(name)(run) is None
+    run.update(trace=None, _spans=None)
+    assert metric(name)(run) is None
+
+
+# --------------------------------------------- PR 44's metric readers
+LONGCAT = "longcat-flash-chat-1chip"
+SHARE_METRICS = [
+    "step.attn_latent_ms.reason4k", "step.moe_gmm_ms.reason4k",
+    "moe.zero_pairs_share.reason4k", "moe.pairs_per_held_expert.reason4k",
+    "moe.experts_touched_share.reason4k", "moe.load_max_over_mean.reason4k",
+    "kernel.flash_mla_roofline.reason4k"]
+
+
+@pytest.fixture()
+def traced_share_run():
+    """Two decode steps of 12 ms from t = 0 and t = 0.03: in a step 8
+    latent kernels of 0.15 ms and 12 grouped matmuls of 0.2 ms; between
+    them a prefill of 700 tokens with 8 flash forwards of 0.5 ms and three
+    grouped matmuls of its own."""
+    cfg = modelcfg.load_config(LONGCAT)
+    model = modelcfg.load_model(cfg)
+    ops, modules = [], []
+    for t0 in (0.0, 0.03):
+        modules.append(E("jit__step(7)", t0, 0.012))
+        for i in range(8):
+            ops.append(_kernel("mla_paged_decode_attn", i,
+                               t0 + 1.4e-3 * i, 0.15e-3))
+        for i in range(12):
+            ops.append(_kernel("moe_gmm", i, t0 + 0.9e-3 * i + 0.3e-3,
+                               0.2e-3))
+    modules.append(E("jit__pre(9)", 0.013, 0.015))
+    ops += [_kernel("flash_fwd", i, 0.014 + 1e-3 * i, 0.5e-3)
+            for i in range(8)]
+    ops += [_kernel("moe_gmm", 90 + j, 0.023 + 1e-3 * j, 0.9e-3)
+            for j in range(3)]
+    ops.sort(key=lambda e: e.start)
+    modules.sort(key=lambda e: e.start)
+    host = [E(spans.PREFILL, 0.0125, 1e-4, {"tokens": 700, "bucket": 1024})]
+    for t0 in (0.0, 0.03):
+        host.append(E(spans.DISPATCH, t0, 1e-4, {
+            "lanes": 32, "live_positions": 44800, "read_positions": 45000}))
+        host.append(E("engine.emit", t0 + 0.013, 1e-4, {
+            "moe_pairs": 30, "moe_experts_touched": 24, "moe_load_max": 9,
+            "moe_zero_pairs": 500, "moe_away_pairs": 1006}))
+    host.sort(key=lambda e: e.start)
+    return {"trace": xplane.Trace({0: modules}, {0: ops}, {}, {}),
+            "model": model, "sizes": model.sizes(cfg), "cfg": cfg,
+            "peaks": PEAKS["TPU v5 lite"], "result": {"traced": {}},
+            "_spans": spans.Reading(host, {}, 0.0)}
+
+
+def test_share_metrics_read_kernels_and_the_five_counts(traced_share_run):
+    run = traced_share_run
+    assert metric("step.attn_latent_ms.reason4k")(run) == pytest.approx(
+        8 * 0.15)
+    # the prefill's grouped matmuls fall in no step
+    assert metric("step.moe_gmm_ms.reason4k")(run) == pytest.approx(12 * 0.2)
+    # 32 lanes x 12 choices x 4 layers a step, of three kinds
+    assert 2 * (30 + 500 + 1006) == 2 * 32 * 12 * 4
+    assert metric("moe.zero_pairs_share.reason4k")(run) == pytest.approx(
+        100 * 500 / 1536)
+    assert metric("moe.pairs_per_held_expert.reason4k")(run) == \
+        pytest.approx(60 / (16 * 4 * 2))
+    assert metric("moe.experts_touched_share.reason4k")(run) == \
+        pytest.approx(100 * 48 / (16 * 4 * 2))
+    assert metric("moe.load_max_over_mean.reason4k")(run) == pytest.approx(
+        18 * 16 / 60)
+    need = run["model"].flash_prefill_call(run["sizes"], 700)
+    want = 100 * max(need["flops"] / 197e12,
+                     need["bytes"] / 819e9) / (8 * 0.5e-3)
+    assert metric("kernel.flash_mla_roofline.reason4k")(run) == \
+        pytest.approx(want, rel=1e-6)
+    assert 0 < want < 100
+    # the accepted readers the cell is listed for read this model's calls
+    need = run["model"].mla_decode_call(run["sizes"], 89600, 64)
+    assert metric("kernel.mla_decode_roofline.batch32")(run) == \
+        pytest.approx(100 * (need["bytes"] / 819e9) / (16 * 0.15e-3),
+                      rel=1e-6)
+    need = run["model"].moe_gmm_call(run["sizes"], 60, 48)
+    assert metric("kernel.moe_gmm_roofline.batch32")(run) == pytest.approx(
+        100 * (need["bytes"] / 819e9) / (24 * 0.2e-3), rel=1e-6)
+
+
+@pytest.mark.parametrize("name", SHARE_METRICS)
+def test_share_metrics_leave_the_line_where_there_is_nothing_to_read(
+        traced_share_run, traced_glm_run, name):
+    """The parent's program on this PR's benchmark files (no such span
+    attribute), a model that holds every expert, an untraced run: None,
+    and nothing raised."""
+    run = dict(traced_glm_run)
+    if name in ("step.attn_latent_ms.reason4k", "step.moe_gmm_ms.reason4k"):
+        assert metric(name)(run) is not None    # GLM's step has the kernels
+    else:
+        assert metric(name)(run) is None
+    run = dict(traced_share_run)
+    plain = [E(spans.DISPATCH, 0.0, 1e-4, {"lanes": 8, "live_positions": 9,
+                                            "read_positions": 16}),
+             E("engine.emit", 0.01, 1e-4, {})]
+    run.update(
+        trace=xplane.Trace({0: [E("jit__step(7)", 0.0, 0.01)]},
+                           {0: [E("%fusion.1 = bf16[8] fusion()", 0, 1e-3)]},
+                           {}, {}),
         _spans=spans.Reading(plain, {}, 0.0))
     assert metric(name)(run) is None
     run.update(trace=None, _spans=None)

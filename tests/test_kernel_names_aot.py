@@ -405,3 +405,43 @@ def test_hybrid_delta_programs_hold_their_kernels_and_alias_the_state(
     assert cache["state"].shape == (1, 33, 96, 5760)
     nbytes = sum(a.size * a.dtype.itemsize for a in cache.values())
     assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+
+
+# -------------------------------------- the fifth architecture's step
+def _compile_shortcut_mla_moe(devices, which: str):
+    """`ShortcutMLAMoE`'s decode step or 2048-token prefill (the reason4k
+    cell's largest bucket): one double layer at the published widths of
+    LongCat-Flash-Chat (64 heads, latent 512 + 64, keys 192 wide and values
+    128, norms 6,144 wide), 16 of 512 experts of 6144 x 2048 held, the
+    router 768 wide."""
+    from ray_tpu.models.shortcut_mla_moe import (ShortcutMLAMoE,
+                                                 ShortcutMLAMoEConfig)
+    model = ShortcutMLAMoE(ShortcutMLAMoEConfig(
+        vocab_size=1024, n_layers=1, experts_held=(0, 16),
+        max_seq_len=2048))
+    compiled, cache = _compile_served(
+        devices, model, which, lambda: model.init_cache(PAGES, PAGE),
+        "mla_paged_decode_attn")
+    return compiled, cache["kv"].shape
+
+
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_shortcut_mla_moe_programs_hold_their_kernels_by_name(
+        which, topo, no_compile_cache):
+    from ray_tpu.ops import grouped_matmul
+    compiled, pool = _compile_shortcut_mla_moe(topo.devices, which)
+    names = kernel_names(compiled.as_text())
+    # gate, up and down of the held experts; two attentions a layer; a
+    # norm before each attention and feed-forward, and the last
+    assert names.count(grouped_matmul.KERNEL_GMM) == 3
+    assert names.count(norms.KERNEL_RMS_FWD) == 5
+    if which == "step":
+        assert names.count(paged_attention.KERNEL_MLA_PAGED_DECODE) == 2
+        assert attention.KERNEL_FWD not in names
+    else:       # the expanded form, keys 192 wide and values 128
+        assert names.count(attention.KERNEL_FWD) == 2
+        assert paged_attention.KERNEL_MLA_PAGED_DECODE not in names
+    # a layer owns two rows of the pool, both updated in place
+    assert pool == (2, PAGES, PAGE, 640)
+    nbytes = 2 * pool[0] * pool[1] * pool[2] * pool[3]
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes

@@ -27,27 +27,34 @@ class TransformerConfig:
     dtype: str = "bfloat16"               # activation/compute dtype
     param_dtype: str = "float32"
     remat: bool = True                    # checkpoint each layer in scan
-    # What a checkpointed layer keeps for its backward beside its input
-    # (`transformer.REMAT_SAVED_NAMES`), cheapest rung first; bytes a
-    # token and layer in 2-byte activations, h = n_heads * head_dim,
-    # kv = kv_heads * head_dim:
-    #   "full"          nothing: the backward runs the layer's forward
-    #                   again (less `down`). 0 bytes.
-    #   "save_attn"     flash attention's output and log-sum-exp, so the
-    #                   forward kernel runs once: 2 h + 4 n_heads (8,320
-    #                   at Mistral-7B's widths).
-    #   "save_attn_qkv" those and q, k and v as the kernel takes them, so
-    #                   the backward reruns neither the kernel nor the
-    #                   three projections, rotations and transposes:
-    #                   2 (2 h + 2 kv) + 4 n_heads (20,608).
-    # The default is the dearest rung because a policy is fixed when the
-    # step is traced, where the model cannot see what memory the
-    # optimizer leaves, and these values spare most time a byte (0.03-
-    # 0.04 ms a MB on a v5e). The next dearest are named and kept by no
-    # rung: the stream after attention (2 d_model, the output projection,
-    # 0.025 ms a MB) and the MLP's `up` and gate (2 d_ff each, 0.023).
-    # Whoever trains at memory's edge asks for "full".
-    remat_policy: str = "save_attn_qkv"
+    # What a checkpointed layer keeps for its backward beside its input:
+    # a prefix of `transformer.REMAT_LADDER`, cheapest rung first. Bytes a
+    # token and layer, a = bytes of an activation (2 in bf16; the figure
+    # in brackets is Mistral-7B's in bf16), h = n_heads * head_dim, kv =
+    # kv_heads * head_dim:
+    #   "full"                nothing: the backward runs the layer's
+    #                         forward again (less `down`). 0.
+    #   "save_attn"           flash attention's output and log-sum-exp:
+    #                         the forward kernel runs once.
+    #                         a h + 4 n_heads (8,320).
+    #   "save_attn_qkv"       + q, k and v as the kernel takes them: no
+    #                         second projection, rotation or transpose.
+    #                         + a (h + 2 kv) (20,608).
+    #   "save_attn_stream"    + the stream after attention: no second
+    #                         `attn @ wo`. + a d_model (28,800). A MoE
+    #                         layer's ladder ends here.
+    #   "save_attn_stream_up" + the MLP's `h @ up`. + a d_ff (57,472).
+    #   "save_matmuls"        + `h @ gate`: what the backward still runs
+    #                         again is bound by bytes and cheap (two
+    #                         rms_norm, silu(gate) * up, the residual
+    #                         additions). + a d_ff (86,144).
+    # "auto" takes, when a step is traced, the dearest rung whose kept
+    # bytes a device (tokens a device x bytes above x n_layers) fit
+    # `transformer.REMAT_KEPT_BYTES_BUDGET`, a quarter of a v5e's 16 GiB,
+    # and never less than "save_attn_qkv". `Transformer.remat_plan(tokens)`
+    # says which rung that is and what it keeps; a named rung is taken as
+    # named. Whoever trains at memory's edge names a rung.
+    remat_policy: str = "auto"
     use_ring_attention: bool = False      # seq-parallel attention (sp axis)
     # >0 with a pp>1 mesh: run the layer stack as a GPipe microbatch
     # pipeline over the pp axis (parallel/pipeline.py). Bubble fraction

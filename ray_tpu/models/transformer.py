@@ -45,14 +45,66 @@ ATTN_INPUT_NAMES = ("attn_q", "attn_k", "attn_v")
 ATTN_STREAM_NAME = "attn_stream"
 MLP_NAMES = ("mlp_gate", "mlp_up")
 
-# `TransformerConfig.remat_policy` -> the names a rematted layer keeps
-# (config.py has what each rung costs). No rung keeps the stream or the
-# MLP's names yet: they are there for the policy that has the memory.
-REMAT_SAVED_NAMES = {
-    "full": (),
-    "save_attn": ATTN_RESIDUAL_NAMES,
-    "save_attn_qkv": ATTN_INPUT_NAMES + ATTN_RESIDUAL_NAMES,
-}
+# `TransformerConfig.remat_policy`: a rung keeps a prefix of this one tuple
+# of names, most time spared a byte first (config.py has what each rung
+# costs); a layer that lacks a name (a MoE layer has neither of the MLP's)
+# keeps nothing under it.
+REMAT_LADDER = (ATTN_RESIDUAL_NAMES + ATTN_INPUT_NAMES
+                + (ATTN_STREAM_NAME, MLP_NAMES[1], MLP_NAMES[0]))
+REMAT_RUNGS = {"full": 0, "save_attn": 2, "save_attn_qkv": 5,
+               "save_attn_stream": 6, "save_attn_stream_up": 7,
+               "save_matmuls": 8}
+REMAT_SAVED_NAMES = {rung: REMAT_LADDER[:n]
+                     for rung, n in REMAT_RUNGS.items()}
+# The policy that names no rung takes the dearest one whose kept bytes a
+# device fit the budget below, and never less than the floor.
+REMAT_AUTO = "auto"
+REMAT_FLOOR = "save_attn_qkv"
+# A quarter of a v5e's 16 GiB: beside the weights, gradients and optimizer
+# state of a step that fills the chip, that is what the records show
+# fitting (the train cell keeps 3.53 GB on the top rung and its step peaks
+# at 15.2 of the chip's 16.9e9 bytes). A constant and no probe of the
+# device: a traced program may not depend on the host it is traced on.
+REMAT_KEPT_BYTES_BUDGET = 4 * 2 ** 30
+
+
+def remat_kept_bytes(config: TransformerConfig, rung: str,
+                     tokens: int) -> int:
+    """Bytes a device keeps for the backward under `rung` beside every
+    layer's input: `tokens` tokens a device through all the layers."""
+    c = config
+    a = c.activation_dtype.itemsize
+    h, kv = c.n_heads * c.head_dim, c.kv_heads * c.head_dim
+    mlp = 0 if c.moe_num_experts else a * c.d_ff
+    per_name = dict(zip(REMAT_LADDER, (
+        a * h, 4 * c.n_heads,           # attention's output, float32 lse
+        a * h, a * kv, a * kv,          # q, k, v
+        a * c.d_model, mlp, mlp)))
+    return (sum(per_name[n] for n in REMAT_SAVED_NAMES[rung])
+            * tokens * c.n_layers)
+
+
+def resolve_remat_rung(config: TransformerConfig, tokens: int) -> str:
+    """The rung `config.remat_policy` means for `tokens` tokens a device:
+    itself where it names one, else the dearest whose kept bytes fit
+    REMAT_KEPT_BYTES_BUDGET, from REMAT_FLOOR up."""
+    policy = config.remat_policy
+    if policy in REMAT_SAVED_NAMES:
+        return policy
+    if policy != REMAT_AUTO:
+        raise ValueError(
+            f"remat_policy {policy!r}: {REMAT_AUTO!r} or one of "
+            f"{list(REMAT_RUNGS)}")
+    rungs = list(REMAT_RUNGS)
+    rung = REMAT_FLOOR
+    for dearer in rungs[rungs.index(REMAT_FLOOR) + 1:]:
+        kept = remat_kept_bytes(config, dearer, tokens)
+        # a rung that adds no byte names what this layer does not have
+        if (kept > REMAT_KEPT_BYTES_BUDGET
+                or kept == remat_kept_bytes(config, rung, tokens)):
+            break
+        rung = dearer
+    return rung
 
 
 def _rules():
@@ -161,28 +213,38 @@ class Transformer:
         return axes
 
     # --------------------------------------------------------- forward
-    def _saved_names(self):
-        """The checkpoint names a rematted layer keeps for its backward."""
-        c = self.config
-        if not c.remat:
-            return ()
-        if c.remat_policy not in REMAT_SAVED_NAMES:
-            raise ValueError(
-                f"remat_policy {c.remat_policy!r}: one of "
-                f"{sorted(REMAT_SAVED_NAMES)}")
-        return REMAT_SAVED_NAMES[c.remat_policy]
+    def remat_plan(self, batch_tokens: int):
+        """(rung, kept bytes a device) of a rematted step over a batch of
+        `batch_tokens` tokens, without tracing it. Tokens divide over the
+        mesh axes that split batch and sequence; tp and pp divide some
+        of the values and are not credited, which counts high."""
+        per_device = batch_tokens
+        if self.mesh is not None:
+            rules = _rules()
+            shards = math.prod(self.mesh.shape.get(axis, 1) for axis in
+                               (*rules["batch"], rules["seq"]))
+            per_device = -(-batch_tokens // shards)
+        rung = resolve_remat_rung(self.config, per_device)
+        return rung, remat_kept_bytes(self.config, rung, per_device)
 
-    def _attention(self, q, k, v):
+    def _saved_names(self, batch_tokens: int):
+        """The checkpoint names a rematted layer keeps for its backward."""
+        if not self.config.remat:
+            return ()
+        return REMAT_SAVED_NAMES[self.remat_plan(batch_tokens)[0]]
+
+    def _attention(self, q, k, v, saved=()):
         """Causal attention for one layer. A policy that keeps the
-        attention output spares the backward a second run of the
-        forward kernel, which takes the call whose residuals carry
-        names; off TPU there is no kernel to spare (attention is the
-        einsum reference, recomputed from the q, k and v that are kept)."""
+        attention output (`saved` holds its name) spares the backward a
+        second run of the forward kernel, which takes the call whose
+        residuals carry names; off TPU there is no kernel to spare
+        (attention is the einsum reference, recomputed from the q, k and
+        v that are kept)."""
         c = self.config
         if (c.use_ring_attention and self.mesh is not None
                 and self.mesh.shape.get("sp", 1) > 1):
             return ring_attention_sharded(q, k, v, self.mesh, causal=True)
-        if ATTN_RESIDUAL_NAMES[0] in self._saved_names() and on_tpu():
+        if ATTN_RESIDUAL_NAMES[0] in saved and on_tpu():
             return flash_attention_saveable(
                 q, k, v, causal=True, block_q=c.attn_block_q,
                 block_k=c.attn_block_k, mesh=self.kernel_mesh)
@@ -216,8 +278,9 @@ class Transformer:
         onehot = self._constrain(onehot, ("batch", "seq", "vocab"))
         return onehot @ table
 
-    def _layer(self, x, layer: Params, rope):
-        """One block; returns (x, moe_aux_loss) — 0.0 for dense FFN."""
+    def _layer(self, x, layer: Params, rope, saved=()):
+        """One block; returns (x, moe_aux_loss) — 0.0 for dense FFN.
+        `saved`: the names the enclosing checkpoint keeps."""
         c = self.config
         ad = c.activation_dtype
         b, s, e = x.shape
@@ -237,7 +300,7 @@ class Transformer:
         q = self._constrain(q, ("batch", "heads", "seq", "head_dim"))
         q, k, v = (checkpoint_name(a, name)
                    for a, name in zip((q, k, v), ATTN_INPUT_NAMES))
-        attn = self._attention(q, k, v)
+        attn = self._attention(q, k, v, saved)
         attn = attn.transpose(0, 2, 1, 3).reshape(b, s, c.n_heads * hd)
         x = x + attn @ layer["wo"].astype(ad)
         x = self._constrain(x, ("batch", "seq", "act_embed"))
@@ -290,7 +353,7 @@ class Transformer:
         from ray_tpu.ops.rope import rope_cos_sin
         rope = rope_cos_sin(positions, c.head_dim, c.rope_theta)
 
-        saved = self._saved_names()
+        saved = self._saved_names(b * s)
         remat_policy = (jax.checkpoint_policies.save_only_these_names(*saved)
                         if saved else None)
 
@@ -324,7 +387,7 @@ class Transformer:
                 rope_mb = (cos[:xm.shape[0]], sin[:xm.shape[0]])
 
                 def sbody(carry, layer):
-                    y, _lb = self._layer(carry, layer, rope_mb)
+                    y, _lb = self._layer(carry, layer, rope_mb, saved)
                     return y, None
                 out, _ = lax.scan(_checkpointed(sbody), xm, stage_layers)
                 return out
@@ -336,7 +399,7 @@ class Transformer:
 
         def body(carry, layer):
             x, aux = carry
-            x, lb = self._layer(x, layer, rope)
+            x, lb = self._layer(x, layer, rope, saved)
             return (x, aux + lb), None
 
         (x, moe_aux), _ = lax.scan(_checkpointed(body),

@@ -95,34 +95,67 @@ def test_no_kernel_is_named_after_its_enclosing_call(compiled_text):
 
 
 # ------------------------------------------------- the scanned trunk
-@pytest.mark.parametrize("policy,flash_forwards", [
-    (None, 1), ("save_attn_qkv", 1), ("save_attn", 1), ("full", 2)])
-def test_rematted_trunk_runs_the_flash_forward_once(
-        topo, no_compile_cache, policy, flash_forwards):
-    """`Transformer`'s scan over rematted layers, loss and gradient, for
-    one chip: the forward's while body holds one `flash_fwd`, and the
-    backward's holds another only where the policy keeps nothing
-    ("full"). Under `remat=True` alone (`policy` None) it is the kernel
-    whose output and log-sum-exp carry names, run once."""
+@pytest.fixture(scope="module")
+def trunk_text(topo, no_compile_cache):
+    """`Transformer`'s scan over rematted layers, loss and gradient,
+    compiled for one chip under a `remat_policy` (None: `remat=True` and
+    no policy named); one compile a policy."""
     from ray_tpu.models import Transformer, TransformerConfig
-    over = {} if policy is None else {"remat_policy": policy}
-    model = Transformer(TransformerConfig(
-        vocab_size=512, d_model=512, n_layers=4, n_heads=4, n_kv_heads=2,
-        d_ff=1024, max_seq_len=512, dtype="bfloat16",
-        param_dtype="bfloat16", remat=True, **over))
     one_chip = SingleDeviceSharding(topo.devices[0])
-    params = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
-    batch = {"tokens": jax.ShapeDtypeStruct((2, 512), jnp.int32,
-                                            sharding=one_chip)}
-    with compute_platform("tpu"):
-        text = jax.jit(jax.value_and_grad(model.loss)).trace(
-            params, batch).lower().compile().as_text()
+    texts = {}
+
+    def compiled(policy):
+        if policy in texts:
+            return texts[policy]
+        over = {} if policy is None else {"remat_policy": policy}
+        model = Transformer(TransformerConfig(
+            vocab_size=512, d_model=512, n_layers=4, n_heads=4,
+            n_kv_heads=2, d_ff=1024, max_seq_len=512, dtype="bfloat16",
+            param_dtype="bfloat16", remat=True, **over))
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+        batch = {"tokens": jax.ShapeDtypeStruct((2, 512), jnp.int32,
+                                                sharding=one_chip)}
+        with compute_platform("tpu"):
+            texts[policy] = jax.jit(jax.value_and_grad(model.loss)).trace(
+                params, batch).lower().compile().as_text()
+        return texts[policy]
+    return compiled
+
+
+def matmuls(text: str) -> int:
+    """The program's matmuls: on the chip a dot is a `convolution`."""
+    return len(re.findall(r"= [^\n]* convolution\(", text))
+
+
+@pytest.mark.parametrize("policy,flash_forwards,matmuls_spared", [
+    (None, 1, 6), ("save_matmuls", 1, 6), ("save_attn_stream_up", 1, 5),
+    ("save_attn_stream", 1, 4), ("save_attn_qkv", 1, 3),
+    ("save_attn", 1, 0), ("full", 2, 0)])
+def test_rematted_trunk_runs_the_flash_forward_once(
+        trunk_text, policy, flash_forwards, matmuls_spared):
+    """The forward's while body holds one `flash_fwd`, and the backward's
+    holds another only where the policy keeps nothing ("full"). Against
+    "full", whose backward runs the layer's six matmuls before `down`
+    again, a rung that keeps q, k and v spares three, and each rung above
+    one more: the output projection, `up`, gate; nothing else of the
+    program holds a matmul that a policy moves. Under `remat=True` alone
+    (`policy` None) the program is the top rung's at these shapes."""
+    text = trunk_text(policy)
     names = kernel_names(text)
     assert names.count(attention.KERNEL_FWD) == flash_forwards
     assert names.count(attention.KERNEL_BWD_DKDV) == 1
     assert names.count(attention.KERNEL_BWD_DQ) == 1
+    assert matmuls(trunk_text("full")) - matmuls(text) == matmuls_spared
+    if policy is None:
+        # every instruction, less what holds this file's lines: the call
+        # stacks and the kernels' payloads
+        def instructions(t):
+            return re.sub(r",? (metadata|backend_config)=[^\n]*", "",
+                          t[t.index("\n\n", t.index("\nStackFrames\n")):])
+        assert instructions(text) == instructions(trunk_text("save_matmuls"))
 
 
 # ------------------------------------------------------ the decode step

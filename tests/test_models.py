@@ -1,5 +1,6 @@
 """Model zoo tests on the virtual 8-device mesh."""
 import collections
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -7,7 +8,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import Transformer, TransformerConfig
-from ray_tpu.models.config import tiny, llama2_7b, PRESETS
+from ray_tpu.models.config import tiny, llama2_7b, llama3_8b, PRESETS
+from ray_tpu.models.transformer import (REMAT_KEPT_BYTES_BUDGET, REMAT_RUNGS,
+                                        remat_kept_bytes)
 from ray_tpu.parallel import prepare_mesh, param_shardings, shard_pytree
 
 
@@ -280,10 +283,13 @@ def unrematted():
 
 
 @pytest.mark.parametrize("policy", ["full", "save_attn", "save_attn_qkv",
+                                    "save_attn_stream",
+                                    "save_attn_stream_up", "save_matmuls",
                                     None])
 def test_remat_policy_keeps_loss_and_gradients(unrematted, policy):
     """A kept value is the value that would have been recomputed: every
-    rung, and the default, gives the loss and gradients of no remat."""
+    rung, and the default (the top rung at these shapes), gives the loss
+    and gradients of no remat."""
     params, (loss0, grads0) = unrematted
     loss, grads = jax.value_and_grad(_remat_model(policy).loss)(
         params, _remat_batch())
@@ -312,6 +318,7 @@ def _walk(jaxpr, visit):
                     _walk(sub, visit)
 
 
+@functools.lru_cache(maxsize=None)
 def _layer_scans(policy):
     """What the two scans over the layers hold, forward then backward:
     the weight shapes of the dots of the form `activations @ weight` (a
@@ -339,14 +346,13 @@ def _layer_scans(policy):
     return out
 
 
-def test_default_remat_reruns_no_projection_rotation_or_transpose():
-    """Under the default the backward's scan holds no q, k or v
+def test_save_attn_qkv_reruns_no_projection_rotation_or_transpose():
+    """Under "save_attn_qkv" the backward's scan holds no q, k or v
     projection, neither rotation and none of the three transposes into
     the kernel's layout, where "full" holds them all: it fails if a name
-    is dropped, or put on q or k before the rotary. What stays is what no
-    rung keeps: the output projection (the stream after it is named and
-    not kept), gate and up."""
-    (fwd, _), (again, prims) = _layer_scans(None)
+    is dropped, or put on q or k before the rotary. What stays is what
+    the rungs above keep: the output projection, gate and up."""
+    (fwd, _), (again, prims) = _layer_scans("save_attn_qkv")
     (fwd_full, _), (again_full, prims_full) = _layer_scans("full")
     layer = {(48, 48): 2, (48, 24): 2, (48, 80): 2, (80, 48): 1}
     assert fwd == fwd_full == layer
@@ -356,3 +362,75 @@ def test_default_remat_reruns_no_projection_rotation_or_transpose():
     # (b, h, s, hd)
     assert prims_full["concatenate"] - prims["concatenate"] == 2
     assert prims_full["transpose"] - prims["transpose"] == 3
+
+
+@pytest.mark.parametrize("policy,run_again", [
+    ("save_attn_stream", {(48, 80): 2}),
+    ("save_attn_stream_up", {(48, 80): 1}),
+    ("save_matmuls", {}),
+    (None, {}),
+])
+def test_upper_rungs_rerun_one_matmul_fewer_each(policy, run_again):
+    """Each rung above "save_attn_qkv" takes one more `activations @
+    weight` out of the backward's scan: the output projection, `up`, then
+    gate; on the top rung, and under the default at these shapes, the
+    backward runs no matmul of the forward again, and no rotation or
+    transpose either."""
+    (fwd, _), (again, prims) = _layer_scans(policy)
+    assert fwd == {(48, 48): 2, (48, 24): 2, (48, 80): 2, (80, 48): 1}
+    assert again == run_again
+    _, (_, prims_qkv) = _layer_scans("save_attn_qkv")
+    assert prims["concatenate"] == prims_qkv["concatenate"]
+    assert prims["transpose"] == prims_qkv["transpose"]
+
+
+# ----------------------------------------- the rung nobody named
+_MISTRAL_7B = dict(vocab_size=32000, d_model=4096, n_heads=32, n_kv_heads=8,
+                   d_ff=14336, dtype="bfloat16", param_dtype="bfloat16")
+
+
+@pytest.mark.parametrize("config,mesh,batch_tokens,per_device,rung", [
+    # the train cell: 2 x 4096 tokens through 5 layers on one device
+    (dict(_MISTRAL_7B, n_layers=5), None, 8192, 8192, "save_matmuls"),
+    # a whole model at 4096 tokens a device: a rung between
+    (llama3_8b(), None, 4096, 4096, "save_attn_stream"),
+    # and at 8192: the floor, today's rung, whatever it keeps
+    (llama3_8b(), None, 8192, 8192, "save_attn_qkv"),
+    (dict(_MISTRAL_7B, n_layers=32), None, 8192, 8192, "save_attn_qkv"),
+    # a batch over 4 devices: what one device takes at a quarter of it
+    (llama3_8b(), ("fsdp", 4), 16384, 4096, "save_attn_stream"),
+    (llama3_8b(), ("dp", 4), 8192, 2048, "save_attn_stream_up"),
+    (llama3_8b(), ("sp", 4), 8192, 2048, "save_attn_stream_up"),
+    # tp splits some of the kept values and not the tokens: counted high
+    (llama3_8b(), ("tp", 4), 4096, 4096, "save_attn_stream"),
+    # a MoE layer has no name above the stream
+    (dict(_MISTRAL_7B, n_layers=2, moe_num_experts=8), None, 1024, 1024,
+     "save_attn_stream"),
+    # a named rung is taken as named, fit or not
+    (dict(_MISTRAL_7B, n_layers=32, remat_policy="save_matmuls"), None,
+     65536, 65536, "save_matmuls"),
+    (dict(_MISTRAL_7B, n_layers=5, remat_policy="full"), None, 8192, 8192,
+     "full"),
+], ids=["train_cell", "llama3_8b_4k", "llama3_8b_8k", "mistral_7b_8k",
+        "fsdp4", "dp4", "sp4", "tp4", "moe", "named_top", "named_full"])
+def test_unnamed_remat_policy_takes_the_dearest_rung_that_fits(
+        config, mesh, batch_tokens, per_device, rung):
+    """`remat_plan` is a pure function of the config, the mesh and the
+    tokens of a batch: no trace, no device asked."""
+    if isinstance(config, dict):
+        config = TransformerConfig(**config)
+    if mesh is not None:
+        axis, n = mesh
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:n]), (axis,))
+    got, kept = Transformer(config, mesh=mesh).remat_plan(batch_tokens)
+    assert got == rung
+    assert (got, kept) == Transformer(config).remat_plan(per_device)
+    assert kept == remat_kept_bytes(config, rung, per_device)
+    if config.remat_policy == "auto" and rung != "save_attn_qkv":
+        assert kept <= REMAT_KEPT_BYTES_BUDGET
+
+
+def test_kept_bytes_a_token_and_layer_are_config_pys_figures():
+    config = TransformerConfig(**_MISTRAL_7B, n_layers=1)
+    assert [remat_kept_bytes(config, rung, 1) for rung in REMAT_RUNGS] == [
+        0, 8320, 20608, 28800, 57472, 86144]

@@ -1,19 +1,24 @@
 """What keeping one more value of a rematted layer buys on the chip: the
 training cell's step (`benchmarks/harness/train_cell.compile_step` on
 `mistral-7b-v0.1-1chip`'s widths, 2 x 4096 tokens, bf16 AdamW) compiled
-with a checkpoint policy that keeps the names given, then timed. Prints,
-a set of names, the sum the benchmark reads from `memory_analysis()`
+under a `remat_policy`, then timed. The argument is a policy as users write
+it (a rung of `models/transformer.py`'s REMAT_RUNGS, or "auto", the default:
+the rung the model takes from the step's shapes), or a list of checkpoint
+names to keep that is no rung. Prints the rung that ran and the bytes it
+keeps (`Transformer.remat_plan`, no compile needed), what every rung would
+keep at these shapes, the sum the benchmark reads from `memory_analysis()`
 (arguments + outputs + temporaries - aliased), its `peak_memory_in_bytes`,
-the chip's `bytes_limit` and milliseconds a step. Chip only, one set a
+the chip's `bytes_limit` and milliseconds a step. Chip only, one policy a
 process (a step that does not fit takes its process with it):
 
-    chiprun -- python tools/bench_remat.py full
-    chiprun -- python tools/bench_remat.py attn_q,attn_k,attn_v,attn_out,attn_lse
-    chiprun -- python tools/bench_remat.py attn_q,attn_k,attn_v,attn_out,attn_lse,attn_stream,mlp_up
+    chiprun -- python tools/bench_remat.py auto
+    chiprun -- python tools/bench_remat.py save_attn_qkv
+    chiprun -- python tools/bench_remat.py save_attn_stream_up
+    chiprun -- python tools/bench_remat.py attn_q,attn_k,attn_v,mlp_gate
 
-The names are `models/transformer.py`'s (ATTN_INPUT_NAMES, ATTN_STREAM_NAME,
-MLP_NAMES) and `ops/attention.py`'s ATTN_RESIDUAL_NAMES; "full" keeps none.
-What PR 40 read with it is in PERF.md section 6.
+The names are the ladder's (REMAT_LADDER: ATTN_RESIDUAL_NAMES of
+`ops/attention.py`, then ATTN_INPUT_NAMES, ATTN_STREAM_NAME and MLP_NAMES).
+What PRs 40 and 45 read with it is in PERF.md section 6.
 """
 import dataclasses
 import json
@@ -36,7 +41,7 @@ STEPS = 10
 
 
 def main() -> int:
-    names = () if sys.argv[1] == "full" else tuple(sys.argv[1].split(","))
+    policy = sys.argv[1]
     if jax.default_backend() != "tpu":
         print("no TPU: nothing here is a measurement off the chip")
         return 3
@@ -48,14 +53,22 @@ def main() -> int:
     model = load_model(cfg)
     sz = model.sizes(cfg)
     program = model.train_model(cfg, int(mix["seq_len"]))
-    transformer.REMAT_SAVED_NAMES["bench"] = names
+    if policy != transformer.REMAT_AUTO and \
+            policy not in transformer.REMAT_SAVED_NAMES:
+        transformer.REMAT_SAVED_NAMES[policy] = tuple(policy.split(","))
     program = Transformer(dataclasses.replace(program.config,
-                                              remat_policy="bench"))
+                                              remat_policy=policy))
+    batch_tokens = int(mix["batch"]) * int(mix["seq_len"])
+    rung, kept = program.remat_plan(batch_tokens)
     params = make_weights(model.weight_shapes(sz), SEED)
     tokens = train_cell.make_tokens(mix, sz.vocab, SEED)
     step, opt_state = train_cell.compile_step(program, mix, params, tokens)
     mem = step.memory_analysis()
-    out = {"names": names,
+    out = {"policy": policy, "rung": rung, "kept_gb": kept / 1e9,
+           "rungs_kept_gb": {
+               r: transformer.remat_kept_bytes(program.config, r,
+                                               batch_tokens) / 1e9
+               for r in transformer.REMAT_RUNGS},
            "sum_gb": (mem.argument_size_in_bytes + mem.output_size_in_bytes
                       + mem.temp_size_in_bytes
                       - mem.alias_size_in_bytes) / 1e9,

@@ -34,6 +34,13 @@ feed-forward beside them that joins at the layer's end; its router is a
 softmax over experts and slots that compute nothing, and the layer is told
 which of the experts it holds. The latent attention is
 `models/latent.py`'s, which `MLAMoE` runs too.
+
+A sixth: `HybridSSMMoE` (`models/hybrid_ssm_moe.py`), pre-norm blocks of
+one mixer each, named layer by layer: a state-space mixer (a selective
+scan, `ops/ssd.py`: a state of its own shape a sequence, which the model
+prices for the allocator's fixed class), grouped-query attention without
+rotary embedding, or a mixture of experts of two matrices that live in a
+latent narrower than the stream, a share of them held.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
@@ -47,6 +54,8 @@ from ray_tpu.models.hybrid_delta import (  # noqa: F401,E402
     HybridDelta, HybridDeltaConfig)
 from ray_tpu.models.shortcut_mla_moe import (  # noqa: F401,E402
     ShortcutMLAMoE, ShortcutMLAMoEConfig)
+from ray_tpu.models.hybrid_ssm_moe import (  # noqa: F401,E402
+    HybridSSMMoE, HybridSSMMoEConfig)
 
 
 # a dict of config fields names its class under "type"; without the key it
@@ -54,10 +63,12 @@ from ray_tpu.models.shortcut_mla_moe import (  # noqa: F401,E402
 CONFIG_TYPES = {"transformer": TransformerConfig, "mla_moe": MLAMoEConfig,
                 "gqa_window_moe": GQAWindowMoEConfig,
                 "hybrid_delta": HybridDeltaConfig,
-                "shortcut_mla_moe": ShortcutMLAMoEConfig}
+                "shortcut_mla_moe": ShortcutMLAMoEConfig,
+                "hybrid_ssm_moe": HybridSSMMoEConfig}
 MODEL_TYPES = {MLAMoEConfig: MLAMoE, GQAWindowMoEConfig: GQAWindowMoE,
                HybridDeltaConfig: HybridDelta,
-               ShortcutMLAMoEConfig: ShortcutMLAMoE}
+               ShortcutMLAMoEConfig: ShortcutMLAMoE,
+               HybridSSMMoEConfig: HybridSSMMoE}
 
 
 def model_config(model):

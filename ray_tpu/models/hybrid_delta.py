@@ -36,9 +36,11 @@ a sequence the same bytes at 100 positions and at 3,000: pools `"state"`
 convolution's last `width - 1` inputs, `(linear layers, slots + 1, (width
 - 1) x channels)`), a sequence's at the slot its **first table entry**
 names. That entry is a page of the allocator's fixed class
-(`serve/llm/kv_cache.py`), ids `0 .. slots - 1`, one a sequence, which the
-full layers' pools back like any page: one table serves both kinds and
-nothing is keyed by lane. `prefill` scans a prompt from a zero state
+(`serve/llm/kv_cache.py`: it names a state of any shape the model holds
+and prices, here a delta rule's; `HybridSSMMoE` keeps a selective scan's
+the same way), ids `0 .. slots - 1`, one a sequence, which the full
+layers' pools back like any page: one table serves both kinds and nothing
+is keyed by lane. `prefill` scans a prompt from a zero state
 (`gated_delta_prefill`: the chunk kernel, which stops at the prompt's true
 length inside its bucket) and writes the slot whole, so a slot that is
 reused holds nothing of its last owner; `decode_step` updates the slots of

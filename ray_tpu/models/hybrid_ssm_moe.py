@@ -1,0 +1,607 @@
+"""A decoder whose layers are one mixer each, of three kinds named layer by
+layer by the config's pattern: a state-space mixer (a selective scan: a
+recurrent state of fixed size a sequence), a mixture of experts that live in
+a latent narrower than the stream, or grouped-query attention (the
+`nemotron_h` family's hybrid of the three), on the ops the other classes run
+on and behind the same serving engine.
+
+Every layer is a pre-norm block, `x' = x + Mixer(N(x))`, `N` an RMSNorm of
+its own; there is no second sub-layer. A final norm before the untied head.
+
+`M`, a **state-space** mixer (`ops.ssd`), u its normed input, H heads of
+width P in G groups, a state of N numbers a channel:
+
+    [z | xBC | dt] = u W_in
+    xBC = SiLU(causal depthwise conv of width 4, with bias, over xBC)
+    [x | B | C] = xBC               (x: H heads of P; B, C: G groups of N)
+    dt = softplus(dt + dt_bias);  a_t = exp(-exp(A_log) dt_t)   (float32)
+    h_t = a_t h_{t-1} + dt_t x_t B_t^T;   y_t = h_t C_t + D x_t
+    out = (RMSNorm_group(y * SiLU(z))) W_out    (the norm a group's channels)
+
+`A_log`, `dt_bias` and `D` are held as offsets from the config's
+`a_log_init`, `dt_bias_init` and `d_init`, as a norm's scale is held as an
+offset from 1.
+
+`*`, **attention**: grouped-query softmax attention, no bias and no rotary
+embedding (position comes from the state-space layers).
+
+`E`, **experts in a latent** (`models.moe.dropless_moe_ffn`): the router
+reads the stream (a sigmoid a slot in float32, the top k of `score + bias`
+chosen, weights renormalised and scaled), the experts a projection of it:
+
+    l = u W_fc1;   E_i(l) = relu(l W_up_i)^2 W_down_i       (two matrices)
+    MoE(u) = (sum over chosen i of w_i E_i(l)) W_fc2 + relu(u S_up)^2 S_down
+
+the chosen experts' results summed in the latent, in float32, and `W_fc2`
+applied once a token; the shared expert reads the stream. **The layer is
+told which experts it holds** (`experts_held = (first, count)` of
+`n_routed_experts`), as `ShortcutMLAMoE`'s is: it routes over all of them
+and computes its own experts' part, one chip's share of a layer divided over
+chips, without the exchange.
+
+**Two kinds of cache behind one page table**, as `HybridDelta` holds them:
+pools `"k"`, `"v"` `(attention layers, num_pages, page, kv heads x head
+dim)` for the attention layers alone; for the state-space layers `"state"`
+`(M layers, slots + 1, N, H x P)` float32 and `"tail"` (the convolution's
+last `width - 1` inputs, `(M layers, slots + 1, (width - 1) x channels)`), a
+sequence's at the slot its **first table entry** names (a page of the
+allocator's fixed class, `serve/llm/kv_cache.py`). `prefill` scans a prompt
+from a zero state (`ssd_prefill`: the chunk kernel, which stops at the
+prompt's true length inside its bucket) and writes the slot whole, so a
+slot that is reused holds nothing of its last owner; `decode_step` updates
+the slots of active lanes in place (`ssd_step`) and leaves every other
+alone. The pools' last slot is nobody's. Beside them `"moe_load"` `(expert
+layers, held experts)` and `"moe_step"`, as `ShortcutMLAMoE`'s.
+
+Given a mesh the class refuses: neither the state pools nor the experts'
+exchange over chips have been built (PERF.md section 7).
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.latent import decode_lanes, prefill_page_ids
+from ray_tpu.models.moe import STEP_COUNTS, dropless_moe_ffn, step_counts
+from ray_tpu.ops import paged_attention as _paged
+from ray_tpu.ops import ssd as _ssd
+from ray_tpu.ops.attention import flash_attention
+from ray_tpu.ops.gated_delta import causal_conv, conv_step
+from ray_tpu.ops.losses import softmax_cross_entropy
+from ray_tpu.ops.norms import rms_norm, rms_norm_reference
+
+Params = Dict[str, Any]
+Cache = Dict[str, Any]
+
+# a layer's kind, by the letters of the family's `hybrid_override_pattern`
+SSM, EXPERTS, ATTENTION = "M", "E", "*"
+# prefill's flash blocks, as `gqa_window_moe.FULL_BLOCKS`
+FULL_BLOCKS = (1024, 1024)
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridSSMMoEConfig:
+    """Fields under the published keys' meanings (`config.json` of
+    `nemotron_h`); `layer_types` the pattern, one letter a layer;
+    `n_routed_experts` the experts of the whole layer and `experts_held`
+    this chip's."""
+    vocab_size: int = 131072
+    d_model: int = 4096                     # hidden_size
+    layer_types: Tuple[str, ...] = tuple("MEMEMEMEM*E")
+    n_heads: int = 32                       # num_attention_heads
+    n_kv_heads: int = 2                     # num_key_value_heads
+    head_dim: int = 128
+    ssm_heads: int = 128                    # mamba_num_heads
+    ssm_head_dim: int = 64                  # mamba_head_dim
+    ssm_groups: int = 8                     # n_groups
+    ssm_state: int = 128                    # ssm_state_size
+    conv_width: int = 4                     # conv_kernel
+    chunk: int = _ssd.CHUNK                 # chunk_size
+    a_log_init: float = 0.0
+    dt_bias_init: float = 0.0
+    d_init: float = 1.0
+    moe_latent_size: int = 1024
+    moe_intermediate_size: int = 2688
+    shared_intermediate_size: int = 5376    # moe_shared_expert_inter..._size
+    n_routed_experts: int = 512
+    experts_held: Optional[Tuple[int, int]] = None   # (first, count); all
+    num_experts_per_tok: int = 22
+    routed_scaling_factor: float = 5.0
+    max_seq_len: int = 8192
+    norm_eps: float = 1e-5
+    dtype: str = "bfloat16"
+    param_dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if set(self.layer_types) - {SSM, EXPERTS, ATTENTION}:
+            raise ValueError(f"layer kinds {set(self.layer_types)} not "
+                             f"built")
+        if self.n_heads % self.n_kv_heads or (
+                self.ssm_heads % self.ssm_groups):
+            raise ValueError("kv heads must divide the heads, the groups "
+                             "the state-space heads")
+        first, count = self.held
+        if count < 1 or not 0 <= first <= self.n_routed_experts - count:
+            raise ValueError(f"experts_held {self.experts_held} of "
+                             f"{self.n_routed_experts} experts")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    def of_kind(self, kind: str) -> Tuple[int, ...]:
+        return tuple(i for i, k in enumerate(self.layer_types) if k == kind)
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts this chip holds."""
+        return tuple(self.experts_held or (0, self.n_routed_experts))
+
+    @property
+    def kv_dim(self) -> int:
+        return self.n_kv_heads * self.head_dim
+
+    @property
+    def ssm_inner(self) -> int:             # the mixer's width, H x P
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def bc_dim(self) -> int:                # B's (and C's) width, G x N
+        return self.ssm_groups * self.ssm_state
+
+    @property
+    def conv_channels(self) -> int:
+        return self.ssm_inner + 2 * self.bc_dim
+
+    @property
+    def activation_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def parameter_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+
+def tiny_hybrid_ssm_moe(vocab_size: int = 256,
+                        experts_held=(4, 4)) -> HybridSSMMoEConfig:
+    """CI/debug model: every mechanism at a size the CPU runs in seconds
+    (one period of all three kinds, 4 state-space heads in 2 groups, 4
+    query heads over 2 kv heads, a latent narrower than the stream, a share
+    of the experts that does not start at 0, chunks of 8)."""
+    return HybridSSMMoEConfig(
+        vocab_size=vocab_size, d_model=64, layer_types=tuple("MEM*E"),
+        n_heads=4, n_kv_heads=2, head_dim=16, ssm_heads=4, ssm_head_dim=8,
+        ssm_groups=2, ssm_state=16, chunk=8, moe_latent_size=32,
+        moe_intermediate_size=48, shared_intermediate_size=96,
+        n_routed_experts=16, experts_held=experts_held,
+        num_experts_per_tok=4, max_seq_len=256, dtype="float32",
+        param_dtype="float32")
+
+
+class HybridSSMMoE:
+    """Functional model bundle for one HybridSSMMoEConfig: `init`, `apply`
+    / `loss` (the plain chunked scan, differentiated by JAX), and what a
+    serving engine asks a model for (`init_cache`, `prefill`,
+    `decode_step`, `cache_page_bytes`, `fixed_pages`, `fixed_step_counts`,
+    `prefill_counts`, `decode_attention`, `step_stats`, `cache_stats`)."""
+
+    def __init__(self, config: HybridSSMMoEConfig, mesh=None):
+        if mesh is not None:
+            raise NotImplementedError(
+                "HybridSSMMoE runs on one device and takes no mesh: neither "
+                "the state pools nor the experts' exchange over chips have "
+                "been built")
+        self.config = config
+
+    # ------------------------------------------------------------ init
+    def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
+        """(shape, init std) of layer i's leaves; std 0 means zeros (a
+        norm scale w, the layer multiplying by 1 + w; `a_log`, `dt_bias`,
+        `d`, offsets from the config's initial values; the convolution's
+        and the router's bias)."""
+        c = self.config
+        e = c.d_model
+        std = 0.02
+        out_std = std / math.sqrt(c.n_layers)
+        kind = c.layer_types[i]
+        if kind == ATTENTION:
+            q = c.n_heads * c.head_dim
+            return {"norm": ((e,), 0.0), "wq": ((e, q), std),
+                    "wk": ((e, c.kv_dim), std), "wv": ((e, c.kv_dim), std),
+                    "wo": ((q, e), out_std)}
+        if kind == EXPERTS:
+            E, lat, f = c.held[1], c.moe_latent_size, c.moe_intermediate_size
+            return {"norm": ((e,), 0.0),
+                    "router": ((e, c.n_routed_experts), std),
+                    "router_bias": ((c.n_routed_experts,), 0.0),
+                    "fc1": ((e, lat), std), "fc2": ((lat, e), out_std),
+                    "moe_up": ((E, lat, f), std),
+                    "moe_down": ((E, f, lat), std),
+                    "shared_up": ((e, c.shared_intermediate_size), std),
+                    "shared_down": ((c.shared_intermediate_size, e),
+                                    out_std)}
+        H = c.ssm_heads
+        return {"norm": ((e,), 0.0),
+                "w_in": ((e, c.ssm_inner + c.conv_channels + H), std),
+                "conv": ((c.conv_width, c.conv_channels), std),
+                "conv_bias": ((c.conv_channels,), 0.0),
+                "a_log": ((H,), 0.0), "dt_bias": ((H,), 0.0),
+                "d": ((H,), 0.0), "gate_norm": ((c.ssm_inner,), 0.0),
+                "w_out": ((c.ssm_inner, e), out_std)}
+
+    def param_count(self) -> int:
+        c = self.config
+        return (2 * c.vocab_size * c.d_model + c.d_model + sum(
+            math.prod(shape) for i in range(c.n_layers)
+            for shape, _ in self.layer_shapes(i).values()))
+
+    def init(self, key: jax.Array) -> Params:
+        c = self.config
+        pd = c.parameter_dtype
+
+        def fill(key, shapes):
+            keys = jax.random.split(key, len(shapes))
+            return {name: (jax.random.normal(k, shape, jnp.float32)
+                           * std).astype(pd) if std else jnp.zeros(shape, pd)
+                    for k, (name, (shape, std)) in zip(keys,
+                                                       shapes.items())}
+
+        keys = jax.random.split(key, c.n_layers + 1)
+        top = fill(keys[-1], {
+            "embed": ((c.vocab_size, c.d_model), 0.02),
+            "lm_head": ((c.d_model, c.vocab_size), 0.02)})
+        return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
+                "layers": [fill(keys[i], self.layer_shapes(i))
+                           for i in range(c.n_layers)]}
+
+    # --------------------------------------------------------- pieces
+    def _norm(self, x, w):
+        return rms_norm(x, w, self.config.norm_eps, None)
+
+    def _ssm_project(self, layer: Params, u):
+        """u (n, e) -> (z (n, H x P), xBC (n, channels) before the
+        convolution, dt (n, H) before the softplus)."""
+        c = self.config
+        proj = u @ layer["w_in"].astype(c.activation_dtype)
+        return jnp.split(proj, [c.ssm_inner, c.ssm_inner + c.conv_channels],
+                         axis=-1)
+
+    def _ssm_inputs(self, layer: Params, mixed, dt):
+        """What the scan takes: x (n, H x P), B, C (n, G x N) of the
+        convolved channels `mixed`, dt (n, H) and A (H,) float32."""
+        c = self.config
+        f32 = jnp.float32           # the offsets are added in float32
+        x, Bm, Cm = jnp.split(mixed, [c.ssm_inner, c.ssm_inner + c.bc_dim],
+                              axis=-1)
+        dt = jax.nn.softplus(dt.astype(f32) + c.dt_bias_init
+                             + layer["dt_bias"].astype(f32))
+        return x, Bm, Cm, dt, jnp.exp(c.a_log_init
+                                      + layer["a_log"].astype(f32))
+
+    def _ssm_out(self, layer: Params, y, x, z):
+        """The scan's y (n, H x P): the skip `D x` added, gated by SiLU(z),
+        normed a group's channels, through W_out; float32 up to the
+        matmul."""
+        c = self.config
+        f32 = jnp.float32
+        n, G = y.shape[0], c.ssm_groups
+        D = jnp.repeat(c.d_init + layer["d"].astype(f32), c.ssm_head_dim)
+        y = (y.astype(f32) + D * x.astype(f32)) * jax.nn.silu(z.astype(f32))
+        y = rms_norm_reference(y.reshape(n, G, -1),
+                               layer["gate_norm"].reshape(G, -1), c.norm_eps)
+        ad = c.activation_dtype
+        return y.reshape(n, -1).astype(ad) @ layer["w_out"].astype(ad)
+
+    def _ssm_seq(self, layer: Params, u, true_len=None):
+        """A state-space mixer over one sequence u (s, e), normed. With a
+        `true_len` (a prefill's padded bucket) through `ssd_prefill`, the
+        kernel where there is one; without, through the plain chunked
+        form, which JAX differentiates. Returns (the output after W_out
+        (s, e), the state at the sequence's end (N, H x P) float32, the
+        convolution's tail)."""
+        c = self.config
+        s = u.shape[0]
+        z, xbc, dt = self._ssm_project(layer, u)
+        mixed, tail = causal_conv(xbc, layer["conv"], true_len,
+                                  layer["conv_bias"])
+        x, Bm, Cm, dt, A = self._ssm_inputs(layer, mixed, dt)
+        pad = -s % c.chunk                  # whole chunks; padding is inert
+        xp, Bp, Cp, dtp = (jnp.pad(a, ((0, pad), (0, 0)))
+                           for a in (x, Bm, Cm, dt))
+        if true_len is None:
+            y, state = _ssd.ssd_chunked(xp, Bp, Cp, dtp, A, c.ssm_groups,
+                                        chunk=c.chunk)
+        else:
+            y, state = _ssd.ssd_prefill(xp, Bp, Cp, dtp, A, true_len,
+                                        c.ssm_groups, c.chunk)
+        return self._ssm_out(layer, y[:s], x, z), state, tail
+
+    def _attn_qkv(self, layer: Params, u):
+        """u (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd)."""
+        c = self.config
+        ad = c.activation_dtype
+        lead = u.shape[:-1]
+        return ((u @ layer["wq"].astype(ad)).reshape(*lead, c.n_heads,
+                                                     c.head_dim),
+                (u @ layer["wk"].astype(ad)).reshape(*lead, c.n_kv_heads,
+                                                     c.head_dim),
+                (u @ layer["wv"].astype(ad)).reshape(*lead, c.n_kv_heads,
+                                                     c.head_dim))
+
+    def _attn_seq(self, layer: Params, u):
+        """Causal attention over whole sequences u (b, s, e). Returns (the
+        output after W_o, k, v (b, s, kv heads, hd))."""
+        q, k, v = self._attn_qkv(layer, u)
+        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        out = flash_attention(qt, kt, vt, causal=True,
+                              block_q=FULL_BLOCKS[0], block_k=FULL_BLOCKS[1])
+        out = out.transpose(0, 2, 1, 3).reshape(*u.shape[:-1], -1)
+        return out @ layer["wo"].astype(self.config.activation_dtype), k, v
+
+    def _experts(self, layer: Params, u, valid=None):
+        """An expert layer's mixer on the normed stream u (n, e): this
+        chip's experts' part through the latent, and the shared expert.
+        Returns (the mixer's output, the routed part's counts)."""
+        c = self.config
+        ad = c.activation_dtype
+        latent, counts = dropless_moe_ffn(
+            u, layer["router"], layer["router_bias"], None,
+            layer["moe_up"], layer["moe_down"], top_k=c.num_experts_per_tok,
+            scale=c.routed_scaling_factor, valid=valid, held=c.held,
+            expert_form="relu2", expert_input=u @ layer["fc1"].astype(ad))
+        shared = jnp.square(jax.nn.relu(u @ layer["shared_up"].astype(ad)))
+        return (latent @ layer["fc2"].astype(ad)
+                + shared @ layer["shared_down"].astype(ad)), counts
+
+    # --------------------------------------------------------- forward
+    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens (b, s) -> hidden states after the final norm."""
+        c = self.config
+        b, s = tokens.shape
+        x = params["embed"].astype(c.activation_dtype)[tokens]
+        for i, layer in enumerate(params["layers"]):
+            u = self._norm(x, layer["norm"])
+            kind = c.layer_types[i]
+            if kind == ATTENTION:
+                mixed = self._attn_seq(layer, u)[0]
+            elif kind == EXPERTS:
+                mixed = self._experts(layer, u.reshape(b * s, -1))[
+                    0].reshape(x.shape)
+            else:
+                mixed = jax.vmap(
+                    lambda seq: self._ssm_seq(layer, seq)[0])(u)
+            x = x + mixed
+        return self._norm(x, params["final_norm"])
+
+    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
+        x = self.hidden(params, tokens)
+        head = params["lm_head"].astype(self.config.activation_dtype)
+        return (x @ head).astype(jnp.float32)
+
+    def loss(self, params: Params, batch: Dict[str, jax.Array]):
+        """Causal LM loss of batch["tokens"] (b, s), as `MLAMoE.loss`. The
+        state-space layers run the plain chunked form here: the chunk
+        kernel has no backward (PERF.md section 7)."""
+        tokens = batch["tokens"]
+        mask = batch.get("loss_mask")
+        logits = self.apply(params, tokens)[:, :-1]
+        if mask is not None:
+            mask = mask[:, 1:]
+        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
+        return loss
+
+    # ------------------------------------------------ what an engine asks
+    def fixed_pages(self, page_size: int) -> int:
+        """Pages of the allocator's fixed class a sequence holds for ever:
+        one, its first table entry, which names its state slot."""
+        return int(bool(self.config.of_kind(SSM)))
+
+    def state_bytes(self, dtype=None) -> int:
+        """Bytes the state-space layers keep of one sequence, whatever its
+        length: a float32 state and the convolution's tail a layer."""
+        c = self.config
+        dt = jnp.dtype(dtype or c.activation_dtype)
+        return len(c.of_kind(SSM)) * (
+            c.ssm_state * c.ssm_inner * 4
+            + (c.conv_width - 1) * c.conv_channels * dt.itemsize)
+
+    def fixed_step_counts(self, length: int, page_size: int,
+                          kernel: bool = True) -> Dict[str, int]:
+        """What a lane's fixed part costs a decode step, by the names the
+        engine's span carries: its state slot, and the bytes the
+        state-space layers move for it (state and tail, read and written),
+        whatever its `length`."""
+        return {"state_slots": 1, "state_bytes": 2 * self.state_bytes()}
+
+    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
+        """What a prefill of `tokens` in its `bucket` runs, for the
+        engine's span: the chunks a state-space layer scans (those that
+        hold the prompt; the kernel skips the bucket's others)."""
+        return {"scan_chunks": -(-tokens // self.config.chunk)}
+
+    def init_cache(self, num_pages: int, page_size: int, dtype=None,
+                   fixed_pages: int = 0) -> Cache:
+        """`num_pages` pages in the attention layers' pools; `fixed_pages`
+        state slots (the allocator's fixed class, one a sequence) and one
+        more, nobody's, in the state-space layers'."""
+        c = self.config
+        dt = dtype or c.activation_dtype
+        kv = (len(c.of_kind(ATTENTION)), num_pages, page_size, c.kv_dim)
+        ssm, slots = len(c.of_kind(SSM)), fixed_pages + 1
+        make = jax.jit(lambda: {
+            "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
+            "state": jnp.zeros((ssm, slots, c.ssm_state, c.ssm_inner),
+                               jnp.float32),
+            "tail": jnp.zeros((ssm, slots,
+                               (c.conv_width - 1) * c.conv_channels), dt),
+            "moe_load": jnp.zeros((len(c.of_kind(EXPERTS)), c.held[1]),
+                                  jnp.int32),
+            "moe_step": {name: jnp.zeros((), jnp.int32)
+                         for name in STEP_COUNTS}})
+        return make()
+
+    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
+                         dtype=None, fixed: bool = False) -> int:
+        """Bytes one page costs: keys and values of the attention layers
+        for a page of the pool `num_pages` counts; what the state-space
+        layers keep of a sequence (`fixed`), which its fixed-class page
+        costs besides."""
+        c = self.config
+        if fixed:
+            return self.state_bytes(dtype)
+        dt = jnp.dtype(dtype or c.activation_dtype)
+        return (2 * len(c.of_kind(ATTENTION)) * page_size
+                * (c.kv_dim // max(1, tp_shards)) * dt.itemsize)
+
+    def decode_attention(self, page_size: int, dtype=None) -> str:
+        """Which kernels a `decode_step` traced here holds, a layer kind
+        each, or "einsum" (the attention layers gather)."""
+        c = self.config
+        if not _paged.uses_kernel(c.head_dim, page_size,
+                                  dtype or c.activation_dtype):
+            return "einsum"
+        step = (_ssd.KERNEL_STEP if _ssd.uses_step_kernel(
+            c.ssm_inner, c.ssm_inner // c.ssm_groups, c.ssm_state)
+            else "ssd_gather")
+        return "+".join(
+            [_paged.KERNEL_PAGED_DECODE] * bool(c.of_kind(ATTENTION))
+            + [step] * bool(c.of_kind(SSM)))
+
+    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
+        """Pages a block of the attention layers' walk holds over tables
+        of `max_pages`, asked what the kernel asks (a layer's page of keys
+        and values)."""
+        return _paged.walk_block_pages(
+            self.cache_page_bytes(page_size)
+            // max(1, len(self.config.of_kind(ATTENTION))), page_size,
+            max_pages)
+
+    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
+        """What the last decode step counted, as `ShortcutMLAMoE`'s."""
+        return cache["moe_step"]
+
+    def cache_stats(self, cache: Cache) -> Dict[str, Any]:
+        """For `EngineCore.device_stats()`: pairs a held expert since the
+        cache was made, by expert layer."""
+        return {"moe_load": jax.device_get(cache["moe_load"]).tolist()}
+
+    def prefill(self, params: Params, tokens: jax.Array, true_len,
+                page_table: jax.Array, cache: Cache,
+                page_size: int) -> Tuple[jax.Array, Cache]:
+        """One padded prompt, as `models.decode.prefill`: an attention
+        layer through the flash kernel, its keys and values written as
+        whole pages in place (donate the cache); a state-space layer
+        scanned from a zero state to `true_len`, its state and tail
+        written whole into the slot the table's first entry names; padding
+        past `true_len` given to no expert. Returns (last-position logits
+        (vocab,) f32, cache)."""
+        c = self.config
+        ad = c.activation_dtype
+        pools = dict(cache)
+        num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
+        s = tokens.shape[0]
+        x = params["embed"].astype(ad)[tokens]                  # (s, e)
+        n = -(-s // page_size)
+        ids = prefill_page_ids(page_table, true_len, s, num_pages, page_size)
+        slot = page_table[0]
+        slot = jnp.where((slot >= 0) & (slot < slots), slot, slots + 1)
+        valid = jnp.arange(s) < true_len
+
+        def pages(a):
+            a = jnp.pad(a[0].reshape(s, c.kv_dim),
+                        ((0, n * page_size - s), (0, 0)))
+            return a.reshape(n, page_size, c.kv_dim)
+
+        for i, layer in enumerate(params["layers"]):
+            u = self._norm(x, layer["norm"])
+            kind = c.layer_types[i]
+            if kind == ATTENTION:
+                li = c.of_kind(ATTENTION).index(i)
+                mixed, k, v = self._attn_seq(layer, u[None])
+                mixed = mixed[0]
+                for name, a in (("k", k), ("v", v)):
+                    pools[name] = pools[name].at[li, ids].set(
+                        pages(a).astype(pools[name].dtype), mode="drop")
+            elif kind == EXPERTS:
+                mixed, _ = self._experts(layer, u, valid)
+            else:
+                li = c.of_kind(SSM).index(i)
+                mixed, state, tail = self._ssm_seq(layer, u, true_len)
+                pools["state"] = pools["state"].at[li, slot].set(
+                    state, mode="drop")
+                pools["tail"] = pools["tail"].at[li, slot].set(
+                    tail.reshape(-1).astype(pools["tail"].dtype),
+                    mode="drop")
+            x = x + mixed
+        x = self._norm(x, params["final_norm"])
+        last = jnp.take(x, true_len - 1, axis=0)
+        logits = (last @ params["lm_head"].astype(ad)).astype(jnp.float32)
+        return logits, pools
+
+    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
+                    positions: jax.Array, page_tables: jax.Array,
+                    active: jax.Array,
+                    page_size: int) -> Tuple[jax.Array, Cache]:
+        """Advance a padded batch by one token each, as
+        `models.decode.decode_step`. An inactive lane, or one whose table
+        is unassigned, writes no page, no state and no tail, and is given
+        to no expert. Returns (logits (B, vocab) f32, cache) — donate the
+        cache."""
+        c = self.config
+        ad = c.activation_dtype
+        pools = dict(cache)
+        num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
+        B = tokens.shape[0]
+        x = params["embed"].astype(ad)[tokens]                  # (B, e)
+        page, offset, lengths = decode_lanes(positions, page_tables, active,
+                                             num_pages, page_size)
+        first = page_tables[:, 0]
+        slot = jnp.where(active & (first >= 0) & (first < slots), first, -1)
+        tail_at = jnp.where(slot >= 0, slot, slots + 1)     # -1: dropped
+        load = pools["moe_load"]
+        sums = [jnp.int32(0)] * len(STEP_COUNTS)
+        for i, layer in enumerate(params["layers"]):
+            u = self._norm(x, layer["norm"])
+            kind = c.layer_types[i]
+            if kind == ATTENTION:
+                li = c.of_kind(ATTENTION).index(i)
+                q, k, v = self._attn_qkv(layer, u)
+                for name, a in (("k", k), ("v", v)):
+                    pools[name] = pools[name].at[li, page, offset].set(
+                        a.reshape(B, c.kv_dim).astype(pools[name].dtype),
+                        mode="drop")
+                out = _paged.paged_decode_attention(
+                    q.astype(pools["k"].dtype), pools["k"], pools["v"], li,
+                    page_tables, lengths)
+                mixed = out.astype(ad).reshape(B, -1) @ layer["wo"].astype(
+                    ad)
+            elif kind == EXPERTS:
+                li = c.of_kind(EXPERTS).index(i)
+                mixed, counts = self._experts(layer, u, active)
+                load = load.at[li].add(counts["load"])
+                sums = [a + n for a, n in zip(sums, step_counts(counts))]
+            else:
+                li = c.of_kind(SSM).index(i)
+                z, xbc, dt = self._ssm_project(layer, u)
+                tail = pools["tail"][li, jnp.clip(slot, 0, slots)].reshape(
+                    B, c.conv_width - 1, c.conv_channels)
+                conv, tail = conv_step(xbc, tail, layer["conv"],
+                                       layer["conv_bias"])
+                pools["tail"] = pools["tail"].at[li, tail_at].set(
+                    tail.reshape(B, -1), mode="drop")
+                xs, Bm, Cm, dt, A = self._ssm_inputs(layer, conv, dt)
+                y, pools["state"] = _ssd.ssd_step(
+                    xs, Bm, Cm, dt, A, pools["state"], li, slot,
+                    c.ssm_groups)
+                mixed = self._ssm_out(layer, y, xs, z)
+            x = x + mixed
+        x = self._norm(x, params["final_norm"])
+        logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
+        return logits, {**pools, "moe_load": load,
+                        "moe_step": dict(zip(STEP_COUNTS, sums))}

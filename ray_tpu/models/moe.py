@@ -3,14 +3,16 @@
 `moe_ffn` (below, first) is the capacity-factor layer `Transformer` trains
 with: top-k of a softmax, a fixed capacity an expert, overflow dropped.
 `dropless_moe_ffn` (at the end) is the serving path of the classes with
-routed experts (`models.mla_moe`, `gqa_window_moe`, `shortcut_mla_moe`):
-no capacity and no dropped token at any imbalance — the (token, expert)
-pairs are sorted by expert and `ops.grouped_matmul` multiplies each
-expert's rows by its matrices, reading only experts that have rows. Its
-scoring (sigmoid or softmax, renormalised or not), the slots that compute
-nothing and the share of the experts it holds are data of the caller's
-config (`route_topk`, `dropless_moe_ffn`). A `simplicity` PR folds the two
-(ROADMAP D1).
+routed experts (`models.mla_moe`, `gqa_window_moe`, `shortcut_mla_moe`,
+`hybrid_ssm_moe`): no capacity and no dropped token at any imbalance — the
+(token, expert) pairs are sorted by expert and `ops.grouped_matmul`
+multiplies each expert's rows by its matrices, reading only experts that
+have rows. Its scoring (sigmoid or softmax, renormalised or not), the slots
+that compute nothing, the share of the experts it holds, the expert's form
+(`EXPERT_FORMS`: three matrices with a gate, or two around a squared ReLU)
+and what the experts read (the router's input, or a narrower projection of
+it the caller made) are data of the caller's config (`route_topk`,
+`dropless_moe_ffn`). A `simplicity` PR folds the two (ROADMAP D1).
 
 The capacity-factor layer: top-k routing + capacity-based dispatch.
 
@@ -161,16 +163,30 @@ def route_topk(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
     return top_e.astype(jnp.int32), top_w * scale
 
 
+# An expert's form: "swiglu" `(silu(x W_gate) * x W_up) W_down`, three
+# matrices; "relu2" `relu(x W_up)^2 W_down`, two and no gate.
+EXPERT_FORMS = ("swiglu", "relu2")
+
+
 def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
-                     gate_w: jax.Array, up_w: jax.Array, down_w: jax.Array,
+                     gate_w: Optional[jax.Array], up_w: jax.Array,
+                     down_w: jax.Array,
                      *, top_k: int, norm_topk_prob: bool = True,
                      scale: float = 1.0,
                      valid: Optional[jax.Array] = None,
                      scoring: str = "sigmoid", zero_experts: int = 0,
-                     held: Optional[Tuple[int, int]] = None
+                     held: Optional[Tuple[int, int]] = None,
+                     expert_form: str = "swiglu",
+                     expert_input: Optional[jax.Array] = None
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """y_t = sum over the k slots token t chose of w_ti * E_i(x_t), every
-    expert a SwiGLU; no token is dropped whatever the imbalance.
+    """y_t = sum over the k slots token t chose of w_ti * E_i(z_t), `E_i`
+    of the form `expert_form` names (`EXPERT_FORMS`; one without a gate
+    does not read `gate_w`) and `z` the experts' input: x itself, or
+    `expert_input` (T, d') where the experts read something narrower than
+    the router does (a latent the caller projected x into: the result is
+    then d' wide too, the k pairs of a token summed there in float32, and
+    the way back to x's width is the caller's, once a token). No token is
+    dropped whatever the imbalance.
 
     The router is `slots` wide: the experts, then `zero_experts` slots
     that compute nothing, for which `E_i(x) = x` (a pair that chose one
@@ -181,19 +197,24 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     has, and leaves out what the experts held elsewhere would add (their
     pairs sort past the last held expert, as padding does). None: all.
 
-    x (T, d); gate_w / up_w (held, d, f); down_w (held, f, d). `valid`
+    x (T, d); gate_w / up_w (held, d', f); down_w (held, f, d'), d' = d
+    without an `expert_input`. `valid`
     (T,) bool: tokens that are padding get no pair and a zero result.
-    Returns (y (T, d), {"pairs": pairs given to held experts, "touched":
+    Returns (y (T, d'), {"pairs": pairs given to held experts, "touched":
     held experts with a pair, "load": (held,) pairs an expert,
     "zero_pairs": pairs of slots that compute nothing, "away_pairs": pairs
     of experts held elsewhere}), the counts int32 on the device."""
     from ray_tpu.ops.grouped_matmul import grouped_matmul
-    T, d = x.shape
+    if expert_form not in EXPERT_FORMS:
+        raise ValueError(f"expert form {expert_form!r}; forms: "
+                         f"{EXPERT_FORMS}")
+    z = x if expert_input is None else expert_input
+    T, d = z.shape
     experts = router_w.shape[-1] - zero_experts
     first, E = held or (0, experts)
-    if E != gate_w.shape[0] or not 0 <= first <= experts - E:
+    if E != up_w.shape[0] or not 0 <= first <= experts - E:
         raise ValueError(f"experts {first}..{first + E} of {experts} held, "
-                         f"{gate_w.shape[0]} given")
+                         f"{up_w.shape[0]} given")
     top_e, top_w = route_topk(x, router_w, bias, top_k=top_k,
                               norm_topk_prob=norm_topk_prob, scale=scale,
                               scoring=scoring)
@@ -214,9 +235,12 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     flat_e = top_e.reshape(-1)
     order = jnp.argsort(flat_e, stable=True)    # pairs sorted by expert
     load = jnp.bincount(flat_e, length=E + 1)[:E].astype(jnp.int32)
-    xs = x[order // top_k]
-    h = (jax.nn.silu(grouped_matmul(xs, gate_w, load))
-         * grouped_matmul(xs, up_w, load))
+    xs = z[order // top_k]
+    if expert_form == "relu2":
+        h = jnp.square(jax.nn.relu(grouped_matmul(xs, up_w, load)))
+    else:
+        h = (jax.nn.silu(grouped_matmul(xs, gate_w, load))
+             * grouped_matmul(xs, up_w, load))
     # (the rows of padding, past the last expert's, come back as zeros)
     ys = grouped_matmul(h, down_w, load).astype(jnp.float32)
     ys = ys * top_w.reshape(-1)[order][:, None]
@@ -224,8 +248,20 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     # token summed in float32
     y = ys[jnp.argsort(order)].reshape(T, top_k, d).sum(axis=1)
     if identity is not None:
-        y = y + identity[:, None] * x.astype(jnp.float32)
-    return y.astype(x.dtype), {
+        y = y + identity[:, None] * z.astype(jnp.float32)
+    return y.astype(z.dtype), {
         "pairs": jnp.sum(load), "touched": jnp.sum(load > 0).astype(
             jnp.int32), "load": load, "zero_pairs": zero_pairs,
         "away_pairs": away_pairs}
+
+
+# What a decode step of a layer that holds a share of its experts counts
+# over its layers, by the names the engine's counters take; `step_counts`
+# gives one layer's in this order from `dropless_moe_ffn`'s counts.
+STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
+               "moe_zero_pairs", "moe_away_pairs")
+
+
+def step_counts(counts: Dict[str, jax.Array]) -> Tuple[jax.Array, ...]:
+    return (counts["pairs"], counts["touched"], jnp.max(counts["load"]),
+            counts["zero_pairs"], counts["away_pairs"])

@@ -62,25 +62,14 @@ import jax.numpy as jnp
 
 from ray_tpu.models.latent import (LatentAttention, LatentDims, attn_shapes,
                                    decode_lanes, prefill_page_ids)
-from ray_tpu.models.moe import SCORING, dropless_moe_ffn
+from ray_tpu.models.moe import (SCORING, STEP_COUNTS, dropless_moe_ffn,
+                                step_counts)
 from ray_tpu.ops.losses import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.rope import rope_cos_sin
 
 Params = Dict[str, Any]
 Cache = Dict[str, Any]
-
-# what a decode step counts over its layers (`Cache["moe_step"]`); the
-# engine's counters take these names
-STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
-               "moe_zero_pairs", "moe_away_pairs")
-
-
-def _step_counts(counts: Dict[str, jax.Array]) -> Tuple[jax.Array, ...]:
-    """One layer's `dropless_moe_ffn` counts in `STEP_COUNTS`' order."""
-    return (counts["pairs"], counts["touched"], jnp.max(counts["load"]),
-            counts["zero_pairs"], counts["away_pairs"])
-
 
 @dataclasses.dataclass(frozen=True)
 class ShortcutMLAMoEConfig(LatentDims):
@@ -369,7 +358,7 @@ class ShortcutMLAMoE(LatentAttention):
                 return out
             x, counts = self._layer(layer, x, attend, active)
             load = load.at[i].add(counts["load"])
-            sums = [a + n for a, n in zip(sums, _step_counts(counts))]
+            sums = [a + n for a, n in zip(sums, step_counts(counts))]
         x = self._norm(x, params["final_norm"])
         logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
         return logits, {"kv": pool, "moe_load": load,

@@ -54,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.dispatch import on_tpu
+from ray_tpu.ops.exact import F32, HIGHEST, dot as _dot
 
 # The kernels' names on the device's clock (see attention.KERNEL_FWD).
 KERNEL_CHUNK = "gated_delta_chunk_fwd"
@@ -69,21 +70,6 @@ CHUNK_HEADS = 6
 # bytes of state a grid step of the step kernel holds (in, out, and the
 # temporaries of the update are each this much)
 STEP_BLOCK_BYTES = 1 << 20
-
-F32 = jnp.float32
-HIGHEST = lax.Precision.HIGHEST
-
-
-def _dot(a, b, dims=((1,), (0,))):
-    """a x b contracted over `dims`, accumulated in float32; operands in
-    float32 are multiplied as float32 (the MXU's several passes), not
-    rounded to bfloat16."""
-    exact = a.dtype == F32 or b.dtype == F32
-    if exact:
-        a, b = a.astype(F32), b.astype(F32)
-    return lax.dot_general(a, b, (dims, ((), ())),
-                           preferred_element_type=F32,
-                           precision=HIGHEST if exact else None)
 
 
 # ------------------------------------------------ around the recurrence
@@ -104,27 +90,32 @@ def gates(a, b, a_log, dt_bias, allow_neg_eigval: bool):
     return g, 2.0 * beta if allow_neg_eigval else beta
 
 
-def causal_conv(x, w, true_len=None):
+def causal_conv(x, w, true_len=None, bias=None):
     """Depthwise causal convolution along the sequence, then SiLU: x (s,
     channels), w (width, channels), `y_t = silu(sum_i w_i x_{t - width + 1
-    + i})` with zeros before the sequence. Returns (y in x's dtype, the
-    last `width - 1` inputs before `true_len` (the sequence's end if None):
-    what a decode step continues from)."""
+    + i} + bias)` with zeros before the sequence (`bias` (channels,) or
+    None: none). Returns (y in x's dtype, the last `width - 1` inputs
+    before `true_len` (the sequence's end if None): what a decode step
+    continues from)."""
     s, width = x.shape[0], w.shape[0]
     xf = jnp.pad(x.astype(F32), ((width - 1, 0), (0, 0)))
     y = sum(w[i].astype(F32) * xf[i:i + s] for i in range(width))
+    if bias is not None:
+        y = y + bias.astype(F32)
     end = s if true_len is None else true_len
     # padded row `end + j` is input `end - (width - 1) + j`
     tail = lax.dynamic_slice_in_dim(xf, end, width - 1, axis=0)
     return jax.nn.silu(y).astype(x.dtype), tail.astype(x.dtype)
 
 
-def conv_step(x, tail, w):
+def conv_step(x, tail, w, bias=None):
     """One position of `causal_conv` a lane: x (B, channels), tail (B,
     width - 1, channels) the inputs before it. Returns (y, the new
     tail)."""
     window = jnp.concatenate([tail, x[:, None]], axis=1)
     y = jnp.sum(window.astype(F32) * w.astype(F32)[None], axis=1)
+    if bias is not None:
+        y = y + bias.astype(F32)
     return jax.nn.silu(y).astype(x.dtype), window[:, 1:]
 
 

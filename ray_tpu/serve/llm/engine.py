@@ -135,6 +135,15 @@ def _tree_bytes(tree) -> int:
     return sum(a.nbytes for a in jax.tree.leaves(tree))
 
 
+def _pool_shapes(cache) -> str:
+    """A cache's pools by name and shape, for a span: `k:6x2048x16x1024
+    v:...`; what a model counts beside them (scalars, a row a layer) is
+    left out."""
+    return " ".join(f"{name}:{'x'.join(map(str, a.shape))}"
+                    for name, a in cache.items()
+                    if getattr(a, "ndim", 0) >= 3)
+
+
 def _bucket(n: int, lo: int = 16, hi: int = 1 << 30) -> int:
     """Prefill pad bucket: next power of two — bounds distinct compiled
     prefill shapes at log2(max_seq_len)."""
@@ -245,6 +254,7 @@ class EngineCore:
             span.add(bytes=_tree_bytes(self._cache),
                      num_pages=self.num_pages,
                      fixed_pages=self.alloc.fixed_pages,
+                     pools=_pool_shapes(self._cache),
                      **({} if rows is None else {"pool_rows": rows}))
         self._waiting: deque = deque()
         # the sequences that hold a lane, oldest admission first

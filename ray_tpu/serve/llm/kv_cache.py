@@ -24,9 +24,12 @@ model's:
   so it keeps a ring of `fixed` = `window_pages` pages a sequence, logical
   page j at table entry `j mod fixed`, in a pool of its own that holds
   exactly the fixed class.
-- `HybridDelta`: a linear-attention layer keeps a recurrent state of one
-  size, so `fixed` is 1 and a sequence's first table entry is also its
-  *state slot*: the linear layers' pools are indexed by it.
+- `HybridDelta`, `HybridSSMMoE`: a recurrent layer (a delta rule's linear
+  attention, a state-space layer's selective scan) keeps a state of one
+  size, of whatever shape the model holds and prices
+  (`cache_page_bytes(fixed=True)`), so `fixed` is 1 and a sequence's first
+  table entry is also its *state slot*: the recurrent layers' pools are
+  indexed by it.
 
 A model that keeps nothing for ever has `fixed` 0 and the one class the
 allocator always had.

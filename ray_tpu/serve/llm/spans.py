@@ -17,8 +17,9 @@ SETUP = "engine.setup"
 SETUP_MODEL = "engine.setup.model"      # build_model, mesh and shardings
 # the engine's own `init` or the `device_put` of given weights; `bytes`
 SETUP_WEIGHTS = "engine.setup.weights"
-# `init_cache`; `bytes`, `num_pages`, `fixed_pages`, and `pool_rows` where
-# the model's pool has a row an attention (the latent caches)
+# `init_cache`; `bytes`, `num_pages`, `fixed_pages`, `pools` (each pool's
+# name and shape: pages, rings, state slots) and `pool_rows` where the
+# model's pool has a row an attention (the latent caches)
 SETUP_CACHE = "engine.setup.cache"
 # the rest of `EngineCore.__init__`: the jitted wrappers, the walk's table
 SETUP_PROGRAMS = "engine.setup.programs"
@@ -42,7 +43,7 @@ LOCK_WAIT = "engine.lock_wait"
 STEP = "engine.step"                # one EngineCore.step()
 # one admission: its prefill dispatched; carries `tokens`, `bucket` and
 # what the model says the prefill runs (`prefill_counts`: a model with
-# linear-attention layers, the `scan_chunks` of its recurrence)
+# recurrent layers, the `scan_chunks` of its recurrence)
 PREFILL = "engine.prefill"
 TABLES = "engine.page_tables"       # the decode batch's host arrays
 # carries this dispatch's counts: `lanes`, `live_positions`,
@@ -52,9 +53,9 @@ TABLES = "engine.page_tables"       # the decode batch's host arrays
 # model says the lanes' fixed parts cost (`fixed_step_counts`): for window
 # layers `window_positions_live` / `_read` / `_attended` and
 # `window_walk_blocks` (a layer that holds a sequence's last positions in
-# a ring), for linear-attention layers
-# `state_slots` / `state_bytes` (the lanes whose state the step reads and
-# writes, and the bytes moved for them)
+# a ring), for layers that hold a recurrent state (linear attention, a
+# selective scan) `state_slots` / `state_bytes` (the lanes whose state the
+# step reads and writes, and the bytes moved for them)
 DISPATCH = "engine.decode_dispatch"
 # waits for the tokens of the step before (and this call's prefills), with
 # the step just dispatched queued behind them on the device

@@ -22,6 +22,7 @@ from benchmarks.harness.peaks import PEAKS                   # noqa: E402
 E = xplane.Event
 OLMO = "olmo-hybrid-7b-1chip"
 CELL = OLMO + ".serve.answers3k"
+NEMOTRON_CELL = "nemotron-3-super-120b-a12b-1chip.serve.agent8k"
 NEW = ["kernel.delta_step_roofline.answers3k",
        "kernel.delta_chunk_roofline.answers3k",
        "step.attn_linear_ms.answers3k", "step.prefill_ms.answers3k",
@@ -188,5 +189,9 @@ def test_the_cell_is_listed_where_its_readers_find_something():
             "engine.kv_live_share.batch"} <= listed
     for m in bench["per_layer"]:
         if m["name"] in NEW:
-            assert m["workloads"] == [CELL]
+            # the delta rule's readers are this cell's alone; the
+            # prefill's reads any model that hands up `prefill_counts`
+            shares = ([NEMOTRON_CELL]
+                      if m["name"] == "step.prefill_ms.answers3k" else [])
+            assert m["workloads"] == [CELL] + shares
             assert m["moves"] == "serve_tokens_per_s"

@@ -478,3 +478,46 @@ def test_shortcut_mla_moe_programs_hold_their_kernels_by_name(
     assert pool == (2, PAGES, PAGE, 640)
     nbytes = 2 * pool[0] * pool[1] * pool[2] * pool[3]
     assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+
+
+# -------------------------------------- the sixth architecture's step
+def _compile_hybrid_ssm_moe(devices, which: str):
+    """`HybridSSMMoE`'s decode step or 4096-token prefill: one layer of
+    each kind at the published widths of Nemotron-3-Super (128 state-space
+    heads of 64 in 8 groups and a state of 128 x 8192 a lane; 32 query
+    heads over 2 kv heads of 128: a group of sixteen in the page walk; 128
+    of 512 experts of 1024 x 2688 held behind a latent), 32 state slots and
+    nobody's."""
+    from ray_tpu.models.hybrid_ssm_moe import (HybridSSMMoE,
+                                               HybridSSMMoEConfig)
+    model = HybridSSMMoE(HybridSSMMoEConfig(
+        vocab_size=1024, layer_types="M*E", experts_held=(0, 128),
+        max_seq_len=4096))
+    return _compile_served(
+        devices, model, which, lambda: model.init_cache(
+            PAGES, PAGE, fixed_pages=32 * model.fixed_pages(PAGE)),
+        "paged_decode_attn+ssd_step")
+
+
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_hybrid_ssm_moe_programs_hold_their_kernels_and_alias_the_state(
+        which, topo, no_compile_cache):
+    from ray_tpu.ops import grouped_matmul, ssd
+    compiled, cache = _compile_hybrid_ssm_moe(topo.devices, which)
+    names = kernel_names(compiled.as_text())
+    # up and down of the held experts: two matrices, no gate
+    assert names.count(grouped_matmul.KERNEL_GMM) == 2
+    if which == "step":
+        assert names.count(ssd.KERNEL_STEP) == 1
+        assert names.count(paged_attention.KERNEL_PAGED_DECODE) == 1
+        assert ssd.KERNEL_CHUNK not in names
+    else:
+        assert names.count(ssd.KERNEL_CHUNK) == 1
+        assert names.count(attention.KERNEL_FWD) == 1
+        assert ssd.KERNEL_STEP not in names
+    # every pool is updated in place: the state (33 slots of 128 x 8192
+    # float32), the tail, the keys and the values
+    assert cache["state"].shape == (1, 33, 128, 8192)
+    nbytes = sum(a.size * a.dtype.itemsize
+                 for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes

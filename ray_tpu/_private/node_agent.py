@@ -994,7 +994,7 @@ class NodeAgent:
         """Reclaim queued-not-started tasks for the head (revoke /
         steal). The scheduler pulls pending-queue entries out
         synchronously and probes worker FIFOs through the r6
-        UNQUEUE_TASK tombstone machinery; anything already started
+        UNQUEUE_TASK steal-back; anything already started
         stays here and completes through the normal done path.
 
         The hand-back is a fire-and-forget ``lease_reclaimed`` NODE

@@ -143,7 +143,7 @@ NODE_TASK_DONE_BATCH = "node_task_done_batch"  # agent -> head: N task
 NODE_LEASE_REVOKE = "node_lease_revoke"  # head -> agent, fire-and-
                                        #   forget: reclaim queued-not-
                                        #   started tasks (UNQUEUE
-                                       #   tombstone machinery for
+                                       #   steal-back for
                                        #   worker FIFOs); the hand-back
                                        #   is the agent's buffered
                                        #   "lease_reclaimed" NODE_EVENT,

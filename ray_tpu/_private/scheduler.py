@@ -669,7 +669,7 @@ class Scheduler:
         of this node and hand their specs to `callback` in one shot.
         Pending-queue entries come out synchronously; tasks already
         pipelined into a worker's FIFO go through the r6 UNQUEUE_TASK
-        tombstone machinery (async — the worker refuses if the task
+        steal-back (async — the worker refuses if the task
         started, in which case it stays leased here and runs to
         completion). `callback(reclaimed_specs)` fires exactly once,
         after every worker probe resolves."""

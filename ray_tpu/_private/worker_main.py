@@ -363,16 +363,16 @@ class WorkerExecutor:
             os.environ.get("RAY_TPU_TASK_EVENT_BUFFER", "32"))
         threading.Thread(target=self._event_flush_loop,
                          name="rtpu-task-events", daemon=True).start()
-        # pipelined-task steal-back (see UNQUEUE_TASK): tasks the driver
-        # reclaimed before they started; _run_task skips them silently.
-        # _queued_tasks tracks ids received but NOT yet started — the
-        # steal may only succeed against those; replying ok to a task
-        # that already ran would leave a poisoned tombstone that
-        # silently skips a lineage-resubmitted task with the same id.
+        # pipelined-task steal-back (see UNQUEUE_TASK): task id -> the
+        # spec of the TASK frame that is received and NOT yet started.
+        # A steal takes the entry out, and _run_task runs a frame only
+        # while its own spec object is still the one registered here.
+        # So the mark is on the frame and not on the id: the driver may
+        # send a task it stole back to this same worker again, and steal
+        # it again, before the exec thread reaches the first frame, and
+        # every stolen frame is skipped, not one frame for each id.
         self._queue_lock = threading.Lock()
-        self._queued_tasks: set[str] = set()
-        self._started_tasks: set[str] = set()
-        self._unqueued_tasks: set[str] = set()
+        self._queued_tasks: dict[str, TaskSpec] = {}
         # tasks/actor-calls accepted but not yet completion-reported:
         # TASK_DONE coalesces (lazy) only while OTHER work is in
         # flight — a lone sync round-trip must not eat the ~1 ms
@@ -501,7 +501,7 @@ class WorkerExecutor:
             spec = msg["spec"]
             self._stamp_recv(spec, msg)
             with self._queue_lock:
-                self._queued_tasks.add(spec.task_id)
+                self._queued_tasks[spec.task_id] = spec
                 self._inflight += 1
             self._pool.submit(self._run_task, spec)
         elif mtype == protocol.ACTOR_CREATE:
@@ -519,19 +519,12 @@ class WorkerExecutor:
             # driver steals back a task pipelined behind a BLOCKED task
             # (it would deadlock if the blocked get transitively depends
             # on it). ok only for a task that is genuinely queued and
-            # not started — a task that already started OR already
-            # COMPLETED (raced ahead of the steal decision) must refuse,
-            # or the tombstone would skip a future lineage resubmission
-            # of the same task id and hang its caller's get().
-            tid = msg["task_id"]
+            # not started: one that already started or already
+            # COMPLETED (raced ahead of the steal decision) has no
+            # entry, and the driver leaves it to the FIFO.
             with self._queue_lock:
-                if tid in self._queued_tasks:
-                    self._queued_tasks.discard(tid)
-                    self._unqueued_tasks.add(tid)
-                    ok = True
-                else:
-                    ok = False
-            conn.reply(msg, ok=ok)
+                stolen = self._queued_tasks.pop(msg["task_id"], None)
+            conn.reply(msg, ok=stolen is not None)
         elif mtype == protocol.TRACE_DUMP:
             conn.reply(msg, dump=_tp.dump())
         elif mtype == protocol.METRICS_DUMP:
@@ -810,14 +803,13 @@ class WorkerExecutor:
     def _run_task(self, spec: TaskSpec) -> None:
         from ray_tpu.exceptions import TaskCancelledError
         with self._queue_lock:
-            self._queued_tasks.discard(spec.task_id)
-            if spec.task_id in self._unqueued_tasks:
+            if self._queued_tasks.get(spec.task_id) is not spec:
                 # stolen back by the driver while queued: it was (or
-                # will be) re-dispatched elsewhere — skip silently
-                self._unqueued_tasks.discard(spec.task_id)
+                # will be) re-dispatched, here or elsewhere, as a frame
+                # of its own — skip this one silently
                 self._inflight = max(0, self._inflight - 1)
                 return
-            self._started_tasks.add(spec.task_id)
+            del self._queued_tasks[spec.task_id]
         t0 = time.time()
         t0m = time.monotonic()      # exec histogram: step-immune clock
         tctx = self._open_exec_span(spec)
@@ -864,11 +856,6 @@ class WorkerExecutor:
         self._record_event(spec.task_id, spec.name,
                            "EXEC_FAILED" if error else "EXEC_FINISHED",
                            duration_s=time.time() - t0)
-        with self._queue_lock:
-            self._started_tasks.discard(spec.task_id)
-            # completion purges any stale steal tombstone so a lineage
-            # resubmission reusing this task id can never be skipped
-            self._unqueued_tasks.discard(spec.task_id)
 
     def _create_actor(self, spec: ActorSpec) -> None:
         try:

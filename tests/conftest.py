@@ -5,7 +5,10 @@ sharding/mesh tests exercise real multi-device paths without TPU hardware —
 the analogue of the reference's same-host multi-raylet trick
 (reference python/ray/cluster_utils.py:135) per SURVEY.md §4.5.
 """
+import faulthandler
 import os
+import signal
+import tempfile
 
 # Force CPU even on a host with a TPU: unit tests always run on the
 # virtual 8-device CPU mesh, and only chip_smoke.py and bench.py touch
@@ -26,6 +29,99 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
 
 import pytest  # noqa: E402
+
+# Seconds a tier-1 test may spend in each of its phases (set-up, body,
+# tear-down). A test that hangs then fails alone, with every thread's
+# stack, instead of holding its xdist worker, the rest of its file and
+# the whole run until the run's own clock cuts it. Five times the slowest
+# tier-1 test of a whole loaded run (43.10 s: CHANGES.md, PR 47, has the
+# runs), rounded up, since that is more than 120. A test that needs more is `slow`
+# ("multi-minute", pytest.ini), and a `slow` test has no limit.
+# pytest.ini's faulthandler_timeout, somewhat under this, is the second
+# net: a hang inside a C call that holds the GIL never lets the handler
+# below run.
+LIMIT = 240.0
+# ... and what the post-mortem and the runtime's teardown may take after
+_GRACE = 20.0
+
+
+class _Expired(BaseException):
+    """Raised in the main thread by the alarm. Not an Exception: no
+    `except Exception` of the code under test swallows it."""
+
+
+def _on_alarm(signum, frame):
+    # armed again at once: should an `except BaseException` swallow this
+    # one, the next comes a few seconds later
+    signal.setitimer(signal.ITIMER_REAL, 5.0)
+    with tempfile.TemporaryFile("w+") as f:
+        faulthandler.dump_traceback(file=f, all_threads=True)
+        f.seek(0)
+        raise _Expired(f.read())
+
+
+signal.signal(signal.SIGALRM, _on_alarm)
+
+
+def _give_up_runtime() -> str:
+    """After an expiry: what the runtime's schedulers hold, then the
+    runtime's end, so that the next test's `ray_cluster` builds a fresh
+    one. Under an alarm of its own, since the runtime is what is sick;
+    the workers that a shutdown which was cut leaves behind are killed."""
+    import ray_tpu
+    from ray_tpu._private import context
+    rt = context.maybe_ctx()
+    if rt is None:
+        return ""
+    said, pids = [], []
+    signal.setitimer(signal.ITIMER_REAL, _GRACE)
+    try:
+        cluster = getattr(rt, "cluster", None)   # a remote driver has none
+        for node in cluster.alive_nodes() if cluster is not None else ():
+            rows = node.scheduler.workers_snapshot()
+            pids += [r["pid"] for r in rows if r["pid"]]
+            said += [repr(node.scheduler.stats())] + [repr(r) for r in rows]
+        ray_tpu.shutdown()
+    except (_Expired, Exception) as e:
+        said.append(f"the runtime's shutdown did not end: {type(e).__name__}")
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+    if context.maybe_ctx() is not None:
+        context.set_ctx(None)
+        for pid in pids:
+            try:
+                os.kill(pid, signal.SIGKILL)
+            except OSError:
+                pass
+    return "\nscheduler state at the expiry:\n" + "\n".join(said)
+
+
+def _time_limit(item, phase):
+    slow = item.get_closest_marker("slow") is not None
+    signal.setitimer(signal.ITIMER_REAL, 0 if slow else LIMIT)
+    try:
+        return (yield)
+    except _Expired as e:
+        stacks = str(e)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+    pytest.fail(f"{item.nodeid} exceeded {LIMIT:g} s in its {phase}\n"
+                f"{stacks}{_give_up_runtime()}", pytrace=False)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    return (yield from _time_limit(item, "set-up"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    return (yield from _time_limit(item, "body"))
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item):
+    return (yield from _time_limit(item, "tear-down"))
 
 
 @pytest.fixture(params=["native", "python"])

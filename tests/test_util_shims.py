@@ -130,10 +130,14 @@ def test_worker_side_task_events_and_host_stats(ray_cluster):
     deadline = _t.time() + 10
     evs = []
     while _t.time() < deadline:
-        # task name is the qualname (here: <test fn>.<locals>.work)
+        # task name is the qualname; the whole of it, since the runtime
+        # is shared and another file's `work` (an instant one, in
+        # tests/test_metrics_config.py) may have run on it before
         evs = [e for e in state.list_tasks()
                if e["state"].startswith("EXEC_")
-               and e.get("name", "").endswith("work")]
+               and e.get("name", "").endswith(
+                   "test_worker_side_task_events_and_host_stats"
+                   ".<locals>.work")]
         if sum(e["state"] == "EXEC_FINISHED" for e in evs) >= 3:
             break
         _t.sleep(0.25)

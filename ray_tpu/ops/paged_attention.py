@@ -10,8 +10,10 @@ prefetch, and copies in only the live pages of each lane, one DMA a page
 (a page is contiguous, and a kv head is whole lanes of its rows),
 double-buffered in blocks whose size comes from the bytes of a page
 (`walk_block_pages`); a block is multiplied over the part of it the lane
-holds (`walk_prefixes`). Lengths are data: one compiled program whatever
-the lanes hold.
+holds (`walk_prefixes`). The buffers outlive a lane: behind its last block
+a lane starts the first block of the lane after it, so the copies stop at
+a call's edge and not at a lane's (`walk_first_blocks_hidden`). Lengths
+are data: one compiled program whatever the lanes hold.
 
 Numerics follow `attention._flash_fwd_kernel`: keys and values stay in
 the pool's dtype for the MXU, scores, running max and sum and the
@@ -55,8 +57,9 @@ from ray_tpu.ops.dispatch import on_tpu, shard_kernel
 KERNEL_PAGED_DECODE = "paged_decode_attn"
 KERNEL_PAGED_SCOPE = "paged_decode_attention"
 
-# Blocks in VMEM at once: one attended to, the next on its way (more
-# bought nothing on a v5e: a block's matmuls, not its copies, take longest).
+# Blocks in VMEM at once: one attended to, the next on its way, be that the
+# lane's next or the next lane's first (more bought nothing on a v5e: a
+# block's matmuls, not its copies, take longest).
 BLOCK_SLOTS = 2
 # A block costs a v5e a fixed time whatever it holds (each head's chain of
 # score matmul, max, exp, sum, value matmul and rescale: half a microsecond
@@ -65,9 +68,13 @@ BLOCK_SLOTS = 2
 # bytes of VMEM the blocks' buffers may take, all slots and pools together
 # (what bounds a page of 30 kv heads) ...
 WALK_BUFFER_BYTES = 16 << 20
-# ... and its positions: a lane's first block is copied in with nothing to
-# multiply in front of it, and past these that wait outweighs the blocks
-# saved (what bounds a page of 8 kv heads, or of one latent row).
+# ... and its positions (what bounds a page of 8 kv heads, or of one latent
+# row). Until PR 48 a lane waited for its first block with nothing to
+# multiply in front of it, and past these that wait outweighed the blocks
+# saved. Now the lane before starts it, and blocks of 2,048 positions read
+# 2-13 % better from 1,180 positions a lane up; what holds the constant is
+# `walk_prefixes`: a lane of 257-512 positions would be multiplied at such
+# a block's half, 1,024, and reads 16-18 % worse (PERF.md section 6, PR 48).
 BLOCK_POSITIONS = 1024
 # Pages a turn of the loop that starts, or waits for, a block's copies
 # (unrolled, the scalar core overlaps their table reads and descriptors:
@@ -120,6 +127,14 @@ def walk_counts(pages: int, block_pages: int, page_size: int):
     tail = next((n for n in walk_prefixes(block_pages, page_size)
                  if n >= rest), 0) if rest else 0
     return full + bool(rest), (full * block_pages + tail) * page_size
+
+
+def walk_first_blocks_hidden(pages) -> int:
+    """Of a call's lanes, which hold `pages` pages each, those whose first
+    block is on its way when their turn comes, started by the lane before
+    them behind its own last block (`_walk_pages`): every lane that holds
+    pages but the first such, whatever empty lanes lie between."""
+    return max(sum(n > 0 for n in pages) - 1, 0)
 
 
 def paged_decode_tiles(head_dim: int, page_size: int, dtype) -> bool:
@@ -178,7 +193,7 @@ def _gathered_attention(q, k_pool, v_pool, layer, tables, seen):
 # mask of what a block holds, the online softmax, and the `pallas_call`
 # around it. A kernel's body names its keys and values; the rest is here.
 def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
-                l_ref, finite_ref, copies, value_buf, attend, *,
+                l_ref, finite_ref, hand_ref, copies, value_buf, attend, *,
                 page_size: int, block_pages: int, max_pages: int,
                 window: int = 0):
     """A paged decode kernel but for its matmuls: `attend(slot, start,
@@ -196,51 +211,71 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
     traces and lowers once a layer at set-up). `copies`: (pool in HBM, buffer, its semaphore's index after
     the slot's) a pool; `value_buf`: where the values are read;
     `finite_ref` (slots,) in SMEM: the walk's own note of how much of each
-    slot of it holds numbers.
+    slot of it holds numbers; `hand_ref` (2,) in SMEM: what a lane hands
+    the lane behind it, the slot its first block goes to and whether that
+    block is on its way already.
     With a `window` the lane sees its last `window` positions only and the
     table is a ring of `max_pages` entries (logical page j at entry j mod
-    `max_pages`): the walk begins at the first page the window reaches."""
+    `max_pages`): the walk begins at the first page the window reaches.
+
+    The grid runs the lanes in order and the scratch outlives a grid step:
+    behind its last block a lane has nothing of its own left to copy in,
+    so it starts the first block of the next lane that holds pages, into
+    the slot it is not multiplying from, and that lane finds it in flight
+    (`walk_first_blocks_hidden` counts them). Only the first such lane of
+    a call waits for a copy that nothing hides."""
     b = pl.program_id(0)
+    lanes = len_ref.shape[0]
     slots = value_buf.shape[0]
     layer = layer_ref[0]
-    length = len_ref[b]
-    if window:
-        first = jnp.maximum(length - window, 0) // page_size
-        n_pages = jnp.minimum(pl.cdiv(length, page_size) - first, max_pages)
-    else:
-        n_pages = jnp.minimum(pl.cdiv(length, page_size), max_pages)
-    n_blocks = pl.cdiv(n_pages, block_pages)
 
-    def page_at(blk, p):
+    def walk_of(lane):
+        """(lane, the first page its walk covers, how many): whose pages
+        a copy or a mask is about."""
+        length = len_ref[lane]
+        if window:
+            first = jnp.maximum(length - window, 0) // page_size
+            return lane, first, jnp.minimum(
+                pl.cdiv(length, page_size) - first, max_pages)
+        return lane, 0, jnp.minimum(pl.cdiv(length, page_size), max_pages)
+
+    me = walk_of(b)
+    length, first, n_blocks = len_ref[b], me[1], pl.cdiv(me[2], block_pages)
+    # the next lane that holds pages (`lanes`: none does), for its table;
+    # a lane that holds none hands nothing on and does not look
+    behind = lax.cond(n_blocks > 0, lambda: lax.while_loop(
+        lambda n: (n < lanes) & (walk_of(jnp.minimum(n, lanes - 1))[2] <= 0),
+        lambda n: n + 1, b + 1), lambda: jnp.int32(lanes))
+    after = walk_of(jnp.minimum(behind, lanes - 1))
+
+    def page_at(who, blk, p):
         """(table entry, whether the lane holds a page there) of page `p`
-        of block `blk`, one the walk reaches."""
+        of block `blk` of the walk `who`, one it reaches."""
+        lane, first, _ = who
         idx = blk * block_pages + p
         if window:
-            page = pt_ref[b * max_pages + (first + idx) % max_pages]
-        else:
-            page = pt_ref[b * max_pages + idx]
+            idx = (first + idx) % max_pages
+        page = pt_ref[lane * max_pages + idx]
         return page, page >= 0
 
-    def reach_of(blk):
-        """Pages of block `blk` the walk reaches."""
-        return jnp.minimum(n_pages - blk * block_pages, block_pages)
+    def reach_of(who, blk):
+        """Pages of block `blk` the walk `who` reaches."""
+        return jnp.minimum(who[2] - blk * block_pages, block_pages)
 
     prefixes = walk_prefixes(block_pages, page_size)
     piece = prefixes[0]
 
-    def each_copy(blk, act):
-        """`act` on the copy of every page of block `blk` the lane holds,
-        into the block's slot (a loop, not unrolled: a block of 64 pages
-        is traced as one page, and a short lane pays for the pages it
-        has). Returns how many pages those were."""
-        slot = blk % slots
-
-        reach = reach_of(blk)
+    def each_copy(who, blk, slot, act):
+        """`act` on the copy of every page of block `blk` the lane of
+        `who` holds, into `slot` (a loop, not unrolled: a block of 64
+        pages is traced as one page, and a short lane pays for the pages
+        it has). Returns how many pages those were."""
+        reach = reach_of(who, blk)
 
         def some(i, held):
             for p in range(COPY_UNROLL):
                 p = i * COPY_UNROLL + p
-                page, live = page_at(blk, p)
+                page, live = page_at(who, blk, p)
                 live = live & (p < reach)
 
                 @pl.when(live)
@@ -262,14 +297,17 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
     def _():
         for slot in range(slots):
             finite_ref[slot] = 0
+        hand_ref[0] = 0
+        hand_ref[1] = 0
 
-    def start(blk):
-        """Begin the copies of block `blk`. A page of the prefix the block
-        will be multiplied at that is not copied in keeps what its buffer
-        held, and a probability of 0 does not neutralise NaN (0 * NaN): so
-        first the slot's values are made numbers that far, once a call
-        (`finite_ref`: zeros, or later the values of an earlier block)."""
-        slot, reach = blk % slots, reach_of(blk)
+    def start(who, blk, slot):
+        """Begin the copies of block `blk` of the walk `who` into `slot`.
+        A page of the prefix the block will be multiplied at that is not
+        copied in keeps what its buffer held, and a probability of 0 does
+        not neutralise NaN (0 * NaN): so first the slot's values are made
+        numbers that far, once a call (`finite_ref`: zeros, or later the
+        values of an earlier block, this lane's or another's)."""
+        reach = reach_of(who, blk)
         need = prefixes[-1]
         for pages in prefixes[-2::-1]:
             need = jnp.where(reach <= pages, pages, need)
@@ -284,25 +322,41 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
                     part[:] = jnp.zeros_like(part)
                 under = pages
             finite_ref[slot] = need
-        each_copy(blk, lambda copy: copy.start())
+        each_copy(who, blk, slot, lambda copy: copy.start())
 
+    # the lane's blocks go to the slots in turn from where the lane before
+    # stopped; its first is on its way if that lane started it
+    base, handed = hand_ref[0], hand_ref[1] == 1
     for ahead in range(slots - 1):
-        @pl.when(ahead < n_blocks)
+        mine = ahead < n_blocks
+        @pl.when(mine if ahead else mine & ~handed)
         def _():
-            start(ahead)
+            start(me, ahead, (base + ahead) % slots)
+    hand_ref[0] = (base + n_blocks) % slots
+    hand_ref[1] = (handed & (n_blocks == 0)).astype(jnp.int32)
 
     def body(blk, carry):
-        @pl.when(blk + slots - 1 < n_blocks)
-        def _():
-            start(blk + slots - 1)
+        # behind the block being multiplied the next is copied in: this
+        # lane's or, behind its last, the first of the lane after it
+        ahead = blk + slots - 1
+        own = ahead < n_blocks
+        hand = (blk == n_blocks - 1) & (behind < lanes)
 
-        held = each_copy(blk, lambda copy: copy.wait())
+        @pl.when(own | hand)
+        def _():
+            start(tuple(jnp.where(own, mine, theirs)
+                        for mine, theirs in zip(me, after)),
+                  jnp.where(own, ahead, 0),
+                  (base + jnp.where(own, ahead, n_blocks)) % slots)
+            hand_ref[1] = hand.astype(jnp.int32)
+
+        slot = (base + blk) % slots
+        held = each_copy(me, blk, slot, lambda copy: copy.wait())
         # the block is multiplied over the shortest prefix that holds the
         # pages the walk reaches in it: what lies behind was not copied in
         # and is not read, what lies inside and is not live is not `seen`
-        reach = reach_of(blk)
-
-        slot, whole = blk % slots, held == reach
+        reach = reach_of(me, blk)
+        whole = held == reach
 
         def by_pieces():
             def one(i, carry):
@@ -328,13 +382,13 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
         return carry
 
     def seen_of(blk, start, pages, reach, whole):
-        """(1, positions) of `pages` pages of the block from its page
+        """(1, positions) of `pages` pages of the lane's block from its page
         `start`: which of them the lane sees, of the `reach` pages the walk
         reaches in the block; `whole`: none of those is missing from the
         table."""
         at = start * page_size + lax.broadcasted_iota(
             jnp.int32, (1, pages * page_size), 1)
-        pos = (blk * block_pages + (first if window else 0)) * page_size + at
+        pos = (blk * block_pages + first) * page_size + at
         seen = (pos < length) & (at < reach * page_size)
         if window:
             seen = seen & (pos >= length - window)
@@ -342,7 +396,7 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
         def holes():     # 1 where the table has no page (as int32: a
             def one(p, out):                 # branch yields no mask)
                 return jnp.where((at // page_size == p)
-                                 & ~page_at(blk, p)[1], 1, out)
+                                 & ~page_at(me, blk, p)[1], 1, out)
             return lax.fori_loop(start, jnp.minimum(reach, start + pages),
                                  one, jnp.zeros_like(at))
         return seen & (lax.cond(whole, lambda: jnp.zeros_like(at), holes)
@@ -413,10 +467,13 @@ def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
                 pltpu.VMEM(stat, jnp.float32),           # running max
                 pltpu.VMEM(stat, jnp.float32),           # running sum
                 pltpu.SMEM((BLOCK_SLOTS,), jnp.int32),   # slots made finite
+                pltpu.SMEM((2,), jnp.int32),        # lane to lane
             ]),
         out_shape=jax.ShapeDtypeStruct((lanes, *out), q.dtype),
+        # the lanes in order, one core: a lane starts the next one's copies;
         # the buffers beside what a call is given when it asks for nothing
         compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
             vmem_limit_bytes=WALK_BUFFER_BYTES + (16 << 20)),
         interpret=interpret,
         name=name,
@@ -431,7 +488,7 @@ def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
 def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
                          q_ref, k_hbm, v_hbm, o_ref,
                          k_buf, v_buf, sems, acc_ref, m_ref, l_ref,
-                         finite_ref, *, sm_scale: float, **walk):
+                         finite_ref, hand_ref, *, sm_scale: float, **walk):
     kvh, _, hd = q_ref.shape[1:]
 
     def attend(slot, start, pages, seen, rolled):
@@ -457,8 +514,9 @@ def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
                 head(h, slice(h * hd, (h + 1) * hd))
 
     _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
-                l_ref, finite_ref, ((k_hbm, k_buf, (0,)), (v_hbm, v_buf, (1,))),
-                v_buf, attend, **walk)
+                l_ref, finite_ref, hand_ref,
+                ((k_hbm, k_buf, (0,)), (v_hbm, v_buf, (1,))), v_buf, attend,
+                **walk)
 
 
 def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
@@ -671,7 +729,8 @@ def mla_paged_attention_reference(q, pool, layer, page_tables, lengths,
 def _mla_paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
                              q_ref, pool_hbm, o_ref,
                              buf, sems, acc_ref, m_ref, l_ref, finite_ref,
-                             *, sm_scale: float, latent: int, **walk):
+                             hand_ref, *, sm_scale: float, latent: int,
+                             **walk):
     width = buf.shape[3]
     q = q_ref[0]                                         # (heads, width)
 
@@ -683,8 +742,8 @@ def _mla_paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
                         acc_ref, m_ref, l_ref)
 
     _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
-                l_ref, finite_ref, ((pool_hbm, buf, ()),), buf, attend,
-                **walk)
+                l_ref, finite_ref, hand_ref, ((pool_hbm, buf, ()),), buf,
+                attend, **walk)
 
 
 # jitted for the reason `_paged_decode_call` is: traced once a program

@@ -294,6 +294,10 @@ class EngineCore:
             # the lanes' pages came in, and the positions its matmuls
             # multiplied (the part of each block a lane holds, in pieces)
             "kv_walk_blocks": 0, "kv_positions_attended": 0,
+            # and the lanes whose first block the lane before them started
+            # behind its own last: of `decode_lane_steps`, all but one a
+            # dispatch wait for no copy that nothing hides
+            "kv_walk_first_blocks_hidden": 0,
             **dict.fromkeys(_FIXED_COUNTERS.values(), 0),
             # and what the model counts on the device in a decode step,
             # under the model's own names (`step_stats`: the counts come
@@ -366,7 +370,9 @@ class EngineCore:
                                  * self.page_size)
         # the kernel's walk over a lane by the pages it holds: (blocks,
         # positions its matmuls multiply), by the kernel's own rule
-        from ray_tpu.ops.paged_attention import walk_counts
+        from ray_tpu.ops.paged_attention import (walk_counts,
+                                                 walk_first_blocks_hidden)
+        self._walk_hidden = walk_first_blocks_hidden
         self._walk_block = self.model.walk_block_pages(
             self.page_size, self.max_pages_per_seq)
         self._walk = [walk_counts(n, self._walk_block, self.page_size)
@@ -640,6 +646,7 @@ class EngineCore:
             active = np.zeros((B,), bool)
             kernel = self._attention != "einsum"
             live = held = blocks = attended = 0
+            walked: List[int] = []
             fixed: Dict[str, int] = {}
             for seq in batch:
                 i = seq.lane
@@ -652,6 +659,7 @@ class EngineCore:
                 if kernel:
                     blocks += self._walk[pages][0]
                     attended += self._walk[pages][1]
+                    walked.append(pages)
                 if self._fixed:     # what the lane's fixed part costs
                     for name, n in self.model.fixed_step_counts(
                             seq.device_len, self.page_size, kernel).items():
@@ -662,6 +670,7 @@ class EngineCore:
         read = held * self.page_size if kernel else self._table_positions
         if not kernel:      # the gather multiplies all it reads
             attended = read
+        hidden = self._walk_hidden(walked)
         c["decode_steps"] += 1
         c["decode_kernel_steps"] += int(kernel)
         c["decode_lane_steps"] += len(batch)
@@ -669,6 +678,7 @@ class EngineCore:
         c["kv_positions_read"] += read
         c["kv_walk_blocks"] += blocks
         c["kv_positions_attended"] += attended
+        c["kv_walk_first_blocks_hidden"] += hidden
         for name, n in fixed.items():
             c[_FIXED_COUNTERS[name]] += n
         # an annotation's attributes are fixed when it opens, so the
@@ -676,7 +686,7 @@ class EngineCore:
         with _Phase(phases, _sp.DISPATCH, lanes=len(batch),
                     live_positions=live, read_positions=read,
                     walk_blocks=blocks, attended_positions=attended,
-                    **fixed):
+                    walk_first_blocks_hidden=hidden, **fixed):
             logits, self._cache = self._decode_fn(
                 self.params, self._cache, self._tokens, *args)
             self._tokens, counts = self._next_fn(

@@ -47,9 +47,11 @@ STEP = "engine.step"                # one EngineCore.step()
 PREFILL = "engine.prefill"
 TABLES = "engine.page_tables"       # the decode batch's host arrays
 # carries this dispatch's counts: `lanes`, `live_positions`,
-# `read_positions`, `walk_blocks` and `attended_positions` (a layer whose
-# cache is whole: what the lanes hold, what the kernel copies in, the
-# blocks of its walk and the positions its matmuls multiply) and what the
+# `read_positions`, `walk_blocks`, `attended_positions` and
+# `walk_first_blocks_hidden` (a layer whose cache is whole: what the lanes
+# hold, what the kernel copies in, the blocks of its walk, the positions
+# its matmuls multiply, and the lanes whose first block the lane before
+# them started) and what the
 # model says the lanes' fixed parts cost (`fixed_step_counts`): for window
 # layers `window_positions_live` / `_read` / `_attended` and
 # `window_walk_blocks` (a layer that holds a sequence's last positions in

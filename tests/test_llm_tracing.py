@@ -113,9 +113,11 @@ def test_core_spans_and_parents(tiny_model, recorder):
     # and multiplies all of it, in no blocks
     assert disp == [
         {"lanes": 1, "live_positions": 6, "read_positions": read,
-         "walk_blocks": 0, "attended_positions": read},
+         "walk_blocks": 0, "attended_positions": read,
+         "walk_first_blocks_hidden": 0},
         {"lanes": 1, "live_positions": 7, "read_positions": read,
-         "walk_blocks": 0, "attended_positions": read}]
+         "walk_blocks": 0, "attended_positions": read,
+         "walk_first_blocks_hidden": 0}]
 
 
 def test_read_positions_under_the_kernel_are_the_pages_held(tiny_model,
@@ -137,16 +139,50 @@ def test_read_positions_under_the_kernel_are_the_pages_held(tiny_model,
     piece = core.max_pages_per_seq * 8
     assert disp == [
         {"lanes": 2, "live_positions": 6 + 21, "read_positions": 8 + 24,
-         "walk_blocks": 2, "attended_positions": 2 * piece},
+         "walk_blocks": 2, "attended_positions": 2 * piece,
+         "walk_first_blocks_hidden": 1},        # b's, behind a's
         {"lanes": 1, "live_positions": 7, "read_positions": 8,
-         "walk_blocks": 1, "attended_positions": piece}]
+         "walk_blocks": 1, "attended_positions": piece,
+         "walk_first_blocks_hidden": 0}]        # a call's first lane waits
     st = core.stats()
     assert st["kv_walk_blocks"] == 3
+    assert st["kv_walk_first_blocks_hidden"] == 1
     assert st["kv_positions_attended"] == 3 * piece
     assert st["kv_positions_read"] == 8 + 24 + 8
     assert st["kv_positions_live"] == 6 + 21 + 7
     assert st["decode_kernel_steps"] == st["decode_steps"] == 2
     assert core.device_stats()["decode_attention"] == "paged_decode_attn"
+
+
+def test_full_lanes_hide_every_first_block_but_one_a_step(tiny_model,
+                                                         recorder):
+    """Where the decode step holds the paged kernel, a dispatch's lanes
+    but one find their first block started by the lane before them: on
+    full lanes `walk_first_blocks_hidden` reads `lanes - 1` a step, and
+    `kv_walk_first_blocks_hidden` over `decode_lane_steps` (lanes - 1) /
+    lanes; `engine_stats()` carries both."""
+    from ray_tpu.ops.paged_attention import KERNEL_PAGED_DECODE
+    lanes = 4
+    core = _core(tiny_model, num_pages=64, max_batch=lanes)
+    core._attention = KERNEL_PAGED_DECODE
+    for i in range(lanes):
+        core.submit([7, 8, 9 + i], max_tokens=5, rid=f"r{i}")
+    _run(core)
+    disp = [e[7] for e in _mine(recorder) if e[4] == sp.DISPATCH]
+    assert [d["lanes"] for d in disp] == [lanes] * 4
+    assert [d["walk_first_blocks_hidden"] for d in disp] == [lanes - 1] * 4
+    st = core.stats()
+    assert st["decode_lane_steps"] == 4 * lanes
+    assert st["kv_walk_first_blocks_hidden"] == 4 * (lanes - 1)
+    eng = LLMEngine(model="tiny", num_pages=32, page_size=8, max_batch=2,
+                    seed=0)
+    try:        # the einsum here: nothing is walked, the count is there
+        assert len(read_stream(eng.generate([4, 5], max_tokens=2))[0]) == 2
+        st = eng.engine_stats()
+        assert st["decode_lane_steps"] >= 1
+        assert st["kv_walk_first_blocks_hidden"] == 0
+    finally:
+        eng.close()
 
 
 def test_request_spans_share_one_trace(tiny_model, recorder):

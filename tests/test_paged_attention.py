@@ -168,6 +168,224 @@ def test_kernel_skips_holes_in_a_full_block_and_in_a_tail_piece(
                                   np.asarray(got, np.float32))
 
 
+# ------------------------------------- a lane starts the next lane's block
+@pytest.fixture
+def walk_spy(monkeypatch):
+    """Counts, a grid step (a lane), the page copies the walk starts and
+    waits for: `spy()` gives (started, waited) by lane since the last
+    call. The kernel runs through the interpreter, so a callback can say
+    when each copy's `start` and `wait` ran."""
+    from jax.experimental import pallas as pl
+    calls = (pa._paged_decode_call, pa._paged_window_decode_call,
+             pa._mla_paged_decode_call)
+    lane, log = [], []
+    real_id, real_copy = pl.program_id, pa.pltpu.make_async_copy
+
+    def program_id(axis):           # the walk asks once, at its top
+        lane[:] = [real_id(axis)]
+        return lane[0]
+
+    class Copy:
+        def __init__(self, *args):
+            self.copy = real_copy(*args)
+
+        def start(self):
+            jax.debug.callback(lambda b: log.append((int(b), 0)), lane[0])
+            self.copy.start()
+
+        def wait(self):
+            jax.debug.callback(lambda b: log.append((int(b), 1)), lane[0])
+            self.copy.wait()
+
+    monkeypatch.setattr(pa.pl, "program_id", program_id)
+    monkeypatch.setattr(pa.pltpu, "make_async_copy", Copy)
+    for call in calls:
+        call.clear_cache()
+
+    def spy(lanes):
+        jax.effects_barrier()
+        counts = np.zeros((lanes, 2), int)
+        for b, what in log:
+            counts[b, what] += 1
+        del log[:]
+        return counts[:, 0], counts[:, 1]
+    yield spy
+    for call in calls:
+        call.clear_cache()
+
+
+def _handed_on(started, waited):
+    """The lanes that found their first block on its way: copies were
+    started and not yet waited for when the lane's turn came. Every copy
+    started is waited for in the call, a lane's by that lane."""
+    ahead = np.cumsum(started - waited) - (started - waited)
+    assert (ahead >= 0).all() and started.sum() == waited.sum()
+    return [int(b) for b in np.flatnonzero((ahead > 0) & (waited > 0))]
+
+
+def _pages(lengths, page=PAGE):
+    return [-(-n // page) for n in lengths]
+
+
+@pytest.mark.parametrize("lengths", [
+    [0, 45, 0, 0, 129],                         # past empty lanes
+    [77],                                       # a lane alone
+    [0, 0, 0],                                  # nothing to walk
+    [200, 0, 0],                                # nobody behind to hand to
+    [FULL, 1, FULL],
+], ids=lambda v: "-".join(map(str, v)))
+def test_a_lane_starts_the_next_lanes_first_block(walk_spy, lengths):
+    """The kernel's copies, counted a lane: every lane that holds pages
+    but the first such finds its first block started, by the lane before
+    it that holds pages; what `walk_first_blocks_hidden` says."""
+    q, k, v, pt, ln = _case(lengths, seed=8)
+    got = pa.paged_decode_attention_kernel(q, k, v, 1, pt, ln)
+    _close(got, pa.paged_attention_reference(q, k, v, 1, pt, ln))
+    started, waited = walk_spy(len(lengths))
+    pages = np.asarray(_pages(lengths))
+    np.testing.assert_array_equal(waited, 2 * pages)    # k and v
+    full = [b for b, n in enumerate(pages) if n]
+    assert _handed_on(started, waited) == full[1:]
+    assert len(full[1:]) == pa.walk_first_blocks_hidden(pages)
+    # a lane starts its own blocks but its first, and the first of the
+    # lane behind it (here every lane is one block)
+    for b, behind in zip(full, full[1:] + [None]):
+        own = 2 * pages[b] if b == full[0] else 0
+        assert started[b] == own + (2 * pages[behind] if behind else 0)
+
+
+@pytest.mark.parametrize("blocks", [
+    (1, 1, 1, 1),           # odd: the slot a lane begins at alternates
+    (2, 2, 2),              # even: every lane begins at the same slot
+    (1, 2, 3, 1, 2),        # mixed
+    (3, 0, 1, 0, 2, 2, 1),
+], ids=lambda v: "-".join(map(str, v)))
+def test_the_slot_is_carried_from_lane_to_lane(walk_budget, walk_spy,
+                                               blocks):
+    """Lanes of one, two and three blocks of 32 pages behind one another:
+    a lane's first block lands in the slot the lane before it is not
+    multiplying from, whatever slot that lane stopped in."""
+    lengths = [n * BLOCK - 40 if n else 0 for n in blocks]
+    q, k, v, pt, ln = _case(lengths, seed=9, MAX_PAGES=3 * 32)
+    # (the budget after the case: both clear the jitted calls' caches)
+    _blocks_of_32(walk_budget)
+    assert [pa.walk_counts(n, 32, PAGE)[0] for n in _pages(lengths)] == list(
+        blocks)
+    _close(pa.paged_decode_attention_kernel(q, k, v, 0, pt, ln),
+           pa.paged_attention_reference(q, k, v, 0, pt, ln))
+    handed = _handed_on(*walk_spy(len(lengths)))
+    assert handed == [b for b, n in enumerate(blocks) if n][1:]
+    assert len(handed) == pa.walk_first_blocks_hidden(_pages(lengths))
+
+
+def test_holes_in_the_block_handed_on_and_in_the_block_that_hands(
+        walk_budget, walk_spy):
+    """-1 in the first block of a lane whose block was started for it
+    (not copied by the lane before, not waited for by the lane itself) and
+    in the last block of the lane that started it."""
+    _blocks_of_32(walk_budget)
+    lengths = [BLOCK + 5 * PAGE, 20 * PAGE, 2 * BLOCK]
+    holes = [(0, 33), (0, 36), (1, 0), (1, 7), (1, 19), (2, 31), (2, 63)]
+    q, k, v, pt, ln = _case(lengths, seed=10, MAX_PAGES=LONG, holes=holes)
+    want = pa.paged_attention_reference(q, k, v, 0, pt, ln)
+    got = pa.paged_decode_attention_kernel(q, k, v, 0, pt, ln)
+    _close(got, want)
+    started, waited = walk_spy(3)
+    held = np.asarray(_pages(lengths)) - [2, 3, 2]
+    np.testing.assert_array_equal(waited, 2 * held)
+    assert _handed_on(started, waited) == [1, 2]
+    # page 0 is what a clamped -1 names: poison it, nothing may change
+    poisoned = k.at[:, 0].set(jnp.nan), v.at[:, 0].set(jnp.nan)
+    again = pa.paged_decode_attention_kernel(q, *poisoned, 0, pt, ln)
+    np.testing.assert_array_equal(np.asarray(again, np.float32),
+                                  np.asarray(got, np.float32))
+
+
+@pytest.mark.parametrize("lengths,holes", [
+    # each lane is multiplied further into its slot than any before it
+    ([3 * PAGE, 5 * PAGE, 12 * PAGE, 14 * PAGE, 20 * PAGE, 30 * PAGE],
+     [(2, 11), (3, 13), (4, 19), (5, 29)]),
+    # a long lane, then short ones handed the slots it filled, with holes
+    ([2 * BLOCK + 20 * PAGE, 3 * PAGE, 12 * PAGE, 3 * PAGE, 30 * PAGE],
+     [(1, 1), (2, 0), (2, 11), (4, 17)]),
+], ids=["rising", "falling"])
+def test_a_block_started_ahead_is_made_finite_to_its_own_lanes_reach(
+        walk_budget, lengths, holes):
+    """The interpreter's scratch begins as NaN, as a chip's may: a slot is
+    made numbers as far as the block that lands in it will be multiplied,
+    which for a block started ahead is what the NEXT lane reaches, not
+    what the lane that starts it does (`_close` refuses a NaN)."""
+    _blocks_of_32(walk_budget)
+    q, k, v, pt, ln = _case(lengths, seed=11, MAX_PAGES=LONG, holes=holes)
+    _close(pa.paged_decode_attention_kernel(q, k, v, 1, pt, ln),
+           pa.paged_attention_reference(q, k, v, 1, pt, ln))
+
+
+def _with_holes(tables, holes):
+    tables = np.array(tables)
+    for b, i in holes:
+        tables[b, i] = -1
+    return jnp.asarray(tables)
+
+
+@pytest.mark.parametrize("block,lengths,holes", [
+    (33, [1000, 0, 513, 40, 0, 2000], [(2, 5), (5, 32)]),   # a block each
+    (16, [1000, 0, 513, 40, 0, 2000], [(0, 3), (3, 0)]),    # 3, 3 and 1
+    (16, [100, 200, 300, 4000], []),                        # 1, 1, 2, 3
+], ids=["one-block", "blocks-of-16", "rising"])
+def test_window_lanes_hand_their_walk_on_over_a_ring(walk_budget, walk_spy,
+                                                     block, lengths, holes):
+    """The same over a ring: the next lane's first block begins at the
+    first page ITS window reaches, its table wrapped at its own length."""
+    from test_gqa_window_moe import _ring_case
+    window, kvh = 512, 2
+    q, kp, vp, tables, _ = _ring_case(lengths, window, PAGE, 4, kvh, seed=12)
+    page_bytes = 2 * PAGE * kvh * HD * 4
+    if block != 33:
+        walk_budget(pa.BLOCK_SLOTS * block * page_bytes)
+    assert pa.walk_block_pages(page_bytes, PAGE, 33) == block
+    args = (jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), 1,
+            _with_holes(tables, holes), jnp.asarray(lengths, jnp.int32),
+            window)
+    got = pa.paged_window_decode_attention_kernel(*args)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(
+        got, pa.paged_window_attention_reference(*args), rtol=2e-4,
+        atol=2e-5)
+    started, waited = walk_spy(len(lengths))
+    read = [pa.ring_walk(n, window, PAGE)[1] // PAGE for n in lengths]
+    assert waited.sum() == 2 * (sum(read) - len(holes))
+    handed = _handed_on(started, waited)
+    assert handed == [b for b, n in enumerate(lengths) if n][1:]
+    assert len(handed) == pa.walk_first_blocks_hidden(read)
+
+
+@pytest.mark.parametrize("lengths,holes", [
+    ([0, 300, 0, 0, 40], []),
+    ([600, 40, 300, 385], [(0, 70), (1, 0), (2, 3)]),   # 2 blocks, 1, 1, 1
+    ([20, 129, 255, 513], [(1, 16), (2, 30), (3, 40)]),  # further each lane
+], ids=["past-empty-lanes", "mixed-blocks", "rising"])
+def test_latent_lanes_hand_their_walk_on(walk_budget, walk_spy, lengths,
+                                         holes):
+    """The latent kernel stands on the same walk: one pool's copies."""
+    from test_mla_moe import _latent_case
+    q, pool, tables, ln = _latent_case(lengths, seed=13, pages=330,
+                                       max_pages=80)
+    walk_budget(pa.BLOCK_SLOTS * 64 * 8 * 256 * 4)
+    assert pa.walk_block_pages(8 * 256 * 4, 8, 80) == 64
+    args = (q, pool, 1, _with_holes(tables, holes), ln, 128, 0.1)
+    got = pa.mla_paged_decode_attention_kernel(*args)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, pa.mla_paged_attention_reference(*args),
+                               atol=2e-5, rtol=2e-5)
+    started, waited = walk_spy(len(lengths))
+    pages = _pages(lengths, 8)
+    assert waited.sum() == sum(pages) - len(holes)
+    handed = _handed_on(started, waited)
+    assert handed == [b for b, n in enumerate(lengths) if n][1:]
+    assert len(handed) == pa.walk_first_blocks_hidden(pages)
+
+
 # page bytes of a layer (all pools), table pages: the five configurations
 CELLS = {
     "internlm2-1.8b": (2 * 16 * 8 * 128 * 2, 256),

@@ -1,13 +1,18 @@
 """The three paged decode kernels on the chip, alone: `paged_decode_attn`,
 `paged_window_decode_attn` and `mla_paged_decode_attn` at the widths, lanes
-and table lengths of the serving cells, every lane at one length, 24 calls
+and table lengths of the serving cells, every lane at one length or
+(`--lanes mixed`) at lengths that differ, empty lanes among them, 24 calls
 (layers) a program. Prints milliseconds a layer and the share of the bytes
-`required_ops.paged_decode_call` says the algorithm needs, at 819 GB/s.
+`required_ops.paged_decode_call` says the algorithm needs, at 819 GB/s;
+then, a shape and block, what a lane and layer costs by its pages, its
+blocks and its first block's pages (`fit`: the last is the copy that
+nothing hides, a lane's before PR 48 and a call's first lane's since).
 Chip only:
 
     chiprun -- python tools/bench_paged.py
     chiprun -- python tools/bench_paged.py --sweep        # every block
     chiprun -- python tools/bench_paged.py --shapes pr27,laguna-window
+    chiprun -- python tools/bench_paged.py --lanes mixed  # unlike lanes
 
 `--sweep` puts each block size (in pages) in the place of
 `walk_block_pages`'s answer: what `WALK_BUFFER_BYTES` and `BLOCK_POSITIONS`
@@ -45,6 +50,9 @@ SHAPES = {
     "glm": ("latent", 32, 20, 1, 256, 0),
 }
 LATENT, ROW, ROW_HELD = 512, 640, 576
+# `--lanes mixed`: lane i is this share of the row's positions long, so a
+# lane hands its walk on to a shorter one, a longer one and past empty ones
+MIXED = (1.0, 0.25, 0.0, 1.0, 0.5, 0.0, 0.0, 0.75)
 
 
 def program(kernel, window):
@@ -80,22 +88,46 @@ def pools_of(kernel, lanes, heads, kvh, table):
             (k, k[:, ::-1]))
 
 
-def lanes_at(kernel, lanes, heads, kvh, table, window, length):
+def lanes_at(kernel, lanes, heads, kvh, table, window, length,
+             mixed=False):
     """(tables, lengths, bytes the algorithm needs a layer): every lane
-    `length` long, its pages anywhere in the pool."""
-    held = min(-(-length // PAGE), table)
+    `length` long, or `MIXED`'s shares of it in turn, its pages anywhere
+    in the pool."""
+    lens = np.full((lanes,), length, np.int32)
+    if mixed:
+        lens = (length * np.resize(MIXED, lanes)).astype(np.int32)
     rng = np.random.default_rng(length)
+    free = rng.permutation(lanes * table).astype(np.int32)
     tables = np.full((lanes, table), -1, np.int32)
-    tables[:, :held] = rng.permutation(lanes * table)[:lanes * held].reshape(
-        lanes, held)
-    live = lanes * (min(length, window) if window else length)
+    for lane, n in enumerate(lens):
+        held = min(-(-int(n) // PAGE), table)
+        tables[lane, :held], free = free[:held], free[held:]
+    live = int((np.minimum(lens, window) if window else lens).sum())
     if kernel == "latent":
         need = paged_decode_call(live, lanes, 1, ROW_HELD // 2,
                                  heads * (ROW_HELD + LATENT) // 2)
     else:
         need = paged_decode_call(live, lanes, 1, kvh * HD, heads * HD)
-    return (jnp.asarray(tables), jnp.full((lanes,), length, jnp.int32),
-            need["bytes"])
+    return jnp.asarray(tables), jnp.asarray(lens), need["bytes"]
+
+
+def fit(rows, lanes, block):
+    """Microseconds a lane and layer as `lane + page * pages + block *
+    blocks + first * (pages of the first block)`, least squares over the
+    rows of one shape, in blocks of `block` pages, whose lanes are all one
+    length: `first` is what a page of a copy that nothing hides costs.
+    None under five lengths."""
+    pages = np.array([r["pages"] for r in rows])
+    if len(set(pages)) < 5:
+        return None
+    terms = np.stack([np.ones_like(pages), pages, -(-pages // block),
+                      np.minimum(pages, block)], axis=1).astype(float)
+    us = np.array([r["ms_a_layer"] for r in rows]) * 1e3 / lanes
+    lane, page, blk, first = (float(c) for c in np.linalg.lstsq(
+        terms, us, rcond=None)[0])
+    return {"block": block, "lane_us": lane, "page_us": page,
+            "block_us": blk, "first_block_page_us": first,
+            "first_block_us": first * block}
 
 
 def timed(fn, *args):
@@ -140,6 +172,9 @@ def main():
     ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--positions", help="comma-separated lengths of a "
                     "lane, in place of " + ",".join(map(str, POSITIONS)))
+    ap.add_argument("--lanes", choices=("same", "mixed"), default="same",
+                    help="every lane at a row's positions, or at "
+                    "MIXED's shares of them")
     ap.add_argument("--prefixes", choices=("rule", "each"), default="rule")
     ap.add_argument("--heads", type=int, help="heads a turn of the loop "
                     "over a block's heads, in place of HEAD_UNROLL")
@@ -166,10 +201,12 @@ def main():
                 done.add(block)
             set_block(block, opts.prefixes)
             fn = program(kernel, window)
+            mine = []
             for length in positions:
                 if length > table * PAGE and not window:
                     continue
-                tables, lens, need = lanes_at(*SHAPES[name], length)
+                tables, lens, need = lanes_at(*SHAPES[name], length,
+                                              opts.lanes == "mixed")
                 try:
                     ms = timed(fn, q, tables, lens, *pools)
                 except Exception as e:      # say so and go on
@@ -177,13 +214,29 @@ def main():
                           f"failed: {str(e)[:300]}", flush=True)
                     continue
                 share = need / HBM * 1e5 / ms
-                rows.append({"shape": name, "block": block,
-                             "positions": length, "ms_a_layer": ms,
-                             "bytes_share": share})
-                print(f"{name} ({kernel}, {lanes} lanes) block "
-                      f"{block or 'as it stands'} positions {length}: "
-                      f"{ms:.4f} ms a layer, {share:.1f} % of the bytes' "
-                      f"floor", flush=True)
+                mine.append({"shape": name, "block": block,
+                             "lanes": opts.lanes, "positions": length,
+                             "pages": int((tables[0] >= 0).sum()),
+                             "ms_a_layer": ms, "bytes_share": share})
+                print(f"{name} ({kernel}, {lanes} lanes {opts.lanes}) "
+                      f"block {block or 'as it stands'} positions "
+                      f"{length}: {ms:.4f} ms a layer, {share:.1f} % of "
+                      f"the bytes' floor", flush=True)
+            rows += mine
+            terms = mine and opts.lanes == "same" and hasattr(
+                pa, "walk_block_pages") and fit(
+                mine, lanes, block or pa.walk_block_pages(sum(
+                    PAGE * p.shape[3] * p.dtype.itemsize for p in pools),
+                    PAGE, table))
+            if terms:
+                rows.append({"shape": name, "fit": terms})
+                print(f"{name} block {terms['block']} fit, us a lane and "
+                      f"layer: {terms['lane_us']:.2f} + "
+                      f"{terms['page_us']:.4f} a page + "
+                      f"{terms['block_us']:.2f} a block + "
+                      f"{terms['first_block_page_us']:.4f} a page of the "
+                      f"first block ({terms['first_block_us']:.2f} when "
+                      f"it is full)", flush=True)
     if opts.json:
         os.makedirs(os.path.dirname(opts.json) or ".", exist_ok=True)
         with open(opts.json, "w") as f:

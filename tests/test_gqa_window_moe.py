@@ -30,7 +30,9 @@ from benchmarks.harness import modelcfg                      # noqa: E402
 from benchmarks.harness.reference import rel_rms             # noqa: E402
 from benchmarks.harness.weights import make_weights          # noqa: E402
 from ray_tpu.models import (GQAWindowMoE, GQAWindowMoEConfig,  # noqa: E402
-                            MLAMoE, Transformer, build_model, model_config)
+                            HybridDeltaConfig, HybridSSMMoEConfig, MLAMoE,
+                            ShortcutMLAMoEConfig, Transformer, build_model,
+                            model_config)
 from ray_tpu.models.config import TransformerConfig          # noqa: E402
 from ray_tpu.models.gqa_window_moe import (RopeParams,       # noqa: E402
                                            tiny_gqa_window_moe)
@@ -571,25 +573,56 @@ PINNED = {
     # group of one query head: the third class is pinned to PR 36's text
     ("GQAWindowMoE", "prefill"): "2ee05703a435ae1e",
     ("GQAWindowMoE", "decode_step"): "89c5c77c979e1f86",
+    # the three classes after it, pinned in PR 49 to PR 48's text before
+    # what the classes share was lifted out of them (`models/paged.py`,
+    # `models/gqa.py`), so that the lift is held by hashes it did not write
+    ("HybridDelta", "prefill"): "276f489ed39b7daf",
+    ("HybridDelta", "decode_step"): "ad19cc87482cb346",
+    ("ShortcutMLAMoE", "prefill"): "d4d9ab62a550993a",
+    ("ShortcutMLAMoE", "decode_step"): "b117688674ce4a5d",
+    ("HybridSSMMoE", "prefill"): "18b87ab133474943",
+    ("HybridSSMMoE", "decode_step"): "6e595a332e8134a7",
+}
+
+# a class's configuration for its pin: small, and of head sizes that tile
+# (heads and latents of 128, chunks of whole tiles, bfloat16), so that the
+# paged, flash, delta and scan kernels are in the text
+PINNED_CONFIGS = {
+    "Transformer": lambda: TransformerConfig(
+        vocab_size=256, d_model=256, n_layers=2, n_heads=2, n_kv_heads=1,
+        d_ff=512, max_seq_len=128),
+    "MLAMoE": tiny_mla_moe,
+    "GQAWindowMoE": lambda: GQAWindowMoEConfig(
+        vocab_size=256, d_model=128, n_kv_heads=1, head_dim=128,
+        layer_types=("full_attention", "sliding_attention"),
+        n_heads_per_layer=(2, 4), mlp_layer_types=("dense", "sparse"),
+        sliding_window=32, d_ff=256, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, num_experts=8,
+        num_experts_per_tok=2, max_seq_len=128),
+    "HybridDelta": lambda: HybridDeltaConfig(
+        vocab_size=256, d_model=256, n_heads=2, n_kv_heads=2,
+        layer_types=("linear_attention", "full_attention"), linear_heads=2,
+        linear_key_dim=64, linear_value_dim=128, chunk=16, d_ff=256,
+        max_seq_len=128),
+    "ShortcutMLAMoE": lambda: ShortcutMLAMoEConfig(
+        vocab_size=256, d_model=128, n_layers=1, n_heads=2, q_lora_rank=64,
+        kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=64, d_ff=256, moe_intermediate_size=128,
+        n_routed_experts=8, zero_expert_num=4, experts_held=(2, 4),
+        num_experts_per_tok=2, max_seq_len=128),
+    "HybridSSMMoE": lambda: HybridSSMMoEConfig(
+        vocab_size=256, d_model=128, layer_types=tuple("ME*"), n_heads=2,
+        n_kv_heads=1, head_dim=128, ssm_heads=2, ssm_head_dim=64,
+        ssm_groups=1, ssm_state=128, chunk=128, moe_latent_size=64,
+        moe_intermediate_size=128, shared_intermediate_size=128,
+        n_routed_experts=8, experts_held=(2, 4), num_experts_per_tok=2,
+        max_seq_len=128),
 }
 
 
 @pytest.mark.parametrize("name,program", sorted(PINNED))
 def test_older_models_programs_lower_to_the_parents_text(name, program):
-    if name == "Transformer":
-        cfg = TransformerConfig(vocab_size=256, d_model=256, n_layers=2,
-                                n_heads=2, n_kv_heads=1, d_ff=512,
-                                max_seq_len=128)
-    elif name == "MLAMoE":
-        cfg = tiny_mla_moe()
-    else:       # heads of 128, so the paged and flash kernels are in it
-        cfg = GQAWindowMoEConfig(
-            vocab_size=256, d_model=128, n_kv_heads=1, head_dim=128,
-            layer_types=("full_attention", "sliding_attention"),
-            n_heads_per_layer=(2, 4), mlp_layer_types=("dense", "sparse"),
-            sliding_window=32, d_ff=256, moe_intermediate_size=128,
-            shared_expert_intermediate_size=128, num_experts=8,
-            num_experts_per_tok=2, max_seq_len=128)
+    cfg = PINNED_CONFIGS[name]()
     text = _programs(build_model(cfg), cfg)[program]
     assert "pallas_call" in text            # the kernels are in the text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[

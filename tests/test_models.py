@@ -434,3 +434,51 @@ def test_kept_bytes_a_token_and_layer_are_config_pys_figures():
     config = TransformerConfig(**_MISTRAL_7B, n_layers=1)
     assert [remat_kept_bytes(config, rung, 1) for rung in REMAT_RUNGS] == [
         0, 8320, 20608, 28800, 57472, 86144]
+
+
+# ----------------------------------------- the served classes' seam
+def _tiny_served():
+    from ray_tpu.models.gqa_window_moe import tiny_gqa_window_moe
+    from ray_tpu.models.hybrid_delta import tiny_hybrid_delta
+    from ray_tpu.models.hybrid_ssm_moe import tiny_hybrid_ssm_moe
+    from ray_tpu.models.mla_moe import tiny_mla_moe
+    from ray_tpu.models.shortcut_mla_moe import tiny_shortcut_mla_moe
+    return {"MLAMoE": tiny_mla_moe, "GQAWindowMoE": tiny_gqa_window_moe,
+            "HybridDelta": tiny_hybrid_delta,
+            "ShortcutMLAMoE": tiny_shortcut_mla_moe,
+            "HybridSSMMoE": tiny_hybrid_ssm_moe}
+
+
+def _tree_sha256(tree) -> str:
+    """One hash of a parameter tree: every leaf's path, shape, dtype and
+    bytes, in the tree's own order."""
+    import hashlib
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        a = np.asarray(leaf)
+        h.update(f"{jax.tree_util.keystr(path)} {a.shape} {a.dtype}".encode())
+        h.update(a.tobytes())
+    return h.hexdigest()[:16]
+
+
+# `init(PRNGKey(0))` of each served class at its tiny configuration, as
+# PR 48's classes gave it (recorded in PR 49 before `init` and its `fill`
+# moved into `models/paged.py`): the same key gives the same arrays under
+# the same names, so the benchmark's `weight_shapes` and every seeded test
+# see the trees they saw
+INIT_SHA256 = {
+    "MLAMoE": "74eb686498725a64",
+    "GQAWindowMoE": "4eb69e36a8d1a19f",
+    "HybridDelta": "8b3f53971ffd9817",
+    "ShortcutMLAMoE": "085f5ae8b852613d",
+    "HybridSSMMoE": "90619eeef1dbbf44",
+}
+
+
+@pytest.mark.parametrize("name", sorted(INIT_SHA256))
+def test_served_class_init_gives_the_arrays_it_gave(name):
+    from ray_tpu.models import build_model
+    model = build_model(_tiny_served()[name]())
+    assert type(model).__name__ == name
+    assert _tree_sha256(model.init(jax.random.PRNGKey(0))) == INIT_SHA256[
+        name]

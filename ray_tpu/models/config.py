@@ -12,8 +12,20 @@ from typing import Optional
 import jax.numpy as jnp
 
 
+class ConfigDtypes:
+    """A config's `dtype` and `param_dtype` as dtypes."""
+
+    @property
+    def activation_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def parameter_dtype(self):
+        return jnp.dtype(self.param_dtype)
+
+
 @dataclasses.dataclass(frozen=True)
-class TransformerConfig:
+class TransformerConfig(ConfigDtypes):
     vocab_size: int = 32000
     d_model: int = 4096
     n_layers: int = 32
@@ -78,42 +90,24 @@ class TransformerConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
-    @property
-    def activation_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def parameter_dtype(self):
-        return jnp.dtype(self.param_dtype)
-
-    def _ffn_params(self, active_only: bool = False) -> int:
+    def _ffn_params(self) -> int:
         e, f = self.d_model, self.d_ff
         if not self.moe_num_experts:
             return 3 * e * f
-        experts = self.moe_top_k if active_only else self.moe_num_experts
-        return experts * 3 * e * f + e * self.moe_num_experts  # + router
+        return self.moe_num_experts * (3 * e * f + e)       # + router
 
-    def num_params(self, active_only: bool = False) -> int:
-        """Parameter count (embeddings + layers + head). With MoE,
-        `active_only` counts router + top_k experts per token — the
-        number that matters for FLOPs."""
+    def num_params(self) -> int:
+        """Parameter count (embeddings + layers + head)."""
         e, hd = self.d_model, self.head_dim
         per_layer = (e * self.n_heads * hd          # wq
                      + 2 * e * self.kv_heads * hd   # wk, wv
                      + self.n_heads * hd * e        # wo
-                     + self._ffn_params(active_only)
+                     + self._ffn_params()
                      + 2 * e)                       # two norms
         total = self.vocab_size * e + self.n_layers * per_layer + e
         if not self.tie_embeddings:
             total += e * self.vocab_size
         return total
-
-    def flops_per_token(self) -> float:
-        """Approximate training FLOPs/token (fwd+bwd ≈ 6·N_active +
-        attention)."""
-        n = self.num_params(active_only=True)
-        attn = 12 * self.n_layers * self.d_model * self.max_seq_len
-        return 6.0 * n + attn
 
 
 def tiny(vocab_size: int = 256) -> TransformerConfig:
@@ -125,11 +119,10 @@ def tiny(vocab_size: int = 256) -> TransformerConfig:
 
 
 def bench_1b() -> TransformerConfig:
-    """The ~1B dense decoder `bench.py` and `chip_smoke.py` run, sized
-    for one 16 GB v5e chip: 953M parameters, bf16 weights and bf16 Adam
-    state, no remat at batch 2 x 2048, 1024-blocks for the flash kernels
-    (the `tools/bench_sweep.py` choice). Invented widths: Llama-shaped,
-    but no published model has them."""
+    """The ~1B dense decoder `chip_smoke.py` runs, sized for one 16 GB
+    v5e chip: 953M parameters, bf16 weights and bf16 Adam state, no remat
+    at batch 2 x 2048, 1024-blocks for the flash kernels. Invented widths:
+    Llama-shaped, but no published model has them."""
     return TransformerConfig(
         vocab_size=32000, d_model=2048, n_layers=16, n_heads=16,
         n_kv_heads=16, d_ff=5632, max_seq_len=2048, remat=False,

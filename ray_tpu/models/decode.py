@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.models.paged import decode_lanes
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin
@@ -247,13 +248,10 @@ def decode_step(model, params: Params, cache: KVCache,
                             tokens[:, None])               # (B, 1, e)
     cos, sin = rope_cos_sin(positions[:, None], hd, c.rope_theta)
 
-    my_page = jnp.take_along_axis(
-        page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
-    wr_page = jnp.where(active & (my_page >= 0), my_page, num_pages)
-    wr_slot = positions % page_size
     # cache slot j is visible iff j <= position and its page is assigned
     # (own-position k/v is written before the read)
-    lengths = jnp.where(active, positions + 1, 0)
+    wr_page, wr_slot, lengths = decode_lanes(positions, page_tables, active,
+                                             num_pages, page_size)
 
     layers = params["layers"]
     for i in range(c.n_layers):

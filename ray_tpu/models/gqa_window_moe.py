@@ -39,44 +39,26 @@ sliding one through `paged_window_decode_attention`, at most
 
 Beside the pools the cache carries what the experts did, as `MLAMoE`'s
 does and under the same names (`"moe_load"`, `"moe_step"`).
-
-Given a mesh the class refuses: neither the experts nor the two pools are
-sharded over chips yet (PERF.md section 7).
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.moe import dropless_moe_ffn
+from ray_tpu.models import gqa
+from ray_tpu.models.config import ConfigDtypes
+from ray_tpu.models.moe import STEP_COUNTS, DenseOrRoutedFFN
+from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
+                                  lane_page, prefill_page_ids_held)
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops import rope as _rope
-from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.losses import softmax_cross_entropy
-from ray_tpu.ops.norms import rms_norm
-
-Params = Dict[str, Any]
-Cache = Dict[str, Any]
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 DENSE, SPARSE = "dense", "sparse"
-# prefill's flash blocks (block_q, block_k), read on the chip at 1k, 4k
-# and 8k tokens (PERF.md, PR 35): a full layer's, as large as training's
-# (8.3 ms at 8192 tokens and 48 heads against 16.9 at 512 and 84 at 128:
-# a grid step costs what a small block's matmuls do); a sliding layer's
-# query block of 512 reaches two key blocks of 1024 (4.3 ms at 8192 tokens
-# and 64 heads against 8.9 at 256 x 256, where less is computed and masked
-# but the steps are four times as many)
-FULL_BLOCKS = (1024, 1024)
-SLIDING_BLOCKS = (512, 1024)
-# what a decode step counts over its expert layers (`Cache["moe_step"]`);
-# the engine's counters take these names
-STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max")
-
 
 @dataclasses.dataclass(frozen=True)
 class RopeParams:
@@ -115,7 +97,7 @@ class RopeParams:
 
 
 @dataclasses.dataclass(frozen=True)
-class GQAWindowMoEConfig:
+class GQAWindowMoEConfig(ConfigDtypes):
     """Fields under the published keys' meanings (`config.json` of
     `laguna`); the per-layer lists are tuples, one entry a layer."""
     vocab_size: int = 100352
@@ -186,14 +168,6 @@ class GQAWindowMoEConfig:
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
 
-    @property
-    def activation_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def parameter_dtype(self):
-        return jnp.dtype(self.param_dtype)
-
 
 def tiny_gqa_window_moe(vocab_size: int = 256) -> GQAWindowMoEConfig:
     """CI/debug model: every mechanism at a size the CPU runs in seconds:
@@ -214,25 +188,24 @@ def tiny_gqa_window_moe(vocab_size: int = 256) -> GQAWindowMoEConfig:
         param_dtype="float32")
 
 
-class GQAWindowMoE:
+class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
     """Functional model bundle for one GQAWindowMoEConfig: `init`, `apply`
-    / `loss` (a plain forward, the tests' and a trainer's), and what a
-    serving engine asks a model for (`init_cache`, `prefill`,
-    `decode_step`, `cache_page_bytes`, `fixed_pages`, `fixed_step_counts`,
-    `prefill_counts`, `decode_attention`, `step_stats`, `cache_stats`)."""
+    / `loss` (a plain forward, the tests' and a trainer's; on a TPU the
+    windowed flash kernel has no backward, so a trainer differentiates it
+    off the chip only), and what a serving engine asks a model for
+    (`models.paged.PagedDecoder`)."""
+
+    no_mesh = "experts and the two pools are not sharded over chips yet"
+    # a layer holds all its experts: none is away, no slot computes nothing
+    step_count_names = STEP_COUNTS[:3]
 
     def __init__(self, config: GQAWindowMoEConfig, mesh=None):
-        if mesh is not None:
-            raise NotImplementedError(
-                "GQAWindowMoE runs on one device and takes no mesh: experts "
-                "and the two pools are not sharded over chips yet")
-        self.config = config
+        super().__init__(config, mesh)
         self._ring_walks: Dict[int, list] = {}  # `fixed_step_counts`'s
 
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
-        """(shape, init std) of layer i's leaves; std 0 means zeros (a
-        norm scale, stored as w with the layer multiplying by 1 + w)."""
+        """A norm's scale is stored as w, the layer multiplying by 1 + w."""
         c = self.config
         e, q_dim = c.d_model, c.n_heads_per_layer[i] * c.head_dim
         std = 0.02
@@ -258,35 +231,7 @@ class GQAWindowMoE:
             shared_down=((fs, e), out_std))
         return shapes
 
-    def param_count(self) -> int:
-        c = self.config
-        return (2 * c.vocab_size * c.d_model + c.d_model + sum(
-            math.prod(shape) for i in range(c.n_layers)
-            for shape, _ in self.layer_shapes(i).values()))
-
-    def init(self, key: jax.Array) -> Params:
-        c = self.config
-        pd = c.parameter_dtype
-
-        def fill(key, shapes):
-            keys = jax.random.split(key, len(shapes))
-            return {name: (jax.random.normal(k, shape, jnp.float32)
-                           * std).astype(pd) if std else jnp.zeros(shape, pd)
-                    for k, (name, (shape, std)) in zip(keys,
-                                                       shapes.items())}
-
-        keys = jax.random.split(key, c.n_layers + 1)
-        top = fill(keys[-1], {
-            "embed": ((c.vocab_size, c.d_model), 0.02),
-            "lm_head": ((c.d_model, c.vocab_size), 0.02)})
-        return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
-                "layers": [fill(keys[i], self.layer_shapes(i))
-                           for i in range(c.n_layers)]}
-
     # --------------------------------------------------------- pieces
-    def _norm(self, x, w):
-        return rms_norm(x, w, self.config.norm_eps, None)
-
     def _ropes(self, positions: jax.Array):
         """kind -> (cos, sin) of `positions`: both tables, once a program."""
         c = self.config
@@ -297,14 +242,9 @@ class GQAWindowMoE:
         """h (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd), q
         and k rotated by the layer kind's scheme."""
         c = self.config
-        ad = c.activation_dtype
         cos, sin = ropes[c.layer_types[i]]
-        q = (h @ layer["wq"].astype(ad)).reshape(
-            *h.shape[:-1], c.n_heads_per_layer[i], c.head_dim)
-        k = (h @ layer["wk"].astype(ad)).reshape(
-            *h.shape[:-1], c.n_kv_heads, c.head_dim)
-        v = (h @ layer["wv"].astype(ad)).reshape(
-            *h.shape[:-1], c.n_kv_heads, c.head_dim)
+        q, k, v = gqa.qkv(layer, h, c.n_heads_per_layer[i], c.n_kv_heads,
+                          c.head_dim, c.activation_dtype)
         return (_rope.rotate_leading(q, cos, sin),
                 _rope.rotate_leading(k, cos, sin), v)
 
@@ -321,41 +261,20 @@ class GQAWindowMoE:
     def _attn_seq(self, i: int, layer: Params, h, ropes):
         """Causal attention of layer i over whole sequences h (b, s, e).
         Returns (attention output after W_o, k, v (b, s, kv heads, hd))."""
-        c = self.config
         q, k, v = self._qkv(i, layer, h, ropes)
-        sliding = c.layer_types[i] == SLIDING
-        block_q, block_k = SLIDING_BLOCKS if sliding else FULL_BLOCKS
-        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        out = flash_attention(
-            qt, kt, vt, causal=True, block_q=block_q, block_k=block_k,
-            window=c.sliding_window if sliding else None)
-        return self._attn_out(layer, h, out.transpose(0, 2, 1, 3)), k, v
+        return self._attn_out(layer, h, gqa.attend_seq(
+            q, k, v, self._window(i))), k, v
 
-    def _ffn(self, layer: Params, x, valid=None):
-        """Feed-forward of one layer on tokens x (T, e) after the norm.
-        Returns (y, expert counts or None for a dense layer)."""
+    def _window(self, i: int) -> Optional[int]:
+        """What layer i sees of a sequence: None is all of it."""
         c = self.config
-        ad = c.activation_dtype
-        if "router" not in layer:
-            gate = jax.nn.silu(x @ layer["gate"].astype(ad))
-            return (gate * (x @ layer["up"].astype(ad))) @ layer[
-                "down"].astype(ad), None
-        y, counts = dropless_moe_ffn(
-            x, layer["router"], jnp.zeros((c.num_experts,), jnp.float32),
-            layer["moe_gate"], layer["moe_up"], layer["moe_down"],
-            top_k=c.num_experts_per_tok, norm_topk_prob=True,
-            scale=c.routed_scaling_factor, valid=valid)
-        shared = jax.nn.silu(x @ layer["shared_gate"].astype(ad))
-        shared = (shared * (x @ layer["shared_up"].astype(ad))) @ layer[
-            "shared_down"].astype(ad)
-        return y + shared, counts
+        return c.sliding_window if c.layer_types[i] == SLIDING else None
 
-    def _block_ffn(self, layer: Params, x, valid=None):
-        """x (..., e) + ffn(norm(x)); returns (x, counts)."""
-        h = self._norm(x, layer["mlp_norm"])
-        y, counts = self._ffn(layer, h.reshape(-1, h.shape[-1]),
-                              None if valid is None else valid.reshape(-1))
-        return x + y.reshape(x.shape), counts
+    def _routing(self, layer: Params):
+        c = self.config                 # no correction bias
+        return jnp.zeros((c.num_experts,), jnp.float32), dict(
+            top_k=c.num_experts_per_tok, norm_topk_prob=True,
+            scale=c.routed_scaling_factor)
 
     # --------------------------------------------------------- forward
     def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
@@ -369,24 +288,6 @@ class GQAWindowMoE:
             x = x + self._attn_seq(i, layer, h, ropes)[0]
             x, _ = self._block_ffn(layer, x)
         return self._norm(x, params["final_norm"])
-
-    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
-        x = self.hidden(params, tokens)
-        head = params["lm_head"].astype(self.config.activation_dtype)
-        return (x @ head).astype(jnp.float32)
-
-    def loss(self, params: Params, batch: Dict[str, jax.Array]):
-        """Causal LM loss of batch["tokens"] (b, s), as `MLAMoE.loss`.
-        On a TPU the windowed flash kernel has no backward: a trainer
-        differentiates this off the chip only (PERF.md section 7)."""
-        tokens = batch["tokens"]
-        mask = batch.get("loss_mask")
-        logits = self.apply(params, tokens)[:, :-1]
-        if mask is not None:
-            mask = mask[:, 1:]
-        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
-        return loss
 
     # ------------------------------------------------ what an engine asks
     def window_pages(self, page_size: int) -> int:
@@ -425,10 +326,6 @@ class GQAWindowMoE:
                 for n in range(ring + 1)]
         return self._ring_walks[page_size]
 
-    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
-        """Nothing to add to the engine's prefill span."""
-        return {}
-
     def window_positions(self, length: int, page_size: int,
                          kernel: bool = True) -> Tuple[int, int]:
         """(positions a sliding layer holds live, positions its decode
@@ -453,11 +350,12 @@ class GQAWindowMoE:
         make = jax.jit(lambda: {
             "k": jnp.zeros(full, dt), "v": jnp.zeros(full, dt),
             "wk": jnp.zeros(ring, dt), "wv": jnp.zeros(ring, dt),
-            "moe_load": jnp.zeros((len(c.sparse_layers), c.num_experts),
-                                  jnp.int32),
-            "moe_step": {name: jnp.zeros((), jnp.int32)
-                         for name in STEP_COUNTS}})
+            **self._zero_counts()})
         return make()
+
+    @property
+    def expert_load_shape(self) -> Tuple[int, int]:
+        return len(self.config.sparse_layers), self.config.num_experts
 
     def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
                          dtype=None, fixed: bool = False) -> int:
@@ -466,53 +364,34 @@ class GQAWindowMoE:
         page of the ring (`fixed`), which a fixed-class page costs
         besides."""
         c = self.config
-        dt = jnp.dtype(dtype or c.activation_dtype)
-        layers = len(c.sliding_layers if fixed else c.full_layers)
-        return (2 * layers * page_size
-                * (c.kv_dim // max(1, tp_shards)) * dt.itemsize)
+        layers = c.sliding_layers if fixed else c.full_layers
+        return len(layers) * gqa.layer_page_bytes(
+            c.kv_dim, page_size, dtype or c.activation_dtype, tp_shards)
 
     def decode_attention(self, page_size: int, dtype=None) -> str:
-        """Which attention a `decode_step` traced here holds: the kernel
-        of each layer kind, or "einsum"."""
+        """The kernel of each layer kind, or "einsum"."""
         c = self.config
-        if _paged.uses_kernel(c.head_dim, page_size,
-                              dtype or c.activation_dtype):
-            return "+".join(
-                [_paged.KERNEL_PAGED_DECODE] * bool(c.full_layers)
-                + [_paged.KERNEL_PAGED_WINDOW_DECODE]
-                * bool(c.sliding_layers))
-        return "einsum"
+        return gqa.decode_kernels(
+            c.head_dim, page_size, dtype or c.activation_dtype,
+            [(_paged.KERNEL_PAGED_DECODE, c.full_layers),
+             (_paged.KERNEL_PAGED_WINDOW_DECODE, c.sliding_layers)])
 
     def walk_block_pages(self, page_size: int, max_pages: int,
                          fixed: bool = False) -> int:
-        """Pages a block of a full layer's walk holds over tables of
-        `max_pages` (of a sliding layer's over its ring: `fixed`), asked
-        what the kernel asks (a layer's page of keys and values)."""
+        """Of a full layer's walk over tables of `max_pages`, or of a
+        sliding layer's over its ring (`fixed`): a layer's page is the
+        same bytes in both."""
         c = self.config
-        layers = len(c.sliding_layers if fixed else c.full_layers)
-        return _paged.walk_block_pages(
-            self.cache_page_bytes(page_size, fixed=fixed) // max(1, layers),
-            page_size, max_pages)
-
-    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
-        """What the last decode step counted, still on the device, by the
-        names the engine's counters take."""
-        return cache["moe_step"] if self.config.sparse_layers else {}
-
-    def cache_stats(self, cache: Cache) -> Dict[str, Any]:
-        """For `EngineCore.device_stats()`: pairs an expert since the
-        cache was made, by expert layer."""
-        return {"moe_load": jax.device_get(cache["moe_load"]).tolist()}
+        return gqa.walk_block_pages(c.kv_dim, page_size, max_pages,
+                                    c.activation_dtype)
 
     def prefill(self, params: Params, tokens: jax.Array, true_len,
                 page_table: jax.Array, cache: Cache,
                 page_size: int) -> Tuple[jax.Array, Cache]:
-        """One padded prompt, as `models.decode.prefill`: every layer
-        through the flash kernel (a sliding one with its window), keys and
-        values written as whole pages in place (donate the cache): a full
+        """Every layer through the flash kernel (a sliding one with its
+        window), keys and values written as whole pages in place: a full
         layer's all, a sliding layer's last `window_pages` into its ring.
-        Padding past `true_len` is given to no expert. Returns
-        (last-position logits (vocab,) f32, cache)."""
+        Padding past `true_len` is given to no expert."""
         c = self.config
         ad = c.activation_dtype
         pools = {name: cache[name] for name in ("k", "v", "wk", "wv")}
@@ -522,49 +401,25 @@ class GQAWindowMoE:
         x = params["embed"].astype(ad)[tokens][None]            # (1, s, e)
         ropes = self._ropes(jnp.arange(s)[None])
         valid = (jnp.arange(s) < true_len)[None]
-        n = -(-s // page_size)
-        j = jnp.arange(n)
-        held = -(-true_len // page_size)         # pages the prompt fills
-        full_ids = jnp.where(j < held,
-                             jnp.take(page_table, j, mode="clip"), num_pages)
-        if ring:
-            # the newest logical page at each ring entry, and no other
-            ring_ids = jnp.where((j < held) & (j >= held - ring),
-                                 jnp.take(page_table, j % ring, mode="clip"),
-                                 ring_pages)
-        order = {FULL: ("k", "v", full_ids, c.full_layers),
-                 SLIDING: ("wk", "wv", ring_ids if ring else None,
-                           c.sliding_layers)}
-
-        def pages(a):
-            a = jnp.pad(a[0].reshape(s, c.kv_dim),
-                        ((0, n * page_size - s), (0, 0)))
-            return a.reshape(n, page_size, c.kv_dim)
-
+        full_ids, ring_ids = prefill_page_ids_held(
+            page_table, true_len, s, num_pages, page_size, ring, ring_pages)
+        order = {FULL: (("k", "v"), full_ids, c.full_layers),
+                 SLIDING: (("wk", "wv"), ring_ids, c.sliding_layers)}
         for i, layer in enumerate(params["layers"]):
             h = self._norm(x, layer["attn_norm"])
             attn, k, v = self._attn_seq(i, layer, h, ropes)
-            kn, vn, ids, layers = order[c.layer_types[i]]
-            li = layers.index(i)
-            pools[kn] = pools[kn].at[li, ids].set(
-                pages(k).astype(pools[kn].dtype), mode="drop")
-            pools[vn] = pools[vn].at[li, ids].set(
-                pages(v).astype(pools[vn].dtype), mode="drop")
+            names, ids, layers = order[c.layer_types[i]]
+            pools.update(gqa.write_prompt(pools, names, layers.index(i),
+                                          ids, k, v))
             x = x + attn
             x, _ = self._block_ffn(layer, x, valid)
-        x = self._norm(x, params["final_norm"])
-        last = jnp.take(x[0], true_len - 1, axis=0)
-        logits = (last @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, {**cache, **pools}
+        return self._logits(params, x, true_len), {**cache, **pools}
 
     def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
                     positions: jax.Array, page_tables: jax.Array,
                     active: jax.Array,
                     page_size: int) -> Tuple[jax.Array, Cache]:
-        """Advance a padded batch by one token each, as
-        `models.decode.decode_step`. Inactive lanes write nothing and are
-        given to no expert. Returns (logits (B, vocab) f32, cache) —
-        donate the cache."""
+        """Inactive lanes write nothing and are given to no expert."""
         c = self.config
         ad = c.activation_dtype
         pools = {name: cache[name] for name in ("k", "v", "wk", "wv")}
@@ -573,51 +428,39 @@ class GQAWindowMoE:
         B = tokens.shape[0]
         x = params["embed"].astype(ad)[tokens]                  # (B, e)
         ropes = self._ropes(positions)                # (B, 1, rot / 2)
+        # a lane's entry and row by hand: the ring needs the entry, and
+        # the order is the one this class's traced text has always had
         lengths = jnp.where(active, positions + 1, 0)
         logical = positions // page_size
         slot = positions % page_size
-
-        def write_page(entry, tables, oob):
-            page = jnp.take_along_axis(tables, entry[:, None], axis=1)[:, 0]
-            return jnp.where(active & (page >= 0), page, oob)
-
-        full_page = write_page(logical, page_tables, num_pages)
+        full = (("k", "v"), lane_page(page_tables, logical, active,
+                                      num_pages), page_tables, c.full_layers)
         if ring:
             ring_tables = page_tables[:, :ring]
-            ring_page = write_page(logical % ring, ring_tables, ring_pages)
+            sliding = (("wk", "wv"), lane_page(
+                ring_tables, logical % ring, active, ring_pages),
+                ring_tables, c.sliding_layers)
         load = cache["moe_load"]
-        pairs = touched = load_max = jnp.int32(0)
+        pairs, touched, load_max = self._step_sums()
         for i, layer in enumerate(params["layers"]):
             h = self._norm(x, layer["attn_norm"])
             q, k, v = self._qkv(i, layer, h, ropes)
+            # both flat before either is written (the traced text's order)
             k, v = k.reshape(B, c.kv_dim), v.reshape(B, c.kv_dim)
-            if c.layer_types[i] == FULL:
-                li = c.full_layers.index(i)
-                pools["k"] = pools["k"].at[li, full_page, slot].set(
-                    k.astype(pools["k"].dtype), mode="drop")
-                pools["v"] = pools["v"].at[li, full_page, slot].set(
-                    v.astype(pools["v"].dtype), mode="drop")
-                out = _paged.paged_decode_attention(
-                    q.astype(pools["k"].dtype), pools["k"], pools["v"], li,
-                    page_tables, lengths)
-            else:
-                li = c.sliding_layers.index(i)
-                pools["wk"] = pools["wk"].at[li, ring_page, slot].set(
-                    k.astype(pools["wk"].dtype), mode="drop")
-                pools["wv"] = pools["wv"].at[li, ring_page, slot].set(
-                    v.astype(pools["wv"].dtype), mode="drop")
-                out = _paged.paged_window_decode_attention(
-                    q.astype(pools["wk"].dtype), pools["wk"], pools["wv"],
-                    li, ring_tables, lengths, c.sliding_window)
+            names, page, tables, layers = (
+                full if c.layer_types[i] == FULL else sliding)
+            out, written = gqa.decode_attend(
+                pools, names, layers.index(i), page, slot, q, k, v, tables,
+                lengths, self._window(i))
+            pools.update(written)
             x = x + self._attn_out(layer, h, out.astype(ad))
             x, counts = self._block_ffn(layer, x, active)
             if counts is not None:
+                # as `_count_step`, the maximum taken after the two sums
+                # (the traced text's order)
                 load = load.at[c.sparse_layers.index(i)].add(counts["load"])
                 pairs = pairs + counts["pairs"]
                 touched = touched + counts["touched"]
                 load_max = load_max + jnp.max(counts["load"])
-        x = self._norm(x, params["final_norm"])
-        logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, {**pools, "moe_load": load,
-                        "moe_step": dict(zip(STEP_COUNTS, (
-                            pairs, touched, load_max)))}
+        return self._logits(params, x), {
+            **pools, **self._counted(load, (pairs, touched, load_max))}

@@ -28,53 +28,47 @@ here, any GQA grouping in general), q and k RMS-normed over their whole
 width before the heads are split, no rotary embedding: position comes
 from the recurrent layers.
 
-**Two kinds of cache behind one page table.** A full layer keeps every
-position: pools `"k"`, `"v"` of `(full layers, num_pages, page, kv x hd)`,
-logical page j of a sequence at its table's entry j. A linear layer keeps
-a sequence the same bytes at 100 positions and at 3,000: pools `"state"`
-`(linear layers, slots + 1, dk, H x dv)` float32 and `"tail"` (the
-convolution's last `width - 1` inputs, `(linear layers, slots + 1, (width
-- 1) x channels)`), a sequence's at the slot its **first table entry**
-names. That entry is a page of the allocator's fixed class
-(`serve/llm/kv_cache.py`: it names a state of any shape the model holds
-and prices, here a delta rule's; `HybridSSMMoE` keeps a selective scan's
-the same way), ids `0 .. slots - 1`, one a sequence, which the full
-layers' pools back like any page: one table serves both kinds and nothing
-is keyed by lane. `prefill` scans a prompt from a zero state
-(`gated_delta_prefill`: the chunk kernel, which stops at the prompt's true
-length inside its bucket) and writes the slot whole, so a slot that is
-reused holds nothing of its last owner; `decode_step` updates the slots of
-active lanes in place (`gated_delta_step`) and leaves every other alone.
-The pools' last slot is nobody's: where an inactive lane's block goes.
-
-Given a mesh the class refuses: neither the heads nor the state pools are
-sharded over chips yet (PERF.md section 7).
+**Two kinds of cache behind one page table** (`models/paged.py` has the
+addresses). A full layer keeps every position: pools `"k"`, `"v"` of
+`(full layers, num_pages, page, kv x hd)`, `models/gqa.py`'s. A linear
+layer keeps a sequence the same bytes at 100 positions and at 3,000: pools
+`"state"` `(linear layers, slots + 1, dk, H x dv)` float32 and `"tail"`
+(the convolution's last `width - 1` inputs, `(linear layers, slots + 1,
+(width - 1) x channels)`), a sequence's at the slot its first table entry
+names (`paged.StateSlots`: a page of the allocator's fixed class, which
+names a state of any shape the model holds and prices, here a delta
+rule's), which the full layers' pools back like any page: one table serves
+both kinds and nothing is keyed by lane. `prefill` scans a prompt from a
+zero state (`gated_delta_prefill`: the chunk kernel, which stops at the
+prompt's true length inside its bucket) and writes the slot whole;
+`decode_step` updates the slots of active lanes in place
+(`gated_delta_step`) and leaves every other alone.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import gqa
+from ray_tpu.models.config import ConfigDtypes
+from ray_tpu.models.moe import swiglu
+from ray_tpu.models.paged import (Cache, PagedDecoder, Params, StateSlots,
+                                  decode_state_slots, lane_page,
+                                  prefill_page_ids_held, prefill_state_slot,
+                                  slot_rows)
 from ray_tpu.ops import gated_delta as _gd
 from ray_tpu.ops import paged_attention as _paged
-from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.losses import softmax_cross_entropy
-from ray_tpu.ops.norms import rms_norm, rms_norm_reference
-
-Params = Dict[str, Any]
-Cache = Dict[str, Any]
+from ray_tpu.ops.norms import rms_norm_reference
 
 LINEAR, FULL = "linear_attention", "full_attention"
-# prefill's flash blocks, as `gqa_window_moe.FULL_BLOCKS`
-FULL_BLOCKS = (1024, 1024)
 
 
 @dataclasses.dataclass(frozen=True)
-class HybridDeltaConfig:
+class HybridDeltaConfig(ConfigDtypes):
     """Fields under the published keys' meanings (`config.json` of
     `olmo_hybrid`); `layer_types` a tuple, one entry a layer."""
     vocab_size: int = 100352
@@ -138,14 +132,6 @@ class HybridDeltaConfig:
     def conv_channels(self) -> int:
         return 2 * self.key_dim + self.value_dim
 
-    @property
-    def activation_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def parameter_dtype(self):
-        return jnp.dtype(self.param_dtype)
-
 
 def tiny_hybrid_delta(vocab_size: int = 256) -> HybridDeltaConfig:
     """CI/debug model: every mechanism at a size the CPU runs in seconds:
@@ -158,25 +144,17 @@ def tiny_hybrid_delta(vocab_size: int = 256) -> HybridDeltaConfig:
         max_seq_len=256, dtype="float32", param_dtype="float32")
 
 
-class HybridDelta:
+class HybridDelta(StateSlots, PagedDecoder):
     """Functional model bundle for one HybridDeltaConfig: `init`, `apply`
     / `loss` (the plain chunked form, differentiated by JAX), and what a
-    serving engine asks a model for (`init_cache`, `prefill`,
-    `decode_step`, `cache_page_bytes`, `fixed_pages`, `fixed_step_counts`,
-    `prefill_counts`, `decode_attention`, `step_stats`, `cache_stats`)."""
+    serving engine asks a model for (`models.paged.PagedDecoder`)."""
 
-    def __init__(self, config: HybridDeltaConfig, mesh=None):
-        if mesh is not None:
-            raise NotImplementedError(
-                "HybridDelta runs on one device and takes no mesh: heads "
-                "and the state pools are not sharded over chips yet")
-        self.config = config
+    no_mesh = "heads and the state pools are not sharded over chips yet"
 
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
-        """(shape, init std) of layer i's leaves; std 0 means zeros (a
-        norm scale w, the layer multiplying by 1 + w; `a_log`, `dt_bias`,
-        offsets from the config's initial values)."""
+        """Zeros are a norm's scale w, the layer multiplying by 1 + w, and
+        `a_log`, `dt_bias`, offsets from the config's initial values."""
         c = self.config
         e = c.d_model
         std = 0.02
@@ -197,45 +175,12 @@ class HybridDelta:
                 "o_norm": ((c.linear_value_dim,), 0.0),
                 "wo": ((c.value_dim, e), out_std), **ffn}
 
-    def param_count(self) -> int:
-        c = self.config
-        return (2 * c.vocab_size * c.d_model + c.d_model + sum(
-            math.prod(shape) for i in range(c.n_layers)
-            for shape, _ in self.layer_shapes(i).values()))
-
-    def init(self, key: jax.Array) -> Params:
-        c = self.config
-        pd = c.parameter_dtype
-
-        def fill(key, shapes):
-            keys = jax.random.split(key, len(shapes))
-            return {name: (jax.random.normal(k, shape, jnp.float32)
-                           * std).astype(pd) if std else jnp.zeros(shape, pd)
-                    for k, (name, (shape, std)) in zip(keys,
-                                                       shapes.items())}
-
-        keys = jax.random.split(key, c.n_layers + 1)
-        top = fill(keys[-1], {
-            "embed": ((c.vocab_size, c.d_model), 0.02),
-            "lm_head": ((c.d_model, c.vocab_size), 0.02)})
-        return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
-                "layers": [fill(keys[i], self.layer_shapes(i))
-                           for i in range(c.n_layers)]}
-
     # --------------------------------------------------------- pieces
-    def _norm(self, x, w):
-        return rms_norm(x, w, self.config.norm_eps, None)
-
-    def _mlp(self, layer: Params, x):
-        ad = self.config.activation_dtype
-        gate = jax.nn.silu(x @ layer["gate"].astype(ad))
-        return (gate * (x @ layer["up"].astype(ad))) @ layer[
-            "down"].astype(ad)
-
     def _close(self, layer: Params, x, mixed):
         """The rest of a block after its mixer: both post-norm adds."""
         x = x + self._norm(mixed, layer["attn_norm"])
-        return x + self._norm(self._mlp(layer, x), layer["mlp_norm"])
+        return x + self._norm(swiglu(x, layer["gate"], layer["up"],
+                                     layer["down"]), layer["mlp_norm"])
 
     def _full_qkv(self, layer: Params, x):
         """x (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd), q
@@ -254,10 +199,7 @@ class HybridDelta:
         """Causal attention over whole sequences x (b, s, e). Returns
         (the output after W_o, k, v (b, s, kv heads, hd))."""
         q, k, v = self._full_qkv(layer, x)
-        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        out = flash_attention(qt, kt, vt, causal=True,
-                              block_q=FULL_BLOCKS[0], block_k=FULL_BLOCKS[1])
-        out = out.transpose(0, 2, 1, 3).reshape(x.shape)
+        out = gqa.attend_seq(q, k, v).reshape(x.shape)
         return out @ layer["wo"].astype(self.config.activation_dtype), k, v
 
     def _linear_inputs(self, layer: Params, x, mixed):
@@ -331,30 +273,7 @@ class HybridDelta:
             x = self._close(layer, x, mixed)
         return self._norm(x, params["final_norm"])
 
-    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
-        x = self.hidden(params, tokens)
-        head = params["lm_head"].astype(self.config.activation_dtype)
-        return (x @ head).astype(jnp.float32)
-
-    def loss(self, params: Params, batch: Dict[str, jax.Array]):
-        """Causal LM loss of batch["tokens"] (b, s), as `MLAMoE.loss`. The
-        linear layers run the plain chunked form here: the chunk kernel
-        has no backward (PERF.md section 7)."""
-        tokens = batch["tokens"]
-        mask = batch.get("loss_mask")
-        logits = self.apply(params, tokens)[:, :-1]
-        if mask is not None:
-            mask = mask[:, 1:]
-        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
-        return loss
-
     # ------------------------------------------------ what an engine asks
-    def fixed_pages(self, page_size: int) -> int:
-        """Pages of the allocator's fixed class a sequence holds for ever:
-        one, its first table entry, which names its state slot."""
-        return int(bool(self.config.linear_layers))
-
     def state_bytes(self, dtype=None) -> int:
         """Bytes the linear layers keep of one sequence, whatever its
         length: a float32 state and the convolution's tail a layer."""
@@ -363,20 +282,6 @@ class HybridDelta:
         return len(c.linear_layers) * (
             c.linear_key_dim * c.value_dim * 4
             + (c.conv_width - 1) * c.conv_channels * dt.itemsize)
-
-    def fixed_step_counts(self, length: int, page_size: int,
-                          kernel: bool = True) -> Dict[str, int]:
-        """What a lane's fixed part costs a decode step, by the names the
-        engine's span carries: its state slot, and the bytes the linear
-        layers move for it (state and tail, read and written), whatever
-        its `length`."""
-        return {"state_slots": 1, "state_bytes": 2 * self.state_bytes()}
-
-    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
-        """What a prefill of `tokens` in its `bucket` runs, for the
-        engine's span: the chunks a linear layer scans (those that hold
-        the prompt; the kernel skips the bucket's others)."""
-        return {"scan_chunks": -(-tokens // self.config.chunk)}
 
     def init_cache(self, num_pages: int, page_size: int, dtype=None,
                    fixed_pages: int = 0) -> Cache:
@@ -395,138 +300,96 @@ class HybridDelta:
                                (c.conv_width - 1) * c.conv_channels), dt)})
         return make()
 
-    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
-                         dtype=None, fixed: bool = False) -> int:
-        """Bytes one page costs: keys and values of the full layers for a
-        page of the pool `num_pages` counts; what the linear layers keep
-        of a sequence (`fixed`), which its fixed-class page costs
-        besides."""
+    def page_bytes(self, page_size: int, tp_shards: int = 1,
+                   dtype=None) -> int:
+        """Keys and values of the full layers."""
         c = self.config
-        if fixed:
-            return self.state_bytes(dtype)
-        dt = jnp.dtype(dtype or c.activation_dtype)
-        return (2 * len(c.full_layers) * page_size
-                * (c.kv_dim // max(1, tp_shards)) * dt.itemsize)
+        return len(c.full_layers) * gqa.layer_page_bytes(
+            c.kv_dim, page_size, dtype or c.activation_dtype, tp_shards)
 
     def decode_attention(self, page_size: int, dtype=None) -> str:
-        """Which attention a `decode_step` traced here holds: the kernel
-        of each layer kind, or "einsum" (the full layers gather)."""
+        """The kernel of each layer kind, or "einsum"."""
         c = self.config
-        if not _paged.uses_kernel(c.head_dim, page_size,
-                                  dtype or c.activation_dtype):
-            return "einsum"
         step = (_gd.KERNEL_STEP if _gd.uses_step_kernel(
             c.linear_heads, c.linear_key_dim, c.linear_value_dim)
             else "gated_delta_gather")
-        return "+".join([_paged.KERNEL_PAGED_DECODE] * bool(c.full_layers)
-                        + [step] * bool(c.linear_layers))
+        return gqa.decode_kernels(
+            c.head_dim, page_size, dtype or c.activation_dtype,
+            [(_paged.KERNEL_PAGED_DECODE, c.full_layers),
+             (step, c.linear_layers)])
 
     def walk_block_pages(self, page_size: int, max_pages: int) -> int:
-        """Pages a block of the full layers' walk holds over tables of
-        `max_pages`, asked what the kernel asks (a layer's page of keys
-        and values)."""
-        return _paged.walk_block_pages(
-            self.cache_page_bytes(page_size)
-            // max(1, len(self.config.full_layers)), page_size, max_pages)
-
-    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
-        return {}
-
-    def cache_stats(self, cache: Cache) -> Dict[str, Any]:
-        return {}
+        """Of the full layers' walk."""
+        c = self.config
+        return gqa.walk_block_pages(c.kv_dim, page_size, max_pages,
+                                    c.activation_dtype)
 
     def prefill(self, params: Params, tokens: jax.Array, true_len,
                 page_table: jax.Array, cache: Cache,
                 page_size: int) -> Tuple[jax.Array, Cache]:
-        """One padded prompt, as `models.decode.prefill`: a full layer
-        through the flash kernel, its keys and values written as whole
-        pages in place (donate the cache); a linear layer scanned from a
+        """A full layer through the flash kernel, its keys and values
+        written as whole pages in place; a linear layer scanned from a
         zero state to `true_len`, its state and tail written whole into
-        the slot the table's first entry names. Returns (last-position
-        logits (vocab,) f32, cache)."""
+        the slot the table's first entry names."""
         c = self.config
         ad = c.activation_dtype
         pools = dict(cache)
         num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
-        s = tokens.shape[0]
         x = params["embed"].astype(ad)[tokens]                  # (s, e)
-        n = -(-s // page_size)
-        j = jnp.arange(n)
-        held = -(-true_len // page_size)         # pages the prompt fills
-        ids = jnp.where(j < held, jnp.take(page_table, j, mode="clip"),
-                        num_pages)
-        slot = page_table[0]
-        slot = jnp.where((slot >= 0) & (slot < slots), slot, slots + 1)
-
-        def pages(a):
-            a = jnp.pad(a[0].reshape(s, c.kv_dim),
-                        ((0, n * page_size - s), (0, 0)))
-            return a.reshape(n, page_size, c.kv_dim)
-
+        ids, _ = prefill_page_ids_held(page_table, true_len,
+                                       tokens.shape[0], num_pages,
+                                       page_size)
+        slot = prefill_state_slot(page_table, slots)
         for i, layer in enumerate(params["layers"]):
             if c.layer_types[i] == FULL:
                 li = c.full_layers.index(i)
                 mixed, k, v = self._full_seq(layer, x[None])
                 mixed = mixed[0]
-                for name, a in (("k", k), ("v", v)):
-                    pools[name] = pools[name].at[li, ids].set(
-                        pages(a).astype(pools[name].dtype), mode="drop")
+                pools.update(gqa.write_prompt(pools, ("k", "v"), li, ids,
+                                              k, v))
             else:
                 li = c.linear_layers.index(i)
                 mixed, state, tail = self._linear_seq(layer, x, true_len)
                 # (H, dk, dv) -> the pool's (dk, H x dv)
                 state = state.transpose(1, 0, 2).reshape(
                     c.linear_key_dim, c.value_dim)
-                pools["state"] = pools["state"].at[li, slot].set(
-                    state, mode="drop")
-                pools["tail"] = pools["tail"].at[li, slot].set(
-                    tail.reshape(-1).astype(pools["tail"].dtype),
-                    mode="drop")
+                pools.update(self._write_slot(pools, li, slot, state,
+                                              tail))
             x = self._close(layer, x, mixed)
-        x = self._norm(x, params["final_norm"])
-        last = jnp.take(x, true_len - 1, axis=0)
-        logits = (last @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, pools
+        return self._logits(params, x, true_len), pools
 
     def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
                     positions: jax.Array, page_tables: jax.Array,
                     active: jax.Array,
                     page_size: int) -> Tuple[jax.Array, Cache]:
-        """Advance a padded batch by one token each, as
-        `models.decode.decode_step`. An inactive lane, or one whose table
-        is unassigned, writes no page, no state and no tail. Returns
-        (logits (B, vocab) f32, cache) — donate the cache."""
+        """An inactive lane, or one whose table is unassigned, writes no
+        page, no state and no tail."""
         c = self.config
         ad = c.activation_dtype
         pools = dict(cache)
         num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
         B = tokens.shape[0]
         x = params["embed"].astype(ad)[tokens]                  # (B, e)
+        # `paged.decode_lanes`' three, in the order this class's traced
+        # text has always had them
         lengths = jnp.where(active, positions + 1, 0)
         logical = positions // page_size
         offset = positions % page_size
-        page = jnp.take_along_axis(page_tables, logical[:, None],
-                                   axis=1)[:, 0]
-        page = jnp.where(active & (page >= 0), page, num_pages)
-        first = page_tables[:, 0]
-        slot = jnp.where(active & (first >= 0) & (first < slots), first, -1)
-        tail_at = jnp.where(slot >= 0, slot, slots + 1)     # -1: dropped
+        page = lane_page(page_tables, logical, active, num_pages)
+        slot, tail_at = decode_state_slots(page_tables, active, slots)
         for i, layer in enumerate(params["layers"]):
             if c.layer_types[i] == FULL:
                 li = c.full_layers.index(i)
                 q, k, v = self._full_qkv(layer, x)
-                for name, a in (("k", k), ("v", v)):
-                    pools[name] = pools[name].at[li, page, offset].set(
-                        a.reshape(B, c.kv_dim).astype(pools[name].dtype),
-                        mode="drop")
-                out = _paged.paged_decode_attention(
-                    q.astype(pools["k"].dtype), pools["k"], pools["v"], li,
+                out, written = gqa.decode_attend(
+                    pools, ("k", "v"), li, page, offset, q, k, v,
                     page_tables, lengths)
+                pools.update(written)
                 mixed = out.astype(ad).reshape(B, -1) @ layer["wo"].astype(
                     ad)
             else:
                 li = c.linear_layers.index(i)
-                tail = pools["tail"][li, jnp.clip(slot, 0, slots)].reshape(
+                tail = slot_rows(pools["tail"], li, slot).reshape(
                     B, c.conv_width - 1, c.conv_channels)
                 mixed, tail = _gd.conv_step(
                     x @ layer["w_qkv"].astype(ad), tail, layer["conv"])
@@ -537,6 +400,4 @@ class HybridDelta:
                     q, k, v, g, beta, pools["state"], li, slot)
                 mixed = self._linear_out(layer, x, o)
             x = self._close(layer, x, mixed)
-        x = self._norm(x, params["final_norm"])
-        logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, pools
+        return self._logits(params, x), pools

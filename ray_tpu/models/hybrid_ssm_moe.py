@@ -44,47 +44,40 @@ pools `"k"`, `"v"` `(attention layers, num_pages, page, kv heads x head
 dim)` for the attention layers alone; for the state-space layers `"state"`
 `(M layers, slots + 1, N, H x P)` float32 and `"tail"` (the convolution's
 last `width - 1` inputs, `(M layers, slots + 1, (width - 1) x channels)`), a
-sequence's at the slot its **first table entry** names (a page of the
-allocator's fixed class, `serve/llm/kv_cache.py`). `prefill` scans a prompt
-from a zero state (`ssd_prefill`: the chunk kernel, which stops at the
-prompt's true length inside its bucket) and writes the slot whole, so a
-slot that is reused holds nothing of its last owner; `decode_step` updates
-the slots of active lanes in place (`ssd_step`) and leaves every other
-alone. The pools' last slot is nobody's. Beside them `"moe_load"` `(expert
-layers, held experts)` and `"moe_step"`, as `ShortcutMLAMoE`'s.
-
-Given a mesh the class refuses: neither the state pools nor the experts'
-exchange over chips have been built (PERF.md section 7).
+sequence's at the slot its first table entry names (`paged.StateSlots`).
+`prefill` scans a prompt from a zero state (`ssd_prefill`: the chunk
+kernel, which stops at the prompt's true length inside its bucket) and
+writes the slot whole; `decode_step` updates the slots of active lanes in
+place (`ssd_step`). Beside them `paged.ExpertCounts`' two entries.
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.latent import decode_lanes, prefill_page_ids
-from ray_tpu.models.moe import STEP_COUNTS, dropless_moe_ffn, step_counts
+from ray_tpu.models import gqa
+from ray_tpu.models.config import ConfigDtypes
+from ray_tpu.models.moe import dropless_moe_ffn
+from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
+                                  StateSlots, decode_lanes,
+                                  decode_state_slots,
+                                  prefill_page_ids, prefill_state_slot,
+                                  slot_rows)
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops import ssd as _ssd
-from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.gated_delta import causal_conv, conv_step
-from ray_tpu.ops.losses import softmax_cross_entropy
-from ray_tpu.ops.norms import rms_norm, rms_norm_reference
-
-Params = Dict[str, Any]
-Cache = Dict[str, Any]
+from ray_tpu.ops.norms import rms_norm_reference
 
 # a layer's kind, by the letters of the family's `hybrid_override_pattern`
 SSM, EXPERTS, ATTENTION = "M", "E", "*"
-# prefill's flash blocks, as `gqa_window_moe.FULL_BLOCKS`
-FULL_BLOCKS = (1024, 1024)
 
 
 @dataclasses.dataclass(frozen=True)
-class HybridSSMMoEConfig:
+class HybridSSMMoEConfig(ConfigDtypes):
     """Fields under the published keys' meanings (`config.json` of
     `nemotron_h`); `layer_types` the pattern, one letter a layer;
     `n_routed_experts` the experts of the whole layer and `experts_held`
@@ -158,14 +151,6 @@ class HybridSSMMoEConfig:
     def conv_channels(self) -> int:
         return self.ssm_inner + 2 * self.bc_dim
 
-    @property
-    def activation_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def parameter_dtype(self):
-        return jnp.dtype(self.param_dtype)
-
 
 def tiny_hybrid_ssm_moe(vocab_size: int = 256,
                         experts_held=(4, 4)) -> HybridSSMMoEConfig:
@@ -183,27 +168,19 @@ def tiny_hybrid_ssm_moe(vocab_size: int = 256,
         param_dtype="float32")
 
 
-class HybridSSMMoE:
+class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
     """Functional model bundle for one HybridSSMMoEConfig: `init`, `apply`
     / `loss` (the plain chunked scan, differentiated by JAX), and what a
-    serving engine asks a model for (`init_cache`, `prefill`,
-    `decode_step`, `cache_page_bytes`, `fixed_pages`, `fixed_step_counts`,
-    `prefill_counts`, `decode_attention`, `step_stats`, `cache_stats`)."""
+    serving engine asks a model for (`models.paged.PagedDecoder`)."""
 
-    def __init__(self, config: HybridSSMMoEConfig, mesh=None):
-        if mesh is not None:
-            raise NotImplementedError(
-                "HybridSSMMoE runs on one device and takes no mesh: neither "
-                "the state pools nor the experts' exchange over chips have "
-                "been built")
-        self.config = config
+    no_mesh = ("neither the state pools nor the experts' exchange over "
+               "chips have been built")
 
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
-        """(shape, init std) of layer i's leaves; std 0 means zeros (a
-        norm scale w, the layer multiplying by 1 + w; `a_log`, `dt_bias`,
-        `d`, offsets from the config's initial values; the convolution's
-        and the router's bias)."""
+        """Zeros are a norm's scale w, the layer multiplying by 1 + w;
+        `a_log`, `dt_bias`, `d`, offsets from the config's initial values;
+        the convolution's and the router's bias."""
         c = self.config
         e = c.d_model
         std = 0.02
@@ -234,35 +211,7 @@ class HybridSSMMoE:
                 "d": ((H,), 0.0), "gate_norm": ((c.ssm_inner,), 0.0),
                 "w_out": ((c.ssm_inner, e), out_std)}
 
-    def param_count(self) -> int:
-        c = self.config
-        return (2 * c.vocab_size * c.d_model + c.d_model + sum(
-            math.prod(shape) for i in range(c.n_layers)
-            for shape, _ in self.layer_shapes(i).values()))
-
-    def init(self, key: jax.Array) -> Params:
-        c = self.config
-        pd = c.parameter_dtype
-
-        def fill(key, shapes):
-            keys = jax.random.split(key, len(shapes))
-            return {name: (jax.random.normal(k, shape, jnp.float32)
-                           * std).astype(pd) if std else jnp.zeros(shape, pd)
-                    for k, (name, (shape, std)) in zip(keys,
-                                                       shapes.items())}
-
-        keys = jax.random.split(key, c.n_layers + 1)
-        top = fill(keys[-1], {
-            "embed": ((c.vocab_size, c.d_model), 0.02),
-            "lm_head": ((c.d_model, c.vocab_size), 0.02)})
-        return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
-                "layers": [fill(keys[i], self.layer_shapes(i))
-                           for i in range(c.n_layers)]}
-
     # --------------------------------------------------------- pieces
-    def _norm(self, x, w):
-        return rms_norm(x, w, self.config.norm_eps, None)
-
     def _ssm_project(self, layer: Params, u):
         """u (n, e) -> (z (n, H x P), xBC (n, channels) before the
         convolution, dt (n, H) before the softplus)."""
@@ -321,27 +270,14 @@ class HybridSSMMoE:
                                         c.ssm_groups, c.chunk)
         return self._ssm_out(layer, y[:s], x, z), state, tail
 
-    def _attn_qkv(self, layer: Params, u):
-        """u (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd)."""
-        c = self.config
-        ad = c.activation_dtype
-        lead = u.shape[:-1]
-        return ((u @ layer["wq"].astype(ad)).reshape(*lead, c.n_heads,
-                                                     c.head_dim),
-                (u @ layer["wk"].astype(ad)).reshape(*lead, c.n_kv_heads,
-                                                     c.head_dim),
-                (u @ layer["wv"].astype(ad)).reshape(*lead, c.n_kv_heads,
-                                                     c.head_dim))
-
     def _attn_seq(self, layer: Params, u):
         """Causal attention over whole sequences u (b, s, e). Returns (the
         output after W_o, k, v (b, s, kv heads, hd))."""
-        q, k, v = self._attn_qkv(layer, u)
-        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        out = flash_attention(qt, kt, vt, causal=True,
-                              block_q=FULL_BLOCKS[0], block_k=FULL_BLOCKS[1])
-        out = out.transpose(0, 2, 1, 3).reshape(*u.shape[:-1], -1)
-        return out @ layer["wo"].astype(self.config.activation_dtype), k, v
+        c = self.config
+        q, k, v = gqa.qkv(layer, u, c.n_heads, c.n_kv_heads, c.head_dim,
+                          c.activation_dtype)
+        out = gqa.attend_seq(q, k, v).reshape(*u.shape[:-1], -1)
+        return out @ layer["wo"].astype(c.activation_dtype), k, v
 
     def _experts(self, layer: Params, u, valid=None):
         """An expert layer's mixer on the normed stream u (n, e): this
@@ -378,30 +314,7 @@ class HybridSSMMoE:
             x = x + mixed
         return self._norm(x, params["final_norm"])
 
-    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
-        x = self.hidden(params, tokens)
-        head = params["lm_head"].astype(self.config.activation_dtype)
-        return (x @ head).astype(jnp.float32)
-
-    def loss(self, params: Params, batch: Dict[str, jax.Array]):
-        """Causal LM loss of batch["tokens"] (b, s), as `MLAMoE.loss`. The
-        state-space layers run the plain chunked form here: the chunk
-        kernel has no backward (PERF.md section 7)."""
-        tokens = batch["tokens"]
-        mask = batch.get("loss_mask")
-        logits = self.apply(params, tokens)[:, :-1]
-        if mask is not None:
-            mask = mask[:, 1:]
-        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
-        return loss
-
     # ------------------------------------------------ what an engine asks
-    def fixed_pages(self, page_size: int) -> int:
-        """Pages of the allocator's fixed class a sequence holds for ever:
-        one, its first table entry, which names its state slot."""
-        return int(bool(self.config.of_kind(SSM)))
-
     def state_bytes(self, dtype=None) -> int:
         """Bytes the state-space layers keep of one sequence, whatever its
         length: a float32 state and the convolution's tail a layer."""
@@ -410,20 +323,6 @@ class HybridSSMMoE:
         return len(c.of_kind(SSM)) * (
             c.ssm_state * c.ssm_inner * 4
             + (c.conv_width - 1) * c.conv_channels * dt.itemsize)
-
-    def fixed_step_counts(self, length: int, page_size: int,
-                          kernel: bool = True) -> Dict[str, int]:
-        """What a lane's fixed part costs a decode step, by the names the
-        engine's span carries: its state slot, and the bytes the
-        state-space layers move for it (state and tail, read and written),
-        whatever its `length`."""
-        return {"state_slots": 1, "state_bytes": 2 * self.state_bytes()}
-
-    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
-        """What a prefill of `tokens` in its `bucket` runs, for the
-        engine's span: the chunks a state-space layer scans (those that
-        hold the prompt; the kernel skips the bucket's others)."""
-        return {"scan_chunks": -(-tokens // self.config.chunk)}
 
     def init_cache(self, num_pages: int, page_size: int, dtype=None,
                    fixed_pages: int = 0) -> Cache:
@@ -440,84 +339,54 @@ class HybridSSMMoE:
                                jnp.float32),
             "tail": jnp.zeros((ssm, slots,
                                (c.conv_width - 1) * c.conv_channels), dt),
-            "moe_load": jnp.zeros((len(c.of_kind(EXPERTS)), c.held[1]),
-                                  jnp.int32),
-            "moe_step": {name: jnp.zeros((), jnp.int32)
-                         for name in STEP_COUNTS}})
+            **self._zero_counts()})
         return make()
 
-    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
-                         dtype=None, fixed: bool = False) -> int:
-        """Bytes one page costs: keys and values of the attention layers
-        for a page of the pool `num_pages` counts; what the state-space
-        layers keep of a sequence (`fixed`), which its fixed-class page
-        costs besides."""
+    @property
+    def expert_load_shape(self) -> Tuple[int, int]:
+        return len(self.config.of_kind(EXPERTS)), self.config.held[1]
+
+    def page_bytes(self, page_size: int, tp_shards: int = 1,
+                   dtype=None) -> int:
+        """Keys and values of the attention layers."""
         c = self.config
-        if fixed:
-            return self.state_bytes(dtype)
-        dt = jnp.dtype(dtype or c.activation_dtype)
-        return (2 * len(c.of_kind(ATTENTION)) * page_size
-                * (c.kv_dim // max(1, tp_shards)) * dt.itemsize)
+        return len(c.of_kind(ATTENTION)) * gqa.layer_page_bytes(
+            c.kv_dim, page_size, dtype or c.activation_dtype, tp_shards)
 
     def decode_attention(self, page_size: int, dtype=None) -> str:
-        """Which kernels a `decode_step` traced here holds, a layer kind
-        each, or "einsum" (the attention layers gather)."""
+        """The kernel of each layer kind, or "einsum"."""
         c = self.config
-        if not _paged.uses_kernel(c.head_dim, page_size,
-                                  dtype or c.activation_dtype):
-            return "einsum"
         step = (_ssd.KERNEL_STEP if _ssd.uses_step_kernel(
             c.ssm_inner, c.ssm_inner // c.ssm_groups, c.ssm_state)
             else "ssd_gather")
-        return "+".join(
-            [_paged.KERNEL_PAGED_DECODE] * bool(c.of_kind(ATTENTION))
-            + [step] * bool(c.of_kind(SSM)))
+        return gqa.decode_kernels(
+            c.head_dim, page_size, dtype or c.activation_dtype,
+            [(_paged.KERNEL_PAGED_DECODE, c.of_kind(ATTENTION)),
+             (step, c.of_kind(SSM))])
 
     def walk_block_pages(self, page_size: int, max_pages: int) -> int:
-        """Pages a block of the attention layers' walk holds over tables
-        of `max_pages`, asked what the kernel asks (a layer's page of keys
-        and values)."""
-        return _paged.walk_block_pages(
-            self.cache_page_bytes(page_size)
-            // max(1, len(self.config.of_kind(ATTENTION))), page_size,
-            max_pages)
-
-    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
-        """What the last decode step counted, as `ShortcutMLAMoE`'s."""
-        return cache["moe_step"]
-
-    def cache_stats(self, cache: Cache) -> Dict[str, Any]:
-        """For `EngineCore.device_stats()`: pairs a held expert since the
-        cache was made, by expert layer."""
-        return {"moe_load": jax.device_get(cache["moe_load"]).tolist()}
+        """Of the attention layers' walk."""
+        c = self.config
+        return gqa.walk_block_pages(c.kv_dim, page_size, max_pages,
+                                    c.activation_dtype)
 
     def prefill(self, params: Params, tokens: jax.Array, true_len,
                 page_table: jax.Array, cache: Cache,
                 page_size: int) -> Tuple[jax.Array, Cache]:
-        """One padded prompt, as `models.decode.prefill`: an attention
-        layer through the flash kernel, its keys and values written as
-        whole pages in place (donate the cache); a state-space layer
+        """An attention layer through the flash kernel, its keys and
+        values written as whole pages in place; a state-space layer
         scanned from a zero state to `true_len`, its state and tail
         written whole into the slot the table's first entry names; padding
-        past `true_len` given to no expert. Returns (last-position logits
-        (vocab,) f32, cache)."""
+        past `true_len` given to no expert."""
         c = self.config
         ad = c.activation_dtype
         pools = dict(cache)
         num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
         s = tokens.shape[0]
         x = params["embed"].astype(ad)[tokens]                  # (s, e)
-        n = -(-s // page_size)
         ids = prefill_page_ids(page_table, true_len, s, num_pages, page_size)
-        slot = page_table[0]
-        slot = jnp.where((slot >= 0) & (slot < slots), slot, slots + 1)
+        slot = prefill_state_slot(page_table, slots)
         valid = jnp.arange(s) < true_len
-
-        def pages(a):
-            a = jnp.pad(a[0].reshape(s, c.kv_dim),
-                        ((0, n * page_size - s), (0, 0)))
-            return a.reshape(n, page_size, c.kv_dim)
-
         for i, layer in enumerate(params["layers"]):
             u = self._norm(x, layer["norm"])
             kind = c.layer_types[i]
@@ -525,34 +394,24 @@ class HybridSSMMoE:
                 li = c.of_kind(ATTENTION).index(i)
                 mixed, k, v = self._attn_seq(layer, u[None])
                 mixed = mixed[0]
-                for name, a in (("k", k), ("v", v)):
-                    pools[name] = pools[name].at[li, ids].set(
-                        pages(a).astype(pools[name].dtype), mode="drop")
+                pools.update(gqa.write_prompt(pools, ("k", "v"), li, ids,
+                                              k, v))
             elif kind == EXPERTS:
                 mixed, _ = self._experts(layer, u, valid)
             else:
                 li = c.of_kind(SSM).index(i)
                 mixed, state, tail = self._ssm_seq(layer, u, true_len)
-                pools["state"] = pools["state"].at[li, slot].set(
-                    state, mode="drop")
-                pools["tail"] = pools["tail"].at[li, slot].set(
-                    tail.reshape(-1).astype(pools["tail"].dtype),
-                    mode="drop")
+                pools.update(self._write_slot(pools, li, slot, state,
+                                              tail))
             x = x + mixed
-        x = self._norm(x, params["final_norm"])
-        last = jnp.take(x, true_len - 1, axis=0)
-        logits = (last @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, pools
+        return self._logits(params, x, true_len), pools
 
     def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
                     positions: jax.Array, page_tables: jax.Array,
                     active: jax.Array,
                     page_size: int) -> Tuple[jax.Array, Cache]:
-        """Advance a padded batch by one token each, as
-        `models.decode.decode_step`. An inactive lane, or one whose table
-        is unassigned, writes no page, no state and no tail, and is given
-        to no expert. Returns (logits (B, vocab) f32, cache) — donate the
-        cache."""
+        """An inactive lane, or one whose table is unassigned, writes no
+        page, no state and no tail, and is given to no expert."""
         c = self.config
         ad = c.activation_dtype
         pools = dict(cache)
@@ -561,35 +420,30 @@ class HybridSSMMoE:
         x = params["embed"].astype(ad)[tokens]                  # (B, e)
         page, offset, lengths = decode_lanes(positions, page_tables, active,
                                              num_pages, page_size)
-        first = page_tables[:, 0]
-        slot = jnp.where(active & (first >= 0) & (first < slots), first, -1)
-        tail_at = jnp.where(slot >= 0, slot, slots + 1)     # -1: dropped
-        load = pools["moe_load"]
-        sums = [jnp.int32(0)] * len(STEP_COUNTS)
+        slot, tail_at = decode_state_slots(page_tables, active, slots)
+        load, sums = pools["moe_load"], self._step_sums()
         for i, layer in enumerate(params["layers"]):
             u = self._norm(x, layer["norm"])
             kind = c.layer_types[i]
             if kind == ATTENTION:
                 li = c.of_kind(ATTENTION).index(i)
-                q, k, v = self._attn_qkv(layer, u)
-                for name, a in (("k", k), ("v", v)):
-                    pools[name] = pools[name].at[li, page, offset].set(
-                        a.reshape(B, c.kv_dim).astype(pools[name].dtype),
-                        mode="drop")
-                out = _paged.paged_decode_attention(
-                    q.astype(pools["k"].dtype), pools["k"], pools["v"], li,
+                q, k, v = gqa.qkv(layer, u, c.n_heads, c.n_kv_heads,
+                                  c.head_dim, ad)
+                out, written = gqa.decode_attend(
+                    pools, ("k", "v"), li, page, offset, q, k, v,
                     page_tables, lengths)
+                pools.update(written)
                 mixed = out.astype(ad).reshape(B, -1) @ layer["wo"].astype(
                     ad)
             elif kind == EXPERTS:
                 li = c.of_kind(EXPERTS).index(i)
                 mixed, counts = self._experts(layer, u, active)
                 load = load.at[li].add(counts["load"])
-                sums = [a + n for a, n in zip(sums, step_counts(counts))]
+                sums = self._count_step(sums, counts)
             else:
                 li = c.of_kind(SSM).index(i)
                 z, xbc, dt = self._ssm_project(layer, u)
-                tail = pools["tail"][li, jnp.clip(slot, 0, slots)].reshape(
+                tail = slot_rows(pools["tail"], li, slot).reshape(
                     B, c.conv_width - 1, c.conv_channels)
                 conv, tail = conv_step(xbc, tail, layer["conv"],
                                        layer["conv_bias"])
@@ -601,7 +455,5 @@ class HybridSSMMoE:
                     c.ssm_groups)
                 mixed = self._ssm_out(layer, y, xs, z)
             x = x + mixed
-        x = self._norm(x, params["final_norm"])
-        logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, {**pools, "moe_load": load,
-                        "moe_step": dict(zip(STEP_COUNTS, sums))}
+        return self._logits(params, x), {**pools,
+                                         **self._counted(load, sums)}

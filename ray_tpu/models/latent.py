@@ -33,6 +33,7 @@ from typing import Any, Dict, Tuple
 
 import jax.numpy as jnp
 
+from ray_tpu.models.config import ConfigDtypes
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.norms import rms_norm_reference
@@ -44,7 +45,7 @@ Params = Dict[str, Any]
 ATTN_BLOCK = 128
 
 
-class LatentDims:
+class LatentDims(ConfigDtypes):
     """What a config with the latent attention's fields (`n_heads`,
     `q_lora_rank`, `kv_lora_rank`, `qk_nope_head_dim`, `qk_rope_head_dim`,
     `v_head_dim`, `d_model`) derives from them."""
@@ -61,14 +62,6 @@ class LatentDims:
     def row_width(self) -> int:
         """A cache row: latent and rotary key, padded to whole lanes."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
-
-    @property
-    def activation_dtype(self):
-        return jnp.dtype(self.dtype)
-
-    @property
-    def parameter_dtype(self):
-        return jnp.dtype(self.param_dtype)
 
 
 def attn_shapes(c, std: float, out_std: float
@@ -92,7 +85,10 @@ def attn_shapes(c, std: float, out_std: float
 
 class LatentAttention:
     """The latent attention of `self.config` (a `LatentDims`); `layer` is
-    the dict of one attention's leaves (`attn_shapes`)."""
+    the dict of one attention's leaves (`attn_shapes`). Mixed into a
+    `models.paged.PagedDecoder`, which it answers for about the pool; the
+    class says `pool_rows`, the rows of the latent pool, one an
+    attention."""
 
     def _q(self, layer: Params, h):
         """h (..., e) -> q (..., heads, nope + rope), not yet rotated."""
@@ -202,11 +198,6 @@ class LatentAttention:
         return out.reshape(h.shape[0], -1), pool
 
     # ------------------------------------------------ what an engine asks
-    @property
-    def pool_rows(self) -> int:
-        """Rows of the latent pool: one an attention."""
-        raise NotImplementedError
-
     def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
                          dtype=None) -> int:
         """Bytes one page costs (all pool rows): the rows as the pool holds
@@ -215,15 +206,6 @@ class LatentAttention:
         c = self.config
         dt = jnp.dtype(dtype or c.activation_dtype)
         return self.pool_rows * page_size * c.row_width * dt.itemsize
-
-    def fixed_pages(self, page_size: int) -> int:
-        """Nothing is kept of a sequence for ever: every attention's cache
-        grows with it (`kv_cache.PageAllocator`'s one class)."""
-        return 0
-
-    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
-        """Nothing to add to the engine's prefill span."""
-        return {}
 
     def decode_attention(self, page_size: int, dtype=None) -> str:
         """Which attention a `decode_step` traced here holds: the latent
@@ -240,26 +222,3 @@ class LatentAttention:
         return _paged.walk_block_pages(
             self.cache_page_bytes(page_size) // self.pool_rows,
             page_size, max_pages)
-
-
-def decode_lanes(positions, page_tables, active, num_pages: int,
-                 page_size: int):
-    """Where each lane of a decode step writes and how far it sees:
-    (wr_page, wr_slot, lengths). A lane that is inactive or whose page is
-    unassigned writes to page `num_pages`, which `mode="drop"` drops."""
-    my_page = jnp.take_along_axis(
-        page_tables, (positions // page_size)[:, None], axis=1)[:, 0]
-    wr_page = jnp.where(active & (my_page >= 0), my_page, num_pages)
-    return wr_page, positions % page_size, jnp.where(active, positions + 1,
-                                                     0)
-
-
-def prefill_page_ids(page_table, true_len, s: int, num_pages: int,
-                     page_size: int):
-    """The pages a padded prompt of `s` positions writes: the table's
-    first ceil(s / page_size) entries, those wholly past `true_len`
-    replaced by `num_pages` (dropped)."""
-    n = -(-s // page_size)
-    page_ids = jnp.take(page_table, jnp.arange(n), mode="clip")
-    return jnp.where(jnp.arange(n) * page_size < true_len, page_ids,
-                     num_pages)

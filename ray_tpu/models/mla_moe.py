@@ -33,38 +33,25 @@ scores `q_lat . c_kv + q_rope . k_rope`, `o_lat = P c_kv`, `o = o_lat W_UV`
 — one row read once, key and value both — through
 `ops.paged_attention.mla_paged_decode_attention`.
 
-Beside the pool the cache carries what the experts did: `"moe_load"`
-`(expert layers, experts)` int32, pairs an expert, accumulated over decode
-steps, and `"moe_step"`, the last decode step's `moe_pairs`,
-`moe_experts_touched` and `moe_load_max` (the busiest expert's pairs), each
-an int32 scalar summed over the expert layers, under the names the
-engine's counters take: it fetches them with the step's tokens.
-
-Given a mesh the class refuses: experts over chips have not been built
-(PERF.md section 7).
+Beside the pool the cache carries what the experts did
+(`paged.ExpertCounts`): `"moe_load"` and `"moe_step"`, the last decode
+step's `moe_pairs`, `moe_experts_touched` and `moe_load_max` (the busiest
+expert's pairs).
 """
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.latent import (LatentAttention, LatentDims, attn_shapes,
-                                   decode_lanes, prefill_page_ids)
-from ray_tpu.models.moe import dropless_moe_ffn
-from ray_tpu.ops.losses import softmax_cross_entropy
-from ray_tpu.ops.norms import rms_norm
+from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
+from ray_tpu.models.moe import STEP_COUNTS, DenseOrRoutedFFN
+from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
+                                  decode_lanes, prefill_page_ids)
 from ray_tpu.ops.rope import rope_cos_sin
-
-Params = Dict[str, Any]
-Cache = Dict[str, Any]
-
-# what a decode step counts over its expert layers (`Cache["moe_step"]`);
-# the engine's counters take these names
-STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -120,23 +107,20 @@ def tiny_mla_moe(vocab_size: int = 256) -> MLAMoEConfig:
         dtype="float32", param_dtype="float32")
 
 
-class MLAMoE(LatentAttention):
+class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
+             PagedDecoder):
     """Functional model bundle for one MLAMoEConfig: `init`, `apply` /
-    `loss` (training graph), and what a serving engine asks a model for
-    (`init_cache`, `prefill`, `decode_step`, `cache_page_bytes`,
-    `decode_attention`, `step_stats`, `cache_stats`)."""
+    `loss` (training graph; no auxiliary term: the published routing has a
+    bias moved between steps, not a loss), and what a serving engine asks a
+    model for (`models.paged.PagedDecoder`)."""
 
-    def __init__(self, config: MLAMoEConfig, mesh=None):
-        if mesh is not None:
-            raise NotImplementedError(
-                "MLAMoE runs on one device and takes no mesh: experts "
-                "and the latent cache are not sharded over chips yet")
-        self.config = config
+    no_mesh = "experts and the latent cache are not sharded over chips yet"
+    # a layer holds all its experts: none is away, no slot computes nothing
+    step_count_names = STEP_COUNTS[:3]
 
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
-        """(shape, init std) of layer i's leaves; std 0 means zeros (a
-        norm scale, stored as w with the layer multiplying by 1 + w)."""
+        """A norm's scale is stored as w, the layer multiplying by 1 + w."""
         c = self.config
         e = c.d_model
         std = 0.02
@@ -156,54 +140,12 @@ class MLAMoE(LatentAttention):
             shared_down=((fs, e), out_std))
         return shapes
 
-    def init(self, key: jax.Array) -> Params:
-        c = self.config
-        pd = c.parameter_dtype
-
-        def fill(key, shapes):
-            keys = jax.random.split(key, len(shapes))
-            return {name: (jax.random.normal(k, shape, jnp.float32)
-                           * std).astype(pd) if std else jnp.zeros(shape, pd)
-                    for k, (name, (shape, std)) in zip(keys,
-                                                       shapes.items())}
-
-        keys = jax.random.split(key, c.n_layers + 1)
-        top = fill(keys[-1], {
-            "embed": ((c.vocab_size, c.d_model), 0.02),
-            "lm_head": ((c.d_model, c.vocab_size), 0.02)})
-        return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
-                "layers": [fill(keys[i], self.layer_shapes(i))
-                           for i in range(c.n_layers)]}
-
     # --------------------------------------------------------- pieces
-    def _norm(self, x, w):
-        return rms_norm(x, w, self.config.norm_eps, None)
-
-    def _ffn(self, layer: Params, x, valid=None):
-        """Feed-forward of one layer on tokens x (T, e) after the norm.
-        Returns (y, expert counts or None for a dense layer)."""
+    def _routing(self, layer: Params):
         c = self.config
-        ad = c.activation_dtype
-        if "router" not in layer:
-            gate = jax.nn.silu(x @ layer["gate"].astype(ad))
-            return (gate * (x @ layer["up"].astype(ad))) @ layer[
-                "down"].astype(ad), None
-        y, counts = dropless_moe_ffn(
-            x, layer["router"], layer["router_bias"], layer["moe_gate"],
-            layer["moe_up"], layer["moe_down"],
+        return layer["router_bias"], dict(
             top_k=c.num_experts_per_tok, norm_topk_prob=c.norm_topk_prob,
-            scale=c.routed_scaling_factor, valid=valid)
-        shared = jax.nn.silu(x @ layer["shared_gate"].astype(ad))
-        shared = (shared * (x @ layer["shared_up"].astype(ad))) @ layer[
-            "shared_down"].astype(ad)
-        return y + shared, counts
-
-    def _block_ffn(self, layer: Params, x, valid=None):
-        """x (..., e) + ffn(norm(x)); returns (x, counts)."""
-        h = self._norm(x, layer["mlp_norm"])
-        y, counts = self._ffn(layer, h.reshape(-1, h.shape[-1]),
-                              None if valid is None else valid.reshape(-1))
-        return x + y.reshape(x.shape), counts
+            scale=c.routed_scaling_factor)
 
     # --------------------------------------------------------- forward
     def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
@@ -221,60 +163,29 @@ class MLAMoE(LatentAttention):
             x, _ = self._block_ffn(layer, x)
         return self._norm(x, params["final_norm"])
 
-    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
-        x = self.hidden(params, tokens)
-        head = params["lm_head"].astype(self.config.activation_dtype)
-        return (x @ head).astype(jnp.float32)
-
-    def loss(self, params: Params, batch: Dict[str, jax.Array]):
-        """Causal LM loss of batch["tokens"] (b, s), as
-        `Transformer.loss`; no auxiliary term (the published routing has
-        a bias moved between steps, not a loss)."""
-        tokens = batch["tokens"]
-        mask = batch.get("loss_mask")
-        logits = self.apply(params, tokens)[:, :-1]
-        if mask is not None:
-            mask = mask[:, 1:]
-        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
-        return loss
-
     # ------------------------------------------------ what an engine asks
     def init_cache(self, num_pages: int, page_size: int,
                    dtype=None) -> Cache:
         c = self.config
         dt = dtype or c.activation_dtype
         shape = (c.n_layers, num_pages, page_size, c.row_width)
-        make = jax.jit(lambda: {
-            "kv": jnp.zeros(shape, dt),
-            "moe_load": jnp.zeros((c.n_moe_layers, c.n_routed_experts),
-                                  jnp.int32),
-            "moe_step": {name: jnp.zeros((), jnp.int32)
-                         for name in STEP_COUNTS}})
+        make = jax.jit(lambda: {"kv": jnp.zeros(shape, dt),
+                                **self._zero_counts()})
         return make()
 
     @property
     def pool_rows(self) -> int:
         return self.config.n_layers
 
-    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
-        """What the last decode step counted: scalars still on the device,
-        by the names the engine's counters take. The engine fetches them
-        with the step's tokens."""
-        return cache["moe_step"] if self.config.n_moe_layers else {}
-
-    def cache_stats(self, cache: Cache) -> Dict[str, Any]:
-        """For `EngineCore.device_stats()`: pairs an expert since the
-        cache was made, by expert layer."""
-        return {"moe_load": jax.device_get(cache["moe_load"]).tolist()}
+    @property
+    def expert_load_shape(self) -> Tuple[int, int]:
+        return self.config.n_moe_layers, self.config.n_routed_experts
 
     def prefill(self, params: Params, tokens: jax.Array, true_len,
                 page_table: jax.Array, cache: Cache,
                 page_size: int) -> Tuple[jax.Array, Cache]:
-        """One padded prompt, as `models.decode.prefill`: the expanded
-        attention, the latent rows written as whole pages in place
-        (donate the cache). Padding past `true_len` is given to no
-        expert. Returns (last-position logits (vocab,) f32, cache)."""
+        """The expanded attention, the latent rows written as whole pages
+        in place. Padding past `true_len` is given to no expert."""
         c = self.config
         ad = c.activation_dtype
         pool = cache["kv"]
@@ -293,19 +204,14 @@ class MLAMoE(LatentAttention):
                                      page_size)
             x = x + attn @ layer["wo"].astype(ad)
             x, _ = self._block_ffn(layer, x, valid)
-        x = self._norm(x, params["final_norm"])
-        last = jnp.take(x[0], true_len - 1, axis=0)
-        logits = (last @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, {**cache, "kv": pool}
+        return self._logits(params, x, true_len), {**cache, "kv": pool}
 
     def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
                     positions: jax.Array, page_tables: jax.Array,
                     active: jax.Array,
                     page_size: int) -> Tuple[jax.Array, Cache]:
-        """Advance a padded batch by one token each, as
-        `models.decode.decode_step`, in the absorbed form. Inactive lanes
-        write nothing and are given to no expert. Returns (logits
-        (B, vocab) f32, cache) — donate the cache."""
+        """In the absorbed form. Inactive lanes write nothing and are
+        given to no expert."""
         c = self.config
         ad = c.activation_dtype
         pool = cache["kv"]
@@ -315,7 +221,7 @@ class MLAMoE(LatentAttention):
         wr_page, wr_slot, lengths = decode_lanes(
             positions, page_tables, active, pool.shape[1], page_size)
         load = cache["moe_load"]
-        pairs = touched = load_max = jnp.int32(0)
+        pairs, touched, load_max = self._step_sums()
         for i, layer in enumerate(params["layers"]):
             h = self._norm(x, layer["attn_norm"])
             out, pool = self._attn_absorbed(
@@ -324,14 +230,13 @@ class MLAMoE(LatentAttention):
             x = x + out @ layer["wo"].astype(ad)
             x, counts = self._block_ffn(layer, x, active)
             if counts is not None:
+                # as `_count_step`, the maximum taken after the two sums
+                # (the traced text's order)
                 j = i - c.first_k_dense_replace
                 load = load.at[j].add(counts["load"])
                 pairs = pairs + counts["pairs"]
                 touched = touched + counts["touched"]
                 load_max = load_max + jnp.max(counts["load"])
-        x = self._norm(x, params["final_norm"])
-        logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, {"kv": pool, "moe_load": load,
-                        "moe_step": dict(zip(STEP_COUNTS, (
-                            pairs, touched, load_max)))}
+        return self._logits(params, x), {
+            "kv": pool, **self._counted(load, (pairs, touched, load_max))}
 

@@ -255,9 +255,11 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
         "away_pairs": away_pairs}
 
 
-# What a decode step of a layer that holds a share of its experts counts
-# over its layers, by the names the engine's counters take; `step_counts`
-# gives one layer's in this order from `dropless_moe_ffn`'s counts.
+# What a decode step counts over its expert layers, by the names the
+# engine's counters take (a class whose layers hold all their experts
+# writes the leading three: none is away, no slot computes nothing);
+# `step_counts` gives one layer's in this order from `dropless_moe_ffn`'s
+# counts.
 STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
                "moe_zero_pairs", "moe_away_pairs")
 
@@ -265,3 +267,38 @@ STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
 def step_counts(counts: Dict[str, jax.Array]) -> Tuple[jax.Array, ...]:
     return (counts["pairs"], counts["touched"], jnp.max(counts["load"]),
             counts["zero_pairs"], counts["away_pairs"])
+
+
+def swiglu(x: jax.Array, gate_w, up_w, down_w) -> jax.Array:
+    """The dense feed-forward `(SiLU(x W_gate) * x W_up) W_down`, the
+    weights cast to x's dtype."""
+    gate = jax.nn.silu(x @ gate_w.astype(x.dtype))
+    return (gate * (x @ up_w.astype(x.dtype))) @ down_w.astype(x.dtype)
+
+
+class DenseOrRoutedFFN:
+    """The feed-forward of a class whose layers have a SwiGLU or, where
+    the layer has a `"router"`, `dropless_moe_ffn` over all the layer's
+    experts plus a shared expert applied to every token. The class says
+    `_routing(layer)`: (the router's bias, `dropless_moe_ffn`'s `top_k`,
+    `norm_topk_prob` and `scale`)."""
+
+    def _ffn(self, layer, x, valid=None):
+        """Feed-forward of one layer on tokens x (T, e) after the norm.
+        Returns (y, expert counts or None for a dense layer)."""
+        if "router" not in layer:
+            return swiglu(x, layer["gate"], layer["up"],
+                          layer["down"]), None
+        bias, how = self._routing(layer)
+        y, counts = dropless_moe_ffn(
+            x, layer["router"], bias, layer["moe_gate"], layer["moe_up"],
+            layer["moe_down"], valid=valid, **how)
+        return y + swiglu(x, layer["shared_gate"], layer["shared_up"],
+                          layer["shared_down"]), counts
+
+    def _block_ffn(self, layer, x, valid=None):
+        """x (..., e) + ffn(norm(x)); returns (x, counts)."""
+        h = self._norm(x, layer["mlp_norm"])
+        y, counts = self._ffn(layer, h.reshape(-1, h.shape[-1]),
+                              None if valid is None else valid.reshape(-1))
+        return x + y.reshape(x.shape), counts

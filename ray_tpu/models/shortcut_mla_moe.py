@@ -41,15 +41,11 @@ No shared expert, no leading dense layer.
 **The cache**: a layer owns two rows of the latent pool, `(2 x layers,
 pages, page_size, row_width)` under `"kv"`, row `2 i + j` attention `j` of
 layer `i`; both are written for every position, so a page costs twice what
-`MLAMoE`'s costs a layer. Beside it `"moe_load"` `(layers, held experts)`
-int32, pairs a held expert over decode steps, and `"moe_step"`, the last
-decode step's `STEP_COUNTS` summed over layers: `moe_pairs` (rows given to
-held experts), `moe_experts_touched`, `moe_load_max`, `moe_zero_pairs`
-(choices of a slot that computes nothing) and `moe_away_pairs` (choices of
-an expert held elsewhere), under the names the engine's counters take.
-
-Given a mesh the class refuses: the exchange over chips has not been built
-(PERF.md section 7).
+`MLAMoE`'s costs a layer. Beside it `paged.ExpertCounts`' two entries,
+`"moe_step"` all of `moe.STEP_COUNTS`: `moe_pairs` (rows given to held
+experts), `moe_experts_touched`, `moe_load_max`, `moe_zero_pairs` (choices
+of a slot that computes nothing) and `moe_away_pairs` (choices of an
+expert held elsewhere).
 """
 from __future__ import annotations
 
@@ -60,16 +56,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.latent import (LatentAttention, LatentDims, attn_shapes,
-                                   decode_lanes, prefill_page_ids)
-from ray_tpu.models.moe import (SCORING, STEP_COUNTS, dropless_moe_ffn,
-                                step_counts)
-from ray_tpu.ops.losses import softmax_cross_entropy
-from ray_tpu.ops.norms import rms_norm
+from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
+from ray_tpu.models.moe import SCORING, dropless_moe_ffn, swiglu
+from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
+                                  decode_lanes, prefill_page_ids)
 from ray_tpu.ops.rope import rope_cos_sin
-
-Params = Dict[str, Any]
-Cache = Dict[str, Any]
 
 @dataclasses.dataclass(frozen=True)
 class ShortcutMLAMoEConfig(LatentDims):
@@ -145,69 +136,39 @@ def tiny_shortcut_mla_moe(vocab_size: int = 256,
         dtype="float32", param_dtype="float32")
 
 
-class ShortcutMLAMoE(LatentAttention):
+class ShortcutMLAMoE(LatentAttention, ExpertCounts, PagedDecoder):
     """Functional model bundle for one ShortcutMLAMoEConfig: `init`,
     `apply` / `loss` (training graph), and what a serving engine asks a
-    model for (`init_cache`, `prefill`, `decode_step`, `cache_page_bytes`,
-    `decode_attention`, `step_stats`, `cache_stats`)."""
+    model for (`models.paged.PagedDecoder`)."""
 
-    def __init__(self, config: ShortcutMLAMoEConfig, mesh=None):
-        if mesh is not None:
-            raise NotImplementedError(
-                "ShortcutMLAMoE runs on one device and takes no mesh: the "
-                "experts' exchange over chips has not been built")
-        self.config = config
+    no_mesh = "the experts' exchange over chips has not been built"
 
     # ------------------------------------------------------------ init
-    def layer_shapes(self) -> Dict[str, Any]:
-        """(shape, init std) of a double layer's leaves; std 0 means zeros
-        (a norm scale, stored as w with the layer multiplying by 1 + w)."""
+    def layer_shapes(self, i: int) -> Dict[str, Any]:
+        """A double layer's leaves, every layer alike (a norm's scale is
+        stored as w, the layer multiplying by 1 + w), named in sorted
+        order at every level: the order in which `init` has dealt a layer
+        its random keys since the class was written (it flattened them)."""
         c = self.config
         e, f, E = c.d_model, c.moe_intermediate_size, c.held[1]
         std = 0.02
         out_std = std / math.sqrt(4 * c.n_layers)
-        ffn = {"mlp_norm": ((e,), 0.0), "gate": ((e, c.d_ff), std),
-               "up": ((e, c.d_ff), std), "down": ((c.d_ff, e), out_std)}
+        ffn = {"down": ((c.d_ff, e), out_std), "gate": ((e, c.d_ff), std),
+               "mlp_norm": ((e,), 0.0), "up": ((e, c.d_ff), std)}
+        attn = dict(sorted(attn_shapes(c, std, out_std).items()))
         return {
-            "attn": [attn_shapes(c, std, out_std) for _ in range(2)],
+            "attn": [dict(attn) for _ in range(2)],
             "ffn": [dict(ffn) for _ in range(2)],
-            "router": ((e, c.router_slots), std),
-            "router_bias": ((c.router_slots,), 0.0),
+            "moe_down": ((E, f, e), out_std),
             "moe_gate": ((E, e, f), std), "moe_up": ((E, e, f), std),
-            "moe_down": ((E, f, e), out_std)}
-
-    def init(self, key: jax.Array) -> Params:
-        c = self.config
-        pd = c.parameter_dtype
-        is_leaf = lambda x: isinstance(x, tuple)                # noqa: E731
-
-        def fill(key, shapes):
-            flat, treedef = jax.tree_util.tree_flatten(shapes,
-                                                       is_leaf=is_leaf)
-            keys = jax.random.split(key, len(flat))
-            return jax.tree_util.tree_unflatten(treedef, [
-                (jax.random.normal(k, shape, jnp.float32)
-                 * std).astype(pd) if std else jnp.zeros(shape, pd)
-                for k, (shape, std) in zip(keys, flat)])
-
-        keys = jax.random.split(key, c.n_layers + 1)
-        top = fill(keys[-1], {
-            "embed": ((c.vocab_size, c.d_model), 0.02),
-            "lm_head": ((c.d_model, c.vocab_size), 0.02)})
-        shapes = self.layer_shapes()
-        return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
-                "layers": [fill(keys[i], shapes)
-                           for i in range(c.n_layers)]}
+            "router": ((e, c.router_slots), std),
+            "router_bias": ((c.router_slots,), 0.0)}
 
     # --------------------------------------------------------- pieces
-    def _norm(self, x, w):
-        return rms_norm(x, w, self.config.norm_eps, None)
-
-    def _dense(self, ffn: Params, u):
+    @staticmethod
+    def _dense(ffn: Params, u):
         """SwiGLU of one half on the normed stream u (..., e)."""
-        ad = self.config.activation_dtype
-        gate = jax.nn.silu(u @ ffn["gate"].astype(ad))
-        return (gate * (u @ ffn["up"].astype(ad))) @ ffn["down"].astype(ad)
+        return swiglu(u, ffn["gate"], ffn["up"], ffn["down"])
 
     def _experts(self, layer: Params, u, valid=None):
         """The shortcut branch on the normed stream u (..., e): this
@@ -254,22 +215,6 @@ class ShortcutMLAMoE(LatentAttention):
                     layer["attn"][j], h, cos, sin)[0])
         return self._norm(x, params["final_norm"])
 
-    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
-        x = self.hidden(params, tokens)
-        head = params["lm_head"].astype(self.config.activation_dtype)
-        return (x @ head).astype(jnp.float32)
-
-    def loss(self, params: Params, batch: Dict[str, jax.Array]):
-        """Causal LM loss of batch["tokens"] (b, s), as `MLAMoE.loss`."""
-        tokens = batch["tokens"]
-        mask = batch.get("loss_mask")
-        logits = self.apply(params, tokens)[:, :-1]
-        if mask is not None:
-            mask = mask[:, 1:]
-        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
-        return loss
-
     # ------------------------------------------------ what an engine asks
     @property
     def pool_rows(self) -> int:
@@ -280,32 +225,20 @@ class ShortcutMLAMoE(LatentAttention):
         c = self.config
         dt = dtype or c.activation_dtype
         shape = (self.pool_rows, num_pages, page_size, c.row_width)
-        make = jax.jit(lambda: {
-            "kv": jnp.zeros(shape, dt),
-            "moe_load": jnp.zeros((c.n_layers, c.held[1]), jnp.int32),
-            "moe_step": {name: jnp.zeros((), jnp.int32)
-                         for name in STEP_COUNTS}})
+        make = jax.jit(lambda: {"kv": jnp.zeros(shape, dt),
+                                **self._zero_counts()})
         return make()
 
-    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
-        """What the last decode step counted: scalars still on the device,
-        by the names the engine's counters take. The engine fetches them
-        with the step's tokens."""
-        return cache["moe_step"]
-
-    def cache_stats(self, cache: Cache) -> Dict[str, Any]:
-        """For `EngineCore.device_stats()`: pairs a held expert since the
-        cache was made, by layer."""
-        return {"moe_load": jax.device_get(cache["moe_load"]).tolist()}
+    @property
+    def expert_load_shape(self) -> Tuple[int, int]:
+        return self.config.n_layers, self.config.held[1]
 
     def prefill(self, params: Params, tokens: jax.Array, true_len,
                 page_table: jax.Array, cache: Cache,
                 page_size: int) -> Tuple[jax.Array, Cache]:
-        """One padded prompt, as `MLAMoE.prefill`: the expanded attention,
-        both of a layer's pool rows written as whole pages in place
-        (donate the cache). Padding past `true_len` is given to no expert
-        and adds no identity part. Returns (last-position logits (vocab,)
-        f32, cache)."""
+        """As `MLAMoE.prefill`: the expanded attention, both of a layer's
+        pool rows written as whole pages in place. Padding past `true_len`
+        is given to no expert and adds no identity part."""
         c = self.config
         ad = c.activation_dtype
         pool = cache["kv"]
@@ -325,20 +258,15 @@ class ShortcutMLAMoE(LatentAttention):
                                          k_rope[0], page_ids, page_size)
                 return out
             x, _ = self._layer(layer, x, attend, valid)
-        x = self._norm(x, params["final_norm"])
-        last = jnp.take(x[0], true_len - 1, axis=0)
-        logits = (last @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, {**cache, "kv": pool}
+        return self._logits(params, x, true_len), {**cache, "kv": pool}
 
     def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
                     positions: jax.Array, page_tables: jax.Array,
                     active: jax.Array,
                     page_size: int) -> Tuple[jax.Array, Cache]:
-        """Advance a padded batch by one token each, as
-        `MLAMoE.decode_step`, both attentions in the absorbed form.
+        """As `MLAMoE.decode_step`, both attentions in the absorbed form.
         Inactive lanes write nothing, are given to no expert and add no
-        identity part. Returns (logits (B, vocab) f32, cache) — donate the
-        cache."""
+        identity part."""
         c = self.config
         ad = c.activation_dtype
         pool = cache["kv"]
@@ -347,8 +275,7 @@ class ShortcutMLAMoE(LatentAttention):
                                 c.rope_theta)              # (B, 1, rope/2)
         wr_page, wr_slot, lengths = decode_lanes(
             positions, page_tables, active, pool.shape[1], page_size)
-        load = cache["moe_load"]
-        sums = [jnp.int32(0)] * len(STEP_COUNTS)
+        load, sums = cache["moe_load"], self._step_sums()
         for i, layer in enumerate(params["layers"]):
             def attend(j, h):
                 nonlocal pool
@@ -358,8 +285,6 @@ class ShortcutMLAMoE(LatentAttention):
                 return out
             x, counts = self._layer(layer, x, attend, active)
             load = load.at[i].add(counts["load"])
-            sums = [a + n for a, n in zip(sums, step_counts(counts))]
-        x = self._norm(x, params["final_norm"])
-        logits = (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
-        return logits, {"kv": pool, "moe_load": load,
-                        "moe_step": dict(zip(STEP_COUNTS, sums))}
+            sums = self._count_step(sums, counts)
+        return self._logits(params, x), {"kv": pool,
+                                         **self._counted(load, sums)}

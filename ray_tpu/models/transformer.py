@@ -20,6 +20,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from ray_tpu.models.config import TransformerConfig
+from ray_tpu.models.paged import PagedDecoder
 from ray_tpu.ops.attention import (ATTN_RESIDUAL_NAMES, flash_attention,
                                    flash_attention_saveable)
 from ray_tpu.ops.dispatch import (compute_platform, kernel_mesh,
@@ -114,8 +115,9 @@ def _rules():
     return rules
 
 
-class Transformer:
-    """Functional model bundle for one TransformerConfig."""
+class Transformer(PagedDecoder):
+    """Functional model bundle for one TransformerConfig; of
+    `PagedDecoder` it takes the six default answers alone."""
 
     def __init__(self, config: TransformerConfig,
                  mesh: Optional[Mesh] = None):
@@ -434,15 +436,6 @@ class Transformer:
         return decode.cache_page_bytes(self.config, page_size,
                                        tp_shards=tp_shards, dtype=dtype)
 
-    def fixed_pages(self, page_size: int) -> int:
-        """Nothing is kept of a sequence for ever: every layer's cache
-        grows with it (`kv_cache.PageAllocator`'s one class)."""
-        return 0
-
-    def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
-        """Nothing to add to the engine's prefill span."""
-        return {}
-
     def decode_attention(self, page_size: int, dtype=None) -> str:
         from ray_tpu.models import decode
         return decode.decode_attention(self.config, page_size, dtype)
@@ -464,14 +457,6 @@ class Transformer:
         from ray_tpu.models import decode
         return decode.decode_step(self, params, cache, tokens, positions,
                                   page_tables, active, page_size)
-
-    def step_stats(self, cache) -> Dict[str, jax.Array]:
-        """Counts of the last decode step for the engine to fetch with
-        its tokens: a dense decoder has none."""
-        return {}
-
-    def cache_stats(self, cache) -> Dict[str, Any]:
-        return {}
 
     # ------------------------------------------------------------ loss
     def loss(self, params: Params, batch: Dict[str, jax.Array]):

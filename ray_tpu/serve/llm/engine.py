@@ -250,7 +250,7 @@ class EngineCore:
                    else {}))
             # a model whose pool has a row an attention says how many
             # (`pool_rows`: two a layer where a layer has two attentions)
-            rows = getattr(self.model, "pool_rows", None)
+            rows = self.model.pool_rows
             span.add(bytes=_tree_bytes(self._cache),
                      num_pages=self.num_pages,
                      fixed_pages=self.alloc.fixed_pages,

@@ -184,7 +184,8 @@ class HybridDelta(StateSlots, PagedDecoder):
 
     def _full_qkv(self, layer: Params, x):
         """x (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd), q
-        and k normed over their whole width first."""
+        and k normed over their whole width first, all three before any
+        is split (`gqa.qkv` splits each as it is projected: another text)."""
         c = self.config
         ad = c.activation_dtype
         q = self._norm(x @ layer["wq"].astype(ad), layer["q_norm"])

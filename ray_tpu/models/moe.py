@@ -12,7 +12,7 @@ that compute nothing, the share of the experts it holds, the expert's form
 (`EXPERT_FORMS`: three matrices with a gate, or two around a squared ReLU)
 and what the experts read (the router's input, or a narrower projection of
 it the caller made) are data of the caller's config (`route_topk`,
-`dropless_moe_ffn`). A `simplicity` PR folds the two (ROADMAP D1).
+`dropless_moe_ffn`). A `simplicity` PR folds the two (ROADMAP D1c).
 
 The capacity-factor layer: top-k routing + capacity-based dispatch.
 
@@ -258,8 +258,7 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
 # What a decode step counts over its expert layers, by the names the
 # engine's counters take (a class whose layers hold all their experts
 # writes the leading three: none is away, no slot computes nothing);
-# `step_counts` gives one layer's in this order from `dropless_moe_ffn`'s
-# counts.
+# `step_counts` gives one layer's, in this order.
 STEP_COUNTS = ("moe_pairs", "moe_experts_touched", "moe_load_max",
                "moe_zero_pairs", "moe_away_pairs")
 

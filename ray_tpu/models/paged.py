@@ -16,10 +16,8 @@ pool, which `mode="drop"` discards. A model that keeps a state of fixed
 size a sequence (`StateSlots`) keeps it at the slot the table's **first**
 entry names, a page of the allocator's fixed class
 (`serve/llm/kv_cache.py`); the pools have one slot more than the class,
-nobody's, for what a kernel must put somewhere.
-
-`ExpertCounts` is what a class with expert layers keeps of them in its
-cache, under the names the engine's counters take.
+nobody's, for what a kernel must put somewhere. `ExpertCounts` is what a
+class with expert layers keeps of them in its cache.
 """
 from __future__ import annotations
 

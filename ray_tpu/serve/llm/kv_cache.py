@@ -1,9 +1,11 @@
 """KV-cache page bookkeeping for the LLM engine.
 
-The device-side page arrays are the model's (`model.init_cache`:
-`ray_tpu.models.decode`'s keys and values per kv head for `Transformer`,
-one latent row a position for `MLAMoE`, whose page costs
-`layers * page_size * row_width` whatever the heads); this
+The device-side page arrays are the model's, whatever class of
+`ray_tpu.models.MODELS` it is (`models.paged.PagedDecoder.init_cache`:
+keys and values per kv head, `models/decode.py`'s and `models/gqa.py`'s,
+or one latent row a position, `models/latent.py`'s, whose page costs
+`pool rows * page_size * row_width` whatever the heads; `models/paged.py`
+has the addresses a table entry gives); this
 module owns the host-side pool: which pages are free, which sequence
 holds which pages, and how many pages a replica can afford given its
 mesh shards. Pure Python so the tier-1 tests exercise alloc / free /
@@ -20,13 +22,13 @@ both classes (logical page j of a sequence at its table's entry j), and
 `num_pages` stays its count. What a fixed-class page names besides is the
 model's:
 
-- `GQAWindowMoE`: a window layer sees a sequence's last `window` positions,
-  so it keeps a ring of `fixed` = `window_pages` pages a sequence, logical
-  page j at table entry `j mod fixed`, in a pool of its own that holds
-  exactly the fixed class.
-- `HybridDelta`, `HybridSSMMoE`: a recurrent layer (a delta rule's linear
-  attention, a state-space layer's selective scan) keeps a state of one
-  size, of whatever shape the model holds and prices
+- a ring: a window layer sees a sequence's last `window` positions, so it
+  keeps a ring of `fixed` = `window_pages` pages a sequence, logical page j
+  at table entry `j mod fixed`, in a pool of its own that holds exactly
+  the fixed class (`models.paged.prefill_page_ids_held`);
+- a state slot (`models.paged.StateSlots`): a recurrent layer (a delta
+  rule's linear attention, a state-space layer's selective scan) keeps a
+  state of one size, of whatever shape the model holds and prices
   (`cache_page_bytes(fixed=True)`), so `fixed` is 1 and a sequence's first
   table entry is also its *state slot*: the recurrent layers' pools are
   indexed by it.

@@ -2,7 +2,7 @@
 
 Every process that compiles for the chip calls `use_compile_cache()`
 before its first compile: the train worker, the LLM engine replica,
-`bench.py`, `chip_smoke.py`'s tasks. The operator places the cache with
+`chip_smoke.py`'s tasks. The operator places the cache with
 `JAX_COMPILATION_CACHE_DIR`, which JAX reads itself; without it the
 cache is `<checkout>/.jax_cache`, a fixed path worked out from where the
 package lies, because the path is part of every entry's key and a

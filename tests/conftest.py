@@ -11,8 +11,8 @@ import signal
 import tempfile
 
 # Force CPU even on a host with a TPU: unit tests always run on the
-# virtual 8-device CPU mesh, and only chip_smoke.py and bench.py touch
-# the chip. The variables reach worker processes; the config update
+# virtual 8-device CPU mesh, and only chip_smoke.py and the benchmark
+# touch the chip. The variables reach worker processes; the config update
 # covers this process, which is also what lets a test call
 # __graft_entry__.dryrun_multichip(8) after the backend is up.
 os.environ["JAX_PLATFORMS"] = "cpu"

@@ -1,5 +1,6 @@
 """Model zoo tests on the virtual 8-device mesh."""
 import collections
+import dataclasses
 import functools
 
 import jax
@@ -482,3 +483,156 @@ def test_served_class_init_gives_the_arrays_it_gave(name):
     assert type(model).__name__ == name
     assert _tree_sha256(model.init(jax.random.PRNGKey(0))) == INIT_SHA256[
         name]
+
+
+def _tiny_models():
+    """name in `MODELS` -> tiny config, every entry of the table."""
+    from ray_tpu.models import MODELS
+    by_class = {model.__name__: name for name, (_, model) in MODELS.items()}
+    tiny_of = {by_class[cls]: make for cls, make in _tiny_served().items()}
+    tiny_of["transformer"] = tiny
+    assert sorted(tiny_of) == sorted(MODELS)
+    return tiny_of
+
+
+@pytest.mark.parametrize("name", ["transformer", "mla_moe", "gqa_window_moe",
+                                  "hybrid_delta", "shortcut_mla_moe",
+                                  "hybrid_ssm_moe"])
+def test_every_class_answers_the_engines_twelve_asks(name):
+    """What `EngineCore` calls on a model, on every class of the table,
+    with the types it uses them as (`models.paged.PagedDecoder`)."""
+    from ray_tpu.models import MODELS, build_model, model_config
+    from ray_tpu.models.paged import PagedDecoder
+    cfg = _tiny_models()[name]()
+    config_type, model_type = MODELS[name]
+    model = build_model(cfg)
+    assert type(cfg) is config_type and type(model) is model_type
+    assert isinstance(model, PagedDecoder)
+    fields = {**dataclasses.asdict(cfg), "type": name}
+    assert model_config(fields) == cfg
+    page, B, s = 8, 2, 16
+    mp = cfg.max_seq_len // page
+    fixed = model.fixed_pages(page)                             # 1
+    assert isinstance(fixed, int) and fixed >= 0
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: model.init_cache(            # 2
+        B * mp, page, **({"fixed_pages": B * fixed} if fixed else {})))
+    assert isinstance(cache, dict)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa
+    logits, after = jax.eval_shape(                             # 3
+        lambda p, t, n, pt, c: model.prefill(p, t, n, pt, c, page),
+        params, i32(s), i32(), i32(mp), cache)
+    assert (logits.shape, logits.dtype) == ((cfg.vocab_size,), jnp.float32)
+    assert jax.tree_util.tree_structure(after) == (
+        jax.tree_util.tree_structure(cache))
+    logits, after = jax.eval_shape(                             # 4
+        lambda p, c, t, pos, pts, a: model.decode_step(
+            p, c, t, pos, pts, a, page),
+        params, cache, i32(B), i32(B), i32(B, mp),
+        jax.ShapeDtypeStruct((B,), jnp.bool_))
+    assert (logits.shape, logits.dtype) == ((B, cfg.vocab_size),
+                                            jnp.float32)
+    assert jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), after) == (
+        jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), cache))
+    assert model.cache_page_bytes(page) > 0                     # 5
+    if fixed:
+        assert model.cache_page_bytes(page, fixed=True) > 0
+    counts = model.fixed_step_counts(9, page)                   # 6
+    assert bool(counts) == bool(fixed)
+    assert all(isinstance(n, int) for n in counts.values())
+    assert all(isinstance(n, int)                               # 7
+               for n in model.prefill_counts(9, s).values())
+    assert isinstance(model.decode_attention(page), str)        # 8
+    assert 1 <= model.walk_block_pages(page, mp) <= mp          # 9
+    stats = model.step_stats(cache)                             # 10
+    assert all(a.shape == () for a in stats.values())
+    assert set(stats) <= set(collections.ChainMap(after, *(
+        v for v in after.values() if isinstance(v, dict))))
+    real = model.init_cache(2, page, **(
+        {"fixed_pages": fixed} if fixed else {}))
+    assert isinstance(model.cache_stats(real), dict)            # 11
+    assert model.pool_rows is None or model.pool_rows >= 1      # 12
+
+
+# -------------------------------------- the cache's addresses, by hand
+def _np_decode_lanes(positions, tables, active, num_pages, page):
+    out = []
+    for pos, table, on in zip(positions, tables, active):
+        entry = table[pos // page]
+        out.append((entry if on and entry >= 0 else num_pages, pos % page,
+                    pos + 1 if on else 0))
+    return [list(col) for col in zip(*out)]
+
+
+@pytest.mark.parametrize("positions,active", [
+    ([0, 7, 8, 31], [True, True, True, True]),      # a page's edges
+    ([5, 9, 17, 2], [True, False, True, False]),    # inactive lanes
+    ([24, 16, 3, 15], [True, True, True, True]),    # unassigned entries
+])
+def test_decode_lanes_against_a_loop(positions, active):
+    from ray_tpu.models.paged import decode_lanes, lane_page
+    page, num_pages = 8, 12
+    tables = np.array([[3, 1, 4, -1], [5, 9, -1, -1], [2, -1, 6, 0],
+                       [7, 8, -1, -1]], np.int32)
+    want = _np_decode_lanes(positions, tables, active, num_pages, page)
+    pos, act = jnp.asarray(positions, jnp.int32), jnp.asarray(active)
+    got = decode_lanes(pos, jnp.asarray(tables), act, num_pages, page)
+    assert [np.asarray(a).tolist() for a in got] == want
+    # the piece the two classes with their own order call
+    assert np.asarray(lane_page(jnp.asarray(tables), pos // page, act,
+                                num_pages)).tolist() == want[0]
+
+
+@pytest.mark.parametrize("true_len", [1, 7, 8, 9, 16, 24, 29])
+@pytest.mark.parametrize("ring", [0, 2, 3])
+def test_prefill_page_ids_against_a_loop(true_len, ring):
+    """Both spellings name the same pages (a prompt that ends on a page's
+    last position fills that page and not the next), and the ring's ids
+    are the newest page at each entry."""
+    from ray_tpu.models.paged import prefill_page_ids, prefill_page_ids_held
+    page, s, num_pages, ring_pages = 8, 29, 20, 6
+    table = np.array([4, 2, 9, 7, 11, 13], np.int32)
+    n = -(-s // page)
+    want = [int(table[j]) if j * page < true_len else num_pages
+            for j in range(n)]
+    held = -(-true_len // page)
+    want_ring = [int(table[j % ring]) if j < held and j >= held - ring
+                 else ring_pages for j in range(n)] if ring else None
+    args = (jnp.asarray(table), jnp.int32(true_len), s, num_pages, page)
+    assert np.asarray(prefill_page_ids(*args)).tolist() == want
+    ids, ring_ids = prefill_page_ids_held(*args, ring, ring_pages)
+    assert np.asarray(ids).tolist() == want
+    assert (ring_ids if ring_ids is None
+            else np.asarray(ring_ids).tolist()) == want_ring
+    if ring:        # every ring entry the prompt reaches is written once
+        live = [i for i in want_ring if i != ring_pages]
+        assert sorted(live) == sorted(set(live))
+        assert len(live) == min(held, ring)
+
+
+@pytest.mark.parametrize("first,active,slot,tail_at,prefill", [
+    (0, True, 0, 0, 0), (3, True, 3, 3, 3),     # a slot of the class
+    (4, True, -1, 5, 5),                        # past the pool: nobody's
+    (9, True, -1, 5, 5), (-1, True, -1, 5, 5),  # and unassigned
+    (2, False, -1, 5, 2),                       # an inactive lane
+])
+def test_state_slot_arithmetic_against_its_cases(first, active, slot,
+                                                 tail_at, prefill):
+    """`slots` = 4 slots of the class and one more, nobody's (index 4):
+    a lane without a slot updates none (-1), writes its tail past the pool
+    (5: dropped) and reads a row that exists."""
+    from ray_tpu.models.paged import (decode_state_slots, prefill_state_slot,
+                                      slot_rows)
+    slots = 4
+    tables = jnp.asarray([[first, 7, 8]], jnp.int32)
+    got_slot, got_at = decode_state_slots(tables, jnp.asarray([active]),
+                                          slots)
+    assert (int(got_slot[0]), int(got_at[0])) == (slot, tail_at)
+    assert int(prefill_state_slot(tables[0], slots)) == prefill
+    pool = jnp.arange(2 * (slots + 1) * 3).reshape(2, slots + 1, 3)
+    rows = slot_rows(pool, 1, got_slot)
+    assert rows.shape == (1, 3)
+    assert rows.tolist() == pool[1, max(slot, 0)][None].tolist()
+    # a write at `tail_at` past the pool is dropped
+    written = pool.at[1, got_at].set(-1, mode="drop")
+    assert bool((written == pool).all()) == (tail_at > slots)

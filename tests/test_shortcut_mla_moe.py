@@ -26,9 +26,10 @@ from benchmarks.harness.weights import make_weights          # noqa: E402
 from ray_tpu.models import (ShortcutMLAMoE, ShortcutMLAMoEConfig,  # noqa: E402
                             build_model, model_config)
 from ray_tpu.models import latent                            # noqa: E402
-from ray_tpu.models.moe import dropless_moe_ffn, route_topk  # noqa: E402
+from ray_tpu.models.moe import (STEP_COUNTS,                 # noqa: E402
+                                dropless_moe_ffn, route_topk)
 from ray_tpu.models.shortcut_mla_moe import (                # noqa: E402
-    STEP_COUNTS, tiny_shortcut_mla_moe)
+    tiny_shortcut_mla_moe)
 from ray_tpu.ops import attention as attn                    # noqa: E402
 from ray_tpu.ops import grouped_matmul as gmm                # noqa: E402
 from ray_tpu.ops import paged_attention as paged             # noqa: E402

@@ -31,6 +31,8 @@ from ray_tpu.models.shortcut_mla_moe import (  # noqa: F401,E402
     ShortcutMLAMoE, ShortcutMLAMoEConfig)
 from ray_tpu.models.hybrid_ssm_moe import (  # noqa: F401,E402
     HybridSSMMoE, HybridSSMMoEConfig)
+from ray_tpu.models.hybrid_kda_moe import (  # noqa: F401,E402
+    HybridKDAMoE, HybridKDAMoEConfig)
 
 
 # name -> (config class, model class). A dict of config fields names its
@@ -40,7 +42,8 @@ MODELS = {"transformer": (TransformerConfig, Transformer),
           "gqa_window_moe": (GQAWindowMoEConfig, GQAWindowMoE),
           "hybrid_delta": (HybridDeltaConfig, HybridDelta),
           "shortcut_mla_moe": (ShortcutMLAMoEConfig, ShortcutMLAMoE),
-          "hybrid_ssm_moe": (HybridSSMMoEConfig, HybridSSMMoE)}
+          "hybrid_ssm_moe": (HybridSSMMoEConfig, HybridSSMMoE),
+          "hybrid_kda_moe": (HybridKDAMoEConfig, HybridKDAMoE)}
 
 
 def model_config(model):

@@ -1,0 +1,454 @@
+"""A decoder whose mixers are of two kinds that keep unlike things of a
+sequence, both narrow: delta-rule layers whose decay is a vector over the
+key width (KDA, linear attention: a recurrent state of fixed size), and
+latent-attention layers (MLA), which hold one latent row a position; and
+whose feed-forwards are dense or routed experts chosen under a group
+limit, a share of them held here. Named layer by layer by the config's two
+lists: the `bailing_hybrid` family's language model (Ling-3.0-flash: five
+KDA layers to one latent), on the ops the other classes run on and behind
+the same serving engine.
+
+Blocks are pre-norm: `x <- x + Mixer(N(x))`, then `x <- x + FFN(N(x))`; a
+final norm before the untied head.
+
+A **linear** layer (`ops.kda`), u its normed input, H heads of key width
+dk and value width dv:
+
+    [q~ | k~ | v~] = u W_qkv;  f = u W_f;  b = u W_b;  z = u W_g
+    q, k, v = SiLU(causal depthwise conv of width 4 over [q~ | k~ | v~])
+    q <- q / |q| / sqrt(dk);  k <- k / |k|                    (a head)
+    g = lower_bound sigmoid(exp(A_log) (f + dt_bias))  (a head and channel)
+    beta = sigmoid(b)                                         (a head)
+    S'_t = Diag(exp(g_t)) S_{t-1}
+    S_t = S'_t + beta_t k_t (v_t - S'_t^T k_t)^T
+    o_t = S_t^T q_t;   y = (RMSNorm_dv(o) * sigmoid(z)) W_o
+
+`g` lies in (`kda_lower_bound`, 0) whatever `f` is, which is what the chunk
+kernel's factors need (`ops.kda.lower_bound_fits`). `A_log` and `dt_bias`
+are held as offsets from the config's `a_log_init` and `dt_bias_init`, as
+a norm's scale is held as an offset from 1.
+
+A **latent** layer is `models.latent.LatentAttention`'s with the query in
+one matrix (no `q_lora_rank`) and a sigmoid gate a head on the attention's
+output (`head_gate`). A **sparse** feed-forward is
+`models.moe.DenseOrRoutedFFN`'s: a sigmoid a slot, the choice among the
+`topk_group` best of `n_group` groups of slots, `experts_held = (first,
+count)` of the `n_routed_experts` computed here (`dropless_moe_ffn(held=)`:
+the layer routes over all, computes its own experts' rows and leaves out
+what the others would add), a shared expert on every token.
+
+**Two kinds of cache behind one page table** (`models/paged.py` has the
+addresses), both small: a latent layer keeps a position one row, pool
+`"kv"` `(latent layers, num_pages, page, row_width)`; a linear layer keeps
+a sequence the same bytes at any length, pools `"state"` `(linear layers,
+slots + 1, dk, H x dv)` float32 and `"tail"` (the convolution's last
+`width - 1` inputs), a sequence's at the slot its first table entry names
+(`paged.StateSlots`), which the latent pool backs like any page. Beside
+them `paged.ExpertCounts`' two entries.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
+from ray_tpu.models.moe import DenseOrRoutedFFN
+from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
+                                  StateSlots, decode_lanes,
+                                  decode_state_slots, prefill_page_ids,
+                                  prefill_state_slot, slot_rows)
+from ray_tpu.ops import kda as _kda
+from ray_tpu.ops.gated_delta import (CHUNK, causal_conv, conv_step,
+                                     l2_normalize)
+from ray_tpu.ops.norms import rms_norm_reference
+from ray_tpu.ops.rope import rope_cos_sin
+
+LINEAR, LATENT = "linear_attention", "latent_attention"
+DENSE, SPARSE = "dense", "sparse"
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridKDAMoEConfig(LatentDims):
+    """Fields under the published keys' meanings (`config.json` of
+    `bailing_hybrid`); `layer_types` and `mlp_layer_types` tuples, one
+    entry a layer; `n_routed_experts` the experts of the whole layer and
+    `experts_held` this chip's."""
+    vocab_size: int = 157184
+    d_model: int = 2560                     # hidden_size
+    layer_types: Tuple[str, ...] = (LINEAR,) * 5 + (LATENT,)
+    mlp_layer_types: Tuple[str, ...] = (SPARSE,) * 6
+    n_heads: int = 32                       # num_attention_heads, both kinds
+    linear_key_dim: int = 128               # head_dim
+    linear_value_dim: int = 128
+    conv_width: int = 4                     # short_conv_kernel_size
+    kda_lower_bound: float = -5.0
+    chunk: int = CHUNK                      # positions a prefill chunk
+    a_log_init: float = 0.0
+    dt_bias_init: float = 0.0
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    head_gate: bool = True      # gated_attention_proj_granularity_type
+    d_ff: int = 6144                        # intermediate_size (dense)
+    moe_intermediate_size: int = 768
+    shared_intermediate_size: int = 768     # moe_shared_expert_inter..._size
+    n_routed_experts: int = 512             # num_experts
+    experts_held: Optional[Tuple[int, int]] = None   # (first, count); all
+    num_experts_per_tok: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    routed_scaling_factor: float = 2.5
+    norm_topk_prob: bool = True
+    max_seq_len: int = 16384
+    rope_theta: float = 6000000.0
+    norm_eps: float = 1e-6
+    dtype: str = "bfloat16"
+    param_dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        for name in ("layer_types", "mlp_layer_types"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if set(self.layer_types) - {LINEAR, LATENT} or set(
+                self.mlp_layer_types) - {DENSE, SPARSE}:
+            raise ValueError(f"layer kinds {set(self.layer_types)} / "
+                             f"{set(self.mlp_layer_types)} not built")
+        if len(self.mlp_layer_types) != len(self.layer_types):
+            raise ValueError("layer_types and mlp_layer_types name unlike "
+                             "numbers of layers")
+        if not _kda.lower_bound_fits(self.kda_lower_bound):
+            raise ValueError(
+                f"kda_lower_bound {self.kda_lower_bound}: the chunk "
+                f"kernel's factors need it in [-"
+                f"{_kda.MAX_EXPONENT / (_kda.SOLVE_BLOCK // 2):g}, 0)")
+        first, count = self.held
+        if count < 1 or not 0 <= first <= self.n_routed_experts - count:
+            raise ValueError(f"experts_held {self.experts_held} of "
+                             f"{self.n_routed_experts} experts")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    def of_kind(self, kind: str) -> Tuple[int, ...]:
+        """The layers whose mixer or feed-forward is of `kind`."""
+        return tuple(i for i, kinds in enumerate(zip(
+            self.layer_types, self.mlp_layer_types)) if kind in kinds)
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts this chip holds."""
+        return tuple(self.experts_held or (0, self.n_routed_experts))
+
+    @property
+    def key_dim(self) -> int:               # a linear layer's q, k, f width
+        return self.n_heads * self.linear_key_dim
+
+    @property
+    def value_dim(self) -> int:             # a linear layer's v, z width
+        return self.n_heads * self.linear_value_dim
+
+    @property
+    def conv_channels(self) -> int:
+        return 2 * self.key_dim + self.value_dim
+
+
+def tiny_hybrid_kda_moe(vocab_size: int = 256,
+                        experts_held=(4, 4)) -> HybridKDAMoEConfig:
+    """CI/debug model: every mechanism at a size the CPU runs in seconds:
+    a dense layer and a period of three (two linear, one latent) over
+    experts, 4 heads of 8 / 16, chunks of 8, a latent row of 128 (so the
+    latent kernel tiles under the interpreter), 16 experts in 4 groups of
+    which 2 are kept, a share of them that does not start at 0."""
+    return HybridKDAMoEConfig(
+        vocab_size=vocab_size, d_model=64,
+        layer_types=(LINEAR, LINEAR, LINEAR, LATENT),
+        mlp_layer_types=(DENSE, SPARSE, SPARSE, SPARSE), n_heads=4,
+        linear_key_dim=8, linear_value_dim=16, chunk=8, kv_lora_rank=96,
+        qk_nope_head_dim=16, qk_rope_head_dim=16, v_head_dim=32, d_ff=128,
+        moe_intermediate_size=32, shared_intermediate_size=32,
+        n_routed_experts=16, experts_held=experts_held,
+        num_experts_per_tok=4, n_group=4, topk_group=2, max_seq_len=256,
+        dtype="float32", param_dtype="float32")
+
+
+class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
+                   ExpertCounts, PagedDecoder):
+    """Functional model bundle for one HybridKDAMoEConfig: `init`, `apply`
+    / `loss` (the plain chunked form, differentiated by JAX), and what a
+    serving engine asks a model for (`models.paged.PagedDecoder`)."""
+
+    no_mesh = ("neither the state pools, the latent cache nor the experts' "
+               "exchange over chips have been built")
+
+    # ------------------------------------------------------------ init
+    def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
+        """Zeros are a norm's scale w, the layer multiplying by 1 + w;
+        `a_log`, `dt_bias`, offsets from the config's initial values; the
+        router's bias."""
+        c = self.config
+        e, H = c.d_model, c.n_heads
+        std = 0.02
+        out_std = std / math.sqrt(2 * c.n_layers)
+        if c.layer_types[i] == LATENT:
+            shapes = attn_shapes(c, std, out_std)
+        else:
+            shapes = {"attn_norm": ((e,), 0.0),
+                      "w_qkv": ((e, c.conv_channels), std),
+                      "w_f": ((e, c.key_dim), std), "w_b": ((e, H), std),
+                      "w_g": ((e, c.value_dim), std),
+                      "conv": ((c.conv_width, c.conv_channels), std),
+                      "a_log": ((H,), 0.0),
+                      "dt_bias": ((H, c.linear_key_dim), 0.0),
+                      "o_norm": ((c.linear_value_dim,), 0.0),
+                      "wo": ((c.value_dim, e), out_std)}
+        shapes["mlp_norm"] = ((e,), 0.0)
+        if c.mlp_layer_types[i] == DENSE:
+            shapes.update(gate=((e, c.d_ff), std), up=((e, c.d_ff), std),
+                          down=((c.d_ff, e), out_std))
+            return shapes
+        E, f, fs = c.held[1], c.moe_intermediate_size, \
+            c.shared_intermediate_size
+        shapes.update(
+            router=((e, c.n_routed_experts), std),
+            router_bias=((c.n_routed_experts,), 0.0),
+            moe_gate=((E, e, f), std), moe_up=((E, e, f), std),
+            moe_down=((E, f, e), out_std),
+            shared_gate=((e, fs), std), shared_up=((e, fs), std),
+            shared_down=((fs, e), out_std))
+        return shapes
+
+    # --------------------------------------------------------- pieces
+    def _routing(self, layer: Params):
+        c = self.config
+        return layer["router_bias"], dict(
+            top_k=c.num_experts_per_tok, norm_topk_prob=c.norm_topk_prob,
+            scale=c.routed_scaling_factor, held=c.held, n_group=c.n_group,
+            topk_group=c.topk_group)
+
+    def _linear_inputs(self, layer: Params, u, mixed):
+        """What the recurrence takes of positions u (n, e) whose convolved
+        channels are `mixed` (n, channels): q, k (n, H, dk) and v (n, H,
+        dv) in the activations' dtype, g (n, H, dk) and beta (n, H)
+        float32."""
+        c = self.config
+        ad = c.activation_dtype
+        H, dk = c.n_heads, c.linear_key_dim
+        n = u.shape[0]
+        q, k, v = jnp.split(mixed, [c.key_dim, 2 * c.key_dim], axis=-1)
+        q = l2_normalize(q.reshape(n, H, dk)) * dk ** -0.5
+        k = l2_normalize(k.reshape(n, H, dk))
+        f32 = jnp.float32           # the offsets are added in float32
+        g, beta = _kda.gates(
+            (u @ layer["w_f"].astype(ad)).reshape(n, H, dk),
+            u @ layer["w_b"].astype(ad),
+            c.a_log_init + layer["a_log"].astype(f32),
+            c.dt_bias_init + layer["dt_bias"].astype(f32),
+            c.kda_lower_bound)
+        return (q.astype(ad), k.astype(ad),
+                v.reshape(n, H, c.linear_value_dim), g, beta)
+
+    def _linear_out(self, layer: Params, u, o):
+        """Heads' outputs o (n, H, dv): normed a head, gated by the
+        sigmoid of a projection of the layer's input u (n, e), through
+        W_o."""
+        c = self.config
+        ad = c.activation_dtype
+        z = (u @ layer["w_g"].astype(ad)).reshape(o.shape)
+        o = rms_norm_reference(o.astype(jnp.float32), layer["o_norm"],
+                               c.norm_eps)
+        y = (o * jax.nn.sigmoid(z.astype(jnp.float32))).astype(ad)
+        return y.reshape(u.shape[0], -1) @ layer["wo"].astype(ad)
+
+    def _linear_seq(self, layer: Params, u, true_len=None):
+        """A linear layer over one sequence u (s, e), normed. With a
+        `true_len` (a prefill's padded bucket) through `kda_prefill`, the
+        kernel where there is one; without, through the plain chunked
+        form, which JAX differentiates. Returns (the output after W_o
+        (s, e), the state at the sequence's end (H, dk, dv) float32, the
+        convolution's tail)."""
+        c = self.config
+        s = u.shape[0]
+        mixed, tail = causal_conv(
+            u @ layer["w_qkv"].astype(c.activation_dtype), layer["conv"],
+            true_len)
+        q, k, v, g, beta = self._linear_inputs(layer, u, mixed)
+        pad = -s % c.chunk                  # whole chunks; padding is inert
+        q, k, v, g, beta = (
+            jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).swapaxes(0, 1)
+            for a in (q, k, v, g, beta))
+        if true_len is None:
+            o, state = _kda.kda_chunked(q, k, v, g, beta, chunk=c.chunk)
+        else:
+            o, state = _kda.kda_prefill(q, k, v, g, beta, true_len, c.chunk)
+        o = o.swapaxes(0, 1)[:s]
+        return self._linear_out(layer, u, o), state, tail
+
+    # --------------------------------------------------------- forward
+    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens (b, s) -> hidden states after the final norm."""
+        c = self.config
+        ad = c.activation_dtype
+        b, s = tokens.shape
+        x = params["embed"].astype(ad)[tokens]
+        cos, sin = rope_cos_sin(jnp.broadcast_to(jnp.arange(s), (b, s)),
+                                c.qk_rope_head_dim, c.rope_theta)
+        for i, layer in enumerate(params["layers"]):
+            u = self._norm(x, layer["attn_norm"])
+            if c.layer_types[i] == LATENT:
+                attn, _, _ = self._attn_expanded(layer, u, cos, sin)
+                x = x + attn @ layer["wo"].astype(ad)
+            else:
+                x = x + jax.vmap(
+                    lambda seq: self._linear_seq(layer, seq)[0])(u)
+            x, _ = self._block_ffn(layer, x)
+        return self._norm(x, params["final_norm"])
+
+    # ------------------------------------------------ what an engine asks
+    def state_bytes(self, dtype=None) -> int:
+        """Bytes the linear layers keep of one sequence, whatever its
+        length: a float32 state and the convolution's tail a layer."""
+        c = self.config
+        dt = jnp.dtype(dtype or c.activation_dtype)
+        return len(c.of_kind(LINEAR)) * (
+            c.linear_key_dim * c.value_dim * 4
+            + (c.conv_width - 1) * c.conv_channels * dt.itemsize)
+
+    def init_cache(self, num_pages: int, page_size: int, dtype=None,
+                   fixed_pages: int = 0) -> Cache:
+        """`num_pages` pages in the latent layers' pool; `fixed_pages`
+        state slots (the allocator's fixed class, one a sequence) and one
+        more, nobody's, in the linear layers'."""
+        c = self.config
+        dt = dtype or c.activation_dtype
+        rows = (self.pool_rows, num_pages, page_size, c.row_width)
+        lin, slots = len(c.of_kind(LINEAR)), fixed_pages + 1
+        make = jax.jit(lambda: {
+            "kv": jnp.zeros(rows, dt),
+            "state": jnp.zeros((lin, slots, c.linear_key_dim, c.value_dim),
+                               jnp.float32),
+            "tail": jnp.zeros((lin, slots,
+                               (c.conv_width - 1) * c.conv_channels), dt),
+            **self._zero_counts()})
+        return make()
+
+    @property
+    def pool_rows(self) -> int:
+        return len(self.config.of_kind(LATENT))
+
+    @property
+    def expert_load_shape(self) -> Tuple[int, int]:
+        return len(self.config.of_kind(SPARSE)), self.config.held[1]
+
+    def page_bytes(self, page_size: int, tp_shards: int = 1,
+                   dtype=None) -> int:
+        """The latent layers' rows."""
+        return LatentAttention.cache_page_bytes(self, page_size, tp_shards,
+                                                dtype)
+
+    def decode_attention(self, page_size: int, dtype=None) -> str:
+        """The kernel of each mixer kind, or "einsum" where the latent
+        kernel does not tile the pool."""
+        c = self.config
+        latent = LatentAttention.decode_attention(self, page_size, dtype)
+        if latent == "einsum":
+            return latent
+        step = (_kda.KERNEL_STEP if _kda.uses_step_kernel(
+            c.n_heads, c.linear_key_dim, c.linear_value_dim)
+            else "kda_gather")
+        return "+".join(name for name, kind in (
+            (latent, LATENT), (step, LINEAR)) if c.of_kind(kind))
+
+    def prefill(self, params: Params, tokens: jax.Array, true_len,
+                page_table: jax.Array, cache: Cache,
+                page_size: int) -> Tuple[jax.Array, Cache]:
+        """A latent layer in the expanded form through the flash kernel,
+        its rows written as whole pages in place; a linear layer scanned
+        from a zero state to `true_len`, its state and tail written whole
+        into the slot the table's first entry names; padding past
+        `true_len` given to no expert."""
+        c = self.config
+        ad = c.activation_dtype
+        pools = dict(cache)
+        num_pages = pools["kv"].shape[1]
+        slots = pools["state"].shape[1] - 1
+        s = tokens.shape[0]
+        x = params["embed"].astype(ad)[tokens]                  # (s, e)
+        cos, sin = rope_cos_sin(jnp.arange(s)[None], c.qk_rope_head_dim,
+                                c.rope_theta)
+        ids = prefill_page_ids(page_table, true_len, s, num_pages, page_size)
+        slot = prefill_state_slot(page_table, slots)
+        valid = jnp.arange(s) < true_len
+        for i, layer in enumerate(params["layers"]):
+            u = self._norm(x, layer["attn_norm"])
+            if c.layer_types[i] == LATENT:
+                li = c.of_kind(LATENT).index(i)
+                attn, c_kv, k_rope = self._attn_expanded(layer, u[None],
+                                                         cos, sin)
+                pools["kv"] = self._write_pages(
+                    pools["kv"], li, c_kv[0], k_rope[0], ids, page_size)
+                x = x + attn[0] @ layer["wo"].astype(ad)
+            else:
+                li = c.of_kind(LINEAR).index(i)
+                mixed, state, tail = self._linear_seq(layer, u, true_len)
+                # (H, dk, dv) -> the pool's (dk, H x dv)
+                state = state.transpose(1, 0, 2).reshape(
+                    c.linear_key_dim, c.value_dim)
+                pools.update(self._write_slot(pools, li, slot, state,
+                                              tail))
+                x = x + mixed
+            x, _ = self._block_ffn(layer, x, valid)
+        return self._logits(params, x, true_len), pools
+
+    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
+                    positions: jax.Array, page_tables: jax.Array,
+                    active: jax.Array,
+                    page_size: int) -> Tuple[jax.Array, Cache]:
+        """A latent layer in the absorbed form. An inactive lane, or one
+        whose table is unassigned, writes no row, no state and no tail,
+        and is given to no expert."""
+        c = self.config
+        ad = c.activation_dtype
+        pools = dict(cache)
+        num_pages = pools["kv"].shape[1]
+        slots = pools["state"].shape[1] - 1
+        B = tokens.shape[0]
+        x = params["embed"].astype(ad)[tokens]                  # (B, e)
+        cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
+                                c.rope_theta)              # (B, 1, rope/2)
+        page, offset, lengths = decode_lanes(positions, page_tables, active,
+                                             num_pages, page_size)
+        slot, tail_at = decode_state_slots(page_tables, active, slots)
+        load, sums = pools["moe_load"], self._step_sums()
+        for i, layer in enumerate(params["layers"]):
+            u = self._norm(x, layer["attn_norm"])
+            if c.layer_types[i] == LATENT:
+                li = c.of_kind(LATENT).index(i)
+                out, pools["kv"] = self._attn_absorbed(
+                    layer, u, cos, sin, pools["kv"], li, page, offset,
+                    page_tables, lengths)
+                x = x + out @ layer["wo"].astype(ad)
+            else:
+                li = c.of_kind(LINEAR).index(i)
+                tail = slot_rows(pools["tail"], li, slot).reshape(
+                    B, c.conv_width - 1, c.conv_channels)
+                mixed, tail = conv_step(u @ layer["w_qkv"].astype(ad), tail,
+                                        layer["conv"])
+                pools["tail"] = pools["tail"].at[li, tail_at].set(
+                    tail.reshape(B, -1), mode="drop")
+                q, k, v, g, beta = self._linear_inputs(layer, u, mixed)
+                o, pools["state"] = _kda.kda_step(
+                    q, k, v, g, beta, pools["state"], li, slot)
+                x = x + self._linear_out(layer, u, o)
+            x, counts = self._block_ffn(layer, x, active)
+            if counts is not None:
+                load = load.at[c.of_kind(SPARSE).index(i)].add(
+                    counts["load"])
+                sums = self._count_step(sums, counts)
+        return self._logits(params, x), {**pools,
+                                         **self._counted(load, sums)}

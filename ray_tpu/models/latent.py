@@ -1,18 +1,22 @@
 """Multi-head latent attention (MLA) as the classes that have it share it:
-`models.mla_moe.MLAMoE` (one attention a layer) and
-`models.shortcut_mla_moe.ShortcutMLAMoE` (two), so that each class's tests
-and cells guard the other's attention.
+`models.mla_moe.MLAMoE` (one attention a layer),
+`models.shortcut_mla_moe.ShortcutMLAMoE` (two) and
+`models.hybrid_kda_moe.HybridKDAMoE` (one layer in six, no query LoRA, a
+gate a head), so that each class's tests and cells guard the others'
+attention.
 
 With `x` the normed input of an attention and `a_q`, `a_kv` the config's two
 LoRA scales (1 where the published model has none):
 
     c_q  = a_q RMSNorm(x W_qa)                  (q_lora_rank)
     q    = c_q W_qb        -> heads of [q_nope | q_rope]
+           (x W_q in one matrix where the config has no q_lora_rank)
     [c_kv | k_rope] = x W_kva;  c_kv = a_kv RMSNorm(c_kv)
     k_rope = RoPE(k_rope)
     [k_nope | v] a head = c_kv W_kvb;           q_rope = RoPE(q_rope)
     scores = q . [k_nope | k_rope] / sqrt(nope + rope), causal softmax
-    o = concat_h(P v) W_o
+    o = concat_h(P v) W_o     (a head's P v times sigmoid((x W_a)_h) first
+                               where the config has a `head_gate`)
 
 `a_q` multiplies the normed query latent (linear in it, so every head of
 `q` carries it) and `a_kv` the normed key-value latent **as the cache holds
@@ -31,6 +35,7 @@ from __future__ import annotations
 import math
 from typing import Any, Dict, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.config import ConfigDtypes
@@ -53,6 +58,9 @@ class LatentDims(ConfigDtypes):
     # the LoRA scales; a config whose model has them overrides these
     q_lora_scale = 1.0
     kv_lora_scale = 1.0
+    # a sigmoid gate a head on the attention's output, from a projection of
+    # the attention's input; a config whose model has one overrides this
+    head_gate = False
 
     @property
     def qk_head_dim(self) -> int:
@@ -70,16 +78,21 @@ def attn_shapes(c, std: float, out_std: float
     before it; std 0 means zeros (a norm scale, stored as w with the layer
     multiplying by 1 + w)."""
     e, H = c.d_model, c.n_heads
+    if c.q_lora_rank:
+        query = {"wq_a": ((e, c.q_lora_rank), std),
+                 "q_norm": ((c.q_lora_rank,), 0.0),
+                 "wq_b": ((c.q_lora_rank, H * c.qk_head_dim), std)}
+    else:                               # no LoRA: one matrix
+        query = {"wq": ((e, H * c.qk_head_dim), std)}
     return {
         "attn_norm": ((e,), 0.0),
-        "wq_a": ((e, c.q_lora_rank), std),
-        "q_norm": ((c.q_lora_rank,), 0.0),
-        "wq_b": ((c.q_lora_rank, H * c.qk_head_dim), std),
+        **query,
         "wkv_a": ((e, c.kv_lora_rank + c.qk_rope_head_dim), std),
         "kv_norm": ((c.kv_lora_rank,), 0.0),
         "wkv_b": ((c.kv_lora_rank,
                    H * (c.qk_nope_head_dim + c.v_head_dim)), std),
         "wo": ((H * c.v_head_dim, e), out_std),
+        **({"w_head_gate": ((e, H), std)} if c.head_gate else {}),
     }
 
 
@@ -94,12 +107,27 @@ class LatentAttention:
         """h (..., e) -> q (..., heads, nope + rope), not yet rotated."""
         c = self.config
         ad = c.activation_dtype
-        c_q = rms_norm_reference(h @ layer["wq_a"].astype(ad),
-                                 layer["q_norm"], c.norm_eps)
-        if c.q_lora_scale != 1.0:
-            c_q = c_q * jnp.asarray(c.q_lora_scale, ad)
-        q = c_q @ layer["wq_b"].astype(ad)
+        if c.q_lora_rank:
+            c_q = rms_norm_reference(h @ layer["wq_a"].astype(ad),
+                                     layer["q_norm"], c.norm_eps)
+            if c.q_lora_scale != 1.0:
+                c_q = c_q * jnp.asarray(c.q_lora_scale, ad)
+            q = c_q @ layer["wq_b"].astype(ad)
+        else:
+            q = h @ layer["wq"].astype(ad)
         return q.reshape(*h.shape[:-1], c.n_heads, c.qk_head_dim)
+
+    def _gated(self, layer: Params, h, out):
+        """The heads' outputs out (..., heads, v), each head's times the
+        sigmoid of its number in `h W_a` where the config has a
+        `head_gate`, flattened to (..., heads * v)."""
+        c = self.config
+        if c.head_gate:
+            gate = jax.nn.sigmoid((h @ layer["w_head_gate"].astype(
+                c.activation_dtype)).astype(jnp.float32))
+            out = (out.astype(jnp.float32) * gate[..., None]).astype(
+                out.dtype)
+        return out.reshape(*h.shape[:-1], c.n_heads * c.v_head_dim)
 
     def _latent(self, layer: Params, h, cos, sin):
         """h (..., e) -> the cache's row parts: c_kv (..., latent) after
@@ -153,8 +181,7 @@ class LatentAttention:
         out = flash_attention(qt, kt, vt, causal=True,
                               sm_scale=1.0 / math.sqrt(c.qk_head_dim),
                               block_q=ATTN_BLOCK, block_k=ATTN_BLOCK)
-        out = out.transpose(0, 2, 1, 3).reshape(
-            b, s, c.n_heads * c.v_head_dim)
+        out = self._gated(layer, h, out.transpose(0, 2, 1, 3))
         return out, c_kv, k_rope
 
     def _write_pages(self, pool, row: int, c_kv, k_rope, page_ids,
@@ -195,7 +222,7 @@ class LatentAttention:
             latent, sm_scale)
         out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
                          w_kvb[..., nope:])
-        return out.reshape(h.shape[0], -1), pool
+        return self._gated(layer, h, out), pool
 
     # ------------------------------------------------ what an engine asks
     def cache_page_bytes(self, page_size: int, tp_shards: int = 1,

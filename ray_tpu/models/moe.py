@@ -4,10 +4,11 @@
 with: top-k of a softmax, a fixed capacity an expert, overflow dropped.
 `dropless_moe_ffn` (at the end) is the serving path of the classes with
 routed experts (`models.mla_moe`, `gqa_window_moe`, `shortcut_mla_moe`,
-`hybrid_ssm_moe`): no capacity and no dropped token at any imbalance — the
-(token, expert) pairs are sorted by expert and `ops.grouped_matmul`
-multiplies each expert's rows by its matrices, reading only experts that
-have rows. Its scoring (sigmoid or softmax, renormalised or not), the slots
+`hybrid_ssm_moe`, `hybrid_kda_moe`): no capacity and no dropped token at
+any imbalance — the (token, expert) pairs are sorted by expert and
+`ops.grouped_matmul` multiplies each expert's rows by its matrices, reading
+only experts that have rows. Its scoring (sigmoid or softmax, renormalised
+or not), a limit on the groups of slots a token may choose from, the slots
 that compute nothing, the share of the experts it holds, the expert's form
 (`EXPERT_FORMS`: three matrices with a gate, or two around a squared ReLU)
 and what the experts read (the router's input, or a narrower projection of
@@ -144,19 +145,34 @@ SCORING = {"sigmoid": jax.nn.sigmoid,
 
 def route_topk(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
                top_k: int, norm_topk_prob: bool = True,
-               scale: float = 1.0, scoring: str = "sigmoid"
+               scale: float = 1.0, scoring: str = "sigmoid",
+               n_group: int = 1, topk_group: int = 1
                ) -> Tuple[jax.Array, jax.Array]:
     """Scores in float32, `scoring` a key of `SCORING` (each slot's sigmoid,
     or a softmax over all slots): choose the top-k of `score + bias` (the
     bias moves the choice only), weigh by the score itself, divide by the
-    chosen scores' sum where `norm_topk_prob`, times `scale`.
+    chosen scores' sum where `norm_topk_prob`, times `scale`. Under a group
+    limit (`n_group` > 1) the slots are `n_group` runs of equal length, a
+    group's score is the sum of its two largest `score + bias`, and the
+    choice is among the slots of the `topk_group` best groups alone.
 
     x (T, d); router_w (d, slots); bias (slots,). Returns (slots chosen
     (T, k) int32, weights (T, k) float32)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     scores = SCORING[scoring](logits)
-    _, top_e = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        T, slots = choice.shape
+        if slots % n_group or not 0 < topk_group <= n_group:
+            raise ValueError(f"{slots} slots in {n_group} groups, "
+                             f"{topk_group} kept")
+        grouped = choice.reshape(T, n_group, slots // n_group)
+        best2 = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)  # (T, n_group)
+        kept = best2 >= lax.top_k(best2, topk_group)[0][:, -1:]
+        choice = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(
+            T, slots)
+    _, top_e = lax.top_k(choice, top_k)
     top_w = jnp.take_along_axis(scores, top_e, axis=-1)
     if norm_topk_prob:
         top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
@@ -177,7 +193,8 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
                      scoring: str = "sigmoid", zero_experts: int = 0,
                      held: Optional[Tuple[int, int]] = None,
                      expert_form: str = "swiglu",
-                     expert_input: Optional[jax.Array] = None
+                     expert_input: Optional[jax.Array] = None,
+                     n_group: int = 1, topk_group: int = 1
                      ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """y_t = sum over the k slots token t chose of w_ti * E_i(z_t), `E_i`
     of the form `expert_form` names (`EXPERT_FORMS`; one without a gate
@@ -196,6 +213,8 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     computes its own experts' rows and the identity part of every token it
     has, and leaves out what the experts held elsewhere would add (their
     pairs sort past the last held expert, as padding does). None: all.
+    `n_group`, `topk_group`: `route_topk`'s group limit, over all the
+    slots whatever is held.
 
     x (T, d); gate_w / up_w (held, d', f); down_w (held, f, d'), d' = d
     without an `expert_input`. `valid`
@@ -217,7 +236,8 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
                          f"{up_w.shape[0]} given")
     top_e, top_w = route_topk(x, router_w, bias, top_k=top_k,
                               norm_topk_prob=norm_topk_prob, scale=scale,
-                              scoring=scoring)
+                              scoring=scoring, n_group=n_group,
+                              topk_group=topk_group)
     zero_pairs = away_pairs = jnp.int32(0)
     identity = None
     if zero_experts or E != experts:
@@ -280,7 +300,8 @@ class DenseOrRoutedFFN:
     the layer has a `"router"`, `dropless_moe_ffn` over all the layer's
     experts plus a shared expert applied to every token. The class says
     `_routing(layer)`: (the router's bias, `dropless_moe_ffn`'s `top_k`,
-    `norm_topk_prob` and `scale`)."""
+    `norm_topk_prob` and `scale`, and `held` and the group limit where it
+    has them)."""
 
     def _ffn(self, layer, x, valid=None):
         """Feed-forward of one layer on tokens x (T, e) after the norm.

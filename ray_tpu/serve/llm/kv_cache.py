@@ -17,9 +17,10 @@ two classes behind the one page table a sequence has. A sequence's first
 `fixed` = `model.fixed_pages(page_size)` table entries are pages of the
 *fixed class*, ids `0 .. fixed_pages - 1` with `fixed_pages = fixed x
 sequences`; its later entries are of the class only the full layers' pool
-backs, ids `fixed_pages .. num_pages - 1`. The full layers' pool backs
-both classes (logical page j of a sequence at its table's entry j), and
-`num_pages` stays its count. What a fixed-class page names besides is the
+backs, ids `fixed_pages .. num_pages - 1`. The full layers' pool (keys
+and values, or the latent rows of a model whose growing pages are latent:
+`models/hybrid_kda_moe.py`) backs both classes (logical page j of a
+sequence at its table's entry j), and `num_pages` stays its count. What a fixed-class page names besides is the
 model's:
 
 - a ring: a window layer sees a sequence's last `window` positions, so it

@@ -30,7 +30,8 @@ from benchmarks.harness import modelcfg                      # noqa: E402
 from benchmarks.harness.reference import rel_rms             # noqa: E402
 from benchmarks.harness.weights import make_weights          # noqa: E402
 from ray_tpu.models import (GQAWindowMoE, GQAWindowMoEConfig,  # noqa: E402
-                            HybridDeltaConfig, HybridSSMMoEConfig, MLAMoE,
+                            HybridDeltaConfig, HybridKDAMoEConfig,
+                            HybridSSMMoEConfig, MLAMoE,
                             ShortcutMLAMoEConfig, Transformer, build_model,
                             model_config)
 from ray_tpu.models.config import TransformerConfig          # noqa: E402
@@ -582,6 +583,11 @@ PINNED = {
     ("ShortcutMLAMoE", "decode_step"): "b117688674ce4a5d",
     ("HybridSSMMoE", "prefill"): "18b87ab133474943",
     ("HybridSSMMoE", "decode_step"): "6e595a332e8134a7",
+    # the seventh class, pinned in PR 50 to the text PR 50 gave it: what it
+    # shares (`models/latent.py` without a LoRA and with the heads' gate,
+    # `route_topk` under a group limit, `ops/kda.py`) is held from here on
+    ("HybridKDAMoE", "prefill"): "882a86995f7cbb01",
+    ("HybridKDAMoE", "decode_step"): "0c9c6ee604dcbc12",
 }
 
 # a class's configuration for its pin: small, and of head sizes that tile
@@ -617,6 +623,15 @@ PINNED_CONFIGS = {
         moe_intermediate_size=128, shared_intermediate_size=128,
         n_routed_experts=8, experts_held=(2, 4), num_experts_per_tok=2,
         max_seq_len=128),
+    "HybridKDAMoE": lambda: HybridKDAMoEConfig(
+        vocab_size=256, d_model=128,
+        layer_types=("linear_attention", "latent_attention"),
+        mlp_layer_types=("dense", "sparse"), n_heads=2, linear_key_dim=128,
+        linear_value_dim=128, chunk=16, kv_lora_rank=64,
+        qk_nope_head_dim=64, qk_rope_head_dim=64, v_head_dim=64, d_ff=256,
+        moe_intermediate_size=128, shared_intermediate_size=128,
+        n_routed_experts=8, experts_held=(2, 4), num_experts_per_tok=2,
+        n_group=2, topk_group=1, max_seq_len=128),
 }
 
 

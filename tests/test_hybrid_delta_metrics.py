@@ -23,6 +23,7 @@ E = xplane.Event
 OLMO = "olmo-hybrid-7b-1chip"
 CELL = OLMO + ".serve.answers3k"
 NEMOTRON_CELL = "nemotron-3-super-120b-a12b-1chip.serve.agent8k"
+LING_CELL = "ling-3.0-flash-vl-1chip.serve.docs16k"
 NEW = ["kernel.delta_step_roofline.answers3k",
        "kernel.delta_chunk_roofline.answers3k",
        "step.attn_linear_ms.answers3k", "step.prefill_ms.answers3k",
@@ -191,7 +192,7 @@ def test_the_cell_is_listed_where_its_readers_find_something():
         if m["name"] in NEW:
             # the delta rule's readers are this cell's alone; the
             # prefill's reads any model that hands up `prefill_counts`
-            shares = ([NEMOTRON_CELL]
+            shares = ([NEMOTRON_CELL, LING_CELL]
                       if m["name"] == "step.prefill_ms.answers3k" else [])
             assert m["workloads"] == [CELL] + shares
             assert m["moves"] == "serve_tokens_per_s"

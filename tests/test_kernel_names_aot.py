@@ -521,3 +521,51 @@ def test_hybrid_ssm_moe_programs_hold_their_kernels_and_alias_the_state(
     nbytes = sum(a.size * a.dtype.itemsize
                  for a in jax.tree.leaves(cache))
     assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+
+
+# ------------------------------------ the seventh architecture's step
+def _compile_hybrid_kda_moe(devices, which: str):
+    """`HybridKDAMoE`'s decode step or 4096-token prefill: a KDA layer
+    and a latent layer over experts at the published widths of
+    Ling-3.0-flash (32 heads of 128 / 128 and a state of 128 x 4096 a lane;
+    a latent row of 512 + 64; 128 of 512 experts of 2560 x 768 held, chosen
+    among 4 of 8 groups), 32 state slots and nobody's."""
+    from ray_tpu.models.hybrid_kda_moe import (LATENT, LINEAR, SPARSE,
+                                               HybridKDAMoE,
+                                               HybridKDAMoEConfig)
+    model = HybridKDAMoE(HybridKDAMoEConfig(
+        vocab_size=1024, layer_types=(LINEAR, LATENT),
+        mlp_layer_types=(SPARSE, SPARSE), experts_held=(0, 128),
+        max_seq_len=4096))
+    return _compile_served(
+        devices, model, which, lambda: model.init_cache(
+            PAGES, PAGE, fixed_pages=32 * model.fixed_pages(PAGE)),
+        "mla_paged_decode_attn+kda_step")
+
+
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_hybrid_kda_moe_programs_hold_their_kernels_and_alias_the_state(
+        which, topo, no_compile_cache):
+    from ray_tpu.ops import gated_delta, grouped_matmul, kda
+    compiled, cache = _compile_hybrid_kda_moe(topo.devices, which)
+    names = kernel_names(compiled.as_text())
+    # gate, up and down of the held experts, two expert layers
+    assert names.count(grouped_matmul.KERNEL_GMM) == 6
+    if which == "step":
+        assert names.count(kda.KERNEL_STEP) == 1
+        assert names.count(paged_attention.KERNEL_MLA_PAGED_DECODE) == 1
+        assert kda.KERNEL_CHUNK not in names
+    else:
+        assert names.count(kda.KERNEL_CHUNK) == 1
+        assert names.count(attention.KERNEL_FWD) == 1
+        assert kda.KERNEL_STEP not in names
+    # the vector decay's kernels are no kernel of the scalar decay's
+    assert not {gated_delta.KERNEL_STEP, gated_delta.KERNEL_CHUNK} & set(
+        names)
+    # every pool is updated in place: the state (33 slots of 128 x 4096
+    # float32), the tail and the latent rows
+    assert cache["state"].shape == (1, 33, 128, 4096)
+    assert cache["kv"].shape == (1, PAGES, PAGE, 640)
+    nbytes = sum(a.size * a.dtype.itemsize
+                 for a in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes

@@ -441,13 +441,15 @@ def test_kept_bytes_a_token_and_layer_are_config_pys_figures():
 def _tiny_served():
     from ray_tpu.models.gqa_window_moe import tiny_gqa_window_moe
     from ray_tpu.models.hybrid_delta import tiny_hybrid_delta
+    from ray_tpu.models.hybrid_kda_moe import tiny_hybrid_kda_moe
     from ray_tpu.models.hybrid_ssm_moe import tiny_hybrid_ssm_moe
     from ray_tpu.models.mla_moe import tiny_mla_moe
     from ray_tpu.models.shortcut_mla_moe import tiny_shortcut_mla_moe
     return {"MLAMoE": tiny_mla_moe, "GQAWindowMoE": tiny_gqa_window_moe,
             "HybridDelta": tiny_hybrid_delta,
             "ShortcutMLAMoE": tiny_shortcut_mla_moe,
-            "HybridSSMMoE": tiny_hybrid_ssm_moe}
+            "HybridSSMMoE": tiny_hybrid_ssm_moe,
+            "HybridKDAMoE": tiny_hybrid_kda_moe}
 
 
 def _tree_sha256(tree) -> str:
@@ -473,6 +475,8 @@ INIT_SHA256 = {
     "HybridDelta": "8b3f53971ffd9817",
     "ShortcutMLAMoE": "085f5ae8b852613d",
     "HybridSSMMoE": "90619eeef1dbbf44",
+    # the seventh class, as PR 50 made it
+    "HybridKDAMoE": "dc5c8e45cfc30e11",
 }
 
 
@@ -497,7 +501,7 @@ def _tiny_models():
 
 @pytest.mark.parametrize("name", ["transformer", "mla_moe", "gqa_window_moe",
                                   "hybrid_delta", "shortcut_mla_moe",
-                                  "hybrid_ssm_moe"])
+                                  "hybrid_ssm_moe", "hybrid_kda_moe"])
 def test_every_class_answers_the_engines_twelve_asks(name):
     """What `EngineCore` calls on a model, on every class of the table,
     with the types it uses them as (`models.paged.PagedDecoder`)."""

@@ -34,15 +34,16 @@ addresses). A full layer keeps every position: pools `"k"`, `"v"` of
 layer keeps a sequence the same bytes at 100 positions and at 3,000: pools
 `"state"` `(linear layers, slots + 1, dk, H x dv)` float32 and `"tail"`
 (the convolution's last `width - 1` inputs, `(linear layers, slots + 1,
-(width - 1) x channels)`), a sequence's at the slot its first table entry
-names (`paged.StateSlots`: a page of the allocator's fixed class, which
+*tail_shape)`: each input folded into rows of whole lanes,
+`ops.gated_delta.tail_shape`), a sequence's at the slot its first table
+entry names (`paged.StateSlots`: a page of the allocator's fixed class, which
 names a state of any shape the model holds and prices, here a delta
 rule's), which the full layers' pools back like any page: one table serves
 both kinds and nothing is keyed by lane. `prefill` scans a prompt from a
 zero state (`gated_delta_prefill`: the chunk kernel, which stops at the
 prompt's true length inside its bucket) and writes the slot whole;
 `decode_step` updates the slots of active lanes in place
-(`gated_delta_step`) and leaves every other alone.
+(`conv_tail_step`, then `gated_delta_step`) and leaves every other alone.
 """
 from __future__ import annotations
 
@@ -58,8 +59,7 @@ from ray_tpu.models.config import ConfigDtypes
 from ray_tpu.models.moe import swiglu
 from ray_tpu.models.paged import (Cache, PagedDecoder, Params, StateSlots,
                                   decode_state_slots, lane_page,
-                                  prefill_page_ids_held, prefill_state_slot,
-                                  slot_rows)
+                                  prefill_page_ids_held, prefill_state_slot)
 from ray_tpu.ops import gated_delta as _gd
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.norms import rms_norm_reference
@@ -277,12 +277,14 @@ class HybridDelta(StateSlots, PagedDecoder):
     # ------------------------------------------------ what an engine asks
     def state_bytes(self, dtype=None) -> int:
         """Bytes the linear layers keep of one sequence, whatever its
-        length: a float32 state and the convolution's tail a layer."""
+        length: a float32 state and the convolution's tail a layer, as
+        the pools hold them (`tail_shape`: whole tiles of rows)."""
         c = self.config
         dt = jnp.dtype(dtype or c.activation_dtype)
         return len(c.linear_layers) * (
             c.linear_key_dim * c.value_dim * 4
-            + (c.conv_width - 1) * c.conv_channels * dt.itemsize)
+            + math.prod(_gd.tail_shape(c.conv_width, c.conv_channels))
+            * dt.itemsize)
 
     def init_cache(self, num_pages: int, page_size: int, dtype=None,
                    fixed_pages: int = 0) -> Cache:
@@ -297,8 +299,8 @@ class HybridDelta(StateSlots, PagedDecoder):
             "k": jnp.zeros(full, dt), "v": jnp.zeros(full, dt),
             "state": jnp.zeros((lin, slots, c.linear_key_dim, c.value_dim),
                                jnp.float32),
-            "tail": jnp.zeros((lin, slots,
-                               (c.conv_width - 1) * c.conv_channels), dt)})
+            "tail": jnp.zeros((lin, slots) + _gd.tail_shape(
+                c.conv_width, c.conv_channels), dt)})
         return make()
 
     def page_bytes(self, page_size: int, tp_shards: int = 1,
@@ -377,7 +379,7 @@ class HybridDelta(StateSlots, PagedDecoder):
         logical = positions // page_size
         offset = positions % page_size
         page = lane_page(page_tables, logical, active, num_pages)
-        slot, tail_at = decode_state_slots(page_tables, active, slots)
+        slot = decode_state_slots(page_tables, active, slots)
         for i, layer in enumerate(params["layers"]):
             if c.layer_types[i] == FULL:
                 li = c.full_layers.index(i)
@@ -390,12 +392,9 @@ class HybridDelta(StateSlots, PagedDecoder):
                     ad)
             else:
                 li = c.linear_layers.index(i)
-                tail = slot_rows(pools["tail"], li, slot).reshape(
-                    B, c.conv_width - 1, c.conv_channels)
-                mixed, tail = _gd.conv_step(
-                    x @ layer["w_qkv"].astype(ad), tail, layer["conv"])
-                pools["tail"] = pools["tail"].at[li, tail_at].set(
-                    tail.reshape(B, -1), mode="drop")
+                mixed, pools["tail"] = _gd.conv_tail_step(
+                    x @ layer["w_qkv"].astype(ad), layer["conv"],
+                    pools["tail"], li, slot)
                 q, k, v, g, beta = self._linear_inputs(layer, x, mixed)
                 o, pools["state"] = _gd.gated_delta_step(
                     q, k, v, g, beta, pools["state"], li, slot)

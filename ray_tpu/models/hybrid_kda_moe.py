@@ -42,9 +42,10 @@ addresses), both small: a latent layer keeps a position one row, pool
 `"kv"` `(latent layers, num_pages, page, row_width)`; a linear layer keeps
 a sequence the same bytes at any length, pools `"state"` `(linear layers,
 slots + 1, dk, H x dv)` float32 and `"tail"` (the convolution's last
-`width - 1` inputs), a sequence's at the slot its first table entry names
-(`paged.StateSlots`), which the latent pool backs like any page. Beside
-them `paged.ExpertCounts`' two entries.
+`width - 1` inputs, `ops.gated_delta.tail_shape` a slot), a sequence's at
+the slot its first table entry names (`paged.StateSlots`), which the
+latent pool backs like any page. Beside them `paged.ExpertCounts`' two
+entries.
 """
 from __future__ import annotations
 
@@ -60,10 +61,10 @@ from ray_tpu.models.moe import DenseOrRoutedFFN
 from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
                                   StateSlots, decode_lanes,
                                   decode_state_slots, prefill_page_ids,
-                                  prefill_state_slot, slot_rows)
+                                  prefill_state_slot)
+from ray_tpu.ops import gated_delta as _gd
 from ray_tpu.ops import kda as _kda
-from ray_tpu.ops.gated_delta import (CHUNK, causal_conv, conv_step,
-                                     l2_normalize)
+from ray_tpu.ops.gated_delta import CHUNK, causal_conv, l2_normalize
 from ray_tpu.ops.norms import rms_norm_reference
 from ray_tpu.ops.rope import rope_cos_sin
 
@@ -312,12 +313,14 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
     # ------------------------------------------------ what an engine asks
     def state_bytes(self, dtype=None) -> int:
         """Bytes the linear layers keep of one sequence, whatever its
-        length: a float32 state and the convolution's tail a layer."""
+        length: a float32 state and the convolution's tail a layer, as
+        the pools hold them (`tail_shape`: whole tiles of rows)."""
         c = self.config
         dt = jnp.dtype(dtype or c.activation_dtype)
         return len(c.of_kind(LINEAR)) * (
             c.linear_key_dim * c.value_dim * 4
-            + (c.conv_width - 1) * c.conv_channels * dt.itemsize)
+            + math.prod(_gd.tail_shape(c.conv_width, c.conv_channels))
+            * dt.itemsize)
 
     def init_cache(self, num_pages: int, page_size: int, dtype=None,
                    fixed_pages: int = 0) -> Cache:
@@ -332,8 +335,8 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
             "kv": jnp.zeros(rows, dt),
             "state": jnp.zeros((lin, slots, c.linear_key_dim, c.value_dim),
                                jnp.float32),
-            "tail": jnp.zeros((lin, slots,
-                               (c.conv_width - 1) * c.conv_channels), dt),
+            "tail": jnp.zeros((lin, slots) + _gd.tail_shape(
+                c.conv_width, c.conv_channels), dt),
             **self._zero_counts()})
         return make()
 
@@ -417,13 +420,12 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
         pools = dict(cache)
         num_pages = pools["kv"].shape[1]
         slots = pools["state"].shape[1] - 1
-        B = tokens.shape[0]
         x = params["embed"].astype(ad)[tokens]                  # (B, e)
         cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
                                 c.rope_theta)              # (B, 1, rope/2)
         page, offset, lengths = decode_lanes(positions, page_tables, active,
                                              num_pages, page_size)
-        slot, tail_at = decode_state_slots(page_tables, active, slots)
+        slot = decode_state_slots(page_tables, active, slots)
         load, sums = pools["moe_load"], self._step_sums()
         for i, layer in enumerate(params["layers"]):
             u = self._norm(x, layer["attn_norm"])
@@ -435,12 +437,9 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
                 x = x + out @ layer["wo"].astype(ad)
             else:
                 li = c.of_kind(LINEAR).index(i)
-                tail = slot_rows(pools["tail"], li, slot).reshape(
-                    B, c.conv_width - 1, c.conv_channels)
-                mixed, tail = conv_step(u @ layer["w_qkv"].astype(ad), tail,
-                                        layer["conv"])
-                pools["tail"] = pools["tail"].at[li, tail_at].set(
-                    tail.reshape(B, -1), mode="drop")
+                mixed, pools["tail"] = _gd.conv_tail_step(
+                    u @ layer["w_qkv"].astype(ad), layer["conv"],
+                    pools["tail"], li, slot)
                 q, k, v, g, beta = self._linear_inputs(layer, u, mixed)
                 o, pools["state"] = _kda.kda_step(
                     q, k, v, g, beta, pools["state"], li, slot)

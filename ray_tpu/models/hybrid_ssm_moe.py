@@ -43,12 +43,13 @@ chips, without the exchange.
 pools `"k"`, `"v"` `(attention layers, num_pages, page, kv heads x head
 dim)` for the attention layers alone; for the state-space layers `"state"`
 `(M layers, slots + 1, N, H x P)` float32 and `"tail"` (the convolution's
-last `width - 1` inputs, `(M layers, slots + 1, (width - 1) x channels)`), a
+last `width - 1` inputs, `(M layers, slots + 1, *tail_shape)`), a
 sequence's at the slot its first table entry names (`paged.StateSlots`).
 `prefill` scans a prompt from a zero state (`ssd_prefill`: the chunk
 kernel, which stops at the prompt's true length inside its bucket) and
 writes the slot whole; `decode_step` updates the slots of active lanes in
-place (`ssd_step`). Beside them `paged.ExpertCounts`' two entries.
+place (`conv_tail_step`, then `ssd_step`). Beside them
+`paged.ExpertCounts`' two entries.
 """
 from __future__ import annotations
 
@@ -65,11 +66,11 @@ from ray_tpu.models.moe import dropless_moe_ffn
 from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
                                   StateSlots, decode_lanes,
                                   decode_state_slots,
-                                  prefill_page_ids, prefill_state_slot,
-                                  slot_rows)
+                                  prefill_page_ids, prefill_state_slot)
+from ray_tpu.ops import gated_delta as _gd
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops import ssd as _ssd
-from ray_tpu.ops.gated_delta import causal_conv, conv_step
+from ray_tpu.ops.gated_delta import causal_conv
 from ray_tpu.ops.norms import rms_norm_reference
 
 # a layer's kind, by the letters of the family's `hybrid_override_pattern`
@@ -317,12 +318,14 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
     # ------------------------------------------------ what an engine asks
     def state_bytes(self, dtype=None) -> int:
         """Bytes the state-space layers keep of one sequence, whatever its
-        length: a float32 state and the convolution's tail a layer."""
+        length: a float32 state and the convolution's tail a layer, as
+        the pools hold them (`tail_shape`: whole tiles of rows)."""
         c = self.config
         dt = jnp.dtype(dtype or c.activation_dtype)
         return len(c.of_kind(SSM)) * (
             c.ssm_state * c.ssm_inner * 4
-            + (c.conv_width - 1) * c.conv_channels * dt.itemsize)
+            + math.prod(_gd.tail_shape(c.conv_width, c.conv_channels))
+            * dt.itemsize)
 
     def init_cache(self, num_pages: int, page_size: int, dtype=None,
                    fixed_pages: int = 0) -> Cache:
@@ -337,8 +340,8 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
             "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
             "state": jnp.zeros((ssm, slots, c.ssm_state, c.ssm_inner),
                                jnp.float32),
-            "tail": jnp.zeros((ssm, slots,
-                               (c.conv_width - 1) * c.conv_channels), dt),
+            "tail": jnp.zeros((ssm, slots) + _gd.tail_shape(
+                c.conv_width, c.conv_channels), dt),
             **self._zero_counts()})
         return make()
 
@@ -420,7 +423,7 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
         x = params["embed"].astype(ad)[tokens]                  # (B, e)
         page, offset, lengths = decode_lanes(positions, page_tables, active,
                                              num_pages, page_size)
-        slot, tail_at = decode_state_slots(page_tables, active, slots)
+        slot = decode_state_slots(page_tables, active, slots)
         load, sums = pools["moe_load"], self._step_sums()
         for i, layer in enumerate(params["layers"]):
             u = self._norm(x, layer["norm"])
@@ -443,12 +446,9 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
             else:
                 li = c.of_kind(SSM).index(i)
                 z, xbc, dt = self._ssm_project(layer, u)
-                tail = slot_rows(pools["tail"], li, slot).reshape(
-                    B, c.conv_width - 1, c.conv_channels)
-                conv, tail = conv_step(xbc, tail, layer["conv"],
-                                       layer["conv_bias"])
-                pools["tail"] = pools["tail"].at[li, tail_at].set(
-                    tail.reshape(B, -1), mode="drop")
+                conv, pools["tail"] = _gd.conv_tail_step(
+                    xbc, layer["conv"], pools["tail"], li, slot,
+                    layer["conv_bias"])
                 xs, Bm, Cm, dt, A = self._ssm_inputs(layer, conv, dt)
                 y, pools["state"] = _ssd.ssd_step(
                     xs, Bm, Cm, dt, A, pools["state"], li, slot,

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.moe import STEP_COUNTS, step_counts
+from ray_tpu.ops.gated_delta import fold_tail
 from ray_tpu.ops.losses import softmax_cross_entropy
 from ray_tpu.ops.norms import rms_norm
 
@@ -243,20 +244,11 @@ def prefill_state_slot(page_table, slots: int):
 
 
 def decode_state_slots(page_tables, active, slots: int):
-    """(slot, tail_at) of a decode step's lanes: the slot each lane's
-    state is updated in, -1 (the step kernels leave it alone) for a lane
-    that is inactive or whose first entry is no slot of the class; and
-    where its rows beside the state are written, past the pool (dropped)
-    for the same lanes."""
+    """The slot each lane of a decode step updates its state and its
+    convolution's tail in: -1 (the step kernels leave both alone) for a
+    lane that is inactive or whose first entry is no slot of the class."""
     first = page_tables[:, 0]
-    slot = jnp.where(active & (first >= 0) & (first < slots), first, -1)
-    return slot, jnp.where(slot >= 0, slot, slots + 1)
-
-
-def slot_rows(pool, li: int, slot):
-    """Rows `slot` (B,) of pool row `li`; a lane without a slot (-1) reads
-    slot 0's, which it will not write."""
-    return pool[li, jnp.clip(slot, 0, pool.shape[1] - 1)]
+    return jnp.where(active & (first >= 0) & (first < slots), first, -1)
 
 
 class StateSlots:
@@ -266,7 +258,8 @@ class StateSlots:
     its length), `page_bytes(page_size, tp_shards=1, dtype=None)` (of the
     pools that grow with a sequence) and its config the `chunk` a prefill
     scans by. Its pools `"state"` and `"tail"` (the rows a causal
-    convolution keeps beside the state) are `(layers, slots + 1, ...)`."""
+    convolution keeps beside the state, `ops.gated_delta.tail_shape` a
+    slot) are `(layers, slots + 1, ...)`."""
 
     def fixed_pages(self, page_size: int) -> int:
         """One: a sequence's first table entry, which names its slot."""
@@ -301,8 +294,8 @@ class StateSlots:
         return {"state": pools["state"].at[li, slot].set(state,
                                                          mode="drop"),
                 "tail": pools["tail"].at[li, slot].set(
-                    tail.reshape(-1).astype(pools["tail"].dtype),
-                    mode="drop")}
+                    fold_tail(tail, pools["tail"].shape).astype(
+                        pools["tail"].dtype), mode="drop")}
 
 
 class ExpertCounts:

@@ -576,18 +576,25 @@ PINNED = {
     ("GQAWindowMoE", "decode_step"): "89c5c77c979e1f86",
     # the three classes after it, pinned in PR 49 to PR 48's text before
     # what the classes share was lifted out of them (`models/paged.py`,
-    # `models/gqa.py`), so that the lift is held by hashes it did not write
-    ("HybridDelta", "prefill"): "276f489ed39b7daf",
-    ("HybridDelta", "decode_step"): "ad19cc87482cb346",
+    # `models/gqa.py`), so that the lift is held by hashes it did not write.
+    # PR 51 gave the pool of the convolution's tails whole tiles a slot
+    # (`ops.gated_delta.tail_shape`, `conv_tail_step`): the decode steps of
+    # the three classes that keep a tail (gather, `conv_step` and scatter
+    # over the new shape) and their prefills (`_write_slot` folds the tail
+    # into it) are pinned anew to PR 51's text; `ShortcutMLAMoE`'s two and
+    # the six before them keep their hashes
+    ("HybridDelta", "prefill"): "328443fc4f95f8b1",
+    ("HybridDelta", "decode_step"): "30470ff95209832d",
     ("ShortcutMLAMoE", "prefill"): "d4d9ab62a550993a",
     ("ShortcutMLAMoE", "decode_step"): "b117688674ce4a5d",
-    ("HybridSSMMoE", "prefill"): "18b87ab133474943",
-    ("HybridSSMMoE", "decode_step"): "6e595a332e8134a7",
+    ("HybridSSMMoE", "prefill"): "2423f3d6a6654486",
+    ("HybridSSMMoE", "decode_step"): "30d8c26f72fc7461",
     # the seventh class, pinned in PR 50 to the text PR 50 gave it: what it
     # shares (`models/latent.py` without a LoRA and with the heads' gate,
-    # `route_topk` under a group limit, `ops/kda.py`) is held from here on
-    ("HybridKDAMoE", "prefill"): "882a86995f7cbb01",
-    ("HybridKDAMoE", "decode_step"): "0c9c6ee604dcbc12",
+    # `route_topk` under a group limit, `ops/kda.py`) is held from here on;
+    # anew in PR 51 with the two others that keep a tail
+    ("HybridKDAMoE", "prefill"): "5dc53e652cdd1d41",
+    ("HybridKDAMoE", "decode_step"): "8e185abd9caf302c",
 }
 
 # a class's configuration for its pin: small, and of head sizes that tile

@@ -133,6 +133,105 @@ def test_the_convolution_continues_from_its_tail():
         jnp.concatenate([jnp.zeros((1, 6)), x[:2]]))
 
 
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def _flat_tail_step(x, w, flat, layer, slots, bias=None):
+    """What the three classes did before PR 51, on the pool as it was
+    (layers, slots + 1, (width - 1) x channels): gather by slot (slot 0's
+    rows for a lane without one), `conv_step`, scatter with a write past
+    the pool dropped."""
+    B, width = x.shape[0], w.shape[0]
+    tail = flat[layer, jnp.clip(slots, 0, flat.shape[1] - 1)].reshape(
+        B, width - 1, -1)
+    y, tail = gd.conv_step(x, tail, w, bias)
+    where = jnp.where(slots >= 0, slots, flat.shape[1])
+    return y, flat.at[layer, where].set(
+        tail.reshape(B, -1).astype(flat.dtype), mode="drop")
+
+
+# the three classes' convolutions (Olmo-Hybrid's 2 x 30 x 96 + 30 x 192
+# channels, Ling's 3 x 32 x 128, Nemotron's 8192 + 2 x 1024 under a bias) at
+# a few lanes, and channels that are no whole lanes (one row an input)
+@pytest.mark.parametrize("pool_dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("channels,bias,slots", [
+    (11520, False, (2, -1, 0)),         # a lane without a slot
+    (12288, False, (-1, 4, -1, 1)),     # two: neither writes
+    (10240, True, (3, 0, 1, 2)),
+    (10240, True, (-1, -1)),            # a step of no active lane
+    (96, False, (1, -1, 3)), (96, True, (0, -1, -1)),
+])
+def test_conv_tail_step_on_whole_tiles_is_the_flat_pools_step_bit_for_bit(
+        channels, bias, slots, pool_dtype):
+    r = np.random.default_rng(channels + len(slots))
+    width, B = 4, len(slots)
+    ad = jnp.dtype(pool_dtype)
+    fold = gd.tail_shape(width, channels)
+    rows = jnp.asarray(r.normal(size=(2, 6, width - 1, channels)), ad)
+    rows = rows.at[1, 1].set(0)         # a fresh slot: zeros before it
+    pool = gd.fold_tail(rows, fold)
+    assert pool.shape == (2, 6) + fold
+    x = jnp.asarray(r.normal(size=(B, channels)), ad)
+    w = jnp.asarray(r.normal(size=(width, channels)), ad)
+    b = jnp.asarray(r.normal(size=(channels,)), ad) if bias else None
+    slots = jnp.asarray(slots, jnp.int32)
+
+    want_y, want_flat = jax.jit(_flat_tail_step, static_argnums=3)(
+        x, w, rows.reshape(2, 6, -1), 1, slots, b)
+    y, new = jax.jit(gd.conv_tail_step, static_argnums=3)(
+        x, w, pool, 1, slots, b)
+    assert y.dtype == x.dtype and new.dtype == pool.dtype
+    # (a lane without a slot convolves nobody's rows here and slot 0's
+    # there: an output nobody reads)
+    on = np.asarray(slots) >= 0
+    assert (_bits(y)[on] == _bits(want_y)[on]).all()
+    # the whole pool: the flat one's numbers, zeros past the channels
+    assert (_bits(new) == _bits(gd.fold_tail(
+        want_flat.reshape(rows.shape), fold))).all()
+    # the other layer, the slots of no lane and nobody's: as they were;
+    # an active lane's rows: its last two inputs and the new one
+    new, pool = np.asarray(new, np.float32), np.asarray(pool, np.float32)
+    held = {int(s) for s in slots if s >= 0}
+    rest = [i for i in range(6) if i not in held]
+    assert (new[0] == pool[0]).all() and (new[1, rest] == pool[1, rest]).all()
+    for lane, slot in enumerate(np.asarray(slots)):
+        if slot >= 0:
+            assert (new[1, slot, :2] == pool[1, slot, 1:]).all()
+            assert (new[1, slot, 2].reshape(-1)[:channels]
+                    == np.asarray(x[lane], np.float32)).all()
+            # and its output the convolution over the four, written out
+            four = np.concatenate([pool[1, slot].reshape(3, -1)[:, :channels],
+                                   np.asarray(x[lane], np.float32)[None]])
+            z = (four * np.asarray(w, np.float32)).sum(0) + (
+                np.asarray(b, np.float32) if bias else 0.0)
+            np.testing.assert_allclose(
+                np.asarray(y[lane], np.float32), z / (1 + np.exp(-z)),
+                rtol=2e-2 if pool_dtype == "bfloat16" else 1e-5, atol=1e-5)
+
+
+def test_conv_tail_step_takes_a_pool_of_another_dtype_than_the_inputs():
+    """A float32 pool under bfloat16 activations (and the other way): the
+    window is promoted as `jnp.concatenate` promotes it, the rows written
+    back in the pool's dtype, as on the flat pool."""
+    r = np.random.default_rng(5)
+    for pool_dt, x_dt in ((jnp.float32, jnp.bfloat16),
+                          (jnp.bfloat16, jnp.float32)):
+        rows = jnp.asarray(r.normal(size=(1, 4, 3, 256)), pool_dt)
+        fold = gd.tail_shape(4, 256)
+        x = jnp.asarray(r.normal(size=(3, 256)), x_dt)
+        w = jnp.asarray(r.normal(size=(4, 256)), x_dt)
+        slots = jnp.asarray([2, -1, 0], jnp.int32)
+        want_y, want_flat = jax.jit(_flat_tail_step, static_argnums=3)(
+            x, w, rows.reshape(1, 4, -1), 0, slots)
+        y, new = jax.jit(gd.conv_tail_step, static_argnums=3)(
+            x, w, gd.fold_tail(rows, fold), 0, slots)
+        want = gd.fold_tail(want_flat.reshape(rows.shape), fold)
+        for a, b in ((y[::2], want_y[::2]), (new, want)):   # the active lanes
+            assert a.dtype == b.dtype and (_bits(a) == _bits(b)).all()
+
+
 def test_kernels_tile_the_published_shapes_and_say_where_they_run():
     assert gd.chunk_tiles(96, 192, 64, jnp.bfloat16)
     assert gd.chunk_heads(30) == 6 and gd.chunk_heads(4) == 4
@@ -140,6 +239,11 @@ def test_kernels_tile_the_published_shapes_and_say_where_they_run():
     assert gd.step_columns(30, 96, 192) == 1920
     assert gd.step_tiles(30, 96, 192) and not gd.step_tiles(4, 8, 16)
     assert not gd.uses_step_kernel(30, 96, 192)         # this is a CPU
+    # an input of the convolution: 90 rows of whole lanes in 96, whole
+    # tiles (Ling's 96 and Nemotron's 80 are)
+    assert gd.tail_shape(4, 11520) == (3, 96, 128)
+    assert gd.tail_shape(4, 10240) == (3, 80, 128)
+    assert gd.tail_shape(4, 96) == (3, 1, 96)
     with compute_platform("tpu"):
         assert gd.uses_step_kernel(30, 96, 192)
         assert gd.uses_chunk_kernel(96, 192, 64, jnp.bfloat16)
@@ -288,6 +392,26 @@ def test_prefill_then_decode_through_the_engine_matches_the_reference(
     assert core.alloc.free_pages == core.num_pages
 
 
+def test_the_kernels_under_the_interpreter_give_the_same_logits(
+        tiny_ref, monkeypatch):
+    """The same check with the kernels of the served path forced on (the
+    Pallas interpreter off the TPU): the chunked scan and the recurrence's
+    step."""
+    mod, sz, params, pc = tiny_ref
+    monkeypatch.setattr(gd, "gated_delta_prefill",
+                        gd.gated_delta_prefill_kernel)
+    monkeypatch.setattr(gd, "gated_delta_step", gd.gated_delta_step_kernel)
+    core = EngineCore(pc, params, num_pages=0, page_size=PAGE, max_batch=2)
+    p, steps = 21, 8
+    toks = np.zeros((256,), np.int32)
+    toks[:p + steps] = np.random.default_rng(3).integers(0, sz.vocab,
+                                                         p + steps)
+    got = _through_the_engine(core, toks, p, steps, lane=1)
+    want = mod.reference_rows(sz, params, jnp.asarray(toks),
+                              jnp.int32(p - 1), steps + 1)
+    assert rel_rms(got, want) < 2e-4
+
+
 def test_prefill_of_n_then_m_steps_is_a_prefill_of_n_plus_m(tiny_ref):
     _, sz, params, pc = tiny_ref
     core = EngineCore(pc, params, num_pages=0, page_size=PAGE, max_batch=2)
@@ -347,8 +471,10 @@ def test_a_state_costs_a_sequence_the_same_at_any_length(tiny_ref):
     assert cache["k"].shape == (2, core.num_pages, PAGE, 64)
     assert cache["state"].shape == (6, 3, DK, H * DV)
     assert cache["state"].dtype == jnp.float32
-    assert cache["tail"].shape == (6, 3, 3 * (2 * H * DK + H * DV))
-    state = 6 * (DK * H * DV * 4 + 3 * 128 * 4)
+    # a slot's three inputs, each 128 channels folded into rows of lanes,
+    # a whole tile of rows
+    assert cache["tail"].shape == (6, 3, 3, 16, 2 * H * DK + H * DV)
+    state = 6 * (DK * H * DV * 4 + 3 * 16 * 128 * 4)
     assert model.state_bytes() == state
     assert model.cache_page_bytes(PAGE, fixed=True) == state
     assert model.cache_page_bytes(PAGE) == 2 * 2 * PAGE * 64 * 4
@@ -367,7 +493,8 @@ def test_a_state_costs_a_sequence_the_same_at_any_length(tiny_ref):
             "paged_decode_attn+gated_delta_step")
     assert served.fixed_pages(16) == 1
     # 3 linear layers: 96 x 5760 float32 of state, 3 x 11520 bf16 of tail
-    assert served.state_bytes() == 3 * (96 * 5760 * 4 + 3 * 11520 * 2)
+    # in 96 rows of 128 lanes, as the pool holds them
+    assert served.state_bytes() == 3 * (96 * 5760 * 4 + 3 * 96 * 128 * 2)
     assert served.cache_page_bytes(16) == 2 * 16 * 3840 * 2
 
 

@@ -554,7 +554,7 @@ def test_the_set_up_span_names_the_pools_shapes(tiny_ref, recorder):  # noqa
     # one latent layer's rows of 128; three KDA layers' slots (2 and
     # nobody's), a state of 8 x 32 and a tail of 3 x 96
     assert spans[sp.SETUP_CACHE]["pools"] == (
-        "kv:1x40x8x128 state:3x3x8x32 tail:3x3x288")
+        "kv:1x40x8x128 state:3x3x8x32 tail:3x3x3x1x96")
 
 
 def test_a_config_names_its_model_and_refusals_are_plain():

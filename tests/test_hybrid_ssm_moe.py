@@ -254,7 +254,7 @@ def test_the_set_up_span_names_the_pools_shapes(tiny_ref, recorder):  # noqa
     # one attention layer's pages; two state-space layers' slots (2 and
     # nobody's), a state of 16 x 32 and a tail of 3 x 96
     assert spans[sp.SETUP_CACHE]["pools"] == (
-        "k:1x40x8x32 state:2x3x16x32 tail:2x3x288 v:1x40x8x32")
+        "k:1x40x8x32 state:2x3x16x32 tail:2x3x3x1x96 v:1x40x8x32")
 
 
 def test_a_config_names_its_model_and_refusals_are_plain():

@@ -321,6 +321,18 @@ def _compile_served(devices, model, which: str, make_cache, kernels: str):
         return traced.lower().compile(), cache
 
 
+def _held_bytes(cache) -> int:
+    """Bytes of a cache that a program must update in place: every leaf
+    but `"moe_step"`'s (`paged.ExpertCounts`: the last step's counts, five
+    int32 scalars that each program makes anew and aliases to nothing;
+    until PR 51 the padding of the tail pool's 33 slots to 48 in the
+    compiler's layout had stood in for their 20 bytes in these sums)."""
+    counts = jax.tree.leaves(cache.get("moe_step", {}))
+    assert all(a.shape == () and a.dtype == jnp.int32 for a in counts)
+    return sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves(cache)) - 4 * len(counts)
+
+
 def _compile_mla_moe(devices, which: str):
     """`MLAMoE`'s decode step or 2048-token prefill (the batch32 cell's
     largest bucket: 8192 pairs through `moe_gmm`): the dense layer and one
@@ -403,19 +415,19 @@ def test_gqa_window_moe_programs_hold_their_kernels_by_name(
 
 
 # ------------------------------------- the fourth architecture's step
-def _compile_hybrid_delta(devices, which: str):
+def _compile_hybrid_delta(devices, which: str, slots: int = 32):
     """`HybridDelta`'s decode step or 3072-token prefill (the answers3k
     cell's context limit): a linear and a full layer at the published
     widths of Olmo-Hybrid-7B (30 heads of 96 / 192 and a state of 96 x
     5760 a lane; 30 query heads over 30 kv heads of 128: a group of one
-    in the page walk), 32 state slots and nobody's."""
+    in the page walk), `slots` state slots and nobody's."""
     from ray_tpu.models.hybrid_delta import (FULL, LINEAR, HybridDelta,
                                              HybridDeltaConfig)
     model = HybridDelta(HybridDeltaConfig(
         vocab_size=1024, layer_types=(LINEAR, FULL)))
     return _compile_served(
         devices, model, which, lambda: model.init_cache(
-            PAGES, PAGE, fixed_pages=32 * model.fixed_pages(PAGE)),
+            PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
         "paged_decode_attn+gated_delta_step")
 
 
@@ -481,13 +493,13 @@ def test_shortcut_mla_moe_programs_hold_their_kernels_by_name(
 
 
 # -------------------------------------- the sixth architecture's step
-def _compile_hybrid_ssm_moe(devices, which: str):
+def _compile_hybrid_ssm_moe(devices, which: str, slots: int = 32):
     """`HybridSSMMoE`'s decode step or 4096-token prefill: one layer of
     each kind at the published widths of Nemotron-3-Super (128 state-space
     heads of 64 in 8 groups and a state of 128 x 8192 a lane; 32 query
     heads over 2 kv heads of 128: a group of sixteen in the page walk; 128
-    of 512 experts of 1024 x 2688 held behind a latent), 32 state slots and
-    nobody's."""
+    of 512 experts of 1024 x 2688 held behind a latent), `slots` state slots
+    and nobody's."""
     from ray_tpu.models.hybrid_ssm_moe import (HybridSSMMoE,
                                                HybridSSMMoEConfig)
     model = HybridSSMMoE(HybridSSMMoEConfig(
@@ -495,7 +507,7 @@ def _compile_hybrid_ssm_moe(devices, which: str):
         max_seq_len=4096))
     return _compile_served(
         devices, model, which, lambda: model.init_cache(
-            PAGES, PAGE, fixed_pages=32 * model.fixed_pages(PAGE)),
+            PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
         "paged_decode_attn+ssd_step")
 
 
@@ -518,18 +530,17 @@ def test_hybrid_ssm_moe_programs_hold_their_kernels_and_alias_the_state(
     # every pool is updated in place: the state (33 slots of 128 x 8192
     # float32), the tail, the keys and the values
     assert cache["state"].shape == (1, 33, 128, 8192)
-    nbytes = sum(a.size * a.dtype.itemsize
-                 for a in jax.tree.leaves(cache))
-    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    assert compiled.memory_analysis().alias_size_in_bytes >= _held_bytes(
+        cache)
 
 
 # ------------------------------------ the seventh architecture's step
-def _compile_hybrid_kda_moe(devices, which: str):
+def _compile_hybrid_kda_moe(devices, which: str, slots: int = 32):
     """`HybridKDAMoE`'s decode step or 4096-token prefill: a KDA layer
     and a latent layer over experts at the published widths of
     Ling-3.0-flash (32 heads of 128 / 128 and a state of 128 x 4096 a lane;
     a latent row of 512 + 64; 128 of 512 experts of 2560 x 768 held, chosen
-    among 4 of 8 groups), 32 state slots and nobody's."""
+    among 4 of 8 groups), `slots` state slots and nobody's."""
     from ray_tpu.models.hybrid_kda_moe import (LATENT, LINEAR, SPARSE,
                                                HybridKDAMoE,
                                                HybridKDAMoEConfig)
@@ -539,7 +550,7 @@ def _compile_hybrid_kda_moe(devices, which: str):
         max_seq_len=4096))
     return _compile_served(
         devices, model, which, lambda: model.init_cache(
-            PAGES, PAGE, fixed_pages=32 * model.fixed_pages(PAGE)),
+            PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
         "mla_paged_decode_attn+kda_step")
 
 
@@ -566,6 +577,39 @@ def test_hybrid_kda_moe_programs_hold_their_kernels_and_alias_the_state(
     # float32), the tail and the latent rows
     assert cache["state"].shape == (1, 33, 128, 4096)
     assert cache["kv"].shape == (1, PAGES, PAGE, 640)
-    nbytes = sum(a.size * a.dtype.itemsize
-                 for a in jax.tree.leaves(cache))
-    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    assert compiled.memory_analysis().alias_size_in_bytes >= _held_bytes(
+        cache)
+
+
+# ------------------------- the recurrent classes' convolution in a step
+@pytest.mark.parametrize("compile_step,tail", [
+    (_compile_hybrid_delta, (3, 96, 128)),      # 11,520 channels in 12,288
+    (_compile_hybrid_ssm_moe, (3, 80, 128)),    # 10,240, under a bias
+    (_compile_hybrid_kda_moe, (3, 96, 128)),    # 12,288
+], ids=["HybridDelta", "HybridSSMMoE", "HybridKDAMoE"])
+def test_a_step_scatters_the_tail_pool_once_in_place_as_it_lies(
+        compile_step, tail, topo, no_compile_cache):
+    """The step of each class that keeps a convolution's tail writes the
+    lanes' rows with one fused `scatter` over the pool in the layout it is
+    written in (`tail_shape`'s whole tiles: the slots ahead of a slot's
+    rows), in place: no loop of `dynamic-update-slice` a lane (the flat
+    pool's, whose slots the compiler laid behind a slot's numbers: 2.6 us a
+    lane and layer, PERF.md PR 51), no copy of the pool. 2048 slots: a
+    pool of 126-151 MB, since one small enough the compiler holds in fast
+    memory over the whole step, a copy each way (the 33 slots of one layer
+    above, and Nemotron's five layers' 10 MB in its cell, at the parent
+    too)."""
+    compiled, cache = compile_step(topo.devices, "step", slots=2048)
+    text = compiled.as_text()
+    assert cache["tail"].shape == (1, 2049) + tail
+    dims = ",".join(map(str, cache["tail"].shape[1:]))
+    # (layout, operation) of every instruction that gives a pool's shape,
+    # with the one layer ahead or without
+    made = re.findall(
+        rf"= bf16\[(?:1,)?{dims}\]\{{([\d,]+)[^\n]*? ([\w\-]+)\(", text)
+    assert {layout for layout, _ in made} == {"4,3,2,1,0", "3,2,1,0"}
+    ops = [op for _, op in made]
+    assert ops.count("scatter") == 1
+    assert set(ops) == {"parameter", "bitcast", "fusion", "scatter"}
+    assert compiled.memory_analysis().alias_size_in_bytes >= _held_bytes(
+        cache)

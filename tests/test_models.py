@@ -614,29 +614,32 @@ def test_prefill_page_ids_against_a_loop(true_len, ring):
         assert len(live) == min(held, ring)
 
 
-@pytest.mark.parametrize("first,active,slot,tail_at,prefill", [
-    (0, True, 0, 0, 0), (3, True, 3, 3, 3),     # a slot of the class
-    (4, True, -1, 5, 5),                        # past the pool: nobody's
-    (9, True, -1, 5, 5), (-1, True, -1, 5, 5),  # and unassigned
-    (2, False, -1, 5, 2),                       # an inactive lane
+@pytest.mark.parametrize("first,active,slot,prefill", [
+    (0, True, 0, 0), (3, True, 3, 3),           # a slot of the class
+    (4, True, -1, 5),                           # past the pool: nobody's
+    (9, True, -1, 5), (-1, True, -1, 5),        # and unassigned
+    (2, False, -1, 2),                          # an inactive lane
 ])
 def test_state_slot_arithmetic_against_its_cases(first, active, slot,
-                                                 tail_at, prefill):
+                                                 prefill):
     """`slots` = 4 slots of the class and one more, nobody's (index 4):
-    a lane without a slot updates none (-1), writes its tail past the pool
-    (5: dropped) and reads a row that exists."""
-    from ray_tpu.models.paged import (decode_state_slots, prefill_state_slot,
-                                      slot_rows)
+    a lane without a slot updates none (-1): its convolution reads
+    nobody's rows, which exist, and writes none."""
+    from ray_tpu.models.paged import decode_state_slots, prefill_state_slot
+    from ray_tpu.ops.gated_delta import (conv_step, conv_tail_step,
+                                         tail_shape)
     slots = 4
     tables = jnp.asarray([[first, 7, 8]], jnp.int32)
-    got_slot, got_at = decode_state_slots(tables, jnp.asarray([active]),
-                                          slots)
-    assert (int(got_slot[0]), int(got_at[0])) == (slot, tail_at)
+    got = decode_state_slots(tables, jnp.asarray([active]), slots)
+    assert int(got[0]) == slot
     assert int(prefill_state_slot(tables[0], slots)) == prefill
-    pool = jnp.arange(2 * (slots + 1) * 3).reshape(2, slots + 1, 3)
-    rows = slot_rows(pool, 1, got_slot)
-    assert rows.shape == (1, 3)
-    assert rows.tolist() == pool[1, max(slot, 0)][None].tolist()
-    # a write at `tail_at` past the pool is dropped
-    written = pool.at[1, got_at].set(-1, mode="drop")
-    assert bool((written == pool).all()) == (tail_at > slots)
+    pool = jnp.arange(2. * (slots + 1) * 3 * 2).reshape(
+        2, slots + 1, *tail_shape(4, 2))
+    x, w = jnp.asarray([[1., -1.]]), jnp.ones((4, 2)) / 8
+    y, written = conv_tail_step(x, w, pool, 1, got)
+    want_y, want_rows = conv_step(x, pool[1, slot].reshape(1, 3, 2), w)
+    assert y.tolist() == want_y.tolist()
+    assert bool((written == pool).all()) == (slot < 0)
+    if slot >= 0:
+        assert written[1, slot].reshape(1, 3, 2).tolist() == \
+            want_rows.tolist()

@@ -7,10 +7,17 @@ recurrence written position by position in float32. Chip only:
 
     chiprun -- python tools/bench_delta.py
     chiprun -- python tools/bench_delta.py --buckets 512,3072 --lanes 8
+    chiprun -- python tools/bench_delta.py --tail
 
 What `kernel.delta_chunk_roofline.answers3k` and
 `kernel.delta_step_roofline.answers3k` read inside a cell, read alone
-(PERF.md section 5).
+(PERF.md section 5). `--tail` times a decode step's convolution alone
+instead, at the three recurrent cells' channels: `conv_tail_step` over the
+pool as it is held since PR 51 (`tail_shape`: a slot whole tiles) against
+the same gather, `conv_step` and scatter over the flat pool of before
+(`(layers, slots + 1, (width - 1) x channels)`), microseconds a layer and
+the share of 819 GB/s that the tails' bytes, read and written once, make
+of it; each at the cells' own 33 slots and at 8 slots a lane.
 """
 import argparse
 import os
@@ -27,6 +34,11 @@ from ray_tpu.ops import gated_delta as gd
 
 H, DK, DV = 30, 96, 192
 HBM = 819e9
+# the recurrent cells' convolutions: (channels, layers, a bias)
+TAILS = {"olmo-hybrid-7b": (11520, 9, False),
+         "ling-3.0-flash": (12288, 6, False),
+         "nemotron-3-super": (10240, 5, True)}
+WIDTH = 4
 
 
 def timed(fn, *args, n=20):
@@ -50,12 +62,67 @@ def inputs(rows, seed=0):
     return q.astype(jnp.bfloat16), k.astype(jnp.bfloat16), v, g, beta
 
 
+def flat_tail_step(x, w, pool, layer, slots, bias=None):
+    """The step over the flat pool, as the three classes wrote it before
+    PR 51: a slot's rows one run of `(width - 1) x channels` numbers."""
+    B, n = x.shape[0], pool.shape[1] - 1
+    tail = pool[layer, jnp.clip(slots, 0, n)].reshape(B, WIDTH - 1, -1)
+    y, tail = gd.conv_step(x, tail, w, bias)
+    return y, pool.at[layer, jnp.where(slots >= 0, slots, n + 1)].set(
+        tail.reshape(B, -1), mode="drop")
+
+
+def tails(B: int, rounds: int = 8, n: int = 20):
+    """A step's convolutions alone: every layer of a pool, one after
+    another as a step runs them (each layer's output the next one's
+    input), `rounds` times a call so that the device and not the host's
+    dispatch is timed. Two pools: the cells' own `B + 1` slots (alone in a
+    program the compiler holds such a pool in fast memory whole) and 8
+    slots a lane (170 MB at Olmo's sizes: in the chip's memory, as a
+    cell's lies beside its weights)."""
+    for name, (channels, layers, has_bias) in TAILS.items():
+        r = np.random.default_rng(channels)
+        x = jnp.asarray(r.normal(size=(B, channels)), jnp.bfloat16)
+        w = jnp.asarray(r.normal(size=(WIDTH, channels)), jnp.bfloat16)
+        bias = (jnp.asarray(r.normal(size=(channels,)), jnp.bfloat16)
+                if has_bias else None)
+        nbytes = (B * 2 * WIDTH * channels + WIDTH * channels) * 2
+        fold = gd.tail_shape(WIDTH, channels)
+        for slots in (B, 8 * B):
+            lanes = jnp.asarray(r.permutation(slots)[:B], jnp.int32)
+            line = (f"{name}, {channels} channels, {layers} layers, {B} "
+                    f"lanes of {slots} slots:")
+            for label, fn, slot in (
+                    ("whole tiles", gd.conv_tail_step, fold),
+                    ("flat", flat_tail_step, ((WIDTH - 1) * channels,))):
+                def step(x, pool, fn=fn):
+                    for li in range(rounds * layers):
+                        x, pool = fn(x, w, pool, li % layers, lanes, bias)
+                    return x, pool
+                step = jax.jit(step, donate_argnums=(1,))
+                pool = jnp.zeros((layers, slots + 1) + slot, jnp.bfloat16)
+                _, pool = step(x, pool)
+                jax.block_until_ready(pool)
+                t = time.perf_counter()
+                for _ in range(n):
+                    _, pool = step(x, pool)
+                jax.block_until_ready(pool)
+                us = (time.perf_counter() - t) / (n * rounds * layers) * 1e6
+                line += (f" {label} {us:.1f} us a layer "
+                         f"({100 * nbytes / HBM / (us * 1e-6):.1f} % of 819 "
+                         f"GB/s);")
+            print(line, flush=True)
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--buckets", default="256,1024,2048")
     ap.add_argument("--lanes", type=int, default=32)
+    ap.add_argument("--tail", action="store_true")
     a = ap.parse_args()
     print(f"device {jax.devices()[0].device_kind}; {H} heads of {DK} / {DV}")
+    if a.tail:
+        return tails(a.lanes)
     for s in (int(x) for x in a.buckets.split(",")):
         q, k, v, g, beta = inputs((H, s))
         fn = jax.jit(lambda *x: gd.gated_delta_prefill(*x, s))

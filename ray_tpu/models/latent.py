@@ -46,8 +46,19 @@ from ray_tpu.ops.rope import apply_rope_cached
 
 Params = Dict[str, Any]
 
-# prefill's flash blocks (keys are 192 or 256 wide: PERF.md section 4)
-ATTN_BLOCK = 128
+# prefill's flash blocks (block_q, block_k), read on the chip alone at the
+# three classes' heads and widths (20 of 256 / 256, 64 and 32 of 192 / 128)
+# over buckets 256 to 16,384 (`tools/bench_flash.py --latent`; PERF.md
+# section 6, PR 53): as large as the GQA classes' (`models/gqa.py`), and one
+# pair for every bucket and width, the fastest of nine by 4 % or more
+# wherever the bucket holds two blocks (0.45 ms at 2048 tokens and 20 heads
+# against 0.51 at 512 x 512 and 1.94 at 128 x 128; 22.0 / 31.6 / 158.6 at
+# 16,384: a grid step costs 0.4-0.5 us whatever it holds, which is what five
+# blocks of 128's matmuls do). A shorter bucket is one block (the call cuts
+# them to it), which is its fastest too. 1024 x 2048 is slower where it
+# compiles (192 / 128: 57.0 ms against 55.5 at 16,384 and 64 heads) and past
+# the kernel's fast memory at 256 / 256 from 8192 on
+PREFILL_BLOCKS = (1024, 1024)
 
 
 class LatentDims(ConfigDtypes):
@@ -178,9 +189,10 @@ class LatentAttention:
                                         c.qk_rope_head_dim))], axis=-1)
         v = kv[..., nope:]
         qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        block_q, block_k = PREFILL_BLOCKS
         out = flash_attention(qt, kt, vt, causal=True,
                               sm_scale=1.0 / math.sqrt(c.qk_head_dim),
-                              block_q=ATTN_BLOCK, block_k=ATTN_BLOCK)
+                              block_q=block_q, block_k=block_k)
         out = self._gated(layer, h, out.transpose(0, 2, 1, 3))
         return out, c_kv, k_rope
 

@@ -352,6 +352,32 @@ def test_share_metrics_read_kernels_and_the_five_counts(traced_share_run):
         100 * (need["bytes"] / 819e9) / (24 * 0.2e-3), rel=1e-6)
 
 
+@pytest.mark.parametrize("case", ["reads", "no kernel", "no prefill span",
+                                  "no spans", "untraced"])
+def test_prefill_flash_ms_is_the_kernels_time_a_traced_prefill(
+        traced_share_run, traced_glm_run, case):
+    """PR 53's reader: 8 flash forwards of 0.5 ms in one traced prefill;
+    None, and nothing raised, for a trace without the kernel (a prefill
+    that runs another forward), a program without the span, and an
+    untraced run."""
+    read = metric("step.prefill_flash_ms")
+    run = dict(traced_share_run)
+    if case == "reads":
+        assert read(run) == pytest.approx(8 * 0.5)
+        return
+    if case == "no kernel":
+        run = dict(traced_glm_run)
+    elif case == "no prefill span":
+        run["_spans"] = spans.Reading(
+            [s for s in run["_spans"].spans if s.name != spans.PREFILL],
+            {}, 0.0)
+    elif case == "no spans":
+        run["_spans"] = None
+    else:
+        run.update(trace=None, _spans=None)
+    assert read(run) is None
+
+
 @pytest.mark.parametrize("name", SHARE_METRICS)
 def test_share_metrics_leave_the_line_where_there_is_nothing_to_read(
         traced_share_run, traced_glm_run, name):

@@ -595,6 +595,10 @@ PINNED = {
     # anew in PR 51 with the two others that keep a tail
     ("HybridKDAMoE", "prefill"): "5dc53e652cdd1d41",
     ("HybridKDAMoE", "decode_step"): "8e185abd9caf302c",
+    # PR 53 gave the latent prefills' flash forward blocks of 1024 x 1024
+    # (`models.latent.PREFILL_BLOCKS`): over these configs' 128 tokens the
+    # call cuts them to the 128 x 128 it had, so the three latent classes'
+    # prefills keep their text and all fourteen their hashes
 }
 
 # a class's configuration for its pin: small, and of head sizes that tile

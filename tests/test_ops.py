@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.latent import PREFILL_BLOCKS
 from ray_tpu.ops import (apply_rope, flash_attention, layer_norm,
                          mha_reference, ring_attention, rms_norm,
                          softmax_cross_entropy)
@@ -207,6 +208,36 @@ def test_flash_non_multiple_seq_fwd_bwd():
     for a, b_ in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=1e-3, rtol=1e-3)
+
+
+# the latent classes' prefills (`models/latent.py`): keys wider than values,
+# at the blocks they give the forward and, for a bucket under them, the
+# whole bucket a block
+LATENT_WIDTHS = [(192, 128), (256, 256)]
+LATENT_BLOCKS = [PREFILL_BLOCKS, (512, 512), (256, 256)]
+
+
+@pytest.mark.parametrize("d,dv", LATENT_WIDTHS)
+@pytest.mark.parametrize("blocks", LATENT_BLOCKS,
+                         ids=lambda b: f"{b[0]}x{b[1]}")
+@pytest.mark.parametrize("fit", ["shorter", "equal", "tail"])
+def test_flash_forward_at_the_latent_prefills_blocks(fit, blocks, d, dv):
+    """A sequence shorter than a block (the call cuts the blocks to it),
+    as long as the larger one, and no multiple of either (the tail key
+    block's mask, the tail query block's rows)."""
+    block_q, block_k = blocks
+    s = {"shorter": min(blocks) // 2, "equal": max(blocks),
+         "tail": max(blocks) + min(blocks) // 2 + 40}[fit]
+    ks = jax.random.split(jax.random.PRNGKey(s + d), 3)
+    q = jax.random.normal(ks[0], (1, 2, s, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 1, s, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 1, s, dv), jnp.float32)
+    got = flash_attention_kernel(q, k, v, causal=True, block_q=block_q,
+                                 block_k=block_k)
+    assert got.shape == (1, 2, s, dv)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(mha_reference(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
 
 
 def test_flash_return_lse_differentiable():

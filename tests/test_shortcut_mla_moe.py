@@ -26,6 +26,7 @@ from benchmarks.harness.weights import make_weights          # noqa: E402
 from ray_tpu.models import (ShortcutMLAMoE, ShortcutMLAMoEConfig,  # noqa: E402
                             build_model, model_config)
 from ray_tpu.models import latent                            # noqa: E402
+from ray_tpu.models.gqa import FULL_BLOCKS                   # noqa: E402
 from ray_tpu.models.moe import (STEP_COUNTS,                 # noqa: E402
                                 dropless_moe_ffn, route_topk)
 from ray_tpu.models.shortcut_mla_moe import (                # noqa: E402
@@ -34,7 +35,7 @@ from ray_tpu.ops import attention as attn                    # noqa: E402
 from ray_tpu.ops import grouped_matmul as gmm                # noqa: E402
 from ray_tpu.ops import paged_attention as paged             # noqa: E402
 from ray_tpu.serve.llm import spans as sp                    # noqa: E402
-from ray_tpu.serve.llm.engine import EngineCore              # noqa: E402
+from ray_tpu.serve.llm.engine import EngineCore, _bucket     # noqa: E402
 from test_llm_tracing import recorder                        # noqa: E402,F401
 
 CONFIG = "longcat-flash-chat-1chip"
@@ -388,6 +389,20 @@ def test_flash_forward_takes_values_narrower_than_keys(s, dk, dv, kvh):
     assert got.shape == (1, 4, s, dv)
     want = attn.mha_reference(q, k, v, causal=True, sm_scale=scale)
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+@pytest.mark.parametrize("bucket", [1 << n for n in range(4, 15)])
+def test_prefill_blocks_tile_every_bucket(bucket):
+    """For every bucket `_bucket` makes of a prompt up to 16,384: the
+    latent prefills' blocks, cut to the bucket as the call cuts them, are
+    whole (8, 128) tiles or the bucket itself and divide it (no tail
+    block); none larger than the GQA classes', the largest swept."""
+    assert _bucket(bucket) == bucket == _bucket(bucket // 2 + 1)
+    assert max(latent.PREFILL_BLOCKS) <= max(FULL_BLOCKS)
+    for block in latent.PREFILL_BLOCKS:
+        block = min(block, bucket)
+        assert block % 8 == 0 and bucket % block == 0
+        assert block % 128 == 0 or block == bucket
 
 
 def test_flash_backward_refuses_unlike_widths():

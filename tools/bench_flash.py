@@ -1,0 +1,169 @@
+"""The flash forward on the chip, alone: `ops.attention.flash_attention`,
+causal, batch 1, bfloat16, over (heads, key width, value width, bucket,
+block_q, block_k), 8 calls (layers) a program, each one's queries hanging
+on the one before. Prints milliseconds a call, microseconds a grid step
+and the share of the chip's 197 TFLOP/s that the causal half's operations
+(QK^T at the keys' width and PV at the values', `s^2 / 2` pairs a head)
+make of it; a pair of blocks the compiler refuses (more fast memory than a
+kernel may use) is said so and left out. Chip only:
+
+    chiprun -- python tools/bench_flash.py --latent
+    chiprun -- python tools/bench_flash.py --heads 48 --d 128 --dv 128 \\
+        --buckets 1024,8192 --blocks 128x128,512x512,1024x1024
+
+`--latent` runs the shapes at which the three latent-attention classes
+call the kernel in a prefill (`models/latent.py`: GLM-4.7-Flash 20 heads
+of 256 / 256, LongCat-Flash 64 of 192 / 128, Ling-3.0-flash 32 of 192 /
+128) over the candidate blocks and buckets 256 to 16,384, and then prints
+the table `models.latent.PREFILL_BLOCKS` was chosen from beside it: a
+bucket and widths, the fastest pair, or the smaller program where two are
+within 3 % (PERF.md section 6, PR 53). The flash backward
+(ROADMAP S5b) has never been swept: this file is where that starts.
+"""
+import argparse
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from benchmarks.harness.peaks import PEAKS
+from ray_tpu.models.latent import PREFILL_BLOCKS
+from ray_tpu.ops.attention import flash_attention
+
+LAYERS, PEAK = 8, PEAKS["TPU v5 lite"]["bf16_flops"]
+# the latent classes' prefills: (heads, key width, value width)
+LATENT = {"glm-4.7-flash": (20, 256, 256),
+          "longcat-flash": (64, 192, 128),
+          "ling-3.0-flash": (32, 192, 128)}
+BUCKETS = (256, 512, 1024, 2048, 4096, 8192, 16384)
+BLOCKS = ((128, 128), (256, 256), (256, 512), (512, 512), (512, 1024),
+          (1024, 512), (1024, 1024), (512, 2048), (1024, 2048))
+WITHIN = 0.03       # two pairs this close: the smaller program
+
+
+def program(block_q, block_k):
+    """`LAYERS` calls, the first rows of each one's queries moved by the
+    output before (an update in place: nothing beside the kernel that a
+    bucket's size would show in)."""
+    def run(q, k, v):
+        for _ in range(LAYERS):
+            out = flash_attention(q, k, v, causal=True, block_q=block_q,
+                                  block_k=block_k)
+            q = q.at[:, :, :8, :v.shape[-1]].add(out[:, :, :8] * 1e-3)
+        return q
+    return jax.jit(run)
+
+
+def timed(fn, *args):
+    """Milliseconds a call: the least of three batches of programs, each
+    long enough (0.05 s or five programs) to be read on the host's
+    clock."""
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    jax.block_until_ready(fn(*args))
+    n = max(5, int(0.05 / max(time.perf_counter() - t, 1e-5)))
+    best = float("inf")
+    for _ in range(3):
+        t = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        best = min(best, (time.perf_counter() - t) / n)
+    return best * 1e3 / LAYERS
+
+
+def sweep(heads, d, dv, buckets, blocks):
+    """{bucket: {(block_q, block_k): ms a call}} of one shape, a line a
+    reading; blocks larger than the bucket are the bucket's own (as the
+    call cuts them) and read once."""
+    key = jax.random.PRNGKey(0)
+    out = {}
+    for s in buckets:
+        q, k = (jax.random.normal(kk, (1, heads, s, d), jnp.bfloat16)
+                for kk in jax.random.split(key))
+        v = k[..., :dv]
+        flops = 2.0 * (s * s / 2.0) * (d + dv) * heads
+        out[s] = {}
+        for bq, bk in blocks:
+            cut = (min(bq, s), min(bk, s))
+            if cut in out[s]:
+                continue
+            steps = heads * -(-s // cut[0]) * -(-s // cut[1])
+            try:
+                ms = timed(program(*cut), q, k, v)
+            except Exception as e:      # say so and go on
+                print(f"{heads} heads of {d} / {dv}, bucket {s}, blocks "
+                      f"{cut[0]} x {cut[1]}: does not compile: "
+                      f"{' '.join(str(e).split())[:200]}", flush=True)
+                continue
+            out[s][cut] = ms
+            print(f"{heads} heads of {d} / {dv}, bucket {s:5d}, blocks "
+                  f"{cut[0]:4d} x {cut[1]:4d}: {ms:8.4f} ms a call, "
+                  f"{ms * 1e3 / steps:6.3f} us a grid step of {steps:6d}, "
+                  f"{100 * flops / PEAK / (ms * 1e-3):5.1f} % of the peak",
+                  flush=True)
+    return out
+
+
+def choice(readings):
+    """The fastest pair of blocks, or the smallest (by the keys and
+    queries a step holds) of those within `WITHIN` of it."""
+    best = min(readings.values())
+    near = [b for b, ms in readings.items() if ms <= best * (1 + WITHIN)]
+    return min(near, key=lambda b: (b[0] * b[1], b[0]))
+
+
+def pairs(text):
+    return tuple(tuple(int(n) for n in p.split("x"))
+                 for p in text.split(","))
+
+
+def cell(readings, blocks):
+    ms = readings.get(blocks)
+    return (f"{blocks[0]} x {blocks[1]} "
+            f"({'not read' if ms is None else f'{ms:.4f}'})")
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--latent", action="store_true",
+                    help="the three latent classes' shapes, and the table")
+    ap.add_argument("--heads", type=int, default=20)
+    ap.add_argument("--d", type=int, default=256, help="width of a key")
+    ap.add_argument("--dv", type=int, default=256, help="width of a value")
+    ap.add_argument("--buckets", default=",".join(map(str, BUCKETS)))
+    ap.add_argument("--blocks", default=",".join(
+        f"{q}x{k}" for q, k in BLOCKS), help="block_q x block_k, ...")
+    opts = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        raise SystemExit("needs a TPU")
+    print(f"device {jax.devices()[0].device_kind}; causal, batch 1, "
+          f"bfloat16, {LAYERS} calls a program")
+    buckets = [int(b) for b in opts.buckets.split(",")]
+    shapes = LATENT if opts.latent else {
+        "as asked": (opts.heads, opts.d, opts.dv)}
+    table = {name: sweep(*shape, buckets, pairs(opts.blocks))
+             for name, shape in shapes.items()}
+    if not opts.latent:
+        return
+    print("\nbucket: fastest (ms), chosen (ms), PREFILL_BLOCKS cut to the "
+          "bucket (ms), 128 x 128 (ms)")
+    for name, (heads, d, dv) in LATENT.items():
+        for s, readings in table[name].items():
+            if not readings:
+                continue
+            pick = choice(readings)
+            held = tuple(min(b, s) for b in PREFILL_BLOCKS)
+            print(f"{name} ({heads} heads of {d} / {dv}) {s:5d}: "
+                  f"{cell(readings, min(readings, key=readings.get))}, "
+                  f"{cell(readings, pick)}, {cell(readings, held)}"
+                  f"{'' if held == pick else ' [differs]'}, "
+                  f"{cell(readings, (min(128, s),) * 2)}")
+
+
+if __name__ == "__main__":
+    main()

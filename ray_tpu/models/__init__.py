@@ -15,7 +15,8 @@ other classes serve on one device and hold their layers one by one, named
 layer by layer by their configs; each module's docstring has its
 mathematics. What they share is written once: the skeleton and the paged
 cache's addresses in `paged.py`, paged grouped-query attention in `gqa.py`,
-latent attention in `latent.py`, the routed feed-forwards in `moe.py`.
+latent attention in `latent.py`, the state-space mixer in `ssm.py`, the
+routed feed-forwards in `moe.py`.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
@@ -33,6 +34,8 @@ from ray_tpu.models.hybrid_ssm_moe import (  # noqa: F401,E402
     HybridSSMMoE, HybridSSMMoEConfig)
 from ray_tpu.models.hybrid_kda_moe import (  # noqa: F401,E402
     HybridKDAMoE, HybridKDAMoEConfig)
+from ray_tpu.models.parallel_hybrid import (  # noqa: F401,E402
+    ParallelHybrid, ParallelHybridConfig)
 
 
 # name -> (config class, model class). A dict of config fields names its
@@ -43,7 +46,8 @@ MODELS = {"transformer": (TransformerConfig, Transformer),
           "hybrid_delta": (HybridDeltaConfig, HybridDelta),
           "shortcut_mla_moe": (ShortcutMLAMoEConfig, ShortcutMLAMoE),
           "hybrid_ssm_moe": (HybridSSMMoEConfig, HybridSSMMoE),
-          "hybrid_kda_moe": (HybridKDAMoEConfig, HybridKDAMoE)}
+          "hybrid_kda_moe": (HybridKDAMoEConfig, HybridKDAMoE),
+          "parallel_hybrid": (ParallelHybridConfig, ParallelHybrid)}
 
 
 def model_config(model):

@@ -8,19 +8,10 @@ on and behind the same serving engine.
 Every layer is a pre-norm block, `x' = x + Mixer(N(x))`, `N` an RMSNorm of
 its own; there is no second sub-layer. A final norm before the untied head.
 
-`M`, a **state-space** mixer (`ops.ssd`), u its normed input, H heads of
-width P in G groups, a state of N numbers a channel:
-
-    [z | xBC | dt] = u W_in
-    xBC = SiLU(causal depthwise conv of width 4, with bias, over xBC)
-    [x | B | C] = xBC               (x: H heads of P; B, C: G groups of N)
-    dt = softplus(dt + dt_bias);  a_t = exp(-exp(A_log) dt_t)   (float32)
-    h_t = a_t h_{t-1} + dt_t x_t B_t^T;   y_t = h_t C_t + D x_t
-    out = (RMSNorm_group(y * SiLU(z))) W_out    (the norm a group's channels)
-
-`A_log`, `dt_bias` and `D` are held as offsets from the config's
-`a_log_init`, `dt_bias_init` and `d_init`, as a norm's scale is held as an
-offset from 1.
+`M`, a **state-space** mixer (`models/ssm.py` has its mathematics and its
+two pools; `ops.ssd` the scan): H heads of width P in G groups, a state of
+N numbers a channel, no scale on `W_in`'s columns, the gate before the
+norm.
 
 `*`, **attention**: grouped-query softmax attention, no bias and no rotary
 embedding (position comes from the state-space layers).
@@ -42,13 +33,11 @@ chips, without the exchange.
 **Two kinds of cache behind one page table**, as `HybridDelta` holds them:
 pools `"k"`, `"v"` `(attention layers, num_pages, page, kv heads x head
 dim)` for the attention layers alone; for the state-space layers `"state"`
-`(M layers, slots + 1, N, H x P)` float32 and `"tail"` (the convolution's
-last `width - 1` inputs, `(M layers, slots + 1, *tail_shape)`), a
-sequence's at the slot its first table entry names (`paged.StateSlots`).
-`prefill` scans a prompt from a zero state (`ssd_prefill`: the chunk
-kernel, which stops at the prompt's true length inside its bucket) and
-writes the slot whole; `decode_step` updates the slots of active lanes in
-place (`conv_tail_step`, then `ssd_step`). Beside them
+and `"tail"` (`models.ssm.SSMMixer`), a sequence's at the slot its first
+table entry names (`paged.StateSlots`). A decode step's recurrence is the
+step kernel where its blocks tile the state (`ops.ssd.step_columns`: whole
+groups side by side where a group fits a block, two of this family's eight
+a grid step; part of one group where it does not). Beside them
 `paged.ExpertCounts`' two entries.
 """
 from __future__ import annotations
@@ -67,18 +56,16 @@ from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
                                   StateSlots, decode_lanes,
                                   decode_state_slots,
                                   prefill_page_ids, prefill_state_slot)
-from ray_tpu.ops import gated_delta as _gd
+from ray_tpu.models.ssm import SSMDims, SSMMixer
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops import ssd as _ssd
-from ray_tpu.ops.gated_delta import causal_conv
-from ray_tpu.ops.norms import rms_norm_reference
 
 # a layer's kind, by the letters of the family's `hybrid_override_pattern`
 SSM, EXPERTS, ATTENTION = "M", "E", "*"
 
 
 @dataclasses.dataclass(frozen=True)
-class HybridSSMMoEConfig(ConfigDtypes):
+class HybridSSMMoEConfig(SSMDims, ConfigDtypes):
     """Fields under the published keys' meanings (`config.json` of
     `nemotron_h`); `layer_types` the pattern, one letter a layer;
     `n_routed_experts` the experts of the whole layer and `experts_held`
@@ -140,18 +127,6 @@ class HybridSSMMoEConfig(ConfigDtypes):
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
 
-    @property
-    def ssm_inner(self) -> int:             # the mixer's width, H x P
-        return self.ssm_heads * self.ssm_head_dim
-
-    @property
-    def bc_dim(self) -> int:                # B's (and C's) width, G x N
-        return self.ssm_groups * self.ssm_state
-
-    @property
-    def conv_channels(self) -> int:
-        return self.ssm_inner + 2 * self.bc_dim
-
 
 def tiny_hybrid_ssm_moe(vocab_size: int = 256,
                         experts_held=(4, 4)) -> HybridSSMMoEConfig:
@@ -169,7 +144,7 @@ def tiny_hybrid_ssm_moe(vocab_size: int = 256,
         param_dtype="float32")
 
 
-class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
+class HybridSSMMoE(SSMMixer, StateSlots, ExpertCounts, PagedDecoder):
     """Functional model bundle for one HybridSSMMoEConfig: `init`, `apply`
     / `loss` (the plain chunked scan, differentiated by JAX), and what a
     serving engine asks a model for (`models.paged.PagedDecoder`)."""
@@ -203,74 +178,9 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
                     "shared_up": ((e, c.shared_intermediate_size), std),
                     "shared_down": ((c.shared_intermediate_size, e),
                                     out_std)}
-        H = c.ssm_heads
-        return {"norm": ((e,), 0.0),
-                "w_in": ((e, c.ssm_inner + c.conv_channels + H), std),
-                "conv": ((c.conv_width, c.conv_channels), std),
-                "conv_bias": ((c.conv_channels,), 0.0),
-                "a_log": ((H,), 0.0), "dt_bias": ((H,), 0.0),
-                "d": ((H,), 0.0), "gate_norm": ((c.ssm_inner,), 0.0),
-                "w_out": ((c.ssm_inner, e), out_std)}
+        return {"norm": ((e,), 0.0), **self.ssm_shapes(std, out_std)}
 
     # --------------------------------------------------------- pieces
-    def _ssm_project(self, layer: Params, u):
-        """u (n, e) -> (z (n, H x P), xBC (n, channels) before the
-        convolution, dt (n, H) before the softplus)."""
-        c = self.config
-        proj = u @ layer["w_in"].astype(c.activation_dtype)
-        return jnp.split(proj, [c.ssm_inner, c.ssm_inner + c.conv_channels],
-                         axis=-1)
-
-    def _ssm_inputs(self, layer: Params, mixed, dt):
-        """What the scan takes: x (n, H x P), B, C (n, G x N) of the
-        convolved channels `mixed`, dt (n, H) and A (H,) float32."""
-        c = self.config
-        f32 = jnp.float32           # the offsets are added in float32
-        x, Bm, Cm = jnp.split(mixed, [c.ssm_inner, c.ssm_inner + c.bc_dim],
-                              axis=-1)
-        dt = jax.nn.softplus(dt.astype(f32) + c.dt_bias_init
-                             + layer["dt_bias"].astype(f32))
-        return x, Bm, Cm, dt, jnp.exp(c.a_log_init
-                                      + layer["a_log"].astype(f32))
-
-    def _ssm_out(self, layer: Params, y, x, z):
-        """The scan's y (n, H x P): the skip `D x` added, gated by SiLU(z),
-        normed a group's channels, through W_out; float32 up to the
-        matmul."""
-        c = self.config
-        f32 = jnp.float32
-        n, G = y.shape[0], c.ssm_groups
-        D = jnp.repeat(c.d_init + layer["d"].astype(f32), c.ssm_head_dim)
-        y = (y.astype(f32) + D * x.astype(f32)) * jax.nn.silu(z.astype(f32))
-        y = rms_norm_reference(y.reshape(n, G, -1),
-                               layer["gate_norm"].reshape(G, -1), c.norm_eps)
-        ad = c.activation_dtype
-        return y.reshape(n, -1).astype(ad) @ layer["w_out"].astype(ad)
-
-    def _ssm_seq(self, layer: Params, u, true_len=None):
-        """A state-space mixer over one sequence u (s, e), normed. With a
-        `true_len` (a prefill's padded bucket) through `ssd_prefill`, the
-        kernel where there is one; without, through the plain chunked
-        form, which JAX differentiates. Returns (the output after W_out
-        (s, e), the state at the sequence's end (N, H x P) float32, the
-        convolution's tail)."""
-        c = self.config
-        s = u.shape[0]
-        z, xbc, dt = self._ssm_project(layer, u)
-        mixed, tail = causal_conv(xbc, layer["conv"], true_len,
-                                  layer["conv_bias"])
-        x, Bm, Cm, dt, A = self._ssm_inputs(layer, mixed, dt)
-        pad = -s % c.chunk                  # whole chunks; padding is inert
-        xp, Bp, Cp, dtp = (jnp.pad(a, ((0, pad), (0, 0)))
-                           for a in (x, Bm, Cm, dt))
-        if true_len is None:
-            y, state = _ssd.ssd_chunked(xp, Bp, Cp, dtp, A, c.ssm_groups,
-                                        chunk=c.chunk)
-        else:
-            y, state = _ssd.ssd_prefill(xp, Bp, Cp, dtp, A, true_len,
-                                        c.ssm_groups, c.chunk)
-        return self._ssm_out(layer, y[:s], x, z), state, tail
-
     def _attn_seq(self, layer: Params, u):
         """Causal attention over whole sequences u (b, s, e). Returns (the
         output after W_o, k, v (b, s, kv heads, hd))."""
@@ -318,14 +228,8 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
     # ------------------------------------------------ what an engine asks
     def state_bytes(self, dtype=None) -> int:
         """Bytes the state-space layers keep of one sequence, whatever its
-        length: a float32 state and the convolution's tail a layer, as
-        the pools hold them (`tail_shape`: whole tiles of rows)."""
-        c = self.config
-        dt = jnp.dtype(dtype or c.activation_dtype)
-        return len(c.of_kind(SSM)) * (
-            c.ssm_state * c.ssm_inner * 4
-            + math.prod(_gd.tail_shape(c.conv_width, c.conv_channels))
-            * dt.itemsize)
+        length."""
+        return len(self.config.of_kind(SSM)) * self.ssm_layer_bytes(dtype)
 
     def init_cache(self, num_pages: int, page_size: int, dtype=None,
                    fixed_pages: int = 0) -> Cache:
@@ -335,13 +239,9 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
         c = self.config
         dt = dtype or c.activation_dtype
         kv = (len(c.of_kind(ATTENTION)), num_pages, page_size, c.kv_dim)
-        ssm, slots = len(c.of_kind(SSM)), fixed_pages + 1
         make = jax.jit(lambda: {
             "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
-            "state": jnp.zeros((ssm, slots, c.ssm_state, c.ssm_inner),
-                               jnp.float32),
-            "tail": jnp.zeros((ssm, slots) + _gd.tail_shape(
-                c.conv_width, c.conv_channels), dt),
+            **self.ssm_pools(len(c.of_kind(SSM)), fixed_pages + 1, dt),
             **self._zero_counts()})
         return make()
 
@@ -359,13 +259,10 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
     def decode_attention(self, page_size: int, dtype=None) -> str:
         """The kernel of each layer kind, or "einsum"."""
         c = self.config
-        step = (_ssd.KERNEL_STEP if _ssd.uses_step_kernel(
-            c.ssm_inner, c.ssm_inner // c.ssm_groups, c.ssm_state)
-            else "ssd_gather")
         return gqa.decode_kernels(
             c.head_dim, page_size, dtype or c.activation_dtype,
             [(_paged.KERNEL_PAGED_DECODE, c.of_kind(ATTENTION)),
-             (step, c.of_kind(SSM))])
+             (self.ssm_step_name(), c.of_kind(SSM))])
 
     def walk_block_pages(self, page_size: int, max_pages: int) -> int:
         """Of the attention layers' walk."""
@@ -445,15 +342,8 @@ class HybridSSMMoE(StateSlots, ExpertCounts, PagedDecoder):
                 sums = self._count_step(sums, counts)
             else:
                 li = c.of_kind(SSM).index(i)
-                z, xbc, dt = self._ssm_project(layer, u)
-                conv, pools["tail"] = _gd.conv_tail_step(
-                    xbc, layer["conv"], pools["tail"], li, slot,
-                    layer["conv_bias"])
-                xs, Bm, Cm, dt, A = self._ssm_inputs(layer, conv, dt)
-                y, pools["state"] = _ssd.ssd_step(
-                    xs, Bm, Cm, dt, A, pools["state"], li, slot,
-                    c.ssm_groups)
-                mixed = self._ssm_out(layer, y, xs, z)
+                mixed, written = self._ssm_step(layer, u, pools, li, slot)
+                pools.update(written)
             x = x + mixed
         return self._logits(params, x), {**pools,
                                          **self._counted(load, sums)}

@@ -1,0 +1,324 @@
+"""A decoder whose every layer runs two mixers side by side on one normed
+input and sums them: a state-space mixer (a selective scan: a recurrent
+state of fixed size a sequence) and grouped-query attention (pages that
+grow with it), then a SwiGLU feed-forward; every projection's input or
+output is scaled by a published scalar (the `falcon_h1` family's
+maximal-update multipliers), on the ops the other classes run on and behind
+the same serving engine.
+
+With `N_1`, `N_2`, `N_f` RMSNorms and the twelve multipliers as the config
+names them:
+
+    x_0 = embedding_multiplier * E[token]
+    h = N_1(x)
+    x = x + ssm_out_multiplier * SSM(ssm_in_multiplier * h)
+          + attention_out_multiplier * Attn(attention_in_multiplier * h)
+    x = x + MLP(N_2(x))
+    logits = lm_head_multiplier * (N_f(x) W_head)
+
+`SSM(u)` is `models.ssm.SSMMixer`'s, `W_in`'s product multiplied column by
+column by `m`: `ssm_multipliers[0..4]` over the z, x, B, C and dt columns;
+the gated norm in the order `mamba_norm_before_gate` says.
+
+`Attn(u)`: `q = u W_q`, `k = key_multiplier * (u W_k)`, `v = u W_v`; q and
+k rotated over the whole head (`rope_theta`), the key scaled before it is
+rotated and written to its page; causal softmax of `q . k / sqrt(head
+dim)`; `W_o`. No bias.
+
+`MLP(h) = (SiLU(mlp_multipliers[0] * (h W_gate)) * (h W_up)) W_down *
+mlp_multipliers[1]`.
+
+The multipliers are applied where the published forward applies them, in
+the activations' dtype (the head's on the float32 logits); none is folded
+into a weight.
+
+**Both kinds of cache in every layer, behind one page table**: pools `"k"`,
+`"v"` `(layers, num_pages, page, kv heads x head dim)` (`models/gqa.py`)
+and `"state"`, `"tail"` `(layers, slots + 1, ...)` (`models.ssm.SSMMixer`),
+all four over all the layers and under the same layer index. A sequence's
+first table entry is a page of the allocator's fixed class
+(`paged.StateSlots`): it names the slot of its states and is, like every
+later entry, a page of its keys and values. `prefill` runs the flash
+forward and the chunked scan on the same `h` and writes pages and slot in
+the one layer; `decode_step` runs `gqa.decode_attend` and `_ssm_step` on
+the same `h`. The two do not depend on each other: the order they are
+issued in (attention, then the scan) is no order the compiler must keep.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import gqa
+from ray_tpu.models.config import ConfigDtypes
+from ray_tpu.models.paged import (Cache, PagedDecoder, Params, StateSlots,
+                                  decode_lanes, decode_state_slots,
+                                  prefill_page_ids, prefill_state_slot)
+from ray_tpu.models.ssm import SSMDims, SSMMixer
+from ray_tpu.ops import paged_attention as _paged
+from ray_tpu.ops import rope as _rope
+from ray_tpu.ops import ssd as _ssd
+
+
+@dataclasses.dataclass(frozen=True)
+class ParallelHybridConfig(SSMDims, ConfigDtypes):
+    """Fields under the published keys' meanings (`config.json` of
+    `falcon_h1`); the twelve multipliers under the published names."""
+    vocab_size: int = 261120
+    d_model: int = 5120                     # hidden_size
+    n_layers: int = 72                      # num_hidden_layers
+    n_heads: int = 20                       # num_attention_heads
+    n_kv_heads: int = 4                     # num_key_value_heads
+    head_dim: int = 128
+    rope_theta: float = 1e11
+    ssm_heads: int = 32                     # mamba_n_heads
+    ssm_head_dim: int = 128                 # mamba_d_head
+    ssm_groups: int = 2                     # mamba_n_groups
+    ssm_state: int = 256                    # mamba_d_state
+    conv_width: int = 4                     # mamba_d_conv
+    chunk: int = _ssd.CHUNK                 # mamba_chunk_size
+    mamba_norm_before_gate: bool = False
+    a_log_init: float = 0.0
+    dt_bias_init: float = 0.0
+    d_init: float = 1.0
+    d_ff: int = 21504                       # intermediate_size
+    embedding_multiplier: float = 5.656854249492381
+    lm_head_multiplier: float = 0.0078125
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 0.0375
+    key_multiplier: float = 0.011048543456039804
+    ssm_in_multiplier: float = 0.25
+    ssm_out_multiplier: float = 0.08838834764831845
+    ssm_multipliers: Tuple[float, ...] = (     # on z, x, B, C, dt
+        0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+        0.3535533905932738)
+    mlp_multipliers: Tuple[float, float] = (   # gate's input, down's output
+        0.1767766952966369, 0.011160714285714284)
+    max_seq_len: int = 2560
+    norm_eps: float = 1e-5
+    dtype: str = "bfloat16"
+    param_dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        for name, n in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
+            values = tuple(float(v) for v in getattr(self, name))
+            if len(values) != n:
+                raise ValueError(f"{name} {values}: {n} scalars")
+            object.__setattr__(self, name, values)
+        if self.n_heads % self.n_kv_heads or (
+                self.ssm_heads % self.ssm_groups):
+            raise ValueError("kv heads must divide the heads, the groups "
+                             "the state-space heads")
+
+    @property
+    def kv_dim(self) -> int:
+        return self.n_kv_heads * self.head_dim
+
+
+def tiny_parallel_hybrid(vocab_size: int = 256) -> ParallelHybridConfig:
+    """CI/debug model: every mechanism at a size the CPU runs in seconds
+    (two layers, 10 query heads over 2 kv heads: a group of 5; 4
+    state-space heads in 2 groups, chunks of 8; every multiplier unlike 1
+    and unlike the others)."""
+    return ParallelHybridConfig(
+        vocab_size=vocab_size, d_model=64, n_layers=2, n_heads=10,
+        n_kv_heads=2, head_dim=16, rope_theta=1e4, ssm_heads=4,
+        ssm_head_dim=8, ssm_groups=2, ssm_state=16, chunk=8, d_ff=96,
+        embedding_multiplier=3.0, lm_head_multiplier=0.4,
+        attention_in_multiplier=1.5, attention_out_multiplier=0.7,
+        key_multiplier=0.3, ssm_in_multiplier=0.8, ssm_out_multiplier=1.3,
+        ssm_multipliers=(0.9, 1.2, 0.5, 1.6, 0.4),
+        mlp_multipliers=(1.4, 0.35), max_seq_len=256, dtype="float32",
+        param_dtype="float32")
+
+
+class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
+    """Functional model bundle for one ParallelHybridConfig: `init`,
+    `apply` / `loss` (the plain chunked scan, differentiated by JAX), and
+    what a serving engine asks a model for (`models.paged.PagedDecoder`)."""
+
+    no_mesh = ("neither the state pools nor a layer's two mixers are "
+               "sharded over chips yet")
+
+    # ------------------------------------------------------------ init
+    def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
+        """Every layer alike: attention, the state-space mixer, the
+        feed-forward and two norms (zeros are a norm's scale w, the layer
+        multiplying by 1 + w, and the mixer's offsets and bias)."""
+        c = self.config
+        e, q, f = c.d_model, c.n_heads * c.head_dim, c.d_ff
+        std = 0.02
+        out_std = std / math.sqrt(2 * c.n_layers)
+        return {"norm": ((e,), 0.0), "wq": ((e, q), std),
+                "wk": ((e, c.kv_dim), std), "wv": ((e, c.kv_dim), std),
+                "wo": ((q, e), out_std), **self.ssm_shapes(std, out_std),
+                "mlp_norm": ((e,), 0.0), "gate": ((e, f), std),
+                "up": ((e, f), std), "down": ((f, e), out_std)}
+
+    # --------------------------------------------------------- pieces
+    @property
+    def ssm_column_scale(self):
+        """`ssm_multipliers` spread over `W_in`'s columns [z | x | B | C |
+        dt], in the activations' dtype."""
+        c = self.config
+        widths = (c.ssm_inner, c.ssm_inner, c.bc_dim, c.bc_dim, c.ssm_heads)
+        return jnp.concatenate([
+            jnp.full((n,), m, c.activation_dtype)
+            for n, m in zip(widths, c.ssm_multipliers)])
+
+    @property
+    def ssm_norm_before_gate(self) -> bool:
+        return self.config.mamba_norm_before_gate
+
+    def _embed(self, params: Params, tokens):
+        c = self.config
+        return params["embed"].astype(c.activation_dtype)[
+            tokens] * c.embedding_multiplier
+
+    def _qkv(self, layer: Params, h, positions):
+        """h (..., e) normed, `positions` (...) -> q (..., heads, hd), k, v
+        (..., kv heads, hd): the key scaled, then q and k rotated."""
+        c = self.config
+        q, k, v = gqa.qkv(layer, h * c.attention_in_multiplier, c.n_heads,
+                          c.n_kv_heads, c.head_dim, c.activation_dtype)
+        cos, sin = _rope.rope_cos_sin(positions, c.head_dim, c.rope_theta)
+        return (_rope.apply_rope_cached(q, cos, sin),
+                _rope.apply_rope_cached(k * c.key_multiplier, cos, sin), v)
+
+    def _attn_seq(self, layer: Params, h):
+        """Causal attention over whole sequences h (b, s, e). Returns (the
+        scaled output after W_o, k, v (b, s, kv heads, hd))."""
+        c = self.config
+        q, k, v = self._qkv(layer, h, jnp.arange(h.shape[-2]))
+        out = gqa.attend_seq(q, k, v).reshape(*h.shape[:-1], -1)
+        return (out @ layer["wo"].astype(c.activation_dtype)
+                * c.attention_out_multiplier), k, v
+
+    def _ssm_in(self, h):
+        return h * self.config.ssm_in_multiplier
+
+    def _close(self, layer: Params, x, attn, ssm):
+        """The rest of a layer after its mixers: their sum added, then the
+        feed-forward on the second norm."""
+        c = self.config
+        ad = c.activation_dtype
+        x = x + ssm * c.ssm_out_multiplier + attn
+        h = self._norm(x, layer["mlp_norm"])
+        gate = jax.nn.silu(h @ layer["gate"].astype(ad)
+                           * c.mlp_multipliers[0])
+        return x + ((gate * (h @ layer["up"].astype(ad)))
+                    @ layer["down"].astype(ad)) * c.mlp_multipliers[1]
+
+    # --------------------------------------------------------- forward
+    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
+        """tokens (b, s) -> hidden states after the final norm."""
+        x = self._embed(params, tokens)
+        for layer in params["layers"]:
+            h = self._norm(x, layer["norm"])
+            attn = self._attn_seq(layer, h)[0]
+            ssm = jax.vmap(lambda seq: self._ssm_seq(layer, seq)[0])(
+                self._ssm_in(h))
+            x = self._close(layer, x, attn, ssm)
+        return self._norm(x, params["final_norm"])
+
+    def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
+        return super().apply(params, tokens) * self.config.lm_head_multiplier
+
+    def _logits(self, params: Params, x, true_len=None):
+        return super()._logits(params, x, true_len
+                               ) * self.config.lm_head_multiplier
+
+    # ------------------------------------------------ what an engine asks
+    def state_bytes(self, dtype=None) -> int:
+        """Bytes the mixers keep of one sequence, whatever its length."""
+        return self.config.n_layers * self.ssm_layer_bytes(dtype)
+
+    def init_cache(self, num_pages: int, page_size: int, dtype=None,
+                   fixed_pages: int = 0) -> Cache:
+        """`num_pages` pages of keys and values and `fixed_pages` state
+        slots (the allocator's fixed class, one a sequence) and one more,
+        nobody's, all in every layer."""
+        c = self.config
+        dt = dtype or c.activation_dtype
+        kv = (c.n_layers, num_pages, page_size, c.kv_dim)
+        make = jax.jit(lambda: {
+            "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
+            **self.ssm_pools(c.n_layers, fixed_pages + 1, dt)})
+        return make()
+
+    def page_bytes(self, page_size: int, tp_shards: int = 1,
+                   dtype=None) -> int:
+        """Keys and values of every layer."""
+        c = self.config
+        return c.n_layers * gqa.layer_page_bytes(
+            c.kv_dim, page_size, dtype or c.activation_dtype, tp_shards)
+
+    def decode_attention(self, page_size: int, dtype=None) -> str:
+        """A layer's two kernels, or "einsum"."""
+        c = self.config
+        return gqa.decode_kernels(
+            c.head_dim, page_size, dtype or c.activation_dtype,
+            [(_paged.KERNEL_PAGED_DECODE, True),
+             (self.ssm_step_name(), True)])
+
+    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
+        c = self.config
+        return gqa.walk_block_pages(c.kv_dim, page_size, max_pages,
+                                    c.activation_dtype)
+
+    def prefill(self, params: Params, tokens: jax.Array, true_len,
+                page_table: jax.Array, cache: Cache,
+                page_size: int) -> Tuple[jax.Array, Cache]:
+        """Every layer through the flash kernel, its keys and values
+        written as whole pages in place, and scanned from a zero state to
+        `true_len`, its state and tail written whole into the slot the
+        table's first entry names."""
+        pools = dict(cache)
+        num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
+        x = self._embed(params, tokens)                         # (s, e)
+        ids = prefill_page_ids(page_table, true_len, tokens.shape[0],
+                               num_pages, page_size)
+        slot = prefill_state_slot(page_table, slots)
+        for li, layer in enumerate(params["layers"]):
+            h = self._norm(x, layer["norm"])
+            attn, k, v = self._attn_seq(layer, h[None])
+            pools.update(gqa.write_prompt(pools, ("k", "v"), li, ids, k, v))
+            ssm, state, tail = self._ssm_seq(layer, self._ssm_in(h),
+                                             true_len)
+            pools.update(self._write_slot(pools, li, slot, state, tail))
+            x = self._close(layer, x, attn[0], ssm)
+        return self._logits(params, x, true_len), pools
+
+    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
+                    positions: jax.Array, page_tables: jax.Array,
+                    active: jax.Array,
+                    page_size: int) -> Tuple[jax.Array, Cache]:
+        """An inactive lane, or one whose table is unassigned, writes no
+        page, no state and no tail."""
+        c = self.config
+        ad = c.activation_dtype
+        pools = dict(cache)
+        num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
+        B = tokens.shape[0]
+        x = self._embed(params, tokens)                         # (B, e)
+        page, offset, lengths = decode_lanes(positions, page_tables, active,
+                                             num_pages, page_size)
+        slot = decode_state_slots(page_tables, active, slots)
+        for li, layer in enumerate(params["layers"]):
+            h = self._norm(x, layer["norm"])
+            q, k, v = self._qkv(layer, h, positions)
+            out, written = gqa.decode_attend(
+                pools, ("k", "v"), li, page, offset, q, k, v, page_tables,
+                lengths)
+            pools.update(written)
+            attn = (out.astype(ad).reshape(B, -1) @ layer["wo"].astype(ad)
+                    * c.attention_out_multiplier)
+            ssm, written = self._ssm_step(layer, self._ssm_in(h), pools, li,
+                                          slot)
+            pools.update(written)
+            x = self._close(layer, x, attn, ssm)
+        return self._logits(params, x), pools

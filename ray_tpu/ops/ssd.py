@@ -43,7 +43,11 @@ Decode advances one position a lane (`ssd_step`, the kernel;
 `ssd_step_reference`): the pool is aliased in and out, only the slots of
 active lanes are written, and the update is elementwise float32 under the
 copies: the kernel is bound by the bytes of the state, read and written
-once.
+once. A grid step takes a block of the state's columns (`step_columns`):
+whole groups side by side where a group fits `STEP_BLOCK_BYTES`, part of
+one group where it does not (16 heads of 128 over a state of 256 are 2
+MiB); a column's group is read off its place in the width, so a block
+needs no more of the groups than whole 128-lanes.
 """
 from __future__ import annotations
 
@@ -337,10 +341,11 @@ def ssd_prefill_kernel(x, Bm, Cm, dt, A, true_len, groups: int,
 def _step_kernel(layer_ref, slot_ref, bt_ref, ct_ref, u_ref, a_ref, s_ref,
                  o_ref, s_out_ref, *, group_cols: int):
     """Grid (lanes, column blocks of the state): the block (N, cols) of a
-    lane's state, `cols` whole groups side by side. B and C come
-    transposed (N, padded groups) and are spread over their groups'
-    columns by a 0/1 matrix; `u = dt x` and the decay come spread already
-    (1, cols)."""
+    lane's state, `cols` whole groups side by side or part of one. B and
+    C come transposed (N, padded groups) and are spread over the block's
+    columns by a 0/1 matrix that names each column's group by its place
+    in the width (`j * cols + column`, over `group_cols`); `u = dt x` and
+    the decay come spread already (1, cols)."""
     del layer_ref
     b, j = pl.program_id(0), pl.program_id(1)
     cols = s_ref.shape[-1]
@@ -366,12 +371,15 @@ def _step_kernel(layer_ref, slot_ref, bt_ref, ct_ref, u_ref, a_ref, s_ref,
 
 def step_columns(width: int, group_cols: int, state: int) -> int:
     """Columns of the state a grid step of the step kernel takes: whole
-    groups, whole 128-lanes, at most `STEP_BLOCK_BYTES` (0: these shapes
-    do not tile)."""
+    128-lanes, at most `STEP_BLOCK_BYTES`; whole groups side by side where
+    a group fits, else the largest part of one group that divides it (0:
+    these shapes do not tile)."""
     groups = width // group_cols
-    fits = [n * group_cols for n in range(1, groups + 1)
-            if groups % n == 0 and (n * group_cols) % LANES == 0
-            and n * group_cols * state * 4 <= STEP_BLOCK_BYTES]
+    whole = [n * group_cols for n in range(1, groups + 1) if groups % n == 0]
+    parts = [group_cols // n for n in range(2, group_cols // LANES + 1)
+             if group_cols % n == 0]
+    fits = [cols for cols in whole + parts if cols % LANES == 0
+            and cols * state * 4 <= STEP_BLOCK_BYTES]
     return max(fits, default=0)
 
 

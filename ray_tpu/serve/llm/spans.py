@@ -57,7 +57,8 @@ TABLES = "engine.page_tables"       # the decode batch's host arrays
 # `window_walk_blocks` (a layer that holds a sequence's last positions in
 # a ring), for layers that hold a recurrent state (linear attention, a
 # selective scan) `state_slots` / `state_bytes` (the lanes whose state the
-# step reads and writes, and the bytes moved for them)
+# step reads and writes, and the bytes moved for them); a layer that holds
+# pages and a state (two mixers side by side) counts under both
 DISPATCH = "engine.decode_dispatch"
 # waits for the tokens of the step before (and this call's prefills), with
 # the step just dispatched queued behind them on the device

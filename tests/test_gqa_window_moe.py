@@ -32,8 +32,8 @@ from benchmarks.harness.weights import make_weights          # noqa: E402
 from ray_tpu.models import (GQAWindowMoE, GQAWindowMoEConfig,  # noqa: E402
                             HybridDeltaConfig, HybridKDAMoEConfig,
                             HybridSSMMoEConfig, MLAMoE,
-                            ShortcutMLAMoEConfig, Transformer, build_model,
-                            model_config)
+                            ParallelHybridConfig, ShortcutMLAMoEConfig,
+                            Transformer, build_model, model_config)
 from ray_tpu.models.config import TransformerConfig          # noqa: E402
 from ray_tpu.models.gqa_window_moe import (RopeParams,       # noqa: E402
                                            tiny_gqa_window_moe)
@@ -598,7 +598,15 @@ PINNED = {
     # PR 53 gave the latent prefills' flash forward blocks of 1024 x 1024
     # (`models.latent.PREFILL_BLOCKS`): over these configs' 128 tokens the
     # call cuts them to the 128 x 128 it had, so the three latent classes'
-    # prefills keep their text and all fourteen their hashes
+    # prefills keep their text and all fourteen their hashes.
+    # PR 54 lifted the state-space mixer out of `HybridSSMMoE` into
+    # `models/ssm.py` (a second class runs it) and let `ops.ssd.step_columns`
+    # cut a group that is too wide for a block: `HybridSSMMoE`'s two keep
+    # their hashes, as do the twelve others. The eighth class, pinned to the
+    # text PR 54 gave it: five query heads a kv head, a scaled key rotated,
+    # both mixers' kernels in the one layer
+    ("ParallelHybrid", "prefill"): "212c76bf28ba5d17",
+    ("ParallelHybrid", "decode_step"): "e6130c468a958a73",
 }
 
 # a class's configuration for its pin: small, and of head sizes that tile
@@ -643,6 +651,10 @@ PINNED_CONFIGS = {
         moe_intermediate_size=128, shared_intermediate_size=128,
         n_routed_experts=8, experts_held=(2, 4), num_experts_per_tok=2,
         n_group=2, topk_group=1, max_seq_len=128),
+    "ParallelHybrid": lambda: ParallelHybridConfig(
+        vocab_size=256, d_model=128, n_layers=1, n_heads=5, n_kv_heads=1,
+        head_dim=128, ssm_heads=2, ssm_head_dim=128, ssm_groups=1,
+        ssm_state=128, chunk=128, d_ff=256, max_seq_len=128),
 }
 
 

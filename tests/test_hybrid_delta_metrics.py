@@ -192,7 +192,9 @@ def test_the_cell_is_listed_where_its_readers_find_something():
         if m["name"] in NEW:
             # the delta rule's readers are this cell's alone; the
             # prefill's reads any model that hands up `prefill_counts`
+            # (the cells of later PRs are appended behind them)
             shares = ([NEMOTRON_CELL, LING_CELL]
                       if m["name"] == "step.prefill_ms.answers3k" else [])
-            assert m["workloads"] == [CELL] + shares
+            assert m["workloads"][:1 + len(shares)] == [CELL] + shares
+            assert shares or m["workloads"] == [CELL]
             assert m["moves"] == "serve_tokens_per_s"

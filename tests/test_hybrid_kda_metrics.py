@@ -189,8 +189,9 @@ def test_new_metrics_leave_the_line_where_there_is_nothing_to_read(
 def test_the_cell_is_listed_where_its_readers_find_something():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == LING
+    # the ninth cell and the eighth configuration; later PRs append
+    assert bench["workloads"][8]["name"] == CELL
+    assert bench["configs"][7]["name"] == LING
     listed = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]
               if CELL in m.get("workloads", [CELL])}
     assert set(NEW) | set(SHARED) <= listed

@@ -581,12 +581,58 @@ def test_hybrid_kda_moe_programs_hold_their_kernels_and_alias_the_state(
         cache)
 
 
+# ------------------------------------- the eighth architecture's step
+def _compile_parallel_hybrid(devices, which: str, slots: int = 32,
+                             layers: int = 1):
+    """`ParallelHybrid`'s decode step or 4096-token prefill: `layers`
+    layers of both mixers at the published widths of Falcon-H1-34B (32
+    state-space heads of 128 in 2 groups and a state of 256 x 4096 a lane:
+    a group of 2,048 columns is 2 MiB, so a block of the step kernel is
+    half a group; 20 query heads over 4 kv heads of 128: a group of five
+    in the page walk; a SwiGLU of 21,504), `slots` state slots and
+    nobody's."""
+    from ray_tpu.models.parallel_hybrid import (ParallelHybrid,
+                                                ParallelHybridConfig)
+    model = ParallelHybrid(ParallelHybridConfig(
+        vocab_size=1024, n_layers=layers, max_seq_len=4096))
+    return _compile_served(
+        devices, model, which, lambda: model.init_cache(
+            PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
+        "paged_decode_attn+ssd_step")
+
+
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_parallel_hybrid_programs_hold_both_mixers_kernels_in_every_layer(
+        which, topo, no_compile_cache):
+    from ray_tpu.ops import ssd
+    compiled, cache = _compile_parallel_hybrid(topo.devices, which,
+                                               layers=2)
+    names = kernel_names(compiled.as_text())
+    if which == "step":         # one of each a layer
+        assert names.count(ssd.KERNEL_STEP) == 2
+        assert names.count(paged_attention.KERNEL_PAGED_DECODE) == 2
+        assert not {ssd.KERNEL_CHUNK, attention.KERNEL_FWD} & set(names)
+    else:
+        assert names.count(ssd.KERNEL_CHUNK) == 2
+        assert names.count(attention.KERNEL_FWD) == 2
+        assert not {ssd.KERNEL_STEP,
+                    paged_attention.KERNEL_PAGED_DECODE} & set(names)
+    # every pool is updated in place, all four under one layer index: the
+    # states (33 slots of 256 x 4096 float32 a layer), the tails, the keys
+    # and the values
+    assert cache["state"].shape == (2, 33, 256, 4096)
+    assert cache["k"].shape == cache["v"].shape == (2, PAGES, PAGE, 512)
+    assert compiled.memory_analysis().alias_size_in_bytes >= _held_bytes(
+        cache)
+
+
 # ------------------------- the recurrent classes' convolution in a step
 @pytest.mark.parametrize("compile_step,tail", [
     (_compile_hybrid_delta, (3, 96, 128)),      # 11,520 channels in 12,288
     (_compile_hybrid_ssm_moe, (3, 80, 128)),    # 10,240, under a bias
     (_compile_hybrid_kda_moe, (3, 96, 128)),    # 12,288
-], ids=["HybridDelta", "HybridSSMMoE", "HybridKDAMoE"])
+    (_compile_parallel_hybrid, (3, 48, 128)),   # 5,120 in 6,144, a bias
+], ids=["HybridDelta", "HybridSSMMoE", "HybridKDAMoE", "ParallelHybrid"])
 def test_a_step_scatters_the_tail_pool_once_in_place_as_it_lies(
         compile_step, tail, topo, no_compile_cache):
     """The step of each class that keeps a convolution's tail writes the

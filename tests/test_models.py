@@ -444,12 +444,14 @@ def _tiny_served():
     from ray_tpu.models.hybrid_kda_moe import tiny_hybrid_kda_moe
     from ray_tpu.models.hybrid_ssm_moe import tiny_hybrid_ssm_moe
     from ray_tpu.models.mla_moe import tiny_mla_moe
+    from ray_tpu.models.parallel_hybrid import tiny_parallel_hybrid
     from ray_tpu.models.shortcut_mla_moe import tiny_shortcut_mla_moe
     return {"MLAMoE": tiny_mla_moe, "GQAWindowMoE": tiny_gqa_window_moe,
             "HybridDelta": tiny_hybrid_delta,
             "ShortcutMLAMoE": tiny_shortcut_mla_moe,
             "HybridSSMMoE": tiny_hybrid_ssm_moe,
-            "HybridKDAMoE": tiny_hybrid_kda_moe}
+            "HybridKDAMoE": tiny_hybrid_kda_moe,
+            "ParallelHybrid": tiny_parallel_hybrid}
 
 
 def _tree_sha256(tree) -> str:
@@ -477,6 +479,8 @@ INIT_SHA256 = {
     "HybridSSMMoE": "90619eeef1dbbf44",
     # the seventh class, as PR 50 made it
     "HybridKDAMoE": "dc5c8e45cfc30e11",
+    # the eighth, as PR 54 made it
+    "ParallelHybrid": "e6602d2e0cf60bfe",
 }
 
 
@@ -501,7 +505,8 @@ def _tiny_models():
 
 @pytest.mark.parametrize("name", ["transformer", "mla_moe", "gqa_window_moe",
                                   "hybrid_delta", "shortcut_mla_moe",
-                                  "hybrid_ssm_moe", "hybrid_kda_moe"])
+                                  "hybrid_ssm_moe", "hybrid_kda_moe",
+                                  "parallel_hybrid"])
 def test_every_class_answers_the_engines_twelve_asks(name):
     """What `EngineCore` calls on a model, on every class of the table,
     with the types it uses them as (`models.paged.PagedDecoder`)."""

@@ -3,7 +3,8 @@ step kernel (through the Pallas interpreter) and their plain twins against
 the recurrence written position by position, at lengths that are and are
 not whole chunks, stopping at a true length inside a bucket, the state
 handed from a prefill to decode steps, the slots a step leaves alone, and
-the convolution with its bias. Tiny sizes, CPU, seeded.
+the convolution with its bias; and both kernels at a group of heads wider
+than a block of the step kernel. Tiny sizes, CPU, seeded.
 """
 import numpy as np
 import pytest
@@ -18,7 +19,7 @@ from ray_tpu.ops.dispatch import compute_platform
 H, G, P, N, C = 4, 2, 8, 16, 8
 
 
-def _case(s, seed=0, dtype=jnp.float32):
+def _case(s, seed=0, dtype=jnp.float32, H=H, P=P):
     """x (s, H x P), B, C (s, G x N), steps dt (s, H) as a softplus gives
     them and rates A (H,) that span an order of magnitude."""
     r = np.random.default_rng(seed)
@@ -172,3 +173,109 @@ def test_kernels_tile_the_published_shapes_and_say_where_they_run():
     with pytest.raises(ValueError, match="groups"):
         ssd.ssd_recurrence(*_case(4)[:3], jnp.ones((4, 3)), jnp.ones((3,)),
                            G)
+
+
+# ------------------------------ a group of heads wider than a step's block
+WIDE = dict(H=8, P=64)      # 2 groups of 256 columns; a block of 128
+
+
+@pytest.fixture
+def half_group_blocks(monkeypatch):
+    """A step's block holds 128 columns of a state of 16: half a group of
+    the `WIDE` case, two heads, as 1,024 of a group's 2,048 columns fit
+    1 MiB at a state of 256."""
+    monkeypatch.setattr(ssd, "STEP_BLOCK_BYTES", 128 * N * 4)
+    assert ssd.step_columns(512, 256, N) == 128
+    return 128
+
+
+def test_step_kernel_finds_its_group_in_a_block_that_is_part_of_one(
+        half_group_blocks):
+    """Four blocks a lane, the first two in group 0 and the last two in
+    group 1: every block's columns against the recurrence, so a block that
+    spread another group's B and C over its columns would show; nobody's
+    slot, an inactive lane's slot and the other layer are bit for bit what
+    they were."""
+    x, Bm, Cm, dt, A = _case(3, seed=7, **WIDE)
+    pool = np.random.default_rng(8).normal(size=(2, 5, N, 512)).astype(
+        np.float32)
+    slots = jnp.asarray([3, -1, 1], jnp.int32)
+    y, new = ssd.ssd_step_kernel(x, Bm, Cm, dt, A, jnp.asarray(pool), 0,
+                                 slots, G)
+    new = np.asarray(new)
+    for lane, slot in ((0, 3), (2, 1)):
+        want_y, want_s = ssd.ssd_recurrence(
+            x[lane][None], Bm[lane][None], Cm[lane][None], dt[lane][None],
+            A, G, state=jnp.asarray(pool[0, slot]))
+        for block in range(4):          # the first and the last among them
+            at = slice(block * half_group_blocks,
+                       (block + 1) * half_group_blocks)
+            np.testing.assert_allclose(y[lane, at], want_y[0, at],
+                                       atol=5e-6)
+            np.testing.assert_allclose(new[0, slot][:, at], want_s[:, at],
+                                       atol=5e-6)
+    assert not np.asarray(y[1]).any()
+    assert (new[1] == pool[1]).all()
+    assert (new[0, [0, 2, 4]] == pool[0, [0, 2, 4]]).all()
+    # B and C of the two groups differ, so the groups' columns do
+    swapped, _ = ssd.ssd_step_kernel(
+        x, jnp.roll(Bm, N, axis=1), jnp.roll(Cm, N, axis=1), dt, A,
+        jnp.asarray(pool), 0, slots, G)
+    assert np.abs(np.asarray(swapped[0] - y[0])).min() > 0
+
+
+@pytest.mark.parametrize("s,true_len", [(40, 40), (40, 21)])
+def test_both_kernels_at_a_wide_group_carry_a_prompt_into_its_steps(
+        half_group_blocks, s, true_len):
+    """The chunk kernel over a group of four heads of 64 (two blocks of
+    columns a group in its head loop) to `true_len`, its state into a
+    slot, then the step kernel at half a group a block: the recurrence
+    over all the positions."""
+    x, Bm, Cm, dt, A = _case(s + 4, seed=s + true_len, **WIDE)
+    want_y, want_s = ssd.ssd_recurrence(
+        *(jnp.concatenate([a[:true_len], a[s:]])
+          for a in (x, Bm, Cm, dt)), A, G)
+    y, state = ssd.ssd_prefill_kernel(x[:s], Bm[:s], Cm[:s], dt[:s], A,
+                                      true_len, G, C)
+    np.testing.assert_allclose(y[:true_len], want_y[:true_len], atol=5e-6)
+    pool = jnp.zeros((1, 3, N, 512), jnp.float32).at[0, 1].set(state)
+    for t in range(4):
+        yt, pool = ssd.ssd_step_kernel(
+            x[s + t][None], Bm[s + t][None], Cm[s + t][None],
+            dt[s + t][None], A, pool, 0, jnp.asarray([1], jnp.int32), G)
+        np.testing.assert_allclose(yt[0], want_y[true_len + t], atol=5e-6)
+    np.testing.assert_allclose(pool[0, 1], want_s, atol=5e-6)
+    assert not np.asarray(pool[0, [0, 2]]).any()
+
+
+def _whole_groups(width, group_cols, state):
+    """`step_columns` as it stood while a block was whole groups."""
+    groups = width // group_cols
+    return max((n * group_cols for n in range(1, groups + 1)
+                if groups % n == 0 and (n * group_cols) % ssd.LANES == 0
+                and n * group_cols * state * 4 <= ssd.STEP_BLOCK_BYTES),
+               default=0)
+
+
+@pytest.mark.parametrize("width,group_cols,state,cols", [
+    (8192, 1024, 128, 2048),    # two of eight groups a block, as before
+    (8192, 1024, 64, 4096),
+    (4096, 512, 128, 2048),
+    (2048, 2048, 128, 2048),    # one group, whole
+    (4096, 2048, 256, 1024),    # half a group: 8 heads of 128, 1 MiB
+    (4096, 2048, 512, 512),
+    (4096, 4096, 128, 2048),    # one group of two blocks
+    (256, 128, 16, 256),
+    (32, 16, 16, 0),            # no whole 128-lanes
+])
+def test_step_columns_keeps_whole_groups_and_cuts_a_group_that_is_too_wide(
+        width, group_cols, state, cols):
+    assert ssd.step_columns(width, group_cols, state) == cols
+    assert cols == 0 or (width % cols == 0 and cols % ssd.LANES == 0
+                         and cols * state * 4 <= ssd.STEP_BLOCK_BYTES)
+    before = _whole_groups(width, group_cols, state)
+    if before:          # where a block was whole groups it still is
+        assert cols == before
+    else:
+        assert cols == 0 or group_cols % cols == 0
+    assert ssd.step_tiles(width, group_cols, state) == bool(cols)

@@ -2,22 +2,22 @@
 
 The hot op of the model zoo. Forward is an online-softmax kernel that
 streams K/V blocks through VMEM on a (batch, head, q-block, k-block)
-grid — O(seq) memory, MXU-shaped matmuls, causal blocks above the
-diagonal skipped. Backward is two Pallas kernels sharing the flash
-recomputation: a dK/dV kernel on a (b, h, k-block, q-block) grid and a
-dQ kernel on (b, h, q-block, k-block), both computing scores in the
-TRANSPOSED (block_k, block_q) orientation so the per-row stats (lse,
-delta) broadcast along sublanes — the cheap direction — instead of
-needing lane-expanded copies; dQ is produced as (b, h, d, s) and
-transposed once by XLA. A blockwise lax.scan backward is kept as the
-cross-check/fallback path (`_flash_bwd_xla`).
+grid — O(seq) memory, causal blocks above the diagonal skipped. Backward
+is ONE kernel on a (b, h, k-block, q-block) grid that computes a block
+pair's scores once, TRANSPOSED (block_k, block_q) so the per-row stats
+(lse, delta) broadcast along sublanes, and takes all three gradients from
+them, five matmuls a pair: dK/dV are summed in VMEM over the inner axis,
+a head's whole dQ (float32) over both inner axes and written once, so no
+partial sum goes through HBM. A pair below the diagonal carries no
+mask; one the diagonal cuts is walked in squares of DIAG_BLOCK, those above
+it left out. `_flash_bwd_xla` (a blockwise lax.scan) is the cross-check.
 
 Layout: (batch, num_heads, seq, head_dim). GQA supported: K/V may have
 fewer heads (num_kv_heads must divide num_heads) — the kernel maps query
 head h to kv head h // (num_heads // num_kv_heads) in the BlockSpec
 index map, no materialised repeat. The forward takes values of another
 width than the keys (the output is as wide as the values); the backward
-kernels do not.
+kernel does not.
 
 The public `flash_attention` compiles the kernels whenever the target
 platform is a TPU (`ops.dispatch.on_tpu`), never the interpreter; off
@@ -59,7 +59,7 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # forward recomputed under remat is still KERNEL_FWD.
 KERNEL_FWD = "flash_fwd"
 KERNEL_BWD_DKDV = "flash_bwd_dkdv"
-KERNEL_BWD_DQ = "flash_bwd_dq"
+# (the whole backward, dQ too, since PR 55: the name is the benchmark's)
 KERNEL_SCOPE = "flash_attention"
 # the forward with a sliding window (forward only: serving's prefill), under
 # a name of its own that a reader looking for KERNEL_FWD does not match
@@ -306,134 +306,116 @@ def _flash_window_fwd(q, k, v, sm_scale, block_q, block_k, interpret,
 
 
 # ---------------------------------------------------- backward (pallas)
-def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-                           dk_ref, dv_ref, dk_acc, dv_acc, *,
-                           sm_scale: float, causal: bool,
-                           block_q: int, block_k: int, seq_q: int):
-    j = pl.program_id(2)           # k block (parallel)
-    i = pl.program_id(3)           # q block (inner scan)
-    nq = pl.num_programs(3)
+# A block pair that the causal mask cuts is walked in squares this wide, and
+# the squares above the diagonal are not computed (the sweep: PERF.md, PR 55).
+DIAG_BLOCK = 512
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
+                      dk_ref, dv_ref, dq_ref, dk_acc, dv_acc, dq_acc, *,
+                      sm_scale: float, causal: bool, block_q: int,
+                      block_k: int, seq_q: int, seq_k: int, diag: int):
+    j = pl.program_id(2)           # k block
+    i = pl.program_id(3)           # q block (inner)
+    nk, nq = pl.num_programs(2), pl.num_programs(3)
+
+    @pl.when((j == 0) & (i == 0))
+    def _init_dq():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
 
     @pl.when(i == 0)
-    def _init():
+    def _init_dkdv():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    # Causal: k block j only sees q blocks whose max q index reaches it.
-    run = (not causal) or (i * block_q + block_q - 1 >= j * block_k)
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        if seq_q % block_q:
+    def pair(kr: slice, qr: slice, masked: bool):
+        """The three gradients' terms of the keys `kr` and the queries
+        `qr` of this step's blocks; `masked`: the diagonal runs through
+        them."""
+        q, do = q_ref[0, 0, qr, :], do_ref[0, 0, qr, :]
+        k, v = k_ref[0, 0, kr, :], v_ref[0, 0, kr, :]
+        lse = lse_ref[0, 0, 0:1, qr]           # (1, queries) f32
+        dlt = dlt_ref[0, 0, 0:1, qr]
+        k0, q0 = j * block_k + kr.start, i * block_q + qr.start
+        tail_q, tail_k = seq_q % block_q, seq_k % block_k
+        valid = None
+        if masked or tail_q or tail_k:
+            shape = (k.shape[0], q.shape[0])
+            rows = k0 + lax.broadcasted_iota(jnp.int32, shape, 0)
+            cols = q0 + lax.broadcasted_iota(jnp.int32, shape, 1)
+            valid = (rows <= cols) if masked else True
+        if tail_q:
             # q/do padding rows hold garbage and are CONTRACTED into
             # dk/dv below — zero them (p=0 does not neutralise NaN).
-            qrows = i * block_q + lax.broadcasted_iota(
-                jnp.int32, q.shape, 0)
+            qrows = q0 + lax.broadcasted_iota(jnp.int32, q.shape, 0)
             q = jnp.where(qrows < seq_q, q, 0)
             do = jnp.where(qrows < seq_q, do, 0)
-        lse = lse_ref[0, 0, 0:1, :]            # (1, block_q) f32
-        dlt = dlt_ref[0, 0, 0:1, :]            # (1, block_q) f32
+            valid &= cols < seq_q
+        if tail_k:
+            # k padding rows are contracted into dq — zero the garbage.
+            krows = k0 + lax.broadcasted_iota(jnp.int32, k.shape, 0)
+            k = jnp.where(krows < seq_k, k, 0)
+            valid &= rows < seq_k
         # Transposed scores: rows = k positions, cols = q positions.
         st = jax.lax.dot_general(
             k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # (bk, bq)
-        rows = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_k, block_q), 0)
-        cols = i * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_k, block_q), 1)
-        valid = None
-        if causal:
-            valid = rows <= cols
-        if seq_q % block_q:
-            vq = cols < seq_q                  # q-tail: garbage columns
-            valid = vq if valid is None else (valid & vq)
-        pt = jnp.exp(st - lse)                 # (bk, bq)
-        if valid is not None:
-            pt = jnp.where(valid, pt, 0.0)
-        dv_acc[:] += jax.lax.dot_general(
-            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bk, d)
-        dpt = jax.lax.dot_general(
-            v, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bk, bq)
-        dst = pt * (dpt - dlt) * sm_scale
-        if valid is not None:                  # kill 0*inf NaNs from tails
-            dst = jnp.where(valid, dst, 0.0)
-        dk_acc[:] += jax.lax.dot_general(
-            dst.astype(q.dtype), q, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bk, d)
-
-    @pl.when(i == nq - 1)
-    def _final():
-        dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
-
-
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dlt_ref,
-                         dqt_ref, dqt_acc, *,
-                         sm_scale: float, causal: bool,
-                         block_q: int, block_k: int, seq_k: int):
-    i = pl.program_id(2)           # q block (parallel)
-    j = pl.program_id(3)           # k block (inner scan)
-    nk = pl.num_programs(3)
-
-    @pl.when(j == 0)
-    def _init():
-        dqt_acc[:] = jnp.zeros_like(dqt_acc)
-
-    run = (not causal) or (j * block_k <= i * block_q + block_q - 1)
-
-    @pl.when(run)
-    def _body():
-        q = q_ref[0, 0, :, :]
-        k = k_ref[0, 0, :, :]
-        v = v_ref[0, 0, :, :]
-        do = do_ref[0, 0, :, :]
-        if seq_k % block_k:
-            # k padding rows are contracted into dq — zero the garbage.
-            krows = j * block_k + lax.broadcasted_iota(
-                jnp.int32, k.shape, 0)
-            k = jnp.where(krows < seq_k, k, 0)
-        lse = lse_ref[0, 0, 0:1, :]
-        dlt = dlt_ref[0, 0, 0:1, :]
-        st = jax.lax.dot_general(
-            k, q, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # (bk, bq)
-        rows = j * block_k + lax.broadcasted_iota(
-            jnp.int32, (block_k, block_q), 0)
-        cols = i * block_q + lax.broadcasted_iota(
-            jnp.int32, (block_k, block_q), 1)
-        valid = None
-        if causal:
-            valid = rows <= cols
-        if seq_k % block_k:
-            vk = rows < seq_k                  # k-tail: garbage rows feed
-            valid = vk if valid is None else (valid & vk)  # the contraction
+            preferred_element_type=jnp.float32) * sm_scale
         pt = jnp.exp(st - lse)
         if valid is not None:
             pt = jnp.where(valid, pt, 0.0)
+        dv_acc[kr, :] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (keys, d)
         dpt = jax.lax.dot_general(
             v, do, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # (bk, bq)
-        dst = pt * (dpt - dlt) * sm_scale
-        if valid is not None:
-            dst = jnp.where(valid, dst, 0.0)
-        # dq^T accumulation: (d, bq) = k^T (d, bk) @ ds^T (bk, bq).
-        dqt_acc[:] += jax.lax.dot_general(
-            k, dst.astype(k.dtype), (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        dst = pt * (dpt - dlt) * sm_scale
+        if valid is not None:                  # kill 0*inf NaNs from tails
+            dst = jnp.where(valid, dst, 0.0)
+        dst = dst.astype(q.dtype)
+        dk_acc[kr, :] += jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (keys, d)
+        dq_acc[i, qr, :] += jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (queries, d)
 
-    @pl.when(j == nk - 1)
-    def _final():
-        dqt_ref[0, 0, :, :] = dqt_acc[:].astype(dqt_ref.dtype)
+    whole_k, whole_q = slice(0, block_k), slice(0, block_q)
+    if not causal:
+        pair(whole_k, whole_q, masked=False)
+    else:
+        # no key of the pair lies past any of its queries: no mask
+        below = i * block_q >= j * block_k + block_k - 1
+
+        @pl.when(below)
+        def _below():
+            pair(whole_k, whole_q, masked=False)
+
+        @pl.when((i * block_q + block_q - 1 >= j * block_k) & ~below)
+        def _diagonal():
+            if block_q != block_k or block_q % diag:
+                pair(whole_k, whole_q, masked=True)
+                return
+            # blocks alike: this is pair (j, j), and which of its squares
+            # the diagonal cuts or leaves out is known here
+            for a in range(block_k // diag):
+                for c in range(a, block_q // diag):
+                    pair(slice(a * diag, (a + 1) * diag),
+                         slice(c * diag, (c + 1) * diag), masked=a == c)
+
+    @pl.when(i == nq - 1)
+    def _final_dkdv():
+        dk_ref[0, 0, :, :] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[0, 0, :, :] = dv_acc[:].astype(dv_ref.dtype)
+
+    @pl.when((j == nk - 1) & (i == nq - 1))
+    def _final_dq():
+        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
-                      block_q, block_k, interpret, mesh=None):
+                      block_q, block_k, interpret, mesh=None,
+                      diag_block=DIAG_BLOCK):
     """Full Pallas backward: returns (dq, dk, dv)."""
     b, h, sq, d = q.shape
     kvh, sk = k.shape[1], k.shape[2]
@@ -443,7 +425,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
 
         def local(*a):
             dq, dk, dv = _flash_bwd_pallas(*a, causal, sm_scale, block_q,
-                                           block_k, interpret)
+                                           block_k, interpret,
+                                           diag_block=diag_block)
             if spec_kv[1] != spec_q[1]:
                 # MQA: the one kv head is replicated over tp and each
                 # device saw only its own q heads
@@ -465,86 +448,72 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
     lse8 = jnp.broadcast_to(lse[:, :, None, :], (b, h, 8, sq))
     dlt8 = jnp.broadcast_to(delta[:, :, None, :], (b, h, 8, sq))
 
-    # -------- dk/dv: grid (b, h, k-block, q-block), q innermost --------
+    def q_block(j, i):
+        # a step above the diagonal does nothing: it stays on the first
+        # query block that runs, which is then not copied in again
+        return jnp.maximum(i, j * block_k // block_q) if causal else i
+
+    # what the kernel holds: dq of a head whole (float32) and its copy
+    # out (twice, as every block), six blocks in and two out twice, the
+    # two sums, and a pair's scores, their exponentials and two products
+    held = (nq * block_q * d * (4 + 2 * q.dtype.itemsize)
+            + 2 * 6 * max(block_q, block_k) * d * 4
+            + 6 * block_q * block_k * 4)
     dkdv_out_dtype = jnp.float32 if group > 1 else k.dtype
-    dkdv_call = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dkdv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, seq_q=sq),
+            _flash_bwd_kernel, sm_scale=sm_scale, causal=causal,
+            block_q=block_q, block_k=block_k, seq_q=sq, seq_k=sk,
+            diag=diag_block),
         grid=(b, h, nk, nq),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, j, i: (b_, h_, i, 0)),
+                         lambda b_, h_, j, i: (b_, h_, q_block(j, i), 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b_, h_, j, i: (b_, h_ // group, j, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b_, h_, j, i: (b_, h_ // group, j, 0)),
             pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, j, i: (b_, h_, i, 0)),
+                         lambda b_, h_, j, i: (b_, h_, q_block(j, i), 0)),
             pl.BlockSpec((1, 1, 8, block_q),
-                         lambda b_, h_, j, i: (b_, h_, 0, i)),
+                         lambda b_, h_, j, i: (b_, h_, 0, q_block(j, i))),
             pl.BlockSpec((1, 1, 8, block_q),
-                         lambda b_, h_, j, i: (b_, h_, 0, i)),
+                         lambda b_, h_, j, i: (b_, h_, 0, q_block(j, i))),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b_, h_, j, i: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, block_k, d),
                          lambda b_, h_, j, i: (b_, h_, j, 0)),
+            # a head's whole dq, block by block: it stays in VMEM over
+            # the two inner axes and is written once
+            pl.BlockSpec((1, 1, nq, block_q, d),
+                         lambda b_, h_, j, i: (b_, h_, 0, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sk, d), dkdv_out_dtype),
             jax.ShapeDtypeStruct((b, h, sk, d), dkdv_out_dtype),
+            jax.ShapeDtypeStruct((b, h, nq, block_q, d), q.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((nq, block_q, d), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=max(held + (8 << 20), 32 << 20)),
         interpret=interpret,
         name=KERNEL_BWD_DKDV,
     )
     with jax.named_scope(KERNEL_SCOPE):
-        dk, dv = dkdv_call(q, k, v, do, lse8, dlt8)
+        dk, dv, dq = call(q, k, v, do, lse8, dlt8)
     if group > 1:
         dk = dk.reshape(b, kvh, group, sk, d).sum(axis=2).astype(k.dtype)
         dv = dv.reshape(b, kvh, group, sk, d).sum(axis=2).astype(v.dtype)
-
-    # -------- dq: grid (b, h, q-block, k-block), k innermost -----------
-    dq_call = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, seq_k=sk),
-        grid=(b, h, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d),
-                         lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
-            pl.BlockSpec((1, 1, block_q, d),
-                         lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, 8, block_q),
-                         lambda b_, h_, i, j: (b_, h_, 0, i)),
-            pl.BlockSpec((1, 1, 8, block_q),
-                         lambda b_, h_, i, j: (b_, h_, 0, i)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d, block_q),
-                               lambda b_, h_, i, j: (b_, h_, 0, i)),
-        out_shape=jax.ShapeDtypeStruct((b, h, d, sq), q.dtype),
-        scratch_shapes=[pltpu.VMEM((d, block_q), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-        name=KERNEL_BWD_DQ,
-    )
-    with jax.named_scope(KERNEL_SCOPE):
-        dqt = dq_call(q, k, v, do, lse8, dlt8)
-    dq = dqt.swapaxes(2, 3)                    # one XLA transpose
+    # a tail block's padding rows go
+    dq = dq.reshape(b, h, nq * block_q, d)[:, :, :sq]
     return dq, dk, dv
 
 

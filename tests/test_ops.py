@@ -124,6 +124,51 @@ def test_flash_gqa_backward():
                                    atol=1e-3, rtol=1e-3)
 
 
+@pytest.mark.parametrize("causal,h,kvh,s,blocks,diag", [
+    (True, 4, 4, 256, (64, 64), 32),     # 4 blocks a side, 2 x 2 squares
+    (True, 4, 1, 256, (64, 64), 16),     # MQA, 4 x 4 squares a diagonal pair
+    (True, 8, 2, 128, (64, 64), 32),     # group 4, 2 blocks a side
+    (True, 8, 2, 64, (64, 64), 16),      # one block: the diagonal case alone
+    (True, 2, 2, 200, (64, 64), 32),     # a tail of 8 in the fourth block
+    (True, 4, 1, 100, (64, 64), 32),     # MQA and a tail
+    (True, 4, 1, 250, (64, 64), 64),     # the diagonal pair walked whole
+    (True, 2, 2, 128, (32, 64), 16),     # blocks unlike: one masked square
+    (True, 2, 1, 128, (64, 32), 16),
+    (True, 2, 2, 128, (64, 64), 48),     # a square that does not divide it
+    (True, 2, 1, 40, (64, 64), 16),      # shorter than a block
+    (False, 8, 2, 128, (64, 64), 32),    # not causal: no mask, no walk
+    (False, 2, 2, 96, (64, 64), 32),     # not causal, a tail
+])
+def test_flash_backward_kernel_matches_reference(causal, h, kvh, s, blocks,
+                                                 diag):
+    """The one backward kernel (interpreter) against the gradients of
+    `mha_reference` and against `_flash_bwd_xla`: a head's dq is summed in
+    VMEM over every key block of its row, dk and dv over a column's query
+    blocks, and of a pair the diagonal cuts only the squares on and under
+    it are computed."""
+    from ray_tpu.ops.attention import (_flash_bwd_pallas, _flash_bwd_xla,
+                                       _flash_fwd)
+    d, (block_q, block_k) = 32, blocks
+    ks = jax.random.split(jax.random.PRNGKey(s + h + diag), 4)
+    q, do = (jax.random.normal(kk, (2, h, s, d), jnp.float32)
+             for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (2, kvh, s, d), jnp.float32)
+            for kk in ks[2:])
+    scale = d ** -0.5
+    o, lse = _flash_fwd(q, k, v, causal, scale, block_q, block_k, True)
+    got = _flash_bwd_pallas(q, k, v, o, lse, do, causal, scale, block_q,
+                            block_k, True, diag_block=diag)
+    _, vjp = jax.vjp(lambda *a: mha_reference(*a, causal=causal), q, k, v)
+    blockwise = _flash_bwd_xla(q, k, v, o, lse, do, causal, scale, block_k)
+    for name, a, want, xla in zip(("dq", "dk", "dv"), got, vjp(do),
+                                  blockwise):
+        assert a.shape == want.shape and a.dtype == want.dtype, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(want),
+                                   atol=1e-3, rtol=1e-3, err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(xla),
+                                   atol=1e-3, rtol=1e-3, err_msg=name)
+
+
 def test_flash_saveable_grads_and_remat_policy():
     """The remat-saveable path (named out/lse residuals) must produce the
     same gradients as the reference, standalone and under jax.checkpoint

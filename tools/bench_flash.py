@@ -17,10 +17,22 @@ of 256 / 256, LongCat-Flash 64 of 192 / 128, Ling-3.0-flash 32 of 192 /
 128) over the candidate blocks and buckets 256 to 16,384, and then prints
 the table `models.latent.PREFILL_BLOCKS` was chosen from beside it: a
 bucket and widths, the fastest pair, or the smaller program where two are
-within 3 % (PERF.md section 6, PR 53). The flash backward
-(ROADMAP S5b) has never been swept: this file is where that starts.
+within 3 % (PERF.md section 6, PR 53).
+
+`--backward` runs the backward kernel alone (`ops.attention.
+_flash_bwd_pallas`, 5 calls a program, each one's `do` hanging on the `dq`
+before) at the training cell's shapes (2 x 32 heads over 8 kv heads of 128,
+4096 tokens) and at 8192 and 16,384 tokens in one sequence, over blocks of
+512 and 1024 and the diagonal's sub-blocks 256, 512 and the whole block:
+milliseconds a call and the share of the peak that the causal half's five
+matmuls make of it. `--parent DIR` (a `git archive` of another commit) runs
+that tree's backward at the same shapes as the first rows; `DIAG_BLOCK` was
+chosen from this table (PERF.md section 6, PR 55):
+
+    chiprun -- python tools/bench_flash.py --backward --parent .bench_base/parent
 """
 import argparse
+import importlib.util
 import os
 import sys
 import time
@@ -32,6 +44,7 @@ import jax.numpy as jnp
 
 from benchmarks.harness.peaks import PEAKS
 from ray_tpu.models.latent import PREFILL_BLOCKS
+from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention
 
 LAYERS, PEAK = 8, PEAKS["TPU v5 lite"]["bf16_flops"]
@@ -43,6 +56,11 @@ BUCKETS = (256, 512, 1024, 2048, 4096, 8192, 16384)
 BLOCKS = ((128, 128), (256, 256), (256, 512), (512, 512), (512, 1024),
           (1024, 512), (1024, 1024), (512, 2048), (1024, 2048))
 WITHIN = 0.03       # two pairs this close: the smaller program
+# the backward: the training cell's heads, calls a program, lengths, (block,
+# the diagonal's sub-block)
+BWD_HEADS, BWD_KV_HEADS, BWD_D, BWD_CALLS, BWD_TOKENS = 32, 8, 128, 5, 8192
+BWD_LENGTHS = (4096, 8192, 16384)
+BWD_BLOCKS = ((512, 256), (512, 512), (1024, 256), (1024, 512), (1024, 1024))
 
 
 def program(block_q, block_k):
@@ -58,7 +76,7 @@ def program(block_q, block_k):
     return jax.jit(run)
 
 
-def timed(fn, *args):
+def timed(fn, *args, calls=LAYERS):
     """Milliseconds a call: the least of three batches of programs, each
     long enough (0.05 s or five programs) to be read on the host's
     clock."""
@@ -73,7 +91,7 @@ def timed(fn, *args):
             out = fn(*args)
         jax.block_until_ready(out)
         best = min(best, (time.perf_counter() - t) / n)
-    return best * 1e3 / LAYERS
+    return best * 1e3 / calls
 
 
 def sweep(heads, d, dv, buckets, blocks):
@@ -109,6 +127,58 @@ def sweep(heads, d, dv, buckets, blocks):
     return out
 
 
+def backward_program(bwd, block, **diag):
+    """`BWD_CALLS` backwards of one forward's residuals, the first rows of
+    each one's `do` moved by the `dq` before."""
+    def run(q, k, v, o, lse, do):
+        for _ in range(BWD_CALLS):
+            dq, dk, dv = bwd(q, k, v, o, lse, do, True, BWD_D ** -0.5, block,
+                             block, False, **diag)
+            do = do.at[:, :, :8].add(
+                (dq[:, :, :8] + dk[:, :1, :8] + dv[:, :1, :8]) * 1e-3)
+        return do
+    return jax.jit(run)
+
+
+def sweep_backward(lengths, parent):
+    """A line a reading: the parent tree's backward at each block (where
+    `parent` names a tree), then this tree's over `BWD_BLOCKS`."""
+    rows = [("this tree", attention._flash_bwd_pallas, block, {
+        "diag_block": diag}) for block, diag in BWD_BLOCKS]
+    if parent:
+        spec = importlib.util.spec_from_file_location(
+            "parent_attention",
+            os.path.join(parent, "ray_tpu", "ops", "attention.py"))
+        theirs = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(theirs)
+        rows = [("parent", theirs._flash_bwd_pallas, block, {})
+                for block in sorted({b for b, _ in BWD_BLOCKS})] + rows
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    for s in lengths:
+        b = max(1, BWD_TOKENS // s)
+        q, do = (jax.random.normal(kk, (b, BWD_HEADS, s, BWD_D),
+                                   jnp.bfloat16) for kk in keys[:2])
+        k, v = (jax.random.normal(kk, (b, BWD_KV_HEADS, s, BWD_D),
+                                  jnp.bfloat16) for kk in keys[2:])
+        o, lse = flash_attention(q, k, v, causal=True, block_q=1024,
+                                 block_k=1024, return_lse=True)
+        flops = 5 * 2.0 * (s * s / 2.0) * BWD_D * BWD_HEADS * b
+        for whose, bwd, block, diag in rows:
+            what = (f"{whose}, {b} x {BWD_HEADS} heads over {BWD_KV_HEADS} "
+                    f"of {BWD_D}, {s:5d} tokens, blocks {block:4d}"
+                    + "".join(f", diagonal {d:4d}" for d in diag.values()))
+            try:
+                ms = timed(backward_program(bwd, block, **diag), q, k, v, o,
+                           lse, do, calls=BWD_CALLS)
+            except Exception as e:      # say so and go on
+                print(f"{what}: does not compile: "
+                      f"{' '.join(str(e).split())[:200]}", flush=True)
+                continue
+            print(f"{what}: {ms:8.4f} ms a call, "
+                  f"{100 * flops / PEAK / (ms * 1e-3):5.1f} % of the peak",
+                  flush=True)
+
+
 def choice(readings):
     """The fastest pair of blocks, or the smallest (by the keys and
     queries a step holds) of those within `WITHIN` of it."""
@@ -132,6 +202,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--latent", action="store_true",
                     help="the three latent classes' shapes, and the table")
+    ap.add_argument("--backward", action="store_true",
+                    help="the backward kernel at the training cell's shapes")
+    ap.add_argument("--parent", help="with --backward: a tree of another "
+                    "commit, whose backward is read first")
     ap.add_argument("--heads", type=int, default=20)
     ap.add_argument("--d", type=int, default=256, help="width of a key")
     ap.add_argument("--dv", type=int, default=256, help="width of a value")
@@ -141,6 +215,10 @@ def main():
     opts = ap.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit("needs a TPU")
+    if opts.backward:
+        print(f"device {jax.devices()[0].device_kind}; the backward, causal, "
+              f"bfloat16, {BWD_CALLS} calls a program")
+        return sweep_backward(BWD_LENGTHS, opts.parent)
     print(f"device {jax.devices()[0].device_kind}; causal, batch 1, "
           f"bfloat16, {LAYERS} calls a program")
     buckets = [int(b) for b in opts.buckets.split(",")]

@@ -65,6 +65,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu.models import regions as R
 from ray_tpu.models.paged import decode_lanes
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
@@ -138,6 +139,7 @@ def decode_attention(config, page_size: int, dtype=None) -> str:
     return "einsum"
 
 
+@R.region(R.ATTN_IN)
 def _qkv(config, layer: Params, h):
     ad = config.activation_dtype
     b, s, _ = h.shape
@@ -152,9 +154,10 @@ def _mlp(model, layer: Params, x):
     config = model.config
     ad = config.activation_dtype
     h = model._norm(x, layer["mlp_norm"])
-    gate = jax.nn.silu(h @ layer["gate"].astype(ad))
-    up = h @ layer["up"].astype(ad)
-    return x + (gate * up) @ layer["down"].astype(ad)
+    with R.region(R.FFN):
+        gate = jax.nn.silu(h @ layer["gate"].astype(ad))
+        up = h @ layer["up"].astype(ad)
+        return x + (gate * up) @ layer["down"].astype(ad)
 
 
 def prefill(model, params: Params, tokens: jax.Array, true_len,
@@ -177,40 +180,49 @@ def prefill(model, params: Params, tokens: jax.Array, true_len,
     s = tokens.shape[0]
     toks = tokens[None]                                   # (1, s)
     positions = jnp.arange(s)[None]
-    x = model._embed_lookup(params["embed"].astype(ad), toks)
-    rope = rope_cos_sin(positions, c.head_dim, c.rope_theta)
+    with R.region(R.EMBED):
+        x = model._embed_lookup(params["embed"].astype(ad), toks)
+    with R.region(R.ATTN_IN):
+        rope = rope_cos_sin(positions, c.head_dim, c.rope_theta)
     cos, sin = rope
 
     def body(x, layer):
         h = model._norm(x, layer["attn_norm"])
         q, k, v = _qkv(c, layer, h)
-        q = apply_rope_cached(q, cos, sin)
-        k = apply_rope_cached(k, cos, sin)
-        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
-        attn = flash_attention(qt, kt, vt, causal=True,
-                               block_q=c.attn_block_q,
-                               block_k=c.attn_block_k,
-                               mesh=model.kernel_mesh)
-        attn = attn.transpose(0, 2, 1, 3).reshape(
-            1, s, c.n_heads * c.head_dim)
-        x = x + attn @ layer["wo"].astype(ad)
+        with R.region(R.ATTN_IN):
+            q = apply_rope_cached(q, cos, sin)
+            k = apply_rope_cached(k, cos, sin)
+            qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        with R.region(R.ATTN_CORE):
+            attn = flash_attention(qt, kt, vt, causal=True,
+                                   block_q=c.attn_block_q,
+                                   block_k=c.attn_block_k,
+                                   mesh=model.kernel_mesh)
+        with R.region(R.ATTN_OUT):
+            attn = attn.transpose(0, 2, 1, 3).reshape(
+                1, s, c.n_heads * c.head_dim)
+            x = x + attn @ layer["wo"].astype(ad)
         x = _mlp(model, layer, x)
         return x, (k[0], v[0])                     # (s, kv, hd) each
 
     x, (ks, vs) = lax.scan(body, x, params["layers"])
-    x = model._norm(x, params["final_norm"])
-    last = jnp.take(x[0], true_len - 1, axis=0)
-    logits = (last @ model._head(params).astype(ad)).astype(jnp.float32)
+    x = model._final_norm(params, x)
+    with R.region(R.HEAD):
+        last = jnp.take(x[0], true_len - 1, axis=0)
+        logits = (last @ model._head(params).astype(ad)).astype(
+            jnp.float32)
 
     # whole pages: (layers, s, kv, hd) -> (layers, pages, page, kv * hd)
     n = -(-s // page_size)
-    first = jnp.arange(n) * page_size
-    page_ids = jnp.take(page_table, jnp.arange(n), mode="clip")
-    # pages past the prompt scatter to the drop sentinel
-    page_ids = jnp.where(first < true_len, page_ids, num_pages)
+    with R.region(R.CACHE):
+        first = jnp.arange(n) * page_size
+        page_ids = jnp.take(page_table, jnp.arange(n), mode="clip")
+        # pages past the prompt scatter to the drop sentinel
+        page_ids = jnp.where(first < true_len, page_ids, num_pages)
 
-    layer_ids = jnp.arange(c.n_layers)[:, None]
+        layer_ids = jnp.arange(c.n_layers)[:, None]
 
+    @R.region(R.ATTN_IN)    # the cache write, every layer's at once
     def paged(a, pool):
         a = a.astype(pool.dtype).reshape(c.n_layers, s, -1)
         a = jnp.pad(a, ((0, 0), (0, n * page_size - s), (0, 0)))
@@ -244,9 +256,11 @@ def decode_step(model, params: Params, cache: KVCache,
     num_pages = ck.shape[1]
     B = tokens.shape[0]
 
-    x = model._embed_lookup(params["embed"].astype(ad),
-                            tokens[:, None])               # (B, 1, e)
-    cos, sin = rope_cos_sin(positions[:, None], hd, c.rope_theta)
+    with R.region(R.EMBED):
+        x = model._embed_lookup(params["embed"].astype(ad),
+                                tokens[:, None])           # (B, 1, e)
+    with R.region(R.ATTN_IN):
+        cos, sin = rope_cos_sin(positions[:, None], hd, c.rope_theta)
 
     # cache slot j is visible iff j <= position and its page is assigned
     # (own-position k/v is written before the read)
@@ -257,22 +271,28 @@ def decode_step(model, params: Params, cache: KVCache,
     for i in range(c.n_layers):
         layer = jax.tree_util.tree_map(lambda a: a[i], layers)
         h = model._norm(x, layer["attn_norm"])
-        # flat until past the barrier (the module's docstring says why)
-        q, k, v = lax.optimization_barrier(tuple(
-            h @ layer[w].astype(ad) for w in ("wq", "wk", "wv")))
-        q = apply_rope_cached(q.reshape(B, 1, c.n_heads, hd), cos, sin)
-        k = apply_rope_cached(k.reshape(B, 1, c.kv_heads, hd), cos, sin)
-        ck = ck.at[i, wr_page, wr_slot].set(
-            k.astype(ck.dtype).reshape(B, -1), mode="drop")
-        cv = cv.at[i, wr_page, wr_slot].set(
-            v[:, 0].astype(cv.dtype), mode="drop")
-        out = _paged.paged_decode_attention(
-            q[:, 0], ck, cv, i, page_tables, lengths,
-            mesh=model.kernel_mesh)
-        out = out.astype(ad).reshape(B, 1, c.n_heads * hd)
-        x = x + out @ layer["wo"].astype(ad)
+        with R.region(R.ATTN_IN):
+            # flat until past the barrier (the module's docstring says
+            # why)
+            q, k, v = lax.optimization_barrier(tuple(
+                h @ layer[w].astype(ad) for w in ("wq", "wk", "wv")))
+            q = apply_rope_cached(q.reshape(B, 1, c.n_heads, hd), cos, sin)
+            k = apply_rope_cached(k.reshape(B, 1, c.kv_heads, hd), cos,
+                                  sin)
+            ck = ck.at[i, wr_page, wr_slot].set(
+                k.astype(ck.dtype).reshape(B, -1), mode="drop")
+            cv = cv.at[i, wr_page, wr_slot].set(
+                v[:, 0].astype(cv.dtype), mode="drop")
+        with R.region(R.ATTN_CORE):
+            out = _paged.paged_decode_attention(
+                q[:, 0], ck, cv, i, page_tables, lengths,
+                mesh=model.kernel_mesh)
+        with R.region(R.ATTN_OUT):
+            out = out.astype(ad).reshape(B, 1, c.n_heads * hd)
+            x = x + out @ layer["wo"].astype(ad)
         x = _mlp(model, layer, x)
 
-    x = model._norm(x, params["final_norm"])
-    logits = (x[:, 0] @ model._head(params).astype(ad))
-    return logits.astype(jnp.float32), {"k": ck, "v": cv}
+    x = model._final_norm(params, x)
+    with R.region(R.HEAD):
+        logits = (x[:, 0] @ model._head(params).astype(ad))
+        return logits.astype(jnp.float32), {"k": ck, "v": cv}

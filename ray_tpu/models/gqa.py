@@ -19,6 +19,7 @@ from typing import Any, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 
+from ray_tpu.models import regions as R
 from ray_tpu.models.paged import Cache, Params
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
@@ -34,6 +35,7 @@ FULL_BLOCKS = (1024, 1024)
 SLIDING_BLOCKS = (512, 1024)
 
 
+@R.region(R.ATTN_IN)
 def qkv(layer: Params, h, heads: int, kv_heads: int, head_dim: int, dtype):
     """h (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd) through
     the layer's `wq`, `wk`, `wv`, each split into heads as it is
@@ -44,6 +46,7 @@ def qkv(layer: Params, h, heads: int, kv_heads: int, head_dim: int, dtype):
         for w, n in (("wq", heads), ("wk", kv_heads), ("wv", kv_heads)))
 
 
+@R.region(R.ATTN_CORE)
 def attend_seq(q, k, v, window: Optional[int] = None):
     """Causal attention over whole sequences through the flash forward, a
     query seeing its last `window` keys where one is given: q (b, s,
@@ -55,6 +58,7 @@ def attend_seq(q, k, v, window: Optional[int] = None):
     return out.transpose(0, 2, 1, 3)
 
 
+@R.region(R.ATTN_IN)
 def write_prompt(pools: Cache, names: Tuple[str, str], li: int, page_ids,
                  k, v) -> Cache:
     """A prefill's keys and values of one sequence, k, v (1, s, kv heads,
@@ -83,16 +87,18 @@ def decode_attend(pools: Cache, names: Tuple[str, str], li: int, page,
     ring `page_tables` names. Returns (out (B, heads, hd) in the pools'
     dtype, the two pools)."""
     B = q.shape[0]
-    out = {name: pools[name].at[li, page, offset].set(
-        a.reshape(B, -1).astype(pools[name].dtype), mode="drop")
-        for name, a in zip(names, (k, v))}
-    k_pool, v_pool = (out[name] for name in names)
-    q = q.astype(k_pool.dtype)
-    if window is None:
-        return _paged.paged_decode_attention(
-            q, k_pool, v_pool, li, page_tables, lengths), out
-    return _paged.paged_window_decode_attention(
-        q, k_pool, v_pool, li, page_tables, lengths, window), out
+    with R.region(R.ATTN_IN):
+        out = {name: pools[name].at[li, page, offset].set(
+            a.reshape(B, -1).astype(pools[name].dtype), mode="drop")
+            for name, a in zip(names, (k, v))}
+        k_pool, v_pool = (out[name] for name in names)
+        q = q.astype(k_pool.dtype)
+    with R.region(R.ATTN_CORE):
+        if window is None:
+            return _paged.paged_decode_attention(
+                q, k_pool, v_pool, li, page_tables, lengths), out
+        return _paged.paged_window_decode_attention(
+            q, k_pool, v_pool, li, page_tables, lengths, window), out
 
 
 # ------------------------------------------------ what an engine asks

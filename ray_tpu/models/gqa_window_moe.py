@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import gqa
+from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
 from ray_tpu.models.moe import STEP_COUNTS, DenseOrRoutedFFN
 from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
@@ -232,12 +233,14 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
         return shapes
 
     # --------------------------------------------------------- pieces
+    @R.region(R.ATTN_IN)
     def _ropes(self, positions: jax.Array):
         """kind -> (cos, sin) of `positions`: both tables, once a program."""
         c = self.config
         return {FULL: c.rope_full.cos_sin(positions, c.head_dim),
                 SLIDING: c.rope_sliding.cos_sin(positions, c.head_dim)}
 
+    @R.region(R.ATTN_IN)
     def _qkv(self, i: int, layer: Params, h, ropes):
         """h (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd), q
         and k rotated by the layer kind's scheme."""
@@ -248,6 +251,7 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
         return (_rope.rotate_leading(q, cos, sin),
                 _rope.rotate_leading(k, cos, sin), v)
 
+    @R.region(R.ATTN_OUT)
     def _attn_out(self, layer: Params, h, out):
         """Heads' outputs `out` (..., heads, hd), gated a head by the
         sigmoid of a projection of the layer's normed input `h`, through
@@ -279,15 +283,16 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
     # --------------------------------------------------------- forward
     def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
         """tokens (b, s) -> hidden states after the final norm."""
-        c = self.config
         b, s = tokens.shape
-        x = params["embed"].astype(c.activation_dtype)[tokens]
+        x = self._embed(params, tokens)
         ropes = self._ropes(jnp.broadcast_to(jnp.arange(s), (b, s)))
         for i, layer in enumerate(params["layers"]):
             h = self._norm(x, layer["attn_norm"])
-            x = x + self._attn_seq(i, layer, h, ropes)[0]
+            attn = self._attn_seq(i, layer, h, ropes)[0]
+            with R.region(R.ATTN_OUT):
+                x = x + attn
             x, _ = self._block_ffn(layer, x)
-        return self._norm(x, params["final_norm"])
+        return self._final_norm(params, x)
 
     # ------------------------------------------------ what an engine asks
     def window_pages(self, page_size: int) -> int:
@@ -393,14 +398,14 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
         layer's all, a sliding layer's last `window_pages` into its ring.
         Padding past `true_len` is given to no expert."""
         c = self.config
-        ad = c.activation_dtype
         pools = {name: cache[name] for name in ("k", "v", "wk", "wv")}
         num_pages, ring_pages = pools["k"].shape[1], pools["wk"].shape[1]
         ring = self.window_pages(page_size)
         s = tokens.shape[0]
-        x = params["embed"].astype(ad)[tokens][None]            # (1, s, e)
+        x = self._embed(params, tokens)[None]                   # (1, s, e)
         ropes = self._ropes(jnp.arange(s)[None])
-        valid = (jnp.arange(s) < true_len)[None]
+        with R.region(R.CACHE):
+            valid = (jnp.arange(s) < true_len)[None]
         full_ids, ring_ids = prefill_page_ids_held(
             page_table, true_len, s, num_pages, page_size, ring, ring_pages)
         order = {FULL: (("k", "v"), full_ids, c.full_layers),
@@ -411,7 +416,8 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
             names, ids, layers = order[c.layer_types[i]]
             pools.update(gqa.write_prompt(pools, names, layers.index(i),
                                           ids, k, v))
-            x = x + attn
+            with R.region(R.ATTN_OUT):
+                x = x + attn
             x, _ = self._block_ffn(layer, x, valid)
         return self._logits(params, x, true_len), {**cache, **pools}
 
@@ -426,41 +432,48 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
         num_pages, ring_pages = pools["k"].shape[1], pools["wk"].shape[1]
         ring = self.window_pages(page_size)
         B = tokens.shape[0]
-        x = params["embed"].astype(ad)[tokens]                  # (B, e)
+        x = self._embed(params, tokens)                         # (B, e)
         ropes = self._ropes(positions)                # (B, 1, rot / 2)
         # a lane's entry and row by hand: the ring needs the entry, and
         # the order is the one this class's traced text has always had
-        lengths = jnp.where(active, positions + 1, 0)
-        logical = positions // page_size
-        slot = positions % page_size
-        full = (("k", "v"), lane_page(page_tables, logical, active,
-                                      num_pages), page_tables, c.full_layers)
-        if ring:
-            ring_tables = page_tables[:, :ring]
-            sliding = (("wk", "wv"), lane_page(
-                ring_tables, logical % ring, active, ring_pages),
-                ring_tables, c.sliding_layers)
+        with R.region(R.CACHE):
+            lengths = jnp.where(active, positions + 1, 0)
+            logical = positions // page_size
+            slot = positions % page_size
+            full = (("k", "v"), lane_page(
+                page_tables, logical, active, num_pages), page_tables,
+                c.full_layers)
+            if ring:
+                ring_tables = page_tables[:, :ring]
+                sliding = (("wk", "wv"), lane_page(
+                    ring_tables, logical % ring, active, ring_pages),
+                    ring_tables, c.sliding_layers)
         load = cache["moe_load"]
         pairs, touched, load_max = self._step_sums()
         for i, layer in enumerate(params["layers"]):
             h = self._norm(x, layer["attn_norm"])
             q, k, v = self._qkv(i, layer, h, ropes)
-            # both flat before either is written (the traced text's order)
-            k, v = k.reshape(B, c.kv_dim), v.reshape(B, c.kv_dim)
+            with R.region(R.ATTN_IN):
+                # both flat before either is written (the traced text's
+                # order)
+                k, v = k.reshape(B, c.kv_dim), v.reshape(B, c.kv_dim)
             names, page, tables, layers = (
                 full if c.layer_types[i] == FULL else sliding)
             out, written = gqa.decode_attend(
                 pools, names, layers.index(i), page, slot, q, k, v, tables,
                 lengths, self._window(i))
             pools.update(written)
-            x = x + self._attn_out(layer, h, out.astype(ad))
+            with R.region(R.ATTN_OUT):
+                x = x + self._attn_out(layer, h, out.astype(ad))
             x, counts = self._block_ffn(layer, x, active)
             if counts is not None:
                 # as `_count_step`, the maximum taken after the two sums
                 # (the traced text's order)
-                load = load.at[c.sparse_layers.index(i)].add(counts["load"])
-                pairs = pairs + counts["pairs"]
-                touched = touched + counts["touched"]
-                load_max = load_max + jnp.max(counts["load"])
+                with R.region(R.MOE_ROUTE):
+                    load = load.at[c.sparse_layers.index(i)].add(
+                        counts["load"])
+                    pairs = pairs + counts["pairs"]
+                    touched = touched + counts["touched"]
+                    load_max = load_max + jnp.max(counts["load"])
         return self._logits(params, x), {
             **pools, **self._counted(load, (pairs, touched, load_max))}

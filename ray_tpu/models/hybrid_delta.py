@@ -55,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import gqa
+from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
 from ray_tpu.models.moe import swiglu
 from ray_tpu.models.paged import (Cache, PagedDecoder, Params, StateSlots,
@@ -178,10 +179,13 @@ class HybridDelta(StateSlots, PagedDecoder):
     # --------------------------------------------------------- pieces
     def _close(self, layer: Params, x, mixed):
         """The rest of a block after its mixer: both post-norm adds."""
-        x = x + self._norm(mixed, layer["attn_norm"])
-        return x + self._norm(swiglu(x, layer["gate"], layer["up"],
-                                     layer["down"]), layer["mlp_norm"])
+        with R.region(R.NORM):      # a post-norm and its residual addition
+            x = x + self._norm(mixed, layer["attn_norm"])
+        y = swiglu(x, layer["gate"], layer["up"], layer["down"])
+        with R.region(R.NORM):
+            return x + self._norm(y, layer["mlp_norm"])
 
+    @R.region(R.ATTN_IN)
     def _full_qkv(self, layer: Params, x):
         """x (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd), q
         and k normed over their whole width first, all three before any
@@ -200,9 +204,13 @@ class HybridDelta(StateSlots, PagedDecoder):
         """Causal attention over whole sequences x (b, s, e). Returns
         (the output after W_o, k, v (b, s, kv heads, hd))."""
         q, k, v = self._full_qkv(layer, x)
-        out = gqa.attend_seq(q, k, v).reshape(x.shape)
-        return out @ layer["wo"].astype(self.config.activation_dtype), k, v
+        out = gqa.attend_seq(q, k, v)
+        with R.region(R.ATTN_OUT):
+            out = out.reshape(x.shape)
+            return (out @ layer["wo"].astype(self.config.activation_dtype),
+                    k, v)
 
+    @R.region(R.MIXER_IN)
     def _linear_inputs(self, layer: Params, x, mixed):
         """What the recurrence takes of positions x (n, e) whose
         convolved channels are `mixed` (n, channels): q, k (n, H, dk) and
@@ -223,6 +231,7 @@ class HybridDelta(StateSlots, PagedDecoder):
         return (q.astype(ad), k.astype(ad),
                 v.reshape(n, H, c.linear_value_dim), g, beta)
 
+    @R.region(R.MIXER_OUT)
     def _linear_out(self, layer: Params, x, o):
         """Heads' outputs o (n, H, dv): normed a head, gated by SiLU of a
         projection of the layer's input x (n, e), through W_o."""
@@ -243,28 +252,31 @@ class HybridDelta(StateSlots, PagedDecoder):
         convolution's tail)."""
         c = self.config
         s = x.shape[0]
-        mixed, tail = _gd.causal_conv(
-            x @ layer["w_qkv"].astype(c.activation_dtype), layer["conv"],
-            true_len)
-        q, k, v, g, beta = self._linear_inputs(layer, x, mixed)
-        pad = -s % c.chunk                  # whole chunks; padding is inert
-        q, k, v, g, beta = (
-            jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).swapaxes(0, 1)
-            for a in (q, k, v, g, beta))
-        if true_len is None:
-            o, state = _gd.gated_delta_chunked(q, k, v, g, beta,
-                                               chunk=c.chunk)
-        else:
-            o, state = _gd.gated_delta_prefill(q, k, v, g, beta, true_len,
-                                               c.chunk)
-        o = o.swapaxes(0, 1)[:s]
+        with R.region(R.MIXER_IN):
+            mixed, tail = _gd.causal_conv(
+                x @ layer["w_qkv"].astype(c.activation_dtype),
+                layer["conv"], true_len)
+            q, k, v, g, beta = self._linear_inputs(layer, x, mixed)
+            pad = -s % c.chunk              # whole chunks; padding is inert
+            q, k, v, g, beta = (
+                jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).swapaxes(
+                    0, 1)
+                for a in (q, k, v, g, beta))
+        with R.region(R.MIXER_CORE):
+            if true_len is None:
+                o, state = _gd.gated_delta_chunked(q, k, v, g, beta,
+                                                   chunk=c.chunk)
+            else:
+                o, state = _gd.gated_delta_prefill(q, k, v, g, beta,
+                                                   true_len, c.chunk)
+            o = o.swapaxes(0, 1)[:s]
         return self._linear_out(layer, x, o), state, tail
 
     # --------------------------------------------------------- forward
     def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
         """tokens (b, s) -> hidden states after the final norm."""
         c = self.config
-        x = params["embed"].astype(c.activation_dtype)[tokens]
+        x = self._embed(params, tokens)
         for i, layer in enumerate(params["layers"]):
             if c.layer_types[i] == FULL:
                 mixed = self._full_seq(layer, x)[0]
@@ -272,7 +284,7 @@ class HybridDelta(StateSlots, PagedDecoder):
                 mixed = jax.vmap(
                     lambda seq: self._linear_seq(layer, seq)[0])(x)
             x = self._close(layer, x, mixed)
-        return self._norm(x, params["final_norm"])
+        return self._final_norm(params, x)
 
     # ------------------------------------------------ what an engine asks
     def state_bytes(self, dtype=None) -> int:
@@ -335,10 +347,9 @@ class HybridDelta(StateSlots, PagedDecoder):
         zero state to `true_len`, its state and tail written whole into
         the slot the table's first entry names."""
         c = self.config
-        ad = c.activation_dtype
         pools = dict(cache)
         num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
-        x = params["embed"].astype(ad)[tokens]                  # (s, e)
+        x = self._embed(params, tokens)                         # (s, e)
         ids, _ = prefill_page_ids_held(page_table, true_len,
                                        tokens.shape[0], num_pages,
                                        page_size)
@@ -353,11 +364,12 @@ class HybridDelta(StateSlots, PagedDecoder):
             else:
                 li = c.linear_layers.index(i)
                 mixed, state, tail = self._linear_seq(layer, x, true_len)
-                # (H, dk, dv) -> the pool's (dk, H x dv)
-                state = state.transpose(1, 0, 2).reshape(
-                    c.linear_key_dim, c.value_dim)
-                pools.update(self._write_slot(pools, li, slot, state,
-                                              tail))
+                with R.region(R.MIXER_CORE):
+                    # (H, dk, dv) -> the pool's (dk, H x dv)
+                    state = state.transpose(1, 0, 2).reshape(
+                        c.linear_key_dim, c.value_dim)
+                    pools.update(self._write_slot(pools, li, slot, state,
+                                                  tail))
             x = self._close(layer, x, mixed)
         return self._logits(params, x, true_len), pools
 
@@ -372,12 +384,13 @@ class HybridDelta(StateSlots, PagedDecoder):
         pools = dict(cache)
         num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
         B = tokens.shape[0]
-        x = params["embed"].astype(ad)[tokens]                  # (B, e)
+        x = self._embed(params, tokens)                         # (B, e)
         # `paged.decode_lanes`' three, in the order this class's traced
         # text has always had them
-        lengths = jnp.where(active, positions + 1, 0)
-        logical = positions // page_size
-        offset = positions % page_size
+        with R.region(R.CACHE):
+            lengths = jnp.where(active, positions + 1, 0)
+            logical = positions // page_size
+            offset = positions % page_size
         page = lane_page(page_tables, logical, active, num_pages)
         slot = decode_state_slots(page_tables, active, slots)
         for i, layer in enumerate(params["layers"]):
@@ -388,16 +401,19 @@ class HybridDelta(StateSlots, PagedDecoder):
                     pools, ("k", "v"), li, page, offset, q, k, v,
                     page_tables, lengths)
                 pools.update(written)
-                mixed = out.astype(ad).reshape(B, -1) @ layer["wo"].astype(
-                    ad)
+                with R.region(R.ATTN_OUT):
+                    mixed = out.astype(ad).reshape(B, -1) @ layer[
+                        "wo"].astype(ad)
             else:
                 li = c.linear_layers.index(i)
-                mixed, pools["tail"] = _gd.conv_tail_step(
-                    x @ layer["w_qkv"].astype(ad), layer["conv"],
-                    pools["tail"], li, slot)
+                with R.region(R.MIXER_IN):
+                    mixed, pools["tail"] = _gd.conv_tail_step(
+                        x @ layer["w_qkv"].astype(ad), layer["conv"],
+                        pools["tail"], li, slot)
                 q, k, v, g, beta = self._linear_inputs(layer, x, mixed)
-                o, pools["state"] = _gd.gated_delta_step(
-                    q, k, v, g, beta, pools["state"], li, slot)
+                with R.region(R.MIXER_CORE):
+                    o, pools["state"] = _gd.gated_delta_step(
+                        q, k, v, g, beta, pools["state"], li, slot)
                 mixed = self._linear_out(layer, x, o)
             x = self._close(layer, x, mixed)
         return self._logits(params, x), pools

@@ -56,6 +56,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import regions as R
 from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
 from ray_tpu.models.moe import DenseOrRoutedFFN
 from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
@@ -232,6 +233,7 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
             scale=c.routed_scaling_factor, held=c.held, n_group=c.n_group,
             topk_group=c.topk_group)
 
+    @R.region(R.MIXER_IN)
     def _linear_inputs(self, layer: Params, u, mixed):
         """What the recurrence takes of positions u (n, e) whose convolved
         channels are `mixed` (n, channels): q, k (n, H, dk) and v (n, H,
@@ -254,6 +256,7 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
         return (q.astype(ad), k.astype(ad),
                 v.reshape(n, H, c.linear_value_dim), g, beta)
 
+    @R.region(R.MIXER_OUT)
     def _linear_out(self, layer: Params, u, o):
         """Heads' outputs o (n, H, dv): normed a head, gated by the
         sigmoid of a projection of the layer's input u (n, e), through
@@ -275,19 +278,23 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
         convolution's tail)."""
         c = self.config
         s = u.shape[0]
-        mixed, tail = causal_conv(
-            u @ layer["w_qkv"].astype(c.activation_dtype), layer["conv"],
-            true_len)
-        q, k, v, g, beta = self._linear_inputs(layer, u, mixed)
-        pad = -s % c.chunk                  # whole chunks; padding is inert
-        q, k, v, g, beta = (
-            jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).swapaxes(0, 1)
-            for a in (q, k, v, g, beta))
-        if true_len is None:
-            o, state = _kda.kda_chunked(q, k, v, g, beta, chunk=c.chunk)
-        else:
-            o, state = _kda.kda_prefill(q, k, v, g, beta, true_len, c.chunk)
-        o = o.swapaxes(0, 1)[:s]
+        with R.region(R.MIXER_IN):
+            mixed, tail = causal_conv(
+                u @ layer["w_qkv"].astype(c.activation_dtype),
+                layer["conv"], true_len)
+            q, k, v, g, beta = self._linear_inputs(layer, u, mixed)
+            pad = -s % c.chunk              # whole chunks; padding is inert
+            q, k, v, g, beta = (
+                jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).swapaxes(
+                    0, 1)
+                for a in (q, k, v, g, beta))
+        with R.region(R.MIXER_CORE):
+            if true_len is None:
+                o, state = _kda.kda_chunked(q, k, v, g, beta, chunk=c.chunk)
+            else:
+                o, state = _kda.kda_prefill(q, k, v, g, beta, true_len,
+                                            c.chunk)
+            o = o.swapaxes(0, 1)[:s]
         return self._linear_out(layer, u, o), state, tail
 
     # --------------------------------------------------------- forward
@@ -296,19 +303,24 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
         c = self.config
         ad = c.activation_dtype
         b, s = tokens.shape
-        x = params["embed"].astype(ad)[tokens]
-        cos, sin = rope_cos_sin(jnp.broadcast_to(jnp.arange(s), (b, s)),
-                                c.qk_rope_head_dim, c.rope_theta)
+        x = self._embed(params, tokens)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(
+                jnp.broadcast_to(jnp.arange(s), (b, s)),
+                c.qk_rope_head_dim, c.rope_theta)
         for i, layer in enumerate(params["layers"]):
             u = self._norm(x, layer["attn_norm"])
             if c.layer_types[i] == LATENT:
                 attn, _, _ = self._attn_expanded(layer, u, cos, sin)
-                x = x + attn @ layer["wo"].astype(ad)
+                with R.region(R.ATTN_OUT):
+                    x = x + attn @ layer["wo"].astype(ad)
             else:
-                x = x + jax.vmap(
+                mixed = jax.vmap(
                     lambda seq: self._linear_seq(layer, seq)[0])(u)
+                with R.region(R.MIXER_OUT):
+                    x = x + mixed
             x, _ = self._block_ffn(layer, x)
-        return self._norm(x, params["final_norm"])
+        return self._final_norm(params, x)
 
     # ------------------------------------------------ what an engine asks
     def state_bytes(self, dtype=None) -> int:
@@ -381,12 +393,14 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
         num_pages = pools["kv"].shape[1]
         slots = pools["state"].shape[1] - 1
         s = tokens.shape[0]
-        x = params["embed"].astype(ad)[tokens]                  # (s, e)
-        cos, sin = rope_cos_sin(jnp.arange(s)[None], c.qk_rope_head_dim,
-                                c.rope_theta)
+        x = self._embed(params, tokens)                         # (s, e)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(jnp.arange(s)[None],
+                                    c.qk_rope_head_dim, c.rope_theta)
         ids = prefill_page_ids(page_table, true_len, s, num_pages, page_size)
         slot = prefill_state_slot(page_table, slots)
-        valid = jnp.arange(s) < true_len
+        with R.region(R.CACHE):
+            valid = jnp.arange(s) < true_len
         for i, layer in enumerate(params["layers"]):
             u = self._norm(x, layer["attn_norm"])
             if c.layer_types[i] == LATENT:
@@ -395,16 +409,19 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
                                                          cos, sin)
                 pools["kv"] = self._write_pages(
                     pools["kv"], li, c_kv[0], k_rope[0], ids, page_size)
-                x = x + attn[0] @ layer["wo"].astype(ad)
+                with R.region(R.ATTN_OUT):
+                    x = x + attn[0] @ layer["wo"].astype(ad)
             else:
                 li = c.of_kind(LINEAR).index(i)
                 mixed, state, tail = self._linear_seq(layer, u, true_len)
-                # (H, dk, dv) -> the pool's (dk, H x dv)
-                state = state.transpose(1, 0, 2).reshape(
-                    c.linear_key_dim, c.value_dim)
-                pools.update(self._write_slot(pools, li, slot, state,
-                                              tail))
-                x = x + mixed
+                with R.region(R.MIXER_CORE):
+                    # (H, dk, dv) -> the pool's (dk, H x dv)
+                    state = state.transpose(1, 0, 2).reshape(
+                        c.linear_key_dim, c.value_dim)
+                    pools.update(self._write_slot(pools, li, slot, state,
+                                                  tail))
+                with R.region(R.MIXER_OUT):
+                    x = x + mixed
             x, _ = self._block_ffn(layer, x, valid)
         return self._logits(params, x, true_len), pools
 
@@ -420,9 +437,10 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
         pools = dict(cache)
         num_pages = pools["kv"].shape[1]
         slots = pools["state"].shape[1] - 1
-        x = params["embed"].astype(ad)[tokens]                  # (B, e)
-        cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
-                                c.rope_theta)              # (B, 1, rope/2)
+        x = self._embed(params, tokens)                         # (B, e)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
+                                    c.rope_theta)          # (B, 1, rope/2)
         page, offset, lengths = decode_lanes(positions, page_tables, active,
                                              num_pages, page_size)
         slot = decode_state_slots(page_tables, active, slots)
@@ -434,20 +452,26 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
                 out, pools["kv"] = self._attn_absorbed(
                     layer, u, cos, sin, pools["kv"], li, page, offset,
                     page_tables, lengths)
-                x = x + out @ layer["wo"].astype(ad)
+                with R.region(R.ATTN_OUT):
+                    x = x + out @ layer["wo"].astype(ad)
             else:
                 li = c.of_kind(LINEAR).index(i)
-                mixed, pools["tail"] = _gd.conv_tail_step(
-                    u @ layer["w_qkv"].astype(ad), layer["conv"],
-                    pools["tail"], li, slot)
+                with R.region(R.MIXER_IN):
+                    mixed, pools["tail"] = _gd.conv_tail_step(
+                        u @ layer["w_qkv"].astype(ad), layer["conv"],
+                        pools["tail"], li, slot)
                 q, k, v, g, beta = self._linear_inputs(layer, u, mixed)
-                o, pools["state"] = _kda.kda_step(
-                    q, k, v, g, beta, pools["state"], li, slot)
-                x = x + self._linear_out(layer, u, o)
+                with R.region(R.MIXER_CORE):
+                    o, pools["state"] = _kda.kda_step(
+                        q, k, v, g, beta, pools["state"], li, slot)
+                mixed = self._linear_out(layer, u, o)
+                with R.region(R.MIXER_OUT):
+                    x = x + mixed
             x, counts = self._block_ffn(layer, x, active)
             if counts is not None:
-                load = load.at[c.of_kind(SPARSE).index(i)].add(
-                    counts["load"])
+                with R.region(R.MOE_ROUTE):
+                    load = load.at[c.of_kind(SPARSE).index(i)].add(
+                        counts["load"])
                 sums = self._count_step(sums, counts)
         return self._logits(params, x), {**pools,
                                          **self._counted(load, sums)}

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import gqa
+from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
 from ray_tpu.models.moe import dropless_moe_ffn
 from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
@@ -62,6 +63,8 @@ from ray_tpu.ops import ssd as _ssd
 
 # a layer's kind, by the letters of the family's `hybrid_override_pattern`
 SSM, EXPERTS, ATTENTION = "M", "E", "*"
+# the region of a layer's residual addition, by its kind
+_CLOSES = {SSM: R.MIXER_OUT, EXPERTS: R.FFN, ATTENTION: R.ATTN_OUT}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -187,8 +190,10 @@ class HybridSSMMoE(SSMMixer, StateSlots, ExpertCounts, PagedDecoder):
         c = self.config
         q, k, v = gqa.qkv(layer, u, c.n_heads, c.n_kv_heads, c.head_dim,
                           c.activation_dtype)
-        out = gqa.attend_seq(q, k, v).reshape(*u.shape[:-1], -1)
-        return out @ layer["wo"].astype(c.activation_dtype), k, v
+        out = gqa.attend_seq(q, k, v)
+        with R.region(R.ATTN_OUT):
+            out = out.reshape(*u.shape[:-1], -1)
+            return out @ layer["wo"].astype(c.activation_dtype), k, v
 
     def _experts(self, layer: Params, u, valid=None):
         """An expert layer's mixer on the normed stream u (n, e): this
@@ -196,21 +201,27 @@ class HybridSSMMoE(SSMMixer, StateSlots, ExpertCounts, PagedDecoder):
         Returns (the mixer's output, the routed part's counts)."""
         c = self.config
         ad = c.activation_dtype
+        with R.region(R.MOE_EXPERTS):       # into the experts' latent
+            narrow = u @ layer["fc1"].astype(ad)
         latent, counts = dropless_moe_ffn(
             u, layer["router"], layer["router_bias"], None,
             layer["moe_up"], layer["moe_down"], top_k=c.num_experts_per_tok,
             scale=c.routed_scaling_factor, valid=valid, held=c.held,
-            expert_form="relu2", expert_input=u @ layer["fc1"].astype(ad))
-        shared = jnp.square(jax.nn.relu(u @ layer["shared_up"].astype(ad)))
-        return (latent @ layer["fc2"].astype(ad)
-                + shared @ layer["shared_down"].astype(ad)), counts
+            expert_form="relu2", expert_input=narrow)
+        with R.region(R.FFN):               # the shared expert
+            shared = jnp.square(jax.nn.relu(
+                u @ layer["shared_up"].astype(ad)))
+        with R.region(R.MOE_EXPERTS):       # and back out of it
+            routed = latent @ layer["fc2"].astype(ad)
+        with R.region(R.FFN):
+            return routed + shared @ layer["shared_down"].astype(ad), counts
 
     # --------------------------------------------------------- forward
     def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
         """tokens (b, s) -> hidden states after the final norm."""
         c = self.config
         b, s = tokens.shape
-        x = params["embed"].astype(c.activation_dtype)[tokens]
+        x = self._embed(params, tokens)
         for i, layer in enumerate(params["layers"]):
             u = self._norm(x, layer["norm"])
             kind = c.layer_types[i]
@@ -222,8 +233,9 @@ class HybridSSMMoE(SSMMixer, StateSlots, ExpertCounts, PagedDecoder):
             else:
                 mixed = jax.vmap(
                     lambda seq: self._ssm_seq(layer, seq)[0])(u)
-            x = x + mixed
-        return self._norm(x, params["final_norm"])
+            with R.region(_CLOSES[kind]):
+                x = x + mixed
+        return self._final_norm(params, x)
 
     # ------------------------------------------------ what an engine asks
     def state_bytes(self, dtype=None) -> int:
@@ -279,14 +291,14 @@ class HybridSSMMoE(SSMMixer, StateSlots, ExpertCounts, PagedDecoder):
         written whole into the slot the table's first entry names; padding
         past `true_len` given to no expert."""
         c = self.config
-        ad = c.activation_dtype
         pools = dict(cache)
         num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
         s = tokens.shape[0]
-        x = params["embed"].astype(ad)[tokens]                  # (s, e)
+        x = self._embed(params, tokens)                         # (s, e)
         ids = prefill_page_ids(page_table, true_len, s, num_pages, page_size)
         slot = prefill_state_slot(page_table, slots)
-        valid = jnp.arange(s) < true_len
+        with R.region(R.CACHE):
+            valid = jnp.arange(s) < true_len
         for i, layer in enumerate(params["layers"]):
             u = self._norm(x, layer["norm"])
             kind = c.layer_types[i]
@@ -303,7 +315,8 @@ class HybridSSMMoE(SSMMixer, StateSlots, ExpertCounts, PagedDecoder):
                 mixed, state, tail = self._ssm_seq(layer, u, true_len)
                 pools.update(self._write_slot(pools, li, slot, state,
                                               tail))
-            x = x + mixed
+            with R.region(_CLOSES[kind]):
+                x = x + mixed
         return self._logits(params, x, true_len), pools
 
     def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
@@ -317,7 +330,7 @@ class HybridSSMMoE(SSMMixer, StateSlots, ExpertCounts, PagedDecoder):
         pools = dict(cache)
         num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
         B = tokens.shape[0]
-        x = params["embed"].astype(ad)[tokens]                  # (B, e)
+        x = self._embed(params, tokens)                         # (B, e)
         page, offset, lengths = decode_lanes(positions, page_tables, active,
                                              num_pages, page_size)
         slot = decode_state_slots(page_tables, active, slots)
@@ -333,17 +346,20 @@ class HybridSSMMoE(SSMMixer, StateSlots, ExpertCounts, PagedDecoder):
                     pools, ("k", "v"), li, page, offset, q, k, v,
                     page_tables, lengths)
                 pools.update(written)
-                mixed = out.astype(ad).reshape(B, -1) @ layer["wo"].astype(
-                    ad)
+                with R.region(R.ATTN_OUT):
+                    mixed = out.astype(ad).reshape(B, -1) @ layer[
+                        "wo"].astype(ad)
             elif kind == EXPERTS:
                 li = c.of_kind(EXPERTS).index(i)
                 mixed, counts = self._experts(layer, u, active)
-                load = load.at[li].add(counts["load"])
+                with R.region(R.MOE_ROUTE):
+                    load = load.at[li].add(counts["load"])
                 sums = self._count_step(sums, counts)
             else:
                 li = c.of_kind(SSM).index(i)
                 mixed, written = self._ssm_step(layer, u, pools, li, slot)
                 pools.update(written)
-            x = x + mixed
+            with R.region(_CLOSES[kind]):
+                x = x + mixed
         return self._logits(params, x), {**pools,
                                          **self._counted(load, sums)}

@@ -38,6 +38,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
@@ -114,6 +115,7 @@ class LatentAttention:
     class says `pool_rows`, the rows of the latent pool, one an
     attention."""
 
+    @R.region(R.ATTN_IN)
     def _q(self, layer: Params, h):
         """h (..., e) -> q (..., heads, nope + rope), not yet rotated."""
         c = self.config
@@ -128,6 +130,7 @@ class LatentAttention:
             q = h @ layer["wq"].astype(ad)
         return q.reshape(*h.shape[:-1], c.n_heads, c.qk_head_dim)
 
+    @R.region(R.ATTN_OUT)
     def _gated(self, layer: Params, h, out):
         """The heads' outputs out (..., heads, v), each head's times the
         sigmoid of its number in `h W_a` where the config has a
@@ -140,6 +143,7 @@ class LatentAttention:
                 out.dtype)
         return out.reshape(*h.shape[:-1], c.n_heads * c.v_head_dim)
 
+    @R.region(R.ATTN_IN)
     def _latent(self, layer: Params, h, cos, sin):
         """h (..., e) -> the cache's row parts: c_kv (..., latent) after
         its norm (and its scale) and k_rope (..., rope) after the
@@ -178,24 +182,30 @@ class LatentAttention:
         b, s, _ = h.shape
         nope = c.qk_nope_head_dim
         q = self._q(layer, h)                           # (b, s, H, qk)
-        q = jnp.concatenate(
-            [q[..., :nope], apply_rope_cached(q[..., nope:], cos, sin)],
-            axis=-1)
+        with R.region(R.ATTN_IN):
+            q = jnp.concatenate(
+                [q[..., :nope], apply_rope_cached(q[..., nope:], cos, sin)],
+                axis=-1)
         c_kv, k_rope = self._latent(layer, h, cos, sin)
-        kv = jnp.einsum("bsc,chd->bshd", c_kv, self._wkv_b(layer))
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(
-                k_rope[:, :, None, :], (b, s, c.n_heads,
-                                        c.qk_rope_head_dim))], axis=-1)
-        v = kv[..., nope:]
-        qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
+        with R.region(R.ATTN_IN):
+            kv = jnp.einsum("bsc,chd->bshd", c_kv, self._wkv_b(layer))
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(
+                    k_rope[:, :, None, :], (b, s, c.n_heads,
+                                            c.qk_rope_head_dim))], axis=-1)
+            v = kv[..., nope:]
+            qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         block_q, block_k = PREFILL_BLOCKS
-        out = flash_attention(qt, kt, vt, causal=True,
-                              sm_scale=1.0 / math.sqrt(c.qk_head_dim),
-                              block_q=block_q, block_k=block_k)
-        out = self._gated(layer, h, out.transpose(0, 2, 1, 3))
+        with R.region(R.ATTN_CORE):
+            out = flash_attention(qt, kt, vt, causal=True,
+                                  sm_scale=1.0 / math.sqrt(c.qk_head_dim),
+                                  block_q=block_q, block_k=block_k)
+        with R.region(R.ATTN_OUT):
+            out = out.transpose(0, 2, 1, 3)
+        out = self._gated(layer, h, out)
         return out, c_kv, k_rope
 
+    @R.region(R.ATTN_IN)
     def _write_pages(self, pool, row: int, c_kv, k_rope, page_ids,
                      page_size: int):
         """A prefill's rows of one sequence (c_kv (s, latent), k_rope
@@ -221,19 +231,21 @@ class LatentAttention:
         pad = c.row_width - latent - c.qk_rope_head_dim
         q = self._q(layer, h)                           # (B, H, qk)
         c_kv, k_rope = self._latent(layer, h, cos, sin)
-        pool = pool.at[row, wr_page, wr_slot].set(
-            self._rows(c_kv, k_rope, pool.dtype), mode="drop")
-        w_kvb = self._wkv_b(layer)
-        q_lat = jnp.einsum("bhn,chn->bhc", q[..., :nope],
-                           w_kvb[..., :nope])
-        q_rope = apply_rope_cached(q[..., nope:], cos, sin)
-        q_row = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
-                        ((0, 0), (0, 0), (0, pad)))
-        o_lat = _paged.mla_paged_decode_attention(
-            q_row.astype(pool.dtype), pool, row, page_tables, lengths,
-            latent, sm_scale)
-        out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
-                         w_kvb[..., nope:])
+        with R.region(R.ATTN_IN):
+            pool = pool.at[row, wr_page, wr_slot].set(
+                self._rows(c_kv, k_rope, pool.dtype), mode="drop")
+            w_kvb = self._wkv_b(layer)
+            q_lat = jnp.einsum("bhn,chn->bhc", q[..., :nope],
+                               w_kvb[..., :nope])
+            q_rope = apply_rope_cached(q[..., nope:], cos, sin)
+            q_row = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
+                            ((0, 0), (0, 0), (0, pad))).astype(pool.dtype)
+        with R.region(R.ATTN_CORE):
+            o_lat = _paged.mla_paged_decode_attention(
+                q_row, pool, row, page_tables, lengths, latent, sm_scale)
+        with R.region(R.ATTN_OUT):
+            out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
+                             w_kvb[..., nope:])
         return self._gated(layer, h, out), pool
 
     # ------------------------------------------------ what an engine asks

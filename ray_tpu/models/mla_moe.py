@@ -47,6 +47,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import regions as R
 from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
 from ray_tpu.models.moe import STEP_COUNTS, DenseOrRoutedFFN
 from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
@@ -153,15 +154,18 @@ class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
         c = self.config
         ad = c.activation_dtype
         b, s = tokens.shape
-        x = params["embed"].astype(ad)[tokens]
-        cos, sin = rope_cos_sin(jnp.broadcast_to(jnp.arange(s), (b, s)),
-                                c.qk_rope_head_dim, c.rope_theta)
+        x = self._embed(params, tokens)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(
+                jnp.broadcast_to(jnp.arange(s), (b, s)),
+                c.qk_rope_head_dim, c.rope_theta)
         for layer in params["layers"]:
             h = self._norm(x, layer["attn_norm"])
             attn, _, _ = self._attn_expanded(layer, h, cos, sin)
-            x = x + attn @ layer["wo"].astype(ad)
+            with R.region(R.ATTN_OUT):
+                x = x + attn @ layer["wo"].astype(ad)
             x, _ = self._block_ffn(layer, x)
-        return self._norm(x, params["final_norm"])
+        return self._final_norm(params, x)
 
     # ------------------------------------------------ what an engine asks
     def init_cache(self, num_pages: int, page_size: int,
@@ -191,10 +195,12 @@ class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
         pool = cache["kv"]
         num_pages = pool.shape[1]
         s = tokens.shape[0]
-        x = params["embed"].astype(ad)[tokens][None]            # (1, s, e)
-        cos, sin = rope_cos_sin(jnp.arange(s)[None], c.qk_rope_head_dim,
-                                c.rope_theta)
-        valid = (jnp.arange(s) < true_len)[None]
+        x = self._embed(params, tokens)[None]                   # (1, s, e)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(jnp.arange(s)[None],
+                                    c.qk_rope_head_dim, c.rope_theta)
+        with R.region(R.CACHE):
+            valid = (jnp.arange(s) < true_len)[None]
         page_ids = prefill_page_ids(page_table, true_len, s, num_pages,
                                     page_size)
         for i, layer in enumerate(params["layers"]):
@@ -202,7 +208,8 @@ class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
             attn, c_kv, k_rope = self._attn_expanded(layer, h, cos, sin)
             pool = self._write_pages(pool, i, c_kv[0], k_rope[0], page_ids,
                                      page_size)
-            x = x + attn @ layer["wo"].astype(ad)
+            with R.region(R.ATTN_OUT):
+                x = x + attn @ layer["wo"].astype(ad)
             x, _ = self._block_ffn(layer, x, valid)
         return self._logits(params, x, true_len), {**cache, "kv": pool}
 
@@ -215,9 +222,10 @@ class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
         c = self.config
         ad = c.activation_dtype
         pool = cache["kv"]
-        x = params["embed"].astype(ad)[tokens]                  # (B, e)
-        cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
-                                c.rope_theta)              # (B, 1, rope/2)
+        x = self._embed(params, tokens)                         # (B, e)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
+                                    c.rope_theta)          # (B, 1, rope/2)
         wr_page, wr_slot, lengths = decode_lanes(
             positions, page_tables, active, pool.shape[1], page_size)
         load = cache["moe_load"]
@@ -227,16 +235,18 @@ class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
             out, pool = self._attn_absorbed(
                 layer, h, cos, sin, pool, i, wr_page, wr_slot, page_tables,
                 lengths)
-            x = x + out @ layer["wo"].astype(ad)
+            with R.region(R.ATTN_OUT):
+                x = x + out @ layer["wo"].astype(ad)
             x, counts = self._block_ffn(layer, x, active)
             if counts is not None:
                 # as `_count_step`, the maximum taken after the two sums
                 # (the traced text's order)
                 j = i - c.first_k_dense_replace
-                load = load.at[j].add(counts["load"])
-                pairs = pairs + counts["pairs"]
-                touched = touched + counts["touched"]
-                load_max = load_max + jnp.max(counts["load"])
+                with R.region(R.MOE_ROUTE):
+                    load = load.at[j].add(counts["load"])
+                    pairs = pairs + counts["pairs"]
+                    touched = touched + counts["touched"]
+                    load_max = load_max + jnp.max(counts["load"])
         return self._logits(params, x), {
             "kv": pool, **self._counted(load, (pairs, touched, load_max))}
 

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import regions as R
+
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
@@ -57,6 +59,7 @@ def expert_capacity(num_tokens: int, num_experts: int, top_k: int,
         num_tokens * top_k / num_experts * capacity_factor)))
 
 
+@R.region(R.MOE_EXPERTS)
 def moe_ffn(x: jax.Array,
             router_w: jax.Array,
             gate_w: jax.Array, up_w: jax.Array, down_w: jax.Array,
@@ -79,13 +82,14 @@ def moe_ffn(x: jax.Array,
     cdtype = x.dtype
 
     xf = x.reshape(T, d)
-    logits = (xf @ router_w.astype(cdtype)).astype(jnp.float32)  # (T, E)
-    if router_jitter and rngs is not None:
-        logits = logits + router_jitter * jax.random.normal(
-            rngs, logits.shape)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = lax.top_k(probs, top_k)                    # (T, k)
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)    # renorm
+    with R.region(R.MOE_ROUTE):
+        logits = (xf @ router_w.astype(cdtype)).astype(jnp.float32)  # (T, E)
+        if router_jitter and rngs is not None:
+            logits = logits + router_jitter * jax.random.normal(
+                rngs, logits.shape)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = lax.top_k(probs, top_k)                    # (T, k)
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)    # renorm
 
     # Position of each (token, k) assignment within its expert's queue:
     # flatten assignments k-major so k=0 choices win capacity ties.
@@ -122,10 +126,11 @@ def moe_ffn(x: jax.Array,
     y = y.reshape(b, s, d)
 
     # Aux: switch-style load-balance loss + routing stats.
-    frac_tokens = jnp.mean(assign[:, 0, :].astype(jnp.float32), axis=0)
-    frac_probs = jnp.mean(probs, axis=0)
-    lb_loss = E * jnp.sum(frac_tokens * frac_probs)
-    dropped = 1.0 - (jnp.sum(dispatch) / (T * top_k))
+    with R.region(R.MOE_ROUTE):
+        frac_tokens = jnp.mean(assign[:, 0, :].astype(jnp.float32), axis=0)
+        frac_probs = jnp.mean(probs, axis=0)
+        lb_loss = E * jnp.sum(frac_tokens * frac_probs)
+        dropped = 1.0 - (jnp.sum(dispatch) / (T * top_k))
     return y, {"moe_load_balance_loss": lb_loss,
                "moe_dropped_fraction": dropped.astype(jnp.float32)}
 
@@ -143,6 +148,7 @@ SCORING = {"sigmoid": jax.nn.sigmoid,
            "softmax": functools.partial(jax.nn.softmax, axis=-1)}
 
 
+@R.region(R.MOE_ROUTE)
 def route_topk(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
                top_k: int, norm_topk_prob: bool = True,
                scale: float = 1.0, scoring: str = "sigmoid",
@@ -184,6 +190,7 @@ def route_topk(x: jax.Array, router_w: jax.Array, bias: jax.Array, *,
 EXPERT_FORMS = ("swiglu", "relu2")
 
 
+@R.region(R.MOE_EXPERTS)
 def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
                      gate_w: Optional[jax.Array], up_w: jax.Array,
                      down_w: jax.Array,
@@ -240,21 +247,23 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
                               topk_group=topk_group)
     zero_pairs = away_pairs = jnp.int32(0)
     identity = None
-    if zero_experts or E != experts:
-        live = jnp.broadcast_to(True if valid is None else valid[:, None],
-                                top_e.shape)
-        zero = live & (top_e >= experts)
-        here = (top_e >= first) & (top_e < first + E)
-        zero_pairs = jnp.sum(zero).astype(jnp.int32)
-        away_pairs = jnp.sum(live & ~zero & ~here).astype(jnp.int32)
-        if zero_experts:
-            identity = jnp.sum(jnp.where(zero, top_w, 0.0), axis=-1)
-        top_e = jnp.where(here, top_e - first, E)
-    if valid is not None:           # padding sorts past the last expert
-        top_e = jnp.where(valid[:, None], top_e, E)
-    flat_e = top_e.reshape(-1)
+    with R.region(R.MOE_ROUTE):     # the pairs' counts
+        if zero_experts or E != experts:
+            live = jnp.broadcast_to(
+                True if valid is None else valid[:, None], top_e.shape)
+            zero = live & (top_e >= experts)
+            here = (top_e >= first) & (top_e < first + E)
+            zero_pairs = jnp.sum(zero).astype(jnp.int32)
+            away_pairs = jnp.sum(live & ~zero & ~here).astype(jnp.int32)
+            if zero_experts:
+                identity = jnp.sum(jnp.where(zero, top_w, 0.0), axis=-1)
+            top_e = jnp.where(here, top_e - first, E)
+        if valid is not None:       # padding sorts past the last expert
+            top_e = jnp.where(valid[:, None], top_e, E)
+        flat_e = top_e.reshape(-1)
     order = jnp.argsort(flat_e, stable=True)    # pairs sorted by expert
-    load = jnp.bincount(flat_e, length=E + 1)[:E].astype(jnp.int32)
+    with R.region(R.MOE_ROUTE):
+        load = jnp.bincount(flat_e, length=E + 1)[:E].astype(jnp.int32)
     xs = z[order // top_k]
     if expert_form == "relu2":
         h = jnp.square(jax.nn.relu(grouped_matmul(xs, up_w, load)))
@@ -269,10 +278,12 @@ def dropless_moe_ffn(x: jax.Array, router_w: jax.Array, bias: jax.Array,
     y = ys[jnp.argsort(order)].reshape(T, top_k, d).sum(axis=1)
     if identity is not None:
         y = y + identity[:, None] * z.astype(jnp.float32)
-    return y.astype(z.dtype), {
-        "pairs": jnp.sum(load), "touched": jnp.sum(load > 0).astype(
-            jnp.int32), "load": load, "zero_pairs": zero_pairs,
-        "away_pairs": away_pairs}
+    y = y.astype(z.dtype)
+    with R.region(R.MOE_ROUTE):
+        return y, {
+            "pairs": jnp.sum(load), "touched": jnp.sum(load > 0).astype(
+                jnp.int32), "load": load, "zero_pairs": zero_pairs,
+            "away_pairs": away_pairs}
 
 
 # What a decode step counts over its expert layers, by the names the
@@ -288,6 +299,7 @@ def step_counts(counts: Dict[str, jax.Array]) -> Tuple[jax.Array, ...]:
             counts["zero_pairs"], counts["away_pairs"])
 
 
+@R.region(R.FFN)
 def swiglu(x: jax.Array, gate_w, up_w, down_w) -> jax.Array:
     """The dense feed-forward `(SiLU(x W_gate) * x W_up) W_down`, the
     weights cast to x's dtype."""
@@ -313,12 +325,14 @@ class DenseOrRoutedFFN:
         y, counts = dropless_moe_ffn(
             x, layer["router"], bias, layer["moe_gate"], layer["moe_up"],
             layer["moe_down"], valid=valid, **how)
-        return y + swiglu(x, layer["shared_gate"], layer["shared_up"],
-                          layer["shared_down"]), counts
+        with R.region(R.FFN):       # the shared expert, added
+            return y + swiglu(x, layer["shared_gate"], layer["shared_up"],
+                              layer["shared_down"]), counts
 
     def _block_ffn(self, layer, x, valid=None):
         """x (..., e) + ffn(norm(x)); returns (x, counts)."""
         h = self._norm(x, layer["mlp_norm"])
         y, counts = self._ffn(layer, h.reshape(-1, h.shape[-1]),
                               None if valid is None else valid.reshape(-1))
-        return x + y.reshape(x.shape), counts
+        with R.region(R.FFN):       # the residual addition
+            return x + y.reshape(x.shape), counts

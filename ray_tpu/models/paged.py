@@ -27,6 +27,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import regions as R
 from ray_tpu.models.moe import STEP_COUNTS, step_counts
 from ray_tpu.ops.gated_delta import fold_tail
 from ray_tpu.ops.losses import softmax_cross_entropy
@@ -123,14 +124,25 @@ class PagedDecoder:
                            for i in range(c.n_layers)]}
 
     # --------------------------------------------------------- forward
+    @R.region(R.EMBED)
+    def _embed(self, params: Params, tokens):
+        return params["embed"].astype(self.config.activation_dtype)[tokens]
+
+    @R.region(R.NORM)
     def _norm(self, x, w):
         return rms_norm(x, w, self.config.norm_eps, None)
+
+    @R.region(R.HEAD)
+    def _final_norm(self, params: Params, x):
+        """The stream's last norm: the head's, not a layer's."""
+        return rms_norm(x, params["final_norm"], self.config.norm_eps, None)
 
     def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
         """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
         x = self.hidden(params, tokens)
-        head = params["lm_head"].astype(self.config.activation_dtype)
-        return (x @ head).astype(jnp.float32)
+        with R.region(R.HEAD):
+            head = params["lm_head"].astype(self.config.activation_dtype)
+            return (x @ head).astype(jnp.float32)
 
     def loss(self, params: Params, batch: Dict[str, jax.Array]):
         """Causal LM loss of batch["tokens"] (b, s), as
@@ -139,18 +151,22 @@ class PagedDecoder:
         kernels) runs its plain form in `hidden` (PERF.md section 7)."""
         tokens = batch["tokens"]
         mask = batch.get("loss_mask")
-        logits = self.apply(params, tokens)[:, :-1]
-        if mask is not None:
-            mask = mask[:, 1:]
-        loss, _ = softmax_cross_entropy(logits, tokens[:, 1:], mask=mask)
-        return loss
+        logits = self.apply(params, tokens)
+        with R.region(R.HEAD):
+            logits = logits[:, :-1]
+            if mask is not None:
+                mask = mask[:, 1:]
+            loss, _ = softmax_cross_entropy(logits, tokens[:, 1:],
+                                            mask=mask)
+            return loss
 
+    @R.region(R.HEAD)
     def _logits(self, params: Params, x, true_len=None):
         """The tail of both served programs: the final norm of the stream
         x, of a prefill (`true_len`; x (s, e) or (1, s, e)) the prompt's
         last position alone, through the head, in f32."""
         ad = self.config.activation_dtype
-        x = self._norm(x, params["final_norm"])
+        x = self._final_norm(params, x)
         if true_len is not None:
             x = jnp.take(x[0] if x.ndim == 3 else x, true_len - 1, axis=0)
         return (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
@@ -184,6 +200,7 @@ class PagedDecoder:
 
 
 # ------------------------------------------------- the cache's addresses
+@R.region(R.CACHE)
 def lane_page(page_tables, entry, active, oob: int):
     """The pool page each lane of a decode step writes: entry `entry` (B,)
     of its table; `oob` (dropped) for a lane that is inactive or whose
@@ -192,6 +209,7 @@ def lane_page(page_tables, entry, active, oob: int):
     return jnp.where(active & (page >= 0), page, oob)
 
 
+@R.region(R.CACHE)
 def decode_lanes(positions, page_tables, active, num_pages: int,
                  page_size: int):
     """Where each lane of a decode step writes and how far it sees:
@@ -203,6 +221,7 @@ def decode_lanes(positions, page_tables, active, num_pages: int,
                                                      0)
 
 
+@R.region(R.CACHE)
 def prefill_page_ids(page_table, true_len, s: int, num_pages: int,
                      page_size: int):
     """The pages a padded prompt of `s` positions writes: the table's
@@ -214,6 +233,7 @@ def prefill_page_ids(page_table, true_len, s: int, num_pages: int,
                      num_pages)
 
 
+@R.region(R.CACHE)
 def prefill_page_ids_held(page_table, true_len, s: int, num_pages: int,
                           page_size: int, ring: int = 0,
                           ring_pages: int = 0):
@@ -235,6 +255,7 @@ def prefill_page_ids_held(page_table, true_len, s: int, num_pages: int,
                           ring_pages)
 
 
+@R.region(R.CACHE)
 def prefill_state_slot(page_table, slots: int):
     """The slot a prompt's state is written to: its table's first entry;
     past the pool (`slots + 1`: dropped) where that is no slot of the
@@ -243,6 +264,7 @@ def prefill_state_slot(page_table, slots: int):
     return jnp.where((slot >= 0) & (slot < slots), slot, slots + 1)
 
 
+@R.region(R.CACHE)
 def decode_state_slots(page_tables, active, slots: int):
     """The slot each lane of a decode step updates its state and its
     convolution's tail in: -1 (the step kernels leave both alone) for a
@@ -287,6 +309,7 @@ class StateSlots:
         return self.page_bytes(page_size, tp_shards, dtype)
 
     @staticmethod
+    @R.region(R.MIXER_CORE)
     def _write_slot(pools: Cache, li: int, slot, state, tail) -> Cache:
         """A prefill's state and tail written whole into `slot` of pool
         row `li`, so that a slot reused holds nothing of its last owner.
@@ -319,6 +342,7 @@ class ExpertCounts:
         return [jnp.int32(0)] * len(self.step_count_names)
 
     @staticmethod
+    @R.region(R.MOE_ROUTE)
     def _count_step(sums, counts):
         """One expert layer's `counts` (`dropless_moe_ffn`'s) added to a
         step's running sums."""

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import gqa
+from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
 from ray_tpu.models.paged import (Cache, PagedDecoder, Params, StateSlots,
                                   decode_lanes, decode_state_slots,
@@ -174,11 +175,13 @@ class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
     def ssm_norm_before_gate(self) -> bool:
         return self.config.mamba_norm_before_gate
 
+    @R.region(R.EMBED)
     def _embed(self, params: Params, tokens):
         c = self.config
         return params["embed"].astype(c.activation_dtype)[
             tokens] * c.embedding_multiplier
 
+    @R.region(R.ATTN_IN)
     def _qkv(self, layer: Params, h, positions):
         """h (..., e) normed, `positions` (...) -> q (..., heads, hd), k, v
         (..., kv heads, hd): the key scaled, then q and k rotated."""
@@ -194,10 +197,13 @@ class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
         scaled output after W_o, k, v (b, s, kv heads, hd))."""
         c = self.config
         q, k, v = self._qkv(layer, h, jnp.arange(h.shape[-2]))
-        out = gqa.attend_seq(q, k, v).reshape(*h.shape[:-1], -1)
-        return (out @ layer["wo"].astype(c.activation_dtype)
-                * c.attention_out_multiplier), k, v
+        out = gqa.attend_seq(q, k, v)
+        with R.region(R.ATTN_OUT):
+            out = out.reshape(*h.shape[:-1], -1)
+            return (out @ layer["wo"].astype(c.activation_dtype)
+                    * c.attention_out_multiplier), k, v
 
+    @R.region(R.MIXER_IN)
     def _ssm_in(self, h):
         return h * self.config.ssm_in_multiplier
 
@@ -206,12 +212,14 @@ class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
         feed-forward on the second norm."""
         c = self.config
         ad = c.activation_dtype
-        x = x + ssm * c.ssm_out_multiplier + attn
+        with R.region(R.MIXER_OUT):     # both mixers' residual addition
+            x = x + ssm * c.ssm_out_multiplier + attn
         h = self._norm(x, layer["mlp_norm"])
-        gate = jax.nn.silu(h @ layer["gate"].astype(ad)
-                           * c.mlp_multipliers[0])
-        return x + ((gate * (h @ layer["up"].astype(ad)))
-                    @ layer["down"].astype(ad)) * c.mlp_multipliers[1]
+        with R.region(R.FFN):
+            gate = jax.nn.silu(h @ layer["gate"].astype(ad)
+                               * c.mlp_multipliers[0])
+            return x + ((gate * (h @ layer["up"].astype(ad)))
+                        @ layer["down"].astype(ad)) * c.mlp_multipliers[1]
 
     # --------------------------------------------------------- forward
     def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
@@ -223,11 +231,13 @@ class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
             ssm = jax.vmap(lambda seq: self._ssm_seq(layer, seq)[0])(
                 self._ssm_in(h))
             x = self._close(layer, x, attn, ssm)
-        return self._norm(x, params["final_norm"])
+        return self._final_norm(params, x)
 
+    @R.region(R.HEAD)
     def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
         return super().apply(params, tokens) * self.config.lm_head_multiplier
 
+    @R.region(R.HEAD)
     def _logits(self, params: Params, x, true_len=None):
         return super()._logits(params, x, true_len
                                ) * self.config.lm_head_multiplier
@@ -315,8 +325,10 @@ class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
                 pools, ("k", "v"), li, page, offset, q, k, v, page_tables,
                 lengths)
             pools.update(written)
-            attn = (out.astype(ad).reshape(B, -1) @ layer["wo"].astype(ad)
-                    * c.attention_out_multiplier)
+            with R.region(R.ATTN_OUT):
+                attn = (out.astype(ad).reshape(B, -1)
+                        @ layer["wo"].astype(ad)
+                        * c.attention_out_multiplier)
             ssm, written = self._ssm_step(layer, self._ssm_in(h), pools, li,
                                           slot)
             pools.update(written)

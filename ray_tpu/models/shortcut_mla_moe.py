@@ -56,6 +56,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import regions as R
 from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
 from ray_tpu.models.moe import SCORING, dropless_moe_ffn, swiglu
 from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
@@ -191,29 +192,37 @@ class ShortcutMLAMoE(LatentAttention, ExpertCounts, PagedDecoder):
         ad = self.config.activation_dtype
         a0, a1 = layer["attn"]
         f0, f1 = layer["ffn"]
-        h = x + attend(0, self._norm(x, a0["attn_norm"])) @ a0["wo"].astype(
-            ad)
+        out = attend(0, self._norm(x, a0["attn_norm"]))
+        with R.region(R.ATTN_OUT):
+            h = x + out @ a0["wo"].astype(ad)
         u = self._norm(h, f0["mlp_norm"])
         m, counts = self._experts(layer, u, valid)
-        h = h + self._dense(f0, u)
-        h = h + attend(1, self._norm(h, a1["attn_norm"])) @ a1["wo"].astype(
-            ad)
-        h = h + self._dense(f1, self._norm(h, f1["mlp_norm"]))
-        return h + m, counts
+        with R.region(R.FFN):
+            h = h + self._dense(f0, u)
+        out = attend(1, self._norm(h, a1["attn_norm"]))
+        with R.region(R.ATTN_OUT):
+            h = h + out @ a1["wo"].astype(ad)
+        u = self._norm(h, f1["mlp_norm"])
+        with R.region(R.FFN):
+            h = h + self._dense(f1, u)
+        with R.region(R.MOE_EXPERTS):       # the shortcut branch joins
+            return h + m, counts
 
     # --------------------------------------------------------- forward
     def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
         """tokens (b, s) -> hidden states after the final norm."""
         c = self.config
         b, s = tokens.shape
-        x = params["embed"].astype(c.activation_dtype)[tokens]
-        cos, sin = rope_cos_sin(jnp.broadcast_to(jnp.arange(s), (b, s)),
-                                c.qk_rope_head_dim, c.rope_theta)
+        x = self._embed(params, tokens)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(
+                jnp.broadcast_to(jnp.arange(s), (b, s)),
+                c.qk_rope_head_dim, c.rope_theta)
         for layer in params["layers"]:
             x, _ = self._layer(
                 layer, x, lambda j, h, layer=layer: self._attn_expanded(
                     layer["attn"][j], h, cos, sin)[0])
-        return self._norm(x, params["final_norm"])
+        return self._final_norm(params, x)
 
     # ------------------------------------------------ what an engine asks
     @property
@@ -240,13 +249,14 @@ class ShortcutMLAMoE(LatentAttention, ExpertCounts, PagedDecoder):
         pool rows written as whole pages in place. Padding past `true_len`
         is given to no expert and adds no identity part."""
         c = self.config
-        ad = c.activation_dtype
         pool = cache["kv"]
         s = tokens.shape[0]
-        x = params["embed"].astype(ad)[tokens][None]            # (1, s, e)
-        cos, sin = rope_cos_sin(jnp.arange(s)[None], c.qk_rope_head_dim,
-                                c.rope_theta)
-        valid = (jnp.arange(s) < true_len)[None]
+        x = self._embed(params, tokens)[None]                   # (1, s, e)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(jnp.arange(s)[None],
+                                    c.qk_rope_head_dim, c.rope_theta)
+        with R.region(R.CACHE):
+            valid = (jnp.arange(s) < true_len)[None]
         page_ids = prefill_page_ids(page_table, true_len, s, pool.shape[1],
                                     page_size)
         for i, layer in enumerate(params["layers"]):
@@ -268,11 +278,11 @@ class ShortcutMLAMoE(LatentAttention, ExpertCounts, PagedDecoder):
         Inactive lanes write nothing, are given to no expert and add no
         identity part."""
         c = self.config
-        ad = c.activation_dtype
         pool = cache["kv"]
-        x = params["embed"].astype(ad)[tokens]                  # (B, e)
-        cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
-                                c.rope_theta)              # (B, 1, rope/2)
+        x = self._embed(params, tokens)                         # (B, e)
+        with R.region(R.ATTN_IN):
+            cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
+                                    c.rope_theta)          # (B, 1, rope/2)
         wr_page, wr_slot, lengths = decode_lanes(
             positions, page_tables, active, pool.shape[1], page_size)
         load, sums = cache["moe_load"], self._step_sums()
@@ -284,7 +294,8 @@ class ShortcutMLAMoE(LatentAttention, ExpertCounts, PagedDecoder):
                     wr_slot, page_tables, lengths)
                 return out
             x, counts = self._layer(layer, x, attend, active)
-            load = load.at[i].add(counts["load"])
+            with R.region(R.MOE_ROUTE):
+                load = load.at[i].add(counts["load"])
             sums = self._count_step(sums, counts)
         return self._logits(params, x), {"kv": pool,
                                          **self._counted(load, sums)}

@@ -37,6 +37,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import regions as R
 from ray_tpu.models.paged import Cache, Params
 from ray_tpu.ops import gated_delta as _gd
 from ray_tpu.ops import ssd as _ssd
@@ -86,6 +87,7 @@ class SSMMixer:
                 "d": ((H,), 0.0), "gate_norm": ((c.ssm_inner,), 0.0),
                 "w_out": ((c.ssm_inner, c.d_model), out_std)}
 
+    @R.region(R.MIXER_IN)
     def _ssm_project(self, layer: Params, u):
         """u (n, e) -> (z (n, H x P), xBC (n, channels) before the
         convolution, dt (n, H) before the softplus)."""
@@ -96,6 +98,7 @@ class SSMMixer:
         return jnp.split(proj, [c.ssm_inner, c.ssm_inner + c.conv_channels],
                          axis=-1)
 
+    @R.region(R.MIXER_IN)
     def _ssm_inputs(self, layer: Params, mixed, dt):
         """What the scan takes: x (n, H x P), B, C (n, G x N) of the
         convolved channels `mixed`, dt (n, H) and A (H,) float32."""
@@ -108,6 +111,7 @@ class SSMMixer:
         return x, Bm, Cm, dt, jnp.exp(c.a_log_init
                                       + layer["a_log"].astype(f32))
 
+    @R.region(R.MIXER_OUT)
     def _ssm_out(self, layer: Params, y, x, z):
         """The scan's y (n, H x P): the skip `D x` added, gated by SiLU(z)
         and normed a group's channels (in the class's order), through
@@ -139,19 +143,22 @@ class SSMMixer:
         c = self.config
         s = u.shape[0]
         z, xbc, dt = self._ssm_project(layer, u)
-        mixed, tail = causal_conv(xbc, layer["conv"], true_len,
-                                  layer["conv_bias"])
-        x, Bm, Cm, dt, A = self._ssm_inputs(layer, mixed, dt)
-        pad = -s % c.chunk                  # whole chunks; padding is inert
-        xp, Bp, Cp, dtp = (jnp.pad(a, ((0, pad), (0, 0)))
-                           for a in (x, Bm, Cm, dt))
-        if true_len is None:
-            y, state = _ssd.ssd_chunked(xp, Bp, Cp, dtp, A, c.ssm_groups,
-                                        chunk=c.chunk)
-        else:
-            y, state = _ssd.ssd_prefill(xp, Bp, Cp, dtp, A, true_len,
-                                        c.ssm_groups, c.chunk)
-        return self._ssm_out(layer, y[:s], x, z), state, tail
+        with R.region(R.MIXER_IN):
+            mixed, tail = causal_conv(xbc, layer["conv"], true_len,
+                                      layer["conv_bias"])
+            x, Bm, Cm, dt, A = self._ssm_inputs(layer, mixed, dt)
+            pad = -s % c.chunk              # whole chunks; padding is inert
+            xp, Bp, Cp, dtp = (jnp.pad(a, ((0, pad), (0, 0)))
+                               for a in (x, Bm, Cm, dt))
+        with R.region(R.MIXER_CORE):
+            if true_len is None:
+                y, state = _ssd.ssd_chunked(xp, Bp, Cp, dtp, A,
+                                            c.ssm_groups, chunk=c.chunk)
+            else:
+                y, state = _ssd.ssd_prefill(xp, Bp, Cp, dtp, A, true_len,
+                                            c.ssm_groups, c.chunk)
+            y = y[:s]
+        return self._ssm_out(layer, y, x, z), state, tail
 
     def _ssm_step(self, layer: Params, u, pools: Cache, li: int, slot):
         """One decode position a lane, u (B, e): the tails and states at
@@ -160,11 +167,14 @@ class SSMMixer:
         pools)."""
         c = self.config
         z, xbc, dt = self._ssm_project(layer, u)
-        conv, tail = _gd.conv_tail_step(
-            xbc, layer["conv"], pools["tail"], li, slot, layer["conv_bias"])
+        with R.region(R.MIXER_IN):
+            conv, tail = _gd.conv_tail_step(
+                xbc, layer["conv"], pools["tail"], li, slot,
+                layer["conv_bias"])
         xs, Bm, Cm, dt, A = self._ssm_inputs(layer, conv, dt)
-        y, state = _ssd.ssd_step(xs, Bm, Cm, dt, A, pools["state"], li,
-                                 slot, c.ssm_groups)
+        with R.region(R.MIXER_CORE):
+            y, state = _ssd.ssd_step(xs, Bm, Cm, dt, A, pools["state"], li,
+                                     slot, c.ssm_groups)
         return self._ssm_out(layer, y, xs, z), {"tail": tail, "state": state}
 
     def ssm_layer_bytes(self, dtype=None) -> int:

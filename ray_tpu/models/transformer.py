@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
+
+from ray_tpu.models import regions as R
 from jax.sharding import Mesh
 
 from ray_tpu.models.config import TransformerConfig
@@ -235,6 +237,7 @@ class Transformer(PagedDecoder):
             return ()
         return REMAT_SAVED_NAMES[self.remat_plan(batch_tokens)[0]]
 
+    @R.region(R.ATTN_CORE)
     def _attention(self, q, k, v, saved=()):
         """Causal attention for one layer. A policy that keeps the
         attention output (`saved` holds its name) spares the backward a
@@ -255,8 +258,15 @@ class Transformer(PagedDecoder):
                                block_k=c.attn_block_k,
                                mesh=self.kernel_mesh)
 
+    @R.region(R.NORM)
     def _norm(self, x, w):
         return rms_norm(x, w, self.config.norm_eps, self.kernel_mesh)
+
+    @R.region(R.HEAD)
+    def _final_norm(self, params: Params, x):
+        """The stream's last norm: the head's, not a layer's."""
+        return rms_norm(x, params["final_norm"], self.config.norm_eps,
+                        self.kernel_mesh)
 
     def _constrain(self, x, axes):
         if self.mesh is None:
@@ -264,6 +274,7 @@ class Transformer(PagedDecoder):
         return with_logical_constraint(x, axes, mesh=self.mesh,
                                        rules=_rules())
 
+    @R.region(R.EMBED)
     def _embed_lookup(self, table, tokens):
         """Token embedding. With the table sharded (vocab->tp,
         embed->fsdp) a gather forces SPMD involuntary full
@@ -289,24 +300,26 @@ class Transformer(PagedDecoder):
         hd = c.head_dim
 
         h = self._norm(x, layer["attn_norm"])
-        q = (h @ layer["wq"].astype(ad)).reshape(b, s, c.n_heads, hd)
-        k = (h @ layer["wk"].astype(ad)).reshape(b, s, c.kv_heads, hd)
-        v = (h @ layer["wv"].astype(ad)).reshape(b, s, c.kv_heads, hd)
-        from ray_tpu.ops.rope import apply_rope_cached
-        cos, sin = rope
-        q = apply_rope_cached(q, cos, sin)
-        k = apply_rope_cached(k, cos, sin)
-        q = q.transpose(0, 2, 1, 3)   # (b, h, s, hd)
-        k = k.transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
-        q = self._constrain(q, ("batch", "heads", "seq", "head_dim"))
-        q, k, v = (checkpoint_name(a, name)
-                   for a, name in zip((q, k, v), ATTN_INPUT_NAMES))
+        with R.region(R.ATTN_IN):
+            q = (h @ layer["wq"].astype(ad)).reshape(b, s, c.n_heads, hd)
+            k = (h @ layer["wk"].astype(ad)).reshape(b, s, c.kv_heads, hd)
+            v = (h @ layer["wv"].astype(ad)).reshape(b, s, c.kv_heads, hd)
+            from ray_tpu.ops.rope import apply_rope_cached
+            cos, sin = rope
+            q = apply_rope_cached(q, cos, sin)
+            k = apply_rope_cached(k, cos, sin)
+            q = q.transpose(0, 2, 1, 3)   # (b, h, s, hd)
+            k = k.transpose(0, 2, 1, 3)
+            v = v.transpose(0, 2, 1, 3)
+            q = self._constrain(q, ("batch", "heads", "seq", "head_dim"))
+            q, k, v = (checkpoint_name(a, name)
+                       for a, name in zip((q, k, v), ATTN_INPUT_NAMES))
         attn = self._attention(q, k, v, saved)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, c.n_heads * hd)
-        x = x + attn @ layer["wo"].astype(ad)
-        x = self._constrain(x, ("batch", "seq", "act_embed"))
-        x = checkpoint_name(x, ATTN_STREAM_NAME)
+        with R.region(R.ATTN_OUT):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, c.n_heads * hd)
+            x = x + attn @ layer["wo"].astype(ad)
+            x = self._constrain(x, ("batch", "seq", "act_embed"))
+            x = checkpoint_name(x, ATTN_STREAM_NAME)
 
         h = self._norm(x, layer["mlp_norm"])
         if c.moe_num_experts:
@@ -317,16 +330,19 @@ class Transformer(PagedDecoder):
                 capacity_factor=c.moe_capacity_factor,
                 constrain=(None if self.mesh is None else
                            lambda a, ax: self._constrain(a, ax)))
-            x = x + y
+            with R.region(R.MOE_EXPERTS):
+                x = x + y
+                return (self._constrain(x, ("batch", "seq", "act_embed")),
+                        aux["moe_load_balance_loss"])
+        with R.region(R.FFN):
+            gate = checkpoint_name(h @ layer["gate"].astype(ad),
+                                   MLP_NAMES[0])
+            up = checkpoint_name(h @ layer["up"].astype(ad), MLP_NAMES[1])
+            mlp = self._constrain(jax.nn.silu(gate) * up,
+                                  ("batch", "seq", "mlp"))
+            x = x + mlp @ layer["down"].astype(ad)
             return (self._constrain(x, ("batch", "seq", "act_embed")),
-                    aux["moe_load_balance_loss"])
-        gate = checkpoint_name(h @ layer["gate"].astype(ad), MLP_NAMES[0])
-        up = checkpoint_name(h @ layer["up"].astype(ad), MLP_NAMES[1])
-        mlp = self._constrain(jax.nn.silu(gate) * up,
-                              ("batch", "seq", "mlp"))
-        x = x + mlp @ layer["down"].astype(ad)
-        return (self._constrain(x, ("batch", "seq", "act_embed")),
-                jnp.float32(0.0))
+                    jnp.float32(0.0))
 
     def hidden(self, params: Params, tokens: jax.Array,
                positions: Optional[jax.Array] = None) -> jax.Array:
@@ -347,13 +363,15 @@ class Transformer(PagedDecoder):
         custom_positions = positions is not None
         if positions is None:
             positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-        x = self._embed_lookup(params["embed"].astype(ad), tokens)
-        x = self._constrain(x, ("batch", "seq", "act_embed"))
+        with R.region(R.EMBED):
+            x = self._embed_lookup(params["embed"].astype(ad), tokens)
+            x = self._constrain(x, ("batch", "seq", "act_embed"))
 
         # cos/sin computed once; identical for every layer and cheap to
         # hold across remat (transcendentals dominate their recompute).
         from ray_tpu.ops.rope import rope_cos_sin
-        rope = rope_cos_sin(positions, c.head_dim, c.rope_theta)
+        with R.region(R.ATTN_IN):
+            rope = rope_cos_sin(positions, c.head_dim, c.rope_theta)
 
         saved = self._saved_names(b * s)
         remat_policy = (jax.checkpoint_policies.save_only_these_names(*saved)
@@ -396,7 +414,7 @@ class Transformer(PagedDecoder):
 
             x = pipeline_apply(self.mesh, stage, params["layers"], x,
                                c.pipeline_microbatches, consts=rope)
-            return (self._norm(x, params["final_norm"]),
+            return (self._final_norm(params, x),
                     jnp.float32(0.0))
 
         def body(carry, layer):
@@ -407,7 +425,7 @@ class Transformer(PagedDecoder):
         (x, moe_aux), _ = lax.scan(_checkpointed(body),
                                    (x, jnp.float32(0.0)),
                                    params["layers"])
-        return self._norm(x, params["final_norm"]), moe_aux
+        return self._final_norm(params, x), moe_aux
 
     def _head(self, params: Params) -> jax.Array:
         return (params["embed"].T if self.config.tie_embeddings
@@ -418,9 +436,10 @@ class Transformer(PagedDecoder):
         """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
         c = self.config
         x = self.hidden(params, tokens, positions)
-        logits = x @ self._head(params).astype(c.activation_dtype)
-        logits = self._constrain(logits, ("batch", "seq", "vocab"))
-        return logits.astype(jnp.float32)
+        with R.region(R.HEAD):
+            logits = x @ self._head(params).astype(c.activation_dtype)
+            logits = self._constrain(logits, ("batch", "seq", "vocab"))
+            return logits.astype(jnp.float32)
 
     # ------------------------------------------- what an engine asks
     # The serving engine asks a model for its cache and its two programs
@@ -479,20 +498,24 @@ class Transformer(PagedDecoder):
             from ray_tpu.ops.losses import chunked_lm_loss
             b, s = tokens.shape
             x, aux = self.hidden_and_aux(params, tokens)
-            labels = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
-            m = (jnp.ones((b, s), jnp.float32) if mask is None
-                 else mask.astype(jnp.float32))
-            m = jnp.concatenate([m[:, 1:], jnp.zeros((b, 1))], axis=1)
-            head = self._head(params).astype(c.activation_dtype)
-            return chunked_lm_loss(x, head, labels, m,
-                                   chunk_size=c.loss_chunk) + moe_term(aux)
+            with R.region(R.HEAD):
+                labels = jnp.concatenate([tokens[:, 1:], tokens[:, :1]],
+                                         axis=1)
+                m = (jnp.ones((b, s), jnp.float32) if mask is None
+                     else mask.astype(jnp.float32))
+                m = jnp.concatenate([m[:, 1:], jnp.zeros((b, 1))], axis=1)
+                head = self._head(params).astype(c.activation_dtype)
+                return chunked_lm_loss(
+                    x, head, labels, m,
+                    chunk_size=c.loss_chunk) + moe_term(aux)
         x, aux = self.hidden_and_aux(params, tokens)
-        logits = x @ self._head(params).astype(c.activation_dtype)
-        logits = self._constrain(logits,
-                                 ("batch", "seq", "vocab"))
-        logits = logits.astype(jnp.float32)[:, :-1]
-        labels = tokens[:, 1:]
-        if mask is not None:
-            mask = mask[:, 1:]
-        loss, _ = softmax_cross_entropy(logits, labels, mask=mask)
-        return loss + moe_term(aux)
+        with R.region(R.HEAD):
+            logits = x @ self._head(params).astype(c.activation_dtype)
+            logits = self._constrain(logits,
+                                     ("batch", "seq", "vocab"))
+            logits = logits.astype(jnp.float32)[:, :-1]
+            labels = tokens[:, 1:]
+            if mask is not None:
+                mask = mask[:, 1:]
+            loss, _ = softmax_cross_entropy(logits, labels, mask=mask)
+            return loss + moe_term(aux)

@@ -53,18 +53,17 @@ DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 # instruction: with `name=` that is the kernel's own name, where it used
 # to be the enclosing call's (`closed_call`, `checkpoint`,
 # `rematted_computation`). A transform wraps the first scope opened
-# under it (`jvp(flash_fwd)` compiles to `jvp_flash_fwd_`), so each call
-# sits in a scope of its own that takes the wrapping instead. The
-# benchmark's roofline metrics find the kernels by these names; a
-# forward recomputed under remat is still KERNEL_FWD.
+# under it (`jvp(flash_fwd)` compiles to `jvp_flash_fwd_`): the caller's
+# region (`models/regions.py`) is that scope and takes the wrapping, and
+# the region a kernel lies in is its caller's to say. The benchmark's
+# roofline metrics find the kernels by these names; a forward recomputed
+# under remat is still KERNEL_FWD.
 KERNEL_FWD = "flash_fwd"
 KERNEL_BWD_DKDV = "flash_bwd_dkdv"
 # (the whole backward, dQ too, since PR 55: the name is the benchmark's)
-KERNEL_SCOPE = "flash_attention"
 # the forward with a sliding window (forward only: serving's prefill), under
 # a name of its own that a reader looking for KERNEL_FWD does not match
 KERNEL_WINDOW_FWD = "flash_window_fwd"
-KERNEL_WINDOW_SCOPE = "flash_window_attention"
 
 
 # ------------------------------------------------------------- reference
@@ -238,8 +237,7 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         interpret=interpret,
         name=KERNEL_FWD,
     )
-    with jax.named_scope(KERNEL_SCOPE):
-        out, lse = call(q, k, v)
+    out, lse = call(q, k, v)
     return out, lse[:, :, 0, :]
 
 
@@ -300,8 +298,7 @@ def _flash_window_fwd(q, k, v, sm_scale, block_q, block_k, interpret,
         interpret=interpret,
         name=KERNEL_WINDOW_FWD,
     )
-    with jax.named_scope(KERNEL_WINDOW_SCOPE):
-        out, lse = call(q, k, v)
+    out, lse = call(q, k, v)
     return out, lse[:, :, 0, :]
 
 
@@ -507,8 +504,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, causal, sm_scale,
         interpret=interpret,
         name=KERNEL_BWD_DKDV,
     )
-    with jax.named_scope(KERNEL_SCOPE):
-        dk, dv, dq = call(q, k, v, do, lse8, dlt8)
+    dk, dv, dq = call(q, k, v, do, lse8, dlt8)
     if group > 1:
         dk = dk.reshape(b, kvh, group, sk, d).sum(axis=2).astype(k.dtype)
         dv = dv.reshape(b, kvh, group, sk, d).sum(axis=2).astype(v.dtype)

@@ -62,9 +62,7 @@ from ray_tpu.ops.exact import F32, HIGHEST, dot as _dot
 
 # The kernels' names on the device's clock (see attention.KERNEL_FWD).
 KERNEL_CHUNK = "gated_delta_chunk_fwd"
-KERNEL_CHUNK_SCOPE = "gated_delta_chunk"
 KERNEL_STEP = "gated_delta_step"
-KERNEL_STEP_SCOPE = "gated_delta_step_scope"
 
 CHUNK = 64              # positions a chunk: the family's convention
 SOLVE_BLOCK = 16        # rows solved by substitution before blocks join
@@ -386,10 +384,9 @@ def _chunk_call(q, k, v, g, beta, true_len, chunk: int, interpret: bool):
         interpret=interpret,
         name=KERNEL_CHUNK,
     )
-    with jax.named_scope(KERNEL_CHUNK_SCOPE):
-        o, state = call(jnp.asarray(true_len, jnp.int32).reshape(1),
-                        q, k, v, G[..., None], G[:, :, None, :], beta,
-                        k_end, s_end)
+    o, state = call(jnp.asarray(true_len, jnp.int32).reshape(1),
+                    q, k, v, G[..., None], G[:, :, None, :], beta,
+                    k_end, s_end)
     return o, state
 
 
@@ -538,13 +535,12 @@ def _step_call(q, k, v, g, beta, pool, layer, slots, cols: int,
         interpret=interpret,
         name=KERNEL_STEP,
     )
-    with jax.named_scope(KERNEL_STEP_SCOPE):
-        o, pool = call(
-            jnp.asarray(layer, jnp.int32).reshape(1),
-            jnp.where(slots < trash, slots, -1).astype(jnp.int32),
-            transposed(q), transposed(k),
-            v.astype(F32).reshape(B, 1, width), spread(jnp.exp(g)),
-            spread(beta), pool)
+    o, pool = call(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.where(slots < trash, slots, -1).astype(jnp.int32),
+        transposed(q), transposed(k),
+        v.astype(F32).reshape(B, 1, width), spread(jnp.exp(g)),
+        spread(beta), pool)
     return o.reshape(B, H, dv), pool
 
 

@@ -32,7 +32,6 @@ from ray_tpu.ops.dispatch import on_tpu
 
 # The kernel's name on the device's clock (see attention.KERNEL_FWD).
 KERNEL_GMM = "moe_gmm"
-KERNEL_GMM_SCOPE = "grouped_matmul"
 
 # The most rows a tile holds: under the rows the MXU multiplies in the time
 # a group's block takes to arrive (197 TFLOP/s over 819 GB/s is 240 rows of
@@ -155,8 +154,7 @@ def _gmm_call(lhs, rhs, group_sizes, interpret: bool):
         interpret=interpret,
         name=KERNEL_GMM,
     )
-    with jax.named_scope(KERNEL_GMM_SCOPE):
-        out = call(group, tile, starts, ends, count, lhs, rhs)
+    out = call(group, tile, starts, ends, count, lhs, rhs)
     # a tile past the last group's rows is never visited, and holds
     # whatever the buffer held
     live = jnp.arange(m)[:, None] < ends[-1]

@@ -65,9 +65,7 @@ from ray_tpu.ops.gated_delta import (CHUNK, SOLVE_BLOCK, chunk_heads,
 
 # The kernels' names on the device's clock (see attention.KERNEL_FWD).
 KERNEL_CHUNK = "kda_chunk_fwd"
-KERNEL_CHUNK_SCOPE = "kda_chunk"
 KERNEL_STEP = "kda_step"
-KERNEL_STEP_SCOPE = "kda_step_scope"
 
 # the largest exponent the chunk kernel's factors take: half a sub-block of
 # positions at the lower bound of g must stay under it (`lower_bound_fits`)
@@ -280,9 +278,8 @@ def _chunk_call(q, k, v, g, beta, true_len, chunk: int, interpret: bool):
         interpret=interpret,
         name=KERNEL_CHUNK,
     )
-    with jax.named_scope(KERNEL_CHUNK_SCOPE):
-        o, state = call(jnp.asarray(true_len, jnp.int32).reshape(1),
-                        q, k, v, G, beta)
+    o, state = call(jnp.asarray(true_len, jnp.int32).reshape(1),
+                    q, k, v, G, beta)
     return o, state.swapaxes(1, 2)
 
 
@@ -395,13 +392,12 @@ def _step_call(q, k, v, g, beta, pool, layer, slots, cols: int,
         interpret=interpret,
         name=KERNEL_STEP,
     )
-    with jax.named_scope(KERNEL_STEP_SCOPE):
-        o, pool = call(
-            jnp.asarray(layer, jnp.int32).reshape(1),
-            jnp.where(slots < trash, slots, -1).astype(jnp.int32),
-            transposed(q), transposed(k),
-            transposed(jnp.exp(g.astype(F32))),
-            v.astype(F32).reshape(B, 1, width), spread(beta), pool)
+    o, pool = call(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.where(slots < trash, slots, -1).astype(jnp.int32),
+        transposed(q), transposed(k),
+        transposed(jnp.exp(g.astype(F32))),
+        v.astype(F32).reshape(B, 1, width), spread(beta), pool)
     return o.reshape(B, H, dv), pool
 
 

@@ -25,10 +25,8 @@ _BLOCK_ROWS = 256
 # the most numbers a block holds: 256 rows up to 4,096 wide; a wider row
 # gets fewer (160 at 6,144, where 256 rows pass the kernel's 16 MB of VMEM)
 _BLOCK_NUMBERS = _BLOCK_ROWS * 4096
-# its name in a device trace, and the scope that keeps a transform's
-# wrapping off it (see ops/attention.py, KERNEL_FWD)
+# its name in a device trace (see ops/attention.py, KERNEL_FWD)
 KERNEL_RMS_FWD = "rms_norm_fwd"
-KERNEL_SCOPE = "rms_norm"
 
 
 # ---------------------------------------------------------------- rmsnorm
@@ -68,8 +66,7 @@ def _rms_fwd_pallas(x2d: jax.Array, w: jax.Array, eps: float) -> jax.Array:
         interpret=not on_tpu(),
         name=KERNEL_RMS_FWD,
     )
-    with jax.named_scope(KERNEL_SCOPE):
-        return call(x2d, w)
+    return call(x2d, w)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))

@@ -55,7 +55,6 @@ from ray_tpu.ops.dispatch import on_tpu, shard_kernel
 
 # The kernel's name on the device's clock (see attention.KERNEL_FWD).
 KERNEL_PAGED_DECODE = "paged_decode_attn"
-KERNEL_PAGED_SCOPE = "paged_decode_attention"
 
 # Blocks in VMEM at once: one attended to, the next on its way, be that the
 # lane's next or the next lane's first (more bought nothing on a v5e: a
@@ -428,7 +427,7 @@ def _softmax_update(q, k, v, seen, sm_scale: float, acc, m, l):
     l[:] = jnp.broadcast_to(l_new, l.shape)
 
 
-def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
+def _paged_pallas_call(kernel, name: str, q, pools, layer,
                        page_tables, lengths, *, out_width: int, sems: tuple,
                        interpret: bool, **walk):
     """The `pallas_call` around `_walk_pages`: a grid over the lanes; the
@@ -478,10 +477,9 @@ def _paged_pallas_call(kernel, name: str, scope: str, q, pools, layer,
         interpret=interpret,
         name=name,
     )
-    with jax.named_scope(scope):
-        return call(jnp.asarray(layer, jnp.int32).reshape(1),
-                    lengths.astype(jnp.int32),
-                    page_tables.astype(jnp.int32).reshape(-1), q, *pools)
+    return call(jnp.asarray(layer, jnp.int32).reshape(1),
+                lengths.astype(jnp.int32),
+                page_tables.astype(jnp.int32).reshape(-1), q, *pools)
 
 
 # ---------------------------------------------------------------- kernel
@@ -558,9 +556,9 @@ def _paged_decode_call(qg, k_pool, v_pool, layer, page_tables, lengths,
     kernel = functools.partial(_paged_decode_kernel,
                                sm_scale=1.0 / math.sqrt(hd))
     return _paged_pallas_call(
-        kernel, KERNEL_PAGED_DECODE, KERNEL_PAGED_SCOPE, qg,
-        (k_pool, v_pool), layer, page_tables, lengths,
-        out_width=hd, sems=(BLOCK_SLOTS, 2), interpret=interpret)
+        kernel, KERNEL_PAGED_DECODE, qg, (k_pool, v_pool), layer,
+        page_tables, lengths, out_width=hd, sems=(BLOCK_SLOTS, 2),
+        interpret=interpret)
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, page_tables, lengths,
@@ -593,7 +591,6 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, layer, page_tables,
 
 # ------------------------------------------ a sliding window over a ring
 KERNEL_PAGED_WINDOW_DECODE = "paged_window_decode_attn"
-KERNEL_PAGED_WINDOW_SCOPE = "paged_window_decode_attention"
 
 
 def ring_pages(window: int, page_size: int) -> int:
@@ -653,7 +650,7 @@ def _paged_window_decode_call(q, k_pool, v_pool, layer, ring_tables,
     kernel = functools.partial(_paged_decode_kernel,
                                sm_scale=1.0 / math.sqrt(hd))
     out = _paged_pallas_call(
-        kernel, KERNEL_PAGED_WINDOW_DECODE, KERNEL_PAGED_WINDOW_SCOPE,
+        kernel, KERNEL_PAGED_WINDOW_DECODE,
         q.reshape(B, kvh, n_heads // kvh, hd), (k_pool, v_pool), layer,
         ring_tables, lengths, out_width=hd, sems=(BLOCK_SLOTS, 2),
         interpret=interpret, window=window)
@@ -690,7 +687,6 @@ def paged_window_decode_attention_kernel(q, k_pool, v_pool, layer,
 # caller multiplies by W_UV afterwards). All heads share the rows: one
 # matmul's rows are the heads.
 KERNEL_MLA_PAGED_DECODE = "mla_paged_decode_attn"
-KERNEL_MLA_PAGED_SCOPE = "mla_paged_decode_attention"
 
 
 def mla_paged_decode_tiles(width: int, latent: int, page_size: int,
@@ -765,9 +761,8 @@ def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
     kernel = functools.partial(_mla_paged_decode_kernel, sm_scale=sm_scale,
                                latent=latent)
     out = _paged_pallas_call(
-        kernel, KERNEL_MLA_PAGED_DECODE, KERNEL_MLA_PAGED_SCOPE, qp,
-        (pool,), layer, page_tables, lengths, out_width=latent,
-        sems=(BLOCK_SLOTS,), interpret=interpret)
+        kernel, KERNEL_MLA_PAGED_DECODE, qp, (pool,), layer, page_tables,
+        lengths, out_width=latent, sems=(BLOCK_SLOTS,), interpret=interpret)
     return out[:, :heads]
 
 

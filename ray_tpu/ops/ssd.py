@@ -64,9 +64,7 @@ from ray_tpu.ops.exact import F32, HIGHEST, dot as _dot
 
 # The kernels' names on the device's clock (see attention.KERNEL_FWD).
 KERNEL_CHUNK = "ssd_chunk_fwd"
-KERNEL_CHUNK_SCOPE = "ssd_chunk"
 KERNEL_STEP = "ssd_step"
-KERNEL_STEP_SCOPE = "ssd_step_scope"
 
 CHUNK = 128             # positions a chunk: the family's `chunk_size`
 # bytes of state a grid step of the step kernel holds (in, out, and the
@@ -280,9 +278,8 @@ def _chunk_call(x, Bm, Cm, dt, A, true_len, heads: int, groups: int,
         interpret=interpret,
         name=KERNEL_CHUNK,
     )
-    with jax.named_scope(KERNEL_CHUNK_SCOPE):
-        y, state = call(jnp.asarray(true_len, jnp.int32).reshape(1),
-                        x, Bm, Cm, Lc, Lr, dtc, dtr)
+    y, state = call(jnp.asarray(true_len, jnp.int32).reshape(1),
+                    x, Bm, Cm, Lc, Lr, dtc, dtr)
     return y, state
 
 
@@ -442,13 +439,12 @@ def _step_call(x, Bm, Cm, dt, A, pool, layer, slots, groups: int, cols: int,
         interpret=interpret,
         name=KERNEL_STEP,
     )
-    with jax.named_scope(KERNEL_STEP_SCOPE):
-        y, pool = call(
-            jnp.asarray(layer, jnp.int32).reshape(1),
-            jnp.where(slots < trash, slots, -1).astype(jnp.int32),
-            transposed(Bm), transposed(Cm),
-            spread(dt) * x.astype(F32)[:, None, :],
-            spread(jnp.exp(-A.astype(F32) * dt)), pool)
+    y, pool = call(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.where(slots < trash, slots, -1).astype(jnp.int32),
+        transposed(Bm), transposed(Cm),
+        spread(dt) * x.astype(F32)[:, None, :],
+        spread(jnp.exp(-A.astype(F32) * dt)), pool)
     return y.reshape(nb, width), pool
 
 

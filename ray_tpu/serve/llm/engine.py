@@ -320,6 +320,7 @@ class EngineCore:
         """The jitted wrappers (built by the calls that first dispatch
         them) and what the host needs to count a dispatch."""
         import jax
+        from ray_tpu.models.regions import SAMPLE, region
         # both programs update the pool in place: the cache argument is
         # donated (whoever holds the old one holds a deleted buffer), and
         # on a mesh every step hands the cache back as it lay, whatever
@@ -343,12 +344,14 @@ class EngineCore:
         def _next(logits, counts):
             # the step's counts are copied out of the cache before the
             # next step is given it
-            return (logits.argmax(axis=-1).astype("int32"),
-                    jax.tree.map(lambda n: n.copy(), counts))
+            with region(SAMPLE):
+                return (logits.argmax(axis=-1).astype("int32"),
+                        jax.tree.map(lambda n: n.copy(), counts))
 
         def _place(tokens, lane, logits):
-            first = logits.argmax().astype("int32")
-            return tokens.at[lane].set(first), first
+            with region(SAMPLE):
+                first = logits.argmax().astype("int32")
+                return tokens.at[lane].set(first), first
         self._next_fn = small(_next)
         self._place_fn = small(_place)      # a prefill's token to its lane
         self._tokens = jax.device_put(

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from ray_tpu.models import regions
 from ray_tpu.ops import attention, norms, paged_attention
 from ray_tpu.ops.dispatch import compute_platform
 
@@ -58,8 +59,14 @@ def compiled_text(topo, no_compile_cache):
 
     def loss(q, k, v, x, w):
         def layer(q, k, v, x):
-            a = attention.flash_attention_saveable(q, k, v, causal=True)
-            return a.astype(jnp.float32).sum() * norms.rms_norm(x, w)
+            # each kernel in its caller's region, as the models call them:
+            # a transform wraps the first scope under it, and a kernel
+            # called bare would take the wrapping (`jvp_flash_fwd_`)
+            with regions.region(regions.ATTN_CORE):
+                a = attention.flash_attention_saveable(q, k, v, causal=True)
+            with regions.region(regions.NORM):
+                n = norms.rms_norm(x, w)
+            return a.astype(jnp.float32).sum() * n
         y = jax.checkpoint(layer)(q, k, v, x)
         return y.astype(jnp.float32).sum()
 

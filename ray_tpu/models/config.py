@@ -72,6 +72,9 @@ class TransformerConfig(ConfigDtypes):
     # pipeline over the pp axis (parallel/pipeline.py). Bubble fraction
     # is (pp-1)/(M+pp-1) — pick M >= 4*pp.
     pipeline_microbatches: int = 0
+    # The training step's flash blocks (`Transformer._layer`, forward and
+    # backward). Serving's prefill does not read them: `models/decode.py`
+    # gives the forward `models.gqa.FULL_BLOCKS` cut to its bucket.
     attn_block_q: int = 128
     attn_block_k: int = 128
     loss_chunk: int = 0                   # >0: chunked LM loss (seq chunks)

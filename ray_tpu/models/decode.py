@@ -66,6 +66,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models import regions as R
+from ray_tpu.models.gqa import FULL_BLOCKS
 from ray_tpu.models.paged import decode_lanes
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
@@ -194,9 +195,12 @@ def prefill(model, params: Params, tokens: jax.Array, true_len,
             k = apply_rope_cached(k, cos, sin)
             qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         with R.region(R.ATTN_CORE):
+            # the blocks follow the bucket, as the other served classes'
+            # do (the call cuts a block to `s`: a bucket under 1,024 is one
+            # block a head); `attn_block_q` / `attn_block_k` are training's
+            block_q, block_k = FULL_BLOCKS
             attn = flash_attention(qt, kt, vt, causal=True,
-                                   block_q=c.attn_block_q,
-                                   block_k=c.attn_block_k,
+                                   block_q=block_q, block_k=block_k,
                                    mesh=model.kernel_mesh)
         with R.region(R.ATTN_OUT):
             attn = attn.transpose(0, 2, 1, 3).reshape(

@@ -665,3 +665,41 @@ def test_older_models_programs_lower_to_the_parents_text(name, program):
     assert "pallas_call" in text            # the kernels are in the text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[
         (name, program)]
+
+
+# The dense prefill's flash blocks (`models/decode.py`) are the other GQA
+# classes' (`gqa.FULL_BLOCKS`) cut to the bucket, whatever the config's
+# `attn_block_q` / `attn_block_k`, which are the training step's and
+# default to 128: a serving cell whose file states none must not walk
+# 1,024 grid steps a layer at the 1024 bucket (PERF.md section 6, PR 57).
+def _dense(**blocks):
+    cfg = TransformerConfig(vocab_size=256, d_model=512, n_layers=2,
+                            n_heads=4, n_kv_heads=2, d_ff=512,
+                            max_seq_len=2048, **blocks)
+    return build_model(cfg), cfg
+
+
+def _flash_grids(text: str):
+    """The grids over (batch, heads, query blocks, key blocks) in a text
+    whose only such kernel is the flash forward."""
+    assert "name=flash_fwd" in text and "flash_window_fwd" not in text
+    return re.findall(r"grid=(\(\d+, \d+, \d+, \d+\))", text)
+
+
+@pytest.mark.parametrize("bucket,blocks", [(512, 1), (1024, 1), (2048, 2)])
+def test_dense_prefill_takes_its_flash_blocks_from_the_bucket(bucket, blocks):
+    texts = [_programs(*_dense(**given), s=bucket)["prefill"] for given in
+             ({}, {"attn_block_q": 256, "attn_block_k": 512})]
+    assert _flash_grids(texts[0]) == [f"(1, 4, {blocks}, {blocks})"]
+    assert texts[0] == texts[1]
+
+
+def test_training_layer_still_follows_the_configs_blocks():
+    for given, grid in (({}, "(1, 4, 8, 8)"),
+                        ({"attn_block_q": 256, "attn_block_k": 512},
+                         "(1, 4, 4, 2)")):
+        model, _ = _dense(**given)
+        text = _program_text(
+            model.apply, jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+            jax.ShapeDtypeStruct((1, 1024), jnp.int32))
+        assert _flash_grids(text) == [grid]

@@ -304,6 +304,26 @@ def test_decode_step_reads_wq_and_wk_where_they_lie(decode_step):
                 < 2 * D_MODEL * HEADS * 128)
 
 
+def test_dense_prefill_for_the_chip_walks_blocks_of_1024(
+        topo, no_compile_cache):
+    """The dense class's 2048-token prefill at the serving cells' widths
+    (16 heads over 8 kv heads of 128): its flash forward at
+    `gqa.FULL_BLOCKS`, 2 x 2 blocks a head, is a kernel the chip's
+    compiler takes, once in the scanned layer, and the pools are written
+    in place."""
+    from ray_tpu.models import Transformer, TransformerConfig
+    model = Transformer(TransformerConfig(
+        vocab_size=512, d_model=D_MODEL, n_layers=2, n_heads=HEADS,
+        n_kv_heads=KV_HEADS, d_ff=D_FF, max_seq_len=2048, remat=False,
+        dtype="bfloat16", param_dtype="bfloat16"))
+    compiled, cache = _compile_served(
+        topo.devices, model, "prefill",
+        lambda: model.init_cache(PAGES, PAGE), "paged_decode_attn")
+    assert kernel_names(compiled.as_text()).count(attention.KERNEL_FWD) == 1
+    assert (compiled.memory_analysis().alias_size_in_bytes
+            >= _held_bytes(cache))
+
+
 # ------------------------------------- the second architecture's step
 def _compile_served(devices, model, which: str, make_cache, kernels: str):
     """The decode step (32 lanes) or the prefill of a whole

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from ray_tpu.models.gqa import FULL_BLOCKS
 from ray_tpu.models.latent import PREFILL_BLOCKS
 from ray_tpu.ops import (apply_rope, flash_attention, layer_norm,
                          mha_reference, ring_attention, rms_norm,
@@ -280,6 +281,25 @@ def test_flash_forward_at_the_latent_prefills_blocks(fit, blocks, d, dv):
     got = flash_attention_kernel(q, k, v, causal=True, block_q=block_q,
                                  block_k=block_k)
     assert got.shape == (1, 2, s, dv)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(mha_reference(q, k, v)),
+                               atol=2e-5, rtol=2e-5)
+
+
+# the dense class's prefill (`models/decode.py`): two query heads a kv head
+# of 128 at `gqa.FULL_BLOCKS`, on the grids the chip runs: one block a head
+# up to 1,024 tokens, 2 x 2 and 4 x 4 with the blocks above the diagonal
+# skipped from there. Off a TPU `flash_attention` is the einsum, so the
+# class's own equivalence tests never reach the kernel.
+@pytest.mark.parametrize("bucket", [512, 1024, 2048, 4096])
+def test_flash_forward_at_the_dense_prefills_blocks(bucket):
+    ks = jax.random.split(jax.random.PRNGKey(bucket), 3)
+    q = jax.random.normal(ks[0], (1, 4, bucket, 128), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 2, bucket, 128), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 2, bucket, 128), jnp.float32)
+    got = flash_attention_kernel(q, k, v, causal=True,
+                                 block_q=FULL_BLOCKS[0],
+                                 block_k=FULL_BLOCKS[1])
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(mha_reference(q, k, v)),
                                atol=2e-5, rtol=2e-5)

@@ -19,6 +19,13 @@ the table `models.latent.PREFILL_BLOCKS` was chosen from beside it: a
 bucket and widths, the fastest pair, or the smaller program where two are
 within 3 % (PERF.md section 6, PR 53).
 
+`--kv-heads` gives the keys and values fewer heads than the queries
+(grouped-query attention; default: the heads). `--dense` is the shape at
+which the dense class's prefill calls the kernel (`models/decode.py`:
+InternLM2-1.8B, 16 query heads over 8 kv heads of 128) over buckets 256 to
+4096, the deployment's context limit, with the same table against
+`models.gqa.FULL_BLOCKS` (PERF.md section 6, PR 57), 3 min of one chip.
+
 `--backward` runs the backward kernel alone (`ops.attention.
 _flash_bwd_pallas`, 5 calls a program, each one's `do` hanging on the `dq`
 before) at the training cell's shapes (2 x 32 heads over 8 kv heads of 128,
@@ -43,15 +50,19 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.harness.peaks import PEAKS
+from ray_tpu.models.gqa import FULL_BLOCKS
 from ray_tpu.models.latent import PREFILL_BLOCKS
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import flash_attention
 
 LAYERS, PEAK = 8, PEAKS["TPU v5 lite"]["bf16_flops"]
-# the latent classes' prefills: (heads, key width, value width)
-LATENT = {"glm-4.7-flash": (20, 256, 256),
-          "longcat-flash": (64, 192, 128),
-          "ling-3.0-flash": (32, 192, 128)}
+# the latent classes' prefills: (heads, kv heads, key width, value width)
+LATENT = {"glm-4.7-flash": (20, 20, 256, 256),
+          "longcat-flash": (64, 64, 192, 128),
+          "ling-3.0-flash": (32, 32, 192, 128)}
+# the dense class's, and the buckets up to its deployment's context limit
+DENSE = {"internlm2-1.8b": (16, 8, 128, 128)}
+DENSE_BUCKETS = (256, 512, 1024, 2048, 4096)
 BUCKETS = (256, 512, 1024, 2048, 4096, 8192, 16384)
 BLOCKS = ((128, 128), (256, 256), (256, 512), (512, 512), (512, 1024),
           (1024, 512), (1024, 1024), (512, 2048), (1024, 2048))
@@ -94,15 +105,21 @@ def timed(fn, *args, calls=LAYERS):
     return best * 1e3 / calls
 
 
-def sweep(heads, d, dv, buckets, blocks):
+def shape_name(heads, kv_heads, d, dv):
+    return (f"{heads} heads" + (f" over {kv_heads}" if kv_heads != heads
+                                else "") + f" of {d} / {dv}")
+
+
+def sweep(heads, kv_heads, d, dv, buckets, blocks):
     """{bucket: {(block_q, block_k): ms a call}} of one shape, a line a
     reading; blocks larger than the bucket are the bucket's own (as the
     call cuts them) and read once."""
     key = jax.random.PRNGKey(0)
+    what = shape_name(heads, kv_heads, d, dv)
     out = {}
     for s in buckets:
-        q, k = (jax.random.normal(kk, (1, heads, s, d), jnp.bfloat16)
-                for kk in jax.random.split(key))
+        q, k = (jax.random.normal(kk, (1, n, s, d), jnp.bfloat16)
+                for kk, n in zip(jax.random.split(key), (heads, kv_heads)))
         v = k[..., :dv]
         flops = 2.0 * (s * s / 2.0) * (d + dv) * heads
         out[s] = {}
@@ -114,12 +131,12 @@ def sweep(heads, d, dv, buckets, blocks):
             try:
                 ms = timed(program(*cut), q, k, v)
             except Exception as e:      # say so and go on
-                print(f"{heads} heads of {d} / {dv}, bucket {s}, blocks "
+                print(f"{what}, bucket {s}, blocks "
                       f"{cut[0]} x {cut[1]}: does not compile: "
                       f"{' '.join(str(e).split())[:200]}", flush=True)
                 continue
             out[s][cut] = ms
-            print(f"{heads} heads of {d} / {dv}, bucket {s:5d}, blocks "
+            print(f"{what}, bucket {s:5d}, blocks "
                   f"{cut[0]:4d} x {cut[1]:4d}: {ms:8.4f} ms a call, "
                   f"{ms * 1e3 / steps:6.3f} us a grid step of {steps:6d}, "
                   f"{100 * flops / PEAK / (ms * 1e-3):5.1f} % of the peak",
@@ -202,14 +219,19 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--latent", action="store_true",
                     help="the three latent classes' shapes, and the table")
+    ap.add_argument("--dense", action="store_true",
+                    help="the dense class's shape over its buckets, and "
+                    "the table")
     ap.add_argument("--backward", action="store_true",
                     help="the backward kernel at the training cell's shapes")
     ap.add_argument("--parent", help="with --backward: a tree of another "
                     "commit, whose backward is read first")
     ap.add_argument("--heads", type=int, default=20)
+    ap.add_argument("--kv-heads", type=int, help="default: the heads")
     ap.add_argument("--d", type=int, default=256, help="width of a key")
     ap.add_argument("--dv", type=int, default=256, help="width of a value")
-    ap.add_argument("--buckets", default=",".join(map(str, BUCKETS)))
+    ap.add_argument("--buckets", help="default: 256 to 16,384, with "
+                    "--dense to 4096")
     ap.add_argument("--blocks", default=",".join(
         f"{q}x{k}" for q, k in BLOCKS), help="block_q x block_k, ...")
     opts = ap.parse_args()
@@ -221,22 +243,29 @@ def main():
         return sweep_backward(BWD_LENGTHS, opts.parent)
     print(f"device {jax.devices()[0].device_kind}; causal, batch 1, "
           f"bfloat16, {LAYERS} calls a program")
-    buckets = [int(b) for b in opts.buckets.split(",")]
-    shapes = LATENT if opts.latent else {
-        "as asked": (opts.heads, opts.d, opts.dv)}
+    buckets = ([int(b) for b in opts.buckets.split(",")] if opts.buckets
+               else DENSE_BUCKETS if opts.dense else BUCKETS)
+    # the shapes, and the constant their class holds (its name, the pair)
+    if opts.latent:
+        shapes, held_as = LATENT, ("PREFILL_BLOCKS", PREFILL_BLOCKS)
+    elif opts.dense:
+        shapes, held_as = DENSE, ("FULL_BLOCKS", FULL_BLOCKS)
+    else:
+        shapes, held_as = {"as asked": (
+            opts.heads, opts.kv_heads or opts.heads, opts.d, opts.dv)}, None
     table = {name: sweep(*shape, buckets, pairs(opts.blocks))
              for name, shape in shapes.items()}
-    if not opts.latent:
+    if held_as is None:
         return
-    print("\nbucket: fastest (ms), chosen (ms), PREFILL_BLOCKS cut to the "
+    print(f"\nbucket: fastest (ms), chosen (ms), {held_as[0]} cut to the "
           "bucket (ms), 128 x 128 (ms)")
-    for name, (heads, d, dv) in LATENT.items():
+    for name, shape in shapes.items():
         for s, readings in table[name].items():
             if not readings:
                 continue
             pick = choice(readings)
-            held = tuple(min(b, s) for b in PREFILL_BLOCKS)
-            print(f"{name} ({heads} heads of {d} / {dv}) {s:5d}: "
+            held = tuple(min(b, s) for b in held_as[1])
+            print(f"{name} ({shape_name(*shape)}) {s:5d}: "
                   f"{cell(readings, min(readings, key=readings.get))}, "
                   f"{cell(readings, pick)}, {cell(readings, held)}"
                   f"{'' if held == pick else ' [differs]'}, "

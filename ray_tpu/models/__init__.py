@@ -16,7 +16,9 @@ layer by layer by their configs; each module's docstring has its
 mathematics. What they share is written once: the skeleton and the paged
 cache's addresses in `paged.py`, paged grouped-query attention in `gqa.py`,
 latent attention in `latent.py`, the state-space mixer in `ssm.py`, the
-routed feed-forwards in `moe.py`.
+routed feed-forwards in `moe.py`. `GatedConvMoE` (`gated_conv_moe.py`) is
+the one whose mixer is a convolution and nothing else, whose heads are half
+a 128-lane wide and whose head is the embedding's table.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
@@ -36,6 +38,8 @@ from ray_tpu.models.hybrid_kda_moe import (  # noqa: F401,E402
     HybridKDAMoE, HybridKDAMoEConfig)
 from ray_tpu.models.parallel_hybrid import (  # noqa: F401,E402
     ParallelHybrid, ParallelHybridConfig)
+from ray_tpu.models.gated_conv_moe import (  # noqa: F401,E402
+    GatedConvMoE, GatedConvMoEConfig)
 
 
 # name -> (config class, model class). A dict of config fields names its
@@ -47,7 +51,8 @@ MODELS = {"transformer": (TransformerConfig, Transformer),
           "shortcut_mla_moe": (ShortcutMLAMoEConfig, ShortcutMLAMoE),
           "hybrid_ssm_moe": (HybridSSMMoEConfig, HybridSSMMoE),
           "hybrid_kda_moe": (HybridKDAMoEConfig, HybridKDAMoE),
-          "parallel_hybrid": (ParallelHybridConfig, ParallelHybrid)}
+          "parallel_hybrid": (ParallelHybridConfig, ParallelHybrid),
+          "gated_conv_moe": (GatedConvMoEConfig, GatedConvMoE)}
 
 
 def model_config(model):

@@ -1,9 +1,11 @@
 """Paged grouped-query attention as the classes that have it share it:
 `models.gqa_window_moe.GQAWindowMoE` (two pairs of pools, one a ring under
-a sliding window), `models.hybrid_delta.HybridDelta` and
-`models.hybrid_ssm_moe.HybridSSMMoE` (one pair, for the layers that are
-attention), so that each class's tests and cells guard the others'
-attention. `models/decode.py` is the dense decoder's, stacked and scanned.
+a sliding window), `models.hybrid_delta.HybridDelta`,
+`models.hybrid_ssm_moe.HybridSSMMoE` and `models.gated_conv_moe.
+GatedConvMoE` (one pair, for the layers that are attention; the last at
+heads of 64, two a 128-lane of a row) and `models.parallel_hybrid.
+ParallelHybrid` (one pair over all layers), so that each class's tests and
+cells guard the others' attention. `models/decode.py` is the dense decoder's, stacked and scanned.
 
 A pool is `(layers of the kind, pages, page_size, kv heads x head dim)`:
 a position is one row of all its kv heads side by side, so a page is
@@ -119,11 +121,13 @@ def walk_block_pages(kv_dim: int, page_size: int, max_pages: int,
 
 
 def decode_kernels(head_dim: int, page_size: int, dtype,
-                   kernels: Sequence[Tuple[str, Any]]) -> str:
+                   kernels: Sequence[Tuple[str, Any]],
+                   kv_dim: int = 0) -> str:
     """Which kernels a decode step holds, for `decode_attention`: the
     names of `kernels` ((name, whether the model has such layers)) joined,
     or "einsum" where the paged kernel does not tile the pools (the
-    attention layers gather)."""
-    if not _paged.uses_kernel(head_dim, page_size, dtype):
+    attention layers gather). `kv_dim`: a pool row's width, which decides
+    for heads narrower than a 128-lane."""
+    if not _paged.uses_kernel(head_dim, page_size, dtype, kv_dim):
         return "einsum"
     return "+".join(name for name, present in kernels if present)

@@ -310,7 +310,8 @@ def swiglu(x: jax.Array, gate_w, up_w, down_w) -> jax.Array:
 class DenseOrRoutedFFN:
     """The feed-forward of a class whose layers have a SwiGLU or, where
     the layer has a `"router"`, `dropless_moe_ffn` over all the layer's
-    experts plus a shared expert applied to every token. The class says
+    experts, plus a shared expert applied to every token where the layer
+    has one (`"shared_gate"`, `"shared_up"`, `"shared_down"`). The class says
     `_routing(layer)`: (the router's bias, `dropless_moe_ffn`'s `top_k`,
     `norm_topk_prob` and `scale`, and `held` and the group limit where it
     has them)."""
@@ -325,6 +326,8 @@ class DenseOrRoutedFFN:
         y, counts = dropless_moe_ffn(
             x, layer["router"], bias, layer["moe_gate"], layer["moe_up"],
             layer["moe_down"], valid=valid, **how)
+        if "shared_gate" not in layer:
+            return y, counts
         with R.region(R.FFN):       # the shared expert, added
             return y + swiglu(x, layer["shared_gate"], layer["shared_up"],
                               layer["shared_down"]), counts

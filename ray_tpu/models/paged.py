@@ -92,6 +92,9 @@ class PagedDecoder:
     # why the class refuses a mesh: what is not sharded over chips yet
     # (PERF.md section 7)
     no_mesh = ""
+    # whether the head is the embedding's own table, read by its other
+    # dimension (`logits = N_f(x) E^T`): there is then no `"lm_head"`
+    tied_head = False
     # rows of a pool that holds one row an attention where that is not one
     # a layer (the engine's `cache_init` span carries it); None: no such
     # pool
@@ -107,7 +110,8 @@ class PagedDecoder:
     # ------------------------------------------------------------ init
     def param_count(self) -> int:
         c = self.config
-        return (2 * c.vocab_size * c.d_model + c.d_model + sum(
+        tables = 1 if self.tied_head else 2
+        return (tables * c.vocab_size * c.d_model + c.d_model + sum(
             math.prod(shape) for i in range(c.n_layers)
             for shape, _ in jax.tree_util.tree_leaves(
                 self.layer_shapes(i), is_leaf=_is_shape)))
@@ -116,9 +120,10 @@ class PagedDecoder:
         c = self.config
         pd = c.parameter_dtype
         keys = jax.random.split(key, c.n_layers + 1)
-        top = fill(keys[-1], {
-            "embed": ((c.vocab_size, c.d_model), 0.02),
-            "lm_head": ((c.d_model, c.vocab_size), 0.02)}, pd)
+        top = {"embed": ((c.vocab_size, c.d_model), 0.02)}
+        if not self.tied_head:
+            top["lm_head"] = ((c.d_model, c.vocab_size), 0.02)
+        top = fill(keys[-1], top, pd)
         return {**top, "final_norm": jnp.zeros((c.d_model,), pd),
                 "layers": [fill(keys[i], self.layer_shapes(i), pd)
                            for i in range(c.n_layers)]}
@@ -141,8 +146,17 @@ class PagedDecoder:
         """tokens (b, s) int32 -> logits (b, s, vocab) in f32."""
         x = self.hidden(params, tokens)
         with R.region(R.HEAD):
-            head = params["lm_head"].astype(self.config.activation_dtype)
-            return (x @ head).astype(jnp.float32)
+            return self._head(params, x)
+
+    def _head(self, params: Params, x):
+        """The normed stream x (..., e) through the head, in f32: `lm_head`,
+        or the embedding's table contracted over its second dimension as it
+        lies (no transposed copy is made)."""
+        ad = self.config.activation_dtype
+        if self.tied_head:
+            return jnp.einsum("...e,ve->...v", x, params["embed"].astype(
+                ad)).astype(jnp.float32)
+        return (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
 
     def loss(self, params: Params, batch: Dict[str, jax.Array]):
         """Causal LM loss of batch["tokens"] (b, s), as
@@ -165,11 +179,10 @@ class PagedDecoder:
         """The tail of both served programs: the final norm of the stream
         x, of a prefill (`true_len`; x (s, e) or (1, s, e)) the prompt's
         last position alone, through the head, in f32."""
-        ad = self.config.activation_dtype
         x = self._final_norm(params, x)
         if true_len is not None:
             x = jnp.take(x[0] if x.ndim == 3 else x, true_len - 1, axis=0)
-        return (x @ params["lm_head"].astype(ad)).astype(jnp.float32)
+        return self._head(params, x)
 
     # ------------------------------------------------ what an engine asks
     def fixed_pages(self, page_size: int) -> int:
@@ -274,14 +287,16 @@ def decode_state_slots(page_tables, active, slots: int):
 
 
 class StateSlots:
-    """What a class whose recurrent layers keep a state of one size a
-    sequence answers the engine about it. The class says `state_bytes(
-    dtype=None)` (bytes the recurrent layers keep of one sequence, whatever
-    its length), `page_bytes(page_size, tp_shards=1, dtype=None)` (of the
-    pools that grow with a sequence) and its config the `chunk` a prefill
-    scans by. Its pools `"state"` and `"tail"` (the rows a causal
-    convolution keeps beside the state, `ops.gated_delta.tail_shape` a
-    slot) are `(layers, slots + 1, ...)`."""
+    """What a class whose layers keep something of one size a sequence
+    answers the engine about it. The class says `state_bytes(dtype=None)`
+    (bytes those layers keep of one sequence, whatever its length),
+    `page_bytes(page_size, tp_shards=1, dtype=None)` (of the pools that
+    grow with a sequence) and, where a prefill scans a recurrence, its
+    config the `chunk` it scans by. Its pools `"state"` (a recurrence's)
+    and `"tail"` (the rows a causal convolution continues from,
+    `ops.gated_delta.tail_shape` a slot) are `(layers, slots + 1, ...)`; a
+    slot may hold a tail alone (a convolution that is the whole mixer:
+    there is then no `"state"` pool, and nothing scans)."""
 
     def fixed_pages(self, page_size: int) -> int:
         """One: a sequence's first table entry, which names its slot."""
@@ -296,8 +311,10 @@ class StateSlots:
 
     def prefill_counts(self, tokens: int, bucket: int) -> Dict[str, int]:
         """The chunks a recurrent layer scans: those that hold the prompt
-        (the chunk kernels skip the bucket's others)."""
-        return {"scan_chunks": -(-tokens // self.config.chunk)}
+        (the chunk kernels skip the bucket's others); nothing where no
+        layer scans (a config without a `chunk`)."""
+        chunk = getattr(self.config, "chunk", 0)
+        return {"scan_chunks": -(-tokens // chunk)} if chunk else {}
 
     def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
                          dtype=None, fixed: bool = False) -> int:
@@ -312,13 +329,15 @@ class StateSlots:
     @R.region(R.MIXER_CORE)
     def _write_slot(pools: Cache, li: int, slot, state, tail) -> Cache:
         """A prefill's state and tail written whole into `slot` of pool
-        row `li`, so that a slot reused holds nothing of its last owner.
-        Returns the two pools."""
-        return {"state": pools["state"].at[li, slot].set(state,
-                                                         mode="drop"),
-                "tail": pools["tail"].at[li, slot].set(
-                    fold_tail(tail, pools["tail"].shape).astype(
-                        pools["tail"].dtype), mode="drop")}
+        row `li`, so that a slot reused holds nothing of its last owner
+        (`state` None: the slot holds a tail alone). Returns the pools
+        written."""
+        out = {} if state is None else {
+            "state": pools["state"].at[li, slot].set(state, mode="drop")}
+        out["tail"] = pools["tail"].at[li, slot].set(
+            fold_tail(tail, pools["tail"].shape).astype(
+                pools["tail"].dtype), mode="drop")
+        return out
 
 
 class ExpertCounts:

@@ -40,12 +40,15 @@ the heads side by side along the lanes, so that a pool row is whole
 elementwise float32 on the VPU under the copies: the kernel is bound by
 the bytes of the state, read and written once.
 
-Around both: the causal depthwise convolution with SiLU (`causal_conv`
-over a prompt, `conv_tail_step` a decode step: the inputs a sequence's
+Around both: the causal depthwise convolution (`causal_conv` over a
+prompt, `conv_tail_step` a decode step: the inputs a sequence's
 convolution continues from live in a pool beside the states, at the same
 slot, `tail_shape` a slot), the L2 norms and the gates, plain `jax.numpy`.
-The family's other recurrences (`ops/kda.py`, `ops/ssd.py`) sit behind the
-same convolution.
+The convolution ends in SiLU where it is a recurrence's way in (this rule,
+`ops/kda.py`, `ops/ssd.py`: the default) and is linear (`activate=False`)
+where it is the mixer itself, between two gates the caller multiplies by
+(`models/gated_conv_moe.py`): its tail pool is then all a layer keeps of
+a sequence.
 """
 from __future__ import annotations
 
@@ -95,13 +98,13 @@ def gates(a, b, a_log, dt_bias, allow_neg_eigval: bool):
     return g, 2.0 * beta if allow_neg_eigval else beta
 
 
-def causal_conv(x, w, true_len=None, bias=None):
+def causal_conv(x, w, true_len=None, bias=None, activate: bool = True):
     """Depthwise causal convolution along the sequence, then SiLU: x (s,
     channels), w (width, channels), `y_t = silu(sum_i w_i x_{t - width + 1
     + i} + bias)` with zeros before the sequence (`bias` (channels,) or
-    None: none). Returns (y in x's dtype, the last `width - 1` inputs
-    before `true_len` (the sequence's end if None): what a decode step
-    continues from)."""
+    None: none); the sum itself, linear, without `activate`. Returns (y in
+    x's dtype, the last `width - 1` inputs before `true_len` (the
+    sequence's end if None): what a decode step continues from)."""
     s, width = x.shape[0], w.shape[0]
     xf = jnp.pad(x.astype(F32), ((width - 1, 0), (0, 0)))
     y = sum(w[i].astype(F32) * xf[i:i + s] for i in range(width))
@@ -110,10 +113,12 @@ def causal_conv(x, w, true_len=None, bias=None):
     end = s if true_len is None else true_len
     # padded row `end + j` is input `end - (width - 1) + j`
     tail = lax.dynamic_slice_in_dim(xf, end, width - 1, axis=0)
-    return jax.nn.silu(y).astype(x.dtype), tail.astype(x.dtype)
+    if activate:
+        y = jax.nn.silu(y)
+    return y.astype(x.dtype), tail.astype(x.dtype)
 
 
-def conv_step(x, tail, w, bias=None):
+def conv_step(x, tail, w, bias=None, activate: bool = True):
     """One position of `causal_conv` a lane: x (B, channels), tail (B,
     width - 1, channels) the inputs before it. Returns (y, the new
     tail)."""
@@ -121,7 +126,9 @@ def conv_step(x, tail, w, bias=None):
     y = jnp.sum(window.astype(F32) * w.astype(F32)[None], axis=1)
     if bias is not None:
         y = y + bias.astype(F32)
-    return jax.nn.silu(y).astype(x.dtype), window[:, 1:]
+    if activate:
+        y = jax.nn.silu(y)
+    return y.astype(x.dtype), window[:, 1:]
 
 
 def tail_shape(width: int, channels: int) -> tuple:
@@ -158,7 +165,8 @@ def _unfold_tail(a, channels: int):
     return a.reshape(*a.shape[:-2], -1)[..., :channels]
 
 
-def conv_tail_step(x, w, pool, layer, slots, bias=None):
+def conv_tail_step(x, w, pool, layer, slots, bias=None,
+                   activate: bool = True):
     """One position of `causal_conv` a lane against the pool of tails:
     x (B, channels) the new inputs, w (width, channels), pool (layers,
     slots + 1, *tail_shape), slots (B,) int32 (-1: an inactive lane, which
@@ -166,7 +174,8 @@ def conv_tail_step(x, w, pool, layer, slots, bias=None):
     x's dtype, the pool with the active lanes' slots shifted by x)."""
     n = pool.shape[1] - 1
     tail = pool[layer, jnp.where(slots >= 0, slots, n)]
-    y, tail = conv_step(x, _unfold_tail(tail, x.shape[1]), w, bias)
+    y, tail = conv_step(x, _unfold_tail(tail, x.shape[1]), w, bias,
+                        activate)
     where = jnp.where(slots >= 0, slots, n + 1)         # -1: written nowhere
     return y, pool.at[layer, where].set(
         fold_tail(tail, pool.shape).astype(pool.dtype), mode="drop")

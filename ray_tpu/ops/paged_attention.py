@@ -7,8 +7,9 @@ lane owns the pages its table lists, in any order. The decode step's
 attention has to move what the lanes hold and no more: the kernel leaves
 the pool in HBM, takes page tables, lengths and the layer index by scalar
 prefetch, and copies in only the live pages of each lane, one DMA a page
-(a page is contiguous, and a kv head is whole lanes of its rows),
-double-buffered in blocks whose size comes from the bytes of a page
+(a page is contiguous, and a kv head is whole lanes of its rows, or a
+whole share of one 128-lane: `LANE`, "A head narrower than a 128-lane"
+below), double-buffered in blocks whose size comes from the bytes of a page
 (`walk_block_pages`); a block is multiplied over the part of it the lane
 holds (`walk_prefixes`). The buffers outlive a lane: behind its last block
 a lane starts the first block of the lane after it, so the copies stop at
@@ -136,13 +137,65 @@ def walk_first_blocks_hidden(pages) -> int:
     return max(sum(n > 0 for n in pages) - 1, 0)
 
 
-def paged_decode_tiles(head_dim: int, page_size: int, dtype) -> bool:
+# A head narrower than a 128-lane. The kernels slice a block of pages by
+# whole 128-lanes: a head is `(positions, head_dim)` of the buffer with
+# nothing moved. A kv head of 64 is half a lane, and its neighbour the
+# other half. Such heads are multiplied `LANE // head_dim` at a time, as
+# one head of 128 whose group is all their groups' query rows, each row
+# zero-extended over its neighbours' part of the lane (as the latent
+# kernel's query is `[... | zeros]`): a score is then the row's own head's
+# dot product and nothing else; the values are read as the whole lane and
+# of a row's output its own head's part is kept (`_lane_queries`,
+# `_own_parts`). The pool is read once, as it lies, with no copy; the MXU
+# multiplies `LANE // head_dim` times what the heads need, on a kernel the
+# bytes bound. The kernels' bodies do not know: they are handed heads of
+# 128.
+LANE = 128
+
+
+def paged_decode_tiles(head_dim: int, page_size: int, dtype,
+                       kv_dim: int = 0) -> bool:
     """Whether the kernel tiles these shapes: a head is whole lanes of a
-    pool row, and a page is whole `(sublane, 128)` tiles of the pool's
-    dtype, so that a page lands in VMEM with one contiguous copy and a
-    block of pages reads as one `(positions, head_dim)` matrix a head."""
+    pool row, or a whole share of one lane in a row of whole lanes
+    (`kv_dim`, kv heads x head dim, where the caller knows it: an odd count
+    of heads of 64 is refused, and its attention gathers), and a page is
+    whole `(sublane, 128)` tiles of the pool's dtype, so that a page lands
+    in VMEM with one contiguous copy and a block of pages reads as one
+    `(positions, 128 k)` matrix a head, or a lane's heads."""
     sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    return head_dim % 128 == 0 and page_size % sublanes == 0
+    lanes = head_dim % LANE == 0 or (
+        0 < head_dim < LANE and LANE % head_dim == 0
+        and kv_dim > 0 and kv_dim % LANE == 0)
+    return lanes and page_size % sublanes == 0
+
+
+def _lane_queries(q, kvh: int):
+    """q (B, n_heads, hd) -> the kernels' query (B, kv heads, group, hd),
+    the `n_heads // kvh` query heads of a kv head one matmul's rows; where
+    a head is a share of a lane, (B, kvh // pack, pack x group, 128): the
+    `pack` heads of a lane as one, each row zeros outside its own head's
+    part."""
+    B, n_heads, hd = q.shape
+    group = n_heads // kvh
+    qg = q.reshape(B, kvh, group, hd)
+    if hd >= LANE:
+        return qg
+    pack = LANE // hd
+    qg = qg.reshape(B, kvh // pack, pack, group, 1, hd)
+    own = jnp.eye(pack, dtype=q.dtype).reshape(1, 1, pack, 1, pack, 1)
+    return (qg * own).reshape(B, kvh // pack, pack * group, LANE)
+
+
+def _own_parts(out, n_heads: int, hd: int):
+    """`_lane_queries` undone on the kernels' output: (B, n_heads, hd), of
+    a row that read a lane of several heads its own head's part."""
+    B = out.shape[0]
+    if hd >= LANE:
+        return out.reshape(B, n_heads, hd)
+    pack = LANE // hd
+    out = out.reshape(B, out.shape[1], pack, out.shape[2] // pack, pack, hd)
+    return jnp.stack([out[:, :, j, :, j] for j in range(pack)],
+                     axis=2).reshape(B, n_heads, hd)
 
 
 # ------------------------------------------------------------- reference
@@ -519,46 +572,46 @@ def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
 
 def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
                   interpret: bool, mesh=None):
-    B, n_heads, hd = q.shape
+    n_heads, hd = q.shape[1:]
     page_size, kvh = k_pool.shape[2], k_pool.shape[3] // hd
     if n_heads % kvh:
         raise ValueError(
             f"num_heads ({n_heads}) must be a multiple of num_kv_heads "
             f"({kvh})")
-    if not paged_decode_tiles(hd, page_size, k_pool.dtype):
+    if not paged_decode_tiles(hd, page_size, k_pool.dtype,
+                              k_pool.shape[3]):
         raise ValueError(
-            f"the paged decode kernel does not tile head_dim {hd} with "
-            f"{page_size}-position pages of {k_pool.dtype}")
-    group = n_heads // kvh
-    qg = q.reshape(B, kvh, group, hd)
+            f"the paged decode kernel does not tile {kvh} kv heads of {hd} "
+            f"with {page_size}-position pages of {k_pool.dtype}")
+    qg = _lane_queries(q, kvh)
+    call = functools.partial(_paged_decode_call, interpret=interpret,
+                             sm_scale=1.0 / math.sqrt(hd))
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     if mesh is not None:
         # kv heads over tp, as models.decode.cache_sharding lays the pool
         tp = "tp" if mesh.shape.get("tp", 1) > 1 else None
         spec_q, spec_pool = P(None, tp, None, None), P(None, None, None, tp)
         out = shard_kernel(
-            functools.partial(_paged_decode_call, interpret=interpret),
-            mesh, (spec_q, spec_pool, spec_pool, P(), P(), P()),
+            call, mesh, (spec_q, spec_pool, spec_pool, P(), P(), P()),
             spec_q)(qg, k_pool, v_pool, layer, page_tables, lengths)
     else:
-        out = _paged_decode_call(qg, k_pool, v_pool, layer, page_tables,
-                                 lengths, interpret=interpret)
-    return out.reshape(B, n_heads, hd)
+        out = call(qg, k_pool, v_pool, layer, page_tables, lengths)
+    return _own_parts(out, n_heads, hd)
 
 
 # jitted: the decode step calls it once a layer with the same shapes, the
 # layer's index an argument, so the kernel is traced and lowered once a
 # program and not once a layer (24 of them cost a replica 8 s of set-up)
-@functools.partial(jax.jit, static_argnames="interpret")
+@functools.partial(jax.jit, static_argnames=("interpret", "sm_scale"))
 def _paged_decode_call(qg, k_pool, v_pool, layer, page_tables, lengths,
-                       interpret: bool):
-    hd = qg.shape[-1]
-    kernel = functools.partial(_paged_decode_kernel,
-                               sm_scale=1.0 / math.sqrt(hd))
+                       interpret: bool, sm_scale: float):
+    """`sm_scale`: of the heads' own width, which a lane of several is
+    not."""
+    kernel = functools.partial(_paged_decode_kernel, sm_scale=sm_scale)
     return _paged_pallas_call(
         kernel, KERNEL_PAGED_DECODE, qg, (k_pool, v_pool), layer,
-        page_tables, lengths, out_width=hd, sems=(BLOCK_SLOTS, 2),
-        interpret=interpret)
+        page_tables, lengths, out_width=qg.shape[-1],
+        sems=(BLOCK_SLOTS, 2), interpret=interpret)
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, page_tables, lengths,
@@ -568,18 +621,21 @@ def paged_decode_attention(q, k_pool, v_pool, layer, page_tables, lengths,
     (`paged_decode_tiles`), the gather + einsum reference elsewhere.
     Shapes as `paged_attention_reference`; `mesh`: the mesh of more than
     one device the pool is sharded over (`ops.dispatch.kernel_mesh`)."""
-    if uses_kernel(q.shape[-1], k_pool.shape[2], k_pool.dtype):
+    if uses_kernel(q.shape[-1], k_pool.shape[2], k_pool.dtype,
+                   k_pool.shape[3]):
         return _paged_decode(q, k_pool, v_pool, layer, page_tables,
                              lengths, False, mesh)
     return paged_attention_reference(q, k_pool, v_pool, layer,
                                      page_tables, lengths)
 
 
-def uses_kernel(head_dim: int, page_size: int, dtype) -> bool:
+def uses_kernel(head_dim: int, page_size: int, dtype,
+                kv_dim: int = 0) -> bool:
     """What `paged_decode_attention` decides, for a caller that reports
     it: decided by what can be seen, the platform being traced for and
     the shapes, and by nothing else."""
-    return on_tpu() and paged_decode_tiles(head_dim, page_size, dtype)
+    return on_tpu() and paged_decode_tiles(head_dim, page_size, dtype,
+                                           kv_dim)
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, layer, page_tables,
@@ -635,9 +691,10 @@ def paged_window_attention_reference(q, k_pool, v_pool, layer, ring_tables,
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def _paged_window_decode_call(q, k_pool, v_pool, layer, ring_tables,
                               lengths, window: int, interpret: bool):
-    B, n_heads, hd = q.shape
+    n_heads, hd = q.shape[1:]
     page_size, kvh = k_pool.shape[2], k_pool.shape[3] // hd
-    if n_heads % kvh or not paged_decode_tiles(hd, page_size, k_pool.dtype):
+    if n_heads % kvh or not paged_decode_tiles(
+            hd, page_size, k_pool.dtype, k_pool.shape[3]):
         raise ValueError(
             f"the paged window kernel does not take {n_heads} heads over "
             f"{kvh} kv heads of {hd} in {page_size}-position pages of "
@@ -649,12 +706,12 @@ def _paged_window_decode_call(q, k_pool, v_pool, layer, ring_tables,
             f"{ring_tables.shape[1]}")
     kernel = functools.partial(_paged_decode_kernel,
                                sm_scale=1.0 / math.sqrt(hd))
+    qg = _lane_queries(q, kvh)
     out = _paged_pallas_call(
-        kernel, KERNEL_PAGED_WINDOW_DECODE,
-        q.reshape(B, kvh, n_heads // kvh, hd), (k_pool, v_pool), layer,
-        ring_tables, lengths, out_width=hd, sems=(BLOCK_SLOTS, 2),
-        interpret=interpret, window=window)
-    return out.reshape(B, n_heads, hd)
+        kernel, KERNEL_PAGED_WINDOW_DECODE, qg, (k_pool, v_pool), layer,
+        ring_tables, lengths, out_width=qg.shape[-1],
+        sems=(BLOCK_SLOTS, 2), interpret=interpret, window=window)
+    return _own_parts(out, n_heads, hd)
 
 
 def paged_window_decode_attention(q, k_pool, v_pool, layer, ring_tables,
@@ -662,7 +719,8 @@ def paged_window_decode_attention(q, k_pool, v_pool, layer, ring_tables,
     """Dispatching entry point of a window layer's decode attention: the
     compiled kernel on a TPU where the shapes tile, the gather + einsum
     reference elsewhere. Shapes as `paged_window_attention_reference`."""
-    if uses_kernel(q.shape[-1], k_pool.shape[2], k_pool.dtype):
+    if uses_kernel(q.shape[-1], k_pool.shape[2], k_pool.dtype,
+                   k_pool.shape[3]):
         return _paged_window_decode_call(q, k_pool, v_pool, layer,
                                          ring_tables, lengths, int(window),
                                          False)

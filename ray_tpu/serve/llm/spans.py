@@ -43,7 +43,8 @@ LOCK_WAIT = "engine.lock_wait"
 STEP = "engine.step"                # one EngineCore.step()
 # one admission: its prefill dispatched; carries `tokens`, `bucket` and
 # what the model says the prefill runs (`prefill_counts`: a model with
-# recurrent layers, the `scan_chunks` of its recurrence)
+# recurrent layers, the `scan_chunks` of its recurrence; a model whose
+# fixed part is a convolution's tail alone scans nothing and adds none)
 PREFILL = "engine.prefill"
 TABLES = "engine.page_tables"       # the decode batch's host arrays
 # carries this dispatch's counts: `lanes`, `live_positions`,
@@ -57,8 +58,10 @@ TABLES = "engine.page_tables"       # the decode batch's host arrays
 # `window_walk_blocks` (a layer that holds a sequence's last positions in
 # a ring), for layers that hold a recurrent state (linear attention, a
 # selective scan) `state_slots` / `state_bytes` (the lanes whose state the
-# step reads and writes, and the bytes moved for them); a layer that holds
-# pages and a state (two mixers side by side) counts under both
+# step reads and writes, and the bytes moved for them: a gated
+# convolution's two rows a layer count here too, a state with no
+# recurrence behind it); a layer that holds pages and a state (two mixers
+# side by side) counts under both
 DISPATCH = "engine.decode_dispatch"
 # waits for the tokens of the step before (and this call's prefills), with
 # the step just dispatched queued behind them on the device

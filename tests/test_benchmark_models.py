@@ -149,7 +149,7 @@ def test_metric_has_a_reader_and_lists_cells_that_exist(entry):
 
 
 def test_a_fifth_of_the_cells_may_take_four_chips_and_none_does():
-    assert len(CELLS) == 10 and not [c for c in CELLS if c["chips"] != 1]
+    assert len(CELLS) == 11 and not [c for c in CELLS if c["chips"] != 1]
     assert len({(c["config"], c["traffic"]) for c in CELLS}) == len(CELLS)
 
 

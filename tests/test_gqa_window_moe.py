@@ -29,8 +29,8 @@ sys.path.insert(0, ROOT)
 from benchmarks.harness import modelcfg                      # noqa: E402
 from benchmarks.harness.reference import rel_rms             # noqa: E402
 from benchmarks.harness.weights import make_weights          # noqa: E402
-from ray_tpu.models import (GQAWindowMoE, GQAWindowMoEConfig,  # noqa: E402
-                            HybridDeltaConfig, HybridKDAMoEConfig,
+from ray_tpu.models import (GatedConvMoEConfig, GQAWindowMoE,  # noqa: E402
+                            GQAWindowMoEConfig, HybridDeltaConfig, HybridKDAMoEConfig,
                             HybridSSMMoEConfig, MLAMoE,
                             ParallelHybridConfig, ShortcutMLAMoEConfig,
                             Transformer, build_model, model_config)
@@ -607,6 +607,17 @@ PINNED = {
     # both mixers' kernels in the one layer
     ("ParallelHybrid", "prefill"): "212c76bf28ba5d17",
     ("ParallelHybrid", "decode_step"): "e6130c468a958a73",
+    # PR 58 let the page walk take heads that are a share of a 128-lane
+    # (`ops.paged_attention.LANE`: the wrapper packs them, `_paged_decode_call`
+    # is given its `sm_scale`), the convolution run without its SiLU
+    # (`activate`), `StateSlots._write_slot` write a tail alone, the head be
+    # the embedding's table (`PagedDecoder._head`) and `DenseOrRoutedFFN`
+    # leave the shared expert out where a layer has none: all sixteen keep
+    # their hashes. The ninth class, pinned to the text PR 58 gave it: two
+    # kv heads of 64 as one lane under eight query rows, the gated
+    # convolution, a router under a bias and no shared expert, a tied head
+    ("GatedConvMoE", "prefill"): "f6c72aa2a2917f9b",
+    ("GatedConvMoE", "decode_step"): "5aa9c8af7f17b51c",
 }
 
 # a class's configuration for its pin: small, and of head sizes that tile
@@ -655,6 +666,12 @@ PINNED_CONFIGS = {
         vocab_size=256, d_model=128, n_layers=1, n_heads=5, n_kv_heads=1,
         head_dim=128, ssm_heads=2, ssm_head_dim=128, ssm_groups=1,
         ssm_state=128, chunk=128, d_ff=256, max_seq_len=128),
+    # (heads of 64, two a 128-lane of a pool row: the paged kernel tiles)
+    "GatedConvMoE": lambda: GatedConvMoEConfig(
+        vocab_size=256, d_model=128, layer_types=("conv", "full_attention"),
+        n_heads=8, n_kv_heads=2, head_dim=64, d_ff=256,
+        moe_intermediate_size=128, num_experts=8, num_experts_per_tok=2,
+        num_dense_layers=1, max_seq_len=128),
 }
 
 

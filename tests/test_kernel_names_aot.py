@@ -665,13 +665,63 @@ def test_parallel_hybrid_programs_hold_both_mixers_kernels_in_every_layer(
         cache)
 
 
+# ------------------------------------- the ninth architecture's step
+def _compile_gated_conv_moe(devices, which: str, slots: int = 64,
+                            layers: int = 2):
+    """`GatedConvMoE`'s decode step or 4096-token prefill at the published
+    widths of LFM2-8B-A1B (32 query heads over 8 kv heads of 64: a pool
+    row of 512 numbers, two kv heads a 128-lane, eight query rows a lane in
+    the page walk; a gated convolution of 3 taps over 2,048 channels; a
+    dense layer, then 8 of the 32 experts of 1,792, 4 a token): `layers`
+    layers, convolution and attention by turns, `slots` tail slots and
+    nobody's."""
+    from ray_tpu.models.gated_conv_moe import (GatedConvMoE,
+                                               GatedConvMoEConfig)
+    model = GatedConvMoE(GatedConvMoEConfig(
+        vocab_size=1024, layer_types=("conv", "full_attention") * (
+            layers // 2) + ("conv",) * (layers % 2), num_experts=8,
+        num_dense_layers=1, max_seq_len=4096))
+    return _compile_served(
+        devices, model, which, lambda: model.init_cache(
+            PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
+        "paged_decode_attn")
+
+
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_gated_conv_moe_programs_hold_a_kernel_an_attention_layer(
+        which, topo, no_compile_cache):
+    """Heads of 64 reach both kernels: no gather stands in for the page
+    walk (`decode_attention` said so before the trace), one call an
+    attention layer."""
+    from ray_tpu.ops import grouped_matmul
+    compiled, cache = _compile_gated_conv_moe(topo.devices, which, layers=4)
+    names = kernel_names(compiled.as_text())
+    # gate, up and down of the experts, three expert layers
+    assert names.count(grouped_matmul.KERNEL_GMM) == 9
+    if which == "step":
+        assert names.count(paged_attention.KERNEL_PAGED_DECODE) == 2
+        assert attention.KERNEL_FWD not in names
+    else:
+        assert names.count(attention.KERNEL_FWD) == 2
+        assert paged_attention.KERNEL_PAGED_DECODE not in names
+    # every pool is updated in place: the keys and values of two layers
+    # (rows of 512) and two layers' tails, 65 slots of 2 x 16 x 128
+    assert cache["k"].shape == cache["v"].shape == (2, PAGES, PAGE, 512)
+    assert cache["tail"].shape == (2, 65, 2, 16, 128)
+    assert "state" not in cache
+    assert compiled.memory_analysis().alias_size_in_bytes >= _held_bytes(
+        cache)
+
+
 # ------------------------- the recurrent classes' convolution in a step
 @pytest.mark.parametrize("compile_step,tail", [
     (_compile_hybrid_delta, (3, 96, 128)),      # 11,520 channels in 12,288
     (_compile_hybrid_ssm_moe, (3, 80, 128)),    # 10,240, under a bias
     (_compile_hybrid_kda_moe, (3, 96, 128)),    # 12,288
     (_compile_parallel_hybrid, (3, 48, 128)),   # 5,120 in 6,144, a bias
-], ids=["HybridDelta", "HybridSSMMoE", "HybridKDAMoE", "ParallelHybrid"])
+    (_compile_gated_conv_moe, (2, 16, 128)),    # 2,048, linear, no state
+], ids=["HybridDelta", "HybridSSMMoE", "HybridKDAMoE", "ParallelHybrid",
+        "GatedConvMoE"])
 def test_a_step_scatters_the_tail_pool_once_in_place_as_it_lies(
         compile_step, tail, topo, no_compile_cache):
     """The step of each class that keeps a convolution's tail writes the

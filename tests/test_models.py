@@ -439,6 +439,7 @@ def test_kept_bytes_a_token_and_layer_are_config_pys_figures():
 
 # ----------------------------------------- the served classes' seam
 def _tiny_served():
+    from ray_tpu.models.gated_conv_moe import tiny_gated_conv_moe
     from ray_tpu.models.gqa_window_moe import tiny_gqa_window_moe
     from ray_tpu.models.hybrid_delta import tiny_hybrid_delta
     from ray_tpu.models.hybrid_kda_moe import tiny_hybrid_kda_moe
@@ -451,7 +452,8 @@ def _tiny_served():
             "ShortcutMLAMoE": tiny_shortcut_mla_moe,
             "HybridSSMMoE": tiny_hybrid_ssm_moe,
             "HybridKDAMoE": tiny_hybrid_kda_moe,
-            "ParallelHybrid": tiny_parallel_hybrid}
+            "ParallelHybrid": tiny_parallel_hybrid,
+            "GatedConvMoE": tiny_gated_conv_moe}
 
 
 def _tree_sha256(tree) -> str:
@@ -481,6 +483,8 @@ INIT_SHA256 = {
     "HybridKDAMoE": "dc5c8e45cfc30e11",
     # the eighth, as PR 54 made it
     "ParallelHybrid": "e6602d2e0cf60bfe",
+    # the ninth, as PR 58 made it (one table: the head is the embedding's)
+    "GatedConvMoE": "ae1fa1c05a49a2dd",
 }
 
 
@@ -506,7 +510,7 @@ def _tiny_models():
 @pytest.mark.parametrize("name", ["transformer", "mla_moe", "gqa_window_moe",
                                   "hybrid_delta", "shortcut_mla_moe",
                                   "hybrid_ssm_moe", "hybrid_kda_moe",
-                                  "parallel_hybrid"])
+                                  "parallel_hybrid", "gated_conv_moe"])
 def test_every_class_answers_the_engines_twelve_asks(name):
     """What `EngineCore` calls on a model, on every class of the table,
     with the types it uses them as (`models.paged.PagedDecoder`)."""

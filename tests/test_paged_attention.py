@@ -24,7 +24,7 @@ FULL = PAGE * MAX_PAGES
 
 
 def _case(lengths, kvh=2, group=2, seed=0, layers=2, holes=(),
-          MAX_PAGES=MAX_PAGES):
+          MAX_PAGES=MAX_PAGES, HD=HD):
     """Random bf16 pools and queries; each lane's table lists pages drawn
     without order from a pool larger than all tables, -1 past the lane's
     pages and at `holes` (lane, table index)."""
@@ -74,6 +74,75 @@ def test_kernel_groups_query_heads(kvh, group):
     q, k, v, pt, ln = _case([37, 200, FULL], kvh=kvh, group=group, seed=1)
     _close(pa.paged_decode_attention_kernel(q, k, v, 1, pt, ln),
            pa.paged_attention_reference(q, k, v, 1, pt, ln))
+
+
+# A head narrower than a 128-lane (`pa.LANE`): the heads of a lane are
+# multiplied as one head of 128 under all their groups' query rows, each
+# row zero outside its own head's part, and of a row's output its own part
+# is kept. The gathered reference knows nothing of lanes.
+@pytest.mark.parametrize("hd,kvh,group", [
+    (64, 2, 4),         # a group of 4, one pair of kv heads: 8 rows a lane
+    (64, 8, 4),         # the published 32 heads over 8
+    (64, 4, 1),         # one query head a kv head
+    (32, 4, 2),         # four heads a lane
+    (128, 2, 4),        # heads of 128 as they were
+])
+@pytest.mark.parametrize("lengths", [
+    [1, PAGE, PAGE + 1, FULL],                  # ragged lanes
+    [8 * PAGE, 8 * PAGE + 1, 0, 3],     # a block's edges, an inactive lane
+], ids=lambda v: "-".join(map(str, v)))
+def test_kernel_takes_heads_that_share_a_lane(hd, kvh, group, lengths):
+    q, k, v, pt, ln = _case(lengths, kvh=kvh, group=group, seed=3, HD=hd)
+    assert q.shape == (len(lengths), kvh * group, hd)
+    want = pa.paged_attention_reference(q, k, v, 1, pt, ln)
+    got = pa.paged_decode_attention_kernel(q, k, v, 1, pt, ln)
+    _close(got, want)
+    idle = np.asarray(ln) == 0
+    assert not np.asarray(got, np.float32)[idle].any()
+
+
+def test_lane_queries_are_zero_outside_their_own_heads_part():
+    q = jnp.arange(1, 1 + 2 * 8 * 64, dtype=jnp.float32).reshape(2, 8, 64)
+    packed = pa._lane_queries(q, 4)         # 4 kv heads of 64, a group of 2
+    assert packed.shape == (2, 2, 4, 128)   # 2 lanes of 2 heads x 2 rows
+    rows = np.asarray(packed)
+    # lane 0: heads 0, 1 (query rows 0-1 and 2-3)
+    np.testing.assert_array_equal(rows[:, 0, :2, :64], np.asarray(q[:, 0:2]))
+    np.testing.assert_array_equal(rows[:, 0, 2:, 64:], np.asarray(q[:, 2:4]))
+    assert not rows[:, :, :2, 64:].any() and not rows[:, :, 2:, :64].any()
+    # and `_own_parts` of an output laid out the same way undoes it
+    np.testing.assert_array_equal(pa._own_parts(packed, 8, 64), q)
+    # heads of whole lanes pass through as the group's rows
+    wide = jnp.ones((2, 8, 128))
+    assert pa._lane_queries(wide, 4).shape == (2, 4, 2, 128)
+
+
+def test_window_kernel_takes_heads_that_share_a_lane():
+    rng = np.random.default_rng(5)
+    B, kvh, group, hd, page, ring, window = 3, 2, 4, 64, 16, 5, 48
+    pool = (2, B * ring + 3, page, kvh * hd)
+    k = jnp.asarray(rng.standard_normal(pool), jnp.bfloat16)
+    v = jnp.asarray(rng.standard_normal(pool), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((B, kvh * group, hd)), jnp.bfloat16)
+    tables = jnp.asarray(1 + rng.permutation(B * ring).reshape(B, ring),
+                         jnp.int32)
+    lengths = jnp.asarray([70, 17, 0], jnp.int32)
+    _close(pa.paged_window_decode_attention_kernel(q, k, v, 1, tables,
+                                                   lengths, window),
+           pa.paged_window_attention_reference(q, k, v, 1, tables, lengths,
+                                               window))
+
+
+def test_an_odd_count_of_half_lane_heads_is_refused():
+    """Three kv heads of 64 are a row of 192 numbers, no whole lanes: the
+    kernel refuses them, and the dispatching entry gathers."""
+    q, k, v, pt, ln = _case([5, 40], kvh=3, group=2, HD=64)
+    assert not pa.paged_decode_tiles(64, PAGE, jnp.bfloat16, 3 * 64)
+    with pytest.raises(ValueError, match="does not tile 3 kv heads of 64"):
+        pa.paged_decode_attention_kernel(q, k, v, 0, pt, ln)
+    with compute_platform("tpu"):
+        assert not pa.uses_kernel(64, PAGE, jnp.bfloat16, 3 * 64)
+        assert pa.uses_kernel(64, PAGE, jnp.bfloat16, 4 * 64)
 
 
 def test_kernel_skips_unassigned_entries():
@@ -468,7 +537,7 @@ def test_a_model_asks_the_rule_what_its_kernel_asks(name, pools):
     (256, 16, jnp.bfloat16, True), (128, 8, jnp.float32, True),
     (128, 8, jnp.bfloat16, False),      # half a bf16 tile a page
     (16, 16, jnp.bfloat16, False),      # the tiny model's heads
-    (64, 16, jnp.bfloat16, False),
+    (64, 16, jnp.bfloat16, False),      # half a lane, the row's width unsaid
 ])
 def test_path_predicate(hd, page, dtype, tiles):
     assert pa.paged_decode_tiles(hd, page, dtype) is tiles
@@ -477,6 +546,16 @@ def test_path_predicate(hd, page, dtype, tiles):
         assert not pa.uses_kernel(hd, page, dtype)
     with compute_platform("tpu"):
         assert pa.uses_kernel(hd, page, dtype) is tiles
+
+
+@pytest.mark.parametrize("hd,kv_dim,tiles", [
+    (64, 512, True), (64, 128, True), (64, 192, False), (64, 64, False),
+    (32, 128, True), (16, 128, True), (48, 384, False), (128, 512, True),
+    (192, 384, False), (256, 512, True),
+])
+def test_path_predicate_of_a_head_that_shares_a_lane(hd, kv_dim, tiles):
+    assert pa.paged_decode_tiles(hd, 16, jnp.bfloat16, kv_dim) is tiles
+    assert not pa.paged_decode_tiles(hd, 8, jnp.bfloat16, kv_dim)
 
 
 def test_kernel_refuses_shapes_it_does_not_tile():

@@ -25,7 +25,8 @@ TINY = {"transformer": C.tiny,
         "shortcut_mla_moe": M.shortcut_mla_moe.tiny_shortcut_mla_moe,
         "hybrid_ssm_moe": M.hybrid_ssm_moe.tiny_hybrid_ssm_moe,
         "hybrid_kda_moe": M.hybrid_kda_moe.tiny_hybrid_kda_moe,
-        "parallel_hybrid": M.parallel_hybrid.tiny_parallel_hybrid}
+        "parallel_hybrid": M.parallel_hybrid.tiny_parallel_hybrid,
+        "gated_conv_moe": M.gated_conv_moe.tiny_gated_conv_moe}
 assert set(TINY) == set(M.MODELS)
 
 ALWAYS = {R.EMBED, R.NORM, R.ATTN_IN, R.ATTN_CORE, R.ATTN_OUT, R.FFN, R.HEAD}
@@ -36,7 +37,8 @@ SHOWS = {"transformer": ALWAYS, "mla_moe": ALWAYS | EXPERTS,
          "shortcut_mla_moe": ALWAYS | EXPERTS,
          "hybrid_ssm_moe": ALWAYS | MIXER | EXPERTS,
          "hybrid_kda_moe": ALWAYS | MIXER | EXPERTS,
-         "parallel_hybrid": ALWAYS | MIXER}
+         "parallel_hybrid": ALWAYS | MIXER,
+         "gated_conv_moe": ALWAYS | MIXER | EXPERTS}
 
 PAGE, LANES, PROMPT, TABLE = 16, 4, 32, 8
 # the instructions the rule is about ("custom-call": a Pallas kernel, and

@@ -73,7 +73,10 @@ FETCH = "engine.fetch_tokens"
 # elsewhere)
 EMIT = "engine.emit"
 INGEST = "engine.ingest"
-PUBLISH = "stream.publish"          # child of ingest: a step's frames
+# child of ingest: a step's frames, written where there is one to send,
+# and the hand-off to a reader of this process behind them; `frames` (one
+# a connection) and `records` (a request's part of a frame)
+PUBLISH = "stream.publish"
 YIELD = "engine.yield"              # the lock released between steps
 # the caller's thread: generate() from entry to the engine's lock held
 SUBMIT = "engine.submit"

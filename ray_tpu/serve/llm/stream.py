@@ -4,33 +4,43 @@ The engine replica opens its own listener (exactly the worker-direct
 idiom: accept loop + `protocol.Connection(server=True)`); consumers
 dial it once per replica through the same `dial_cached` machinery the
 direct actor caller uses and send one `llm_sub` frame per request.
-After that every token is a server-PUSHED `llm_tok` frame on that
-connection — the head sees zero frames per token, the client polls
-nothing.
+After that every token is server-PUSHED on that connection — the head
+sees zero frames per token, the client polls nothing. Everything one
+engine step owes one connection leaves in ONE `llm_tok` frame: a record
+a request, however many of the connection's requests the step moved
+(one encode, one write, one wake of the peer's reader a step, where a
+frame a request cost the step thread 0.12-0.15 ms each: PERF.md,
+Findings PR 59). Nothing is held for a later step. Once the frames are
+written the step thread gives the interpreter up for `HANDOFF_S`, so a
+reader thread of its own process reads them before the next step's host
+work and not at whatever point that work next lets it in.
 
-Fencing: every frame carries the engine's incarnation and the
-request's attempt number. The client registered an expectation at
-subscribe time; stale frames — a zombie replica still decoding into a
-partition, or a frame from a superseded attempt after failover — are
-counted and dropped, never delivered. Duplicate suppression uses the
-`base` sequence offset: subscribe replays the backlog from the
-client's cursor, and overlap trimming makes replay + live racing
-harmless.
+Fencing: every frame carries the engine's incarnation and every record
+its request's attempt number. The client registered an expectation at
+subscribe time; stale records — a zombie replica still decoding into a
+partition, or a superseded attempt after failover — are counted and
+dropped, never delivered, and their neighbours in the frame are.
+Duplicate suppression uses the `base` sequence offset: subscribe
+replays the backlog from the client's cursor, and overlap trimming
+makes replay + live racing harmless.
 
-Wire frames:
 Wire frames use "req" for the request id — the envelope reserves
 "rid" for its own integer reply-id field:
   client -> engine  {"type": "llm_sub", "req", "cursor"}
                     {"type": "llm_unsub", "req"}
-  engine -> client  {"type": "llm_tok", "req", "inc", "attempt",
-                     "base", "toks", "done", "reason", "err"}
-                    ("unknown": True when the rid isn't on this
-                    replica — the consumer fails over)
+  engine -> client  {"type": "llm_tok", "inc", "recs": [record, ...]}
+                    record: {"req", "attempt", "base", "toks", "done",
+                             "reason", "err"} ("unknown": True when the
+                    rid isn't on this replica — the consumer fails over)
+A subscribe's replay and the `unknown` answer are frames of one record.
+The client hands a sink one dict a record, the frame's `type` and `inc`
+beside the record's keys and `unknown`.
 """
 from __future__ import annotations
 
 import socket
 import threading
+import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ray_tpu._private import protocol
@@ -38,12 +48,33 @@ from ray_tpu.serve.llm import spans as _sp
 
 STREAM_STATS = {
     "frames_out": 0,        # server: token frames pushed
+    "records_out": 0,       # server: per-request records inside them
     "frames_in": 0,         # client: token frames received
     "tokens_in": 0,         # client: tokens accepted
-    "zombie_dropped": 0,    # client: frames fenced (stale inc/attempt)
+    "zombie_dropped": 0,    # client: records fenced (stale inc/attempt)
     "conn_drops": 0,        # client: stream connections lost
     "subscribes": 0,        # client: llm_sub frames sent
 }
+
+
+# What the step thread sleeps once a step's frames are written. A consumer
+# inside this process (a test's, the benchmark's, a caller beside the
+# replica) reads on a thread the write has woken and that needs the
+# interpreter: `conn.send` gives it up for the microseconds of its system
+# call, which a reader catches only where the kernel runs it at once, and
+# the next release is wherever the next step's host work blocks. With a
+# frame a request the reader had a chance a lane; with one frame it has
+# this one. The kernel rounds any sleep up by its timer slack, so this
+# asks for the least there is (some 0.06 ms).
+HANDOFF_S = 1e-6
+
+
+def _record(rid: str, src: dict, base: int, toks: List[int]) -> dict:
+    """A request's part of a frame: `toks` from position `base` on, and
+    how the request stands in `src` (a step's record or a backlog)."""
+    return {"req": rid, "attempt": src["attempt"], "base": base,
+            "toks": toks, "done": src["done"], "reason": src["reason"],
+            "err": src["err"]}
 
 
 class TokenStreamServer:
@@ -115,22 +146,15 @@ class TokenStreamServer:
             with self._engine_lock:
                 back = self._backlog(rid, cursor)
                 if back is None:
-                    self._send(conn, {"type": "llm_tok", "req": rid,
-                                      "inc": self._inc, "unknown": True,
-                                      "attempt": -1, "base": cursor,
-                                      "toks": [], "done": True,
-                                      "reason": None,
-                                      "err": "unknown_rid"})
+                    self._send(conn, [{"req": rid, "unknown": True,
+                                       "attempt": -1, "base": cursor,
+                                       "toks": [], "done": True,
+                                       "reason": None,
+                                       "err": "unknown_rid"}])
                     return
                 if back["toks"] or back["done"]:
-                    self._send(conn, {"type": "llm_tok", "req": rid,
-                                      "inc": self._inc,
-                                      "attempt": back["attempt"],
-                                      "base": back["base"],
-                                      "toks": back["toks"],
-                                      "done": back["done"],
-                                      "reason": back["reason"],
-                                      "err": back["err"]})
+                    self._send(conn, [_record(rid, back, back["base"],
+                                              back["toks"])])
                 if not back["done"]:
                     with self._lock:
                         self._subs.setdefault(rid, []).append(
@@ -147,17 +171,19 @@ class TokenStreamServer:
         elif mtype == protocol.PING:
             conn.reply(msg, ok=True)
 
-    def _send(self, conn, frame: dict) -> None:
+    def _send(self, conn, recs: List[dict]) -> None:
+        """One frame: the incarnation once, and a record a request."""
         try:
-            conn.send(frame)
+            conn.send({"type": "llm_tok", "inc": self._inc, "recs": recs})
             STREAM_STATS["frames_out"] += 1
+            STREAM_STATS["records_out"] += len(recs)
         except protocol.ConnectionClosed:
             pass
 
     def publish(self, events: List[dict]) -> None:
-        """Push one step's events. Events are grouped per rid into one
-        frame (a step emits at most one token per sequence, but a
-        drain can batch terminals)."""
+        """Push one step's events: one frame a connection, holding a
+        record for each of its requests the step moved (a step emits at
+        most one token per sequence, but a drain can batch terminals)."""
         per_rid: Dict[str, dict] = {}
         for ev in events:
             rec = per_rid.setdefault(
@@ -170,36 +196,33 @@ class TokenStreamServer:
                 rec["done"] = True
                 rec["reason"] = ev["reason"]
                 rec["err"] = ev.get("err")
+        owed: Dict[protocol.Connection, List[dict]] = {}
+        with self._lock:
+            for rid, rec in per_rid.items():
+                for s in self._subs.get(rid, ()):
+                    conn, sent = s
+                    base, toks = rec["base"], rec["toks"]
+                    if sent > base:
+                        # replay already covered part of this record
+                        skip = min(sent - base, len(toks))
+                        base, toks = base + skip, toks[skip:]
+                        if not toks and not rec["done"]:
+                            continue
+                    owed.setdefault(conn, []).append(
+                        _record(rid, rec, base, toks))
+                    s[1] = base + len(toks)
+                if rec["done"]:
+                    self._subs.pop(rid, None)
+        if not owed:
+            return
         # one span for all of a step's frames: a span around each send
         # cost the step thread more than the bound of the cell it was
         # measured in (PERF.md, Findings PR 26)
-        with _sp.span(_sp.PUBLISH, frames=len(per_rid)):
-            self._push(per_rid)
-
-    def _push(self, per_rid: Dict[str, dict]) -> None:
-        for rid, rec in per_rid.items():
-            with self._lock:
-                subs = list(self._subs.get(rid, ()))
-            for s in subs:
-                conn, sent = s
-                base, toks = rec["base"], rec["toks"]
-                if sent > base:
-                    # replay already covered part of this frame
-                    skip = min(sent - base, len(toks))
-                    base, toks = base + skip, toks[skip:]
-                    if not toks and not rec["done"]:
-                        continue
-                self._send(conn, {"type": "llm_tok", "req": rid,
-                                  "inc": self._inc,
-                                  "attempt": rec["attempt"],
-                                  "base": base, "toks": toks,
-                                  "done": rec["done"],
-                                  "reason": rec["reason"],
-                                  "err": rec["err"]})
-                s[1] = base + len(toks)
-            if rec["done"]:
-                with self._lock:
-                    self._subs.pop(rid, None)
+        with _sp.span(_sp.PUBLISH, frames=len(owed),
+                      records=sum(map(len, owed.values()))):
+            for conn, recs in owed.items():
+                self._send(conn, recs)
+            time.sleep(HANDOFF_S)
 
     def close(self) -> None:
         self._closed.set()
@@ -270,21 +293,27 @@ class StreamClient:
         if msg.get("type") != "llm_tok":
             return
         STREAM_STATS["frames_in"] += 1
-        rid = msg.get("req")
+        inc, recs = msg.get("inc"), msg.get("recs", ())
         with self._lock:
-            route = self._routes.get(rid)
-        if route is None:
-            return
-        sink, inc, attempt, _addr = route
-        if not msg.get("unknown") and (msg.get("inc") != inc
-                                       or msg.get("attempt") != attempt):
-            # zombie fence: a stale incarnation (replica restarted /
-            # partitioned survivor) or superseded attempt never
-            # reaches the consumer
-            STREAM_STATS["zombie_dropped"] += 1
-            return
-        STREAM_STATS["tokens_in"] += len(msg.get("toks", ()))
-        sink.put(msg)
+            routes = [self._routes.get(rec.get("req")) for rec in recs]
+        for rec, route in zip(recs, routes):
+            if route is None:
+                continue
+            sink, expect_inc, expect_attempt, _addr = route
+            if not rec.get("unknown") and (
+                    inc != expect_inc
+                    or rec.get("attempt") != expect_attempt):
+                # zombie fence: a stale incarnation (replica restarted /
+                # partitioned survivor) or superseded attempt never
+                # reaches the consumer
+                STREAM_STATS["zombie_dropped"] += 1
+                continue
+            STREAM_STATS["tokens_in"] += len(rec.get("toks", ()))
+            # the decoded record is this frame's alone: it becomes the
+            # message, the frame's `type` and `inc` beside its own keys
+            rec.update(type="llm_tok", inc=inc)
+            rec.setdefault("unknown", False)
+            sink.put(rec)
 
     def _on_close(self, conn) -> None:
         STREAM_STATS["conn_drops"] += 1

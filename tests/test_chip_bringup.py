@@ -368,7 +368,8 @@ def test_stream_subscribe_replays_before_any_live_frame():
         frames = []
 
         def send(self, frame):
-            self.frames.append((frame["base"], list(frame["toks"])))
+            self.frames += [(r["base"], list(r["toks"]))
+                            for r in frame["recs"]]
 
     server = TokenStreamServer("inc0", backlog, engine_lock)
     try:

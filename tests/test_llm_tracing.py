@@ -331,7 +331,13 @@ def test_program_names_the_benchmark_reads(tiny_model):
     assert "module @jit__pre" in pre.as_text()
 
 
-def test_llm_engine_emits_every_span_of_the_table(tiny_model, recorder):
+def test_llm_engine_emits_every_span_of_the_table(tiny_model, recorder,
+                                                  monkeypatch):
+    # `stream.publish` is written where a frame is: a real pause between
+    # steps, so the subscriber is let in before the generation is over
+    # (at 0 the step thread can re-take its lock through all four steps)
+    monkeypatch.setenv("RAY_TPU_LLM_STEP_DELAY_S", "0.02")
+    CONFIG.reload()
     eng = LLMEngine(model="tiny", num_pages=32, page_size=8, max_batch=2,
                     seed=0)
     try:
@@ -355,7 +361,9 @@ def test_llm_engine_emits_every_span_of_the_table(tiny_model, recorder):
         assert all(e[2] == 0 for e in evs if e[4] == name), name
     sub = [e for e in evs if e[4] == sp.SUBMIT]
     assert [e[7] for e in sub] == [{"rid": "s"}]
-    assert all(e[7]["frames"] >= 1 for e in evs if e[4] == sp.PUBLISH)
+    # frames written, one a connection, and the requests' records in them
+    assert all(e[7]["records"] >= e[7]["frames"] >= 1
+               for e in evs if e[4] == sp.PUBLISH)
 
 
 def test_step_histogram_on_the_metrics_plane(tiny_model):

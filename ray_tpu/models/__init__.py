@@ -18,7 +18,10 @@ cache's addresses in `paged.py`, paged grouped-query attention in `gqa.py`,
 latent attention in `latent.py`, the state-space mixer in `ssm.py`, the
 routed feed-forwards in `moe.py`. `GatedConvMoE` (`gated_conv_moe.py`) is
 the one whose mixer is a convolution and nothing else, whose heads are half
-a 128-lane wide and whose head is the embedding's table.
+a 128-lane wide and whose head is the embedding's table. `SparseMLAMoE`
+(`sparse_mla_moe.py`) is `MLAMoE` whose attention reads the positions a
+learned indexer chooses, with the indexer's keys a second pool under the
+latent pool's page ids.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401
@@ -40,6 +43,8 @@ from ray_tpu.models.parallel_hybrid import (  # noqa: F401,E402
     ParallelHybrid, ParallelHybridConfig)
 from ray_tpu.models.gated_conv_moe import (  # noqa: F401,E402
     GatedConvMoE, GatedConvMoEConfig)
+from ray_tpu.models.sparse_mla_moe import (  # noqa: F401,E402
+    SparseMLAMoE, SparseMLAMoEConfig)
 
 
 # name -> (config class, model class). A dict of config fields names its
@@ -52,7 +57,8 @@ MODELS = {"transformer": (TransformerConfig, Transformer),
           "hybrid_ssm_moe": (HybridSSMMoEConfig, HybridSSMMoE),
           "hybrid_kda_moe": (HybridKDAMoEConfig, HybridKDAMoE),
           "parallel_hybrid": (ParallelHybridConfig, ParallelHybrid),
-          "gated_conv_moe": (GatedConvMoEConfig, GatedConvMoE)}
+          "gated_conv_moe": (GatedConvMoEConfig, GatedConvMoE),
+          "sparse_mla_moe": (SparseMLAMoEConfig, SparseMLAMoE)}
 
 
 def model_config(model):

@@ -30,6 +30,10 @@ ATTN_IN = "r.attn_in"
 # the flash / paged / window / latent kernel, or the gather and einsum
 # that stand in for it
 ATTN_CORE = "r.attn_core"
+# a learned sparse attention's indexer: its projections, its key's norm
+# and rotary, the index pool's write, the scores over the cached index
+# keys and the choice of the positions the attention reads
+ATTN_INDEX = "r.attn_index"
 # the absorbed form's W_UV relay, a head gate, the output projection and
 # the residual addition it feeds
 ATTN_OUT = "r.attn_out"
@@ -58,7 +62,8 @@ SAMPLE = "r.sample"
 # lane writes, a prefill's page ids, the experts' load counts
 CACHE = "r.cache"
 
-ALL = (EMBED, NORM, ATTN_IN, ATTN_CORE, ATTN_OUT, MIXER_IN, MIXER_CORE,
+ALL = (EMBED, NORM, ATTN_IN, ATTN_INDEX, ATTN_CORE, ATTN_OUT, MIXER_IN,
+       MIXER_CORE,
        MIXER_OUT, FFN, MOE_ROUTE, MOE_EXPERTS, HEAD, SAMPLE, CACHE)
 
 # `with region(NORM): ...`, or `@region(NORM)` on a function that is one

@@ -149,7 +149,7 @@ def test_metric_has_a_reader_and_lists_cells_that_exist(entry):
 
 
 def test_a_fifth_of_the_cells_may_take_four_chips_and_none_does():
-    assert len(CELLS) == 11 and not [c for c in CELLS if c["chips"] != 1]
+    assert len(CELLS) == 12 and not [c for c in CELLS if c["chips"] != 1]
     assert len({(c["config"], c["traffic"]) for c in CELLS}) == len(CELLS)
 
 
@@ -400,4 +400,119 @@ def test_share_metrics_leave_the_line_where_there_is_nothing_to_read(
         _spans=spans.Reading(plain, {}, 0.0))
     assert metric(name)(run) is None
     run.update(trace=None, _spans=None)
+    assert metric(name)(run) is None
+
+
+# --------------------------------------------- PR 61's metric readers
+GLM5 = "glm-5-1chip"
+DSA_METRICS = [
+    "step.attn_index_ms.code16k", "step.attn_sparse_ms.code16k",
+    "kernel.dsa_index_roofline.code16k", "kernel.dsa_attend_roofline.code16k",
+    "kernel.dsa_prefill_roofline.code16k", "dsa.selected_share.code16k"]
+
+
+@pytest.fixture()
+def traced_dsa_run():
+    """Two decode steps and a prefill of 5,000 tokens as `op_scopes` files
+    them: in a step, five layers of 0.4 ms in `r.attn_index` (a kernel and
+    a reduction) and 0.7 ms in `r.attn_core`, 6 ms of everything else; in
+    the prefill 30 ms of index scores and 100 ms of masked flash."""
+    from benchmarks.harness import op_scopes
+    cfg = modelcfg.load_config(GLM5)
+    model = modelcfg.load_model(cfg)
+    ps = 1e9                                    # picoseconds a millisecond
+
+    def op(path, ms):
+        return (op_scopes.OpMeta("%op = f32[1] fusion()", tf_op=path),
+                int(ms * ps))
+
+    step_ops = [op("jit(_step)/r.ffn/dot_general", 6.0)]
+    for _ in range(5):
+        step_ops += [op("jit(_step)/r.attn_index/jit(_paged_index_call)/"
+                        "dsa_paged_index/pallas_call", 0.3),
+                     op("jit(_step)/r.attn_index/while/body/reduce_sum", 0.1),
+                     op("jit(_step)/r.attn_core/jit(_paged_attend_call)/"
+                        "dsa_paged_attend/pallas_call", 0.7)]
+    pre_ops = [op("jit(_pre)/r.attn_index/dsa_index_scores/pallas_call", 30.0),
+               op("jit(_pre)/r.attn_core/dsa_flash_fwd/pallas_call", 100.0),
+               op("jit(_pre)/r.moe_experts/moe_gmm/pallas_call", 250.0)]
+    dev = op_scopes.DeviceOps([], [
+        op_scopes.Execution("jit__step", 7, 0, 0, step_ops),
+        op_scopes.Execution("jit__pre", 9, 0, 0, pre_ops),
+        op_scopes.Execution("jit__step", 7, 0, 0, list(step_ops))])
+    steps = []
+    for t0 in (0.0, 0.5):
+        steps.append(E(spans.DISPATCH, t0, 1e-4, {
+            "lanes": 32, "live_positions": 192000, "read_positions": 524288}))
+        steps.append(E("engine.emit", t0 + 0.011, 1e-4, {
+            "moe_pairs": 64, "dsa_positions_scored": 5 * 192000,
+            "dsa_positions_selected": 5 * 32 * 2048,
+            "dsa_lanes_past_topk": 5 * 32}))
+    steps.append(E(spans.PREFILL, 0.1, 0.38, {"tokens": 5000,
+                                              "bucket": 8192}))
+    return {"trace": xplane.Trace({0: []}, {0: []}, {}, {}),
+            "model": model, "sizes": model.sizes(cfg), "cfg": cfg,
+            "peaks": PEAKS["TPU v5 lite"], "result": {"traced": {}},
+            "_spans": spans.Reading(steps, {}, 0.0), "_op_scopes": dev}
+
+
+def test_dsa_step_regions_and_counts(traced_dsa_run):
+    run = traced_dsa_run
+    assert metric("step.attn_index_ms.code16k")(run) == pytest.approx(2.0)
+    assert metric("step.attn_sparse_ms.code16k")(run) == pytest.approx(3.5)
+    assert metric("dsa.selected_share.code16k")(run) == pytest.approx(
+        100 * 2048 * 32 / 192000)
+    assert metric("cache.index_bytes_share.code16k")(run) == pytest.approx(
+        100 * 128 / 768)
+
+
+def test_dsa_rooflines_count_what_the_algorithm_moves(traced_dsa_run):
+    run = traced_dsa_run
+    model, sz = run["model"], run["sizes"]
+    need = model.dsa_index_call(sz, 384000, 64)
+    # a 256-byte key and a float32 score a live position and layer; a
+    # lane's 32 index queries of 128 and 32 float32 weights in
+    assert need["bytes"] == 5 * (384000 * (256 + 4) + 64 * 32 * (256 + 4))
+    assert need["flops"] == 2.0 * 32 * 128 * 384000 * 5
+    want = 100 * (need["bytes"] / 819e9) / (2 * 2.0e-3)
+    assert metric("kernel.dsa_index_roofline.code16k")(run) == \
+        pytest.approx(want, rel=1e-6)
+    assert 0 < want < 100
+    need = model.dsa_attend_call(sz, 2 * 5 * 32 * 2048, 2 * 32 * 5)
+    # 1,152 bytes a chosen row; 64 queries of 576 in and latents of 512 out
+    assert need["bytes"] == (2 * 5 * 32 * 2048 * 1152
+                             + 2 * 32 * 5 * 64 * (576 + 512) * 2)
+    want = 100 * (need["bytes"] / 819e9) / (2 * 3.5e-3)
+    assert metric("kernel.dsa_attend_roofline.code16k")(run) == \
+        pytest.approx(want, rel=1e-6)
+    assert 0 < want < 100
+    # reading every live row would be more bytes than the chosen rows: an
+    # implementation that does reads low, never above its roofline
+    assert need["bytes"] < model.dsa_attend_call(
+        sz, 2 * 5 * 192000, 2 * 32 * 5)["bytes"]
+    need = model.dsa_prefill_call(sz, 5000)
+    chosen = 2048 * 2049 / 2 + (5000 - 2048) * 2048
+    assert need["flops"] == 5 * (2.0 * 32 * 128 * 5000 * 5001 / 2
+                                 + 2.0 * 64 * 512 * chosen)
+    want = 100 * (need["flops"] / 197e12) / 0.130
+    assert metric("kernel.dsa_prefill_roofline.code16k")(run) == \
+        pytest.approx(want, rel=1e-6)
+    assert 0 < want < 100
+
+
+@pytest.mark.parametrize("name", DSA_METRICS)
+def test_dsa_metrics_leave_the_line_where_there_is_nothing_to_read(
+        traced_dsa_run, traced_glm_run, name):
+    """A program without an indexer (no such region, no such count: every
+    other class, and the parent of PR 61), an untraced run: None, and
+    nothing raised."""
+    from benchmarks.harness import op_scopes
+    run = dict(traced_glm_run)
+    run["_op_scopes"] = op_scopes.DeviceOps([], [op_scopes.Execution(
+        "jit__step", 7, 0, 0, [(op_scopes.OpMeta(
+            "%op = f32[1] fusion()",
+            tf_op="jit(_step)/r.ffn/dot_general"), 10 ** 9)])])
+    assert metric(name)(run) is None
+    run = dict(traced_dsa_run)
+    run.update(trace=None, _spans=None, _op_scopes=None)
     assert metric(name)(run) is None

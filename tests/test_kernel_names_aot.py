@@ -531,6 +531,42 @@ def test_shortcut_mla_moe_programs_hold_their_kernels_by_name(
     assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
 
 
+# ------------------------------ the class with a learned sparse attention
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_sparse_mla_moe_programs_hold_their_kernels_by_name(
+        which, topo, no_compile_cache):
+    """`SparseMLAMoE`'s decode step or 4096-token prefill (past
+    `index_topk`: the sparse forms): the dense layer and one expert layer
+    at the published widths of GLM-5 (64 heads, latent 512 + 64, 32 index
+    heads of 128, 16 of 256 experts of 6144 x 2048). What the interpreter
+    cannot refuse (tiling, fast memory) the chip's compiler does here."""
+    from ray_tpu.models.sparse_mla_moe import (SparseMLAMoE,
+                                               SparseMLAMoEConfig)
+    from ray_tpu.ops import grouped_matmul, sparse_attention
+    model = SparseMLAMoE(SparseMLAMoEConfig(
+        vocab_size=1024, n_layers=2, first_k_dense_replace=1,
+        experts_held=(0, 16), max_seq_len=4096))
+    compiled, cache = _compile_served(
+        topo.devices, model, which, lambda: model.init_cache(PAGES, PAGE),
+        sparse_attention.KERNEL_PAGED_ATTEND)
+    names = kernel_names(compiled.as_text())
+    assert names.count(grouped_matmul.KERNEL_GMM) == 3
+    assert paged_attention.KERNEL_MLA_PAGED_DECODE not in names
+    assert attention.KERNEL_FWD not in names
+    if which == "step":     # a walk over index pages, one over latent rows
+        assert names.count(sparse_attention.KERNEL_PAGED_INDEX) == 2
+        assert names.count(sparse_attention.KERNEL_PAGED_ATTEND) == 2
+        assert sparse_attention.KERNEL_FLASH_FWD not in names
+    else:                   # index scores a block of queries, then flash
+        assert names.count(sparse_attention.KERNEL_INDEX_SCORES) == 2
+        assert names.count(sparse_attention.KERNEL_FLASH_FWD) == 2
+    # both pools under one page id, both updated in place
+    assert cache["kv"].shape == (2, PAGES, PAGE, 640)
+    assert cache["idx"].shape == (2, PAGES, PAGE, 128)
+    nbytes = sum(2 * a.size for a in (cache["kv"], cache["idx"]))
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+
+
 # -------------------------------------- the sixth architecture's step
 def _compile_hybrid_ssm_moe(devices, which: str, slots: int = 32):
     """`HybridSSMMoE`'s decode step or 4096-token prefill: one layer of

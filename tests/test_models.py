@@ -447,13 +447,15 @@ def _tiny_served():
     from ray_tpu.models.mla_moe import tiny_mla_moe
     from ray_tpu.models.parallel_hybrid import tiny_parallel_hybrid
     from ray_tpu.models.shortcut_mla_moe import tiny_shortcut_mla_moe
+    from ray_tpu.models.sparse_mla_moe import tiny_sparse_mla_moe
     return {"MLAMoE": tiny_mla_moe, "GQAWindowMoE": tiny_gqa_window_moe,
             "HybridDelta": tiny_hybrid_delta,
             "ShortcutMLAMoE": tiny_shortcut_mla_moe,
             "HybridSSMMoE": tiny_hybrid_ssm_moe,
             "HybridKDAMoE": tiny_hybrid_kda_moe,
             "ParallelHybrid": tiny_parallel_hybrid,
-            "GatedConvMoE": tiny_gated_conv_moe}
+            "GatedConvMoE": tiny_gated_conv_moe,
+            "SparseMLAMoE": tiny_sparse_mla_moe}
 
 
 def _tree_sha256(tree) -> str:
@@ -485,6 +487,9 @@ INIT_SHA256 = {
     "ParallelHybrid": "e6602d2e0cf60bfe",
     # the ninth, as PR 58 made it (one table: the head is the embedding's)
     "GatedConvMoE": "ae1fa1c05a49a2dd",
+    # the tenth, as PR 61 made it (`MLAMoE`'s leaves, the indexer's behind
+    # them, the held experts')
+    "SparseMLAMoE": "6c8d1c95cb0cd4d3",
 }
 
 
@@ -510,7 +515,8 @@ def _tiny_models():
 @pytest.mark.parametrize("name", ["transformer", "mla_moe", "gqa_window_moe",
                                   "hybrid_delta", "shortcut_mla_moe",
                                   "hybrid_ssm_moe", "hybrid_kda_moe",
-                                  "parallel_hybrid", "gated_conv_moe"])
+                                  "parallel_hybrid", "gated_conv_moe",
+                                  "sparse_mla_moe"])
 def test_every_class_answers_the_engines_twelve_asks(name):
     """What `EngineCore` calls on a model, on every class of the table,
     with the types it uses them as (`models.paged.PagedDecoder`)."""

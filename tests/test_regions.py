@@ -26,7 +26,10 @@ TINY = {"transformer": C.tiny,
         "hybrid_ssm_moe": M.hybrid_ssm_moe.tiny_hybrid_ssm_moe,
         "hybrid_kda_moe": M.hybrid_kda_moe.tiny_hybrid_kda_moe,
         "parallel_hybrid": M.parallel_hybrid.tiny_parallel_hybrid,
-        "gated_conv_moe": M.gated_conv_moe.tiny_gated_conv_moe}
+        "gated_conv_moe": M.gated_conv_moe.tiny_gated_conv_moe,
+        # (a choice of 16: the 32-token prompt and the tables pass it)
+        "sparse_mla_moe": lambda: M.sparse_mla_moe.tiny_sparse_mla_moe(
+            index_topk=16)}
 assert set(TINY) == set(M.MODELS)
 
 ALWAYS = {R.EMBED, R.NORM, R.ATTN_IN, R.ATTN_CORE, R.ATTN_OUT, R.FFN, R.HEAD}
@@ -38,7 +41,8 @@ SHOWS = {"transformer": ALWAYS, "mla_moe": ALWAYS | EXPERTS,
          "hybrid_ssm_moe": ALWAYS | MIXER | EXPERTS,
          "hybrid_kda_moe": ALWAYS | MIXER | EXPERTS,
          "parallel_hybrid": ALWAYS | MIXER,
-         "gated_conv_moe": ALWAYS | MIXER | EXPERTS}
+         "gated_conv_moe": ALWAYS | MIXER | EXPERTS,
+         "sparse_mla_moe": ALWAYS | EXPERTS | {R.ATTN_INDEX}}
 
 PAGE, LANES, PROMPT, TABLE = 16, 4, 32, 8
 # the instructions the rule is about ("custom-call": a Pallas kernel, and

@@ -63,7 +63,7 @@ def fill(key: jax.Array, shapes, dtype):
 
 
 class PagedDecoder:
-    """A model as the serving engine sees it: twelve asks. Six a class
+    """A model as the serving engine sees it: thirteen asks. Six a class
     answers itself:
 
     - `init_cache(num_pages, page_size, dtype=None[, fixed_pages=0])`: the
@@ -82,12 +82,13 @@ class PagedDecoder:
     - `walk_block_pages(page_size, max_pages)`: pages a block of the decode
       kernel's walk holds (the engine's walk counts stand on it).
 
-    The other six have the answer here of a model that keeps nothing of a
-    sequence for ever and counts nothing. A class whose layers are held one
-    by one says `layer_shapes(i)` (a tree of `(shape, init std)` of layer
-    i's leaves) and `hidden(params, tokens)` (the stream after the final
-    norm) and gets `init`, `param_count`, `apply`, `loss` and the tail of
-    both programs; `Transformer` (stacked layers, a mesh) keeps its own."""
+    The other seven have the answer here of a model that keeps nothing of
+    a sequence for ever, walks its pages one by one and counts nothing. A
+    class whose layers are held one by one says `layer_shapes(i)` (a tree
+    of `(shape, init std)` of layer i's leaves) and `hidden(params,
+    tokens)` (the stream after the final norm) and gets `init`,
+    `param_count`, `apply`, `loss` and the tail of both programs;
+    `Transformer` (stacked layers, a mesh) keeps its own."""
 
     # why the class refuses a mesh: what is not sharded over chips yet
     # (PERF.md section 7)
@@ -189,6 +190,13 @@ class PagedDecoder:
         """Pages of the allocator's fixed class a sequence holds for ever
         (0: `kv_cache.PageAllocator`'s one class)."""
         return 0
+
+    def page_run(self, page_size: int, max_pages: int) -> int:
+        """Pages of the class that grows that a sequence is to be handed
+        at once, ids behind one another from a multiple of it on
+        (`kv_cache.PageAllocator`'s `run`): what one copy of the class's
+        decode walk brings. 1: a page at a time, in any order."""
+        return 1
 
     def fixed_step_counts(self, length: int, page_size: int,
                           kernel: bool = True) -> Dict[str, int]:

@@ -306,16 +306,17 @@ class SparseMLAMoE(MLAMoE):
                             ((0, 0), (0, 0), (0, pad))).astype(pool.dtype)
         kernel = self._step_kernels(pool.shape[2], page_tables.shape[1],
                                     pool.dtype)
+        run = self.page_run(pool.shape[2], page_tables.shape[1])
         with R.region(R.ATTN_INDEX):
             choice, chosen = _sparse.choose_paged(
                 q_idx.astype(idx_pool.dtype), w, idx_pool, row, page_tables,
-                lengths, c.index_topk, kernel)
+                lengths, c.index_topk, kernel, run=run)
             counts = (seen, jnp.sum(chosen).astype(jnp.int32),
                       jnp.sum(lengths > c.index_topk).astype(jnp.int32))
         with R.region(R.ATTN_CORE):
             o_lat = _sparse.attend_chosen(
                 q_row, pool, row, page_tables, lengths, choice, latent,
-                1.0 / math.sqrt(c.qk_head_dim), kernel)
+                1.0 / math.sqrt(c.qk_head_dim), kernel, run=run)
         with R.region(R.ATTN_OUT):
             out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
                              w_kvb[..., nope:])
@@ -380,6 +381,19 @@ class SparseMLAMoE(MLAMoE):
         page = page_size * c.row_width * jnp.dtype(
             c.activation_dtype).itemsize
         return _paged.walk_block_pages(page, page_size, max_pages)
+
+    def page_run(self, page_size: int, max_pages: int) -> int:
+        """Pages one copy of the step's two walks brings, which the
+        allocator is asked to hand out behind one another: by what a page
+        of index keys, the smaller pool's, weighs a layer
+        (`ops.sparse_attention.walk_run_pages`); 1 where a step traced
+        here runs no walk kernel."""
+        c = self.config
+        if c.max_seq_len <= c.index_topk or not self._step_kernels(
+                page_size, max_pages, c.activation_dtype):
+            return 1
+        return _sparse.walk_run_pages(
+            self.index_page_bytes(page_size) // c.n_layers, max_pages)
 
     def decode_attention(self, page_size: int, dtype=None) -> str:
         """Which attention a `decode_step` traced here holds: `MLAMoE`'s

@@ -47,6 +47,7 @@ truth); tests reach the kernels through the Pallas interpreter
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -148,29 +149,57 @@ def mla_selected_attention(q, pool, layer: int, page_tables, positions,
 # bytes each, where a page of 16 rows costs one copy too, so that up to
 # some 32k positions a lane reading every live row and masking is the
 # cheaper way to read 2,048 of them).
+#
+# A copy costs the scalar core some 40-58 ns to start whatever it brings
+# (PERF.md section 6, PR 61 and PR 62), which a page of 4 KB of index keys
+# (5 ns of bytes) or of 20 KB of latent rows (25 ns) does not cover. Where
+# the allocator hands a sequence its pages in aligned runs of `run`
+# consecutive ids (`serve/llm/kv_cache.py`; `walk_run_pages` says how
+# long), a walk is told so and brings a run a copy: entry `k * run` of a
+# table names the first page of `run` that lie behind one another in the
+# pool, all the sequence's own, those no position has reached yet too.
 INDEX_WALK_PAGES = 256          # 4,096 positions of index keys a block
 ATTEND_WALK_PAGES = 64          # 1,024 latent rows a block
+# Bytes of the smaller pool that one copy of a walk should bring, so that
+# the bytes and not the descriptor are what a copy costs
+RUN_COPY_BYTES = 32 << 10
+
+
+def walk_run_pages(page_bytes: int, max_pages: int) -> int:
+    """Pages a run holds where a page of the smaller pool walked is
+    `page_bytes` (one layer's): as many as make a copy of `RUN_COPY_BYTES`,
+    cut to a divisor of the table's `max_pages` and of both walks' blocks
+    (a run lies in one block)."""
+    return math.gcd(
+        1 << (-(-RUN_COPY_BYTES // page_bytes) - 1).bit_length(),
+        max_pages, min(INDEX_WALK_PAGES, max_pages),
+        min(ATTEND_WALK_PAGES, max_pages))
 
 
 def _walk_blocks(layer_ref, len_ref, pt_ref, pool_hbm, buf, sems, block,
-                 *, page_size: int, block_pages: int, max_pages: int):
+                 *, page_size: int, block_pages: int, max_pages: int,
+                 run: int):
     """`block(blk, slot)` on every block of `block_pages` pages the grid's
     lane holds, its pages copied into `buf[slot]` (`slots`, block_pages,
-    page_size, width), the next block's on their way meanwhile."""
+    page_size, width), the next block's on their way meanwhile: a copy a
+    page, or one a run of `run` pages that holds a live page (the table's
+    entry `k * run` names the run's first)."""
     b = pl.program_id(0)
     layer = layer_ref[0]
     pages = pl.cdiv(len_ref[b], page_size)
     n_blocks = pl.cdiv(pages, block_pages)
 
     def each_copy(blk, slot, act):
-        def one(p, carry):
-            page = pt_ref[b * max_pages + blk * block_pages + p]
+        def one(k, carry):
+            page = jnp.maximum(
+                pt_ref[b * max_pages + blk * block_pages + k * run], 0)
             act(pltpu.make_async_copy(
-                pool_hbm.at[layer, jnp.maximum(page, 0)], buf.at[slot, p],
+                pool_hbm.at[layer, pl.ds(pl.multiple_of(page, run), run)],
+                buf.at[slot, pl.ds(pl.multiple_of(k * run, run), run)],
                 sems.at[slot]))
             return carry
-        lax.fori_loop(0, jnp.minimum(pages - blk * block_pages,
-                                     block_pages), one, 0)
+        lax.fori_loop(0, pl.cdiv(jnp.minimum(pages - blk * block_pages,
+                                             block_pages), run), one, 0)
 
     @pl.when(n_blocks > 0)
     def _():
@@ -206,23 +235,28 @@ def _paged_index_kernel(layer_ref, len_ref, pt_ref,             # scalars
                  **walk)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+def _whole_blocks(max_pages: int, block_pages: int, run: int) -> None:
+    if max_pages % block_pages or block_pages % run:
+        raise ValueError(f"tables of {max_pages} pages are not whole blocks "
+                         f"of {block_pages} in whole runs of {run}")
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "run"))
 def _paged_index_call(q_idx, w, idx_pool, layer, page_tables, lengths,
-                      interpret: bool):
+                      interpret: bool, run: int = 1):
     lanes, heads, width = q_idx.shape
     page_size, max_pages = idx_pool.shape[2], page_tables.shape[1]
     block_pages = min(INDEX_WALK_PAGES, max_pages)
     span = max_pages * page_size
-    if max_pages % block_pages:
-        raise ValueError(f"tables of {max_pages} pages are not whole blocks "
-                         f"of {block_pages}")
+    _whole_blocks(max_pages, block_pages, run)
 
     def lane(b, *_):
         return b, 0, 0
 
     call = pl.pallas_call(
         functools.partial(_paged_index_kernel, page_size=page_size,
-                          block_pages=block_pages, max_pages=max_pages),
+                          block_pages=block_pages, max_pages=max_pages,
+                          run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(lanes,),
@@ -280,17 +314,16 @@ def _paged_attend_kernel(layer_ref, len_ref, pt_ref,            # scalars
         o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("latent", "sm_scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("latent", "sm_scale",
+                                             "interpret", "run"))
 def _paged_attend_call(q, pool, layer, page_tables, lengths, keep,
-                       latent: int, sm_scale: float, interpret: bool):
+                       latent: int, sm_scale: float, interpret: bool,
+                       run: int = 1):
     lanes, heads, width = q.shape
     page_size, max_pages = pool.shape[2], page_tables.shape[1]
     block_pages = min(ATTEND_WALK_PAGES, max_pages)
     span = max_pages * page_size
-    if max_pages % block_pages:
-        raise ValueError(f"tables of {max_pages} pages are not whole blocks "
-                         f"of {block_pages}")
+    _whole_blocks(max_pages, block_pages, run)
     sublanes = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
     hp = -(-heads // sublanes) * sublanes
     qp = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
@@ -301,7 +334,8 @@ def _paged_attend_call(q, pool, layer, page_tables, lengths, keep,
     call = pl.pallas_call(
         functools.partial(_paged_attend_kernel, sm_scale=sm_scale,
                           latent=latent, page_size=page_size,
-                          block_pages=block_pages, max_pages=max_pages),
+                          block_pages=block_pages, max_pages=max_pages,
+                          run=run),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(lanes,),
@@ -359,17 +393,20 @@ def step_uses_kernels(index_width: int, row_width: int, latent: int,
 
 
 def choose_paged(q_idx, w, idx_pool, layer: int, page_tables, lengths,
-                 topk: int, kernel: bool, interpret: bool = False):
+                 topk: int, kernel: bool, interpret: bool = False,
+                 run: int = 1):
     """A decode step's indexer of one layer: the scores of every position
     a lane holds and the `topk` best of them. Shapes as
     `index_scores_paged`. Returns (the choice as `attend_chosen` takes it,
     positions chosen a lane (B,) int32). `kernel`: the walk over the live
     index pages as a kernel and the choice a mask over the table's span
-    (`keep_topk`), else the gather and `select_topk`'s positions."""
+    (`keep_topk`), else the gather and `select_topk`'s positions. `run`:
+    the pages one copy of the kernel's walk brings, which the tables'
+    owner vouches lie in such runs (`_walk_blocks`)."""
     if kernel:
         keep = keep_topk(_paged_index_call(
-            q_idx, w, idx_pool, layer, page_tables, lengths, interpret),
-            topk)
+            q_idx, w, idx_pool, layer, page_tables, lengths, interpret,
+            run=run), topk)
         return keep, jnp.sum(keep, axis=1).astype(jnp.int32)
     positions, chosen = select_topk(index_scores_paged(
         q_idx, w, idx_pool, layer, page_tables, lengths), topk)
@@ -378,13 +415,15 @@ def choose_paged(q_idx, w, idx_pool, layer: int, page_tables, lengths,
 
 def attend_chosen(q, pool, layer: int, page_tables, lengths, choice,
                   latent: int, sm_scale: float, kernel: bool,
-                  interpret: bool = False):
+                  interpret: bool = False, run: int = 1):
     """The absorbed attention over what `choose_paged` chose (with the same
-    `kernel`): every live row read and the chosen kept, as a kernel, or the
-    chosen rows gathered. Returns (B, heads, latent) in q's dtype."""
+    `kernel` and `run`): every live row read and the chosen kept, as a
+    kernel, or the chosen rows gathered. Returns (B, heads, latent) in q's
+    dtype."""
     if kernel:
         return _paged_attend_call(q, pool, layer, page_tables, lengths,
-                                  choice, latent, float(sm_scale), interpret)
+                                  choice, latent, float(sm_scale), interpret,
+                                  run=run)
     return mla_selected_attention(q, pool, layer, page_tables, *choice,
                                   latent, sm_scale)
 

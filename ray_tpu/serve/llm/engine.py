@@ -241,8 +241,11 @@ class EngineCore:
         # `_fixed` pages a sequence holds: the allocator's fixed class
         # (`kv_cache.py`), `_fixed` a decode lane; 0: one class, one pool
         self._fixed = self.model.fixed_pages(self.page_size)
-        self.alloc = PageAllocator(self.num_pages, fixed=self._fixed,
-                                   sequences=self.max_batch)
+        # and the model says how many pages one copy of its decode walk
+        # brings: the allocator hands the growing class out in such runs
+        self.alloc = PageAllocator(
+            self.num_pages, fixed=self._fixed, sequences=self.max_batch,
+            run=self.model.page_run(self.page_size, self.max_pages_per_seq))
         with _SetupPhase(self.record, _sp.SETUP_CACHE) as span:
             self._cache = self.model.init_cache(
                 self.num_pages, self.page_size,
@@ -294,6 +297,9 @@ class EngineCore:
             # the lanes' pages came in, and the positions its matmuls
             # multiplied (the part of each block a lane holds, in pieces)
             "kv_walk_blocks": 0, "kv_positions_attended": 0,
+            # the copies that walk started, a layer and pool: one a run of
+            # the allocator's that holds a live page (a page, at runs of 1)
+            "kv_walk_copies": 0,
             # and the lanes whose first block the lane before them started
             # behind its own last: of `decode_lane_steps`, all but one a
             # dispatch wait for no copy that nothing hides
@@ -648,7 +654,8 @@ class EngineCore:
             pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
             active = np.zeros((B,), bool)
             kernel = self._attention != "einsum"
-            live = held = blocks = attended = 0
+            run = self.alloc.run
+            live = copies = blocks = attended = 0
             walked: List[int] = []
             fixed: Dict[str, int] = {}
             for seq in batch:
@@ -658,7 +665,7 @@ class EngineCore:
                 active[i] = True
                 live += seq.device_len
                 pages = pages_needed(seq.device_len, self.page_size)
-                held += pages
+                copies += -(-pages // run)
                 if kernel:
                     blocks += self._walk[pages][0]
                     attended += self._walk[pages][1]
@@ -669,10 +676,12 @@ class EngineCore:
                         fixed[name] = fixed.get(name, 0) + n
             args = (jnp.asarray(positions), jnp.asarray(pts),
                     jnp.asarray(active))
-        # the kernel copies in each lane's live pages, whole
-        read = held * self.page_size if kernel else self._table_positions
-        if not kernel:      # the gather multiplies all it reads
-            attended = read
+        # the kernel copies in each lane's live pages, whole, a run of the
+        # allocator's a copy
+        read = (copies * run * self.page_size if kernel
+                else self._table_positions)
+        if not kernel:      # the gather multiplies all it reads, in one
+            attended, copies = read, 0
         hidden = self._walk_hidden(walked)
         c["decode_steps"] += 1
         c["decode_kernel_steps"] += int(kernel)
@@ -680,6 +689,7 @@ class EngineCore:
         c["kv_positions_live"] += live
         c["kv_positions_read"] += read
         c["kv_walk_blocks"] += blocks
+        c["kv_walk_copies"] += copies
         c["kv_positions_attended"] += attended
         c["kv_walk_first_blocks_hidden"] += hidden
         for name, n in fixed.items():
@@ -688,7 +698,8 @@ class EngineCore:
         # step's counts ride the first span that opens once they are known
         with _Phase(phases, _sp.DISPATCH, lanes=len(batch),
                     live_positions=live, read_positions=read,
-                    walk_blocks=blocks, attended_positions=attended,
+                    walk_blocks=blocks, walk_copies=copies,
+                    attended_positions=attended,
                     walk_first_blocks_hidden=hidden, **fixed):
             logits, self._cache = self._decode_fn(
                 self.params, self._cache, self._tokens, *args)
@@ -777,7 +788,11 @@ class EngineCore:
         fixed = ({"fixed_pages": self.alloc.fixed_pages,
                   "fixed_pages_used": self.alloc.fixed_used}
                  if self._fixed else {})
-        return {**self.model.cache_stats(self._cache), **fixed}
+        # pages in runs, and those of the pool that make no whole run
+        runs = ({"page_run": self.alloc.run,
+                 "unused_pages": self.alloc.unused_pages}
+                if self.alloc.run > 1 else {})
+        return {**self.model.cache_stats(self._cache), **fixed, **runs}
 
     def stats(self) -> dict:
         return {"waiting": len(self._waiting),

@@ -36,10 +36,31 @@ model's:
 
 A model that keeps nothing for ever has `fixed` 0 and the one class the
 allocator always had.
+
+**A page and a run.** A page is `page_size` positions: what a table entry
+names, what a prefill writes whole and a decode step a row of, and it
+stays that whatever follows. A *run* is `run` pages of the class that
+grows whose ids lie behind one another from a multiple of `run` on, `[g x
+run, g x run + run)`. A model class whose decode walk is bound by the
+copies it starts and not by their bytes asks for one
+(`models.paged.PagedDecoder.page_run`, from shapes alone; 1 for every
+class but `SparseMLAMoE`, whose two walks then bring a run a copy:
+`ops/sparse_attention.py`), the engine passes the answer on
+(`PageAllocator(run=)`) and keeps no notion of a run of its own. The
+allocator then hands the class out and takes it back in whole runs: what a
+sequence holds of it is rounded up to whole runs (`alloc(1, held)` at a
+run's end returns `run` ids, inside one none), so for every sequence and
+every k the table's entries `fixed + k x run ..` that are held are `p, p +
+1, ..` with `p % run == 0`, the pages no position has reached yet among
+them: the sequence's own, masked by its length as the unused tail of a last
+page is. What it costs: at most `run - 1` pages a sequence held ahead of
+need, and the pages of the class that make no whole run (`unused_pages`,
+under `run` at each end), which nobody gets. With `run` 1 the free list,
+the order of ids and every answer are the allocator's without runs.
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 
 def pages_needed(n_positions: int, page_size: int) -> int:
@@ -82,57 +103,94 @@ class PageAllocator:
     the first `fixed` pages a sequence holds come from the fixed class,
     `fixed x sequences` pages (fewer where the pool is smaller), the rest
     from the other.
+
+    With `run` > 1 the other class is handed out and taken back in whole
+    runs, ids `[g x run, g x run + run)` (the module's docstring): what a
+    sequence holds of it is rounded up to whole runs, and the pages of the
+    class that make no whole run (`unused_pages`) are nobody's. At `run`
+    1 every id, every order and every answer is the allocator's without
+    runs.
     """
 
-    def __init__(self, num_pages: int, fixed: int = 0, sequences: int = 0):
+    def __init__(self, num_pages: int, fixed: int = 0, sequences: int = 0,
+                 run: int = 1):
         if num_pages <= 0:
             raise ValueError(f"num_pages must be > 0, got {num_pages}")
+        if run < 1:
+            raise ValueError(f"a run is one page at least, got {run}")
         self.num_pages = num_pages
         self.fixed = int(fixed)
+        self.run = int(run)
         self.fixed_pages = min(num_pages, self.fixed * int(sequences))
-        self._free: List[int] = list(range(num_pages - 1,
-                                           self.fixed_pages - 1, -1))
+        # the first page of every free run, the lowest popped first
+        first = -(-self.fixed_pages // self.run) * self.run
+        self._free: List[int] = list(range(
+            num_pages // self.run * self.run - self.run, first - 1,
+            -self.run))
+        self.unused_pages = (num_pages - self.fixed_pages
+                             - len(self._free) * self.run)
         self._free_fixed: List[int] = list(range(self.fixed_pages - 1, -1, -1))
         self._held = set()
+        self._held_of_run: Dict[int, int] = {}  # first page -> pages held
 
     @property
     def free_pages(self) -> int:
-        return len(self._free) + len(self._free_fixed)
+        return len(self._free) * self.run + len(self._free_fixed)
 
     @property
     def used_pages(self) -> int:
-        return self.num_pages - self.free_pages
+        return self.num_pages - self.unused_pages - self.free_pages
 
     @property
     def fixed_used(self) -> int:
         """Pages of the fixed class that sequences hold."""
         return self.fixed_pages - len(self._free_fixed)
 
+    def _whole_runs(self, n: int) -> int:
+        """n pages of the class that grows, rounded up to whole runs."""
+        return -(-n // self.run) * self.run
+
     def fits(self, n: int) -> bool:
         """Whether a sequence alone in the pool could hold n pages."""
         first = min(n, self.fixed)
         return (first <= self.fixed_pages
-                and n - first <= self.num_pages - self.fixed_pages)
+                and self._whole_runs(n - first) <= self.num_pages
+                - self.fixed_pages - self.unused_pages)
 
     def alloc(self, n: int, held: int = 0) -> Optional[List[int]]:
         """Claim n pages for a sequence that holds `held` already, or None
         (and claim nothing) if short: the pages that fill its table up to
-        entry `fixed` from the fixed class, then the others."""
+        entry `fixed` from the fixed class, then the others, as many whole
+        runs as bring what it holds of them up to whole runs (all of each
+        run, so more than n at a run's start and none inside one)."""
         if n < 0:
             raise ValueError(f"cannot alloc {n} pages")
         first = min(n, max(0, self.fixed - held))
-        if first > len(self._free_fixed) or n - first > len(self._free):
+        grown = max(0, held - self.fixed)
+        runs = (self._whole_runs(grown + n - first)
+                - self._whole_runs(grown)) // self.run
+        if first > len(self._free_fixed) or runs > len(self._free):
             return None
-        pages = ([self._free_fixed.pop() for _ in range(first)]
-                 + [self._free.pop() for _ in range(n - first)])
+        pages = [self._free_fixed.pop() for _ in range(first)]
+        for _ in range(runs):
+            start = self._free.pop()
+            self._held_of_run[start] = self.run
+            pages.extend(range(start, start + self.run))
         self._held.update(pages)
         return pages
 
     def free(self, pages: List[int]) -> None:
+        """Take back `pages`; a run is free again with its last page."""
         for p in pages:
             if p not in self._held:
                 raise ValueError(
                     f"page {p} freed twice (or never allocated)")
             self._held.discard(p)
-            (self._free_fixed if p < self.fixed_pages
-             else self._free).append(p)
+            if p < self.fixed_pages:
+                self._free_fixed.append(p)
+                continue
+            start = p - p % self.run
+            self._held_of_run[start] -= 1
+            if not self._held_of_run[start]:
+                del self._held_of_run[start]
+                self._free.append(start)

@@ -554,6 +554,8 @@ def test_sparse_mla_moe_programs_hold_their_kernels_by_name(
     assert paged_attention.KERNEL_MLA_PAGED_DECODE not in names
     assert attention.KERNEL_FWD not in names
     if which == "step":     # a walk over index pages, one over latent rows
+        with compute_platform("tpu"):       # each a run of 8 pages a copy
+            assert model.page_run(PAGE, 4096 // PAGE) == 8
         assert names.count(sparse_attention.KERNEL_PAGED_INDEX) == 2
         assert names.count(sparse_attention.KERNEL_PAGED_ATTEND) == 2
         assert sparse_attention.KERNEL_FLASH_FWD not in names

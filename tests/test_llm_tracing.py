@@ -110,13 +110,13 @@ def test_core_spans_and_parents(tiny_model, recorder):
     # the einsum (the tiny model's heads are no shape the kernel tiles)
     # gathers every lane's whole table, whatever the lanes hold
     read = 2 * core.max_pages_per_seq * 8
-    # and multiplies all of it, in no blocks
+    # and multiplies all of it, in no blocks and no copies of a walk
     assert disp == [
         {"lanes": 1, "live_positions": 6, "read_positions": read,
-         "walk_blocks": 0, "attended_positions": read,
+         "walk_blocks": 0, "walk_copies": 0, "attended_positions": read,
          "walk_first_blocks_hidden": 0},
         {"lanes": 1, "live_positions": 7, "read_positions": read,
-         "walk_blocks": 0, "attended_positions": read,
+         "walk_blocks": 0, "walk_copies": 0, "attended_positions": read,
          "walk_first_blocks_hidden": 0}]
 
 
@@ -139,13 +139,15 @@ def test_read_positions_under_the_kernel_are_the_pages_held(tiny_model,
     piece = core.max_pages_per_seq * 8
     assert disp == [
         {"lanes": 2, "live_positions": 6 + 21, "read_positions": 8 + 24,
-         "walk_blocks": 2, "attended_positions": 2 * piece,
+         "walk_blocks": 2, "walk_copies": 1 + 3,    # a copy a page
+         "attended_positions": 2 * piece,
          "walk_first_blocks_hidden": 1},        # b's, behind a's
         {"lanes": 1, "live_positions": 7, "read_positions": 8,
-         "walk_blocks": 1, "attended_positions": piece,
+         "walk_blocks": 1, "walk_copies": 1, "attended_positions": piece,
          "walk_first_blocks_hidden": 0}]        # a call's first lane waits
     st = core.stats()
     assert st["kv_walk_blocks"] == 3
+    assert st["kv_walk_copies"] == 5
     assert st["kv_walk_first_blocks_hidden"] == 1
     assert st["kv_positions_attended"] == 3 * piece
     assert st["kv_positions_read"] == 8 + 24 + 8

@@ -47,6 +47,131 @@ def test_page_allocator_alloc_free():
         a2.free(p)                      # double free across calls
 
 
+# a script of calls and what the allocator without runs answered to it
+# (ids in order, None where short; after a free, pages free and used), at
+# 19 pages with `fixed=2, sequences=3` and with one class: recorded from
+# the tree before PR 62, which `run=1` must answer alike
+_SCRIPT = (
+    [["free", "b", 0], ["free", "d", 1], ["alloc", "b", 1], ["alloc", "a", 1],
+    ["free", "a", 2], ["alloc", "a", 2], ["alloc", "b", 1], ["alloc", "c", 5],
+    ["alloc", "a", 2], ["alloc", "c", 1], ["alloc", "c", 5], ["free", "d", 1],
+    ["free", "b", 0], ["free", "b", 0], ["free", "b", 1], ["alloc", "d", 2],
+    ["alloc", "b", 5], ["free", "b", 2], ["free", "d", 2], ["alloc", "a", 5],
+    ["free", "a", 0], ["free", "d", 0], ["free", "c", 0], ["free", "d", 1],
+    ["free", "c", 2], ["alloc", "c", 3], ["alloc", "c", 3], ["alloc", "a", 2],
+    ["alloc", "d", 1], ["free", "b", 0]])
+_RECORDED = {
+    (("fixed", 2), ("sequences", 3)):
+        [[19, 0], [19, 0], [0], [1], [18, 1], [1, 2], [3], [4, 5, 6, 7, 8],
+        [9, 10], [11], [12, 13, 14, 15, 16], [2, 17], [4, 15], [4, 15], [4,
+        15], [3, 0], None, [2, 17], [4, 15], None, [8, 11], [8, 11], [19,
+        0], [19, 0], [19, 0], [5, 4, 16], [15, 14, 13], [2, 1], [0], [10,
+        9]],
+    ():
+        [[19, 0], [19, 0], [0], [1], [18, 1], [1, 2], [3], [4, 5, 6, 7, 8],
+        [9, 10], [11], [12, 13, 14, 15, 16], [2, 17], [4, 15], [4, 15], [4,
+        15], [3, 0], None, [2, 17], [4, 15], None, [8, 11], [8, 11], [19,
+        0], [19, 0], [19, 0], [16, 15, 14], [13, 12, 11], [8, 7], [6], [10,
+        9]]}
+
+
+def _play(script, **kw):
+    """The script's calls on a new allocator, every sequence telling what
+    it holds; a free of 0 gives back all of it, of n its last n."""
+    a, held, out = PageAllocator(**kw), {}, []
+    for op, who, n in script:
+        mine = held.setdefault(who, [])
+        if op == "alloc":
+            got = a.alloc(n, held=len(mine))
+            mine.extend(got or [])
+            out.append(got)
+        else:
+            back = mine[len(mine) - n:] if n else list(mine)
+            del mine[len(mine) - len(back):]
+            a.free(back)
+            out.append([a.free_pages, a.used_pages])
+    return out
+
+
+@pytest.mark.parametrize("kw", list(_RECORDED), ids=["two-classes", "one"])
+def test_page_allocator_without_runs_answers_as_it_did(kw):
+    assert _play(_SCRIPT, num_pages=19, run=1, **dict(kw)) == _RECORDED[kw]
+    assert _play(_SCRIPT, num_pages=19, **dict(kw)) == _RECORDED[kw]
+
+
+@pytest.mark.parametrize("run", [1, 4, 8])
+def test_page_allocator_hands_out_whole_aligned_runs(run):
+    """Pages of the class that grows come and go in runs `[g x run, g x
+    run + run)`: what a sequence holds is rounded up to whole runs."""
+    def runs_of(pages):
+        """The first page of each run `pages` is made of, checked."""
+        starts = pages[::run]
+        assert all(start % run == 0 for start in starts)
+        assert pages == [start + i for start in starts for i in range(run)]
+        return starts
+
+    # a pool that is not whole runs: the tail is nobody's
+    a = PageAllocator(5 * run + run // 2, run=run)
+    assert a.unused_pages == run // 2 and a.free_pages == 5 * run
+    assert a.fits(5 * run) and a.fits(4 * run + 1)
+    assert not a.fits(5 * run + 1)
+    # one page asked: a whole run got
+    x = a.alloc(1)
+    assert len(runs_of(x)) == 1 and a.used_pages == run
+    # inside a run the sequence holds what it needs; at its end a run more
+    if run > 1:
+        assert a.alloc(1, held=1) == [] == a.alloc(run - 1, held=1)
+        assert a.used_pages == run
+    more = a.alloc(1, held=len(x))
+    assert len(runs_of(x + more)) == 2
+    y = a.alloc(2 * run + 1)
+    assert len(runs_of(y)) == 3 and not set(y) & set(x + more)
+    assert a.free_pages == 0 and a.used_pages == 5 * run
+    # all-or-nothing: none left, nothing claimed
+    assert a.alloc(1) is None and a.free_pages == 0
+    # free and reuse: a run comes back with the last of its pages
+    a.free(more[1:])
+    assert a.free_pages == 0
+    a.free(more[:1])
+    assert a.free_pages == run and a.alloc(run + 1) is None
+    assert sorted(a.alloc(run)) == sorted(more)
+    # double free, in one call and across calls
+    a.free(more)
+    with pytest.raises(ValueError):
+        a.free(more[:1])
+    with pytest.raises(ValueError):
+        a.free(x[:1] + x[:1])
+    # every id handed out over a shuffled life lies in a whole aligned run
+    b = PageAllocator(16 * run, run=run)
+    rng, held = np.random.default_rng(run), {}
+    for _ in range(200):
+        mine = held.setdefault(int(rng.integers(5)), [])
+        if mine and rng.random() < 0.2:
+            b.free(mine)
+            mine.clear()
+        else:
+            mine.extend(b.alloc(int(rng.integers(1, 4)), held=len(mine))
+                        or [])
+        runs_of(mine)
+        every = [p for pages in held.values() for p in pages]
+        assert len(every) == len(set(every)) == b.used_pages
+    # a fixed class is what it was: ids 0 .., one at a time; the runs
+    # start at the first multiple of `run` behind it
+    c = PageAllocator(3 + 4 * run + 1, fixed=1, sequences=3, run=run)
+    plain = PageAllocator(3 + 4 * run + 1, fixed=1, sequences=3)
+    assert c.fixed_pages == plain.fixed_pages == 3
+    # (of 20 pages 4 .. 19 are runs of 4, of 36 pages 8 .. 31 runs of 8)
+    assert c.unused_pages == {1: 0, 4: 1, 8: 9}[run]
+    got = c.alloc(2)
+    assert got[0] == plain.alloc(2)[0] == 0
+    assert got[1] >= 3 and len(runs_of(got[1:])) == 1
+    assert len(runs_of(c.alloc(1, held=len(got)))) == 1
+    assert c.alloc(1)[0] == plain.alloc(1)[0] == 1
+    assert c.fixed_used == 2
+    c.free(got)
+    assert c.fixed_used == 1 and c.alloc(1)[0] == 0
+
+
 def test_pages_needed_and_budget():
     assert pages_needed(1, 16) == 1
     assert pages_needed(16, 16) == 1

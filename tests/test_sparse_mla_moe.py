@@ -32,6 +32,7 @@ from ray_tpu.ops import paged_attention as paged             # noqa: E402
 from ray_tpu.ops import sparse_attention as sparse           # noqa: E402
 from ray_tpu.ops.rope import rope_cos_sin                    # noqa: E402
 from ray_tpu.serve.llm.engine import EngineCore, _bucket     # noqa: E402
+from ray_tpu.serve.llm.kv_cache import PageAllocator         # noqa: E402
 
 CONFIG = "glm-5-1chip"
 PAGE, TOPK, CONTEXT = 16, 32, 256
@@ -235,9 +236,9 @@ def _interpreted(monkeypatch, index_pages=4, attend_pages=8):
     monkeypatch.setattr(sparse, "step_uses_kernels", lambda *a: True)
     index, attend = sparse._paged_index_call, sparse._paged_attend_call
     monkeypatch.setattr(sparse, "_paged_index_call",
-                        lambda *a: index(*a[:-1], True))
+                        lambda *a, **run: index(*a[:-1], True, **run))
     monkeypatch.setattr(sparse, "_paged_attend_call",
-                        lambda *a: attend(*a[:-1], True))
+                        lambda *a, **run: attend(*a[:-1], True, **run))
 
 
 def test_the_steps_kernels_choose_and_read_what_the_gathers_do(monkeypatch):
@@ -277,6 +278,99 @@ def test_the_steps_kernels_choose_and_read_what_the_gathers_do(monkeypatch):
                                 False)
     assert float(jnp.abs(got - want).max()) < 2e-5
     assert not np.asarray(got[2]).any()
+
+
+def _run_tables(lengths, max_pages, num_pages, run, seed=1):
+    """Tables of lanes that hold `lengths` positions, their pages handed
+    out by the allocator in runs of `run`, a page (so a run) at a time in
+    any order of the lanes: the runs of a lane lie anywhere in the pool."""
+    alloc = PageAllocator(num_pages, run=run)
+    rng = np.random.default_rng(seed)
+    need = [-(-int(n) // PAGE) for n in lengths]
+    held = [[] for _ in lengths]
+    spare = alloc.alloc(run)            # so that no lane starts at page 0
+    while any(len(h) < n for h, n in zip(held, need)):
+        lane = int(rng.integers(len(held)))
+        if len(held[lane]) < need[lane]:
+            held[lane] += alloc.alloc(1, held=len(held[lane]))
+    alloc.free(spare)
+    tables = np.full((len(held), max_pages), -1, np.int32)
+    for lane, pages in enumerate(held):
+        tables[lane, :len(pages)] = pages
+    return jnp.asarray(tables)
+
+
+@pytest.mark.parametrize("run", [4, 8])
+def test_the_steps_kernels_copy_a_run_at_a_time(monkeypatch, run):
+    """On tables the allocator laid out in runs, a walk that brings a run
+    a copy scores and reads what the plain forms do, and to the bit what
+    the walk a page a copy does: a lane shorter than one run, one whose
+    last run is partly live, one at `max_pages`, one that holds nothing;
+    the pages of a last run that no position has reached are the lane's
+    own, whatever they hold, and masked."""
+    _interpreted(monkeypatch, index_pages=16, attend_pages=8)
+    ks = jax.random.split(jax.random.PRNGKey(run), 5)
+    lanes, heads, width, latent, topk = 4, 6, 256, 128, 48
+    pool = jax.random.normal(ks[0], (2, 64, PAGE, width))
+    idx_pool = jax.random.normal(ks[1], (2, 64, PAGE, 128))
+    q = jax.random.normal(ks[2], (lanes, heads, width))
+    q_idx = jax.random.normal(ks[3], (lanes, 16, 128))
+    w = jax.random.normal(ks[4], (lanes, 16))
+    lengths = jnp.asarray([2 * PAGE + 3, (run + 2) * PAGE - 5, 0,
+                           16 * PAGE], jnp.int32)
+    tables = _run_tables(lengths, 16, 64, run)
+    held = np.asarray(tables >= 0).sum(axis=1)
+    assert list(held) == [run, 2 * run, 0, 16]      # whole runs, spare pages
+    firsts = np.asarray(tables)[:, ::run]
+    assert not (firsts[firsts >= 0] % run).any()
+    args = (q_idx, w, idx_pool, 1, tables, lengths)
+    plain = sparse.index_scores_paged(*args)
+    by_page = sparse._paged_index_call(*args, True)
+    by_run = sparse._paged_index_call(*args, True, run=run)
+    assert (np.asarray(by_run) == np.asarray(by_page)).all()
+    assert (np.isfinite(plain) == np.isfinite(by_run)).all()
+    assert float(jnp.abs(jnp.where(jnp.isfinite(plain), plain - by_run,
+                                   0)).max()) < 1e-4
+    (positions, chosen), n = sparse.choose_paged(*args, topk, False)
+    keep, m = sparse.choose_paged(*args, topk, True, run=run)
+    assert list(np.asarray(n)) == list(np.asarray(m)) == [35, 48, 0, 48]
+    for lane in range(lanes):
+        assert set(np.flatnonzero(keep[lane])) == set(
+            np.asarray(positions[lane])[np.asarray(chosen[lane])])
+    rest = (q, pool, 1, tables, lengths)
+    got = sparse.attend_chosen(*rest, keep, latent, 0.2, True, run=run)
+    paged = sparse.attend_chosen(*rest, keep, latent, 0.2, True)
+    want = sparse.attend_chosen(*rest, (positions, chosen), latent, 0.2,
+                                False)
+    assert (np.asarray(got) == np.asarray(paged)).all()
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    assert not np.asarray(got[2]).any()
+    # a table that is not whole runs of a block is refused, not misread
+    with pytest.raises(ValueError, match="whole runs"):
+        sparse._paged_attend_call(*rest, keep, latent, 0.2, True, run=16)
+
+
+def test_the_run_is_the_class_answer_from_shapes(monkeypatch):
+    """`page_run`: 1 where a step runs no walk kernel (a context that
+    cannot pass `index_topk`; shapes that do not tile, so every CPU
+    engine), else as many pages as make a copy of the smaller pool's page
+    `RUN_COPY_BYTES` long, cut to a divisor of the table and the blocks."""
+    assert sparse.walk_run_pages(16 * 128 * 2, 1024) == 8      # GLM-5's
+    assert sparse.walk_run_pages(16 * 128 * 2, 1000) == 8
+    assert sparse.walk_run_pages(16 * 128 * 2, 36) == 4
+    assert sparse.walk_run_pages(16 * 128 * 2, 7) == 1
+    assert sparse.walk_run_pages(16 * 128, 1024) == 16
+    assert sparse.walk_run_pages(64 << 10, 1024) == 1
+    assert sparse.walk_run_pages(5000, 1024) == 8              # 7 made 8
+    cfg = tiny_sparse_mla_moe(index_topk=16)
+    assert SparseMLAMoE(cfg).page_run(8, 16) == 1              # no kernel
+    monkeypatch.setattr(sparse, "step_uses_kernels", lambda *a: True)
+    assert SparseMLAMoE(cfg).page_run(8, 16) == 16  # 1 KB a page; 16 | 16
+    assert SparseMLAMoE(cfg).page_run(8, 12) == 4
+    under = tiny_sparse_mla_moe(index_topk=128)      # max_seq_len 128
+    assert SparseMLAMoE(under).page_run(8, 16) == 1
+    from ray_tpu.models import MLAMoE, mla_moe
+    assert MLAMoE(mla_moe.tiny_mla_moe()).page_run(8, 16) == 1
 
 
 @pytest.mark.parametrize("p,steps", [(24, 20), (100, 12)])
@@ -465,6 +559,69 @@ def test_engine_core_serves_it_and_counts_what_it_chose():
     # a latent row of 128 numbers and an index key of 32, float32
     assert st["cache_bytes_per_position"] == cfg.n_layers * (128 + 32) * 4
     assert np.asarray(st["moe_load"]).shape == (cfg.n_moe_layers, 4)
+
+
+def _serve_in_runs(monkeypatch, cfg, params, run):
+    """An engine whose step runs the two walk kernels (interpreted) over a
+    pool too small for its three lanes, pages in runs of `run` (None: the
+    class's own answer): admission, growth across a run's end, an
+    eviction and its re-admission, a cancel. Returns (tokens by request,
+    the engine), every lane's table checked after every step."""
+    if run is not None:
+        monkeypatch.setattr(SparseMLAMoE, "page_run", lambda *a: run)
+    core = EngineCore(cfg, params, num_pages=17, page_size=8, max_batch=3)
+    run = core.alloc.run
+    rng = np.random.default_rng(0)
+    prompts = {"a": rng.integers(0, 256, 21).tolist(),
+               "b": rng.integers(0, 256, 30).tolist(),
+               "c": rng.integers(0, 256, 12).tolist(),
+               "d": [5, 6, 7]}
+    for rid, prompt in prompts.items():
+        core.submit(prompt, max_tokens=40 if rid == "a" else 24, rid=rid)
+    got = {rid: [] for rid in prompts}
+    for _ in range(300):
+        if not core.has_work:
+            break
+        for ev in core.step():
+            if ev["token"] is not None:
+                got[ev["rid"]].append(ev["token"])
+        if len(got["c"]) == 5:
+            core.cancel("c")
+        held = [p for seq in core._running for p in seq.pages]
+        assert len(held) == len(set(held)) == core.alloc.used_pages
+        for seq in core._running:
+            starts = seq.pages[::run]
+            assert all(p % run == 0 for p in starts)
+            assert seq.pages == [p + i for p in starts for i in range(run)]
+            # (the page of the token asked for next comes with its step)
+            assert len(seq.pages) >= -(-(seq.device_len - 1) // 8)
+    assert not core.has_work and core.alloc.used_pages == 0
+    return got, core
+
+
+def test_an_engine_in_runs_serves_what_one_page_at_a_time_does(monkeypatch):
+    _interpreted(monkeypatch, index_pages=4, attend_pages=8)
+    cfg = tiny_sparse_mla_moe(index_topk=16)
+    params = SparseMLAMoE(cfg).init(jax.random.PRNGKey(0))
+    got, core = _serve_in_runs(monkeypatch, cfg, params, None)
+    # the class's answer at these shapes: 1 KB a page of index keys, cut
+    # to the blocks of 4 and 8 and the table of 16
+    assert core.alloc.run == 4 and core.alloc.unused_pages == 1
+    assert core.cache_stats()["page_run"] == 4
+    assert core.device_stats()["decode_attention"] == "dsa_paged_attend"
+    c = core.counters
+    assert c["evictions"] > 0 and c["dsa_lanes_past_topk"] > 0
+    assert len(got["a"]) == 40 and len(got["b"]) == len(got["d"]) == 24
+    assert len(got["c"]) == 5
+    # a copy a run: whole runs read, up to 3 pages a lane beyond the live
+    assert c["kv_positions_read"] == c["kv_walk_copies"] * 4 * 8
+    assert c["kv_positions_live"] <= c["kv_positions_read"] \
+        < c["kv_positions_live"] + c["decode_lane_steps"] * 4 * 8
+    want, plain = _serve_in_runs(monkeypatch, cfg, params, 1)
+    assert plain.alloc.run == 1 and "page_run" not in plain.cache_stats()
+    p = plain.counters
+    assert p["kv_positions_read"] == p["kv_walk_copies"] * 8
+    assert got == want
 
 
 def test_a_config_names_the_class_and_refusals_are_plain():

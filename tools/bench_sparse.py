@@ -5,13 +5,18 @@ the chip, alone, at GLM-5's widths (32 lanes, 64 heads over latent rows of
     chiprun -- python tools/bench_sparse.py            # both halves
     chiprun -- python tools/bench_sparse.py --only decode --lengths 3000,6000
     chiprun -- python tools/bench_sparse.py --only prefill --buckets 4096
+    chiprun -- python tools/bench_sparse.py --only decode --run 1,4,8,16
 
 A decode step's three pieces a layer, each both ways (the index scores as
 a gather of every table entry and as the walk over live pages, the choice
 as `lax.top_k` and as a threshold, the attention as a gather of the chosen
 rows and as the walk that reads every live row and keeps the chosen),
 beside the dense latent kernel over the same lanes, every lane at one
-length; a prefill's
+length; with `--run` the two walks again at each run length, the tables
+laid out in aligned runs of that many consecutive pages, the runs
+shuffled (`walk_index_ms` / `walk_attend_ms` a row, and the ns a copy
+their slope over the lengths gives; the other pieces, which no run
+touches, are timed at the first run alone); a prefill's
 three (the index-score kernel, the bisection that makes the mask, the
 masked flash forward) beside the dense causal flash forward at the latent
 classes' blocks. Milliseconds a layer, the median of `--reps` calls, each
@@ -19,6 +24,7 @@ call `LAYERS` layers in one program so a dispatch is not what is timed.
 Chip only.
 """
 import argparse
+import functools
 import json
 import os
 import sys
@@ -66,7 +72,15 @@ def chained(piece):
     return jax.jit(run)
 
 
-def decode(lengths, context, reps):
+def run_tables(pages, max_pages, run):
+    """Every page of the pool in a table, in aligned runs of `run`
+    consecutive ids, the runs in any order (`run` 1: the pages)."""
+    starts = np.random.default_rng(0).permutation(pages // run) * run
+    return jnp.asarray((starts[:, None] + np.arange(run)).reshape(
+        LANES, max_pages).astype(np.int32))
+
+
+def decode(lengths, context, reps, runs=(1,)):
     max_pages = context // PAGE
     pages = LANES * max_pages
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
@@ -78,8 +92,6 @@ def decode(lengths, context, reps):
     q_idx = jax.random.normal(ks[3], (LANES, IDX_HEADS, IDX_DIM),
                               jnp.bfloat16)
     w = jax.random.normal(ks[4], (LANES, IDX_HEADS), jnp.float32)
-    tables = jnp.asarray(np.random.default_rng(0).permutation(
-        pages).reshape(LANES, max_pages).astype(np.int32))
     bf = jnp.bfloat16
 
     def scores(a, layer, c):
@@ -100,39 +112,55 @@ def decode(lengths, context, reps):
             a["q"] + c.astype(bf), a["pool"], layer, a["live"], a["lens"],
             LATENT, SCALE)
 
-    def walk_scores(a, layer, c):
+    def walk_scores(run, a, layer, c):
         return sa._paged_index_call(a["q_idx"] + c.astype(bf), a["w"],
                                     a["idx_pool"], layer, a["live"],
-                                    a["lens"], False)
+                                    a["lens"], False, run)
 
     def threshold(a, layer, c):
         return sa.keep_topk(a["scores"] + c, TOPK)
 
-    def walk_attend(a, layer, c):
+    def walk_attend(run, a, layer, c):
         return sa._paged_attend_call(
             a["q"] + c.astype(bf), a["pool"], layer, a["live"], a["lens"],
-            a["keep"], LATENT, SCALE, False)
+            a["keep"], LATENT, SCALE, False, run)
 
-    pieces = {"index_ms": chained(scores), "choice_ms": chained(choose),
-              "attend_ms": chained(attend),
-              "walk_index_ms": chained(walk_scores),
-              "threshold_ms": chained(threshold),
-              "walk_attend_ms": chained(walk_attend),
-              "dense_kernel_ms": chained(dense)}
+    once = {"index_ms": chained(scores), "choice_ms": chained(choose),
+            "attend_ms": chained(attend),
+            "threshold_ms": chained(threshold),
+            "dense_kernel_ms": chained(dense)}
     rows = []
-    for n in lengths:
-        a = {"pool": pool, "idx_pool": idx_pool, "q": q, "q_idx": q_idx,
-             "w": w, "lens": jnp.full((LANES,), n, jnp.int32),
-             "live": jnp.where(jnp.arange(max_pages)[None, :] * PAGE < n,
-                               tables, -1)}
-        a["scores"] = jax.jit(lambda a: scores(a, 0, jnp.float32(0)))(a)
-        a["pos"], a["chosen"] = jax.jit(
-            lambda s: sa.select_topk(s, TOPK))(a["scores"])
-        a["keep"] = jax.jit(lambda s: sa.keep_topk(s, TOPK))(a["scores"])
-        row = {"length": n, **{name: timed(fn, a, reps=reps)
-                               for name, fn in pieces.items()}}
-        rows.append(row)
-        print(json.dumps(row), flush=True)
+    for run in runs:
+        tables = run_tables(pages, max_pages, run)
+        pieces = {"walk_index_ms": chained(functools.partial(walk_scores,
+                                                             run)),
+                  "walk_attend_ms": chained(functools.partial(walk_attend,
+                                                              run)),
+                  **(once if run == runs[0] else {})}
+        for n in lengths:
+            a = {"pool": pool, "idx_pool": idx_pool, "q": q, "q_idx": q_idx,
+                 "w": w, "lens": jnp.full((LANES,), n, jnp.int32),
+                 "live": jnp.where(
+                     jnp.arange(max_pages)[None, :] * PAGE < n, tables, -1)}
+            a["scores"] = jax.jit(lambda a: scores(a, 0, jnp.float32(0)))(a)
+            a["pos"], a["chosen"] = jax.jit(
+                lambda s: sa.select_topk(s, TOPK))(a["scores"])
+            a["keep"] = jax.jit(lambda s: sa.keep_topk(s, TOPK))(a["scores"])
+            row = {"run": run, "length": n,
+                   **{name: timed(fn, a, reps=reps)
+                      for name, fn in pieces.items()}}
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+    for run in runs:            # ns a copy: the walks' slopes over length
+        mine = [r for r in rows if r["run"] == run]
+        if len(mine) > 1:
+            pages = [-(-r["length"] // PAGE) for r in mine]
+            copies = [LANES * -(-n // run) for n in pages]
+            print(json.dumps({"run": run, **{
+                name.replace("_ms", "_ns_a_copy"): 1e6 * float(np.polyfit(
+                    copies, [r[name] for r in mine], 1)[0])
+                for name in ("walk_index_ms", "walk_attend_ms")}}),
+                flush=True)
     return rows
 
 
@@ -189,13 +217,16 @@ def main():
     ap.add_argument("--context", type=int, default=16384)
     ap.add_argument("--buckets", default="4096,8192,16384")
     ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--run", default="1",
+                    help="pages a copy of the two walks, comma-separated")
     a = ap.parse_args()
     if jax.default_backend() != "tpu":
         sys.exit("bench_sparse.py measures the chip; there is none here")
     out = {}
     if a.only != "prefill":
         out["decode"] = decode([int(x) for x in a.lengths.split(",")],
-                               a.context, a.reps)
+                               a.context, a.reps,
+                               [int(x) for x in a.run.split(",")])
     if a.only != "decode":
         out["prefill"] = prefill([int(x) for x in a.buckets.split(",")],
                                  a.reps)

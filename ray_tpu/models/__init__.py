@@ -11,17 +11,16 @@ the flagship: Llama-family, GQA + RoPE + SwiGLU, layers stacked and scanned
 (`lax.scan`, so compile time is O(1) in depth), annotated by logical
 sharding axes (`ray_tpu.parallel.sharding`) and trained on a mesh; what a
 rematted layer keeps is a rule over the step's shapes (`remat_plan`). The
-other classes serve on one device and hold their layers one by one, named
-layer by layer by their configs; each module's docstring has its
-mathematics. What they share is written once: the skeleton and the paged
-cache's addresses in `paged.py`, paged grouped-query attention in `gqa.py`,
+other classes serve on one device and hold their layers one by one; each
+module's docstring has its mathematics. A layer's mixer answers for itself
+(`paged.Mixer`: its leaves, the pools it keeps of a sequence, the kernel
+that reads them, its three forwards): grouped-query attention in `gqa.py`,
 latent attention in `latent.py`, the state-space mixer in `ssm.py`, the
-routed feed-forwards in `moe.py`. `GatedConvMoE` (`gated_conv_moe.py`) is
-the one whose mixer is a convolution and nothing else, whose heads are half
-a 128-lane wide and whose head is the embedding's table. `SparseMLAMoE`
-(`sparse_mla_moe.py`) is `MLAMoE` whose attention reads the positions a
-learned indexer chooses, with the indexer's keys a second pool under the
-latent pool's page ids.
+delta rules, the gated short convolution and the sparse latent attention
+beside the one class that has each. A served class is its config, a table
+of layers built in its `__init__` (which mixers read the normed stream, the
+feed-forward that follows: `moe.py` has the routed ones) and what is its
+own; `paged.py` walks the table for every program and every ask.
 """
 from ray_tpu.models.config import TransformerConfig  # noqa: F401
 from ray_tpu.models.decode import (cache_page_bytes,  # noqa: F401

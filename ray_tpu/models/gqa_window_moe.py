@@ -49,12 +49,12 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import gqa
 from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
+from ray_tpu.models.gqa import Attention
 from ray_tpu.models.moe import STEP_COUNTS, DenseOrRoutedFFN
-from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
-                                  lane_page, prefill_page_ids_held)
+from ray_tpu.models.paged import (ExpertCounts, Layer, PagedDecoder, Params,
+                                  Walk)
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops import rope as _rope
 
@@ -189,6 +189,49 @@ def tiny_gqa_window_moe(vocab_size: int = 256) -> GQAWindowMoEConfig:
         param_dtype="float32")
 
 
+class GatedAttention(Attention):
+    """A layer's attention: `heads` query heads rotated by the layer
+    kind's scheme, a sliding layer's under the window and in a ring of its
+    own pools; the heads' outputs gated a head."""
+
+    def __init__(self, config: GQAWindowMoEConfig, kind: str, heads: int):
+        c = config
+        sliding = kind == SLIDING
+        self.rope = c.rope_sliding if sliding else c.rope_full
+        super().__init__(
+            c.d_model, heads, c.n_kv_heads, c.head_dim, c.activation_dtype,
+            *((c.sliding_window, ("wk", "wv")) if sliding else ()))
+
+    def shapes(self, std: float, out_std: float) -> Dict[str, tuple]:
+        return {**super().shapes(std, out_std),
+                "wg": ((self.d_model, self.heads), std)}    # the gate, a head
+
+    def open(self, at: Walk) -> None:
+        """(cos, sin) of the program's positions under this kind's scheme:
+        one table a scheme, once a program."""
+        if self.rope not in at.tables:
+            with R.region(R.ATTN_IN):
+                at.tables[self.rope] = self.rope.cos_sin(at.positions(),
+                                                         self.head_dim)
+
+    @R.region(R.ATTN_IN)
+    def _qkv(self, layer: Params, h, at: Walk):
+        cos, sin = at.tables[self.rope]
+        q, k, v = super()._qkv(layer, h, at)
+        return (_rope.rotate_leading(q, cos, sin),
+                _rope.rotate_leading(k, cos, sin), v)
+
+    @R.region(R.ATTN_OUT)
+    def _out(self, layer: Params, h, out):
+        """Heads' outputs `out` (..., heads, hd), gated a head by the
+        sigmoid of a projection of the layer's normed input `h`, through
+        W_o."""
+        out = out.astype(self.dtype)
+        gate = jax.nn.sigmoid(
+            (h @ layer["wg"].astype(self.dtype)).astype(jnp.float32))
+        return super()._out(layer, h, out * gate[..., None].astype(out.dtype))
+
+
 class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
     """Functional model bundle for one GQAWindowMoEConfig: `init`, `apply`
     / `loss` (a plain forward, the tests' and a trainer's; on a TPU the
@@ -202,22 +245,26 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
 
     def __init__(self, config: GQAWindowMoEConfig, mesh=None):
         super().__init__(config, mesh)
+        c = config
         self._ring_walks: Dict[int, list] = {}  # `fixed_step_counts`'s
+        # a mixer a (kind, head count): the full layers' before the rings'
+        of = {key: GatedAttention(c, *key) for key in sorted(set(zip(
+            c.layer_types, c.n_heads_per_layer)))}
+        self._lay(list(of.values()), [
+            Layer((of[key],), experts=c.num_experts if ffn == SPARSE else 0)
+            for key, ffn in zip(zip(c.layer_types, c.n_heads_per_layer),
+                                c.mlp_layer_types)])
 
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
         """A norm's scale is stored as w, the layer multiplying by 1 + w."""
         c = self.config
-        e, q_dim = c.d_model, c.n_heads_per_layer[i] * c.head_dim
+        e = c.d_model
         std = 0.02
         out_std = std / math.sqrt(2 * c.n_layers)
-        shapes = {
-            "attn_norm": ((e,), 0.0),
-            "wq": ((e, q_dim), std), "wk": ((e, c.kv_dim), std),
-            "wv": ((e, c.kv_dim), std), "wo": ((q_dim, e), out_std),
-            "wg": ((e, c.n_heads_per_layer[i]), std),   # the gate, a head
-            "mlp_norm": ((e,), 0.0),
-        }
+        mixer, = self.layers[i].mixers
+        shapes = {"attn_norm": ((e,), 0.0), **mixer.shapes(std, out_std),
+                  "mlp_norm": ((e,), 0.0)}
         if c.mlp_layer_types[i] == DENSE:
             shapes.update(gate=((e, c.d_ff), std), up=((e, c.d_ff), std),
                           down=((c.d_ff, e), out_std))
@@ -233,80 +280,17 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
         return shapes
 
     # --------------------------------------------------------- pieces
-    @R.region(R.ATTN_IN)
-    def _ropes(self, positions: jax.Array):
-        """kind -> (cos, sin) of `positions`: both tables, once a program."""
-        c = self.config
-        return {FULL: c.rope_full.cos_sin(positions, c.head_dim),
-                SLIDING: c.rope_sliding.cos_sin(positions, c.head_dim)}
-
-    @R.region(R.ATTN_IN)
-    def _qkv(self, i: int, layer: Params, h, ropes):
-        """h (..., e) -> q (..., heads, hd), k, v (..., kv heads, hd), q
-        and k rotated by the layer kind's scheme."""
-        c = self.config
-        cos, sin = ropes[c.layer_types[i]]
-        q, k, v = gqa.qkv(layer, h, c.n_heads_per_layer[i], c.n_kv_heads,
-                          c.head_dim, c.activation_dtype)
-        return (_rope.rotate_leading(q, cos, sin),
-                _rope.rotate_leading(k, cos, sin), v)
-
-    @R.region(R.ATTN_OUT)
-    def _attn_out(self, layer: Params, h, out):
-        """Heads' outputs `out` (..., heads, hd), gated a head by the
-        sigmoid of a projection of the layer's normed input `h`, through
-        W_o."""
-        ad = self.config.activation_dtype
-        gate = jax.nn.sigmoid(
-            (h @ layer["wg"].astype(ad)).astype(jnp.float32))
-        out = out * gate[..., None].astype(out.dtype)
-        return out.reshape(*out.shape[:-2], -1) @ layer["wo"].astype(ad)
-
-    def _attn_seq(self, i: int, layer: Params, h, ropes):
-        """Causal attention of layer i over whole sequences h (b, s, e).
-        Returns (attention output after W_o, k, v (b, s, kv heads, hd))."""
-        q, k, v = self._qkv(i, layer, h, ropes)
-        return self._attn_out(layer, h, gqa.attend_seq(
-            q, k, v, self._window(i))), k, v
-
-    def _window(self, i: int) -> Optional[int]:
-        """What layer i sees of a sequence: None is all of it."""
-        c = self.config
-        return c.sliding_window if c.layer_types[i] == SLIDING else None
-
     def _routing(self, layer: Params):
         c = self.config                 # no correction bias
         return jnp.zeros((c.num_experts,), jnp.float32), dict(
             top_k=c.num_experts_per_tok, norm_topk_prob=True,
             scale=c.routed_scaling_factor)
 
-    # --------------------------------------------------------- forward
-    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) -> hidden states after the final norm."""
-        b, s = tokens.shape
-        x = self._embed(params, tokens)
-        ropes = self._ropes(jnp.broadcast_to(jnp.arange(s), (b, s)))
-        for i, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["attn_norm"])
-            attn = self._attn_seq(i, layer, h, ropes)[0]
-            with R.region(R.ATTN_OUT):
-                x = x + attn
-            x, _ = self._block_ffn(layer, x)
-        return self._final_norm(params, x)
-
     # ------------------------------------------------ what an engine asks
     def window_pages(self, page_size: int) -> int:
         """Pages of a sequence that its sliding layers keep: the ring a
         sequence's first table entries name (0: no such layer)."""
-        c = self.config
-        if not c.sliding_layers:
-            return 0
-        return _paged.ring_pages(c.sliding_window, page_size)
-
-    def fixed_pages(self, page_size: int) -> int:
-        """Pages of the allocator's fixed class a sequence holds for ever:
-        its sliding layers' ring."""
-        return self.window_pages(page_size)
+        return self.fixed_pages(page_size)
 
     def fixed_step_counts(self, length: int, page_size: int,
                           kernel: bool = True) -> Dict[str, int]:
@@ -342,138 +326,3 @@ class GQAWindowMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
         if not kernel:
             read = self.window_pages(page_size) * page_size
         return live, read
-
-    def init_cache(self, num_pages: int, page_size: int, dtype=None,
-                   fixed_pages: int = 0) -> Cache:
-        """`num_pages` pages in the full layers' pools, `fixed_pages` (the
-        allocator's fixed class: the rings) in the sliding layers'."""
-        c = self.config
-        dt = dtype or c.activation_dtype
-        full = (len(c.full_layers), num_pages, page_size, c.kv_dim)
-        ring = (len(c.sliding_layers), max(fixed_pages, 1), page_size,
-                c.kv_dim)
-        make = jax.jit(lambda: {
-            "k": jnp.zeros(full, dt), "v": jnp.zeros(full, dt),
-            "wk": jnp.zeros(ring, dt), "wv": jnp.zeros(ring, dt),
-            **self._zero_counts()})
-        return make()
-
-    @property
-    def expert_load_shape(self) -> Tuple[int, int]:
-        return len(self.config.sparse_layers), self.config.num_experts
-
-    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
-                         dtype=None, fixed: bool = False) -> int:
-        """Bytes one page costs: keys and values of the full layers for a
-        page of the pool `num_pages` counts, of the sliding layers for a
-        page of the ring (`fixed`), which a fixed-class page costs
-        besides."""
-        c = self.config
-        layers = c.sliding_layers if fixed else c.full_layers
-        return len(layers) * gqa.layer_page_bytes(
-            c.kv_dim, page_size, dtype or c.activation_dtype, tp_shards)
-
-    def decode_attention(self, page_size: int, dtype=None) -> str:
-        """The kernel of each layer kind, or "einsum"."""
-        c = self.config
-        return gqa.decode_kernels(
-            c.head_dim, page_size, dtype or c.activation_dtype,
-            [(_paged.KERNEL_PAGED_DECODE, c.full_layers),
-             (_paged.KERNEL_PAGED_WINDOW_DECODE, c.sliding_layers)])
-
-    def walk_block_pages(self, page_size: int, max_pages: int,
-                         fixed: bool = False) -> int:
-        """Of a full layer's walk over tables of `max_pages`, or of a
-        sliding layer's over its ring (`fixed`): a layer's page is the
-        same bytes in both."""
-        c = self.config
-        return gqa.walk_block_pages(c.kv_dim, page_size, max_pages,
-                                    c.activation_dtype)
-
-    def prefill(self, params: Params, tokens: jax.Array, true_len,
-                page_table: jax.Array, cache: Cache,
-                page_size: int) -> Tuple[jax.Array, Cache]:
-        """Every layer through the flash kernel (a sliding one with its
-        window), keys and values written as whole pages in place: a full
-        layer's all, a sliding layer's last `window_pages` into its ring.
-        Padding past `true_len` is given to no expert."""
-        c = self.config
-        pools = {name: cache[name] for name in ("k", "v", "wk", "wv")}
-        num_pages, ring_pages = pools["k"].shape[1], pools["wk"].shape[1]
-        ring = self.window_pages(page_size)
-        s = tokens.shape[0]
-        x = self._embed(params, tokens)[None]                   # (1, s, e)
-        ropes = self._ropes(jnp.arange(s)[None])
-        with R.region(R.CACHE):
-            valid = (jnp.arange(s) < true_len)[None]
-        full_ids, ring_ids = prefill_page_ids_held(
-            page_table, true_len, s, num_pages, page_size, ring, ring_pages)
-        order = {FULL: (("k", "v"), full_ids, c.full_layers),
-                 SLIDING: (("wk", "wv"), ring_ids, c.sliding_layers)}
-        for i, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["attn_norm"])
-            attn, k, v = self._attn_seq(i, layer, h, ropes)
-            names, ids, layers = order[c.layer_types[i]]
-            pools.update(gqa.write_prompt(pools, names, layers.index(i),
-                                          ids, k, v))
-            with R.region(R.ATTN_OUT):
-                x = x + attn
-            x, _ = self._block_ffn(layer, x, valid)
-        return self._logits(params, x, true_len), {**cache, **pools}
-
-    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
-                    positions: jax.Array, page_tables: jax.Array,
-                    active: jax.Array,
-                    page_size: int) -> Tuple[jax.Array, Cache]:
-        """Inactive lanes write nothing and are given to no expert."""
-        c = self.config
-        ad = c.activation_dtype
-        pools = {name: cache[name] for name in ("k", "v", "wk", "wv")}
-        num_pages, ring_pages = pools["k"].shape[1], pools["wk"].shape[1]
-        ring = self.window_pages(page_size)
-        B = tokens.shape[0]
-        x = self._embed(params, tokens)                         # (B, e)
-        ropes = self._ropes(positions)                # (B, 1, rot / 2)
-        # a lane's entry and row by hand: the ring needs the entry, and
-        # the order is the one this class's traced text has always had
-        with R.region(R.CACHE):
-            lengths = jnp.where(active, positions + 1, 0)
-            logical = positions // page_size
-            slot = positions % page_size
-            full = (("k", "v"), lane_page(
-                page_tables, logical, active, num_pages), page_tables,
-                c.full_layers)
-            if ring:
-                ring_tables = page_tables[:, :ring]
-                sliding = (("wk", "wv"), lane_page(
-                    ring_tables, logical % ring, active, ring_pages),
-                    ring_tables, c.sliding_layers)
-        load = cache["moe_load"]
-        pairs, touched, load_max = self._step_sums()
-        for i, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["attn_norm"])
-            q, k, v = self._qkv(i, layer, h, ropes)
-            with R.region(R.ATTN_IN):
-                # both flat before either is written (the traced text's
-                # order)
-                k, v = k.reshape(B, c.kv_dim), v.reshape(B, c.kv_dim)
-            names, page, tables, layers = (
-                full if c.layer_types[i] == FULL else sliding)
-            out, written = gqa.decode_attend(
-                pools, names, layers.index(i), page, slot, q, k, v, tables,
-                lengths, self._window(i))
-            pools.update(written)
-            with R.region(R.ATTN_OUT):
-                x = x + self._attn_out(layer, h, out.astype(ad))
-            x, counts = self._block_ffn(layer, x, active)
-            if counts is not None:
-                # as `_count_step`, the maximum taken after the two sums
-                # (the traced text's order)
-                with R.region(R.MOE_ROUTE):
-                    load = load.at[c.sparse_layers.index(i)].add(
-                        counts["load"])
-                    pairs = pairs + counts["pairs"]
-                    touched = touched + counts["touched"]
-                    load_max = load_max + jnp.max(counts["load"])
-        return self._logits(params, x), {
-            **pools, **self._counted(load, (pairs, touched, load_max))}

@@ -28,7 +28,7 @@ kernel's factors need (`ops.kda.lower_bound_fits`). `A_log` and `dt_bias`
 are held as offsets from the config's `a_log_init` and `dt_bias_init`, as
 a norm's scale is held as an offset from 1.
 
-A **latent** layer is `models.latent.LatentAttention`'s with the query in
+A **latent** layer is the mixer `models.latent.LatentAttention` with the query in
 one matrix (no `q_lora_rank`) and a sigmoid gate a head on the attention's
 output (`head_gate`). A **sparse** feed-forward is
 `models.moe.DenseOrRoutedFFN`'s: a sigmoid a slot, the choice among the
@@ -38,14 +38,11 @@ the layer routes over all, computes its own experts' rows and leaves out
 what the others would add), a shared expert on every token.
 
 **Two kinds of cache behind one page table** (`models/paged.py` has the
-addresses), both small: a latent layer keeps a position one row, pool
-`"kv"` `(latent layers, num_pages, page, row_width)`; a linear layer keeps
-a sequence the same bytes at any length, pools `"state"` `(linear layers,
-slots + 1, dk, H x dv)` float32 and `"tail"` (the convolution's last
-`width - 1` inputs, `ops.gated_delta.tail_shape` a slot), a sequence's at
-the slot its first table entry names (`paged.StateSlots`), which the
-latent pool backs like any page. Beside them `paged.ExpertCounts`' two
-entries.
+addresses), both small and each its mixer's: a latent layer keeps a
+position one row, pool `"kv"`; a linear layer keeps a sequence the same
+bytes at any length (`models.hybrid_delta.GatedDelta`'s two pools), at the
+slot its first table entry names, which the latent pool backs like any
+page. Beside them `paged.ExpertCounts`' two entries.
 """
 from __future__ import annotations
 
@@ -57,17 +54,13 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import regions as R
-from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
+from ray_tpu.models.hybrid_delta import GatedDelta
+from ray_tpu.models.latent import LatentAttention, LatentDims
 from ray_tpu.models.moe import DenseOrRoutedFFN
-from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
-                                  StateSlots, decode_lanes,
-                                  decode_state_slots, prefill_page_ids,
-                                  prefill_state_slot)
-from ray_tpu.ops import gated_delta as _gd
+from ray_tpu.models.paged import ExpertCounts, Layer, PagedDecoder, Params
 from ray_tpu.ops import kda as _kda
-from ray_tpu.ops.gated_delta import CHUNK, causal_conv, l2_normalize
+from ray_tpu.ops.gated_delta import CHUNK, l2_normalize
 from ray_tpu.ops.norms import rms_norm_reference
-from ray_tpu.ops.rope import rope_cos_sin
 
 LINEAR, LATENT = "linear_attention", "latent_attention"
 DENSE, SPARSE = "dense", "sparse"
@@ -179,8 +172,70 @@ def tiny_hybrid_kda_moe(vocab_size: int = 256,
         dtype="float32", param_dtype="float32")
 
 
-class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
-                   ExpertCounts, PagedDecoder):
+class KDA(GatedDelta):
+    """The linear layers' mixer: `GatedDelta` with the decay a vector over
+    the key width (`ops.kda`), its own gates and a sigmoid on the way
+    out."""
+
+    def shapes(self, std: float, out_std: float) -> Dict[str, tuple]:
+        c = self.config
+        e, H = c.d_model, self.heads
+        return {"w_qkv": ((e, c.conv_channels), std),
+                "w_f": ((e, c.key_dim), std), "w_b": ((e, H), std),
+                "w_g": ((e, c.value_dim), std),
+                "conv": ((c.conv_width, c.conv_channels), std),
+                "a_log": ((H,), 0.0),
+                "dt_bias": ((H, c.linear_key_dim), 0.0),
+                "o_norm": ((c.linear_value_dim,), 0.0),
+                "wo": ((c.value_dim, e), out_std)}
+
+    def decode_kernel(self, page_size: int, dtype) -> str:
+        c = self.config
+        return (_kda.KERNEL_STEP if _kda.uses_step_kernel(
+            self.heads, c.linear_key_dim, c.linear_value_dim)
+            else "kda_gather")
+
+    def _rule(self):
+        return _kda.kda_chunked, _kda.kda_prefill, _kda.kda_step
+
+    @R.region(R.MIXER_IN)
+    def _inputs(self, layer: Params, u, mixed):
+        """What the recurrence takes of positions u (n, e) whose convolved
+        channels are `mixed` (n, channels): q, k (n, H, dk) and v (n, H,
+        dv) in the activations' dtype, g (n, H, dk) and beta (n, H)
+        float32."""
+        c = self.config
+        ad = c.activation_dtype
+        H, dk = self.heads, c.linear_key_dim
+        n = u.shape[0]
+        q, k, v = jnp.split(mixed, [c.key_dim, 2 * c.key_dim], axis=-1)
+        q = l2_normalize(q.reshape(n, H, dk)) * dk ** -0.5
+        k = l2_normalize(k.reshape(n, H, dk))
+        f32 = jnp.float32           # the offsets are added in float32
+        g, beta = _kda.gates(
+            (u @ layer["w_f"].astype(ad)).reshape(n, H, dk),
+            u @ layer["w_b"].astype(ad),
+            c.a_log_init + layer["a_log"].astype(f32),
+            c.dt_bias_init + layer["dt_bias"].astype(f32),
+            c.kda_lower_bound)
+        return (q.astype(ad), k.astype(ad),
+                v.reshape(n, H, c.linear_value_dim), g, beta)
+
+    @R.region(R.MIXER_OUT)
+    def _out(self, layer: Params, u, o):
+        """Heads' outputs o (n, H, dv): normed a head, gated by the
+        sigmoid of a projection of the layer's input u (n, e), through
+        W_o."""
+        c = self.config
+        ad = c.activation_dtype
+        z = (u @ layer["w_g"].astype(ad)).reshape(o.shape)
+        o = rms_norm_reference(o.astype(jnp.float32), layer["o_norm"],
+                               c.norm_eps)
+        y = (o * jax.nn.sigmoid(z.astype(jnp.float32))).astype(ad)
+        return y.reshape(u.shape[0], -1) @ layer["wo"].astype(ad)
+
+
+class HybridKDAMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
     """Functional model bundle for one HybridKDAMoEConfig: `init`, `apply`
     / `loss` (the plain chunked form, differentiated by JAX), and what a
     serving engine asks a model for (`models.paged.PagedDecoder`)."""
@@ -188,28 +243,28 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
     no_mesh = ("neither the state pools, the latent cache nor the experts' "
                "exchange over chips have been built")
 
+    def __init__(self, config: HybridKDAMoEConfig, mesh=None):
+        super().__init__(config, mesh)
+        c = config
+        self.attention = LatentAttention(c)
+        self.linear = KDA(c, c.n_heads)
+        mixers = {LATENT: self.attention, LINEAR: self.linear}
+        self._lay([self.attention, self.linear], [
+            Layer((mixers[kind],), experts=c.held[1] if ffn == SPARSE else 0)
+            for kind, ffn in zip(c.layer_types, c.mlp_layer_types)])
+
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
         """Zeros are a norm's scale w, the layer multiplying by 1 + w;
         `a_log`, `dt_bias`, offsets from the config's initial values; the
         router's bias."""
         c = self.config
-        e, H = c.d_model, c.n_heads
+        e = c.d_model
         std = 0.02
         out_std = std / math.sqrt(2 * c.n_layers)
-        if c.layer_types[i] == LATENT:
-            shapes = attn_shapes(c, std, out_std)
-        else:
-            shapes = {"attn_norm": ((e,), 0.0),
-                      "w_qkv": ((e, c.conv_channels), std),
-                      "w_f": ((e, c.key_dim), std), "w_b": ((e, H), std),
-                      "w_g": ((e, c.value_dim), std),
-                      "conv": ((c.conv_width, c.conv_channels), std),
-                      "a_log": ((H,), 0.0),
-                      "dt_bias": ((H, c.linear_key_dim), 0.0),
-                      "o_norm": ((c.linear_value_dim,), 0.0),
-                      "wo": ((c.value_dim, e), out_std)}
-        shapes["mlp_norm"] = ((e,), 0.0)
+        mixer, = self.layers[i].mixers
+        shapes = {"attn_norm": ((e,), 0.0), **mixer.shapes(std, out_std),
+                  "mlp_norm": ((e,), 0.0)}
         if c.mlp_layer_types[i] == DENSE:
             shapes.update(gate=((e, c.d_ff), std), up=((e, c.d_ff), std),
                           down=((c.d_ff, e), out_std))
@@ -232,246 +287,3 @@ class HybridKDAMoE(StateSlots, LatentAttention, DenseOrRoutedFFN,
             top_k=c.num_experts_per_tok, norm_topk_prob=c.norm_topk_prob,
             scale=c.routed_scaling_factor, held=c.held, n_group=c.n_group,
             topk_group=c.topk_group)
-
-    @R.region(R.MIXER_IN)
-    def _linear_inputs(self, layer: Params, u, mixed):
-        """What the recurrence takes of positions u (n, e) whose convolved
-        channels are `mixed` (n, channels): q, k (n, H, dk) and v (n, H,
-        dv) in the activations' dtype, g (n, H, dk) and beta (n, H)
-        float32."""
-        c = self.config
-        ad = c.activation_dtype
-        H, dk = c.n_heads, c.linear_key_dim
-        n = u.shape[0]
-        q, k, v = jnp.split(mixed, [c.key_dim, 2 * c.key_dim], axis=-1)
-        q = l2_normalize(q.reshape(n, H, dk)) * dk ** -0.5
-        k = l2_normalize(k.reshape(n, H, dk))
-        f32 = jnp.float32           # the offsets are added in float32
-        g, beta = _kda.gates(
-            (u @ layer["w_f"].astype(ad)).reshape(n, H, dk),
-            u @ layer["w_b"].astype(ad),
-            c.a_log_init + layer["a_log"].astype(f32),
-            c.dt_bias_init + layer["dt_bias"].astype(f32),
-            c.kda_lower_bound)
-        return (q.astype(ad), k.astype(ad),
-                v.reshape(n, H, c.linear_value_dim), g, beta)
-
-    @R.region(R.MIXER_OUT)
-    def _linear_out(self, layer: Params, u, o):
-        """Heads' outputs o (n, H, dv): normed a head, gated by the
-        sigmoid of a projection of the layer's input u (n, e), through
-        W_o."""
-        c = self.config
-        ad = c.activation_dtype
-        z = (u @ layer["w_g"].astype(ad)).reshape(o.shape)
-        o = rms_norm_reference(o.astype(jnp.float32), layer["o_norm"],
-                               c.norm_eps)
-        y = (o * jax.nn.sigmoid(z.astype(jnp.float32))).astype(ad)
-        return y.reshape(u.shape[0], -1) @ layer["wo"].astype(ad)
-
-    def _linear_seq(self, layer: Params, u, true_len=None):
-        """A linear layer over one sequence u (s, e), normed. With a
-        `true_len` (a prefill's padded bucket) through `kda_prefill`, the
-        kernel where there is one; without, through the plain chunked
-        form, which JAX differentiates. Returns (the output after W_o
-        (s, e), the state at the sequence's end (H, dk, dv) float32, the
-        convolution's tail)."""
-        c = self.config
-        s = u.shape[0]
-        with R.region(R.MIXER_IN):
-            mixed, tail = causal_conv(
-                u @ layer["w_qkv"].astype(c.activation_dtype),
-                layer["conv"], true_len)
-            q, k, v, g, beta = self._linear_inputs(layer, u, mixed)
-            pad = -s % c.chunk              # whole chunks; padding is inert
-            q, k, v, g, beta = (
-                jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1)).swapaxes(
-                    0, 1)
-                for a in (q, k, v, g, beta))
-        with R.region(R.MIXER_CORE):
-            if true_len is None:
-                o, state = _kda.kda_chunked(q, k, v, g, beta, chunk=c.chunk)
-            else:
-                o, state = _kda.kda_prefill(q, k, v, g, beta, true_len,
-                                            c.chunk)
-            o = o.swapaxes(0, 1)[:s]
-        return self._linear_out(layer, u, o), state, tail
-
-    # --------------------------------------------------------- forward
-    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) -> hidden states after the final norm."""
-        c = self.config
-        ad = c.activation_dtype
-        b, s = tokens.shape
-        x = self._embed(params, tokens)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(
-                jnp.broadcast_to(jnp.arange(s), (b, s)),
-                c.qk_rope_head_dim, c.rope_theta)
-        for i, layer in enumerate(params["layers"]):
-            u = self._norm(x, layer["attn_norm"])
-            if c.layer_types[i] == LATENT:
-                attn, _, _ = self._attn_expanded(layer, u, cos, sin)
-                with R.region(R.ATTN_OUT):
-                    x = x + attn @ layer["wo"].astype(ad)
-            else:
-                mixed = jax.vmap(
-                    lambda seq: self._linear_seq(layer, seq)[0])(u)
-                with R.region(R.MIXER_OUT):
-                    x = x + mixed
-            x, _ = self._block_ffn(layer, x)
-        return self._final_norm(params, x)
-
-    # ------------------------------------------------ what an engine asks
-    def state_bytes(self, dtype=None) -> int:
-        """Bytes the linear layers keep of one sequence, whatever its
-        length: a float32 state and the convolution's tail a layer, as
-        the pools hold them (`tail_shape`: whole tiles of rows)."""
-        c = self.config
-        dt = jnp.dtype(dtype or c.activation_dtype)
-        return len(c.of_kind(LINEAR)) * (
-            c.linear_key_dim * c.value_dim * 4
-            + math.prod(_gd.tail_shape(c.conv_width, c.conv_channels))
-            * dt.itemsize)
-
-    def init_cache(self, num_pages: int, page_size: int, dtype=None,
-                   fixed_pages: int = 0) -> Cache:
-        """`num_pages` pages in the latent layers' pool; `fixed_pages`
-        state slots (the allocator's fixed class, one a sequence) and one
-        more, nobody's, in the linear layers'."""
-        c = self.config
-        dt = dtype or c.activation_dtype
-        rows = (self.pool_rows, num_pages, page_size, c.row_width)
-        lin, slots = len(c.of_kind(LINEAR)), fixed_pages + 1
-        make = jax.jit(lambda: {
-            "kv": jnp.zeros(rows, dt),
-            "state": jnp.zeros((lin, slots, c.linear_key_dim, c.value_dim),
-                               jnp.float32),
-            "tail": jnp.zeros((lin, slots) + _gd.tail_shape(
-                c.conv_width, c.conv_channels), dt),
-            **self._zero_counts()})
-        return make()
-
-    @property
-    def pool_rows(self) -> int:
-        return len(self.config.of_kind(LATENT))
-
-    @property
-    def expert_load_shape(self) -> Tuple[int, int]:
-        return len(self.config.of_kind(SPARSE)), self.config.held[1]
-
-    def page_bytes(self, page_size: int, tp_shards: int = 1,
-                   dtype=None) -> int:
-        """The latent layers' rows."""
-        return LatentAttention.cache_page_bytes(self, page_size, tp_shards,
-                                                dtype)
-
-    def decode_attention(self, page_size: int, dtype=None) -> str:
-        """The kernel of each mixer kind, or "einsum" where the latent
-        kernel does not tile the pool."""
-        c = self.config
-        latent = LatentAttention.decode_attention(self, page_size, dtype)
-        if latent == "einsum":
-            return latent
-        step = (_kda.KERNEL_STEP if _kda.uses_step_kernel(
-            c.n_heads, c.linear_key_dim, c.linear_value_dim)
-            else "kda_gather")
-        return "+".join(name for name, kind in (
-            (latent, LATENT), (step, LINEAR)) if c.of_kind(kind))
-
-    def prefill(self, params: Params, tokens: jax.Array, true_len,
-                page_table: jax.Array, cache: Cache,
-                page_size: int) -> Tuple[jax.Array, Cache]:
-        """A latent layer in the expanded form through the flash kernel,
-        its rows written as whole pages in place; a linear layer scanned
-        from a zero state to `true_len`, its state and tail written whole
-        into the slot the table's first entry names; padding past
-        `true_len` given to no expert."""
-        c = self.config
-        ad = c.activation_dtype
-        pools = dict(cache)
-        num_pages = pools["kv"].shape[1]
-        slots = pools["state"].shape[1] - 1
-        s = tokens.shape[0]
-        x = self._embed(params, tokens)                         # (s, e)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(jnp.arange(s)[None],
-                                    c.qk_rope_head_dim, c.rope_theta)
-        ids = prefill_page_ids(page_table, true_len, s, num_pages, page_size)
-        slot = prefill_state_slot(page_table, slots)
-        with R.region(R.CACHE):
-            valid = jnp.arange(s) < true_len
-        for i, layer in enumerate(params["layers"]):
-            u = self._norm(x, layer["attn_norm"])
-            if c.layer_types[i] == LATENT:
-                li = c.of_kind(LATENT).index(i)
-                attn, c_kv, k_rope = self._attn_expanded(layer, u[None],
-                                                         cos, sin)
-                pools["kv"] = self._write_pages(
-                    pools["kv"], li, c_kv[0], k_rope[0], ids, page_size)
-                with R.region(R.ATTN_OUT):
-                    x = x + attn[0] @ layer["wo"].astype(ad)
-            else:
-                li = c.of_kind(LINEAR).index(i)
-                mixed, state, tail = self._linear_seq(layer, u, true_len)
-                with R.region(R.MIXER_CORE):
-                    # (H, dk, dv) -> the pool's (dk, H x dv)
-                    state = state.transpose(1, 0, 2).reshape(
-                        c.linear_key_dim, c.value_dim)
-                    pools.update(self._write_slot(pools, li, slot, state,
-                                                  tail))
-                with R.region(R.MIXER_OUT):
-                    x = x + mixed
-            x, _ = self._block_ffn(layer, x, valid)
-        return self._logits(params, x, true_len), pools
-
-    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
-                    positions: jax.Array, page_tables: jax.Array,
-                    active: jax.Array,
-                    page_size: int) -> Tuple[jax.Array, Cache]:
-        """A latent layer in the absorbed form. An inactive lane, or one
-        whose table is unassigned, writes no row, no state and no tail,
-        and is given to no expert."""
-        c = self.config
-        ad = c.activation_dtype
-        pools = dict(cache)
-        num_pages = pools["kv"].shape[1]
-        slots = pools["state"].shape[1] - 1
-        x = self._embed(params, tokens)                         # (B, e)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
-                                    c.rope_theta)          # (B, 1, rope/2)
-        page, offset, lengths = decode_lanes(positions, page_tables, active,
-                                             num_pages, page_size)
-        slot = decode_state_slots(page_tables, active, slots)
-        load, sums = pools["moe_load"], self._step_sums()
-        for i, layer in enumerate(params["layers"]):
-            u = self._norm(x, layer["attn_norm"])
-            if c.layer_types[i] == LATENT:
-                li = c.of_kind(LATENT).index(i)
-                out, pools["kv"] = self._attn_absorbed(
-                    layer, u, cos, sin, pools["kv"], li, page, offset,
-                    page_tables, lengths)
-                with R.region(R.ATTN_OUT):
-                    x = x + out @ layer["wo"].astype(ad)
-            else:
-                li = c.of_kind(LINEAR).index(i)
-                with R.region(R.MIXER_IN):
-                    mixed, pools["tail"] = _gd.conv_tail_step(
-                        u @ layer["w_qkv"].astype(ad), layer["conv"],
-                        pools["tail"], li, slot)
-                q, k, v, g, beta = self._linear_inputs(layer, u, mixed)
-                with R.region(R.MIXER_CORE):
-                    o, pools["state"] = _kda.kda_step(
-                        q, k, v, g, beta, pools["state"], li, slot)
-                mixed = self._linear_out(layer, u, o)
-                with R.region(R.MIXER_OUT):
-                    x = x + mixed
-            x, counts = self._block_ffn(layer, x, active)
-            if counts is not None:
-                with R.region(R.MOE_ROUTE):
-                    load = load.at[c.of_kind(SPARSE).index(i)].add(
-                        counts["load"])
-                sums = self._count_step(sums, counts)
-        return self._logits(params, x), {**pools,
-                                         **self._counted(load, sums)}

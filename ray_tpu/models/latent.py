@@ -1,9 +1,9 @@
-"""Multi-head latent attention (MLA) as the classes that have it share it:
-`models.mla_moe.MLAMoE` (one attention a layer),
-`models.shortcut_mla_moe.ShortcutMLAMoE` (two) and
+"""Multi-head latent attention (MLA) as the classes that have it share it
+(`LatentAttention`, a `models.paged.Mixer`): `models.mla_moe.MLAMoE` (one
+attention a layer), `models.shortcut_mla_moe.ShortcutMLAMoE` (two),
 `models.hybrid_kda_moe.HybridKDAMoE` (one layer in six, no query LoRA, a
-gate a head), so that each class's tests and cells guard the others'
-attention.
+gate a head) and, under an indexer, `models.sparse_mla_moe.SparseMLAMoE`,
+so that each class's tests and cells guard the others' attention.
 
 With `x` the normed input of an attention and `a_q`, `a_kv` the config's two
 LoRA scales (1 where the published model has none):
@@ -33,19 +33,19 @@ decode step's (`ops.paged_attention.mla_paged_decode_attention`).
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
+from ray_tpu.models.paged import (PAGED, Cache, Mixer, Params, Pool,
+                                  Walk)
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.norms import rms_norm_reference
-from ray_tpu.ops.rope import apply_rope_cached
-
-Params = Dict[str, Any]
+from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin
 
 # prefill's flash blocks (block_q, block_k), read on the chip alone at the
 # three classes' heads and widths (20 of 256 / 256, 64 and 32 of 192 / 128)
@@ -84,37 +84,59 @@ class LatentDims(ConfigDtypes):
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
 
-def attn_shapes(c, std: float, out_std: float
-                ) -> Dict[str, Tuple[tuple, float]]:
-    """(shape, init std) of one latent attention's leaves and of the norm
-    before it; std 0 means zeros (a norm scale, stored as w with the layer
-    multiplying by 1 + w)."""
-    e, H = c.d_model, c.n_heads
-    if c.q_lora_rank:
-        query = {"wq_a": ((e, c.q_lora_rank), std),
-                 "q_norm": ((c.q_lora_rank,), 0.0),
-                 "wq_b": ((c.q_lora_rank, H * c.qk_head_dim), std)}
-    else:                               # no LoRA: one matrix
-        query = {"wq": ((e, H * c.qk_head_dim), std)}
-    return {
-        "attn_norm": ((e,), 0.0),
-        **query,
-        "wkv_a": ((e, c.kv_lora_rank + c.qk_rope_head_dim), std),
-        "kv_norm": ((c.kv_lora_rank,), 0.0),
-        "wkv_b": ((c.kv_lora_rank,
-                   H * (c.qk_nope_head_dim + c.v_head_dim)), std),
-        "wo": ((H * c.v_head_dim, e), out_std),
-        **({"w_head_gate": ((e, H), std)} if c.head_gate else {}),
-    }
+class LatentAttention(Mixer):
+    """The latent attention of `config` (a `LatentDims` with `rope_theta`
+    and `norm_eps`); `layer` is the dict of one attention's leaves
+    (`shapes`). Its pool `"kv"` holds one row an attention, which is the
+    engine's `pool_rows`."""
 
+    closes = R.ATTN_OUT
+    batched = True
 
-class LatentAttention:
-    """The latent attention of `self.config` (a `LatentDims`); `layer` is
-    the dict of one attention's leaves (`attn_shapes`). Mixed into a
-    `models.paged.PagedDecoder`, which it answers for about the pool; the
-    class says `pool_rows`, the rows of the latent pool, one an
-    attention."""
+    def __init__(self, config):
+        self.config, self.dtype = config, config.activation_dtype
+        self.pools = (Pool("kv", PAGED, (config.row_width,),
+                           an_attention=True),)
 
+    def shapes(self, std: float, out_std: float
+               ) -> Dict[str, Tuple[tuple, float]]:
+        """(shape, init std) of one latent attention's leaves; std 0 means
+        zeros (a norm scale, stored as w with the layer multiplying by 1 +
+        w)."""
+        c = self.config
+        e, H = c.d_model, c.n_heads
+        if c.q_lora_rank:
+            query = {"wq_a": ((e, c.q_lora_rank), std),
+                     "q_norm": ((c.q_lora_rank,), 0.0),
+                     "wq_b": ((c.q_lora_rank, H * c.qk_head_dim), std)}
+        else:                               # no LoRA: one matrix
+            query = {"wq": ((e, H * c.qk_head_dim), std)}
+        return {
+            **query,
+            "wkv_a": ((e, c.kv_lora_rank + c.qk_rope_head_dim), std),
+            "kv_norm": ((c.kv_lora_rank,), 0.0),
+            "wkv_b": ((c.kv_lora_rank,
+                       H * (c.qk_nope_head_dim + c.v_head_dim)), std),
+            "wo": ((H * c.v_head_dim, e), out_std),
+            **({"w_head_gate": ((e, H), std)} if c.head_gate else {}),
+        }
+
+    def decode_kernel(self, page_size: int, dtype) -> str:
+        """The latent kernel's name, or "einsum"."""
+        c = self.config
+        if _paged.mla_uses_kernel(c.row_width, c.kv_lora_rank, page_size,
+                                  dtype):
+            return _paged.KERNEL_MLA_PAGED_DECODE
+        return "einsum"
+
+    def open(self, at: Walk) -> None:
+        """The rotary part's cos and sin of the program's positions."""
+        c = self.config
+        with R.region(R.ATTN_IN):
+            at.tables[self] = rope_cos_sin(
+                at.positions(), c.qk_rope_head_dim, c.rope_theta)
+
+    # --------------------------------------------------------- pieces
     @R.region(R.ATTN_IN)
     def _q(self, layer: Params, h):
         """h (..., e) -> q (..., heads, nope + rope), not yet rotated."""
@@ -248,28 +270,40 @@ class LatentAttention:
                              w_kvb[..., nope:])
         return self._gated(layer, h, out), pool
 
-    # ------------------------------------------------ what an engine asks
-    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
-                         dtype=None) -> int:
-        """Bytes one page costs (all pool rows): the rows as the pool holds
-        them, padding and all. The latent is shared by every head, so a
-        tp shard holds it whole."""
-        c = self.config
-        dt = jnp.dtype(dtype or c.activation_dtype)
-        return self.pool_rows * page_size * c.row_width * dt.itemsize
+    # ------------------------------------------------------- forwards
+    def _prompt(self, layer: Params, h, pools: Cache, li: int, at: Walk):
+        """A prompt h (1, s, e) in the expanded form, its rows written as
+        whole pages in place. Returns (the attention's output before W_o,
+        the pools written)."""
+        attn, c_kv, k_rope = self._attn_expanded(layer, h, *at.tables[self])
+        pool = pools["kv"]
+        return attn, {"kv": self._write_pages(
+            pool, li, c_kv[0], k_rope[0], at.pages[PAGED], pool.shape[2])}
 
-    def decode_attention(self, page_size: int, dtype=None) -> str:
-        """Which attention a `decode_step` traced here holds: the latent
-        kernel's name, or "einsum"."""
-        c = self.config
-        if _paged.mla_uses_kernel(c.row_width, c.kv_lora_rank, page_size,
-                                  dtype or c.activation_dtype):
-            return _paged.KERNEL_MLA_PAGED_DECODE
-        return "einsum"
+    def _lanes(self, layer: Params, h, pools: Cache, li: int, at: Walk):
+        """A decode step's lanes h (B, e) in the absorbed form. Returns
+        (the attention's output before W_o, the pools written)."""
+        page, tables = at.pages[PAGED]
+        out, pool = self._attn_absorbed(
+            layer, h, *at.tables[self], pools["kv"], li, page, at.offset,
+            tables, at.lengths)
+        return out, {"kv": pool}
 
-    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
-        """Pages a block of the latent kernel's walk holds over tables of
-        `max_pages`, asked what the kernel asks (one pool row's page)."""
-        return _paged.walk_block_pages(
-            self.cache_page_bytes(page_size) // self.pool_rows,
-            page_size, max_pages)
+    def hidden(self, layer: Params, h, at: Walk):
+        attn, _, _ = self._attn_expanded(layer, h, *at.tables[self])
+        with R.region(R.ATTN_OUT):
+            return attn @ layer["wo"].astype(self.dtype)
+
+    def prefill(self, layer: Params, h, pools: Cache, li: int, at: Walk):
+        one = h.ndim == 2               # a stream without a batch of one
+        attn, written = self._prompt(layer, h[None] if one else h, pools,
+                                     li, at)
+        with R.region(R.ATTN_OUT):
+            return (attn[0] if one else attn) @ layer["wo"].astype(
+                self.dtype), written
+
+    def decode_step(self, layer: Params, h, pools: Cache, li: int,
+                    at: Walk):
+        out, written = self._lanes(layer, h, pools, li, at)
+        with R.region(R.ATTN_OUT):
+            return out @ layer["wo"].astype(self.dtype), written

@@ -11,8 +11,8 @@ Every layer's attention is MLA. With `x` the normed input:
     scores = q . [k_nope | k_rope] / sqrt(nope + rope), causal softmax
     o = concat_h(P v) W_o
 
-`k_rope` is one for all heads; the attention's pieces are
-`models.latent.LatentAttention`'s, shared with `ShortcutMLAMoE`. The first
+`k_rope` is one for all heads; the attention is the mixer
+`models.latent.LatentAttention`, shared with `ShortcutMLAMoE`. The first
 `first_k_dense_replace` layers have a SwiGLU feed-forward, the rest
 `models.moe.dropless_moe_ffn` (scores in float32, sigmoid as published,
 top-k of score + bias, weights from the scores alone, normalised and
@@ -44,15 +44,9 @@ import dataclasses
 import math
 from typing import Dict, Tuple
 
-import jax
-import jax.numpy as jnp
-
-from ray_tpu.models import regions as R
-from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
+from ray_tpu.models.latent import LatentAttention, LatentDims
 from ray_tpu.models.moe import STEP_COUNTS, DenseOrRoutedFFN
-from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
-                                  decode_lanes, prefill_page_ids)
-from ray_tpu.ops.rope import rope_cos_sin
+from ray_tpu.models.paged import ExpertCounts, Layer, PagedDecoder, Params
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,8 +102,7 @@ def tiny_mla_moe(vocab_size: int = 256) -> MLAMoEConfig:
         dtype="float32", param_dtype="float32")
 
 
-class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
-             PagedDecoder):
+class MLAMoE(DenseOrRoutedFFN, ExpertCounts, PagedDecoder):
     """Functional model bundle for one MLAMoEConfig: `init`, `apply` /
     `loss` (training graph; no auxiliary term: the published routing has a
     bias moved between steps, not a loss), and what a serving engine asks a
@@ -118,6 +111,20 @@ class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
     no_mesh = "experts and the latent cache are not sharded over chips yet"
     # a layer holds all its experts: none is away, no slot computes nothing
     step_count_names = STEP_COUNTS[:3]
+    # the attention's mixer; a subclass names another
+    attention_type = LatentAttention
+
+    def __init__(self, config: MLAMoEConfig, mesh=None):
+        super().__init__(config, mesh)
+        c = config
+        self.attention = self.attention_type(c)
+        self._lay([self.attention], [
+            Layer((self.attention,), experts=self._experts_held() if (
+                i >= c.first_k_dense_replace) else 0)
+            for i in range(c.n_layers)])
+
+    def _experts_held(self) -> int:
+        return self.config.n_routed_experts
 
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
@@ -126,7 +133,9 @@ class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
         e = c.d_model
         std = 0.02
         out_std = std / math.sqrt(2 * c.n_layers)
-        shapes = {**attn_shapes(c, std, out_std), "mlp_norm": ((e,), 0.0)}
+        shapes = {"attn_norm": ((e,), 0.0),
+                  **self.attention.shapes(std, out_std),
+                  "mlp_norm": ((e,), 0.0)}
         if i < c.first_k_dense_replace:
             shapes.update(gate=((e, c.d_ff), std), up=((e, c.d_ff), std),
                           down=((c.d_ff, e), out_std))
@@ -147,106 +156,3 @@ class MLAMoE(LatentAttention, DenseOrRoutedFFN, ExpertCounts,
         return layer["router_bias"], dict(
             top_k=c.num_experts_per_tok, norm_topk_prob=c.norm_topk_prob,
             scale=c.routed_scaling_factor)
-
-    # --------------------------------------------------------- forward
-    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) -> hidden states after the final norm."""
-        c = self.config
-        ad = c.activation_dtype
-        b, s = tokens.shape
-        x = self._embed(params, tokens)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(
-                jnp.broadcast_to(jnp.arange(s), (b, s)),
-                c.qk_rope_head_dim, c.rope_theta)
-        for layer in params["layers"]:
-            h = self._norm(x, layer["attn_norm"])
-            attn, _, _ = self._attn_expanded(layer, h, cos, sin)
-            with R.region(R.ATTN_OUT):
-                x = x + attn @ layer["wo"].astype(ad)
-            x, _ = self._block_ffn(layer, x)
-        return self._final_norm(params, x)
-
-    # ------------------------------------------------ what an engine asks
-    def init_cache(self, num_pages: int, page_size: int,
-                   dtype=None) -> Cache:
-        c = self.config
-        dt = dtype or c.activation_dtype
-        shape = (c.n_layers, num_pages, page_size, c.row_width)
-        make = jax.jit(lambda: {"kv": jnp.zeros(shape, dt),
-                                **self._zero_counts()})
-        return make()
-
-    @property
-    def pool_rows(self) -> int:
-        return self.config.n_layers
-
-    @property
-    def expert_load_shape(self) -> Tuple[int, int]:
-        return self.config.n_moe_layers, self.config.n_routed_experts
-
-    def prefill(self, params: Params, tokens: jax.Array, true_len,
-                page_table: jax.Array, cache: Cache,
-                page_size: int) -> Tuple[jax.Array, Cache]:
-        """The expanded attention, the latent rows written as whole pages
-        in place. Padding past `true_len` is given to no expert."""
-        c = self.config
-        ad = c.activation_dtype
-        pool = cache["kv"]
-        num_pages = pool.shape[1]
-        s = tokens.shape[0]
-        x = self._embed(params, tokens)[None]                   # (1, s, e)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(jnp.arange(s)[None],
-                                    c.qk_rope_head_dim, c.rope_theta)
-        with R.region(R.CACHE):
-            valid = (jnp.arange(s) < true_len)[None]
-        page_ids = prefill_page_ids(page_table, true_len, s, num_pages,
-                                    page_size)
-        for i, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["attn_norm"])
-            attn, c_kv, k_rope = self._attn_expanded(layer, h, cos, sin)
-            pool = self._write_pages(pool, i, c_kv[0], k_rope[0], page_ids,
-                                     page_size)
-            with R.region(R.ATTN_OUT):
-                x = x + attn @ layer["wo"].astype(ad)
-            x, _ = self._block_ffn(layer, x, valid)
-        return self._logits(params, x, true_len), {**cache, "kv": pool}
-
-    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
-                    positions: jax.Array, page_tables: jax.Array,
-                    active: jax.Array,
-                    page_size: int) -> Tuple[jax.Array, Cache]:
-        """In the absorbed form. Inactive lanes write nothing and are
-        given to no expert."""
-        c = self.config
-        ad = c.activation_dtype
-        pool = cache["kv"]
-        x = self._embed(params, tokens)                         # (B, e)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
-                                    c.rope_theta)          # (B, 1, rope/2)
-        wr_page, wr_slot, lengths = decode_lanes(
-            positions, page_tables, active, pool.shape[1], page_size)
-        load = cache["moe_load"]
-        pairs, touched, load_max = self._step_sums()
-        for i, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["attn_norm"])
-            out, pool = self._attn_absorbed(
-                layer, h, cos, sin, pool, i, wr_page, wr_slot, page_tables,
-                lengths)
-            with R.region(R.ATTN_OUT):
-                x = x + out @ layer["wo"].astype(ad)
-            x, counts = self._block_ffn(layer, x, active)
-            if counts is not None:
-                # as `_count_step`, the maximum taken after the two sums
-                # (the traced text's order)
-                j = i - c.first_k_dense_replace
-                with R.region(R.MOE_ROUTE):
-                    load = load.at[j].add(counts["load"])
-                    pairs = pairs + counts["pairs"]
-                    touched = touched + counts["touched"]
-                    load_max = load_max + jnp.max(counts["load"])
-        return self._logits(params, x), {
-            "kv": pool, **self._counted(load, (pairs, touched, load_max))}
-

@@ -314,7 +314,7 @@ class DenseOrRoutedFFN:
     has one (`"shared_gate"`, `"shared_up"`, `"shared_down"`). The class says
     `_routing(layer)`: (the router's bias, `dropless_moe_ffn`'s `top_k`,
     `norm_topk_prob` and `scale`, and `held` and the group limit where it
-    has them)."""
+    has them). `PagedDecoder._block_ffn` puts it behind its norm."""
 
     def _ffn(self, layer, x, valid=None):
         """Feed-forward of one layer on tokens x (T, e) after the norm.
@@ -331,11 +331,3 @@ class DenseOrRoutedFFN:
         with R.region(R.FFN):       # the shared expert, added
             return y + swiglu(x, layer["shared_gate"], layer["shared_up"],
                               layer["shared_down"]), counts
-
-    def _block_ffn(self, layer, x, valid=None):
-        """x (..., e) + ffn(norm(x)); returns (x, counts)."""
-        h = self._norm(x, layer["mlp_norm"])
-        y, counts = self._ffn(layer, h.reshape(-1, h.shape[-1]),
-                              None if valid is None else valid.reshape(-1))
-        with R.region(R.FFN):       # the residual addition
-            return x + y.reshape(x.shape), counts

@@ -16,7 +16,7 @@ names them:
     x = x + MLP(N_2(x))
     logits = lm_head_multiplier * (N_f(x) W_head)
 
-`SSM(u)` is `models.ssm.SSMMixer`'s, `W_in`'s product multiplied column by
+`SSM(u)` is `models.ssm.SSM`'s, `W_in`'s product multiplied column by
 column by `m`: `ssm_multipliers[0..4]` over the z, x, B, C and dt columns;
 the gated norm in the order `mamba_norm_before_gate` says.
 
@@ -34,15 +34,13 @@ into a weight.
 
 **Both kinds of cache in every layer, behind one page table**: pools `"k"`,
 `"v"` `(layers, num_pages, page, kv heads x head dim)` (`models/gqa.py`)
-and `"state"`, `"tail"` `(layers, slots + 1, ...)` (`models.ssm.SSMMixer`),
+and `"state"`, `"tail"` `(layers, slots + 1, ...)` (`models.ssm.SSM`),
 all four over all the layers and under the same layer index. A sequence's
-first table entry is a page of the allocator's fixed class
-(`paged.StateSlots`): it names the slot of its states and is, like every
-later entry, a page of its keys and values. `prefill` runs the flash
-forward and the chunked scan on the same `h` and writes pages and slot in
-the one layer; `decode_step` runs `gqa.decode_attend` and `_ssm_step` on
-the same `h`. The two do not depend on each other: the order they are
-issued in (attention, then the scan) is no order the compiler must keep.
+first table entry is a page of the allocator's fixed class: it names the
+slot of its states and is, like every later entry, a page of its keys and
+values. A row of the table holds both mixers, which read the same `h`; the
+two do not depend on each other: the order they are issued in (attention,
+then the scan) is no order the compiler must keep.
 """
 from __future__ import annotations
 
@@ -51,16 +49,12 @@ import math
 from typing import Dict, Tuple
 
 import jax
-import jax.numpy as jnp
 
-from ray_tpu.models import gqa
 from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
-from ray_tpu.models.paged import (Cache, PagedDecoder, Params, StateSlots,
-                                  decode_lanes, decode_state_slots,
-                                  prefill_page_ids, prefill_state_slot)
-from ray_tpu.models.ssm import SSMDims, SSMMixer
-from ray_tpu.ops import paged_attention as _paged
+from ray_tpu.models.gqa import Attention
+from ray_tpu.models.paged import Layer, PagedDecoder, Params, Walk
+from ray_tpu.models.ssm import SSM, SSMDims
 from ray_tpu.ops import rope as _rope
 from ray_tpu.ops import ssd as _ssd
 
@@ -137,7 +131,33 @@ def tiny_parallel_hybrid(vocab_size: int = 256) -> ParallelHybridConfig:
         param_dtype="float32")
 
 
-class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
+class ScaledAttention(Attention):
+    """The layers' attention: its input, its key and its output each
+    times a published scalar, q and k rotated over the whole head."""
+
+    def __init__(self, config: ParallelHybridConfig):
+        c = self.config = config
+        super().__init__(c.d_model, c.n_heads, c.n_kv_heads, c.head_dim,
+                         c.activation_dtype)
+
+    @R.region(R.ATTN_IN)
+    def _qkv(self, layer: Params, h, at: Walk):
+        """h (..., e) normed -> q (..., heads, hd), k, v (..., kv heads,
+        hd): the key scaled, then q and k rotated."""
+        c = self.config
+        positions = at.positions_along(h)
+        q, k, v = super()._qkv(layer, h * c.attention_in_multiplier, at)
+        cos, sin = _rope.rope_cos_sin(positions, c.head_dim, c.rope_theta)
+        return (_rope.apply_rope_cached(q, cos, sin),
+                _rope.apply_rope_cached(k * c.key_multiplier, cos, sin), v)
+
+    @R.region(R.ATTN_OUT)
+    def _out(self, layer: Params, h, out):
+        return super()._out(layer, h, out
+                            ) * self.config.attention_out_multiplier
+
+
+class ParallelHybrid(PagedDecoder):
     """Functional model bundle for one ParallelHybridConfig: `init`,
     `apply` / `loss` (the plain chunked scan, differentiated by JAX), and
     what a serving engine asks a model for (`models.paged.PagedDecoder`)."""
@@ -145,93 +165,51 @@ class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
     no_mesh = ("neither the state pools nor a layer's two mixers are "
                "sharded over chips yet")
 
+    def __init__(self, config: ParallelHybridConfig, mesh=None):
+        super().__init__(config, mesh)
+        c = config
+        self.attention = ScaledAttention(c)
+        self.ssm = SSM(c, c.ssm_in_multiplier, c.ssm_multipliers,
+                       c.mamba_norm_before_gate)
+        self._lay([self.attention, self.ssm],
+                  [Layer((self.attention, self.ssm), "norm")] * c.n_layers)
+
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
         """Every layer alike: attention, the state-space mixer, the
         feed-forward and two norms (zeros are a norm's scale w, the layer
         multiplying by 1 + w, and the mixer's offsets and bias)."""
         c = self.config
-        e, q, f = c.d_model, c.n_heads * c.head_dim, c.d_ff
+        e, f = c.d_model, c.d_ff
         std = 0.02
         out_std = std / math.sqrt(2 * c.n_layers)
-        return {"norm": ((e,), 0.0), "wq": ((e, q), std),
-                "wk": ((e, c.kv_dim), std), "wv": ((e, c.kv_dim), std),
-                "wo": ((q, e), out_std), **self.ssm_shapes(std, out_std),
+        return {"norm": ((e,), 0.0), **self.attention.shapes(std, out_std),
+                **self.ssm.shapes(std, out_std),
                 "mlp_norm": ((e,), 0.0), "gate": ((e, f), std),
                 "up": ((e, f), std), "down": ((f, e), out_std)}
 
     # --------------------------------------------------------- pieces
-    @property
-    def ssm_column_scale(self):
-        """`ssm_multipliers` spread over `W_in`'s columns [z | x | B | C |
-        dt], in the activations' dtype."""
-        c = self.config
-        widths = (c.ssm_inner, c.ssm_inner, c.bc_dim, c.bc_dim, c.ssm_heads)
-        return jnp.concatenate([
-            jnp.full((n,), m, c.activation_dtype)
-            for n, m in zip(widths, c.ssm_multipliers)])
-
-    @property
-    def ssm_norm_before_gate(self) -> bool:
-        return self.config.mamba_norm_before_gate
-
     @R.region(R.EMBED)
     def _embed(self, params: Params, tokens):
         c = self.config
         return params["embed"].astype(c.activation_dtype)[
             tokens] * c.embedding_multiplier
 
-    @R.region(R.ATTN_IN)
-    def _qkv(self, layer: Params, h, positions):
-        """h (..., e) normed, `positions` (...) -> q (..., heads, hd), k, v
-        (..., kv heads, hd): the key scaled, then q and k rotated."""
-        c = self.config
-        q, k, v = gqa.qkv(layer, h * c.attention_in_multiplier, c.n_heads,
-                          c.n_kv_heads, c.head_dim, c.activation_dtype)
-        cos, sin = _rope.rope_cos_sin(positions, c.head_dim, c.rope_theta)
-        return (_rope.apply_rope_cached(q, cos, sin),
-                _rope.apply_rope_cached(k * c.key_multiplier, cos, sin), v)
+    def _add(self, row: Layer, layer: Params, x, outs):
+        attn, ssm = outs
+        with R.region(R.MIXER_OUT):     # both mixers' residual addition
+            return x + ssm * self.config.ssm_out_multiplier + attn
 
-    def _attn_seq(self, layer: Params, h):
-        """Causal attention over whole sequences h (b, s, e). Returns (the
-        scaled output after W_o, k, v (b, s, kv heads, hd))."""
-        c = self.config
-        q, k, v = self._qkv(layer, h, jnp.arange(h.shape[-2]))
-        out = gqa.attend_seq(q, k, v)
-        with R.region(R.ATTN_OUT):
-            out = out.reshape(*h.shape[:-1], -1)
-            return (out @ layer["wo"].astype(c.activation_dtype)
-                    * c.attention_out_multiplier), k, v
-
-    @R.region(R.MIXER_IN)
-    def _ssm_in(self, h):
-        return h * self.config.ssm_in_multiplier
-
-    def _close(self, layer: Params, x, attn, ssm):
-        """The rest of a layer after its mixers: their sum added, then the
-        feed-forward on the second norm."""
+    @R.region(R.FFN)
+    def _ffn(self, layer: Params, h, valid=None):
+        """The SwiGLU on the second norm, its gate's input and its output
+        each times a scalar."""
         c = self.config
         ad = c.activation_dtype
-        with R.region(R.MIXER_OUT):     # both mixers' residual addition
-            x = x + ssm * c.ssm_out_multiplier + attn
-        h = self._norm(x, layer["mlp_norm"])
-        with R.region(R.FFN):
-            gate = jax.nn.silu(h @ layer["gate"].astype(ad)
-                               * c.mlp_multipliers[0])
-            return x + ((gate * (h @ layer["up"].astype(ad)))
-                        @ layer["down"].astype(ad)) * c.mlp_multipliers[1]
-
-    # --------------------------------------------------------- forward
-    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) -> hidden states after the final norm."""
-        x = self._embed(params, tokens)
-        for layer in params["layers"]:
-            h = self._norm(x, layer["norm"])
-            attn = self._attn_seq(layer, h)[0]
-            ssm = jax.vmap(lambda seq: self._ssm_seq(layer, seq)[0])(
-                self._ssm_in(h))
-            x = self._close(layer, x, attn, ssm)
-        return self._final_norm(params, x)
+        gate = jax.nn.silu(h @ layer["gate"].astype(ad)
+                           * c.mlp_multipliers[0])
+        return ((gate * (h @ layer["up"].astype(ad)))
+                @ layer["down"].astype(ad)) * c.mlp_multipliers[1], None
 
     @R.region(R.HEAD)
     def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
@@ -241,96 +219,3 @@ class ParallelHybrid(SSMMixer, StateSlots, PagedDecoder):
     def _logits(self, params: Params, x, true_len=None):
         return super()._logits(params, x, true_len
                                ) * self.config.lm_head_multiplier
-
-    # ------------------------------------------------ what an engine asks
-    def state_bytes(self, dtype=None) -> int:
-        """Bytes the mixers keep of one sequence, whatever its length."""
-        return self.config.n_layers * self.ssm_layer_bytes(dtype)
-
-    def init_cache(self, num_pages: int, page_size: int, dtype=None,
-                   fixed_pages: int = 0) -> Cache:
-        """`num_pages` pages of keys and values and `fixed_pages` state
-        slots (the allocator's fixed class, one a sequence) and one more,
-        nobody's, all in every layer."""
-        c = self.config
-        dt = dtype or c.activation_dtype
-        kv = (c.n_layers, num_pages, page_size, c.kv_dim)
-        make = jax.jit(lambda: {
-            "k": jnp.zeros(kv, dt), "v": jnp.zeros(kv, dt),
-            **self.ssm_pools(c.n_layers, fixed_pages + 1, dt)})
-        return make()
-
-    def page_bytes(self, page_size: int, tp_shards: int = 1,
-                   dtype=None) -> int:
-        """Keys and values of every layer."""
-        c = self.config
-        return c.n_layers * gqa.layer_page_bytes(
-            c.kv_dim, page_size, dtype or c.activation_dtype, tp_shards)
-
-    def decode_attention(self, page_size: int, dtype=None) -> str:
-        """A layer's two kernels, or "einsum"."""
-        c = self.config
-        return gqa.decode_kernels(
-            c.head_dim, page_size, dtype or c.activation_dtype,
-            [(_paged.KERNEL_PAGED_DECODE, True),
-             (self.ssm_step_name(), True)])
-
-    def walk_block_pages(self, page_size: int, max_pages: int) -> int:
-        c = self.config
-        return gqa.walk_block_pages(c.kv_dim, page_size, max_pages,
-                                    c.activation_dtype)
-
-    def prefill(self, params: Params, tokens: jax.Array, true_len,
-                page_table: jax.Array, cache: Cache,
-                page_size: int) -> Tuple[jax.Array, Cache]:
-        """Every layer through the flash kernel, its keys and values
-        written as whole pages in place, and scanned from a zero state to
-        `true_len`, its state and tail written whole into the slot the
-        table's first entry names."""
-        pools = dict(cache)
-        num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
-        x = self._embed(params, tokens)                         # (s, e)
-        ids = prefill_page_ids(page_table, true_len, tokens.shape[0],
-                               num_pages, page_size)
-        slot = prefill_state_slot(page_table, slots)
-        for li, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["norm"])
-            attn, k, v = self._attn_seq(layer, h[None])
-            pools.update(gqa.write_prompt(pools, ("k", "v"), li, ids, k, v))
-            ssm, state, tail = self._ssm_seq(layer, self._ssm_in(h),
-                                             true_len)
-            pools.update(self._write_slot(pools, li, slot, state, tail))
-            x = self._close(layer, x, attn[0], ssm)
-        return self._logits(params, x, true_len), pools
-
-    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
-                    positions: jax.Array, page_tables: jax.Array,
-                    active: jax.Array,
-                    page_size: int) -> Tuple[jax.Array, Cache]:
-        """An inactive lane, or one whose table is unassigned, writes no
-        page, no state and no tail."""
-        c = self.config
-        ad = c.activation_dtype
-        pools = dict(cache)
-        num_pages, slots = pools["k"].shape[1], pools["state"].shape[1] - 1
-        B = tokens.shape[0]
-        x = self._embed(params, tokens)                         # (B, e)
-        page, offset, lengths = decode_lanes(positions, page_tables, active,
-                                             num_pages, page_size)
-        slot = decode_state_slots(page_tables, active, slots)
-        for li, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["norm"])
-            q, k, v = self._qkv(layer, h, positions)
-            out, written = gqa.decode_attend(
-                pools, ("k", "v"), li, page, offset, q, k, v, page_tables,
-                lengths)
-            pools.update(written)
-            with R.region(R.ATTN_OUT):
-                attn = (out.astype(ad).reshape(B, -1)
-                        @ layer["wo"].astype(ad)
-                        * c.attention_out_multiplier)
-            ssm, written = self._ssm_step(layer, self._ssm_in(h), pools, li,
-                                          slot)
-            pools.update(written)
-            x = self._close(layer, x, attn, ssm)
-        return self._logits(params, x), pools

@@ -18,7 +18,7 @@ A layer, `x` its input and every `N` an RMSNorm with its own weight:
 Nothing orders `m` against the second half but the data: no barrier is put
 between them, and the compiler places the branch where it likes.
 
-`MLA` is `models.latent.LatentAttention`'s, with the two LoRA scales this
+`MLA` is the mixer `models.latent.LatentAttention`, with the two LoRA scales this
 family has: `mla_scale_q_lora` multiplies the normed query latent by
 `sqrt(d_model / q_lora_rank)` **before `W_qb`** (1,536 numbers a token
 where the heads are 12,288), and `mla_scale_kv_lora` the normed key-value
@@ -57,11 +57,11 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import regions as R
-from ray_tpu.models.latent import LatentAttention, LatentDims, attn_shapes
+from ray_tpu.models.latent import LatentAttention, LatentDims
 from ray_tpu.models.moe import SCORING, dropless_moe_ffn, swiglu
-from ray_tpu.models.paged import (Cache, ExpertCounts, PagedDecoder, Params,
-                                  decode_lanes, prefill_page_ids)
-from ray_tpu.ops.rope import rope_cos_sin
+from ray_tpu.models.paged import (PAGED, Cache, ExpertCounts, Layer,
+                                  PagedDecoder, Params, decode_lanes,
+                                  prefill_page_ids)
 
 @dataclasses.dataclass(frozen=True)
 class ShortcutMLAMoEConfig(LatentDims):
@@ -137,12 +137,23 @@ def tiny_shortcut_mla_moe(vocab_size: int = 256,
         dtype="float32", param_dtype="float32")
 
 
-class ShortcutMLAMoE(LatentAttention, ExpertCounts, PagedDecoder):
+class ShortcutMLAMoE(ExpertCounts, PagedDecoder):
     """Functional model bundle for one ShortcutMLAMoEConfig: `init`,
     `apply` / `loss` (training graph), and what a serving engine asks a
-    model for (`models.paged.PagedDecoder`)."""
+    model for (`models.paged.PagedDecoder`). Its table says what a layer
+    keeps (two rows of the latent pool, the experts held); the layer
+    itself, with the expert branch beside its second half, is no row of
+    mixers and a feed-forward, so `_layer` and the three walks are its
+    own."""
 
     no_mesh = "the experts' exchange over chips has not been built"
+
+    def __init__(self, config: ShortcutMLAMoEConfig, mesh=None):
+        super().__init__(config, mesh)
+        self.attention = LatentAttention(config)
+        self._lay([self.attention], [Layer(
+            (self.attention,) * 2, experts=config.held[1])
+        ] * config.n_layers)
 
     # ------------------------------------------------------------ init
     def layer_shapes(self, i: int) -> Dict[str, Any]:
@@ -156,7 +167,8 @@ class ShortcutMLAMoE(LatentAttention, ExpertCounts, PagedDecoder):
         out_std = std / math.sqrt(4 * c.n_layers)
         ffn = {"down": ((c.d_ff, e), out_std), "gate": ((e, c.d_ff), std),
                "mlp_norm": ((e,), 0.0), "up": ((e, c.d_ff), std)}
-        attn = dict(sorted(attn_shapes(c, std, out_std).items()))
+        attn = dict(sorted({"attn_norm": ((e,), 0.0),
+                            **self.attention.shapes(std, out_std)}.items()))
         return {
             "attn": [dict(attn) for _ in range(2)],
             "ffn": [dict(ffn) for _ in range(2)],
@@ -211,91 +223,63 @@ class ShortcutMLAMoE(LatentAttention, ExpertCounts, PagedDecoder):
     # --------------------------------------------------------- forward
     def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
         """tokens (b, s) -> hidden states after the final norm."""
-        c = self.config
         b, s = tokens.shape
         x = self._embed(params, tokens)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(
-                jnp.broadcast_to(jnp.arange(s), (b, s)),
-                c.qk_rope_head_dim, c.rope_theta)
+        at = self._open(lambda: jnp.broadcast_to(jnp.arange(s), (b, s)))
+        cos, sin = at.tables[self.attention]
         for layer in params["layers"]:
             x, _ = self._layer(
-                layer, x, lambda j, h, layer=layer: self._attn_expanded(
-                    layer["attn"][j], h, cos, sin)[0])
+                layer, x, lambda j, h, layer=layer:
+                self.attention._attn_expanded(layer["attn"][j], h, cos,
+                                              sin)[0])
         return self._final_norm(params, x)
-
-    # ------------------------------------------------ what an engine asks
-    @property
-    def pool_rows(self) -> int:
-        return 2 * self.config.n_layers
-
-    def init_cache(self, num_pages: int, page_size: int,
-                   dtype=None) -> Cache:
-        c = self.config
-        dt = dtype or c.activation_dtype
-        shape = (self.pool_rows, num_pages, page_size, c.row_width)
-        make = jax.jit(lambda: {"kv": jnp.zeros(shape, dt),
-                                **self._zero_counts()})
-        return make()
-
-    @property
-    def expert_load_shape(self) -> Tuple[int, int]:
-        return self.config.n_layers, self.config.held[1]
 
     def prefill(self, params: Params, tokens: jax.Array, true_len,
                 page_table: jax.Array, cache: Cache,
                 page_size: int) -> Tuple[jax.Array, Cache]:
-        """As `MLAMoE.prefill`: the expanded attention, both of a layer's
-        pool rows written as whole pages in place. Padding past `true_len`
-        is given to no expert and adds no identity part."""
-        c = self.config
-        pool = cache["kv"]
+        """As `PagedDecoder.prefill`: the expanded attention, both of a
+        layer's pool rows written as whole pages in place. Padding past
+        `true_len` is given to no expert and adds no identity part."""
+        pools = dict(cache)
         s = tokens.shape[0]
         x = self._embed(params, tokens)[None]                   # (1, s, e)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(jnp.arange(s)[None],
-                                    c.qk_rope_head_dim, c.rope_theta)
+        at = self._open(lambda: jnp.arange(s)[None], true_len=true_len)
         with R.region(R.CACHE):
             valid = (jnp.arange(s) < true_len)[None]
-        page_ids = prefill_page_ids(page_table, true_len, s, pool.shape[1],
-                                    page_size)
-        for i, layer in enumerate(params["layers"]):
+        at.pages[PAGED] = prefill_page_ids(
+            page_table, true_len, s, pools["kv"].shape[1], page_size)
+        for row, layer in zip(self.layers, params["layers"]):
             def attend(j, h):
-                nonlocal pool
-                out, c_kv, k_rope = self._attn_expanded(layer["attn"][j], h,
-                                                        cos, sin)
-                pool = self._write_pages(pool, 2 * i + j, c_kv[0],
-                                         k_rope[0], page_ids, page_size)
+                out, written = self.attention._prompt(
+                    layer["attn"][j], h, pools, row.rows[j], at)
+                pools.update(written)
                 return out
             x, _ = self._layer(layer, x, attend, valid)
-        return self._logits(params, x, true_len), {**cache, "kv": pool}
+        return self._logits(params, x, true_len), pools
 
     def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
                     positions: jax.Array, page_tables: jax.Array,
                     active: jax.Array,
                     page_size: int) -> Tuple[jax.Array, Cache]:
-        """As `MLAMoE.decode_step`, both attentions in the absorbed form.
-        Inactive lanes write nothing, are given to no expert and add no
-        identity part."""
-        c = self.config
-        pool = cache["kv"]
+        """As `PagedDecoder.decode_step`, both attentions in the absorbed
+        form. Inactive lanes write nothing, are given to no expert and add
+        no identity part."""
+        pools = dict(cache)
         x = self._embed(params, tokens)                         # (B, e)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
-                                    c.rope_theta)          # (B, 1, rope/2)
-        wr_page, wr_slot, lengths = decode_lanes(
-            positions, page_tables, active, pool.shape[1], page_size)
-        load, sums = cache["moe_load"], self._step_sums()
-        for i, layer in enumerate(params["layers"]):
+        at = self._open(lanes=positions)
+        page, at.offset, at.lengths = decode_lanes(
+            positions, page_tables, active, pools["kv"].shape[1], page_size)
+        at.pages[PAGED] = page, page_tables
+        load, sums = pools["moe_load"], self._step_sums()
+        for row, layer in zip(self.layers, params["layers"]):
             def attend(j, h):
-                nonlocal pool
-                out, pool = self._attn_absorbed(
-                    layer["attn"][j], h, cos, sin, pool, 2 * i + j, wr_page,
-                    wr_slot, page_tables, lengths)
+                out, written = self.attention._lanes(
+                    layer["attn"][j], h, pools, row.rows[j], at)
+                pools.update(written)
                 return out
             x, counts = self._layer(layer, x, attend, active)
             with R.region(R.MOE_ROUTE):
-                load = load.at[i].add(counts["load"])
+                load = load.at[row.expert_row].add(counts["load"])
             sums = self._count_step(sums, counts)
-        return self._logits(params, x), {"kv": pool,
+        return self._logits(params, x), {**pools,
                                          **self._counted(load, sums)}

@@ -61,14 +61,14 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import regions as R
+from ray_tpu.models.latent import LatentAttention
 from ray_tpu.models.mla_moe import MLAMoE, MLAMoEConfig
 from ray_tpu.models.moe import STEP_COUNTS
-from ray_tpu.models.paged import (Cache, Params, decode_lanes,
-                                  prefill_page_ids)
+from ray_tpu.models.paged import PAGED, Cache, Params, Pool, Walk
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops import sparse_attention as _sparse
 from ray_tpu.ops.norms import layer_norm, rms_norm_reference
-from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin, rotate_leading
+from ray_tpu.ops.rope import apply_rope_cached, rotate_leading
 
 DSA_COUNTS = ("dsa_positions_scored", "dsa_positions_selected",
               "dsa_lanes_past_topk")
@@ -133,59 +133,30 @@ def tiny_sparse_mla_moe(vocab_size: int = 256, experts_held=(4, 4),
         dtype="float32", param_dtype="float32")
 
 
-class SparseMLAMoE(MLAMoE):
-    """Functional model bundle for one SparseMLAMoEConfig: `init`, `apply`
-    / `loss`, and what a serving engine asks a model for
-    (`models.paged.PagedDecoder`)."""
+class SparseLatentAttention(LatentAttention):
+    """`LatentAttention` under the indexer: beside the latent pool the
+    index keys `"idx"` under the same page ids, and what a step's indexer
+    did in `"dsa_step"`."""
 
-    no_mesh = "experts, the latent cache and the index keys are not " \
-              "sharded over chips yet"
-    step_count_names = STEP_COUNTS
+    counts = ("dsa_step", DSA_COUNTS)
 
-    # ------------------------------------------------------------ init
-    def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
-        """`MLAMoE`'s leaves, the routed experts' for those held here, and
-        the indexer's: `wq_idx` from the query latent, `wk_idx` and
-        `w_idx` from the normed stream, the key's LayerNorm (scale stored
-        as w, the layer multiplying by 1 + w, and a bias)."""
+    def __init__(self, config: SparseMLAMoEConfig):
+        super().__init__(config)
+        self.pools += (Pool("idx", PAGED, (config.index_head_dim,)),)
+
+    def index_shapes(self, std: float) -> Dict[str, tuple]:
+        """The indexer's leaves: `wq_idx` from the query latent, `wk_idx`
+        and `w_idx` from the normed stream, the key's LayerNorm (scale
+        stored as w, the layer multiplying by 1 + w, and a bias)."""
         c = self.config
-        e, std = c.d_model, 0.02
-        shapes = dict(super().layer_shapes(i))
-        E = c.held[1]
-        for name in ("moe_gate", "moe_up", "moe_down"):
-            if name in shapes:
-                (_, *rest), leaf_std = shapes[name]
-                shapes[name] = ((E, *rest), leaf_std)
-        shapes.update(
-            wq_idx=((c.q_lora_rank, c.index_n_heads * c.index_head_dim),
-                    std),
-            wk_idx=((e, c.index_head_dim), std),
-            k_idx_norm=((c.index_head_dim,), 0.0),
-            k_idx_bias=((c.index_head_dim,), 0.0),
-            w_idx=((e, c.index_n_heads), std))
-        return shapes
+        return {"wq_idx": ((c.q_lora_rank,
+                            c.index_n_heads * c.index_head_dim), std),
+                "wk_idx": ((c.d_model, c.index_head_dim), std),
+                "k_idx_norm": ((c.index_head_dim,), 0.0),
+                "k_idx_bias": ((c.index_head_dim,), 0.0),
+                "w_idx": ((c.d_model, c.index_n_heads), std)}
 
     # --------------------------------------------------------- pieces
-    def _routing(self, layer: Params):
-        bias, how = super()._routing(layer)
-        return bias, {**how, "held": self.config.held}
-
-    def _ffn(self, layer: Params, x, valid=None):
-        """`DenseOrRoutedFFN._ffn`, an expert layer's tokens `FFN_ROWS` at
-        a time where there are more (a long prefill)."""
-        T, e = x.shape
-        if "router" not in layer or T <= FFN_ROWS or T % FFN_ROWS:
-            return super()._ffn(layer, x, valid)
-        if valid is None:
-            valid = jnp.ones((T,), bool)
-        whole = super()._ffn
-        y, counts = jax.lax.map(
-            lambda xv: whole(layer, *xv),
-            (x.reshape(-1, FFN_ROWS, e), valid.reshape(-1, FFN_ROWS)))
-        with R.region(R.MOE_ROUTE):
-            return y.reshape(T, e), jax.tree.map(
-                lambda n: jnp.sum(n, axis=0), counts)
-
     @R.region(R.ATTN_IN)
     def _q_latent(self, layer: Params, h):
         """h (..., e) -> (c_q (..., q_lora) the normed query latent, q
@@ -322,65 +293,55 @@ class SparseMLAMoE(MLAMoE):
                              w_kvb[..., nope:])
         return self._gated(layer, h, out), pool, idx_pool, counts
 
-    # --------------------------------------------------------- forward
-    def hidden(self, params: Params, tokens: jax.Array) -> jax.Array:
-        """tokens (b, s) -> hidden states after the final norm, one
-        sequence at a time past `index_topk` (each has its own sets)."""
-        c = self.config
-        ad = c.activation_dtype
-        b, s = tokens.shape
-        if s <= c.index_topk:
-            return super().hidden(params, tokens)
-        x = self._embed(params, tokens)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(jnp.arange(s)[None],
-                                    c.qk_rope_head_dim, c.rope_theta)
-        for layer in params["layers"]:
-            h = self._norm(x, layer["attn_norm"])
-            attn = jnp.concatenate([
-                self._attn_selected(layer, h[i:i + 1], cos, sin)[0]
-                for i in range(b)])
-            with R.region(R.ATTN_OUT):
-                x = x + attn @ layer["wo"].astype(ad)
-            x, _ = self._block_ffn(layer, x)
-        return self._final_norm(params, x)
+    # ------------------------------------------------------- forwards
+    def hidden(self, layer: Params, h, at: Walk):
+        """One sequence at a time past `index_topk` (each has its own
+        sets)."""
+        if h.shape[1] <= self.config.index_topk:
+            return super().hidden(layer, h, at)
+        cos, sin = at.tables[self]
+        attn = jnp.concatenate([
+            self._attn_selected(layer, h[i:i + 1], cos[i:i + 1],
+                                sin[i:i + 1])[0]
+            for i in range(h.shape[0])])
+        with R.region(R.ATTN_OUT):
+            return attn @ layer["wo"].astype(self.dtype)
+
+    def _prompt(self, layer: Params, h, pools: Cache, li: int, at: Walk):
+        """Each query's attention over its set, and the index keys written
+        under the latent rows' page ids."""
+        attn, c_kv, k_rope, k_idx = self._attn_selected(layer, h,
+                                                        *at.tables[self])
+        ids, page_size = at.pages[PAGED], pools["kv"].shape[2]
+        return attn, {
+            "kv": self._write_pages(pools["kv"], li, c_kv[0], k_rope[0],
+                                    ids, page_size),
+            "idx": self._write_index_pages(pools["idx"], li, k_idx[0], ids,
+                                           page_size)}
+
+    def _lanes(self, layer: Params, h, pools: Cache, li: int, at: Walk):
+        """The attention over the indexer's choice; what the indexer did
+        added to the step's counts."""
+        page, tables = at.pages[PAGED]
+        out, pool, idx_pool, counts = self._attn_selected_step(
+            layer, h, *at.tables[self], pools["kv"], pools["idx"], li, page,
+            at.offset, tables, at.lengths)
+        with R.region(R.ATTN_INDEX):
+            dsa = {name: pools["dsa_step"][name] + n
+                   for name, n in zip(DSA_COUNTS, counts)}
+        return out, {"kv": pool, "idx": idx_pool, "dsa_step": dsa}
 
     # ------------------------------------------------ what an engine asks
-    def init_cache(self, num_pages: int, page_size: int,
-                   dtype=None) -> Cache:
-        c = self.config
-        dt = dtype or c.activation_dtype
-        rows = (c.n_layers, num_pages, page_size)
-        make = jax.jit(lambda: {
-            "kv": jnp.zeros((*rows, c.row_width), dt),
-            "idx": jnp.zeros((*rows, c.index_head_dim), dt),
-            "dsa_step": {name: jnp.zeros((), jnp.int32)
-                         for name in DSA_COUNTS},
-            **self._zero_counts()})
-        return make()
-
-    def cache_page_bytes(self, page_size: int, tp_shards: int = 1,
-                         dtype=None) -> int:
-        """Bytes one page costs: a latent row and an index key a position
-        and layer (`index_page_bytes` of it the keys')."""
-        return (super().cache_page_bytes(page_size, tp_shards, dtype)
-                + self.index_page_bytes(page_size, dtype))
-
-    def index_page_bytes(self, page_size: int, dtype=None) -> int:
-        c = self.config
-        dt = jnp.dtype(dtype or c.activation_dtype)
-        return c.n_layers * page_size * c.index_head_dim * dt.itemsize
-
     def walk_block_pages(self, page_size: int, max_pages: int) -> int:
-        """Pages a block of the walk over the latent pool holds: `MLAMoE`'s
-        kernel's where the tables cannot pass `index_topk` (asked by one
-        pool row's page, not by both pools'), else `dsa_paged_attend`'s."""
+        """Pages a block of the walk over the latent pool holds:
+        `LatentAttention`'s kernel's where the tables cannot pass
+        `index_topk` (asked by one pool row's page, not by both pools'),
+        else `dsa_paged_attend`'s."""
         c = self.config
         if c.max_seq_len > c.index_topk:
             return min(_sparse.ATTEND_WALK_PAGES, max_pages)
-        page = page_size * c.row_width * jnp.dtype(
-            c.activation_dtype).itemsize
-        return _paged.walk_block_pages(page, page_size, max_pages)
+        return _paged.walk_block_pages(
+            self.pools[0].bytes(self.dtype, page_size), page_size, max_pages)
 
     def page_run(self, page_size: int, max_pages: int) -> int:
         """Pages one copy of the step's two walks brings, which the
@@ -390,21 +351,20 @@ class SparseMLAMoE(MLAMoE):
         here runs no walk kernel."""
         c = self.config
         if c.max_seq_len <= c.index_topk or not self._step_kernels(
-                page_size, max_pages, c.activation_dtype):
+                page_size, max_pages, self.dtype):
             return 1
         return _sparse.walk_run_pages(
-            self.index_page_bytes(page_size) // c.n_layers, max_pages)
+            self.pools[1].bytes(self.dtype, page_size), max_pages)
 
-    def decode_attention(self, page_size: int, dtype=None) -> str:
-        """Which attention a `decode_step` traced here holds: `MLAMoE`'s
-        answer where the context cannot pass `index_topk`, else the walk
-        over every live row that keeps the chosen ones, or "einsum" (the
-        chosen rows gathered)."""
+    def decode_kernel(self, page_size: int, dtype) -> str:
+        """`LatentAttention`'s answer where the context cannot pass
+        `index_topk`, else the walk over every live row that keeps the
+        chosen ones, or "einsum" (the chosen rows gathered)."""
         c = self.config
         if c.max_seq_len <= c.index_topk:
-            return super().decode_attention(page_size, dtype)
+            return super().decode_kernel(page_size, dtype)
         if self._step_kernels(page_size, -(-c.max_seq_len // page_size),
-                              dtype or c.activation_dtype):
+                              dtype):
             return _sparse.KERNEL_PAGED_ATTEND
         return "einsum"
 
@@ -416,78 +376,65 @@ class SparseMLAMoE(MLAMoE):
             c.index_head_dim, c.row_width, c.kv_lora_rank, page_size,
             max_pages, dtype)
 
-    @property
-    def expert_load_shape(self) -> Tuple[int, int]:
-        return self.config.n_moe_layers, self.config.held[1]
 
-    def step_stats(self, cache: Cache) -> Dict[str, jax.Array]:
-        return {**super().step_stats(cache), **cache["dsa_step"]}
+class SparseMLAMoE(MLAMoE):
+    """Functional model bundle for one SparseMLAMoEConfig: `init`, `apply`
+    / `loss`, and what a serving engine asks a model for
+    (`models.paged.PagedDecoder`)."""
 
-    def prefill(self, params: Params, tokens: jax.Array, true_len,
-                page_table: jax.Array, cache: Cache,
-                page_size: int) -> Tuple[jax.Array, Cache]:
-        """As `MLAMoE.prefill`, each query's attention over its set, and
-        the index keys written under the latent rows' page ids."""
-        c = self.config
-        ad = c.activation_dtype
-        pool, idx_pool = cache["kv"], cache["idx"]
-        s = tokens.shape[0]
-        x = self._embed(params, tokens)[None]                   # (1, s, e)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(jnp.arange(s)[None],
-                                    c.qk_rope_head_dim, c.rope_theta)
-        with R.region(R.CACHE):
-            valid = (jnp.arange(s) < true_len)[None]
-        page_ids = prefill_page_ids(page_table, true_len, s, pool.shape[1],
-                                    page_size)
-        for i, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["attn_norm"])
-            attn, c_kv, k_rope, k_idx = self._attn_selected(layer, h, cos,
-                                                            sin)
-            pool = self._write_pages(pool, i, c_kv[0], k_rope[0], page_ids,
-                                     page_size)
-            idx_pool = self._write_index_pages(idx_pool, i, k_idx[0],
-                                               page_ids, page_size)
-            with R.region(R.ATTN_OUT):
-                x = x + attn @ layer["wo"].astype(ad)
-            x, _ = self._block_ffn(layer, x, valid)
-        return self._logits(params, x, true_len), {
-            **cache, "kv": pool, "idx": idx_pool}
+    no_mesh = "experts, the latent cache and the index keys are not " \
+              "sharded over chips yet"
+    step_count_names = STEP_COUNTS
+    attention_type = SparseLatentAttention
 
-    def decode_step(self, params: Params, cache: Cache, tokens: jax.Array,
-                    positions: jax.Array, page_tables: jax.Array,
-                    active: jax.Array,
-                    page_size: int) -> Tuple[jax.Array, Cache]:
-        """As `MLAMoE.decode_step`, the attention over the indexer's
-        choice. Inactive lanes write nothing, score nothing and are given
-        to no expert."""
-        c = self.config
-        ad = c.activation_dtype
-        pool, idx_pool = cache["kv"], cache["idx"]
-        x = self._embed(params, tokens)                         # (B, e)
-        with R.region(R.ATTN_IN):
-            cos, sin = rope_cos_sin(positions, c.qk_rope_head_dim,
-                                    c.rope_theta)          # (B, 1, rope/2)
-        wr_page, wr_slot, lengths = decode_lanes(
-            positions, page_tables, active, pool.shape[1], page_size)
-        load, sums = cache["moe_load"], self._step_sums()
-        dsa = [jnp.int32(0)] * len(DSA_COUNTS)
-        for i, layer in enumerate(params["layers"]):
-            h = self._norm(x, layer["attn_norm"])
-            out, pool, idx_pool, counts = self._attn_selected_step(
-                layer, h, cos, sin, pool, idx_pool, i, wr_page, wr_slot,
-                page_tables, lengths)
-            with R.region(R.ATTN_INDEX):
-                dsa = [a + n for a, n in zip(dsa, counts)]
-            with R.region(R.ATTN_OUT):
-                x = x + out @ layer["wo"].astype(ad)
-            x, counts = self._block_ffn(layer, x, active)
-            if counts is not None:
-                with R.region(R.MOE_ROUTE):
-                    load = load.at[i - c.first_k_dense_replace].add(
-                        counts["load"])
-                sums = self._count_step(sums, counts)
-        return self._logits(params, x), {
-            "kv": pool, "idx": idx_pool,
-            "dsa_step": dict(zip(DSA_COUNTS, dsa)),
-            **self._counted(load, sums)}
+    def _experts_held(self) -> int:
+        return self.config.held[1]
+
+    # ------------------------------------------------------------ init
+    def layer_shapes(self, i: int) -> Dict[str, Tuple[tuple, float]]:
+        """`MLAMoE`'s leaves, the routed experts' for those held here, and
+        the indexer's behind them."""
+        shapes = dict(super().layer_shapes(i))
+        E = self.config.held[1]
+        for name in ("moe_gate", "moe_up", "moe_down"):
+            if name in shapes:
+                (_, *rest), leaf_std = shapes[name]
+                shapes[name] = ((E, *rest), leaf_std)
+        return {**shapes, **self.attention.index_shapes(0.02)}
+
+    # --------------------------------------------------------- pieces
+    def _routing(self, layer: Params):
+        bias, how = super()._routing(layer)
+        return bias, {**how, "held": self.config.held}
+
+    def _ffn(self, layer: Params, x, valid=None):
+        """`DenseOrRoutedFFN._ffn`, an expert layer's tokens `FFN_ROWS` at
+        a time where there are more (a long prefill)."""
+        T, e = x.shape
+        if "router" not in layer or T <= FFN_ROWS or T % FFN_ROWS:
+            return super()._ffn(layer, x, valid)
+        if valid is None:
+            valid = jnp.ones((T,), bool)
+        whole = super()._ffn
+        y, counts = jax.lax.map(
+            lambda xv: whole(layer, *xv),
+            (x.reshape(-1, FFN_ROWS, e), valid.reshape(-1, FFN_ROWS)))
+        with R.region(R.MOE_ROUTE):
+            return y.reshape(T, e), jax.tree.map(
+                lambda n: jnp.sum(n, axis=0), counts)
+
+    # what `benchmarks/tools/dsa_sets.py` reads off the model: the
+    # indexer's pieces, its mixer's
+    def _q_latent(self, layer: Params, h):
+        return self.attention._q_latent(layer, h)
+
+    def _index_key(self, layer: Params, h, cos, sin):
+        return self.attention._index_key(layer, h, cos, sin)
+
+    def _index_query(self, layer: Params, h, c_q, cos, sin):
+        return self.attention._index_query(layer, h, c_q, cos, sin)
+
+    # ------------------------------------------------ what an engine asks
+    def index_page_bytes(self, page_size: int, dtype=None) -> int:
+        """What of `cache_page_bytes` is the index keys'."""
+        return self._pool_bytes((PAGED,), dtype, page_size, names=("idx",))

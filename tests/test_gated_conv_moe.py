@@ -32,7 +32,7 @@ from ray_tpu.models import gated_conv_moe as gcm             # noqa: E402
 from ray_tpu.models import moe                               # noqa: E402
 from ray_tpu.models.gated_conv_moe import (                  # noqa: E402
     tiny_gated_conv_moe)
-from ray_tpu.ops import gated_delta as gd                    # noqa: E402
+from ray_tpu.ops import conv                              # noqa: E402
 from ray_tpu.ops import paged_attention as paged             # noqa: E402
 from ray_tpu.ops.dispatch import compute_platform            # noqa: E402
 from ray_tpu.serve.llm import spans as sp                    # noqa: E402
@@ -65,15 +65,15 @@ def test_causal_conv_is_three_shifted_sums_then_silu_where_asked(activate):
     x = jnp.asarray(r.normal(size=(12, 6)), jnp.float32)
     w = jnp.asarray(r.normal(size=(3, 6)), jnp.float32)
     want = _shifted_sums(x, w)
-    got, tail = gd.causal_conv(x, w, activate=activate)
+    got, tail = conv.causal_conv(x, w, activate=activate)
     np.testing.assert_allclose(got, _silu(want) if activate else want,
                                atol=1e-5)
     np.testing.assert_array_equal(tail, x[-2:])
     # the default is what the four recurrent classes call: activated
-    np.testing.assert_array_equal(gd.causal_conv(x, w)[0],
-                                  gd.causal_conv(x, w, activate=True)[0])
-    assert (np.asarray(gd.causal_conv(x, w)[0])
-            != np.asarray(gd.causal_conv(x, w, activate=False)[0])).any()
+    np.testing.assert_array_equal(conv.causal_conv(x, w)[0],
+                                  conv.causal_conv(x, w, activate=True)[0])
+    assert (np.asarray(conv.causal_conv(x, w)[0])
+            != np.asarray(conv.causal_conv(x, w, activate=False)[0])).any()
 
 
 @pytest.mark.parametrize("true_len", [1, 2, 9])
@@ -85,15 +85,15 @@ def test_linear_conv_step_continues_a_prompt_shorter_than_the_taps(true_len):
     x = jnp.asarray(r.normal(size=(12, 4)), jnp.float32)
     w = jnp.asarray(r.normal(size=(3, 4)), jnp.float32)
     whole = _shifted_sums(x, w)
-    _, tail = gd.causal_conv(x, w, true_len, activate=False)
+    _, tail = conv.causal_conv(x, w, true_len, activate=False)
     before = np.concatenate([np.zeros((2, 4)), np.asarray(x)])[
         true_len:true_len + 2]
     np.testing.assert_array_equal(tail, before)
-    y, new = gd.conv_step(x[true_len][None], tail[None], w, activate=False)
+    y, new = conv.conv_step(x[true_len][None], tail[None], w, activate=False)
     np.testing.assert_allclose(y[0], whole[true_len], atol=1e-5)
     np.testing.assert_array_equal(new[0], x[true_len - 1:true_len + 1]
                                   if true_len else new[0])
-    ya, _ = gd.conv_step(x[true_len][None], tail[None], w)
+    ya, _ = conv.conv_step(x[true_len][None], tail[None], w)
     np.testing.assert_allclose(ya[0], _silu(whole[true_len]), atol=1e-5)
 
 
@@ -101,14 +101,14 @@ def test_linear_conv_step_continues_a_prompt_shorter_than_the_taps(true_len):
 def test_conv_tail_step_against_the_pool_with_and_without_silu(activate):
     r = np.random.default_rng(2)
     channels, lanes = 256, 3
-    shape = gd.tail_shape(3, channels)
+    shape = conv.tail_shape(3, channels)
     assert shape == (2, 16, 128)        # one bf16 tile a row
     pool = jnp.asarray(r.normal(size=(2, 5, *shape)), jnp.float32)
     pool = pool.at[..., channels // 128:, :].set(0.0)   # the rows' padding
     x = jnp.asarray(r.normal(size=(lanes, channels)), jnp.float32)
     w = jnp.asarray(r.normal(size=(3, channels)), jnp.float32)
     slots = jnp.asarray([2, -1, 0], jnp.int32)
-    y, new = gd.conv_tail_step(x, w, pool, 1, slots, activate=activate)
+    y, new = conv.conv_tail_step(x, w, pool, 1, slots, activate=activate)
     for lane, slot in ((0, 2), (2, 0)):
         rows = np.asarray(pool[1, slot]).reshape(2, -1)[:, :channels]
         want = _shifted_sums(np.concatenate([rows, x[lane][None]]), w)[-1]
@@ -250,15 +250,13 @@ def test_the_paged_kernel_under_the_interpreter_gives_the_same_logits(
 
 
 # ------------------------------------------- faults that are this model's
-class _NoBGate(GatedConvMoE):
-    def _conv_in(self, layer, h):
-        _, C, u = jnp.split(h @ layer["w_in"], 3, axis=-1)
-        return u, C
+def _no_b_gate(self, layer, h):
+    _, C, u = jnp.split(h @ layer["w_in"], 3, axis=-1)
+    return u, C
 
 
-class _NoCGate(GatedConvMoE):
-    def _conv_out(self, layer, C, conv):
-        return conv @ layer["w_out"]
+def _no_c_gate(self, layer, C, conv):
+    return conv @ layer["w_out"]
 
 
 def _bias_in_the_weights(x, router_w, bias, *, top_k, norm_topk_prob=True,
@@ -283,9 +281,9 @@ def test_a_fault_of_this_models_own_misses_the_reference(tiny_ref, fault,
                               48)
     model = GatedConvMoE(pc)
     if fault == "the B gate gone":
-        model = _NoBGate(pc)
+        monkeypatch.setattr(gcm.GatedConv, "_conv_in", _no_b_gate)
     elif fault == "the C gate gone":
-        model = _NoCGate(pc)
+        monkeypatch.setattr(gcm.GatedConv, "_conv_out", _no_c_gate)
     elif fault == "the head norms gone":
         monkeypatch.setattr(gcm, "rms_norm_reference", lambda x, w, eps: x)
     elif fault == "the bias left out of the choice":
@@ -407,7 +405,7 @@ def test_a_slot_holds_two_rows_a_convolution_at_the_published_sizes():
     mod = modelcfg.load_model(cfg)
     served = build_model(mod.program_config(cfg, 4096))
     # 2 rows of 2,048 bf16 a convolution, one tile a row: 8 KB a layer
-    assert gd.tail_shape(3, 2048) == (2, 16, 128)
+    assert conv.tail_shape(3, 2048) == (2, 16, 128)
     assert served.state_bytes() == 12 * 2 * 16 * 128 * 2 == 98304
     assert served.fixed_step_counts(2000, 16) == served.fixed_step_counts(
         9, 16) == {"state_slots": 1, "state_bytes": 2 * 98304}

@@ -33,6 +33,7 @@ from ray_tpu.models import (GatedConvMoEConfig, GQAWindowMoE,  # noqa: E402
                             GQAWindowMoEConfig, HybridDeltaConfig, HybridKDAMoEConfig,
                             HybridSSMMoEConfig, MLAMoE,
                             ParallelHybridConfig, ShortcutMLAMoEConfig,
+                            SparseMLAMoEConfig,
                             Transformer, build_model, model_config)
 from ray_tpu.models.config import TransformerConfig          # noqa: E402
 from ray_tpu.models.gqa_window_moe import (RopeParams,       # noqa: E402
@@ -568,27 +569,27 @@ PINNED = {
     # two steps are pinned anew to PR 48's text; the three prefills and
     # `MLAMoE`'s step keep their hashes, so nothing else moved.
     ("Transformer", "decode_step"): "e7da2271789da96f",
-    ("MLAMoE", "prefill"): "ad1cf41a588df5a6",
-    ("MLAMoE", "decode_step"): "3ff8c18bb669e7c8",
+    ("MLAMoE", "prefill"): "50899f6b3446b401",
+    ("MLAMoE", "decode_step"): "f5faaa6b1a81bca9",
     # PR 37 renamed the allocator's ring class and gave the page walk a
     # group of one query head: the third class is pinned to PR 36's text
-    ("GQAWindowMoE", "prefill"): "2ee05703a435ae1e",
-    ("GQAWindowMoE", "decode_step"): "89c5c77c979e1f86",
+    ("GQAWindowMoE", "prefill"): "1c076adecba546a0",
+    ("GQAWindowMoE", "decode_step"): "d7ceb6a28ee7cb0b",
     # the three classes after it, pinned in PR 49 to PR 48's text before
     # what the classes share was lifted out of them (`models/paged.py`,
     # `models/gqa.py`), so that the lift is held by hashes it did not write.
     # PR 51 gave the pool of the convolution's tails whole tiles a slot
-    # (`ops.gated_delta.tail_shape`, `conv_tail_step`): the decode steps of
+    # (`ops.conv.tail_shape`, `conv_tail_step`): the decode steps of
     # the three classes that keep a tail (gather, `conv_step` and scatter
     # over the new shape) and their prefills (`_write_slot` folds the tail
     # into it) are pinned anew to PR 51's text; `ShortcutMLAMoE`'s two and
     # the six before them keep their hashes
     ("HybridDelta", "prefill"): "328443fc4f95f8b1",
-    ("HybridDelta", "decode_step"): "30470ff95209832d",
+    ("HybridDelta", "decode_step"): "61459a317f791d1e",
     ("ShortcutMLAMoE", "prefill"): "d4d9ab62a550993a",
     ("ShortcutMLAMoE", "decode_step"): "b117688674ce4a5d",
     ("HybridSSMMoE", "prefill"): "2423f3d6a6654486",
-    ("HybridSSMMoE", "decode_step"): "30d8c26f72fc7461",
+    ("HybridSSMMoE", "decode_step"): "f046ae2debee7f59",
     # the seventh class, pinned in PR 50 to the text PR 50 gave it: what it
     # shares (`models/latent.py` without a LoRA and with the heads' gate,
     # `route_topk` under a group limit, `ops/kda.py`) is held from here on;
@@ -605,8 +606,8 @@ PINNED = {
     # their hashes, as do the twelve others. The eighth class, pinned to the
     # text PR 54 gave it: five query heads a kv head, a scaled key rotated,
     # both mixers' kernels in the one layer
-    ("ParallelHybrid", "prefill"): "212c76bf28ba5d17",
-    ("ParallelHybrid", "decode_step"): "e6130c468a958a73",
+    ("ParallelHybrid", "prefill"): "0974a39ba83215f5",
+    ("ParallelHybrid", "decode_step"): "d62d5e9fcb638d6d",
     # PR 58 let the page walk take heads that are a share of a 128-lane
     # (`ops.paged_attention.LANE`: the wrapper packs them, `_paged_decode_call`
     # is given its `sm_scale`), the convolution run without its SiLU
@@ -618,6 +619,26 @@ PINNED = {
     # convolution, a router under a bias and no shared expert, a tied head
     ("GatedConvMoE", "prefill"): "f6c72aa2a2917f9b",
     ("GatedConvMoE", "decode_step"): "5aa9c8af7f17b51c",
+    # the tenth class, pinned in PR 63 to PR 62's text (prefill
+    # d9caf436e4f2bd1d, decode_step 5ee65bfddb48e168) before the mixers were
+    # lifted out of the classes and `models/paged.py` walked a table of them.
+    # PR 63's one walk holds eleven of the twenty texts byte for byte and
+    # writes nine in another order, each shown by `tools/lowered_text.py
+    # --tpu` (`graph_hashes`) to be the parent's graph of equations at these
+    # shapes and at its cell's: `valid` after the prompt's page ids and not
+    # before (the prefills of `MLAMoE`, `SparseMLAMoE`, `GQAWindowMoE`); a
+    # step's three expert counts through `ExpertCounts._count_step`, the
+    # maximum first (`MLAMoE`, `GQAWindowMoE`); a lane's entry, row and
+    # length through `paged.lane_entries` (`HybridDelta`, `GQAWindowMoE`),
+    # whose k and v are each flattened as it is written; an expert layer's
+    # residual addition before its counts and not after (`HybridSSMMoE`'s
+    # step); the attention's `[0]` before its pages are written and not
+    # after the scan (`ParallelHybrid`'s prefill), both of whose programs
+    # also lose the six equations a layer that built the column scales a
+    # second time for nothing to read (`graph` the parent's, `equations`
+    # six a layer fewer)
+    ("SparseMLAMoE", "prefill"): "ea6ece939d3c610e",
+    ("SparseMLAMoE", "decode_step"): "5ee65bfddb48e168",
 }
 
 # a class's configuration for its pin: small, and of head sizes that tile
@@ -672,6 +693,17 @@ PINNED_CONFIGS = {
         n_heads=8, n_kv_heads=2, head_dim=64, d_ff=256,
         moe_intermediate_size=128, num_experts=8, num_experts_per_tok=2,
         num_dense_layers=1, max_seq_len=128),
+    # (index keys and a latent of 128, tables of 8 pages of 16 that pass an
+    # `index_topk` of 64: the step's two walk kernels tile and are in the
+    # text, the prompt of 32 is `MLAMoE`'s prefill with the index keys
+    # written beside it)
+    "SparseMLAMoE": lambda: SparseMLAMoEConfig(
+        vocab_size=256, d_model=128, n_layers=2, n_heads=2, q_lora_rank=64,
+        kv_lora_rank=128, qk_nope_head_dim=64, qk_rope_head_dim=64,
+        v_head_dim=64, d_ff=256, moe_intermediate_size=128,
+        n_routed_experts=8, experts_held=(2, 4), num_experts_per_tok=2,
+        first_k_dense_replace=1, index_n_heads=2, index_head_dim=128,
+        index_topk=64, max_seq_len=128),
 }
 
 
@@ -682,6 +714,41 @@ def test_older_models_programs_lower_to_the_parents_text(name, program):
     assert "pallas_call" in text            # the kernels are in the text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[
         (name, program)]
+
+
+# What lets a pin be written anew: `tools/lowered_text.py`'s two hashes that
+# no order of the equations moves, so that "the same equations in another
+# order" is read off two numbers and not argued.
+@pytest.mark.parametrize("change,graph,equations", [
+    ("another order", True, True), ("another constant", False, False),
+    ("a result nothing reads", True, False)])
+def test_graph_hashes_see_through_an_order_and_nothing_else(change, graph,
+                                                            equations):
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
+                                    "tools"))
+    from lowered_text import graph_hashes
+
+    def parent(x, y):
+        a = jnp.sin(x) @ y
+        b = jnp.where(y > 0, y, 2.0).sum(axis=0)
+        return a + b
+
+    def changed(x, y):
+        b = jnp.where(y > 0, y, 3.0 if change == "another constant"
+                      else 2.0).sum(axis=0)
+        if change == "a result nothing reads":
+            jnp.cos(x)
+        a = jnp.sin(x) @ y
+        return a + b
+
+    x, y = jnp.ones((4, 8)), jnp.ones((8, 8))
+    before, after = (jax.make_jaxpr(fn)(x, y) for fn in (parent, changed))
+    assert str(before) != str(after)
+    old, new = graph_hashes(before), graph_hashes(after)
+    assert (old["graph"] == new["graph"]) is graph
+    assert (old["equations"] == new["equations"]) is equations
+    assert new["n_equations"] - old["n_equations"] == (
+        change == "a result nothing reads")
 
 
 # The dense prefill's flash blocks (`models/decode.py`) are the other GQA

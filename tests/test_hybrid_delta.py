@@ -28,6 +28,7 @@ from ray_tpu.models import (HybridDelta, HybridDeltaConfig,  # noqa: E402
                             build_model, model_config)
 from ray_tpu.models.hybrid_delta import tiny_hybrid_delta    # noqa: E402
 from ray_tpu.ops import gated_delta as gd                    # noqa: E402
+from ray_tpu.ops import conv                              # noqa: E402
 from ray_tpu.ops import paged_attention as paged             # noqa: E402
 from ray_tpu.ops.dispatch import compute_platform            # noqa: E402
 from ray_tpu.serve.llm import spans as sp                    # noqa: E402
@@ -121,15 +122,15 @@ def test_the_convolution_continues_from_its_tail():
     r = np.random.default_rng(4)
     x = jnp.asarray(r.normal(size=(12, 6)), jnp.float32)
     w = jnp.asarray(r.normal(size=(4, 6)), jnp.float32)
-    whole, _ = gd.causal_conv(x, w)
-    _, tail = gd.causal_conv(x, w, 9)       # a bucket of 12, 9 real
+    whole, _ = conv.causal_conv(x, w)
+    _, tail = conv.causal_conv(x, w, 9)       # a bucket of 12, 9 real
     np.testing.assert_array_equal(tail, x[6:9])
-    y, new_tail = gd.conv_step(x[9][None], tail[None], w)
+    y, new_tail = conv.conv_step(x[9][None], tail[None], w)
     np.testing.assert_allclose(y[0], whole[9], atol=1e-6)
     np.testing.assert_array_equal(new_tail[0], x[7:10])
     # a prompt shorter than the tail: zeros before the sequence
     np.testing.assert_array_equal(
-        gd.causal_conv(x, w, 2)[1],
+        conv.causal_conv(x, w, 2)[1],
         jnp.concatenate([jnp.zeros((1, 6)), x[:2]]))
 
 
@@ -146,7 +147,7 @@ def _flat_tail_step(x, w, flat, layer, slots, bias=None):
     B, width = x.shape[0], w.shape[0]
     tail = flat[layer, jnp.clip(slots, 0, flat.shape[1] - 1)].reshape(
         B, width - 1, -1)
-    y, tail = gd.conv_step(x, tail, w, bias)
+    y, tail = conv.conv_step(x, tail, w, bias)
     where = jnp.where(slots >= 0, slots, flat.shape[1])
     return y, flat.at[layer, where].set(
         tail.reshape(B, -1).astype(flat.dtype), mode="drop")
@@ -168,10 +169,10 @@ def test_conv_tail_step_on_whole_tiles_is_the_flat_pools_step_bit_for_bit(
     r = np.random.default_rng(channels + len(slots))
     width, B = 4, len(slots)
     ad = jnp.dtype(pool_dtype)
-    fold = gd.tail_shape(width, channels)
+    fold = conv.tail_shape(width, channels)
     rows = jnp.asarray(r.normal(size=(2, 6, width - 1, channels)), ad)
     rows = rows.at[1, 1].set(0)         # a fresh slot: zeros before it
-    pool = gd.fold_tail(rows, fold)
+    pool = conv.fold_tail(rows, fold)
     assert pool.shape == (2, 6) + fold
     x = jnp.asarray(r.normal(size=(B, channels)), ad)
     w = jnp.asarray(r.normal(size=(width, channels)), ad)
@@ -180,7 +181,7 @@ def test_conv_tail_step_on_whole_tiles_is_the_flat_pools_step_bit_for_bit(
 
     want_y, want_flat = jax.jit(_flat_tail_step, static_argnums=3)(
         x, w, rows.reshape(2, 6, -1), 1, slots, b)
-    y, new = jax.jit(gd.conv_tail_step, static_argnums=3)(
+    y, new = jax.jit(conv.conv_tail_step, static_argnums=3)(
         x, w, pool, 1, slots, b)
     assert y.dtype == x.dtype and new.dtype == pool.dtype
     # (a lane without a slot convolves nobody's rows here and slot 0's
@@ -188,7 +189,7 @@ def test_conv_tail_step_on_whole_tiles_is_the_flat_pools_step_bit_for_bit(
     on = np.asarray(slots) >= 0
     assert (_bits(y)[on] == _bits(want_y)[on]).all()
     # the whole pool: the flat one's numbers, zeros past the channels
-    assert (_bits(new) == _bits(gd.fold_tail(
+    assert (_bits(new) == _bits(conv.fold_tail(
         want_flat.reshape(rows.shape), fold))).all()
     # the other layer, the slots of no lane and nobody's: as they were;
     # an active lane's rows: its last two inputs and the new one
@@ -219,15 +220,15 @@ def test_conv_tail_step_takes_a_pool_of_another_dtype_than_the_inputs():
     for pool_dt, x_dt in ((jnp.float32, jnp.bfloat16),
                           (jnp.bfloat16, jnp.float32)):
         rows = jnp.asarray(r.normal(size=(1, 4, 3, 256)), pool_dt)
-        fold = gd.tail_shape(4, 256)
+        fold = conv.tail_shape(4, 256)
         x = jnp.asarray(r.normal(size=(3, 256)), x_dt)
         w = jnp.asarray(r.normal(size=(4, 256)), x_dt)
         slots = jnp.asarray([2, -1, 0], jnp.int32)
         want_y, want_flat = jax.jit(_flat_tail_step, static_argnums=3)(
             x, w, rows.reshape(1, 4, -1), 0, slots)
-        y, new = jax.jit(gd.conv_tail_step, static_argnums=3)(
-            x, w, gd.fold_tail(rows, fold), 0, slots)
-        want = gd.fold_tail(want_flat.reshape(rows.shape), fold)
+        y, new = jax.jit(conv.conv_tail_step, static_argnums=3)(
+            x, w, conv.fold_tail(rows, fold), 0, slots)
+        want = conv.fold_tail(want_flat.reshape(rows.shape), fold)
         for a, b in ((y[::2], want_y[::2]), (new, want)):   # the active lanes
             assert a.dtype == b.dtype and (_bits(a) == _bits(b)).all()
 
@@ -241,9 +242,9 @@ def test_kernels_tile_the_published_shapes_and_say_where_they_run():
     assert not gd.uses_step_kernel(30, 96, 192)         # this is a CPU
     # an input of the convolution: 90 rows of whole lanes in 96, whole
     # tiles (Ling's 96 and Nemotron's 80 are)
-    assert gd.tail_shape(4, 11520) == (3, 96, 128)
-    assert gd.tail_shape(4, 10240) == (3, 80, 128)
-    assert gd.tail_shape(4, 96) == (3, 1, 96)
+    assert conv.tail_shape(4, 11520) == (3, 96, 128)
+    assert conv.tail_shape(4, 10240) == (3, 80, 128)
+    assert conv.tail_shape(4, 96) == (3, 1, 96)
     with compute_platform("tpu"):
         assert gd.uses_step_kernel(30, 96, 192)
         assert gd.uses_chunk_kernel(96, 192, 64, jnp.bfloat16)

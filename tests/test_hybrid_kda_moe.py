@@ -224,13 +224,12 @@ def test_one_group_routes_bit_for_bit_as_it_did(how):
 # ------------------------------------------- the latent attention's forms
 def test_a_lora_query_without_a_gate_has_the_leaves_it_had():
     c = tiny_mla_moe()
-    assert list(latent.attn_shapes(c, 0.02, 0.01)) == [
-        "attn_norm", "wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b",
-        "wo"]
+    assert list(latent.LatentAttention(c).shapes(0.02, 0.01)) == [
+        "wq_a", "q_norm", "wq_b", "wkv_a", "kv_norm", "wkv_b", "wo"]
     assert not c.head_gate
     mine = tiny_hybrid_kda_moe()
-    assert list(latent.attn_shapes(mine, 0.02, 0.01)) == [
-        "attn_norm", "wq", "wkv_a", "kv_norm", "wkv_b", "wo", "w_head_gate"]
+    assert list(latent.LatentAttention(mine).shapes(0.02, 0.01)) == [
+        "wq", "wkv_a", "kv_norm", "wkv_b", "wo", "w_head_gate"]
 
 
 def test_the_absorbed_form_is_the_expanded_form_with_the_gate():
@@ -248,20 +247,20 @@ def test_the_absorbed_form_is_the_expanded_form_with_the_gate():
     h = jax.random.normal(jax.random.PRNGKey(1), (1, s, cfg.d_model))
     cos, sin = rope_cos_sin(jnp.arange(s)[None], cfg.qk_rope_head_dim,
                             cfg.rope_theta)
-    out, c_kv, k_rope = model._attn_expanded(layer, h, cos, sin)
+    out, c_kv, k_rope = model.attention._attn_expanded(layer, h, cos, sin)
     pool = jnp.zeros((1, 4, PAGE, cfg.row_width))
     ids = jnp.asarray([2, 0, 3])
-    pool = model._write_pages(pool, 0, c_kv[0, :s - 1], k_rope[0, :s - 1],
-                              ids, PAGE)
+    pool = model.attention._write_pages(
+        pool, 0, c_kv[0, :s - 1], k_rope[0, :s - 1], ids, PAGE)
     tables = jnp.asarray([[2, 0, 3, -1]])
     cos1, sin1 = rope_cos_sin(jnp.asarray([s - 1]), cfg.qk_rope_head_dim,
                               cfg.rope_theta)
-    got, _ = model._attn_absorbed(
+    got, _ = model.attention._attn_absorbed(
         layer, h[0, -1:], cos1, sin1, pool, 0, jnp.asarray([3]),
         jnp.asarray([(s - 1) % PAGE]), tables, jnp.asarray([s]))
     np.testing.assert_allclose(got[0], out[0, -1], atol=2e-5)
     plain = HybridKDAMoE(dataclasses.replace(cfg, head_gate=False))
-    bare, _, _ = plain._attn_expanded(layer, h, cos, sin)
+    bare, _, _ = plain.attention._attn_expanded(layer, h, cos, sin)
     assert rel_rms(bare, out) > 0.1
 
 
@@ -274,7 +273,7 @@ def test_latent_attention_with_a_lora_gives_what_it_gave():
     h = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.d_model))
     cos, sin = rope_cos_sin(jnp.broadcast_to(jnp.arange(16), (2, 16)),
                             cfg.qk_rope_head_dim, cfg.rope_theta)
-    got, _, _ = model._attn_expanded(layer, h, cos, sin)
+    got, _, _ = model.attention._attn_expanded(layer, h, cos, sin)
 
     def q_before(layer, h):
         c_q = latent.rms_norm_reference(h @ layer["wq_a"], layer["q_norm"],
@@ -282,7 +281,8 @@ def test_latent_attention_with_a_lora_gives_what_it_gave():
         return (c_q @ layer["wq_b"]).reshape(2, 16, cfg.n_heads,
                                              cfg.qk_head_dim)
 
-    np.testing.assert_array_equal(model._q(layer, h), q_before(layer, h))
+    np.testing.assert_array_equal(model.attention._q(layer, h),
+                                  q_before(layer, h))
     assert got.shape == (2, 16, cfg.n_heads * cfg.v_head_dim)
 
 

@@ -308,7 +308,7 @@ def test_the_shares_of_an_expert_layer_add_up_to_the_uncut_layer(tiny_ref):
         ref_parts.append(mod.held_part(share, u, mine, _ident))
         program = HybridSSMMoE(dataclasses.replace(
             pc, experts_held=(first, 4)))
-        out, counts = program._experts(mine, u)
+        out, counts = program._ffn(mine, u)
         got_parts.append(out - shared)
         assert int(counts["pairs"]) + int(counts["away_pairs"]) == (
             24 * sz.top_k)

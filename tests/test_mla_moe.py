@@ -169,14 +169,15 @@ def test_absorbed_attention_equals_expanded(tiny_ref):
     from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin
     cos, sin = rope_cos_sin(jnp.arange(n)[None], c.qk_rope_head_dim,
                             c.rope_theta)
-    expanded, c_kv, k_rope = model._attn_expanded(layer, h, cos, sin)
+    attention = model.attention
+    expanded, c_kv, k_rope = attention._attn_expanded(layer, h, cos, sin)
     nope, latent = c.qk_nope_head_dim, c.kv_lora_rank
-    q = model._q(layer, h)[0, -1]                           # (H, qk)
-    w = model._wkv_b(layer)
+    q = attention._q(layer, h)[0, -1]                       # (H, qk)
+    w = attention._wkv_b(layer)
     q_lat = jnp.einsum("hn,chn->hc", q[:, :nope], w[..., :nope])
     q_rope = apply_rope_cached(q[None, None, :, nope:], cos[:, -1:],
                                sin[:, -1:])[0, 0]
-    rows = model._rows(c_kv[0], k_rope[0], jnp.float32)[None, None]
+    rows = attention._rows(c_kv[0], k_rope[0], jnp.float32)[None, None]
     q_row = jnp.pad(jnp.concatenate([q_lat, q_rope], -1),
                     ((0, 0), (0, c.row_width - latent
                               - c.qk_rope_head_dim)))

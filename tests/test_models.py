@@ -2,6 +2,7 @@
 import collections
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -517,7 +518,7 @@ def _tiny_models():
                                   "hybrid_ssm_moe", "hybrid_kda_moe",
                                   "parallel_hybrid", "gated_conv_moe",
                                   "sparse_mla_moe"])
-def test_every_class_answers_the_engines_twelve_asks(name):
+def test_every_class_answers_the_engines_thirteen_asks(name):
     """What `EngineCore` calls on a model, on every class of the table,
     with the types it uses them as (`models.paged.PagedDecoder`)."""
     from ray_tpu.models import MODELS, build_model, model_config
@@ -571,6 +572,16 @@ def test_every_class_answers_the_engines_twelve_asks(name):
         {"fixed_pages": fixed} if fixed else {}))
     assert isinstance(model.cache_stats(real), dict)            # 11
     assert model.pool_rows is None or model.pool_rows >= 1      # 12
+    assert mp % model.page_run(page, mp) == 0                   # 13
+    # what the byte asks say the cache costs is what `init_cache` makes
+    # (the counts apart): `num_pages` pages, and of the fixed class a
+    # ring's pages, or the sequences' slots and one more, nobody's
+    pools = [a for key, a in cache.items()
+             if not isinstance(a, dict) and key != "moe_load"]
+    held = B * fixed + (model.state_bytes() > 0 if fixed else 0)
+    assert sum(math.prod(a.shape) * a.dtype.itemsize for a in pools) == (
+        B * mp * model.cache_page_bytes(page)
+        + (held * model.cache_page_bytes(page, fixed=True) if fixed else 0))
 
 
 # -------------------------------------- the cache's addresses, by hand
@@ -641,8 +652,7 @@ def test_state_slot_arithmetic_against_its_cases(first, active, slot,
     a lane without a slot updates none (-1): its convolution reads
     nobody's rows, which exist, and writes none."""
     from ray_tpu.models.paged import decode_state_slots, prefill_state_slot
-    from ray_tpu.ops.gated_delta import (conv_step, conv_tail_step,
-                                         tail_shape)
+    from ray_tpu.ops.conv import conv_step, conv_tail_step, tail_shape
     slots = 4
     tables = jnp.asarray([[first, 7, 8]], jnp.int32)
     got = decode_state_slots(tables, jnp.asarray([active]), slots)

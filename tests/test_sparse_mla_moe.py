@@ -26,8 +26,8 @@ from benchmarks.harness.weights import make_weights          # noqa: E402
 from ray_tpu.models import (MODELS, SparseMLAMoE,            # noqa: E402
                             SparseMLAMoEConfig, build_model, model_config)
 from ray_tpu.models.moe import dropless_moe_ffn              # noqa: E402
-from ray_tpu.models.sparse_mla_moe import (DSA_COUNTS,       # noqa: E402
-                                           tiny_sparse_mla_moe)
+from ray_tpu.models.sparse_mla_moe import (                  # noqa: E402
+    DSA_COUNTS, SparseLatentAttention, tiny_sparse_mla_moe)
 from ray_tpu.ops import paged_attention as paged             # noqa: E402
 from ray_tpu.ops import sparse_attention as sparse           # noqa: E402
 from ray_tpu.ops.rope import rope_cos_sin                    # noqa: E402
@@ -568,7 +568,8 @@ def _serve_in_runs(monkeypatch, cfg, params, run):
     eviction and its re-admission, a cancel. Returns (tokens by request,
     the engine), every lane's table checked after every step."""
     if run is not None:
-        monkeypatch.setattr(SparseMLAMoE, "page_run", lambda *a: run)
+        monkeypatch.setattr(SparseLatentAttention, "page_run",
+                            lambda *a: run)
     core = EngineCore(cfg, params, num_pages=17, page_size=8, max_batch=3)
     run = core.alloc.run
     rng = np.random.default_rng(0)

@@ -12,7 +12,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import gated_delta as gd
+from ray_tpu.ops import conv
 from ray_tpu.ops import ssd
 from ray_tpu.ops.dispatch import compute_platform
 
@@ -141,19 +141,19 @@ def test_the_convolution_adds_its_bias_and_continues_from_its_tail():
     x = jnp.asarray(r.normal(size=(12, 6)), jnp.float32)
     w = jnp.asarray(r.normal(size=(4, 6)), jnp.float32)
     b = jnp.asarray(r.normal(size=(6,)), jnp.float32)
-    whole, _ = gd.causal_conv(x, w, bias=b)
+    whole, _ = conv.causal_conv(x, w, bias=b)
     padded = jnp.pad(x, ((3, 0), (0, 0)))
     np.testing.assert_allclose(
         whole, jax.nn.silu(b + sum(w[i] * padded[i:i + 12]
                                    for i in range(4))), atol=1e-6)
-    _, tail = gd.causal_conv(x, w, 9, b)        # a bucket of 12, 9 real
-    y, new_tail = gd.conv_step(x[9][None], tail[None], w, b)
+    _, tail = conv.causal_conv(x, w, 9, b)        # a bucket of 12, 9 real
+    y, new_tail = conv.conv_step(x[9][None], tail[None], w, b)
     np.testing.assert_allclose(y[0], whole[9], atol=1e-6)
     np.testing.assert_array_equal(new_tail[0], x[7:10])
     # without a bias both are what they were
-    np.testing.assert_array_equal(gd.causal_conv(x, w)[0],
-                                  gd.causal_conv(x, w, bias=None)[0])
-    assert (np.asarray(gd.causal_conv(x, w)[0]) != np.asarray(whole)).any()
+    np.testing.assert_array_equal(conv.causal_conv(x, w)[0],
+                                  conv.causal_conv(x, w, bias=None)[0])
+    assert (np.asarray(conv.causal_conv(x, w)[0]) != np.asarray(whole)).any()
 
 
 def test_kernels_tile_the_published_shapes_and_say_where_they_run():

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops import gated_delta as gd
+from ray_tpu.ops import conv
 
 H, DK, DV = 30, 96, 192
 HBM = 819e9
@@ -67,7 +68,7 @@ def flat_tail_step(x, w, pool, layer, slots, bias=None):
     PR 51: a slot's rows one run of `(width - 1) x channels` numbers."""
     B, n = x.shape[0], pool.shape[1] - 1
     tail = pool[layer, jnp.clip(slots, 0, n)].reshape(B, WIDTH - 1, -1)
-    y, tail = gd.conv_step(x, tail, w, bias)
+    y, tail = conv.conv_step(x, tail, w, bias)
     return y, pool.at[layer, jnp.where(slots >= 0, slots, n + 1)].set(
         tail.reshape(B, -1), mode="drop")
 
@@ -87,13 +88,13 @@ def tails(B: int, rounds: int = 8, n: int = 20):
         bias = (jnp.asarray(r.normal(size=(channels,)), jnp.bfloat16)
                 if has_bias else None)
         nbytes = (B * 2 * WIDTH * channels + WIDTH * channels) * 2
-        fold = gd.tail_shape(WIDTH, channels)
+        fold = conv.tail_shape(WIDTH, channels)
         for slots in (B, 8 * B):
             lanes = jnp.asarray(r.permutation(slots)[:B], jnp.int32)
             line = (f"{name}, {channels} channels, {layers} layers, {B} "
                     f"lanes of {slots} slots:")
             for label, fn, slot in (
-                    ("whole tiles", gd.conv_tail_step, fold),
+                    ("whole tiles", conv.conv_tail_step, fold),
                     ("flat", flat_tail_step, ((WIDTH - 1) * channels,))):
                 def step(x, pool, fn=fn):
                     for li in range(rounds * layers):

@@ -18,7 +18,15 @@ the traced program, the Pallas calls with their grids and bodies in it and
 the source lines dropped (a call carries its call path's files and lines,
 which differ between two checkouts whatever they hold); each program's
 line also gives the grids of its calls over four axes (the flash
-forward's: batch, heads, query blocks, key blocks).
+forward's: batch, heads, query blocks, key blocks), and two hashes that no
+order of the equations moves (`graph_hashes`): `graph`, of the program's
+outputs as a graph of equations over its inputs (an equation is its
+primitive, its parameters, what it reads and what it gives; one whose
+result nothing reads is no part of it), and `equations`, of all its
+equations as a set, with their count. Where `sha256` differs between two
+checkouts and `graph` and `equations` agree, the change wrote the same
+equations in another order; where `equations` differs too, the count says
+how many equations that nothing reads came or went.
 """
 import functools
 import hashlib
@@ -26,6 +34,60 @@ import json
 import os
 import re
 import sys
+
+
+def _clean(text: str) -> str:
+    """A traced text without what two checkouts differ by whatever they
+    hold: a call path's files and lines, an object's address."""
+    return re.sub(r" at (0x[0-9a-f]+|[^\s\]]+:\d+)", "", text)
+
+
+def graph_hashes(closed) -> dict:
+    """`graph`, `equations` and `n_equations` of a `ClosedJaxpr` (the
+    module's docstring). A variable's hash is its equation's (primitive,
+    parameters with a nested program by its own `graph`, the hashes of
+    what it reads) and its place among the equation's results; an input's
+    is its place and type."""
+    from jax.extend import core as jcore
+
+    def digest(*parts) -> str:
+        return hashlib.sha256("\x1f".join(map(str, parts)).encode()
+                              ).hexdigest()[:24]
+
+    def param(value):
+        if isinstance(value, jcore.ClosedJaxpr):
+            return walk(value.jaxpr)[0]
+        if isinstance(value, jcore.Jaxpr):
+            return walk(value)[0]
+        if isinstance(value, (tuple, list)):
+            return digest(*map(param, value))
+        return _clean(str(value))
+
+    def walk(jaxpr):
+        seen = {}
+        for kind, group in (("const", jaxpr.constvars), ("in", jaxpr.invars)):
+            for i, var in enumerate(group):
+                seen[var] = digest(kind, i, var.aval)
+        nodes = []
+
+        def read(atom):
+            if isinstance(atom, jcore.Literal):
+                return digest("literal", atom.val, atom.aval)
+            return seen[atom]
+
+        for eqn in jaxpr.eqns:
+            node = digest(eqn.primitive.name, *(
+                f"{key}={param(value)}"
+                for key, value in sorted(eqn.params.items())),
+                *map(read, eqn.invars), *(v.aval for v in eqn.outvars))
+            nodes.append(node)
+            for i, var in enumerate(eqn.outvars):
+                seen[var] = digest(node, i)
+        return (digest(*map(read, jaxpr.outvars)), digest(*sorted(nodes)),
+                len(nodes))
+
+    graph, equations, n = walk(closed.jaxpr)
+    return {"graph": graph, "equations": equations, "n_equations": n}
 
 
 def lowered(root: str, name: str, tpu: bool = False) -> dict:
@@ -72,6 +134,7 @@ def lowered(root: str, name: str, tpu: bool = False) -> dict:
         if tpu:
             out[which]["grids"] = re.findall(
                 r"grid=(\(\d+, \d+, \d+, \d+\))", text)
+            out[which].update(graph_hashes(traced.jaxpr))
     return out
 
 

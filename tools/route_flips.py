@@ -90,7 +90,8 @@ def main():
         tops = []
         for layer in params["layers"]:
             h = model._norm(x, layer["attn_norm"])
-            attn, _, _ = model._attn_expanded(layer, h, cos, sin)
+            attn, _, _ = model.attention._attn_expanded(layer, h, cos,
+                                                        sin)
             x = x + attn @ layer["wo"].astype(ad)
             if "router" in layer:
                 h = model._norm(x, layer["mlp_norm"])

@@ -129,6 +129,16 @@ class LatentAttention(Mixer):
             return _paged.KERNEL_MLA_PAGED_DECODE
         return "einsum"
 
+    def page_run(self, page_size: int, max_pages: int) -> int:
+        """Pages one copy of the latent kernel's walk brings: by what one
+        layer's page of the pool weighs (`mla_walk_run_pages`: 20 KB of
+        rows is under what a descriptor costs the scalar core); 1 where a
+        step traced here runs no kernel (the gather reads any table)."""
+        if self.decode_kernel(page_size, self.dtype) == "einsum":
+            return 1
+        return _paged.mla_walk_run_pages(
+            self.pools[0].bytes(self.dtype, page_size), page_size, max_pages)
+
     def open(self, at: Walk) -> None:
         """The rotary part's cos and sin of the program's positions."""
         c = self.config
@@ -240,12 +250,13 @@ class LatentAttention(Mixer):
             rows.reshape(n, page_size, self.config.row_width), mode="drop")
 
     def _attn_absorbed(self, layer: Params, h, cos, sin, pool, row: int,
-                       wr_page, wr_slot, page_tables, lengths):
+                       wr_page, wr_slot, page_tables, lengths, run: int = 1):
         """One decode position a lane in the absorbed form: `q_lat = q_nope
         W_UK^T`, scores `q_lat . c_kv + q_rope . k_rope`, `o_lat = P c_kv`,
         `o = o_lat W_UV`, over pool row `row`, which first gets this
-        position's row (`wr_page` of `num_pages` writes nothing). h (B, e).
-        Returns (attention output before W_o (B, heads * v), pool)."""
+        position's row (`wr_page` of `num_pages` writes nothing); `run`:
+        the runs the tables are laid in (`Walk.run`). h (B, e). Returns
+        (attention output before W_o (B, heads * v), pool)."""
         c = self.config
         ad = c.activation_dtype
         nope, latent = c.qk_nope_head_dim, c.kv_lora_rank
@@ -264,7 +275,8 @@ class LatentAttention(Mixer):
                             ((0, 0), (0, 0), (0, pad))).astype(pool.dtype)
         with R.region(R.ATTN_CORE):
             o_lat = _paged.mla_paged_decode_attention(
-                q_row, pool, row, page_tables, lengths, latent, sm_scale)
+                q_row, pool, row, page_tables, lengths, latent, sm_scale,
+                run)
         with R.region(R.ATTN_OUT):
             out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
                              w_kvb[..., nope:])
@@ -286,7 +298,7 @@ class LatentAttention(Mixer):
         page, tables = at.pages[PAGED]
         out, pool = self._attn_absorbed(
             layer, h, *at.tables[self], pools["kv"], li, page, at.offset,
-            tables, at.lengths)
+            tables, at.lengths, at.run)
         return out, {"kv": pool}
 
     def hidden(self, layer: Params, h, at: Walk):

@@ -119,9 +119,13 @@ class Walk:
     step's positions (B,); None where sequences start at 0), `true_len` (a
     padded prompt's), where a pool's kind is written (`pages[kind]`: a
     prompt's page ids; a step's (page a lane, the tables its kernel walks)),
-    `offset` and `lengths` (a step's row and reach a lane), `slot`, and
-    `tables`, what a mixer's `open` made once for all its layers."""
+    `offset` and `lengths` (a step's row and reach a lane), `slot`, `run`
+    (a step's: what the class answered the engine's allocator with,
+    `PagedDecoder.page_run`, so that a kernel copies runs only over tables
+    laid in them) and `tables`, what a mixer's `open` made once for all its
+    layers."""
     slot = offset = lengths = None
+    run = 1
 
     def __init__(self, sequences=None, lanes=None, true_len=None):
         self._sequences, self.lanes, self.true_len = sequences, lanes, true_len
@@ -180,8 +184,9 @@ class Mixer:
             page_size, max_pages)
 
     def page_run(self, page_size: int, max_pages: int) -> int:
-        """Pages one copy of its decode walk brings (`PagedDecoder.
-        page_run`)."""
+        """Pages one copy of its decode walk would bring, from shapes alone
+        (`PagedDecoder.page_run` decides; `decode_step` reads the decision
+        off `Walk.run`, never this)."""
         return 1
 
 
@@ -462,6 +467,7 @@ class PagedDecoder:
             entry, page, at.offset, at.lengths = lane_entries(
                 positions, page_tables, active, paged.shape[1], page_size)
             at.pages[PAGED] = page, page_tables
+            at.run = self.page_run(page_size, page_tables.shape[1])
         if ring is not None:
             held = self.fixed_pages(page_size)
             with R.region(R.CACHE):
@@ -594,7 +600,11 @@ class PagedDecoder:
         """Pages of the class that grows that a sequence is to be handed
         at once, ids behind one another from a multiple of it on
         (`kv_cache.PageAllocator`'s `run`): what one copy of the class's
-        decode walk brings. 1: a page at a time, in any order."""
+        decode walk brings. 1: a page at a time, in any order; and for a
+        class that keeps a fixed page, whose runs would start at table
+        entry `fixed` and not 0, which no kernel reads yet."""
+        if self.fixed_pages(page_size):
+            return 1
         return max((mixer.page_run(page_size, max_pages)
                     for mixer in self.mixers), default=1)
 

@@ -270,6 +270,7 @@ class ShortcutMLAMoE(ExpertCounts, PagedDecoder):
         page, at.offset, at.lengths = decode_lanes(
             positions, page_tables, active, pools["kv"].shape[1], page_size)
         at.pages[PAGED] = page, page_tables
+        at.run = self.page_run(page_size, page_tables.shape[1])
         load, sums = pools["moe_load"], self._step_sums()
         for row, layer in zip(self.layers, params["layers"]):
             def attend(j, h):

@@ -242,11 +242,12 @@ class SparseLatentAttention(LatentAttention):
 
     def _attn_selected_step(self, layer: Params, h, cos, sin, pool,
                             idx_pool, row: int, wr_page, wr_slot,
-                            page_tables, lengths):
+                            page_tables, lengths, run: int):
         """One decode position a lane: both pools get this position's row,
         the indexer scores the lane's positions, and the absorbed form
         reads the chosen rows. Tables that cannot pass `index_topk` are
-        `_attn_absorbed`'s. h (B, e). Returns (attention output before W_o
+        `_attn_absorbed`'s; `run`: the runs the tables are laid in
+        (`Walk.run`). h (B, e). Returns (attention output before W_o
         (B, heads * v), pool, idx_pool, the layer's `DSA_COUNTS`)."""
         c = self.config
         ad = c.activation_dtype
@@ -259,7 +260,7 @@ class SparseLatentAttention(LatentAttention):
         if span <= c.index_topk:
             out, pool = self._attn_absorbed(
                 layer, h, cos, sin, pool, row, wr_page, wr_slot,
-                page_tables, lengths)
+                page_tables, lengths, run)
             return out, pool, idx_pool, (jnp.int32(0), seen, jnp.int32(0))
         nope, latent = c.qk_nope_head_dim, c.kv_lora_rank
         pad = c.row_width - latent - c.qk_rope_head_dim
@@ -277,7 +278,6 @@ class SparseLatentAttention(LatentAttention):
                             ((0, 0), (0, 0), (0, pad))).astype(pool.dtype)
         kernel = self._step_kernels(pool.shape[2], page_tables.shape[1],
                                     pool.dtype)
-        run = self.page_run(pool.shape[2], page_tables.shape[1])
         with R.region(R.ATTN_INDEX):
             choice, chosen = _sparse.choose_paged(
                 q_idx.astype(idx_pool.dtype), w, idx_pool, row, page_tables,
@@ -325,7 +325,7 @@ class SparseLatentAttention(LatentAttention):
         page, tables = at.pages[PAGED]
         out, pool, idx_pool, counts = self._attn_selected_step(
             layer, h, *at.tables[self], pools["kv"], pools["idx"], li, page,
-            at.offset, tables, at.lengths)
+            at.offset, tables, at.lengths, at.run)
         with R.region(R.ATTN_INDEX):
             dsa = {name: pools["dsa_step"][name] + n
                    for name, n in zip(DSA_COUNTS, counts)}
@@ -348,10 +348,12 @@ class SparseLatentAttention(LatentAttention):
         allocator is asked to hand out behind one another: by what a page
         of index keys, the smaller pool's, weighs a layer
         (`ops.sparse_attention.walk_run_pages`); 1 where a step traced
-        here runs no walk kernel."""
+        here runs no walk kernel; `LatentAttention`'s answer where the
+        context cannot pass `index_topk`."""
         c = self.config
-        if c.max_seq_len <= c.index_topk or not self._step_kernels(
-                page_size, max_pages, self.dtype):
+        if c.max_seq_len <= c.index_topk:
+            return super().page_run(page_size, max_pages)
+        if not self._step_kernels(page_size, max_pages, self.dtype):
             return 1
         return _sparse.walk_run_pages(
             self.pools[1].bytes(self.dtype, page_size), max_pages)

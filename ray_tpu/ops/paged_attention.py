@@ -76,9 +76,12 @@ WALK_BUFFER_BYTES = 16 << 20
 # `walk_prefixes`: a lane of 257-512 positions would be multiplied at such
 # a block's half, 1,024, and reads 16-18 % worse (PERF.md section 6, PR 48).
 BLOCK_POSITIONS = 1024
-# Pages a turn of the loop that starts, or waits for, a block's copies
-# (unrolled, the scalar core overlaps their table reads and descriptors:
-# it is what bounds a walk over pages as small as a latent row's).
+# Copies a turn of the loop that starts, or waits for, a block's copies: a
+# page each, or a run of pages where the walk is told its tables are laid
+# in runs (unrolled, the scalar core overlaps their table reads and
+# descriptors; a descriptor costs it some 50 ns whatever it brings, which
+# is what bounds a walk whose copies are under some 64 KB: a page of 8-20
+# KB of a pool, PERF.md section 6, PR 62 and PR 64).
 COPY_UNROLL = 4
 # Heads a turn of the loop over a block's heads multiplies, unrolled (as
 # many, up to these, as divide the kv heads). A head's chain of matmul, max,
@@ -247,7 +250,7 @@ def _gathered_attention(q, k_pool, v_pool, layer, tables, seen):
 def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
                 l_ref, finite_ref, hand_ref, copies, value_buf, attend, *,
                 page_size: int, block_pages: int, max_pages: int,
-                window: int = 0):
+                window: int = 0, run: int = 1):
     """A paged decode kernel but for its matmuls: `attend(slot, start,
     pages, seen, rolled)` on every block of pages the grid's lane holds, in table
     order, between the reset of the running softmax and its division into
@@ -269,6 +272,14 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
     With a `window` the lane sees its last `window` positions only and the
     table is a ring of `max_pages` entries (logical page j at entry j mod
     `max_pages`): the walk begins at the first page the window reaches.
+    With a `run` above 1 the tables' owner vouches that a lane's pages lie
+    in aligned runs of `run` (`serve/llm/kv_cache.py`: entry `k * run`
+    names the first of `run` pages that lie behind one another in the pool,
+    all the lane's own, those no position has reached yet too), and one
+    copy brings a run: a run is copied whole where its first entry is held,
+    and what of it lies past the lane's length is not `seen`. A run lies
+    in one block (tables and blocks of whole runs), and not in a ring,
+    whose walk begins at any entry.
 
     The grid runs the lanes in order and the scratch outlives a grid step:
     behind its last block a lane has nothing of its own left to copy in,
@@ -276,6 +287,11 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
     the slot it is not multiplying from, and that lane finds it in flight
     (`walk_first_blocks_hidden` counts them). Only the first such lane of
     a call waits for a copy that nothing hides."""
+    if run > 1 and (window or max_pages % run or block_pages % run):
+        raise ValueError(
+            f"a walk that copies runs of {run} pages takes tables and "
+            f"blocks of whole runs and no ring: {max_pages} pages in "
+            f"blocks of {block_pages}, window {window}")
     b = pl.program_id(0)
     lanes = len_ref.shape[0]
     slots = value_buf.shape[0]
@@ -321,24 +337,38 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
         """`act` on the copy of every page of block `blk` the lane of
         `who` holds, into `slot` (a loop, not unrolled: a block of 64
         pages is traced as one page, and a short lane pays for the pages
-        it has). Returns how many pages those were."""
+        it has); a copy a page, or one a run of `run` pages whose first
+        the lane holds. Returns how many pages the walk reaches those
+        were."""
         reach = reach_of(who, blk)
 
         def some(i, held):
             for p in range(COPY_UNROLL):
                 p = i * COPY_UNROLL + p
+                if run > 1:                 # the run's first page
+                    p = p * run
                 page, live = page_at(who, blk, p)
                 live = live & (p < reach)
 
                 @pl.when(live)
                 def _():
                     for pool, buf, sem in copies:
+                        if run == 1:
+                            src, dst = pool.at[layer, page], buf.at[slot, p]
+                        else:
+                            src = pool.at[layer, pl.ds(
+                                pl.multiple_of(page, run), run)]
+                            dst = buf.at[slot, pl.ds(
+                                pl.multiple_of(p, run), run)]
                         act(pltpu.make_async_copy(
-                            pool.at[layer, page], buf.at[slot, p],
-                            sems.at[(slot, *sem)]))
-                held = held + live.astype(jnp.int32)
+                            src, dst, sems.at[(slot, *sem)]))
+                if run == 1:
+                    held = held + live.astype(jnp.int32)
+                else:
+                    held = held + jnp.where(
+                        live, jnp.minimum(reach - p, run), 0)
             return held
-        return lax.fori_loop(0, pl.cdiv(reach, COPY_UNROLL), some,
+        return lax.fori_loop(0, pl.cdiv(reach, COPY_UNROLL * run), some,
                              jnp.int32(0))
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
@@ -447,8 +477,10 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
 
         def holes():     # 1 where the table has no page (as int32: a
             def one(p, out):                 # branch yields no mask)
+                # (a page of a run is held where the run's first is)
+                first = p - p % run if run > 1 else p
                 return jnp.where((at // page_size == p)
-                                 & ~page_at(me, blk, p)[1], 1, out)
+                                 & ~page_at(me, blk, first)[1], 1, out)
             return lax.fori_loop(start, jnp.minimum(reach, start + pages),
                                  one, jnp.zeros_like(at))
         return seen & (lax.cond(whole, lambda: jnp.zeros_like(at), holes)
@@ -488,7 +520,7 @@ def _paged_pallas_call(kernel, name: str, q, pools, layer,
     rows, width) a lane a block; the pools left in HBM; scratch as
     `_walk_pages` takes it, the blocks of `walk_block_pages` pages by what
     a page of these pools weighs. `kernel` gets the walk's sizes by
-    keyword, and what else `walk` holds (a `window`)."""
+    keyword, and what else `walk` holds (a `window`, a `run`)."""
     lanes, *rows = q.shape
     out = (*rows[:-1], out_width)
     stat = (*rows[:-1], 128)
@@ -745,6 +777,34 @@ def paged_window_decode_attention_kernel(q, k_pool, v_pool, layer,
 # caller multiplies by W_UV afterwards). All heads share the rows: one
 # matmul's rows are the heads.
 KERNEL_MLA_PAGED_DECODE = "mla_paged_decode_attn"
+# Bytes one copy of the latent walk should bring where the tables' owner
+# lays a lane's pages in runs (`_walk_pages`' `run`): the scalar core needs
+# some 50 ns to start a copy and await it, which a page of 20 KB (25 ns at
+# 819 GB/s) does not cover, and a copy's bytes hide only part of it: a page
+# read 49.8 / 33.4 / 27.2 / 27.4 ns at 1 / 2 / 4 / 8 pages a copy
+# (`tools/bench_paged.py --run`; PERF.md section 6, PR 64), at its bytes
+# from 80 KB on. Twice `ops.sparse_attention.RUN_COPY_BYTES`, which is
+# sized by the index keys' page that walk brings beside this one.
+MLA_RUN_COPY_BYTES = 64 << 10
+
+
+def copy_run_pages(copy_bytes: int, page_bytes: int, *whole: int) -> int:
+    """Pages a run holds so that one copy of it brings `copy_bytes` at
+    `page_bytes` a page: the least power of two that does, cut to a
+    divisor of each of `whole` (a table, a walk's block: a run lies in
+    one)."""
+    return math.gcd(1 << (-(-copy_bytes // page_bytes) - 1).bit_length(),
+                    *whole)
+
+
+def mla_walk_run_pages(page_bytes: int, page_size: int,
+                       max_pages: int) -> int:
+    """Pages one copy of the latent walk brings where a page of the pool
+    is `page_bytes` (one layer's): `copy_run_pages` of `MLA_RUN_COPY_BYTES`
+    in the table's `max_pages` and the walk's block."""
+    return copy_run_pages(
+        MLA_RUN_COPY_BYTES, page_bytes, max_pages,
+        walk_block_pages(page_bytes, page_size, max_pages))
 
 
 def mla_paged_decode_tiles(width: int, latent: int, page_size: int,
@@ -801,10 +861,11 @@ def _mla_paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
 
 
 # jitted for the reason `_paged_decode_call` is: traced once a program
-@functools.partial(jax.jit,
-                   static_argnames=("latent", "sm_scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("latent", "sm_scale",
+                                             "interpret", "run"))
 def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
-                           latent: int, sm_scale: float, interpret: bool):
+                           latent: int, sm_scale: float, interpret: bool,
+                           run: int = 1):
     heads, width = q.shape[1:]
     page_size = pool.shape[2]
     if not mla_paged_decode_tiles(width, latent, page_size, pool.dtype):
@@ -820,7 +881,8 @@ def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
                                latent=latent)
     out = _paged_pallas_call(
         kernel, KERNEL_MLA_PAGED_DECODE, qp, (pool,), layer, page_tables,
-        lengths, out_width=latent, sems=(BLOCK_SLOTS,), interpret=interpret)
+        lengths, out_width=latent, sems=(BLOCK_SLOTS,), interpret=interpret,
+        run=run)
     return out[:, :heads]
 
 
@@ -832,19 +894,24 @@ def mla_uses_kernel(width: int, latent: int, page_size: int, dtype) -> bool:
 
 
 def mla_paged_decode_attention(q, pool, layer, page_tables, lengths,
-                               latent: int, sm_scale: float):
+                               latent: int, sm_scale: float, run: int = 1):
     """Dispatching entry point of the latent pool's decode attention: the
     compiled kernel on a TPU where the shapes tile, the gather + einsum
-    reference elsewhere. Shapes as `mla_paged_attention_reference`."""
+    reference elsewhere. Shapes as `mla_paged_attention_reference`; `run`:
+    the pages one copy of the kernel's walk brings, which the tables' owner
+    vouches lie in such runs (`_walk_pages`; the gather reads any table)."""
     if mla_uses_kernel(q.shape[-1], latent, pool.shape[2], pool.dtype):
         return _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
-                                      latent, float(sm_scale), False)
+                                      latent, float(sm_scale), False,
+                                      run=run)
     return mla_paged_attention_reference(q, pool, layer, page_tables,
                                          lengths, latent, sm_scale)
 
 
 def mla_paged_decode_attention_kernel(q, pool, layer, page_tables, lengths,
-                                      latent: int, sm_scale: float):
+                                      latent: int, sm_scale: float,
+                                      run: int = 1):
     """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
     return _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
-                                  latent, float(sm_scale), not on_tpu())
+                                  latent, float(sm_scale), not on_tpu(),
+                                  run=run)

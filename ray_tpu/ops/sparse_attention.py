@@ -57,7 +57,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 from ray_tpu.ops.dispatch import on_tpu
-from ray_tpu.ops.paged_attention import _softmax_update
+from ray_tpu.ops.paged_attention import _softmax_update, copy_run_pages
 
 # The kernels' names on the device's clock (see attention.KERNEL_FWD).
 KERNEL_INDEX_SCORES = "dsa_index_scores"
@@ -170,10 +170,9 @@ def walk_run_pages(page_bytes: int, max_pages: int) -> int:
     `page_bytes` (one layer's): as many as make a copy of `RUN_COPY_BYTES`,
     cut to a divisor of the table's `max_pages` and of both walks' blocks
     (a run lies in one block)."""
-    return math.gcd(
-        1 << (-(-RUN_COPY_BYTES // page_bytes) - 1).bit_length(),
-        max_pages, min(INDEX_WALK_PAGES, max_pages),
-        min(ATTEND_WALK_PAGES, max_pages))
+    return copy_run_pages(
+        RUN_COPY_BYTES, page_bytes, max_pages,
+        min(INDEX_WALK_PAGES, max_pages), min(ATTEND_WALK_PAGES, max_pages))
 
 
 def _walk_blocks(layer_ref, len_ref, pt_ref, pool_hbm, buf, sems, block,

@@ -43,9 +43,12 @@ stays that whatever follows. A *run* is `run` pages of the class that
 grows whose ids lie behind one another from a multiple of `run` on, `[g x
 run, g x run + run)`. A model class whose decode walk is bound by the
 copies it starts and not by their bytes asks for one
-(`models.paged.PagedDecoder.page_run`, from shapes alone; 1 for every
-class but `SparseMLAMoE`, whose two walks then bring a run a copy:
-`ops/sparse_attention.py`), the engine passes the answer on
+(`models.paged.PagedDecoder.page_run`, from shapes alone: `SparseMLAMoE`,
+whose two walks then bring a run a copy, `ops/sparse_attention.py`, and
+since PR 64 the latent classes without a fixed page, `MLAMoE` and
+`ShortcutMLAMoE`, whose kernel does, `ops/paged_attention.py`; 1 for the
+per-head classes and for every class that keeps a fixed page, whose runs
+would start at table entry `fixed`), the engine passes the answer on
 (`PageAllocator(run=)`) and keeps no notion of a run of its own. The
 allocator then hands the class out and takes it back in whole runs: what a
 sequence holds of it is rounded up to whole runs (`alloc(1, held)` at a

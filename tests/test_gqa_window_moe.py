@@ -587,7 +587,16 @@ PINNED = {
     ("HybridDelta", "prefill"): "328443fc4f95f8b1",
     ("HybridDelta", "decode_step"): "61459a317f791d1e",
     ("ShortcutMLAMoE", "prefill"): "d4d9ab62a550993a",
-    ("ShortcutMLAMoE", "decode_step"): "b117688674ce4a5d",
+    # PR 64 let `_walk_pages` bring a run of pages a copy and gave the
+    # latent kernel the run its class answers the engine with
+    # (`LatentAttention.page_run`): at this config's rows of 256 in pages
+    # of 16 (8 KB a page, tables of 8) that is 8, so the step's two latent
+    # kernels are pinned anew to PR 64's text. With the run at 1 the text
+    # is the parent's, b117688674ce4a5d (`PINNED_AT_RUN_1` below), and the
+    # nineteen others keep their hashes: `MLAMoE`'s and `HybridKDAMoE`'s
+    # tiny rows are no shape the latent kernel tiles, and the latter keeps
+    # a fixed page, so a run of 1, whatever its rows
+    ("ShortcutMLAMoE", "decode_step"): "4de7c6fe6411287f",
     ("HybridSSMMoE", "prefill"): "2423f3d6a6654486",
     ("HybridSSMMoE", "decode_step"): "f046ae2debee7f59",
     # the seventh class, pinned in PR 50 to the text PR 50 gave it: what it
@@ -714,6 +723,21 @@ def test_older_models_programs_lower_to_the_parents_text(name, program):
     assert "pallas_call" in text            # the kernels are in the text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[
         (name, program)]
+
+
+# a pin PR 64 rewrote, as the parent wrote it: the text with the run at 1
+PINNED_AT_RUN_1 = {("ShortcutMLAMoE", "decode_step"): "b117688674ce4a5d"}
+
+
+@pytest.mark.parametrize("name,program", sorted(PINNED_AT_RUN_1))
+def test_a_page_a_copy_is_the_parents_text(monkeypatch, name, program):
+    """`run` 1 traces what the walk traced before it took a run."""
+    cfg = PINNED_CONFIGS[name]()
+    model = build_model(cfg)
+    monkeypatch.setattr(type(model), "page_run", lambda self, *a: 1)
+    text = _programs(model, cfg)[program]
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+        PINNED_AT_RUN_1[(name, program)])
 
 
 # What lets a pin be written anew: `tools/lowered_text.py`'s two hashes that

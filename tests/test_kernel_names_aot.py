@@ -380,6 +380,8 @@ def _compile_mla_moe(devices, which: str):
     from ray_tpu.models.mla_moe import MLAMoE, MLAMoEConfig
     model = MLAMoE(MLAMoEConfig(vocab_size=1024, n_layers=2,
                                 max_seq_len=2048))
+    with compute_platform("tpu"):       # the step's walk: 4 pages a copy
+        assert model.page_run(PAGE, 2048 // PAGE) == 4
     compiled, cache = _compile_served(
         devices, model, which, lambda: model.init_cache(PAGES, PAGE),
         "mla_paged_decode_attn")
@@ -503,6 +505,8 @@ def _compile_shortcut_mla_moe(devices, which: str):
     model = ShortcutMLAMoE(ShortcutMLAMoEConfig(
         vocab_size=1024, n_layers=1, experts_held=(0, 16),
         max_seq_len=2048))
+    with compute_platform("tpu"):       # the step's walks: 4 pages a copy
+        assert model.page_run(PAGE, 2048 // PAGE) == 4
     compiled, cache = _compile_served(
         devices, model, which, lambda: model.init_cache(PAGES, PAGE),
         "mla_paged_decode_attn")

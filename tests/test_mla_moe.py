@@ -300,6 +300,92 @@ def test_decode_attention_names_the_latent_kernel_or_einsum():
         assert MLAMoE(tiny_mla_moe()).decode_attention(8) == "einsum"
 
 
+# ---------------------------------------- a run of pages a copy (PR 64)
+def test_the_latent_walks_run_is_the_pages_bytes_answer():
+    """`page_run` at the batch32 cell's shapes: 4 pages a copy, what
+    `mla_walk_run_pages` makes of a page of 20,480 B (the least power of
+    two that brings `MLA_RUN_COPY_BYTES`), where the kernel runs; 1 on the
+    CPU and where the shapes do not tile (the gather reads any table); cut
+    to a divisor of the table and of the walk's block."""
+    assert paged.mla_walk_run_pages(16 * 640 * 2, 16, 256) == 4
+    assert paged.mla_walk_run_pages(16 * 640 * 2, 16, 250) == 2
+    assert paged.mla_walk_run_pages(16 * 640 * 2, 16, 255) == 1
+    assert paged.mla_walk_run_pages(16 * 128 * 2, 16, 256) == 16
+    assert paged.mla_walk_run_pages(16 * 128 * 2, 16, 8) == 8    # the block
+    assert paged.mla_walk_run_pages(128 << 10, 16, 256) == 1
+    served = MLAMoE(MLAMoEConfig(n_layers=2))     # the published widths
+    assert served.page_run(16, 256) == 1                     # off the TPU
+    with compute_platform("tpu"):
+        assert served.page_run(16, 256) == 4
+        assert served.page_run(16, 255) == 1
+        assert served.walk_block_pages(16, 256) == 64   # whole runs of 4
+        assert MLAMoE(tiny_mla_moe()).page_run(8, 16) == 1       # gathers
+
+
+# the run each served configuration's class answers its engine at its
+# deployment's shapes, where the kernels run: the two latent classes without
+# a fixed page by their page's bytes, `SparseMLAMoE` by its index keys'
+# (PR 62), and 1 for every class that keeps a fixed page (a state slot, a
+# ring: its runs would start at table entry `fixed`) and every per-head one
+SERVED_RUNS = {
+    "internlm2-1.8b": 1, "laguna-xs.2-1chip": 1, "olmo-hybrid-7b-1chip": 1,
+    "nemotron-3-super-120b-a12b-1chip": 1, "ling-3.0-flash-vl-1chip": 1,
+    "falcon-h1-34b-instruct-1chip": 1, "lfm2-8b-a1b-1chip": 1,
+    "glm-5-1chip": 8, "glm-4.7-flash-1chip": 4,
+    "longcat-flash-chat-1chip": 4}
+
+
+@pytest.mark.parametrize("name", sorted(SERVED_RUNS))
+def test_a_served_class_answers_its_run_from_shapes_alone(name):
+    import json
+    from ray_tpu.models.latent import LatentAttention
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    module, dep = modelcfg.load_model(cfg), cfg["deployment"]
+    model = build_model(module.program_config(
+        cfg, max_seq_len=dep["context_limit"]))
+    page, table = dep["page_size"], dep["context_limit"] // dep["page_size"]
+    assert model.page_run(page, table) == 1                  # off the TPU
+    with compute_platform("tpu"):
+        assert model.page_run(page, table) == SERVED_RUNS[name]
+        if model.fixed_pages(page):     # whatever a mixer alone would say
+            assert model.page_run(page, table) == 1
+            assert name != "ling-3.0-flash-vl-1chip" or [
+                m.page_run(page, table) for m in model.mixers
+                if isinstance(m, LatentAttention)] == [4]
+    run = SERVED_RUNS[name]
+    assert table % run == 0 and dep["num_pages"] % run == 0
+
+
+def test_the_kernel_is_handed_the_run_the_class_answered(monkeypatch):
+    """A step's latent kernel copies the runs `PagedDecoder.page_run` said
+    (what the engine's allocator was told), carried in the `Walk`: never
+    the mixer's own answer a second time."""
+    from ray_tpu.models.latent import LatentAttention
+    handed = []
+    real = paged.mla_paged_decode_attention
+
+    def spy(*a):
+        handed.append(a[7])
+        return real(*a[:7])
+    monkeypatch.setattr(paged, "mla_paged_decode_attention", spy)
+    monkeypatch.setattr(MLAMoE, "page_run", lambda self, *a: 4)
+    monkeypatch.setattr(LatentAttention, "page_run", lambda self, *a: 2)
+    cfg = tiny_mla_moe()
+    model = MLAMoE(cfg)
+    B, page = 2, 8
+    mp = cfg.max_seq_len // page
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)     # noqa
+    jax.eval_shape(
+        lambda p, c, t, pos, pts, a: model.decode_step(p, c, t, pos, pts, a,
+                                                       page),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+        jax.eval_shape(lambda: model.init_cache(B * mp, page)),
+        i32(B), i32(B), i32(B, mp), jax.ShapeDtypeStruct((B,), jnp.bool_))
+    assert handed == [4] * cfg.n_layers
+
+
 # ------------------------------------------------- the grouped matmul
 @pytest.mark.parametrize("m,sizes,k,n", [
     (128, (0, 5, 0, 100, 3, 0, 0, 10), 128, 256),   # rows past the last

@@ -455,6 +455,118 @@ def test_latent_lanes_hand_their_walk_on(walk_budget, walk_spy, lengths,
     assert len(handed) == pa.walk_first_blocks_hidden(pages)
 
 
+# ------------------------------------------- a run of pages a copy (PR 64)
+LPAGE, LWIDTH, LLATENT = 8, 256, 128
+AHEAD = 1e30        # what the pages held ahead of a lane's length hold
+
+
+def _latent_runs_case(lengths, run, max_pages=48, seed=0, heads=5,
+                      aligned=True):
+    """A float32 latent pool whose tables are laid as the allocator lays
+    them at `run`: a lane holds whole runs, each `run` ids behind one
+    another from a multiple of `run` on, the runs anywhere in the pool;
+    the pages of a lane's last run that no position has reached hold
+    `AHEAD`. Not `aligned`: a page at a time, anywhere (`run` 1 alone)."""
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+    pages = B * max_pages + 2 * run
+    pool = rng.normal(size=(2, pages, LPAGE, LWIDTH)).astype(np.float32)
+    q = jnp.asarray(rng.normal(size=(B, heads, LWIDTH)), jnp.float32)
+    tables = np.full((B, max_pages), -1, np.int32)
+    free = (list(rng.permutation(pages // run) * run) if aligned
+            else list(rng.permutation(pages)))
+    for b, n in enumerate(lengths):
+        live = -(-n // LPAGE)
+        for k in range(-(-live // run) if aligned else live):
+            first = free.pop()
+            tables[b, k * run:(k + 1) * run] = first + np.arange(run)
+        ahead = tables[b, live:][tables[b, live:] >= 0]
+        pool[:, ahead] = AHEAD
+    return q, jnp.asarray(pool), jnp.asarray(tables), jnp.asarray(
+        lengths, jnp.int32)
+
+
+def _latent_blocks_of_16(walk_budget, max_pages=48):
+    walk_budget(pa.BLOCK_SLOTS * 16 * LPAGE * LWIDTH * 4)
+    assert pa.walk_block_pages(LPAGE * LWIDTH * 4, LPAGE, max_pages) == 16
+
+
+RUN_LANES = {
+    "ends-inside-a-run": (5 * LPAGE, 9 * LPAGE + 3, 13 * LPAGE + 1),
+    "one-page": (LPAGE, 1, 5),
+    "an-empty-lane-between": (100, 0, 0, 60),       # the hand-on
+    # blocks of 16 pages: 17 pages, 38 (three blocks), a block to the page
+    "crosses-a-block": (16 * LPAGE + 1, 300, 16 * LPAGE),
+}
+
+
+@pytest.mark.parametrize("run,lanes,aligned", [
+    *((run, lanes, True) for run in (1, 2, 4) for lanes in RUN_LANES),
+    (1, "crosses-a-block", False),      # any table, as before PR 64
+], ids=lambda v: str(v))
+def test_latent_kernel_copies_a_run_of_pages_a_descriptor(
+        walk_budget, walk_spy, run, lanes, aligned):
+    """`run` pages a copy over tables laid in aligned runs give what the
+    gather gives, what is held ahead of a lane's length never read into
+    the output; the copies are one a run that holds a live page, and a
+    lane still finds its first block started by the lane before it."""
+    lengths = RUN_LANES[lanes]
+    case = _latent_runs_case(lengths, run, seed=17, aligned=aligned)
+    _latent_blocks_of_16(walk_budget)
+    got = pa.mla_paged_decode_attention_kernel(*case[:2], 1, *case[2:],
+                                               LLATENT, 0.1, run=run)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got)).max() < 1e3      # nothing of AHEAD
+    np.testing.assert_allclose(got, pa.mla_paged_attention_reference(
+        *case[:2], 1, *case[2:], LLATENT, 0.1), atol=2e-5, rtol=2e-5)
+    started, waited = walk_spy(len(lengths))
+    pages = _pages(lengths, LPAGE)
+    assert waited.sum() == sum(-(-n // run) for n in pages)
+    handed = _handed_on(started, waited)
+    assert handed == [b for b, n in enumerate(lengths) if n][1:]
+    assert len(handed) == pa.walk_first_blocks_hidden(pages)
+
+
+def test_a_run_whose_first_entry_is_unassigned_is_a_hole(walk_budget):
+    """A run is held where its first entry is: -1 there and the run's
+    pages are not copied and not seen, in a full block and in a tail."""
+    lengths = (300, 70)
+    q, pool, tables, ln = _latent_runs_case(lengths, 2, seed=19)
+    _latent_blocks_of_16(walk_budget)
+    holes = np.asarray(tables).copy()
+    holes[0, 4:6] = holes[0, 36:38] = holes[1, 0:2] = -1
+    holes = jnp.asarray(holes)
+    got = pa.mla_paged_decode_attention_kernel(q, pool, 0, holes, ln,
+                                               LLATENT, 0.1, run=2)
+    np.testing.assert_allclose(got, pa.mla_paged_attention_reference(
+        q, pool, 0, holes, ln, LLATENT, 0.1), atol=2e-5, rtol=2e-5)
+
+
+def test_a_table_that_ends_inside_a_block_is_walked_in_runs(walk_budget):
+    """40 pages in blocks of 16: the last block is half a block, and whole
+    runs of 4."""
+    q, pool, tables, ln = _latent_runs_case((40 * LPAGE - 3, 33 * LPAGE), 4,
+                                            max_pages=40, seed=23)
+    _latent_blocks_of_16(walk_budget, 40)
+    got = pa.mla_paged_decode_attention_kernel(q, pool, 1, tables, ln,
+                                               LLATENT, 0.1, run=4)
+    np.testing.assert_allclose(got, pa.mla_paged_attention_reference(
+        q, pool, 1, tables, ln, LLATENT, 0.1), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("run,max_pages", [
+    (4, 42),                # a table that is no whole runs
+    (32, 64),               # a run longer than a block of 16
+])
+def test_a_walk_of_runs_refuses_tables_it_cannot_lay_runs_in(
+        walk_budget, run, max_pages):
+    case = _latent_runs_case((40, 40), 1, max_pages=max_pages)
+    _latent_blocks_of_16(walk_budget, max_pages)
+    with pytest.raises(ValueError, match="blocks of whole runs"):
+        pa.mla_paged_decode_attention_kernel(*case[:2], 0, *case[2:],
+                                             LLATENT, 0.1, run=run)
+
+
 # page bytes of a layer (all pools), table pages: the five configurations
 CELLS = {
     "internlm2-1.8b": (2 * 16 * 8 * 128 * 2, 256),

@@ -172,6 +172,51 @@ def test_page_allocator_hands_out_whole_aligned_runs(run):
     assert c.fixed_used == 1 and c.alloc(1)[0] == 0
 
 
+def test_an_engine_over_a_latent_class_lays_and_walks_runs(monkeypatch):
+    """What PR 62's engine did for `SparseMLAMoE`'s walks it does for the
+    latent kernel's (interpreted here, the mixer made to answer 4): the
+    run the class answers is the allocator's, the kernel's (through the
+    step's `Walk`) and the counters', across admission, growth, an eviction
+    and a cancel, and the tokens are those of a page at a time."""
+    import dataclasses
+    from ray_tpu.models.latent import LatentAttention
+    from ray_tpu.models.mla_moe import MLAMoE, tiny_mla_moe
+    from ray_tpu.ops import paged_attention as pa
+    from test_sparse_mla_moe import _serve_in_runs
+    handed = []
+    call = pa._mla_paged_decode_call
+
+    def interpreted(*a, run=1):
+        handed.append(run)
+        return call(*a[:-1], True, run=run)
+    monkeypatch.setattr(pa, "mla_uses_kernel", lambda *a: True)
+    monkeypatch.setattr(pa, "_mla_paged_decode_call", interpreted)
+    # rows of 256 that hold a latent of 128: shapes the kernel tiles
+    cfg = dataclasses.replace(tiny_mla_moe(), kv_lora_rank=128)
+    params = MLAMoE(cfg).init(jax.random.PRNGKey(0))
+    got, core = _serve_in_runs(monkeypatch, cfg, params, 4, LatentAttention)
+    assert core.alloc.run == 4 and core.alloc.unused_pages == 1
+    assert core.cache_stats()["page_run"] == 4
+    assert core.device_stats()["decode_attention"] == "mla_paged_decode_attn"
+    assert set(handed) == {4}               # one trace, every layer's call
+    c = core.counters
+    assert c["evictions"] > 0
+    assert len(got["a"]) == 40 and len(got["b"]) == len(got["d"]) == 24
+    assert len(got["c"]) == 5
+    # a copy a run: whole runs read, up to 3 pages a lane beyond the live
+    assert c["kv_positions_read"] == c["kv_walk_copies"] * 4 * 8
+    assert c["kv_positions_live"] <= c["kv_positions_read"] \
+        < c["kv_positions_live"] + c["decode_lane_steps"] * 4 * 8
+    del handed[:]
+    want, plain = _serve_in_runs(monkeypatch, cfg, params, 1,
+                                 LatentAttention)
+    assert plain.alloc.run == 1 and "page_run" not in plain.cache_stats()
+    assert set(handed) == {1}
+    p = plain.counters
+    assert p["kv_positions_read"] == p["kv_walk_copies"] * 8
+    assert got == want
+
+
 def test_pages_needed_and_budget():
     assert pages_needed(1, 16) == 1
     assert pages_needed(16, 16) == 1

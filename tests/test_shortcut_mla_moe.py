@@ -205,6 +205,40 @@ def test_served_path_with_the_kernels_under_the_interpreter(monkeypatch):
     assert rel_rms(got, mod.logits_fn(sz, params, toks)[p - 1:]) < 1e-4
 
 
+def test_both_attentions_of_a_layer_walk_the_run_the_class_answered(
+        monkeypatch):
+    """`page_run` at the reason4k cell's shapes is the rule's 4 where the
+    kernel runs and 1 on the CPU; a step hands both attentions' kernels
+    what the class answered (its own `decode_step` sets the `Walk`'s)."""
+    from ray_tpu.ops.dispatch import compute_platform
+    served = ShortcutMLAMoE(ShortcutMLAMoEConfig(
+        n_layers=1, experts_held=(0, 16)))       # the published widths
+    assert served.page_run(16, 256) == 1                     # off the TPU
+    with compute_platform("tpu"):
+        assert served.fixed_pages(16) == 0
+        assert served.page_run(16, 256) == 4     # 20,480 B a page
+    handed = []
+    real = paged.mla_paged_decode_attention
+
+    def spy(*a):
+        handed.append(a[7])
+        return real(*a[:7])
+    monkeypatch.setattr(paged, "mla_paged_decode_attention", spy)
+    monkeypatch.setattr(ShortcutMLAMoE, "page_run", lambda self, *a: 4)
+    cfg = tiny_shortcut_mla_moe()
+    model = ShortcutMLAMoE(cfg)
+    B, page = 2, 8
+    mp = cfg.max_seq_len // page
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)     # noqa
+    jax.eval_shape(
+        lambda p, c, t, pos, pts, a: model.decode_step(p, c, t, pos, pts, a,
+                                                       page),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+        jax.eval_shape(lambda: model.init_cache(B * mp, page)),
+        i32(B), i32(B), i32(B, mp), jax.ShapeDtypeStruct((B,), jnp.bool_))
+    assert handed == [4] * 2 * cfg.n_layers
+
+
 # --------------------------------------------------- the shares add up
 def test_the_shares_of_a_layer_add_up_to_the_uncut_layer(tiny_ref):
     """Four chips share a layer of 16 experts, 4 each: the held parts of

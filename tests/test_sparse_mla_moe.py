@@ -561,15 +561,16 @@ def test_engine_core_serves_it_and_counts_what_it_chose():
     assert np.asarray(st["moe_load"]).shape == (cfg.n_moe_layers, 4)
 
 
-def _serve_in_runs(monkeypatch, cfg, params, run):
-    """An engine whose step runs the two walk kernels (interpreted) over a
+def _serve_in_runs(monkeypatch, cfg, params, run,
+                   mixer=SparseLatentAttention):
+    """An engine whose step runs its walk kernels (interpreted) over a
     pool too small for its three lanes, pages in runs of `run` (None: the
-    class's own answer): admission, growth across a run's end, an
-    eviction and its re-admission, a cancel. Returns (tokens by request,
-    the engine), every lane's table checked after every step."""
+    class's own answer; else what its `mixer` is made to answer):
+    admission, growth across a run's end, an eviction and its
+    re-admission, a cancel. Returns (tokens by request, the engine), every
+    lane's table checked after every step."""
     if run is not None:
-        monkeypatch.setattr(SparseLatentAttention, "page_run",
-                            lambda *a: run)
+        monkeypatch.setattr(mixer, "page_run", lambda *a: run)
     core = EngineCore(cfg, params, num_pages=17, page_size=8, max_batch=3)
     run = core.alloc.run
     rng = np.random.default_rng(0)

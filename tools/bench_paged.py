@@ -13,13 +13,20 @@ Chip only:
     chiprun -- python tools/bench_paged.py --sweep        # every block
     chiprun -- python tools/bench_paged.py --shapes pr27,laguna-window
     chiprun -- python tools/bench_paged.py --lanes mixed  # unlike lanes
+    chiprun -- python tools/bench_paged.py --run 1,2,4,8  # pages a copy
 
 `--sweep` puts each block size (in pages) in the place of
 `walk_block_pages`'s answer: what `WALK_BUFFER_BYTES` and `BLOCK_POSITIONS`
 in `ops/paged_attention.py` were chosen from (PERF.md section 6, PR 38);
 `--prefixes each` lets the matmuls of a block reach every whole number of
 pieces in the place of `walk_prefixes`'s few, `--heads n` puts `n` in the
-place of `HEAD_UNROLL`. A tree from before PR 38 has two constants and no
+place of `HEAD_UNROLL`. `--run` is the latent kernel alone (the shapes
+"glm" and "longcat", at 1,024 / 2,048 / 4,096 positions a lane unless
+`--positions` says otherwise) with its tables laid in aligned runs of that
+many pages, the runs shuffled, and a run a copy: ms a layer and, from their
+slope over the pages walked, the ns a page and a copy that
+`models.latent.LatentAttention.page_run`'s constant was read from (PERF.md
+section 6, PR 64). A tree from before PR 38 has two constants and no
 rule: copy this file into its `tools/` and run it there to set parent
 beside change in one call (`--json` writes the rows).
 """
@@ -48,21 +55,27 @@ SHAPES = {
     "laguna-window": ("window", 32, 64, 8, 33, 512),
     "olmo": ("full", 32, 30, 30, 192, 0),
     "glm": ("latent", 32, 20, 1, 256, 0),
+    "longcat": ("latent", 32, 64, 1, 256, 0),       # 64 query rows a lane
 }
+RUN_POSITIONS = (1024, 2048, 4096)
 LATENT, ROW, ROW_HELD = 512, 640, 576
 # `--lanes mixed`: lane i is this share of the row's positions long, so a
 # lane hands its walk on to a shorter one, a longer one and past empty ones
 MIXED = (1.0, 0.25, 0.0, 1.0, 0.5, 0.0, 0.0, 0.75)
 
 
-def program(kernel, window):
+def program(kernel, window, run=None):
     """`LAYERS` calls of a kernel, each one's queries hanging on the one
-    before: lengths and tables are data, so one program a shape and block."""
+    before: lengths and tables are data, so one program a shape and block
+    (and, the latent kernel's, a `run`)."""
+    # (by keyword and only where asked: an older tree's kernel takes none)
+    runs = {} if run is None else {"run": run}
+
     def step(q, tables, lens, *pools):
         for _ in range(LAYERS):
             if kernel == "latent":
                 out = jnp.pad(pa.mla_paged_decode_attention_kernel(
-                    q, *pools, 0, tables, lens, LATENT, 0.07),
+                    q, *pools, 0, tables, lens, LATENT, 0.07, **runs),
                     ((0, 0), (0, 0), (0, ROW - LATENT)))
             elif window:
                 out = pa.paged_window_decode_attention_kernel(
@@ -89,19 +102,22 @@ def pools_of(kernel, lanes, heads, kvh, table):
 
 
 def lanes_at(kernel, lanes, heads, kvh, table, window, length,
-             mixed=False):
+             mixed=False, run=1):
     """(tables, lengths, bytes the algorithm needs a layer): every lane
     `length` long, or `MIXED`'s shares of it in turn, its pages anywhere
-    in the pool."""
+    in the pool: a page at a time or, as the allocator hands them out at
+    `run`, in whole aligned runs of `run` ids behind one another."""
     lens = np.full((lanes,), length, np.int32)
     if mixed:
         lens = (length * np.resize(MIXED, lanes)).astype(np.int32)
     rng = np.random.default_rng(length)
-    free = rng.permutation(lanes * table).astype(np.int32)
+    free = rng.permutation(lanes * table // run).astype(np.int32) * run
     tables = np.full((lanes, table), -1, np.int32)
     for lane, n in enumerate(lens):
-        held = min(-(-int(n) // PAGE), table)
-        tables[lane, :held], free = free[:held], free[held:]
+        runs = -(-min(-(-int(n) // PAGE), table) // run)
+        mine, free = free[:runs], free[runs:]
+        tables[lane, :runs * run] = (mine[:, None]
+                                     + np.arange(run)[None]).reshape(-1)
     live = int((np.minimum(lens, window) if window else lens).sum())
     if kernel == "latent":
         need = paged_decode_call(live, lanes, 1, ROW_HELD // 2,
@@ -164,12 +180,56 @@ def set_block(pages, prefixes):
             *range(piece, block, piece), block)
 
 
+def write(path, rows):
+    if path:
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        with open(path, "w") as f:
+            json.dump(rows, f, indent=1)
+
+
+def by_run(opts):
+    """The latent kernel at each `--run`: ms a layer by length, then the
+    slope of the lanes' time over the pages they walk."""
+    positions = [int(p) for p in opts.positions.split(",")] \
+        if opts.positions else RUN_POSITIONS
+    rows = []
+    for name in opts.shapes.split(","):
+        kernel, lanes, heads, kvh, table, window = SHAPES[name]
+        if kernel != "latent":
+            raise SystemExit(f"--run is the latent kernel's: {name} is not")
+        q, pools = pools_of(kernel, lanes, heads, kvh, table)
+        for run in (int(r) for r in opts.run.split(",")):
+            fn = program(kernel, window, run)
+            mine = []
+            for length in positions:
+                tables, lens, need = lanes_at(*SHAPES[name], length, run=run)
+                ms = timed(fn, q, tables, lens, *pools)
+                mine.append({"shape": name, "run": run, "positions": length,
+                             "pages": lanes * -(-length // PAGE),
+                             "ms_a_layer": ms,
+                             "bytes_share": need / HBM * 1e5 / ms})
+                print(f"{name} run {run} positions {length}: {ms:.4f} ms a "
+                      f"layer, {mine[-1]['bytes_share']:.1f} % of the "
+                      f"bytes' floor", flush=True)
+            rows += mine
+            if len(mine) > 1:
+                ns = 1e6 * float(np.polyfit(
+                    [r["pages"] for r in mine],
+                    [r["ms_a_layer"] for r in mine], 1)[0])
+                rows.append({"shape": name, "run": run, "ns_a_page": ns,
+                             "ns_a_copy": ns * run})
+                print(f"{name} run {run}: {ns:.1f} ns a page, "
+                      f"{ns * run:.1f} a copy", flush=True)
+    return rows
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--sweep", action="store_true")
     ap.add_argument("--blocks", default="8,16,32,64,128",
                     help="pages a block, under --sweep")
-    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--shapes", help="comma-separated, of " + ",".join(
+        SHAPES) + " (all; under --run the latent ones)")
     ap.add_argument("--positions", help="comma-separated lengths of a "
                     "lane, in place of " + ",".join(map(str, POSITIONS)))
     ap.add_argument("--lanes", choices=("same", "mixed"), default="same",
@@ -178,10 +238,17 @@ def main():
     ap.add_argument("--prefixes", choices=("rule", "each"), default="rule")
     ap.add_argument("--heads", type=int, help="heads a turn of the loop "
                     "over a block's heads, in place of HEAD_UNROLL")
+    ap.add_argument("--run", help="pages a copy of the latent kernel's "
+                    "walk, comma-separated: its tables in such runs")
     ap.add_argument("--json", help="write the rows here too")
     opts = ap.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit("needs a TPU")
+    opts.shapes = opts.shapes or ",".join(
+        name for name, shape in SHAPES.items()
+        if not opts.run or shape[0] == "latent")
+    if opts.run:
+        return write(opts.json, by_run(opts))
     blocks = [int(b) for b in opts.blocks.split(",")] if opts.sweep else [
         None]
     positions = [int(p) for p in opts.positions.split(",")] \
@@ -237,10 +304,7 @@ def main():
                       f"{terms['first_block_page_us']:.4f} a page of the "
                       f"first block ({terms['first_block_us']:.2f} when "
                       f"it is full)", flush=True)
-    if opts.json:
-        os.makedirs(os.path.dirname(opts.json) or ".", exist_ok=True)
-        with open(opts.json, "w") as f:
-            json.dump(rows, f, indent=1)
+    write(opts.json, rows)
 
 
 if __name__ == "__main__":

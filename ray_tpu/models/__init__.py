@@ -44,6 +44,8 @@ from ray_tpu.models.gated_conv_moe import (  # noqa: F401,E402
     GatedConvMoE, GatedConvMoEConfig)
 from ray_tpu.models.sparse_mla_moe import (  # noqa: F401,E402
     SparseMLAMoE, SparseMLAMoEConfig)
+from ray_tpu.models.sparse_window_mla_moe import (  # noqa: F401,E402
+    SparseWindowMLAMoE, SparseWindowMLAMoEConfig)
 
 
 # name -> (config class, model class). A dict of config fields names its
@@ -57,7 +59,9 @@ MODELS = {"transformer": (TransformerConfig, Transformer),
           "hybrid_kda_moe": (HybridKDAMoEConfig, HybridKDAMoE),
           "parallel_hybrid": (ParallelHybridConfig, ParallelHybrid),
           "gated_conv_moe": (GatedConvMoEConfig, GatedConvMoE),
-          "sparse_mla_moe": (SparseMLAMoEConfig, SparseMLAMoE)}
+          "sparse_mla_moe": (SparseMLAMoEConfig, SparseMLAMoE),
+          "sparse_window_mla_moe": (SparseWindowMLAMoEConfig,
+                                    SparseWindowMLAMoE)}
 
 
 def model_config(model):

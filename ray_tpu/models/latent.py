@@ -40,8 +40,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models import regions as R
 from ray_tpu.models.config import ConfigDtypes
-from ray_tpu.models.paged import (PAGED, Cache, Mixer, Params, Pool,
-                                  Walk)
+from ray_tpu.models.paged import (PAGED, RING, Cache, Mixer, Params,
+                                  Pool, Walk)
 from ray_tpu.ops import paged_attention as _paged
 from ray_tpu.ops.attention import flash_attention
 from ray_tpu.ops.norms import rms_norm_reference
@@ -60,6 +60,9 @@ from ray_tpu.ops.rope import apply_rope_cached, rope_cos_sin
 # compiles (192 / 128: 57.0 ms against 55.5 at 16,384 and 64 heads) and past
 # the kernel's fast memory at 256 / 256 from 8192 on
 PREFILL_BLOCKS = (1024, 1024)
+# a windowed prefill's flash blocks (block_q, block_k): `models/gqa.py`'s
+# `SLIDING_BLOCKS`, a query block of 512 reaching two key blocks of 1024
+WINDOW_PREFILL_BLOCKS = (512, 1024)
 
 
 class LatentDims(ConfigDtypes):
@@ -92,6 +95,9 @@ class LatentAttention(Mixer):
 
     closes = R.ATTN_OUT
     batched = True
+    # a sliding window's positions (`WindowLatentAttention`); None: a query
+    # sees every causal position
+    window = None
 
     def __init__(self, config):
         self.config, self.dtype = config, config.activation_dtype
@@ -226,13 +232,25 @@ class LatentAttention(Mixer):
                     k_rope[:, :, None, :], (b, s, c.n_heads,
                                             c.qk_rope_head_dim))], axis=-1)
             v = kv[..., nope:]
+            if self.window:
+                # the windowed flash forward takes values as wide as its
+                # keys: padded with zeros, the output cut back
+                v = jnp.pad(v, ((0, 0),) * 3 + (
+                    (0, max(0, c.qk_head_dim - c.v_head_dim)),))
             qt, kt, vt = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
         block_q, block_k = PREFILL_BLOCKS
+        window = {}
+        if self.window:
+            block_q, block_k = WINDOW_PREFILL_BLOCKS
+            window = {"window": self.window}
         with R.region(R.ATTN_CORE):
             out = flash_attention(qt, kt, vt, causal=True,
                                   sm_scale=1.0 / math.sqrt(c.qk_head_dim),
-                                  block_q=block_q, block_k=block_k)
+                                  block_q=block_q, block_k=block_k,
+                                  **window)
         with R.region(R.ATTN_OUT):
+            if self.window:
+                out = out[..., :c.v_head_dim]
             out = out.transpose(0, 2, 1, 3)
         out = self._gated(layer, h, out)
         return out, c_kv, k_rope
@@ -274,13 +292,18 @@ class LatentAttention(Mixer):
             q_row = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
                             ((0, 0), (0, 0), (0, pad))).astype(pool.dtype)
         with R.region(R.ATTN_CORE):
-            o_lat = _paged.mla_paged_decode_attention(
-                q_row, pool, row, page_tables, lengths, latent, sm_scale,
-                run)
+            o_lat = self._walk(q_row, pool, row, page_tables, lengths,
+                               latent, sm_scale, run)
         with R.region(R.ATTN_OUT):
             out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
                              w_kvb[..., nope:])
         return self._gated(layer, h, out), pool
+
+    def _walk(self, q_row, pool, row: int, page_tables, lengths,
+              latent: int, sm_scale: float, run: int):
+        """The lanes' rows of the latent over what their tables hold."""
+        return _paged.mla_paged_decode_attention(
+            q_row, pool, row, page_tables, lengths, latent, sm_scale, run)
 
     # ------------------------------------------------------- forwards
     def _prompt(self, layer: Params, h, pools: Cache, li: int, at: Walk):
@@ -288,9 +311,11 @@ class LatentAttention(Mixer):
         whole pages in place. Returns (the attention's output before W_o,
         the pools written)."""
         attn, c_kv, k_rope = self._attn_expanded(layer, h, *at.tables[self])
-        pool = pools["kv"]
-        return attn, {"kv": self._write_pages(
-            pool, li, c_kv[0], k_rope[0], at.pages[PAGED], pool.shape[2])}
+        kept = self.pools[0]            # a ring's pages: the last it holds
+        pool = pools[kept.name]
+        return attn, {kept.name: self._write_pages(
+            pool, li, c_kv[0], k_rope[0], at.pages[kept.kind],
+            pool.shape[2])}
 
     def _lanes(self, layer: Params, h, pools: Cache, li: int, at: Walk):
         """A decode step's lanes h (B, e) in the absorbed form. Returns
@@ -319,3 +344,68 @@ class LatentAttention(Mixer):
         out, written = self._lanes(layer, h, pools, li, at)
         with R.region(R.ATTN_OUT):
             return out @ layer["wo"].astype(self.dtype), written
+
+
+# what a decode step's ring walks did, summed over the ring's layers: the
+# positions they copied in (whole pages from the first the window reaches)
+# and the positions the lanes saw of them (at most `window` a lane and layer)
+RING_COUNTS = ("ring_positions_read", "ring_positions_seen")
+
+
+class WindowLatentAttention(LatentAttention):
+    """`LatentAttention` under a sliding window: a query sees its last
+    `window` positions, itself among them, and the cache keeps a sequence's
+    last rows only, in a ring of the allocator's fixed class
+    (`ops.paged_attention.ring_pages(window)` pages a sequence, logical page
+    j at table entry `j mod ring`) under the pool `pool`. `config` is the
+    layer kind's own `LatentDims` (a class may hold two geometries). A
+    prefill is the windowed flash forward in the expanded form and writes
+    the last pages the ring holds; a decode step the absorbed form through
+    `mla_paged_window_decode_attention`. A lane shorter than the window
+    reads what `LatentAttention` reads of the same rows. What a step's
+    walks did is summed in `"ring_step"` (`RING_COUNTS`)."""
+
+    counts = ("ring_step", RING_COUNTS)
+
+    def __init__(self, config, window: int, pool: str = "kv_w"):
+        self.config, self.dtype = config, config.activation_dtype
+        self.window = int(window)
+        self.pools = (Pool(pool, RING, (config.row_width,),
+                           window=self.window, an_attention=True),)
+
+    def decode_kernel(self, page_size: int, dtype) -> str:
+        """The latent window kernel's name, or "einsum"."""
+        c = self.config
+        if _paged.mla_uses_kernel(c.row_width, c.kv_lora_rank, page_size,
+                                  dtype):
+            return _paged.KERNEL_MLA_PAGED_WINDOW_DECODE
+        return "einsum"
+
+    def page_run(self, page_size: int, max_pages: int) -> int:
+        """A ring's walk begins at any entry: a page a copy."""
+        return 1
+
+    def _walk(self, q_row, pool, row: int, ring_tables, lengths,
+              latent: int, sm_scale: float, run: int):
+        """The lanes' rows over their rings: `ring_tables` (B, ring) the
+        lanes' first table entries."""
+        return _paged.mla_paged_window_decode_attention(
+            q_row, pool, row, ring_tables, lengths, latent, sm_scale,
+            self.window)
+
+    def _lanes(self, layer: Params, h, pools: Cache, li: int, at: Walk):
+        """A decode step's lanes over their rings; what the walk copied in
+        and what the lanes saw of it added to the step's counts."""
+        name = self.pools[0].name
+        page, tables = at.pages[RING]
+        out, pool = self._attn_absorbed(
+            layer, h, *at.tables[self], pools[name], li, page, at.offset,
+            tables, at.lengths)
+        with R.region(R.CACHE):
+            size, n = pool.shape[2], at.lengths
+            first = jnp.maximum(n - self.window, 0) // size
+            counts = (jnp.sum((-(-n // size) - first) * size),
+                      jnp.sum(jnp.minimum(n, self.window)))
+            step = {key: pools["ring_step"][key] + c.astype(jnp.int32)
+                    for key, c in zip(RING_COUNTS, counts)}
+        return out, {name: pool, "ring_step": step}

@@ -166,6 +166,8 @@ class SparseLatentAttention(LatentAttention):
         ad = c.activation_dtype
         c_q = rms_norm_reference(h @ layer["wq_a"].astype(ad),
                                  layer["q_norm"], c.norm_eps)
+        if c.q_lora_scale != 1.0:       # the indexer reads it scaled too
+            c_q = c_q * jnp.asarray(c.q_lora_scale, ad)
         q = c_q @ layer["wq_b"].astype(ad)
         return c_q, q.reshape(*h.shape[:-1], c.n_heads, c.qk_head_dim)
 

@@ -915,3 +915,102 @@ def mla_paged_decode_attention_kernel(q, pool, layer, page_tables, lengths,
     return _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
                                   latent, float(sm_scale), not on_tpu(),
                                   run=run)
+
+
+# ------------------------------------- the latent pool in a ring (a window)
+#
+# A latent layer with a sliding window keeps a lane's last rows only, in a
+# ring of the fixed class's pages, as a per-head window layer keeps its keys
+# and values: the latent kernel's matmuls on the walk that begins at the
+# first page the window reaches, the table wrapped, positions below the
+# window masked. Its own name, so that a trace tells a ring's walks from
+# the walks over a pool that grows.
+KERNEL_MLA_PAGED_WINDOW_DECODE = "mla_paged_window_decode_attn"
+
+
+def mla_paged_window_attention_reference(q, pool, layer, ring_tables,
+                                         lengths, latent: int,
+                                         sm_scale: float, window: int):
+    """Gather every ring entry, work out which position it holds, mask,
+    softmax in float32.
+
+    q (B, heads, width); pool (layers, pages, page, width); ring_tables
+    (B, ring) int32, -1 unassigned: logical page j of a lane lies at entry
+    j mod ring, the newest such j winning; lengths (B,): a lane sees
+    positions length - window .. length - 1. Returns (B, heads, latent) in
+    q's dtype; a lane that sees nothing gets zeros."""
+    B = q.shape[0]
+    num_pages, page, width = pool.shape[1:]
+    ring = ring_tables.shape[1]
+    last = (lengths[:, None] - 1) // page                      # (B, 1)
+    logical = last - (last - jnp.arange(ring)[None, :]) % ring  # (B, ring)
+    pos = (jnp.repeat(logical, page, axis=1) * page
+           + jnp.tile(jnp.arange(page), ring)[None, :])
+    mask = ((pos >= 0) & (pos < lengths[:, None])
+            & (pos >= lengths[:, None] - window)
+            & jnp.repeat(ring_tables >= 0, page, axis=1))
+    pt = jnp.clip(ring_tables, 0, num_pages - 1)
+    rows = pool[layer][pt].reshape(B, ring * page, width).astype(
+        jnp.float32)
+    scores = jnp.einsum("bhw,bsw->bhs", q.astype(jnp.float32),
+                        rows) * sm_scale
+    scores = jnp.where(mask[:, None, :], scores,
+                       jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhs,bsc->bhc", probs, rows[..., :latent])
+    out = jnp.where(mask.any(axis=1)[:, None, None], out, 0.0)
+    return out.astype(q.dtype)
+
+
+# jitted for the reason `_paged_decode_call` is: traced once a program
+@functools.partial(jax.jit, static_argnames=("latent", "sm_scale", "window",
+                                             "interpret"))
+def _mla_paged_window_decode_call(q, pool, layer, ring_tables, lengths,
+                                  latent: int, sm_scale: float, window: int,
+                                  interpret: bool):
+    heads, width = q.shape[1:]
+    page_size = pool.shape[2]
+    if not mla_paged_decode_tiles(width, latent, page_size, pool.dtype):
+        raise ValueError(
+            f"the latent window kernel does not tile rows of {width} "
+            f"(value part {latent}) in {page_size}-position pages of "
+            f"{pool.dtype}")
+    if ring_tables.shape[1] < ring_pages(window, page_size):
+        raise ValueError(
+            f"a window of {window} needs a ring of "
+            f"{ring_pages(window, page_size)} pages, the table has "
+            f"{ring_tables.shape[1]}")
+    # the heads are a matmul's rows: whole sublanes of them
+    sublanes = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
+    hp = -(-heads // sublanes) * sublanes
+    qp = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
+    kernel = functools.partial(_mla_paged_decode_kernel, sm_scale=sm_scale,
+                               latent=latent)
+    out = _paged_pallas_call(
+        kernel, KERNEL_MLA_PAGED_WINDOW_DECODE, qp, (pool,), layer,
+        ring_tables, lengths, out_width=latent, sems=(BLOCK_SLOTS,),
+        interpret=interpret, window=window)
+    return out[:, :heads]
+
+
+def mla_paged_window_decode_attention(q, pool, layer, ring_tables, lengths,
+                                      latent: int, sm_scale: float,
+                                      window: int):
+    """Dispatching entry point of a window layer's latent decode attention:
+    the compiled kernel on a TPU where the shapes tile, the gather + einsum
+    reference elsewhere. Shapes as `mla_paged_window_attention_reference`."""
+    if mla_uses_kernel(q.shape[-1], latent, pool.shape[2], pool.dtype):
+        return _mla_paged_window_decode_call(
+            q, pool, layer, ring_tables, lengths, latent, float(sm_scale),
+            int(window), False)
+    return mla_paged_window_attention_reference(
+        q, pool, layer, ring_tables, lengths, latent, sm_scale, window)
+
+
+def mla_paged_window_decode_attention_kernel(q, pool, layer, ring_tables,
+                                             lengths, latent: int,
+                                             sm_scale: float, window: int):
+    """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
+    return _mla_paged_window_decode_call(
+        q, pool, layer, ring_tables, lengths, latent, float(sm_scale),
+        int(window), not on_tpu())

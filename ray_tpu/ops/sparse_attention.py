@@ -68,8 +68,13 @@ KERNEL_PAGED_ATTEND = "dsa_paged_attend"
 # Queries whose scores exist together while their sets are chosen: a block
 # of (SELECT_ROWS, s) float32, 134 MB at 16,384 keys
 SELECT_ROWS = 2048
-# tiles of the index-score kernel (queries, keys)
+# tiles of the index-score kernel (queries, keys) up to INDEX_HEADS index
+# heads; the kernel unrolls a tile's heads, and at 64 of them a tile of
+# 512 queries asked for 100.5 MB of the 96 its fast memory has (compiled
+# for v5e without a chip, PR 65): past INDEX_HEADS the query block shrinks
+# in their ratio
 INDEX_BLOCKS = (512, 1024)
+INDEX_HEADS = 32
 # blocks of the masked flash forward (queries, keys): the latent classes'
 # (`models.latent.PREFILL_BLOCKS`) in queries, half in keys, so that the
 # mask's tile fits beside the keys and values of 256
@@ -461,7 +466,9 @@ def _index_scores_call(q_idx, w, k_idx, first_row, interpret: bool):
     anything."""
     rows, heads, width = q_idx.shape
     s = k_idx.shape[0]
-    block_q, block_k = min(INDEX_BLOCKS[0], rows), min(INDEX_BLOCKS[1], s)
+    block_q = min(INDEX_BLOCKS[0] * INDEX_HEADS // max(heads, INDEX_HEADS),
+                  rows)
+    block_k = min(INDEX_BLOCKS[1], s)
     if rows % block_q or s % block_k:
         raise ValueError(f"{rows} queries and {s} keys are not whole "
                          f"tiles of {block_q} x {block_k}")
@@ -636,10 +643,12 @@ def _masked_flash_call(q, k, v, keep, sm_scale: float, interpret: bool):
 
 def masked_flash_tiles(s: int, d: int, dv: int) -> bool:
     """Whether the masked flash forward tiles these shapes: whole blocks
-    of positions, heads of whole 128-lanes."""
+    of positions, values of whole 128-lanes, keys of whole halves (a key
+    of 192 is a block's whole last dimension, as the flash forward takes
+    it)."""
     return (s % min(FLASH_BLOCKS[0], s) == 0
             and s % min(FLASH_BLOCKS[1], s) == 0 and s % 128 == 0
-            and d % 128 == 0 and dv % 128 == 0)
+            and d % 64 == 0 and dv % 128 == 0)
 
 
 def masked_flash_attention(q, k, v, keep, sm_scale: float):

@@ -149,7 +149,7 @@ def test_metric_has_a_reader_and_lists_cells_that_exist(entry):
 
 
 def test_a_fifth_of_the_cells_may_take_four_chips_and_none_does():
-    assert len(CELLS) == 12 and not [c for c in CELLS if c["chips"] != 1]
+    assert len(CELLS) == 13 and not [c for c in CELLS if c["chips"] != 1]
     assert len({(c["config"], c["traffic"]) for c in CELLS}) == len(CELLS)
 
 
@@ -516,3 +516,101 @@ def test_dsa_metrics_leave_the_line_where_there_is_nothing_to_read(
     run = dict(traced_dsa_run)
     run.update(trace=None, _spans=None, _op_scopes=None)
     assert metric(name)(run) is None
+
+
+# --------------------------------------------- PR 65's metric readers
+DOTS3 = "dots3-note-prev-1chip"
+RING_METRICS = [
+    "kernel.mla_window_decode_roofline.notes12k",
+    "step.attn_window_latent_ms.notes12k", "cache.ring_read_share.notes12k",
+    "dsa.dense_lanes_share.notes12k"]
+
+
+@pytest.fixture()
+def traced_ring_run():
+    """Two decode steps of 10 ms from t = 0 and t = 0.02: in each, three
+    ring kernels of 0.1 ms (the sliding layers'); 32 lanes of which 8 are
+    under `index_topk`, 30 past the window and 2 of 100 positions."""
+    cfg = modelcfg.load_config(DOTS3)
+    model = modelcfg.load_model(cfg)
+    ops, modules, steps = [], [], []
+    seen = 30 * 513 + 2 * 100
+    read = 30 * 544 + 2 * 112
+    for t0 in (0.0, 0.02):
+        modules.append(E("jit__step(7)", t0, 0.010))
+        ops += [_kernel("mla_paged_window_decode_attn", j,
+                        t0 + 2e-3 * (j + 1), 0.1e-3) for j in range(3)]
+        steps.append(E(spans.DISPATCH, t0, 1e-4, {
+            "lanes": 32, "live_positions": 160000, "read_positions": 160256}))
+        steps.append(E("engine.emit", t0 + 0.011, 1e-4, {
+            "moe_pairs": 128, "ring_positions_read": 3 * read,
+            "ring_positions_seen": 3 * seen, "dsa_lanes_past_topk": 2 * 24,
+            "dsa_positions_scored": 2 * 160000,
+            "dsa_positions_selected": 2 * 60000}))
+    return {"trace": xplane.Trace({0: modules}, {0: ops}, {}, {}),
+            "model": model, "sizes": model.sizes(cfg), "cfg": cfg,
+            "peaks": PEAKS["TPU v5 lite"], "result": {"traced": {}},
+            "_spans": spans.Reading(steps, {}, 0.0)}, seen, read
+
+
+def test_ring_metrics_read_the_kernel_and_the_counts(traced_ring_run):
+    run, seen, read = traced_ring_run
+    model, sz = run["model"], run["sizes"]
+    need = model.window_latent_decode_call(sz, 2 * 3 * seen, 2 * 3 * 32)
+    # 1,088 numbers a position of the window and sliding layer, once; 64
+    # queries of 1,088 in and latents of 1,024 out a lane and layer
+    assert need["bytes"] == 2 * (6 * seen * 1088 + 6 * 32 * 64 * (1088
+                                                                  + 1024))
+    assert need["flops"] == 2.0 * 64 * (1088 + 1024) * 6 * seen
+    want = 100 * (need["bytes"] / 819e9) / (6 * 0.1e-3)
+    assert metric("kernel.mla_window_decode_roofline.notes12k")(run) == \
+        pytest.approx(want, rel=1e-6)
+    assert 0 < want < 100
+    # whole pages and a row's padding are the program's: never above 100
+    assert need["bytes"] < 6 * read * 1152 * 2 + 6 * 32 * 64 * 2112 * 2
+    assert metric("step.attn_window_latent_ms.notes12k")(run) == \
+        pytest.approx(0.3)
+    assert metric("cache.ring_read_share.notes12k")(run) == pytest.approx(
+        100 * 3 * read / (3 * read + 2 * 160256))
+    assert metric("dsa.dense_lanes_share.notes12k")(run) == pytest.approx(
+        100 * 8 / 32)
+    # the cell's other readers find their counts beside the ring's
+    assert metric("dsa.selected_share.code16k")(run) == pytest.approx(37.5)
+    assert metric("cache.index_bytes_share.code16k")(run) == pytest.approx(
+        100 * 128 / 768)
+
+
+@pytest.mark.parametrize("name", RING_METRICS)
+def test_ring_metrics_leave_the_line_where_there_is_nothing_to_read(
+        traced_ring_run, traced_dsa_run, name):
+    """A program without a ring of latents (no such kernel, no such count:
+    every other class, and the parent of PR 65), an untraced run: None, and
+    nothing raised."""
+    assert metric(name)(traced_dsa_run) is None
+    run = dict(traced_ring_run[0])
+    run.update(trace=None, _spans=None)
+    assert metric(name)(run) is None
+
+
+def test_dots3_required_operations_follow_the_two_geometries():
+    cfg = modelcfg.load_config(DOTS3)
+    model = modelcfg.load_model(cfg)
+    sz = model.sizes(cfg)
+    assert (sz.of_kind("full_attention"), sz.of_kind("sliding_attention"),
+            sz.of_kind("E")) == ((0, 1), (2, 3, 4), (1, 2, 3, 4))
+    assert (sz.full.cache_row, sz.sliding.cache_row) == (576, 1088)
+    assert model.param_count(sz) == cfg["parameters"] == 4087154176
+    full = model.flash_prefill_call(sz, 5000, "full_attention")
+    chosen = 2048 * 2049 / 2 + (5000 - 2048) * 2048
+    assert full["flops"] == 2 * 2.0 * 128 * (192 + 128) * chosen
+    window = model.flash_window_call(sz, 5000)
+    inside = 513 * 514 / 2 + (5000 - 513) * 513
+    assert window["flops"] == 3 * 2.0 * 64 * (256 + 128) * inside
+    both = model.dsa_prefill_call(sz, 5000)
+    assert both["flops"] == (2 * 2.0 * 64 * 128 * 5000 * 5001 / 2
+                             + full["flops"] + window["flops"])
+    # the reader hands lanes x all five layers: the two full ones' share
+    need = model.dsa_attend_call(sz, 1000, 5 * 32)
+    assert need["bytes"] == 1000 * 1152 + 2 * 32 * 128 * (576 + 512) * 2
+    assert model.dsa_index_call(sz, 1000, 32)["flops"] == \
+        2 * 2.0 * 64 * 128 * 1000

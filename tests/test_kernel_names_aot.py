@@ -573,6 +573,56 @@ def test_sparse_mla_moe_programs_hold_their_kernels_by_name(
     assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
 
 
+# ------------------------- the class of two latent geometries and a ring
+@pytest.mark.parametrize("which", ["step", "prefill"])
+def test_sparse_window_mla_moe_programs_hold_their_kernels_by_name(
+        which, topo, no_compile_cache):
+    """`SparseWindowMLAMoE`'s decode step or 4096-token prefill: a full
+    expert layer and a sliding one at the published widths of
+    dots3-note-prev (128 heads over a latent of 512 + 64 under 64 index
+    heads of 128; 64 heads over a latent of 1024 + 64 in a ring of 513: rows
+    of 1,152 with a value part of 1,024, 34 pages of 16; 32 of 256 experts
+    of 5120 x 1536). The ring's kernel has its own name; the index-score
+    tile at 64 heads and the masked flash forward at keys of 192 are shapes
+    only this class asks for."""
+    from ray_tpu.models.sparse_window_mla_moe import (
+        FULL, SLIDING, SparseWindowMLAMoE, SparseWindowMLAMoEConfig)
+    from ray_tpu.ops import grouped_matmul, sparse_attention
+    model = SparseWindowMLAMoE(SparseWindowMLAMoEConfig(
+        vocab_size=1024, n_layers=2, layer_types=(FULL, SLIDING),
+        first_k_dense_replace=0, experts_held=(0, 32), max_seq_len=4096))
+    ring = 32 * model.fixed_pages(PAGE)
+    assert model.fixed_pages(PAGE) == 34
+    compiled, cache = _compile_served(
+        topo.devices, model, which,
+        lambda: model.init_cache(PAGES, PAGE, fixed_pages=ring),
+        sparse_attention.KERNEL_PAGED_ATTEND + "+"
+        + paged_attention.KERNEL_MLA_PAGED_WINDOW_DECODE)
+    names = kernel_names(compiled.as_text())
+    assert names.count(grouped_matmul.KERNEL_GMM) == 6
+    assert paged_attention.KERNEL_MLA_PAGED_DECODE not in names
+    assert paged_attention.KERNEL_PAGED_WINDOW_DECODE not in names
+    if which == "step":     # a fixed page: a page a copy (PERF.md 7)
+        with compute_platform("tpu"):
+            assert model.page_run(PAGE, 4096 // PAGE) == 1
+        assert names.count(sparse_attention.KERNEL_PAGED_INDEX) == 1
+        assert names.count(sparse_attention.KERNEL_PAGED_ATTEND) == 1
+        assert names.count(
+            paged_attention.KERNEL_MLA_PAGED_WINDOW_DECODE) == 1
+        assert attention.KERNEL_WINDOW_FWD not in names
+    else:
+        assert names.count(sparse_attention.KERNEL_INDEX_SCORES) == 1
+        assert names.count(sparse_attention.KERNEL_FLASH_FWD) == 1
+        assert names.count(attention.KERNEL_WINDOW_FWD) == 1
+        assert paged_attention.KERNEL_MLA_PAGED_WINDOW_DECODE not in names
+    # the ring beside the two pools under one page id, all updated in place
+    assert cache["kv"].shape == (1, PAGES, PAGE, 640)
+    assert cache["idx"].shape == (1, PAGES, PAGE, 128)
+    assert cache["kv_w"].shape == (1, ring, PAGE, 1152)
+    nbytes = sum(2 * cache[name].size for name in ("kv", "idx", "kv_w"))
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+
+
 # -------------------------------------- the sixth architecture's step
 def _compile_hybrid_ssm_moe(devices, which: str, slots: int = 32):
     """`HybridSSMMoE`'s decode step or 4096-token prefill: one layer of

@@ -449,6 +449,8 @@ def _tiny_served():
     from ray_tpu.models.parallel_hybrid import tiny_parallel_hybrid
     from ray_tpu.models.shortcut_mla_moe import tiny_shortcut_mla_moe
     from ray_tpu.models.sparse_mla_moe import tiny_sparse_mla_moe
+    from ray_tpu.models.sparse_window_mla_moe import (
+        tiny_sparse_window_mla_moe)
     return {"MLAMoE": tiny_mla_moe, "GQAWindowMoE": tiny_gqa_window_moe,
             "HybridDelta": tiny_hybrid_delta,
             "ShortcutMLAMoE": tiny_shortcut_mla_moe,
@@ -456,7 +458,8 @@ def _tiny_served():
             "HybridKDAMoE": tiny_hybrid_kda_moe,
             "ParallelHybrid": tiny_parallel_hybrid,
             "GatedConvMoE": tiny_gated_conv_moe,
-            "SparseMLAMoE": tiny_sparse_mla_moe}
+            "SparseMLAMoE": tiny_sparse_mla_moe,
+            "SparseWindowMLAMoE": tiny_sparse_window_mla_moe}
 
 
 def _tree_sha256(tree) -> str:
@@ -517,7 +520,8 @@ def _tiny_models():
                                   "hybrid_delta", "shortcut_mla_moe",
                                   "hybrid_ssm_moe", "hybrid_kda_moe",
                                   "parallel_hybrid", "gated_conv_moe",
-                                  "sparse_mla_moe"])
+                                  "sparse_mla_moe",
+                                  "sparse_window_mla_moe"])
 def test_every_class_answers_the_engines_thirteen_asks(name):
     """What `EngineCore` calls on a model, on every class of the table,
     with the types it uses them as (`models.paged.PagedDecoder`)."""

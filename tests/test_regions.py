@@ -29,7 +29,12 @@ TINY = {"transformer": C.tiny,
         "gated_conv_moe": M.gated_conv_moe.tiny_gated_conv_moe,
         # (a choice of 16: the 32-token prompt and the tables pass it)
         "sparse_mla_moe": lambda: M.sparse_mla_moe.tiny_sparse_mla_moe(
-            index_topk=16)}
+            index_topk=16),
+        # (and a window of 21: the prompt and the tables pass that too; the
+        # ring's counts, `latent.RING_COUNTS`, are sums under `r.cache`)
+        "sparse_window_mla_moe": lambda: (
+            M.sparse_window_mla_moe.tiny_sparse_window_mla_moe(
+                index_topk=16))}
 assert set(TINY) == set(M.MODELS)
 
 ALWAYS = {R.EMBED, R.NORM, R.ATTN_IN, R.ATTN_CORE, R.ATTN_OUT, R.FFN, R.HEAD}
@@ -42,7 +47,8 @@ SHOWS = {"transformer": ALWAYS, "mla_moe": ALWAYS | EXPERTS,
          "hybrid_kda_moe": ALWAYS | MIXER | EXPERTS,
          "parallel_hybrid": ALWAYS | MIXER,
          "gated_conv_moe": ALWAYS | MIXER | EXPERTS,
-         "sparse_mla_moe": ALWAYS | EXPERTS | {R.ATTN_INDEX}}
+         "sparse_mla_moe": ALWAYS | EXPERTS | {R.ATTN_INDEX},
+         "sparse_window_mla_moe": ALWAYS | EXPERTS | {R.ATTN_INDEX}}
 
 PAGE, LANES, PROMPT, TABLE = 16, 4, 32, 8
 # the instructions the rule is about ("custom-call": a Pallas kernel, and

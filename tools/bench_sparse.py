@@ -6,6 +6,7 @@ the chip, alone, at GLM-5's widths (32 lanes, 64 heads over latent rows of
     chiprun -- python tools/bench_sparse.py --only decode --lengths 3000,6000
     chiprun -- python tools/bench_sparse.py --only prefill --buckets 4096
     chiprun -- python tools/bench_sparse.py --only decode --run 1,4,8,16
+    chiprun -- python tools/bench_sparse.py --only decode --widths dots3
 
 A decode step's three pieces a layer, each both ways (the index scores as
 a gather of every table entry and as the walk over live pages, the choice
@@ -46,6 +47,9 @@ LANES, HEADS, ROW, LATENT = 32, 64, 640, 512
 IDX_HEADS, IDX_DIM, TOPK = 32, 128, 2048
 QK, V = 256, 256
 SCALE = QK ** -0.5
+# (heads, index heads, a prefill's key width, its value width) by `--widths`:
+# GLM-5's, and dots3-note-prev's full layers' (the same rows of 640)
+WIDTHS = {"glm-5": (HEADS, IDX_HEADS, QK, V), "dots3": (128, 64, 192, 128)}
 
 
 def timed(fn, *args, reps=5):
@@ -219,7 +223,12 @@ def main():
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--run", default="1",
                     help="pages a copy of the two walks, comma-separated")
+    ap.add_argument("--widths", choices=sorted(WIDTHS), default="glm-5",
+                    help="whose heads and head widths")
     a = ap.parse_args()
+    global HEADS, IDX_HEADS, QK, V, SCALE
+    HEADS, IDX_HEADS, QK, V = WIDTHS[a.widths]
+    SCALE = QK ** -0.5
     if jax.default_backend() != "tpu":
         sys.exit("bench_sparse.py measures the chip; there is none here")
     out = {}

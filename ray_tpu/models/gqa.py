@@ -72,6 +72,21 @@ class Attention(Mixer):
         return (_paged.KERNEL_PAGED_DECODE if self.window is None
                 else _paged.KERNEL_PAGED_WINDOW_DECODE)
 
+    def page_run(self, page_size: int, max_pages: int,
+                 fixed: int = 0) -> int:
+        """Pages one copy of the walk over its two pools brings: by what
+        one layer's page of one pool weighs
+        (`ops.paged_attention.decode_walk_run_pages`: 16 KB asks for 4, 8
+        KB for 8, 32 KB and more for a page a copy); 1 over a ring, whose
+        walk begins at any entry, and where a step traced here runs no
+        kernel (the gather reads any table)."""
+        if (self.window is not None
+                or self.decode_kernel(page_size, self.dtype) == "einsum"):
+            return 1
+        page = self.pools[0].bytes(self.dtype, page_size)
+        return _paged.decode_walk_run_pages(
+            page, len(self.pools) * page, page_size, max_pages, fixed)
+
     # --------------------------------------------------------- pieces
     @R.region(R.ATTN_IN)
     def _qkv(self, layer: Params, h, at: Walk):
@@ -126,13 +141,15 @@ class Attention(Mixer):
         return out
 
     def _attend(self, pools: Cache, li: int, page, offset, q, k, v,
-                page_tables, lengths):
+                page_tables, lengths, run: int = 1, fixed: int = 0):
         """One decode position a lane: its k, v (B, kv heads, hd) written
         at `(li, page, offset)` of the pools (a page past the pool writes
         nothing), then q (B, heads, hd) over the `lengths` positions the
         lane's table holds: every one, or under a window the last `window`
-        in the ring `page_tables` names. Returns (out (B, heads, hd) in the
-        pools' dtype, the two pools)."""
+        in the ring `page_tables` names; `run`, `fixed`: the runs the
+        tables are laid in behind their fixed entries (`Walk.run`,
+        `Walk.fixed`; a ring's walk takes neither). Returns (out (B, heads,
+        hd) in the pools' dtype, the two pools)."""
         B = q.shape[0]
         with R.region(R.ATTN_IN):
             out = {pool.name: pools[pool.name].at[li, page, offset].set(
@@ -143,7 +160,8 @@ class Attention(Mixer):
         with R.region(R.ATTN_CORE):
             if self.window is None:
                 return _paged.paged_decode_attention(
-                    q, k_pool, v_pool, li, page_tables, lengths), out
+                    q, k_pool, v_pool, li, page_tables, lengths, run=run,
+                    fixed=fixed), out
             return _paged.paged_window_decode_attention(
                 q, k_pool, v_pool, li, page_tables, lengths,
                 self.window), out
@@ -164,5 +182,5 @@ class Attention(Mixer):
         q, k, v = self._qkv(layer, h, at)
         page, tables = at.pages[self.kind]
         out, written = self._attend(pools, li, page, at.offset, q, k, v,
-                                    tables, at.lengths)
+                                    tables, at.lengths, at.run, at.fixed)
         return self._out(layer, h, out), written

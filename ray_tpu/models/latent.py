@@ -135,7 +135,8 @@ class LatentAttention(Mixer):
             return _paged.KERNEL_MLA_PAGED_DECODE
         return "einsum"
 
-    def page_run(self, page_size: int, max_pages: int) -> int:
+    def page_run(self, page_size: int, max_pages: int,
+                 fixed: int = 0) -> int:
         """Pages one copy of the latent kernel's walk brings: by what one
         layer's page of the pool weighs (`mla_walk_run_pages`: 20 KB of
         rows is under what a descriptor costs the scalar core); 1 where a
@@ -143,7 +144,8 @@ class LatentAttention(Mixer):
         if self.decode_kernel(page_size, self.dtype) == "einsum":
             return 1
         return _paged.mla_walk_run_pages(
-            self.pools[0].bytes(self.dtype, page_size), page_size, max_pages)
+            self.pools[0].bytes(self.dtype, page_size), page_size, max_pages,
+            fixed)
 
     def open(self, at: Walk) -> None:
         """The rotary part's cos and sin of the program's positions."""
@@ -268,13 +270,15 @@ class LatentAttention(Mixer):
             rows.reshape(n, page_size, self.config.row_width), mode="drop")
 
     def _attn_absorbed(self, layer: Params, h, cos, sin, pool, row: int,
-                       wr_page, wr_slot, page_tables, lengths, run: int = 1):
+                       wr_page, wr_slot, page_tables, lengths, run: int = 1,
+                       fixed: int = 0):
         """One decode position a lane in the absorbed form: `q_lat = q_nope
         W_UK^T`, scores `q_lat . c_kv + q_rope . k_rope`, `o_lat = P c_kv`,
         `o = o_lat W_UV`, over pool row `row`, which first gets this
-        position's row (`wr_page` of `num_pages` writes nothing); `run`:
-        the runs the tables are laid in (`Walk.run`). h (B, e). Returns
-        (attention output before W_o (B, heads * v), pool)."""
+        position's row (`wr_page` of `num_pages` writes nothing); `run`,
+        `fixed`: the runs the tables are laid in behind their fixed entries
+        (`Walk.run`, `Walk.fixed`). h (B, e). Returns (attention output
+        before W_o (B, heads * v), pool)."""
         c = self.config
         ad = c.activation_dtype
         nope, latent = c.qk_nope_head_dim, c.kv_lora_rank
@@ -293,17 +297,18 @@ class LatentAttention(Mixer):
                             ((0, 0), (0, 0), (0, pad))).astype(pool.dtype)
         with R.region(R.ATTN_CORE):
             o_lat = self._walk(q_row, pool, row, page_tables, lengths,
-                               latent, sm_scale, run)
+                               latent, sm_scale, run, fixed)
         with R.region(R.ATTN_OUT):
             out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
                              w_kvb[..., nope:])
         return self._gated(layer, h, out), pool
 
     def _walk(self, q_row, pool, row: int, page_tables, lengths,
-              latent: int, sm_scale: float, run: int):
+              latent: int, sm_scale: float, run: int, fixed: int):
         """The lanes' rows of the latent over what their tables hold."""
         return _paged.mla_paged_decode_attention(
-            q_row, pool, row, page_tables, lengths, latent, sm_scale, run)
+            q_row, pool, row, page_tables, lengths, latent, sm_scale, run,
+            fixed)
 
     # ------------------------------------------------------- forwards
     def _prompt(self, layer: Params, h, pools: Cache, li: int, at: Walk):
@@ -323,7 +328,7 @@ class LatentAttention(Mixer):
         page, tables = at.pages[PAGED]
         out, pool = self._attn_absorbed(
             layer, h, *at.tables[self], pools["kv"], li, page, at.offset,
-            tables, at.lengths, at.run)
+            tables, at.lengths, at.run, at.fixed)
         return out, {"kv": pool}
 
     def hidden(self, layer: Params, h, at: Walk):
@@ -381,12 +386,13 @@ class WindowLatentAttention(LatentAttention):
             return _paged.KERNEL_MLA_PAGED_WINDOW_DECODE
         return "einsum"
 
-    def page_run(self, page_size: int, max_pages: int) -> int:
+    def page_run(self, page_size: int, max_pages: int,
+                 fixed: int = 0) -> int:
         """A ring's walk begins at any entry: a page a copy."""
         return 1
 
     def _walk(self, q_row, pool, row: int, ring_tables, lengths,
-              latent: int, sm_scale: float, run: int):
+              latent: int, sm_scale: float, run: int, fixed: int):
         """The lanes' rows over their rings: `ring_tables` (B, ring) the
         lanes' first table entries."""
         return _paged.mla_paged_window_decode_attention(

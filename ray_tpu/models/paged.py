@@ -120,12 +120,13 @@ class Walk:
     padded prompt's), where a pool's kind is written (`pages[kind]`: a
     prompt's page ids; a step's (page a lane, the tables its kernel walks)),
     `offset` and `lengths` (a step's row and reach a lane), `slot`, `run`
-    (a step's: what the class answered the engine's allocator with,
-    `PagedDecoder.page_run`, so that a kernel copies runs only over tables
-    laid in them) and `tables`, what a mixer's `open` made once for all its
-    layers."""
+    and `fixed` (a step's: what the class answered the engine's allocator
+    with, `PagedDecoder.page_run` and `fixed_pages`, so that a kernel
+    copies runs only over tables laid in them: the first `fixed` entries a
+    page each, of the fixed class, then whole runs of `run`) and `tables`,
+    what a mixer's `open` made once for all its layers."""
     slot = offset = lengths = None
-    run = 1
+    run, fixed = 1, 0
 
     def __init__(self, sequences=None, lanes=None, true_len=None):
         self._sequences, self.lanes, self.true_len = sequences, lanes, true_len
@@ -183,10 +184,12 @@ class Mixer:
             sum(pool.bytes(self.dtype, page_size) for pool in self.pools),
             page_size, max_pages)
 
-    def page_run(self, page_size: int, max_pages: int) -> int:
-        """Pages one copy of its decode walk would bring, from shapes alone
-        (`PagedDecoder.page_run` decides; `decode_step` reads the decision
-        off `Walk.run`, never this)."""
+    def page_run(self, page_size: int, max_pages: int,
+                 fixed: int = 0) -> int:
+        """Pages one copy of its decode walk would bring over tables of
+        `max_pages` entries, the first `fixed` of the fixed class, from
+        shapes alone (`PagedDecoder.page_run` decides; `decode_step` reads
+        the decision off `Walk.run`, never this)."""
         return 1
 
 
@@ -207,7 +210,7 @@ class Layer:
 
 
 class PagedDecoder:
-    """A model as the serving engine sees it: thirteen asks.
+    """A model as the serving engine sees it: fourteen asks.
 
     - `init_cache(num_pages, page_size, dtype=None, fixed_pages=0)`: the
       zeroed cache, `num_pages` pages in the pools that grow with a
@@ -224,8 +227,8 @@ class PagedDecoder:
       `decode_step` traced here holds, by name, or "einsum";
     - `walk_block_pages(page_size, max_pages)`: pages a block of the decode
       kernel's walk holds (the engine's walk counts stand on it);
-    - `fixed_pages`, `page_run`, `fixed_step_counts`, `prefill_counts`,
-      `step_stats`, `cache_stats`, `pool_rows`.
+    - `fixed_pages`, `page_run`, `table_pages`, `fixed_step_counts`,
+      `prefill_counts`, `step_stats`, `cache_stats`, `pool_rows`.
 
     A class whose layers are held one by one lays its table in `__init__`
     (`_lay`: its mixers and a `Layer` a layer), says `layer_shapes(i)` (a
@@ -468,6 +471,7 @@ class PagedDecoder:
                 positions, page_tables, active, paged.shape[1], page_size)
             at.pages[PAGED] = page, page_tables
             at.run = self.page_run(page_size, page_tables.shape[1])
+            at.fixed = self.fixed_pages(page_size)
         if ring is not None:
             held = self.fixed_pages(page_size)
             with R.region(R.CACHE):
@@ -600,13 +604,23 @@ class PagedDecoder:
         """Pages of the class that grows that a sequence is to be handed
         at once, ids behind one another from a multiple of it on
         (`kv_cache.PageAllocator`'s `run`): what one copy of the class's
-        decode walk brings. 1: a page at a time, in any order; and for a
-        class that keeps a fixed page, whose runs would start at table
-        entry `fixed` and not 0, which no kernel reads yet."""
-        if self.fixed_pages(page_size):
-            return 1
-        return max((mixer.page_run(page_size, max_pages)
+        decode walk brings, as its mixers answer. 1: a page at a time, in
+        any order. A class that keeps a fixed page has its runs open at
+        table entry `fixed_pages`, which the walks are told (`Walk.fixed`),
+        and the same answer for a table of `max_pages` entries and for the
+        one `table_pages` makes of it."""
+        fixed = self.fixed_pages(page_size)
+        return max((mixer.page_run(page_size, max_pages, fixed)
                     for mixer in self.mixers), default=1)
+
+    def table_pages(self, page_size: int, pages: int) -> int:
+        """Entries of a sequence's page table where a sequence holds up to
+        `pages` pages: `pages`, or for a class that keeps a fixed page and
+        asks for runs, the fixed entries and then whole runs
+        (`ops.paged_attention.run_table_pages`). The one place the engine's
+        tables and the step's walks take a table's width from."""
+        return _paged.run_table_pages(pages, self.fixed_pages(page_size),
+                                      self.page_run(page_size, pages))
 
     def fixed_step_counts(self, length: int, page_size: int,
                           kernel: bool = True) -> Dict[str, int]:

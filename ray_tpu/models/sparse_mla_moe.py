@@ -244,13 +244,14 @@ class SparseLatentAttention(LatentAttention):
 
     def _attn_selected_step(self, layer: Params, h, cos, sin, pool,
                             idx_pool, row: int, wr_page, wr_slot,
-                            page_tables, lengths, run: int):
+                            page_tables, lengths, run: int, fixed: int):
         """One decode position a lane: both pools get this position's row,
         the indexer scores the lane's positions, and the absorbed form
         reads the chosen rows. Tables that cannot pass `index_topk` are
-        `_attn_absorbed`'s; `run`: the runs the tables are laid in
-        (`Walk.run`). h (B, e). Returns (attention output before W_o
-        (B, heads * v), pool, idx_pool, the layer's `DSA_COUNTS`)."""
+        `_attn_absorbed`'s; `run`, `fixed`: the runs the tables are laid in
+        behind their fixed entries (`Walk.run`, `Walk.fixed`). h (B, e).
+        Returns (attention output before W_o (B, heads * v), pool,
+        idx_pool, the layer's `DSA_COUNTS`)."""
         c = self.config
         ad = c.activation_dtype
         with R.region(R.ATTN_INDEX):
@@ -262,7 +263,7 @@ class SparseLatentAttention(LatentAttention):
         if span <= c.index_topk:
             out, pool = self._attn_absorbed(
                 layer, h, cos, sin, pool, row, wr_page, wr_slot,
-                page_tables, lengths, run)
+                page_tables, lengths, run, fixed)
             return out, pool, idx_pool, (jnp.int32(0), seen, jnp.int32(0))
         nope, latent = c.qk_nope_head_dim, c.kv_lora_rank
         pad = c.row_width - latent - c.qk_rope_head_dim
@@ -279,17 +280,18 @@ class SparseLatentAttention(LatentAttention):
             q_row = jnp.pad(jnp.concatenate([q_lat, q_rope], axis=-1),
                             ((0, 0), (0, 0), (0, pad))).astype(pool.dtype)
         kernel = self._step_kernels(pool.shape[2], page_tables.shape[1],
-                                    pool.dtype)
+                                    pool.dtype, fixed)
         with R.region(R.ATTN_INDEX):
             choice, chosen = _sparse.choose_paged(
                 q_idx.astype(idx_pool.dtype), w, idx_pool, row, page_tables,
-                lengths, c.index_topk, kernel, run=run)
+                lengths, c.index_topk, kernel, run=run, fixed=fixed)
             counts = (seen, jnp.sum(chosen).astype(jnp.int32),
                       jnp.sum(lengths > c.index_topk).astype(jnp.int32))
         with R.region(R.ATTN_CORE):
             o_lat = _sparse.attend_chosen(
                 q_row, pool, row, page_tables, lengths, choice, latent,
-                1.0 / math.sqrt(c.qk_head_dim), kernel, run=run)
+                1.0 / math.sqrt(c.qk_head_dim), kernel, run=run,
+                fixed=fixed)
         with R.region(R.ATTN_OUT):
             out = jnp.einsum("bhc,chv->bhv", o_lat.astype(ad),
                              w_kvb[..., nope:])
@@ -327,7 +329,7 @@ class SparseLatentAttention(LatentAttention):
         page, tables = at.pages[PAGED]
         out, pool, idx_pool, counts = self._attn_selected_step(
             layer, h, *at.tables[self], pools["kv"], pools["idx"], li, page,
-            at.offset, tables, at.lengths, at.run)
+            at.offset, tables, at.lengths, at.run, at.fixed)
         with R.region(R.ATTN_INDEX):
             dsa = {name: pools["dsa_step"][name] + n
                    for name, n in zip(DSA_COUNTS, counts)}
@@ -345,7 +347,8 @@ class SparseLatentAttention(LatentAttention):
         return _paged.walk_block_pages(
             self.pools[0].bytes(self.dtype, page_size), page_size, max_pages)
 
-    def page_run(self, page_size: int, max_pages: int) -> int:
+    def page_run(self, page_size: int, max_pages: int,
+                 fixed: int = 0) -> int:
         """Pages one copy of the step's two walks brings, which the
         allocator is asked to hand out behind one another: by what a page
         of index keys, the smaller pool's, weighs a layer
@@ -354,11 +357,11 @@ class SparseLatentAttention(LatentAttention):
         context cannot pass `index_topk`."""
         c = self.config
         if c.max_seq_len <= c.index_topk:
-            return super().page_run(page_size, max_pages)
-        if not self._step_kernels(page_size, max_pages, self.dtype):
+            return super().page_run(page_size, max_pages, fixed)
+        if not self._step_kernels(page_size, max_pages, self.dtype, fixed):
             return 1
         return _sparse.walk_run_pages(
-            self.pools[1].bytes(self.dtype, page_size), max_pages)
+            self.pools[1].bytes(self.dtype, page_size), max_pages, fixed)
 
     def decode_kernel(self, page_size: int, dtype) -> str:
         """`LatentAttention`'s answer where the context cannot pass
@@ -372,13 +375,14 @@ class SparseLatentAttention(LatentAttention):
             return _sparse.KERNEL_PAGED_ATTEND
         return "einsum"
 
-    def _step_kernels(self, page_size: int, max_pages: int, dtype) -> bool:
+    def _step_kernels(self, page_size: int, max_pages: int, dtype,
+                      fixed: int = 0) -> bool:
         """Whether a step past `index_topk` traced here runs the two walk
         kernels (else the gathers)."""
         c = self.config
         return _sparse.step_uses_kernels(
             c.index_head_dim, c.row_width, c.kv_lora_rank, page_size,
-            max_pages, dtype)
+            max_pages, dtype, fixed)
 
 
 class SparseMLAMoE(MLAMoE):

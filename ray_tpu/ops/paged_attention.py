@@ -123,13 +123,37 @@ def walk_prefixes(block_pages: int, page_size: int) -> tuple:
                          if piece <= n < block_pages} | {block_pages}))
 
 
-def walk_counts(pages: int, block_pages: int, page_size: int):
+def walk_counts(pages: int, block_pages: int, page_size: int,
+                pad: int = 0):
     """(blocks, positions multiplied) of a lane whose walk covers `pages`
-    pages: what `_walk_pages` does, counted on the host."""
-    full, rest = divmod(pages, block_pages)
+    pages: what `_walk_pages` does, counted on the host. `pad`: the places
+    of its first block that no entry lands on (`run_pad`), which a lane
+    that holds a page walks with the rest."""
+    full, rest = divmod(pages + pad if pages else 0, block_pages)
     tail = next((n for n in walk_prefixes(block_pages, page_size)
                  if n >= rest), 0) if rest else 0
     return full + bool(rest), (full * block_pages + tail) * page_size
+
+
+def run_pad(fixed: int, run: int) -> int:
+    """Places at the head of a walk's first block that no table entry
+    lands on, where the tables' first `fixed` entries are of the fixed
+    class and the runs of `run` open behind them: as many as bring entry
+    `fixed` to a multiple of `run`, so that no run straddles a block."""
+    return -fixed % run
+
+
+def run_table_pages(pages: int, fixed: int, run: int) -> int:
+    """Entries of a table whose sequences hold up to `pages` pages, the
+    first `fixed` of the fixed class, the rest handed out in runs of `run`
+    (`serve/llm/kv_cache.py`): the fixed entries and then whole runs, so
+    that a sequence at full length fits with the last run it is handed,
+    and every walk finds whole runs behind entry `fixed`. Without a fixed
+    class a table is its `pages`, which its run divides
+    (`copy_run_pages`)."""
+    if not fixed:
+        return pages
+    return fixed + -(-max(pages - fixed, 0) // run) * run
 
 
 def walk_first_blocks_hidden(pages) -> int:
@@ -250,7 +274,7 @@ def _gathered_attention(q, k_pool, v_pool, layer, tables, seen):
 def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
                 l_ref, finite_ref, hand_ref, copies, value_buf, attend, *,
                 page_size: int, block_pages: int, max_pages: int,
-                window: int = 0, run: int = 1):
+                window: int = 0, run: int = 1, fixed: int = 0):
     """A paged decode kernel but for its matmuls: `attend(slot, start,
     pages, seen, rolled)` on every block of pages the grid's lane holds, in table
     order, between the reset of the running softmax and its division into
@@ -280,6 +304,14 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
     and what of it lies past the lane's length is not `seen`. A run lies
     in one block (tables and blocks of whole runs), and not in a ring,
     whose walk begins at any entry.
+    With `fixed` the tables' first `fixed` entries are pages of the
+    allocator's fixed class, handed out one by one, and the runs open at
+    entry `fixed`: those entries are copied a page a descriptor, the rest
+    a run, and table entry `e` lies at place `e + run_pad(fixed, run)` of
+    the walk, so that entry `fixed` lands on a multiple of `run` and a run
+    in one block still; the first block's leading places, fewer than `run`,
+    hold nothing and are not `seen`. At `run` 1 a table's head is walked
+    as the rest is.
 
     The grid runs the lanes in order and the scratch outlives a grid step:
     behind its last block a lane has nothing of its own left to copy in,
@@ -287,11 +319,16 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
     the slot it is not multiplying from, and that lane finds it in flight
     (`walk_first_blocks_hidden` counts them). Only the first such lane of
     a call waits for a copy that nothing hides."""
-    if run > 1 and (window or max_pages % run or block_pages % run):
+    if run == 1:
+        fixed = 0
+    if run > 1 and (window or (max_pages - fixed) % run
+                    or block_pages % run):
         raise ValueError(
             f"a walk that copies runs of {run} pages takes tables and "
-            f"blocks of whole runs and no ring: {max_pages} pages in "
-            f"blocks of {block_pages}, window {window}")
+            f"blocks of whole runs and no ring: {max_pages} pages "
+            f"({fixed} fixed) in blocks of {block_pages}, window {window}")
+    pad = run_pad(fixed, run)
+    head = fixed + pad              # the place the runs open at
     b = pl.program_id(0)
     lanes = len_ref.shape[0]
     slots = value_buf.shape[0]
@@ -305,7 +342,10 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
             first = jnp.maximum(length - window, 0) // page_size
             return lane, first, jnp.minimum(
                 pl.cdiv(length, page_size) - first, max_pages)
-        return lane, 0, jnp.minimum(pl.cdiv(length, page_size), max_pages)
+        pages = jnp.minimum(pl.cdiv(length, page_size), max_pages)
+        if pad:
+            pages = jnp.where(pages > 0, pages + pad, 0)
+        return lane, 0, pages
 
     me = walk_of(b)
     length, first, n_blocks = len_ref[b], me[1], pl.cdiv(me[2], block_pages)
@@ -323,6 +363,8 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
         idx = blk * block_pages + p
         if window:
             idx = (first + idx) % max_pages
+        if pad:
+            idx = idx - pad
         page = pt_ref[lane * max_pages + idx]
         return page, page >= 0
 
@@ -333,43 +375,61 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
     prefixes = walk_prefixes(block_pages, page_size)
     piece = prefixes[0]
 
+    def first_place(blk):
+        """The first place of block `blk` an entry lands on."""
+        return jnp.clip(pad - blk * block_pages, 0, block_pages)
+
     def each_copy(who, blk, slot, act):
         """`act` on the copy of every page of block `blk` the lane of
         `who` holds, into `slot` (a loop, not unrolled: a block of 64
         pages is traced as one page, and a short lane pays for the pages
         it has); a copy a page, or one a run of `run` pages whose first
-        the lane holds. Returns how many pages the walk reaches those
-        were."""
+        the lane holds (behind a table's `fixed` head, a page each).
+        Returns how many pages the walk reaches those were."""
         reach = reach_of(who, blk)
 
-        def some(i, held):
-            for p in range(COPY_UNROLL):
-                p = i * COPY_UNROLL + p
-                if run > 1:                 # the run's first page
-                    p = p * run
-                page, live = page_at(who, blk, p)
-                live = live & (p < reach)
+        def loop(step, first, end, held, unroll=COPY_UNROLL):
+            """The copies of `step` pages each from place `first` (None:
+            the block's first) up to `end`, `unroll` a turn."""
+            def some(i, held):
+                for p in range(unroll):
+                    p = i * unroll + p
+                    if step > 1:                # the run's first page
+                        p = p * step
+                    if first is not None:
+                        p = first + p
+                    page, live = page_at(who, blk, p)
+                    live = live & (p < end)
 
-                @pl.when(live)
-                def _():
-                    for pool, buf, sem in copies:
-                        if run == 1:
-                            src, dst = pool.at[layer, page], buf.at[slot, p]
-                        else:
-                            src = pool.at[layer, pl.ds(
-                                pl.multiple_of(page, run), run)]
-                            dst = buf.at[slot, pl.ds(
-                                pl.multiple_of(p, run), run)]
-                        act(pltpu.make_async_copy(
-                            src, dst, sems.at[(slot, *sem)]))
-                if run == 1:
-                    held = held + live.astype(jnp.int32)
-                else:
-                    held = held + jnp.where(
-                        live, jnp.minimum(reach - p, run), 0)
-            return held
-        return lax.fori_loop(0, pl.cdiv(reach, COPY_UNROLL * run), some,
-                             jnp.int32(0))
+                    @pl.when(live)
+                    def _():
+                        for pool, buf, sem in copies:
+                            if step == 1:
+                                src, dst = (pool.at[layer, page],
+                                            buf.at[slot, p])
+                            else:
+                                src = pool.at[layer, pl.ds(
+                                    pl.multiple_of(page, step), step)]
+                                dst = buf.at[slot, pl.ds(
+                                    pl.multiple_of(p, step), step)]
+                            act(pltpu.make_async_copy(
+                                src, dst, sems.at[(slot, *sem)]))
+                    if step == 1:
+                        held = held + live.astype(jnp.int32)
+                    else:
+                        held = held + jnp.where(
+                            live, jnp.minimum(end - p, step), 0)
+                return held
+            return lax.fori_loop(
+                0, pl.cdiv(end if first is None else end - first,
+                           unroll * step), some, held)
+
+        if not fixed:
+            return loop(run, None, reach, jnp.int32(0))
+        runs = jnp.clip(head - blk * block_pages, 0, block_pages)
+        held = loop(1, first_place(blk), jnp.minimum(runs, reach),
+                    jnp.int32(0), min(COPY_UNROLL, fixed))
+        return loop(run, runs, reach, held)
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
     m_ref[:] = jnp.full_like(m_ref, -jnp.inf)
@@ -438,7 +498,7 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
         # pages the walk reaches in it: what lies behind was not copied in
         # and is not read, what lies inside and is not live is not `seen`
         reach = reach_of(me, blk)
-        whole = held == reach
+        whole = held == (reach - first_place(blk) if pad else reach)
 
         def by_pieces():
             def one(i, carry):
@@ -471,7 +531,11 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
         at = start * page_size + lax.broadcasted_iota(
             jnp.int32, (1, pages * page_size), 1)
         pos = (blk * block_pages + first) * page_size + at
+        if pad:
+            pos = pos - pad * page_size
         seen = (pos < length) & (at < reach * page_size)
+        if pad:
+            seen = seen & (pos >= 0)
         if window:
             seen = seen & (pos >= length - window)
 
@@ -479,10 +543,14 @@ def _walk_pages(layer_ref, len_ref, pt_ref, o_ref, sems, acc_ref, m_ref,
             def one(p, out):                 # branch yields no mask)
                 # (a page of a run is held where the run's first is)
                 first = p - p % run if run > 1 else p
+                if fixed:                   # (the head's, where it is)
+                    first = jnp.where(blk * block_pages + p < head, p,
+                                      first)
                 return jnp.where((at // page_size == p)
                                  & ~page_at(me, blk, first)[1], 1, out)
-            return lax.fori_loop(start, jnp.minimum(reach, start + pages),
-                                 one, jnp.zeros_like(at))
+            return lax.fori_loop(
+                jnp.maximum(start, first_place(blk)) if pad else start,
+                jnp.minimum(reach, start + pages), one, jnp.zeros_like(at))
         return seen & (lax.cond(whole, lambda: jnp.zeros_like(at), holes)
                        == 0)
 
@@ -519,15 +587,17 @@ def _paged_pallas_call(kernel, name: str, q, pools, layer,
     layer, lengths and flat tables by scalar prefetch; `q` (lanes, ...,
     rows, width) a lane a block; the pools left in HBM; scratch as
     `_walk_pages` takes it, the blocks of `walk_block_pages` pages by what
-    a page of these pools weighs. `kernel` gets the walk's sizes by
-    keyword, and what else `walk` holds (a `window`, a `run`)."""
+    a page of these pools weighs over the table's entries as the walk
+    places them (`run_pad`). `kernel` gets the walk's sizes by keyword,
+    and what else `walk` holds (a `window`, a `run`, a `fixed`)."""
     lanes, *rows = q.shape
     out = (*rows[:-1], out_width)
     stat = (*rows[:-1], 128)
     page_size, max_pages = pools[0].shape[2], page_tables.shape[1]
     block_pages = walk_block_pages(
         sum(page_size * pool.shape[3] * pool.dtype.itemsize
-            for pool in pools), page_size, max_pages)
+            for pool in pools), page_size,
+        max_pages + run_pad(walk.get("fixed", 0), walk.get("run", 1)))
 
     def lane(b, *_):
         return (b,) + (0,) * len(rows)
@@ -603,7 +673,7 @@ def _paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
 
 
 def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
-                  interpret: bool, mesh=None):
+                  interpret: bool, mesh=None, run: int = 1, fixed: int = 0):
     n_heads, hd = q.shape[1:]
     page_size, kvh = k_pool.shape[2], k_pool.shape[3] // hd
     if n_heads % kvh:
@@ -617,7 +687,8 @@ def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
             f"with {page_size}-position pages of {k_pool.dtype}")
     qg = _lane_queries(q, kvh)
     call = functools.partial(_paged_decode_call, interpret=interpret,
-                             sm_scale=1.0 / math.sqrt(hd))
+                             sm_scale=1.0 / math.sqrt(hd), run=run,
+                             fixed=fixed)
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
     if mesh is not None:
         # kv heads over tp, as models.decode.cache_sharding lays the pool
@@ -634,29 +705,34 @@ def _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
 # jitted: the decode step calls it once a layer with the same shapes, the
 # layer's index an argument, so the kernel is traced and lowered once a
 # program and not once a layer (24 of them cost a replica 8 s of set-up)
-@functools.partial(jax.jit, static_argnames=("interpret", "sm_scale"))
+@functools.partial(jax.jit, static_argnames=("interpret", "sm_scale", "run",
+                                             "fixed"))
 def _paged_decode_call(qg, k_pool, v_pool, layer, page_tables, lengths,
-                       interpret: bool, sm_scale: float):
+                       interpret: bool, sm_scale: float, run: int = 1,
+                       fixed: int = 0):
     """`sm_scale`: of the heads' own width, which a lane of several is
     not."""
     kernel = functools.partial(_paged_decode_kernel, sm_scale=sm_scale)
     return _paged_pallas_call(
         kernel, KERNEL_PAGED_DECODE, qg, (k_pool, v_pool), layer,
         page_tables, lengths, out_width=qg.shape[-1],
-        sems=(BLOCK_SLOTS, 2), interpret=interpret)
+        sems=(BLOCK_SLOTS, 2), interpret=interpret, run=run, fixed=fixed)
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, page_tables, lengths,
-                           mesh=None):
+                           mesh=None, run: int = 1, fixed: int = 0):
     """Dispatching entry point: the compiled kernel when the target
     platform is a TPU and the shapes are ones it tiles
     (`paged_decode_tiles`), the gather + einsum reference elsewhere.
     Shapes as `paged_attention_reference`; `mesh`: the mesh of more than
-    one device the pool is sharded over (`ops.dispatch.kernel_mesh`)."""
+    one device the pool is sharded over (`ops.dispatch.kernel_mesh`);
+    `run`, `fixed`: the pages one copy of the kernel's walk brings behind
+    the tables' first `fixed` entries, which the tables' owner vouches lie
+    in such runs (`_walk_pages`; the gather reads any table)."""
     if uses_kernel(q.shape[-1], k_pool.shape[2], k_pool.dtype,
                    k_pool.shape[3]):
         return _paged_decode(q, k_pool, v_pool, layer, page_tables,
-                             lengths, False, mesh)
+                             lengths, False, mesh, run, fixed)
     return paged_attention_reference(q, k_pool, v_pool, layer,
                                      page_tables, lengths)
 
@@ -671,10 +747,11 @@ def uses_kernel(head_dim: int, page_size: int, dtype,
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, layer, page_tables,
-                                  lengths, mesh=None):
+                                  lengths, mesh=None, run: int = 1,
+                                  fixed: int = 0):
     """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
     return _paged_decode(q, k_pool, v_pool, layer, page_tables, lengths,
-                         not on_tpu(), mesh)
+                         not on_tpu(), mesh, run, fixed)
 
 
 # ------------------------------------------ a sliding window over a ring
@@ -797,14 +874,55 @@ def copy_run_pages(copy_bytes: int, page_bytes: int, *whole: int) -> int:
                     *whole)
 
 
+def run_wholes(max_pages: int, fixed: int, block_of) -> tuple:
+    """What a run of a table of `max_pages` entries has to divide
+    (`copy_run_pages`' `whole`), `block_of(max_pages)` being the walk's
+    block over such a table. Without a fixed class the table and its
+    block. With `fixed` entries of that class in front the run is sized on
+    the entries that grow and the table made whole runs of it
+    (`run_table_pages`): no longer than the least power of two that holds
+    them, and a divisor of the block of a table as long as can be, which
+    a walk's blocks are or are whole runs of. The answer for a table and
+    for the table `run_table_pages` makes of it is one."""
+    if not fixed:
+        return max_pages, block_of(max_pages)
+    grow = max(max_pages - fixed, 1)
+    return 1 << (grow - 1).bit_length(), block_of(1 << 30)
+
+
 def mla_walk_run_pages(page_bytes: int, page_size: int,
-                       max_pages: int) -> int:
+                       max_pages: int, fixed: int = 0) -> int:
     """Pages one copy of the latent walk brings where a page of the pool
     is `page_bytes` (one layer's): `copy_run_pages` of `MLA_RUN_COPY_BYTES`
-    in the table's `max_pages` and the walk's block."""
+    in the table's `max_pages` and the walk's block (`run_wholes`)."""
     return copy_run_pages(
-        MLA_RUN_COPY_BYTES, page_bytes, max_pages,
-        walk_block_pages(page_bytes, page_size, max_pages))
+        MLA_RUN_COPY_BYTES, page_bytes, *run_wholes(
+            max_pages, fixed,
+            lambda n: walk_block_pages(page_bytes, page_size, n)))
+
+
+# A page of one pool, one layer's, that a copy of the per-head walk brings
+# alone: PR 64's rule (a copy is at its bytes from some 64-80 KB on,
+# `MLA_RUN_COPY_BYTES`) read 16 KB a pool as runs of 4 and 8 KB as runs of
+# 8; whether pages of 32 KB and more gain from runs of 2 nobody has
+# measured (ROADMAP S11(a3)), and their classes keep a page a copy.
+RUN_PAGE_BYTES = 32 << 10
+
+
+def decode_walk_run_pages(pool_page_bytes: int, page_bytes: int,
+                          page_size: int, max_pages: int,
+                          fixed: int = 0) -> int:
+    """Pages one copy of the per-head walk brings where a page of one pool
+    is `pool_page_bytes` and of all the walk's pools `page_bytes` (one
+    layer's): 1 from `RUN_PAGE_BYTES` a pool on, else `copy_run_pages` of
+    `MLA_RUN_COPY_BYTES` in the table and the walk's block
+    (`run_wholes`)."""
+    if pool_page_bytes >= RUN_PAGE_BYTES:
+        return 1
+    return copy_run_pages(
+        MLA_RUN_COPY_BYTES, pool_page_bytes, *run_wholes(
+            max_pages, fixed,
+            lambda n: walk_block_pages(page_bytes, page_size, n)))
 
 
 def mla_paged_decode_tiles(width: int, latent: int, page_size: int,
@@ -862,10 +980,10 @@ def _mla_paged_decode_kernel(layer_ref, len_ref, pt_ref,       # scalars
 
 # jitted for the reason `_paged_decode_call` is: traced once a program
 @functools.partial(jax.jit, static_argnames=("latent", "sm_scale",
-                                             "interpret", "run"))
+                                             "interpret", "run", "fixed"))
 def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
                            latent: int, sm_scale: float, interpret: bool,
-                           run: int = 1):
+                           run: int = 1, fixed: int = 0):
     heads, width = q.shape[1:]
     page_size = pool.shape[2]
     if not mla_paged_decode_tiles(width, latent, page_size, pool.dtype):
@@ -882,7 +1000,7 @@ def _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
     out = _paged_pallas_call(
         kernel, KERNEL_MLA_PAGED_DECODE, qp, (pool,), layer, page_tables,
         lengths, out_width=latent, sems=(BLOCK_SLOTS,), interpret=interpret,
-        run=run)
+        run=run, fixed=fixed)
     return out[:, :heads]
 
 
@@ -894,27 +1012,29 @@ def mla_uses_kernel(width: int, latent: int, page_size: int, dtype) -> bool:
 
 
 def mla_paged_decode_attention(q, pool, layer, page_tables, lengths,
-                               latent: int, sm_scale: float, run: int = 1):
+                               latent: int, sm_scale: float, run: int = 1,
+                               fixed: int = 0):
     """Dispatching entry point of the latent pool's decode attention: the
     compiled kernel on a TPU where the shapes tile, the gather + einsum
-    reference elsewhere. Shapes as `mla_paged_attention_reference`; `run`:
-    the pages one copy of the kernel's walk brings, which the tables' owner
-    vouches lie in such runs (`_walk_pages`; the gather reads any table)."""
+    reference elsewhere. Shapes as `mla_paged_attention_reference`; `run`,
+    `fixed`: the pages one copy of the kernel's walk brings behind the
+    tables' first `fixed` entries, which the tables' owner vouches lie in
+    such runs (`_walk_pages`; the gather reads any table)."""
     if mla_uses_kernel(q.shape[-1], latent, pool.shape[2], pool.dtype):
         return _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
                                       latent, float(sm_scale), False,
-                                      run=run)
+                                      run=run, fixed=fixed)
     return mla_paged_attention_reference(q, pool, layer, page_tables,
                                          lengths, latent, sm_scale)
 
 
 def mla_paged_decode_attention_kernel(q, pool, layer, page_tables, lengths,
                                       latent: int, sm_scale: float,
-                                      run: int = 1):
+                                      run: int = 1, fixed: int = 0):
     """Force the Pallas kernel path (interpreter off-TPU) — test hook."""
     return _mla_paged_decode_call(q, pool, layer, page_tables, lengths,
                                   latent, float(sm_scale), not on_tpu(),
-                                  run=run)
+                                  run=run, fixed=fixed)
 
 
 # ------------------------------------- the latent pool in a ring (a window)

@@ -57,7 +57,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops.attention import DEFAULT_MASK_VALUE
 from ray_tpu.ops.dispatch import on_tpu
-from ray_tpu.ops.paged_attention import _softmax_update, copy_run_pages
+from ray_tpu.ops.paged_attention import (_softmax_update, copy_run_pages,
+                                         run_pad, run_wholes)
 
 # The kernels' names on the device's clock (see attention.KERNEL_FWD).
 KERNEL_INDEX_SCORES = "dsa_index_scores"
@@ -170,40 +171,66 @@ ATTEND_WALK_PAGES = 64          # 1,024 latent rows a block
 RUN_COPY_BYTES = 32 << 10
 
 
-def walk_run_pages(page_bytes: int, max_pages: int) -> int:
+def walk_run_pages(page_bytes: int, max_pages: int, fixed: int = 0) -> int:
     """Pages a run holds where a page of the smaller pool walked is
     `page_bytes` (one layer's): as many as make a copy of `RUN_COPY_BYTES`,
     cut to a divisor of the table's `max_pages` and of both walks' blocks
-    (a run lies in one block)."""
+    (a run lies in one block; `ops.paged_attention.run_wholes` for tables
+    with `fixed` entries of the fixed class in front)."""
     return copy_run_pages(
-        RUN_COPY_BYTES, page_bytes, max_pages,
-        min(INDEX_WALK_PAGES, max_pages), min(ATTEND_WALK_PAGES, max_pages))
+        RUN_COPY_BYTES, page_bytes, *run_wholes(
+            max_pages, fixed,
+            lambda n: math.gcd(min(INDEX_WALK_PAGES, n),
+                               min(ATTEND_WALK_PAGES, n))))
 
 
 def _walk_blocks(layer_ref, len_ref, pt_ref, pool_hbm, buf, sems, block,
                  *, page_size: int, block_pages: int, max_pages: int,
-                 run: int):
+                 run: int, fixed: int = 0):
     """`block(blk, slot)` on every block of `block_pages` pages the grid's
     lane holds, its pages copied into `buf[slot]` (`slots`, block_pages,
     page_size, width), the next block's on their way meanwhile: a copy a
     page, or one a run of `run` pages that holds a live page (the table's
-    entry `k * run` names the run's first)."""
+    entry `k * run` names the run's first). With `fixed` the tables' first
+    `fixed` entries are copied a page each and the runs open at entry
+    `fixed`: entry `e` lies at place `e + run_pad(fixed, run)` of the walk
+    (`ops.paged_attention._walk_pages`), and the first block's leading
+    places hold what the buffer held."""
     b = pl.program_id(0)
     layer = layer_ref[0]
+    pad = run_pad(fixed, run)
     pages = pl.cdiv(len_ref[b], page_size)
+    if pad:
+        pages = jnp.where(pages > 0, pages + pad, 0)
     n_blocks = pl.cdiv(pages, block_pages)
 
     def each_copy(blk, slot, act):
-        def one(k, carry):
-            page = jnp.maximum(
-                pt_ref[b * max_pages + blk * block_pages + k * run], 0)
-            act(pltpu.make_async_copy(
-                pool_hbm.at[layer, pl.ds(pl.multiple_of(page, run), run)],
-                buf.at[slot, pl.ds(pl.multiple_of(k * run, run), run)],
-                sems.at[slot]))
-            return carry
-        lax.fori_loop(0, pl.cdiv(jnp.minimum(pages - blk * block_pages,
-                                             block_pages), run), one, 0)
+        def loop(step, first, end):
+            """The copies of `step` pages each from place `first` (None:
+            the block's first) up to `end`."""
+            def place(k):
+                return k * step if first is None else first + k * step
+
+            def one(k, carry):
+                entry = b * max_pages + blk * block_pages + place(k)
+                page = jnp.maximum(pt_ref[entry - pad if pad else entry], 0)
+                act(pltpu.make_async_copy(
+                    pool_hbm.at[layer,
+                                pl.ds(pl.multiple_of(page, step), step)],
+                    buf.at[slot,
+                           pl.ds(pl.multiple_of(place(k), step), step)],
+                    sems.at[slot]))
+                return carry
+            lax.fori_loop(0, pl.cdiv(end if first is None else end - first,
+                                     step), one, 0)
+
+        reach = jnp.minimum(pages - blk * block_pages, block_pages)
+        if not fixed:
+            return loop(run, None, reach)
+        at = blk * block_pages
+        runs = jnp.clip(fixed + pad - at, 0, block_pages)
+        loop(1, jnp.clip(pad - at, 0, block_pages), jnp.minimum(runs, reach))
+        loop(run, runs, reach)
 
     @pl.when(n_blocks > 0)
     def _():
@@ -239,20 +266,41 @@ def _paged_index_kernel(layer_ref, len_ref, pt_ref,             # scalars
                  **walk)
 
 
-def _whole_blocks(max_pages: int, block_pages: int, run: int) -> None:
-    if max_pages % block_pages or block_pages % run:
-        raise ValueError(f"tables of {max_pages} pages are not whole blocks "
-                         f"of {block_pages} in whole runs of {run}")
+def _walk_layout(max_pages: int, walk_pages: int, run: int, fixed: int):
+    """(fixed as the walk takes it, the first table entry's place, a
+    block's pages, the walk's blocks) of a walk in blocks of up to
+    `walk_pages` over tables of `max_pages` entries. Tables without a
+    fixed class, and those walked a page a copy, are whole blocks, each
+    whole runs. Tables with `fixed` entries of that class in front and
+    whole runs behind them (`ops.paged_attention.run_table_pages`) are
+    walked from place `run_pad(fixed, run)` on in blocks of `walk_pages`,
+    the last one past the table's end where it must be: the caller cuts
+    the walk's span to the table's."""
+    block_pages = min(walk_pages, max_pages)
+    if run == 1 and not max_pages % block_pages:
+        fixed = 0
+    if not fixed:
+        if max_pages % block_pages or block_pages % run:
+            raise ValueError(
+                f"tables of {max_pages} pages are not whole blocks of "
+                f"{block_pages} in whole runs of {run}")
+        return 0, 0, block_pages, max_pages // block_pages
+    if walk_pages % run or (max_pages - fixed) % run:
+        raise ValueError(
+            f"tables of {max_pages} pages are not {fixed} fixed and whole "
+            f"runs of {run} in blocks of {walk_pages}")
+    pad = run_pad(fixed, run)
+    return fixed, pad, walk_pages, -(-(max_pages + pad) // walk_pages)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "run"))
+@functools.partial(jax.jit, static_argnames=("interpret", "run", "fixed"))
 def _paged_index_call(q_idx, w, idx_pool, layer, page_tables, lengths,
-                      interpret: bool, run: int = 1):
+                      interpret: bool, run: int = 1, fixed: int = 0):
     lanes, heads, width = q_idx.shape
     page_size, max_pages = idx_pool.shape[2], page_tables.shape[1]
-    block_pages = min(INDEX_WALK_PAGES, max_pages)
-    span = max_pages * page_size
-    _whole_blocks(max_pages, block_pages, run)
+    fixed, pad, block_pages, blocks = _walk_layout(
+        max_pages, INDEX_WALK_PAGES, run, fixed)
+    span = blocks * block_pages * page_size
 
     def lane(b, *_):
         return b, 0, 0
@@ -260,7 +308,7 @@ def _paged_index_call(q_idx, w, idx_pool, layer, page_tables, lengths,
     call = pl.pallas_call(
         functools.partial(_paged_index_kernel, page_size=page_size,
                           block_pages=block_pages, max_pages=max_pages,
-                          run=run),
+                          run=run, fixed=fixed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(lanes,),
@@ -283,6 +331,9 @@ def _paged_index_call(q_idx, w, idx_pool, layer, page_tables, lengths,
                   page_tables.astype(jnp.int32).reshape(-1),
                   q_idx.astype(idx_pool.dtype),
                   w.astype(jnp.float32)[..., None], idx_pool)[:, 0]
+    if fixed:                   # the walk's places -> the table's entries
+        scores = lax.slice_in_dim(scores, pad * page_size,
+                                  (pad + max_pages) * page_size, axis=1)
     return jnp.where(_live(page_tables, lengths, page_size), scores,
                      -jnp.inf)
 
@@ -319,15 +370,18 @@ def _paged_attend_kernel(layer_ref, len_ref, pt_ref,            # scalars
 
 
 @functools.partial(jax.jit, static_argnames=("latent", "sm_scale",
-                                             "interpret", "run"))
+                                             "interpret", "run", "fixed"))
 def _paged_attend_call(q, pool, layer, page_tables, lengths, keep,
                        latent: int, sm_scale: float, interpret: bool,
-                       run: int = 1):
+                       run: int = 1, fixed: int = 0):
     lanes, heads, width = q.shape
     page_size, max_pages = pool.shape[2], page_tables.shape[1]
-    block_pages = min(ATTEND_WALK_PAGES, max_pages)
-    span = max_pages * page_size
-    _whole_blocks(max_pages, block_pages, run)
+    fixed, pad, block_pages, blocks = _walk_layout(
+        max_pages, ATTEND_WALK_PAGES, run, fixed)
+    span = blocks * block_pages * page_size
+    if fixed:                   # the table's entries -> the walk's places
+        keep = jnp.pad(keep, ((0, 0), (
+            pad * page_size, span - (pad + max_pages) * page_size)))
     sublanes = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
     hp = -(-heads // sublanes) * sublanes
     qp = jnp.pad(q, ((0, 0), (0, hp - heads), (0, 0)))
@@ -339,7 +393,7 @@ def _paged_attend_call(q, pool, layer, page_tables, lengths, keep,
         functools.partial(_paged_attend_kernel, sm_scale=sm_scale,
                           latent=latent, page_size=page_size,
                           block_pages=block_pages, max_pages=max_pages,
-                          run=run),
+                          run=run, fixed=fixed),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(lanes,),
@@ -367,13 +421,19 @@ def _paged_attend_call(q, pool, layer, page_tables, lengths, keep,
 
 
 def step_kernels_tile(index_width: int, row_width: int, latent: int,
-                      page_size: int, max_pages: int, dtype) -> bool:
+                      page_size: int, max_pages: int, dtype,
+                      fixed: int = 0) -> bool:
     """Whether the step's two kernels tile these shapes: rows of whole
-    128-lanes, pages of whole sublanes, tables of whole blocks."""
+    128-lanes, pages of whole sublanes, tables of whole blocks, or with
+    `fixed` entries of the fixed class in front, which are walked in the
+    walks' own blocks whatever their length (`_walk_layout`)."""
     sublanes = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
-    return (index_width % 128 == 0 and row_width % 128 == 0
-            and latent % 128 == 0 and page_size % sublanes == 0
-            and max_pages % min(INDEX_WALK_PAGES, max_pages) == 0
+    if not (index_width % 128 == 0 and row_width % 128 == 0
+            and latent % 128 == 0 and page_size % sublanes == 0):
+        return False
+    if fixed:
+        return (ATTEND_WALK_PAGES * page_size) % 128 == 0
+    return (max_pages % min(INDEX_WALK_PAGES, max_pages) == 0
             and max_pages % min(ATTEND_WALK_PAGES, max_pages) == 0
             and (min(ATTEND_WALK_PAGES, max_pages) * page_size) % 128 == 0)
 
@@ -389,28 +449,30 @@ def keep_topk(scores, topk: int):
 
 
 def step_uses_kernels(index_width: int, row_width: int, latent: int,
-                      page_size: int, max_pages: int, dtype) -> bool:
+                      page_size: int, max_pages: int, dtype,
+                      fixed: int = 0) -> bool:
     """What `choose_paged` and `attend_chosen` are told by a caller that
     lets the platform being traced for and the shapes decide."""
-    return on_tpu() and step_kernels_tile(index_width, row_width, latent,
-                                          page_size, max_pages, dtype)
+    return on_tpu() and step_kernels_tile(
+        index_width, row_width, latent, page_size, max_pages, dtype, fixed)
 
 
 def choose_paged(q_idx, w, idx_pool, layer: int, page_tables, lengths,
                  topk: int, kernel: bool, interpret: bool = False,
-                 run: int = 1):
+                 run: int = 1, fixed: int = 0):
     """A decode step's indexer of one layer: the scores of every position
     a lane holds and the `topk` best of them. Shapes as
     `index_scores_paged`. Returns (the choice as `attend_chosen` takes it,
     positions chosen a lane (B,) int32). `kernel`: the walk over the live
     index pages as a kernel and the choice a mask over the table's span
-    (`keep_topk`), else the gather and `select_topk`'s positions. `run`:
-    the pages one copy of the kernel's walk brings, which the tables'
-    owner vouches lie in such runs (`_walk_blocks`)."""
+    (`keep_topk`), else the gather and `select_topk`'s positions. `run`,
+    `fixed`: the pages one copy of the kernel's walk brings behind the
+    tables' first `fixed` entries, which the tables' owner vouches lie in
+    such runs (`_walk_blocks`)."""
     if kernel:
         keep = keep_topk(_paged_index_call(
             q_idx, w, idx_pool, layer, page_tables, lengths, interpret,
-            run=run), topk)
+            run=run, fixed=fixed), topk)
         return keep, jnp.sum(keep, axis=1).astype(jnp.int32)
     positions, chosen = select_topk(index_scores_paged(
         q_idx, w, idx_pool, layer, page_tables, lengths), topk)
@@ -419,15 +481,15 @@ def choose_paged(q_idx, w, idx_pool, layer: int, page_tables, lengths,
 
 def attend_chosen(q, pool, layer: int, page_tables, lengths, choice,
                   latent: int, sm_scale: float, kernel: bool,
-                  interpret: bool = False, run: int = 1):
+                  interpret: bool = False, run: int = 1, fixed: int = 0):
     """The absorbed attention over what `choose_paged` chose (with the same
-    `kernel` and `run`): every live row read and the chosen kept, as a
-    kernel, or the chosen rows gathered. Returns (B, heads, latent) in q's
-    dtype."""
+    `kernel`, `run` and `fixed`): every live row read and the chosen kept,
+    as a kernel, or the chosen rows gathered. Returns (B, heads, latent)
+    in q's dtype."""
     if kernel:
         return _paged_attend_call(q, pool, layer, page_tables, lengths,
                                   choice, latent, float(sm_scale), interpret,
-                                  run=run)
+                                  run=run, fixed=fixed)
     return mla_selected_attention(q, pool, layer, page_tables, *choice,
                                   latent, sm_scale)
 

@@ -223,19 +223,22 @@ class EngineCore:
         self.config = config
         self.page_size = int(page_size)
         self.max_batch = int(max_batch)
-        self.max_pages_per_seq = pages_needed(config.max_seq_len,
-                                              self.page_size)
+        # the config's type names the model class; the engine asks the
+        # model for its cache and programs and names no class itself
+        with _SetupPhase(self.record, _sp.SETUP_MODEL):
+            self.model = build_model(config, mesh)
+        self.params = params
+        # a table's entries: a full-length sequence's pages, or where the
+        # class keeps a fixed page and asks for runs, its fixed entries
+        # and then whole runs (the model's word: the step's walks read it)
+        self.max_pages_per_seq = self.model.table_pages(
+            self.page_size, pages_needed(config.max_seq_len, self.page_size))
         if not num_pages:
             # default pool: every decode lane can hold a full-length
             # sequence (the mesh-budget path goes through
             # kv_cache.pages_from_budget at engine construction)
             num_pages = self.max_batch * self.max_pages_per_seq
         self.num_pages = int(num_pages)
-        # the config's type names the model class; the engine asks the
-        # model for its cache and programs and names no class itself
-        with _SetupPhase(self.record, _sp.SETUP_MODEL):
-            self.model = build_model(config, mesh)
-        self.params = params
         # what a model keeps of a sequence for ever (a window layer's
         # ring of pages, a recurrent layer's state) is named by the first
         # `_fixed` pages a sequence holds: the allocator's fixed class
@@ -379,12 +382,17 @@ class EngineCore:
                                  * self.page_size)
         # the kernel's walk over a lane by the pages it holds: (blocks,
         # positions its matmuls multiply), by the kernel's own rule
-        from ray_tpu.ops.paged_attention import (walk_counts,
+        # (a table's entries lie `pad` places into the walk where runs
+        # open behind a fixed class's entries)
+        from ray_tpu.ops.paged_attention import (run_pad, walk_counts,
                                                  walk_first_blocks_hidden)
         self._walk_hidden = walk_first_blocks_hidden
+        # the table entries a walk copies a page each before its runs
+        self._walk_head = self._fixed if self.alloc.run > 1 else 0
+        pad = run_pad(self._walk_head, self.alloc.run)
         self._walk_block = self.model.walk_block_pages(
-            self.page_size, self.max_pages_per_seq)
-        self._walk = [walk_counts(n, self._walk_block, self.page_size)
+            self.page_size, self.max_pages_per_seq + pad)
+        self._walk = [walk_counts(n, self._walk_block, self.page_size, pad)
                       for n in range(self.max_pages_per_seq + 1)]
 
     # ------------------------------------------------------ intake
@@ -654,8 +662,8 @@ class EngineCore:
             pts = np.full((B, self.max_pages_per_seq), -1, np.int32)
             active = np.zeros((B,), bool)
             kernel = self._attention != "einsum"
-            run = self.alloc.run
-            live = copies = blocks = attended = 0
+            run, head = self.alloc.run, self._walk_head
+            live = copies = read = blocks = attended = 0
             walked: List[int] = []
             fixed: Dict[str, int] = {}
             for seq in batch:
@@ -665,7 +673,11 @@ class EngineCore:
                 active[i] = True
                 live += seq.device_len
                 pages = pages_needed(seq.device_len, self.page_size)
-                copies += -(-pages // run)
+                # the walk's copies: a page each of the table's fixed
+                # entries, then a run each, whole
+                runs = -(-max(pages - head, 0) // run)
+                copies += min(pages, head) + runs
+                read += (min(pages, head) + runs * run) * self.page_size
                 if kernel:
                     blocks += self._walk[pages][0]
                     attended += self._walk[pages][1]
@@ -677,11 +689,11 @@ class EngineCore:
             args = (jnp.asarray(positions), jnp.asarray(pts),
                     jnp.asarray(active))
         # the kernel copies in each lane's live pages, whole, a run of the
-        # allocator's a copy
-        read = (copies * run * self.page_size if kernel
-                else self._table_positions)
-        if not kernel:      # the gather multiplies all it reads, in one
-            attended, copies = read, 0
+        # allocator's a copy; the gather reads every table entry and
+        # multiplies all it reads, in one
+        if not kernel:
+            read = attended = self._table_positions
+            copies = 0
         hidden = self._walk_hidden(walked)
         c["decode_steps"] += 1
         c["decode_kernel_steps"] += int(kernel)

@@ -43,12 +43,12 @@ stays that whatever follows. A *run* is `run` pages of the class that
 grows whose ids lie behind one another from a multiple of `run` on, `[g x
 run, g x run + run)`. A model class whose decode walk is bound by the
 copies it starts and not by their bytes asks for one
-(`models.paged.PagedDecoder.page_run`, from shapes alone: `SparseMLAMoE`,
-whose two walks then bring a run a copy, `ops/sparse_attention.py`, and
-since PR 64 the latent classes without a fixed page, `MLAMoE` and
-`ShortcutMLAMoE`, whose kernel does, `ops/paged_attention.py`; 1 for the
-per-head classes and for every class that keeps a fixed page, whose runs
-would start at table entry `fixed`), the engine passes the answer on
+(`models.paged.PagedDecoder.page_run`, from shapes alone, as its mixers
+answer: the two sparse walks by their index keys' page,
+`ops/sparse_attention.py`; the latent kernel by its rows' page, PR 64; the
+per-head kernel by one pool's page, 16 KB asking for 4 and 8 KB for 8, PR
+66, `ops/paged_attention.py`; 1 from 32 KB a pool on, over a ring, and
+wherever a step gathers), the engine passes the answer on
 (`PageAllocator(run=)`) and keeps no notion of a run of its own. The
 allocator then hands the class out and takes it back in whole runs: what a
 sequence holds of it is rounded up to whole runs (`alloc(1, held)` at a
@@ -58,8 +58,19 @@ every k the table's entries `fixed + k x run ..` that are held are `p, p +
 them: the sequence's own, masked by its length as the unused tail of a last
 page is. What it costs: at most `run - 1` pages a sequence held ahead of
 need, and the pages of the class that make no whole run (`unused_pages`,
-under `run` at each end), which nobody gets. With `run` 1 the free list,
-the order of ids and every answer are the allocator's without runs.
+under `run` at each end), which nobody gets.
+
+**A table of a class that keeps a fixed page** (PR 66) is its `fixed`
+entries, handed out page by page in any order, and then whole runs: the
+runs open at table entry `fixed`, not 0, the walks are told `fixed` beside
+`run` and copy the first `fixed` entries a page each and the rest a run
+each, and the table is `fixed + ceil((pages - fixed) / run) x run` entries
+wide (`ops.paged_attention.run_table_pages`, asked through
+`PagedDecoder.table_pages`: the engine's `max_pages_per_seq`), so that a
+sequence at full length fits with the last run it is handed. A ring's own
+walk still reads the table's first `fixed` entries a page at a time. With
+`run` 1 the free list, the order of ids and every answer are the
+allocator's without runs.
 """
 from __future__ import annotations
 

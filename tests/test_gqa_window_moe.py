@@ -540,7 +540,8 @@ def _program_text(fn, *args):
 
 
 def _programs(model, cfg, B=2, s=32, page=16):
-    mp = cfg.max_seq_len // page
+    with compute_platform("tpu"):   # the table as wide as the class says
+        mp = model.table_pages(page, cfg.max_seq_len // page)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     fixed = B * model.fixed_pages(page)
     cache = jax.eval_shape(lambda: model.init_cache(
@@ -597,8 +598,8 @@ PINNED = {
     # tiny rows are no shape the latent kernel tiles, and the latter keeps
     # a fixed page, so a run of 1, whatever its rows
     ("ShortcutMLAMoE", "decode_step"): "4de7c6fe6411287f",
-    ("HybridSSMMoE", "prefill"): "2423f3d6a6654486",
-    ("HybridSSMMoE", "decode_step"): "f046ae2debee7f59",
+    ("HybridSSMMoE", "prefill"): "a63f8231742dfc7c",
+    ("HybridSSMMoE", "decode_step"): "28b7025de7c15535",
     # the seventh class, pinned in PR 50 to the text PR 50 gave it: what it
     # shares (`models/latent.py` without a LoRA and with the heads' gate,
     # `route_topk` under a group limit, `ops/kda.py`) is held from here on;
@@ -615,8 +616,8 @@ PINNED = {
     # their hashes, as do the twelve others. The eighth class, pinned to the
     # text PR 54 gave it: five query heads a kv head, a scaled key rotated,
     # both mixers' kernels in the one layer
-    ("ParallelHybrid", "prefill"): "0974a39ba83215f5",
-    ("ParallelHybrid", "decode_step"): "d62d5e9fcb638d6d",
+    ("ParallelHybrid", "prefill"): "21868d3701abe89c",
+    ("ParallelHybrid", "decode_step"): "2438e0ed0cacbae7",
     # PR 58 let the page walk take heads that are a share of a 128-lane
     # (`ops.paged_attention.LANE`: the wrapper packs them, `_paged_decode_call`
     # is given its `sm_scale`), the convolution run without its SiLU
@@ -626,8 +627,8 @@ PINNED = {
     # their hashes. The ninth class, pinned to the text PR 58 gave it: two
     # kv heads of 64 as one lane under eight query rows, the gated
     # convolution, a router under a bias and no shared expert, a tied head
-    ("GatedConvMoE", "prefill"): "f6c72aa2a2917f9b",
-    ("GatedConvMoE", "decode_step"): "5aa9c8af7f17b51c",
+    ("GatedConvMoE", "prefill"): "fd25f7776bc01dfe",
+    ("GatedConvMoE", "decode_step"): "d7858856f2850810",
     # the tenth class, pinned in PR 63 to PR 62's text (prefill
     # d9caf436e4f2bd1d, decode_step 5ee65bfddb48e168) before the mixers were
     # lifted out of the classes and `models/paged.py` walked a table of them.
@@ -716,17 +717,44 @@ PINNED_CONFIGS = {
 }
 
 
+# PR 66 gave the per-head walk a run by what a pool's page weighs and let
+# a class that keeps a fixed page lay runs behind it. These tiny configs'
+# pages are 4-8 KB a pool, so every per-head class here would answer 8,
+# where Laguna's and Olmo's deployments (32 KB a pool and more) answer 1
+# and run the parent's programs: their two classes are traced at that
+# answer and keep their hashes, as do `Transformer`'s (its own walk),
+# the three latent classes' without a fixed page and `HybridKDAMoE`'s (its
+# tiny rows gather). The three per-head classes that keep a slot and whose
+# deployments ask for runs (`HybridSSMMoE`, `ParallelHybrid`,
+# `GatedConvMoE`) are pinned anew to PR 66's text: tables of 1 + 8
+# entries, the first a page a copy and a run of 8 behind it
+AT_THEIR_DEPLOYMENTS_RUN = {"GQAWindowMoE": 1, "HybridDelta": 1}
+
+
 @pytest.mark.parametrize("name,program", sorted(PINNED))
-def test_older_models_programs_lower_to_the_parents_text(name, program):
+def test_older_models_programs_lower_to_the_parents_text(monkeypatch, name,
+                                                         program):
     cfg = PINNED_CONFIGS[name]()
-    text = _programs(build_model(cfg), cfg)[program]
+    model = build_model(cfg)
+    if name in AT_THEIR_DEPLOYMENTS_RUN:
+        run = AT_THEIR_DEPLOYMENTS_RUN[name]
+        monkeypatch.setattr(type(model), "page_run", lambda self, *a: run)
+    text = _programs(model, cfg)[program]
     assert "pallas_call" in text            # the kernels are in the text
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PINNED[
         (name, program)]
 
 
-# a pin PR 64 rewrote, as the parent wrote it: the text with the run at 1
-PINNED_AT_RUN_1 = {("ShortcutMLAMoE", "decode_step"): "b117688674ce4a5d"}
+# a pin PR 64 rewrote, as the parent wrote it: the text with the run at 1;
+# and the six PR 66 rewrote (a prefill's text moves with its table's width
+# alone), as PR 65 held them
+PINNED_AT_RUN_1 = {("ShortcutMLAMoE", "decode_step"): "b117688674ce4a5d",
+                   ("HybridSSMMoE", "prefill"): "2423f3d6a6654486",
+                   ("HybridSSMMoE", "decode_step"): "f046ae2debee7f59",
+                   ("ParallelHybrid", "prefill"): "0974a39ba83215f5",
+                   ("ParallelHybrid", "decode_step"): "d62d5e9fcb638d6d",
+                   ("GatedConvMoE", "prefill"): "f6c72aa2a2917f9b",
+                   ("GatedConvMoE", "decode_step"): "5aa9c8af7f17b51c"}
 
 
 @pytest.mark.parametrize("name,program", sorted(PINNED_AT_RUN_1))

@@ -325,10 +325,13 @@ def test_dense_prefill_for_the_chip_walks_blocks_of_1024(
 
 
 # ------------------------------------- the second architecture's step
-def _compile_served(devices, model, which: str, make_cache, kernels: str):
+def _compile_served(devices, model, which: str, make_cache, kernels: str,
+                    run: int = 1):
     """The decode step (32 lanes) or the prefill of a whole
     `max_seq_len` of a model with its own programs, as `EngineCore` jits
-    them, for one chip, 16-token bf16 pages. Returns (compiled, cache)."""
+    them, for one chip, 16-token bf16 pages, the tables as wide as the
+    class says (`table_pages`: its fixed entries and whole runs of `run`,
+    the class's answer at these shapes). Returns (compiled, cache)."""
     one = SingleDeviceSharding(devices[0])
 
     def on_chip(tree):
@@ -343,20 +346,26 @@ def _compile_served(devices, model, which: str, make_cache, kernels: str):
     lanes, seq = 32, model.config.max_seq_len
     with compute_platform("tpu"):
         assert model.decode_attention(PAGE) == kernels
+        assert model.page_run(PAGE, seq // PAGE) == run
+        fixed = model.fixed_pages(PAGE)
+        table = model.table_pages(PAGE, seq // PAGE)
+        assert table == (seq // PAGE if run == 1 or not fixed else
+                         fixed + -(-(seq // PAGE - fixed) // run) * run)
+        assert model.page_run(PAGE, table) == run
         if which == "step":
             def _step(params, cache, tokens, positions, pts, active):
                 return model.decode_step(params, cache, tokens, positions,
                                          pts, active, PAGE)
             traced = jax.jit(_step, donate_argnums=(1,)).trace(
                 params, cache, ints(lanes), ints(lanes),
-                ints(lanes, seq // PAGE), jax.ShapeDtypeStruct(
+                ints(lanes, table), jax.ShapeDtypeStruct(
                     (lanes,), jnp.bool_, sharding=one))
         else:
             def _pre(params, tokens, true_len, page_table, cache):
                 return model.prefill(params, tokens, true_len, page_table,
                                      cache, PAGE)
             traced = jax.jit(_pre, donate_argnums=(4,)).trace(
-                params, ints(seq), ints(), ints(seq // PAGE), cache)
+                params, ints(seq), ints(), ints(table), cache)
         return traced.lower().compile(), cache
 
 
@@ -384,7 +393,7 @@ def _compile_mla_moe(devices, which: str):
         assert model.page_run(PAGE, 2048 // PAGE) == 4
     compiled, cache = _compile_served(
         devices, model, which, lambda: model.init_cache(PAGES, PAGE),
-        "mla_paged_decode_attn")
+        "mla_paged_decode_attn", run=4)
     return compiled, cache["kv"].shape
 
 
@@ -509,7 +518,7 @@ def _compile_shortcut_mla_moe(devices, which: str):
         assert model.page_run(PAGE, 2048 // PAGE) == 4
     compiled, cache = _compile_served(
         devices, model, which, lambda: model.init_cache(PAGES, PAGE),
-        "mla_paged_decode_attn")
+        "mla_paged_decode_attn", run=4)
     return compiled, cache["kv"].shape
 
 
@@ -552,7 +561,7 @@ def test_sparse_mla_moe_programs_hold_their_kernels_by_name(
         experts_held=(0, 16), max_seq_len=4096))
     compiled, cache = _compile_served(
         topo.devices, model, which, lambda: model.init_cache(PAGES, PAGE),
-        sparse_attention.KERNEL_PAGED_ATTEND)
+        sparse_attention.KERNEL_PAGED_ATTEND, run=8)
     names = kernel_names(compiled.as_text())
     assert names.count(grouped_matmul.KERNEL_GMM) == 3
     assert paged_attention.KERNEL_MLA_PAGED_DECODE not in names
@@ -597,14 +606,16 @@ def test_sparse_window_mla_moe_programs_hold_their_kernels_by_name(
         topo.devices, model, which,
         lambda: model.init_cache(PAGES, PAGE, fixed_pages=ring),
         sparse_attention.KERNEL_PAGED_ATTEND + "+"
-        + paged_attention.KERNEL_MLA_PAGED_WINDOW_DECODE)
+        + paged_attention.KERNEL_MLA_PAGED_WINDOW_DECODE, run=8)
     names = kernel_names(compiled.as_text())
     assert names.count(grouped_matmul.KERNEL_GMM) == 6
     assert paged_attention.KERNEL_MLA_PAGED_DECODE not in names
     assert paged_attention.KERNEL_PAGED_WINDOW_DECODE not in names
-    if which == "step":     # a fixed page: a page a copy (PERF.md 7)
+    if which == "step":     # the ring's 34 entries, then runs of 8 (PR 66)
         with compute_platform("tpu"):
-            assert model.page_run(PAGE, 4096 // PAGE) == 1
+            assert model.page_run(PAGE, 4096 // PAGE) == 8
+            assert model.table_pages(PAGE, 4096 // PAGE) == 34 + 224
+            assert model.window_attention.page_run(PAGE, 256, 34) == 1
         assert names.count(sparse_attention.KERNEL_PAGED_INDEX) == 1
         assert names.count(sparse_attention.KERNEL_PAGED_ATTEND) == 1
         assert names.count(
@@ -639,7 +650,7 @@ def _compile_hybrid_ssm_moe(devices, which: str, slots: int = 32):
     return _compile_served(
         devices, model, which, lambda: model.init_cache(
             PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
-        "paged_decode_attn+ssd_step")
+        "paged_decode_attn+ssd_step", run=8)     # 8 KB a pool's page
 
 
 @pytest.mark.parametrize("which", ["step", "prefill"])
@@ -682,7 +693,7 @@ def _compile_hybrid_kda_moe(devices, which: str, slots: int = 32):
     return _compile_served(
         devices, model, which, lambda: model.init_cache(
             PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
-        "mla_paged_decode_attn+kda_step")
+        "mla_paged_decode_attn+kda_step", run=4)     # 20 KB of latent rows
 
 
 @pytest.mark.parametrize("which", ["step", "prefill"])
@@ -729,7 +740,7 @@ def _compile_parallel_hybrid(devices, which: str, slots: int = 32,
     return _compile_served(
         devices, model, which, lambda: model.init_cache(
             PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
-        "paged_decode_attn+ssd_step")
+        "paged_decode_attn+ssd_step", run=4)    # 16 KB a pool's page
 
 
 @pytest.mark.parametrize("which", ["step", "prefill"])
@@ -776,7 +787,7 @@ def _compile_gated_conv_moe(devices, which: str, slots: int = 64,
     return _compile_served(
         devices, model, which, lambda: model.init_cache(
             PAGES, PAGE, fixed_pages=slots * model.fixed_pages(PAGE)),
-        "paged_decode_attn")
+        "paged_decode_attn", run=4)             # 16 KB a pool's page
 
 
 @pytest.mark.parametrize("which", ["step", "prefill"])

@@ -323,22 +323,32 @@ def test_the_latent_walks_run_is_the_pages_bytes_answer():
 
 
 # the run each served configuration's class answers its engine at its
-# deployment's shapes, where the kernels run: the two latent classes without
-# a fixed page by their page's bytes, `SparseMLAMoE` by its index keys'
-# (PR 62), and 1 for every class that keeps a fixed page (a state slot, a
-# ring: its runs would start at table entry `fixed`) and every per-head one
+# deployment's shapes, where the kernels run: the latent classes by their
+# page's bytes, `SparseMLAMoE` and dots3-note-prev's class by their index
+# keys' (PR 62), the per-head classes by one pool's page (PR 66: 16 KB asks
+# for 4, 8 KB for 8, 32 KB and more for a page a copy). A class that keeps a
+# fixed page (a state slot, a ring) has its runs open at table entry `fixed`
+# and its table made whole runs behind it (PR 66); a ring's own walk
+# answers 1
 SERVED_RUNS = {
     "internlm2-1.8b": 1, "laguna-xs.2-1chip": 1, "olmo-hybrid-7b-1chip": 1,
-    "nemotron-3-super-120b-a12b-1chip": 1, "ling-3.0-flash-vl-1chip": 1,
-    "falcon-h1-34b-instruct-1chip": 1, "lfm2-8b-a1b-1chip": 1,
+    "nemotron-3-super-120b-a12b-1chip": 8, "ling-3.0-flash-vl-1chip": 4,
+    "falcon-h1-34b-instruct-1chip": 4, "lfm2-8b-a1b-1chip": 4,
+    "dots3-note-prev-1chip": 8,
     "glm-5-1chip": 8, "glm-4.7-flash-1chip": 4,
     "longcat-flash-chat-1chip": 4}
+# (fixed entries, table entries) of the classes whose table PR 66 widened
+SERVED_TABLES = {
+    "nemotron-3-super-120b-a12b-1chip": (1, 513),
+    "ling-3.0-flash-vl-1chip": (1, 1025),
+    "falcon-h1-34b-instruct-1chip": (1, 161), "lfm2-8b-a1b-1chip": (1, 257),
+    "dots3-note-prev-1chip": (34, 1026)}
 
 
 @pytest.mark.parametrize("name", sorted(SERVED_RUNS))
 def test_a_served_class_answers_its_run_from_shapes_alone(name):
     import json
-    from ray_tpu.models.latent import LatentAttention
+    from ray_tpu.models.latent import WindowLatentAttention
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
                            "configs", name + ".json")) as f:
         cfg = json.load(f)
@@ -347,15 +357,21 @@ def test_a_served_class_answers_its_run_from_shapes_alone(name):
         cfg, max_seq_len=dep["context_limit"]))
     page, table = dep["page_size"], dep["context_limit"] // dep["page_size"]
     assert model.page_run(page, table) == 1                  # off the TPU
+    assert model.table_pages(page, table) == table
+    run, fixed = SERVED_RUNS[name], model.fixed_pages(page)
     with compute_platform("tpu"):
-        assert model.page_run(page, table) == SERVED_RUNS[name]
-        if model.fixed_pages(page):     # whatever a mixer alone would say
-            assert model.page_run(page, table) == 1
-            assert name != "ling-3.0-flash-vl-1chip" or [
-                m.page_run(page, table) for m in model.mixers
-                if isinstance(m, LatentAttention)] == [4]
-    run = SERVED_RUNS[name]
-    assert table % run == 0 and dep["num_pages"] % run == 0
+        assert model.page_run(page, table) == run
+        wide = model.table_pages(page, table)
+        assert (fixed, wide) == SERVED_TABLES.get(name, (fixed, table))
+        # the step reads the run off the table it is handed: the same
+        assert model.page_run(page, wide) == run
+        assert model.table_pages(page, wide) == wide
+        for mixer in model.mixers:      # a ring begins at any entry
+            if isinstance(mixer, WindowLatentAttention) or getattr(
+                    mixer, "window", None) is not None:
+                assert mixer.page_run(page, table, fixed) == 1
+    assert (wide - fixed) % run == 0 and wide >= table
+    assert fixed or (table % run == 0 and dep["num_pages"] % run == 0)
 
 
 def test_the_kernel_is_handed_the_run_the_class_answered(monkeypatch):

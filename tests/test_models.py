@@ -522,7 +522,7 @@ def _tiny_models():
                                   "parallel_hybrid", "gated_conv_moe",
                                   "sparse_mla_moe",
                                   "sparse_window_mla_moe"])
-def test_every_class_answers_the_engines_thirteen_asks(name):
+def test_every_class_answers_the_engines_fourteen_asks(name):
     """What `EngineCore` calls on a model, on every class of the table,
     with the types it uses them as (`models.paged.PagedDecoder`)."""
     from ray_tpu.models import MODELS, build_model, model_config
@@ -576,7 +576,15 @@ def test_every_class_answers_the_engines_thirteen_asks(name):
         {"fixed_pages": fixed} if fixed else {}))
     assert isinstance(model.cache_stats(real), dict)            # 11
     assert model.pool_rows is None or model.pool_rows >= 1      # 12
-    assert mp % model.page_run(page, mp) == 0                   # 13
+    from ray_tpu.ops.dispatch import compute_platform
+    for platform in (None, "tpu"):      # (where the kernels run: runs)
+        with compute_platform(platform):
+            run = model.page_run(page, mp)                      # 13
+            table = model.table_pages(page, mp)                 # 14
+            assert model.page_run(page, table) == run
+        assert table == (mp if run == 1 or not fixed else
+                         fixed + -(-(mp - fixed) // run) * run)
+        assert (table - fixed) % run == 0 or not fixed and mp % run == 0
     # what the byte asks say the cache costs is what `init_cache` makes
     # (the counts apart): `num_pages` pages, and of the fixed class a
     # ring's pages, or the sequences' slots and one more, nobody's

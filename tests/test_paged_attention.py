@@ -567,6 +567,199 @@ def test_a_walk_of_runs_refuses_tables_it_cannot_lay_runs_in(
                                              LLATENT, 0.1, run=run)
 
 
+# ---------------------- runs behind a table's fixed entries (PR 66)
+def _fixed_case(kernel, lengths, run, fixed, max_pages=48, seed=0,
+                holes=()):
+    """A float32 case of `kernel` ("latent": a pool of `LWIDTH` rows and 5
+    heads; "heads": keys and values of 2 kv heads of 128 under 4 query
+    heads, pages of `LPAGE`) whose tables are laid as the allocator lays
+    those of a class that keeps `fixed` pages: a lane's first `fixed`
+    entries ids of the fixed class (`0 .. lanes x fixed - 1`, in no order),
+    then whole runs of `run` ids behind one another from a multiple of
+    `run` on, anywhere behind that class; the table `run_table_pages`
+    wide; what a lane holds ahead of its length holds `AHEAD`; `holes`
+    (lane, entry) are unassigned, a run's first entry the whole run.
+    Returns (q, pools, tables, lengths)."""
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+    table = pa.run_table_pages(max_pages, fixed, run)
+    first = -(-B * fixed // run) * run
+    pages = first + B * (table - fixed) + 2 * run
+    width, heads = (LWIDTH, 5) if kernel == "latent" else (2 * 128, 4)
+    pools = [rng.normal(size=(2, pages, LPAGE, width)).astype(np.float32)
+             for _ in range(1 if kernel == "latent" else 2)]
+    q = jnp.asarray(rng.normal(size=(
+        B, heads, LWIDTH if kernel == "latent" else 128)), jnp.float32)
+    tables = np.full((B, table), -1, np.int32)
+    singles = list(rng.permutation(B * fixed))
+    free = list(first + rng.permutation((pages - first) // run) * run)
+    for b, n in enumerate(lengths):
+        live = -(-n // LPAGE)
+        tables[b, :min(live, fixed)] = [
+            singles.pop() for _ in range(min(live, fixed))]
+        for k in range(-(-max(live - fixed, 0) // run)):
+            at = fixed + k * run
+            tables[b, at:at + run] = free.pop() + np.arange(run)
+        ahead = tables[b, live:][tables[b, live:] >= 0]
+        for pool in pools:
+            pool[:, ahead] = AHEAD
+    for b, e in holes:
+        tables[b, e:e + (run if e >= fixed else 1)] = -1
+    return q, tuple(jnp.asarray(pool) for pool in pools), jnp.asarray(
+        tables), jnp.asarray(lengths, jnp.int32)
+
+
+def _fixed_both_ways(kernel, case, layer, run, fixed):
+    q, pools, tables, ln = case
+    if kernel == "latent":
+        return (pa.mla_paged_decode_attention_kernel(
+            q, *pools, layer, tables, ln, LLATENT, 0.1, run=run,
+            fixed=fixed), pa.mla_paged_attention_reference(
+                q, *pools, layer, tables, ln, LLATENT, 0.1))
+    return (pa.paged_decode_attention_kernel(
+        q, *pools, layer, tables, ln, run=run, fixed=fixed),
+        pa.paged_attention_reference(q, *pools, layer, tables, ln))
+
+
+def _fixed_blocks_of_16(walk_budget, kernel):
+    pools = 1 if kernel == "latent" else 2
+    walk_budget(pa.BLOCK_SLOTS * 16 * pools * LPAGE * LWIDTH * 4)
+    assert pa.walk_block_pages(pools * LPAGE * LWIDTH * 4, LPAGE, 48) == 16
+
+
+@pytest.mark.parametrize("kernel,fixed,run", [
+    (kernel, fixed, run) for kernel in ("latent", "heads")
+    for fixed in (1, 34) for run in (1, 4, 8)])
+def test_kernels_copy_runs_behind_a_tables_fixed_entries(
+        walk_budget, walk_spy, kernel, fixed, run):
+    """Both kernels that take `fixed` give what the gather gives over
+    tables of `fixed` single pages, in no order, and whole runs of `run`
+    behind them, in blocks of 16 pages: a lane that holds nothing, one
+    inside its fixed entries, one that holds exactly those, one a run's
+    first page alone, one that ends inside a run, one across three blocks
+    and one at the table's end; nothing held ahead of a lane's length is
+    read into the output; the copies are a page each of the fixed entries
+    and one a run behind them, and a lane still finds its first block
+    started by the lane before it."""
+    table = pa.run_table_pages(48, fixed, run)
+    lengths = (0, fixed * LPAGE - 3, fixed * LPAGE, fixed * LPAGE + 1,
+               (fixed + run) * LPAGE + 3, 43 * LPAGE - 1, table * LPAGE)
+    case = _fixed_case(kernel, lengths, run, fixed, seed=29)
+    assert case[2].shape == (len(lengths), table)
+    _fixed_blocks_of_16(walk_budget, kernel)
+    got, want = _fixed_both_ways(kernel, case, 1, run, fixed)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got)).max() < 1e3      # nothing of AHEAD
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got[0]).any()
+    started, waited = walk_spy(len(lengths))
+    pages = _pages(lengths, LPAGE)
+    pools = 1 if kernel == "latent" else 2
+    assert waited.sum() == pools * sum(
+        min(n, fixed) + -(-max(n - fixed, 0) // run) for n in pages)
+    handed = _handed_on(started, waited)
+    assert handed == [b for b, n in enumerate(lengths) if n][1:]
+
+
+@pytest.mark.parametrize("kernel,fixed,run", [
+    ("latent", 1, 4), ("heads", 1, 8), ("latent", 34, 8), ("heads", 34, 4)])
+def test_holes_among_fixed_entries_and_runs_are_not_read(
+        walk_budget, kernel, fixed, run):
+    """-1 at a fixed entry is that page's hole, at a run's first entry the
+    run's: in a block multiplied whole and in a tail, not copied, not
+    seen."""
+    lengths = (48 * LPAGE - 5, (fixed + run) * LPAGE + 9, 20 * LPAGE)
+    holes = [(0, 0), (0, fixed + run), (0, fixed + 3 * run),
+             (1, fixed - 1), (2, fixed)]
+    case = _fixed_case(kernel, lengths, run, fixed, seed=31, holes=holes)
+    assert (np.asarray(case[2])[0, fixed + run:fixed + 2 * run] == -1).all()
+    _fixed_blocks_of_16(walk_budget, kernel)
+    got, want = _fixed_both_ways(kernel, case, 0, run, fixed)
+    assert np.abs(np.asarray(got)).max() < 1e3
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+def test_a_walk_behind_fixed_entries_refuses_a_table_of_no_whole_runs(
+        walk_budget):
+    case = _fixed_case("latent", (40, 40), 4, 1)
+    _fixed_blocks_of_16(walk_budget, "latent")
+    q, pools, tables, ln = case
+    with pytest.raises(ValueError, match="blocks of whole runs"):
+        pa.mla_paged_decode_attention_kernel(q, *pools, 0, tables, ln,
+                                             LLATENT, 0.1, run=4, fixed=2)
+
+
+# the kernels' texts as the parent (PR 65) traced them at these shapes (4
+# lanes, tables of 64 pages of 16, float32; sha256 of `str(jaxpr)` without
+# source lines): a walk told of no fixed entries, and one that walks a page
+# a copy whatever it is told, is that program to the byte
+KERNEL_TEXTS = {("latent", 1): "a4ded6dd67d81ee4",
+                ("latent", 4): "99189648cbaa751f",
+                ("heads", 1): "257e6db65feb88ae"}
+
+
+@pytest.mark.parametrize("kernel,run,fixed", [
+    *((kernel, run, 0) for kernel, run in sorted(KERNEL_TEXTS)),
+    ("latent", 1, 1), ("latent", 1, 34), ("heads", 1, 1), ("heads", 1, 34)])
+def test_a_walk_without_fixed_entries_traces_the_parents_text(kernel, run,
+                                                              fixed):
+    import hashlib
+    import re
+    S, i32, f32 = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
+    tables = (S((1,), i32), S((4, 64), i32), S((4,), i32))
+    if kernel == "latent":
+        traced = pa._mla_paged_decode_call.trace(
+            S((4, 8, 256), f32), S((2, 300, 16, 256), f32), *tables,
+            latent=128, sm_scale=0.1, interpret=True, run=run, fixed=fixed)
+    else:
+        pool = S((2, 300, 16, 256), f32)
+        traced = pa._paged_decode_call.trace(
+            S((4, 2, 4, 128), f32), pool, pool, *tables, interpret=True,
+            sm_scale=0.1, run=run, fixed=fixed)
+    text = re.sub(r" at (0x[0-9a-f]+|[^\s\]]+:\d+)", "", str(traced.jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == KERNEL_TEXTS[
+        kernel, run]
+
+
+def test_tables_and_runs_behind_fixed_entries_are_sized_in_one_place():
+    """`run_table_pages`, `run_pad`, `run_wholes` and the kernels' rules at
+    the five classes' shapes (PR 66): the run is sized on the entries that
+    grow, the table is its fixed entries and whole runs, and a table so
+    made answers the same run."""
+    assert [pa.run_pad(f, r) for f, r in ((0, 8), (1, 4), (34, 8), (33, 1),
+                                          (8, 8))] == [0, 3, 6, 0, 0]
+    assert pa.run_table_pages(1024, 0, 8) == 1024
+    assert pa.run_table_pages(1024, 34, 1) == 1024
+    # notes12k, docs16k, chat2k, turns4k, agent8k
+    assert [pa.run_table_pages(*a) for a in (
+        (1024, 34, 8), (1024, 1, 4), (160, 1, 4), (256, 1, 4),
+        (512, 1, 8))] == [1026, 1025, 161, 257, 513]
+    assert pa.run_table_pages(3, 34, 8) == 34       # under the fixed entries
+    latent, k16, k8 = 16 * 640 * 2, 16 * 512 * 2, 16 * 256 * 2
+    for table in (1024, 1025):
+        assert pa.mla_walk_run_pages(latent, 16, table, 1) == 4
+    for table, page, run in ((160, k16, 4), (161, k16, 4), (256, k16, 4),
+                             (257, k16, 4), (512, k8, 8), (513, k8, 8)):
+        assert pa.decode_walk_run_pages(page, 2 * page, 16, table, 1) == run
+    # 32 KB a pool and more: a page a copy (Laguna's, Olmo's, InternLM2's)
+    assert pa.decode_walk_run_pages(32 << 10, 64 << 10, 16, 512, 33) == 1
+    assert pa.decode_walk_run_pages(16 * 30 * 128 * 2, 2 * 16 * 30 * 128 * 2,
+                                    16, 192, 1) == 1
+    # a run no longer than the least power of two that holds what grows,
+    # and a divisor of the walk's block as long as can be
+    assert pa.mla_walk_run_pages(latent, 16, 3, 1) == 2
+    assert pa.run_table_pages(3, 1, 2) == 3
+    assert pa.mla_walk_run_pages(latent, 16, 4, 1) == 4      # 3 -> 4
+    assert pa.run_table_pages(4, 1, 4) == 5
+    assert pa.mla_walk_run_pages(latent, 16, 5, 1) == 4
+    assert pa.run_wholes(256, 0, lambda n: min(64, n)) == (256, 64)
+    assert pa.run_wholes(160, 1, lambda n: min(64, n)) == (256, 64)
+    # the walk's count with the first block's empty places
+    assert pa.walk_counts(0, 64, 16, pad=3) == (0, 0)
+    assert pa.walk_counts(61, 64, 16, pad=3) == pa.walk_counts(64, 64, 16)
+    assert pa.walk_counts(62, 64, 16, pad=3) == pa.walk_counts(65, 64, 16)
+
+
 # page bytes of a layer (all pools), table pages: the five configurations
 CELLS = {
     "internlm2-1.8b": (2 * 16 * 8 * 128 * 2, 256),

@@ -148,13 +148,15 @@ def lower(built, program: str):
     def loss(params, tokens):
         return model.loss(params, {"tokens": tokens})
 
+    with compute_platform("tpu"):   # the table as wide as the class says
+        table = model.table_pages(PAGE, TABLE)
     if program == "_step":
         traced = jax.jit(_step, donate_argnums=(1,)).trace
-        args = (params, cache, sds(LANES), sds(LANES), sds(LANES, TABLE),
+        args = (params, cache, sds(LANES), sds(LANES), sds(LANES, table),
                 sds(LANES, dtype=jnp.bool_))
     elif program == "_pre":
         traced = jax.jit(_pre, donate_argnums=(4,)).trace
-        args = (params, sds(PROMPT), sds(), sds(TABLE), cache)
+        args = (params, sds(PROMPT), sds(), sds(table), cache)
     else:
         traced = jax.jit(loss if program == "loss"
                          else jax.grad(loss)).trace

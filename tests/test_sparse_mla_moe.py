@@ -280,11 +280,14 @@ def test_the_steps_kernels_choose_and_read_what_the_gathers_do(monkeypatch):
     assert not np.asarray(got[2]).any()
 
 
-def _run_tables(lengths, max_pages, num_pages, run, seed=1):
+def _run_tables(lengths, max_pages, num_pages, run, seed=1, fixed=0):
     """Tables of lanes that hold `lengths` positions, their pages handed
     out by the allocator in runs of `run`, a page (so a run) at a time in
-    any order of the lanes: the runs of a lane lie anywhere in the pool."""
-    alloc = PageAllocator(num_pages, run=run)
+    any order of the lanes: the runs of a lane lie anywhere in the pool,
+    behind its `fixed` pages of the fixed class, which come one by one in
+    that order too."""
+    alloc = PageAllocator(num_pages, fixed=fixed, sequences=len(lengths),
+                          run=run)
     rng = np.random.default_rng(seed)
     need = [-(-int(n) // PAGE) for n in lengths]
     held = [[] for _ in lengths]
@@ -350,6 +353,98 @@ def test_the_steps_kernels_copy_a_run_at_a_time(monkeypatch, run):
         sparse._paged_attend_call(*rest, keep, latent, 0.2, True, run=16)
 
 
+@pytest.mark.parametrize("fixed,run", [
+    (fixed, run) for fixed in (1, 34) for run in (1, 4, 8)])
+def test_the_steps_kernels_copy_runs_behind_a_tables_fixed_entries(
+        monkeypatch, fixed, run):
+    """Tables as a class that keeps a ring or a slot has them (PR 66):
+    `fixed` entries of the fixed class, handed out page by page in no
+    order, then whole runs. The two walks, told `fixed`, score and read
+    what the plain forms do: a lane that holds nothing, one inside its
+    fixed entries, one that holds exactly those, one a run's first page
+    alone, one that ends inside a run, one that crosses the walks' blocks
+    and one at the table's end; a page a copy up to entry `fixed`, a run a
+    copy from there on, whatever the pages ahead of a lane's length hold."""
+    _interpreted(monkeypatch, index_pages=16, attend_pages=8)
+    ks = jax.random.split(jax.random.PRNGKey(run), 5)
+    table = paged.run_table_pages(48, fixed, run)
+    assert table == fixed + -(-(48 - fixed) // run) * run
+    lengths = jnp.asarray([
+        0, fixed * PAGE - 3, fixed * PAGE, fixed * PAGE + 1,
+        (fixed + run) * PAGE + 3, 43 * PAGE - 1, table * PAGE], jnp.int32)
+    lanes, heads, width, latent, topk = len(lengths), 6, 256, 128, 48
+    pages = lanes * (table + run) + run
+    pool = jax.random.normal(ks[0], (2, pages, PAGE, width))
+    idx_pool = jax.random.normal(ks[1], (2, pages, PAGE, 128))
+    q = jax.random.normal(ks[2], (lanes, heads, width))
+    q_idx = jax.random.normal(ks[3], (lanes, 16, 128))
+    w = jax.random.normal(ks[4], (lanes, 16))
+    tables = _run_tables(lengths, table, pages, run, fixed=fixed)
+    t = np.asarray(tables)
+    assert (t[:, :fixed][t[:, :fixed] >= 0] < lanes * fixed).all()
+    firsts = t[:, fixed::run]
+    assert (firsts[firsts >= 0] >= lanes * fixed).all()
+    assert not (firsts[firsts >= 0] % run).any()
+    args = (q_idx, w, idx_pool, 1, tables, lengths)
+    plain = sparse.index_scores_paged(*args)
+    walked = sparse._paged_index_call(*args, True, run=run, fixed=fixed)
+    assert walked.shape == plain.shape == (lanes, table * PAGE)
+    assert (np.isfinite(plain) == np.isfinite(walked)).all()
+    assert float(jnp.abs(jnp.where(jnp.isfinite(plain), plain - walked,
+                                   0)).max()) < 1e-4
+    (positions, chosen), n = sparse.choose_paged(*args, topk, False)
+    keep, m = sparse.choose_paged(*args, topk, True, run=run, fixed=fixed)
+    assert list(np.asarray(n)) == list(np.asarray(m)) == [
+        min(int(length), topk) for length in lengths]
+    for lane in range(lanes):
+        assert set(np.flatnonzero(keep[lane])) == set(
+            np.asarray(positions[lane])[np.asarray(chosen[lane])])
+    rest = (q, pool, 1, tables, lengths)
+    got = sparse.attend_chosen(*rest, keep, latent, 0.2, True, run=run,
+                               fixed=fixed)
+    want = sparse.attend_chosen(*rest, (positions, chosen), latent, 0.2,
+                                False)
+    assert float(jnp.abs(got - want).max()) < 2e-5
+    assert not np.asarray(got[0]).any()
+    if run > 1:     # a table that is no whole runs behind its fixed entries
+        with pytest.raises(ValueError, match="whole runs"):
+            sparse._paged_attend_call(*rest, keep, latent, 0.2, True,
+                                      run=run, fixed=fixed + 1)
+
+
+# the two walks' texts as the parent (PR 65) traced them at these shapes
+# (4 lanes, tables of 64 pages of 16, 16 index heads; sha256 of
+# `str(jaxpr)` without source lines): a walk told of no fixed entries, or
+# walking a page a copy, is that program to the byte
+WALK_TEXTS = {("index", 1): "234be3dd4db38af6",
+              ("index", 8): "7501b1165cdae10b",
+              ("attend", 1): "04aadd342fe1ff4f",
+              ("attend", 8): "dff507a1a917eea7"}
+
+
+@pytest.mark.parametrize("walk,run,fixed", [
+    *((walk, run, 0) for walk, run in sorted(WALK_TEXTS)),
+    ("index", 1, 34), ("attend", 1, 34), ("index", 1, 1), ("attend", 1, 1)])
+def test_a_walk_without_fixed_entries_traces_the_parents_text(walk, run,
+                                                              fixed):
+    import hashlib
+    import re
+    S, i32, f32 = jax.ShapeDtypeStruct, jnp.int32, jnp.float32
+    tables = (S((1,), i32), S((4, 64), i32), S((4,), i32))
+    if walk == "index":
+        traced = sparse._paged_index_call.trace(
+            S((4, 16, 128), f32), S((4, 16), f32), S((2, 300, 16, 128), f32),
+            *tables, interpret=True, run=run, fixed=fixed)
+    else:
+        traced = sparse._paged_attend_call.trace(
+            S((4, 6, 256), f32), S((2, 300, 16, 256), f32), *tables,
+            S((4, 64 * 16), jnp.bool_), latent=128, sm_scale=0.1,
+            interpret=True, run=run, fixed=fixed)
+    text = re.sub(r" at (0x[0-9a-f]+|[^\s\]]+:\d+)", "", str(traced.jaxpr))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == WALK_TEXTS[
+        walk, run]
+
+
 def test_the_run_is_the_class_answer_from_shapes(monkeypatch):
     """`page_run`: 1 where a step runs no walk kernel (a context that
     cannot pass `index_topk`; shapes that do not tile, so every CPU
@@ -369,6 +464,15 @@ def test_the_run_is_the_class_answer_from_shapes(monkeypatch):
     assert SparseMLAMoE(cfg).page_run(8, 12) == 4
     under = tiny_sparse_mla_moe(index_topk=128)      # max_seq_len 128
     assert SparseMLAMoE(under).page_run(8, 16) == 1
+    # behind a fixed class's entries the run is sized on the entries that
+    # grow and the table is made whole runs of it (notes12k: 34 + 992)
+    assert sparse.walk_run_pages(16 * 128 * 2, 1024, 34) == 8
+    assert sparse.walk_run_pages(16 * 128 * 2, 1026, 34) == 8
+    assert paged.run_table_pages(1024, 34, 8) == 1026
+    assert sparse.walk_run_pages(16 * 128 * 2, 36, 34) == 2    # 2 entries
+    assert sparse.walk_run_pages(16 * 128 * 2, 40, 34) == 8    # 6 -> 8
+    assert paged.run_table_pages(40, 34, 8) == 42
+    assert sparse.walk_run_pages(16 * 128 * 2, 42, 34) == 8
     from ray_tpu.models import MLAMoE, mla_moe
     assert MLAMoE(mla_moe.tiny_mla_moe()).page_run(8, 16) == 1
 

@@ -360,7 +360,8 @@ def test_engine_core_serves_it_and_counts_its_rings():
     core = EngineCore(cfg, params, num_pages=24, page_size=8, max_batch=3)
     assert isinstance(core.model, SparseWindowMLAMoE)
     assert core.alloc.fixed_pages == 3 * paged.ring_pages(13, 8) == 9
-    assert core.alloc.run == 1      # a fixed page: no runs (PERF.md 7)
+    assert core.alloc.run == 1      # the gathers read any table: no runs
+    assert core.max_pages_per_seq == cfg.max_seq_len // 8
     rng = np.random.default_rng(0)
     prompts = {"a": rng.integers(0, 256, 21).tolist(),  # past both limits
                "b": [5, 6, 7],                          # under both
@@ -405,6 +406,15 @@ def test_a_config_names_the_class_and_its_two_geometries():
     assert (ring.config.n_heads, ring.config.row_width, ring.window) == (
         64, 1152, 513)
     assert model.fixed_pages(16) == 34 and model.page_run(16, 1024) == 1
+    assert model.table_pages(16, 1024) == 1024
+    # where the kernels run the two sparse walks ask for runs of 8 behind
+    # the ring's 34 entries (PR 66), the ring's own walk for none
+    from ray_tpu.ops.dispatch import compute_platform
+    with compute_platform("tpu"):
+        assert model.page_run(16, 1024) == model.page_run(16, 1026) == 8
+        assert model.table_pages(16, 1024) == 34 + 992
+        assert full.page_run(16, 1024, 34) == 8
+        assert ring.page_run(16, 1024, 34) == 1
     assert model.cache_page_bytes(16) == 2 * 16 * (640 + 128) * 2
     assert model.cache_page_bytes(16, fixed=True) == 3 * 16 * 1152 * 2
     assert model.index_page_bytes(16) == 2 * 16 * 128 * 2

@@ -14,6 +14,7 @@ Chip only:
     chiprun -- python tools/bench_paged.py --shapes pr27,laguna-window
     chiprun -- python tools/bench_paged.py --lanes mixed  # unlike lanes
     chiprun -- python tools/bench_paged.py --run 1,2,4,8  # pages a copy
+    chiprun -- python tools/bench_paged.py --fixed        # behind a slot
 
 `--sweep` puts each block size (in pages) in the place of
 `walk_block_pages`'s answer: what `WALK_BUFFER_BYTES` and `BLOCK_POSITIONS`
@@ -26,7 +27,11 @@ place of `HEAD_UNROLL`. `--run` is the latent kernel alone (the shapes
 many pages, the runs shuffled, and a run a copy: ms a layer and, from their
 slope over the pages walked, the ns a page and a copy that
 `models.latent.LatentAttention.page_run`'s constant was read from (PERF.md
-section 6, PR 64). A tree from before PR 38 has two constants and no
+section 6, PR 64). `--fixed` is `--run` at the four classes that keep a
+state or tail slot ("ling", "falcon", "lfm2", "nemotron": `FIXED` has
+each one's run and lane lengths), a lane's first table entry a single page
+of the fixed class and whole runs behind it, run 1 against the class's run
+(PERF.md section 6, PR 66). A tree from before PR 38 has two constants and no
 rule: copy this file into its `tools/` and run it there to set parent
 beside change in one call (`--json` writes the rows).
 """
@@ -56,20 +61,33 @@ SHAPES = {
     "olmo": ("full", 32, 30, 30, 192, 0),
     "glm": ("latent", 32, 20, 1, 256, 0),
     "longcat": ("latent", 32, 64, 1, 256, 0),       # 64 query rows a lane
+    # the classes that keep a slot: their table's first entry (`FIXED`)
+    "ling": ("latent", 32, 32, 1, 1024, 0),         # docs16k
+    "falcon": ("full", 32, 20, 4, 160, 0),          # chat2k: 16 KB a pool
+    # turns4k: 8 kv heads of 64, two a 128-lane, as the kernel reads them
+    "lfm2": ("full", 64, 32, 4, 256, 0),
+    "nemotron": ("full", 32, 32, 2, 512, 0),        # agent8k: 8 KB a pool
 }
 RUN_POSITIONS = (1024, 2048, 4096)
+# name: (fixed entries, the class's run, a lane's positions) under `--fixed`
+FIXED = {"ling": (1, 4, (2048, 6144, 12288)),
+         "falcon": (1, 4, (512, 1024, 2048)),
+         "lfm2": (1, 4, (512, 1024, 3072)),
+         "nemotron": (1, 8, (1024, 4096, 8000))}
 LATENT, ROW, ROW_HELD = 512, 640, 576
 # `--lanes mixed`: lane i is this share of the row's positions long, so a
 # lane hands its walk on to a shorter one, a longer one and past empty ones
 MIXED = (1.0, 0.25, 0.0, 1.0, 0.5, 0.0, 0.0, 0.75)
 
 
-def program(kernel, window, run=None):
+def program(kernel, window, run=None, fixed=0):
     """`LAYERS` calls of a kernel, each one's queries hanging on the one
     before: lengths and tables are data, so one program a shape and block
-    (and, the latent kernel's, a `run`)."""
+    (and a `run` behind `fixed` table entries)."""
     # (by keyword and only where asked: an older tree's kernel takes none)
     runs = {} if run is None else {"run": run}
+    if fixed:
+        runs["fixed"] = fixed
 
     def step(q, tables, lens, *pools):
         for _ in range(LAYERS):
@@ -82,15 +100,17 @@ def program(kernel, window, run=None):
                     q, *pools, 0, tables, lens, window)
             else:
                 out = pa.paged_decode_attention_kernel(q, *pools, 0, tables,
-                                                       lens)
+                                                       lens, **runs)
             q = q + out * 1e-3
         return q
     return jax.jit(step)
 
 
 def pools_of(kernel, lanes, heads, kvh, table):
-    """(queries, pools): a pool holds a whole table a lane."""
+    """(queries, pools): a pool holds a whole table a lane (and a run
+    more: the fixed class is no whole runs)."""
     key = jax.random.PRNGKey(0)
+    table += 1
     if kernel == "latent":
         return (jax.random.normal(key, (lanes, heads, ROW), jnp.bfloat16),
                 (jax.random.normal(key, (1, lanes * table, PAGE, ROW),
@@ -102,22 +122,31 @@ def pools_of(kernel, lanes, heads, kvh, table):
 
 
 def lanes_at(kernel, lanes, heads, kvh, table, window, length,
-             mixed=False, run=1):
+             mixed=False, run=1, fixed=0):
     """(tables, lengths, bytes the algorithm needs a layer): every lane
     `length` long, or `MIXED`'s shares of it in turn, its pages anywhere
     in the pool: a page at a time or, as the allocator hands them out at
-    `run`, in whole aligned runs of `run` ids behind one another."""
+    `run`, in whole aligned runs of `run` ids behind one another, behind
+    `fixed` single pages of the fixed class (ids under `lanes x fixed`;
+    the table then `run_table_pages` wide)."""
     lens = np.full((lanes,), length, np.int32)
     if mixed:
         lens = (length * np.resize(MIXED, lanes)).astype(np.int32)
     rng = np.random.default_rng(length)
-    free = rng.permutation(lanes * table // run).astype(np.int32) * run
-    tables = np.full((lanes, table), -1, np.int32)
+    first = -(-lanes * fixed // run) * run
+    width = pa.run_table_pages(table, fixed, run) if fixed else table
+    free = first + rng.permutation(
+        lanes * (width - fixed) // run).astype(np.int32) * run
+    heads_ = rng.permutation(lanes * fixed).astype(np.int32)
+    tables = np.full((lanes, width), -1, np.int32)
     for lane, n in enumerate(lens):
-        runs = -(-min(-(-int(n) // PAGE), table) // run)
+        pages = min(-(-int(n) // PAGE), table)
+        held = min(pages, fixed)
+        tables[lane, :held] = heads_[lane * fixed:lane * fixed + held]
+        runs = -(-(pages - held) // run)
         mine, free = free[:runs], free[runs:]
-        tables[lane, :runs * run] = (mine[:, None]
-                                     + np.arange(run)[None]).reshape(-1)
+        tables[lane, fixed:fixed + runs * run] = (
+            mine[:, None] + np.arange(run)[None]).reshape(-1)
     live = int((np.minimum(lens, window) if window else lens).sum())
     if kernel == "latent":
         need = paged_decode_call(live, lanes, 1, ROW_HELD // 2,
@@ -188,23 +217,29 @@ def write(path, rows):
 
 
 def by_run(opts):
-    """The latent kernel at each `--run`: ms a layer by length, then the
-    slope of the lanes' time over the pages they walk."""
-    positions = [int(p) for p in opts.positions.split(",")] \
-        if opts.positions else RUN_POSITIONS
+    """A kernel at each `--run` (under `--fixed`: at 1 and at its class's,
+    behind the class's fixed entries): ms a layer by length, then the slope
+    of the lanes' time over the pages they walk."""
     rows = []
     for name in opts.shapes.split(","):
         kernel, lanes, heads, kvh, table, window = SHAPES[name]
-        if kernel != "latent":
-            raise SystemExit(f"--run is the latent kernel's: {name} is not")
+        fixed, own, positions = FIXED[name] if opts.fixed else (
+            0, None, RUN_POSITIONS)
+        if opts.positions:
+            positions = [int(p) for p in opts.positions.split(",")]
+        if window:
+            raise SystemExit(f"--run is no ring's: {name} walks one")
         q, pools = pools_of(kernel, lanes, heads, kvh, table)
-        for run in (int(r) for r in opts.run.split(",")):
-            fn = program(kernel, window, run)
+        for run in ((1, own) if opts.fixed else (
+                int(r) for r in opts.run.split(","))):
+            fn = program(kernel, window, run, fixed)
             mine = []
             for length in positions:
-                tables, lens, need = lanes_at(*SHAPES[name], length, run=run)
+                tables, lens, need = lanes_at(*SHAPES[name], length, run=run,
+                                              fixed=fixed)
                 ms = timed(fn, q, tables, lens, *pools)
-                mine.append({"shape": name, "run": run, "positions": length,
+                mine.append({"shape": name, "run": run, "fixed": fixed,
+                             "positions": length,
                              "pages": lanes * -(-length // PAGE),
                              "ms_a_layer": ms,
                              "bytes_share": need / HBM * 1e5 / ms})
@@ -216,8 +251,8 @@ def by_run(opts):
                 ns = 1e6 * float(np.polyfit(
                     [r["pages"] for r in mine],
                     [r["ms_a_layer"] for r in mine], 1)[0])
-                rows.append({"shape": name, "run": run, "ns_a_page": ns,
-                             "ns_a_copy": ns * run})
+                rows.append({"shape": name, "run": run, "fixed": fixed,
+                             "ns_a_page": ns, "ns_a_copy": ns * run})
                 print(f"{name} run {run}: {ns:.1f} ns a page, "
                       f"{ns * run:.1f} a copy", flush=True)
     return rows
@@ -238,16 +273,19 @@ def main():
     ap.add_argument("--prefixes", choices=("rule", "each"), default="rule")
     ap.add_argument("--heads", type=int, help="heads a turn of the loop "
                     "over a block's heads, in place of HEAD_UNROLL")
-    ap.add_argument("--run", help="pages a copy of the latent kernel's "
-                    "walk, comma-separated: its tables in such runs")
+    ap.add_argument("--run", help="pages a copy of a kernel's walk, "
+                    "comma-separated: its tables in such runs")
+    ap.add_argument("--fixed", action="store_true", help="the classes that "
+                    "keep a slot (FIXED), at run 1 and at their own run")
     ap.add_argument("--json", help="write the rows here too")
     opts = ap.parse_args()
     if jax.default_backend() != "tpu":
         raise SystemExit("needs a TPU")
     opts.shapes = opts.shapes or ",".join(
-        name for name, shape in SHAPES.items()
-        if not opts.run or shape[0] == "latent")
-    if opts.run:
+        FIXED if opts.fixed else (
+            name for name, shape in SHAPES.items() if name not in FIXED and (
+                not opts.run or shape[0] == "latent")))
+    if opts.run or opts.fixed:
         return write(opts.json, by_run(opts))
     blocks = [int(b) for b in opts.blocks.split(",")] if opts.sweep else [
         None]

@@ -7,6 +7,8 @@ the chip, alone, at GLM-5's widths (32 lanes, 64 heads over latent rows of
     chiprun -- python tools/bench_sparse.py --only prefill --buckets 4096
     chiprun -- python tools/bench_sparse.py --only decode --run 1,4,8,16
     chiprun -- python tools/bench_sparse.py --only decode --widths dots3
+    chiprun -- python tools/bench_sparse.py --only decode --widths dots3 \
+        --fixed 34 --run 1,8 --lengths 3000,6000,12000
 
 A decode step's three pieces a layer, each both ways (the index scores as
 a gather of every table entry and as the walk over live pages, the choice
@@ -17,7 +19,10 @@ length; with `--run` the two walks again at each run length, the tables
 laid out in aligned runs of that many consecutive pages, the runs
 shuffled (`walk_index_ms` / `walk_attend_ms` a row, and the ns a copy
 their slope over the lengths gives; the other pieces, which no run
-touches, are timed at the first run alone); a prefill's
+touches, are timed at the first run alone; with `--fixed` a lane's first
+that many table entries are single pages of a fixed class, in any order,
+the runs open behind them and the walks are told so, as a class that keeps
+a ring or a slot lays its tables: `walk_*_ns_a_page` a row); a prefill's
 three (the index-score kernel, the bisection that makes the mask, the
 masked flash forward) beside the dense causal flash forward at the latent
 classes' blocks. Milliseconds a layer, the median of `--reps` calls, each
@@ -76,17 +81,25 @@ def chained(piece):
     return jax.jit(run)
 
 
-def run_tables(pages, max_pages, run):
-    """Every page of the pool in a table, in aligned runs of `run`
-    consecutive ids, the runs in any order (`run` 1: the pages)."""
-    starts = np.random.default_rng(0).permutation(pages // run) * run
-    return jnp.asarray((starts[:, None] + np.arange(run)).reshape(
-        LANES, max_pages).astype(np.int32))
+def run_tables(max_pages, run, fixed=0):
+    """Tables (LANES, `run_table_pages`) as the allocator lays them: a
+    lane's first `fixed` entries single pages of the fixed class (ids
+    under `LANES x fixed`), the rest aligned runs of `run` consecutive ids
+    behind that class, singles and runs in any order (`run` 1: pages)."""
+    rng = np.random.default_rng(0)
+    width = pa.run_table_pages(max_pages, fixed, run)
+    first = -(-LANES * fixed // run) * run
+    starts = first + rng.permutation(LANES * (width - fixed) // run) * run
+    return jnp.asarray(np.concatenate([
+        rng.permutation(LANES * fixed).reshape(LANES, fixed),
+        (starts[:, None] + np.arange(run)).reshape(LANES, width - fixed)],
+        axis=1).astype(np.int32))
 
 
-def decode(lengths, context, reps, runs=(1,)):
+def decode(lengths, context, reps, runs=(1,), fixed=0):
     max_pages = context // PAGE
-    pages = LANES * max_pages
+    pages = 1 + max(int(run_tables(max_pages, run, fixed).max())
+                    for run in runs)
     ks = jax.random.split(jax.random.PRNGKey(0), 6)
     pool = jax.random.normal(ks[0], (LAYERS, pages, PAGE, ROW),
                              jnp.bfloat16)
@@ -119,7 +132,7 @@ def decode(lengths, context, reps, runs=(1,)):
     def walk_scores(run, a, layer, c):
         return sa._paged_index_call(a["q_idx"] + c.astype(bf), a["w"],
                                     a["idx_pool"], layer, a["live"],
-                                    a["lens"], False, run)
+                                    a["lens"], False, run, fixed)
 
     def threshold(a, layer, c):
         return sa.keep_topk(a["scores"] + c, TOPK)
@@ -127,7 +140,7 @@ def decode(lengths, context, reps, runs=(1,)):
     def walk_attend(run, a, layer, c):
         return sa._paged_attend_call(
             a["q"] + c.astype(bf), a["pool"], layer, a["live"], a["lens"],
-            a["keep"], LATENT, SCALE, False, run)
+            a["keep"], LATENT, SCALE, False, run, fixed)
 
     once = {"index_ms": chained(scores), "choice_ms": chained(choose),
             "attend_ms": chained(attend),
@@ -135,7 +148,7 @@ def decode(lengths, context, reps, runs=(1,)):
             "dense_kernel_ms": chained(dense)}
     rows = []
     for run in runs:
-        tables = run_tables(pages, max_pages, run)
+        tables = run_tables(max_pages, run, fixed)
         pieces = {"walk_index_ms": chained(functools.partial(walk_scores,
                                                              run)),
                   "walk_attend_ms": chained(functools.partial(walk_attend,
@@ -145,7 +158,8 @@ def decode(lengths, context, reps, runs=(1,)):
             a = {"pool": pool, "idx_pool": idx_pool, "q": q, "q_idx": q_idx,
                  "w": w, "lens": jnp.full((LANES,), n, jnp.int32),
                  "live": jnp.where(
-                     jnp.arange(max_pages)[None, :] * PAGE < n, tables, -1)}
+                     jnp.arange(tables.shape[1])[None, :] * PAGE < n, tables,
+                     -1)}
             a["scores"] = jax.jit(lambda a: scores(a, 0, jnp.float32(0)))(a)
             a["pos"], a["chosen"] = jax.jit(
                 lambda s: sa.select_topk(s, TOPK))(a["scores"])
@@ -153,13 +167,19 @@ def decode(lengths, context, reps, runs=(1,)):
             row = {"run": run, "length": n,
                    **{name: timed(fn, a, reps=reps)
                       for name, fn in pieces.items()}}
+            if fixed:
+                row.update(fixed=fixed, **{
+                    name.replace("_ms", "_ns_a_page"):
+                    1e6 * row[name] / (LANES * -(-n // PAGE))
+                    for name in ("walk_index_ms", "walk_attend_ms")})
             rows.append(row)
             print(json.dumps(row), flush=True)
     for run in runs:            # ns a copy: the walks' slopes over length
         mine = [r for r in rows if r["run"] == run]
         if len(mine) > 1:
             pages = [-(-r["length"] // PAGE) for r in mine]
-            copies = [LANES * -(-n // run) for n in pages]
+            copies = [LANES * (min(n, fixed) + -(-max(n - fixed, 0) // run))
+                      for n in pages]
             print(json.dumps({"run": run, **{
                 name.replace("_ms", "_ns_a_copy"): 1e6 * float(np.polyfit(
                     copies, [r[name] for r in mine], 1)[0])
@@ -223,6 +243,8 @@ def main():
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--run", default="1",
                     help="pages a copy of the two walks, comma-separated")
+    ap.add_argument("--fixed", type=int, default=0,
+                    help="table entries of a fixed class ahead of the runs")
     ap.add_argument("--widths", choices=sorted(WIDTHS), default="glm-5",
                     help="whose heads and head widths")
     a = ap.parse_args()
@@ -235,7 +257,7 @@ def main():
     if a.only != "prefill":
         out["decode"] = decode([int(x) for x in a.lengths.split(",")],
                                a.context, a.reps,
-                               [int(x) for x in a.run.split(",")])
+                               [int(x) for x in a.run.split(",")], a.fixed)
     if a.only != "decode":
         out["prefill"] = prefill([int(x) for x in a.buckets.split(",")],
                                  a.reps)

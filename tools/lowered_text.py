@@ -111,6 +111,9 @@ def lowered(root: str, name: str, tpu: bool = False) -> dict:
     cache = jax.eval_shape(lambda: model.init_cache(
         dep["num_pages"], page, **({"fixed_pages": fixed} if fixed else {})))
     table = dep["context_limit"] // page
+    with compute_platform("tpu" if tpu else None):
+        # (a class with a fixed page and runs has whole runs behind it)
+        table = getattr(model, "table_pages", lambda page, n: n)(page, table)
     programs = {
         "decode_step": (jax.jit(
             functools.partial(model.decode_step, page_size=page),
